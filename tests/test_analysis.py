@@ -223,7 +223,7 @@ def test_audit_clean_dp_and_gspmd_steps(fused_wf, eight_devices):
 
 
 def test_audit_flags_f64_promotion(fused_wf, monkeypatch):
-    from veles_tpu._compat import enable_x64
+    from jax import enable_x64
     from veles_tpu.znicz.all2all import All2AllTanh
     orig = All2AllTanh.fused_apply
 
@@ -356,31 +356,23 @@ def test_audit_nonfinite_guard_warning(fused_wf):
 
 
 def test_audit_pipeline_step(fused_wf, eight_devices):
-    from veles_tpu._compat import GRAD_TRANSPOSE_PSUM
     from veles_tpu.parallel.pipeline import make_stage_mesh
     mesh = make_stage_mesh(eight_devices[:2])
     step = fused_wf.build_pipeline_step(mesh, n_microbatches=2)
     findings = audit(step, fused_wf)
-    got = rules(findings)
-    if GRAD_TRANSPOSE_PSUM:
-        assert "pre-vma-numerics" not in got
-    else:
-        # the structured twin of warn_pre_vma_numerics' log line
-        assert "pre-vma-numerics" in got
+    assert "pre-vma-numerics" not in rules(findings)
     assert not [f for f in findings if f.severity == SEV_ERROR]
 
 
 def test_environment_findings_parse_child_argv():
-    from veles_tpu._compat import GRAD_TRANSPOSE_PSUM
     from veles_tpu.analysis.trace import environment_findings
     fs = environment_findings(argv=["wf.py", "--pp", "4"])
     got = rules(fs)
     assert "nonfinite-guard-off" in got
-    assert ("pre-vma-numerics" in got) == (not GRAD_TRANSPOSE_PSUM)
+    assert "pre-vma-numerics" not in got
     fs2 = environment_findings(
         argv=["wf.py", "--sp=2", "--tp=2", "--nonfinite-guard"])
-    assert ("pre-vma-numerics" in rules(fs2)) \
-        == (not GRAD_TRANSPOSE_PSUM)
+    assert "pre-vma-numerics" not in rules(fs2)
     assert "nonfinite-guard-off" not in rules(fs2)
     # --debug-nans counts as a guard for the granular path
     fs3 = environment_findings(argv=["wf.py", "--debug-nans"])
@@ -399,9 +391,6 @@ def test_supervisor_exit_report_embeds_analysis(tmp_path):
     assert "analysis" in data
     got = {f["rule"] for f in data["analysis"]}
     assert "nonfinite-guard-off" in got
-    from veles_tpu._compat import GRAD_TRANSPOSE_PSUM
-    if not GRAD_TRANSPOSE_PSUM:
-        assert "pre-vma-numerics" in got
 
 
 # == granular non-finite guard (ROADMAP gap closed) ===========================

@@ -1,0 +1,295 @@
+"""What the TPU v5e COMPILER says, asked without a chip (ISSUE 21).
+
+Interpret mode hid the compiler for nineteen PRs: kernels that passed
+every interpret-mode test were refused on the chip for scoped-VMEM
+overflow and an unsupported gather. The TPU compiler is installed here
+and compiles for a chip that is DESCRIBED, not attached — so the main
+path's kernels at AlexNet's real widths, the generated points the search
+would try, and the whole fused train step (one chip, and the dp/ZeRO
+step over the 2x2 mesh) are compiled here, at no chip time, on every
+tier-1 run. A compile that passes is not a chip run; `chip_smoke.py` is.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described ONLY inside the module-scoped fixture below —
+never at import, in a skipif/parametrize argument or in conftest.py —
+because one process at a time may load libtpu and xdist workers import
+every test file; everything built from it (shardings, meshes, shapes)
+is built in fixtures or tests; compiles happen in the test's own
+process; the persistent compilation cache is off around them; and all of
+it lives in this ONE file so one worker owns the library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from veles_tpu.analysis import resources as res
+from veles_tpu.ops import pallas_kernels as pk
+from veles_tpu.ops import variants
+
+#: the smoke's per-chip batch (chip_smoke.py) — the LRN sites are
+#: (B*55*55, 96) and (B*27*27, 256) rows x channels
+BATCH = 1024
+LRN_SITES = ((55, 55, 96), (27, 27, 256))
+V5E = "TPU v5 lite"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip, say why
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one (it warns and recompiles)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_pallas(monkeypatch):
+    """Steer the kernels to their COMPILED form although the default
+    backend here is the CPU (the program itself never interprets unless
+    asked — this only answers its `available()` question the way the
+    described chip would)."""
+    monkeypatch.setattr(pk, "available", lambda: True)
+    assert not pk._interpret()
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; returns the compiled text."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _refusal(fn, *args) -> str:
+    with pytest.raises(Exception) as ei:  # noqa: PT011 — XLA's own type
+        _compile(fn, *args)
+    return str(ei.value)
+
+
+# -- the kernels of the main path, forward AND backward ----------------------
+
+@pytest.mark.parametrize("site", LRN_SITES, ids=lambda s: "x".join(map(str, s)))
+def test_lrn_pallas_fwd_bwd_compiles_at_alexnet_sites(one_chip, site,
+                                                      compiled_pallas):
+    x = _sds(one_chip, (BATCH,) + site, jnp.bfloat16)
+
+    def fwd_bwd(a):
+        return jax.grad(
+            lambda v: pk.lrn_pallas(v).astype(jnp.float32).sum())(a)
+
+    assert "tpu_custom_call" in _compile(pk.lrn_pallas, x)
+    assert "tpu_custom_call" in _compile(fwd_bwd, x)
+
+
+@pytest.mark.parametrize("row_tile", (8, 1024))
+def test_sgd_update_pallas_compiles(one_chip, row_tile, compiled_pallas):
+    p = _sds(one_chip, (4096, 4096), jnp.float32)
+    txt = _compile(lambda a, b, c: pk.sgd_update_pallas(
+        a, b, c, 0.01, 0.9, 5e-4, row_tile=row_tile), p, p, p)
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_attention_pallas_fwd_bwd_compiles(one_chip, compiled_pallas):
+    q = _sds(one_chip, (1, 4096, 8, 64), jnp.bfloat16)
+
+    def fwd(a, b, c):
+        return pk.flash_attention_pallas(a, b, c, causal=True)
+
+    def fwd_bwd(a, b, c):
+        return jax.grad(lambda *t: fwd(*t).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(a, b, c)
+
+    assert "tpu_custom_call" in _compile(fwd, q, q, q)
+    assert "tpu_custom_call" in _compile(fwd_bwd, q, q, q)
+
+
+@pytest.mark.parametrize("site", LRN_SITES, ids=lambda s: "x".join(map(str, s)))
+def test_lrn_maxpool_pallas_fwd_bwd_compiles(one_chip, site,
+                                             compiled_pallas):
+    """PR 13's fused template survived ISSUE 21 by routing its window
+    taps through strided REF loads/stores (the value-slice form was
+    refused: "Only 2D gather is supported"), one sample per block."""
+    x = _sds(one_chip, (128,) + site, jnp.bfloat16)
+    txt = _compile(lambda a: jax.grad(
+        lambda v: pk.lrn_maxpool_pallas(v).astype(jnp.float32).sum())(a),
+        x)
+    assert "tpu_custom_call" in txt
+
+
+# -- the ledger and the compiler agree ----------------------------------------
+
+def _lrn_point(one_chip, site, rt, io):
+    x = _sds(one_chip, (BATCH,) + site, jnp.bfloat16)
+    return (lambda a: jax.grad(lambda v: pk.lrn_pallas(
+        v, 2.0, 1e-4, 0.75, 5, rt, io).astype(jnp.float32).sum())(a)), x
+
+
+@pytest.mark.parametrize("site,rt,io,fits", [
+    ((55, 55, 96), 2048, "native", True),
+    ((55, 55, 96), 2048, "f32", True),
+    ((27, 27, 256), 1024, "f32", True),
+    ((27, 27, 256), 2048, "native", False),  # 18.74M > 16M
+    ((27, 27, 256), 2048, "f32", False),     # 21.71M > 16M
+])
+def test_lrn_ledger_matches_the_compiler(one_chip, site, rt, io, fits,
+                                         compiled_pallas):
+    """VMEM_BUDGETS holds the limit the kernels compile under, and the
+    footprint rule prices what Mosaic allocates: a point the compiler
+    refuses is a point the ledger prunes (and the template's axis
+    values that fit do compile)."""
+    name = f"pallas[rt={rt},io={io}]"
+    verdict = res.kernel_verdict(
+        "lrn", name, shapes={"c": site[-1]}, dtype="bfloat16",
+        budget=res.vmem_budget(V5E))
+    assert (verdict is None) == fits, verdict
+    fn, x = _lrn_point(one_chip, site, rt, io)
+    if fits:
+        assert "tpu_custom_call" in _compile(fn, x)
+    else:
+        msg = _refusal(fn, x)
+        assert "exceeded scoped vmem limit" in msg
+        assert "limit 16.00M" in msg
+        assert res.vmem_budget(V5E) == res.SCOPED_VMEM_LIMIT == 16 << 20
+
+
+def test_lrn_maxpool_ledger_prunes_what_the_compiler_refuses(
+        one_chip, compiled_pallas):
+    site = LRN_SITES[0]
+    shapes = {"h": site[0], "w": site[1], "c": site[2]}
+    budget = res.vmem_budget(V5E)
+    assert res.kernel_verdict("lrn_maxpool", "fused[rt=1,io=native,fuse=1]",
+                              shapes=shapes, dtype="bfloat16",
+                              budget=budget) is None
+    assert res.kernel_verdict("lrn_maxpool", "fused[rt=2,io=native,fuse=1]",
+                              shapes=shapes, dtype="bfloat16",
+                              budget=budget) is not None
+    x = _sds(one_chip, (128,) + site, jnp.bfloat16)
+    msg = _refusal(lambda a: jax.grad(lambda v: pk.lrn_maxpool_pallas(
+        v, 2.0, 1e-4, 0.75, 5, (3, 3), (2, 2), 2, "native")
+        .astype(jnp.float32).sum())(a), x)
+    assert "exceeded scoped vmem limit" in msg
+
+
+# -- the whole fused train step from described state --------------------------
+
+@pytest.fixture(scope="module")
+def alexnet():
+    """Full-geometry AlexNet (227x227x3, FC 4096, 1000 classes), host
+    params only: nothing is put on a device."""
+    from veles_tpu import prng
+    from veles_tpu.samples.alexnet import create_workflow
+    prng.seed_all(1234)
+    wf = create_workflow(minibatch_size=8, n_train=8, n_validation=8)
+    wf.initialize(device=None)
+    return wf
+
+
+def _abstract_step_args(step, batch, shardings, xsh):
+    """(state, x, y, w) ShapeDtypeStructs for `step` from HOST shapes and
+    the step's own sharding plan (the checkpoint restore target)."""
+    from veles_tpu.parallel import checkpoint as ck
+    tmpl = ck._abstract_state(step, "threefry2x32")
+    state = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        tmpl, shardings(tmpl))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state["key"] = jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                        sharding=state["key"].sharding)
+    in_shape = tuple(step.forwards[0].input.shape[1:])
+    return (state,
+            jax.ShapeDtypeStruct((batch,) + in_shape, jnp.float32,
+                                 sharding=xsh),
+            jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=xsh),
+            jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=xsh))
+
+
+@pytest.mark.parametrize("lrn", ("banded_matmul", "pallas_one_pass"))
+def test_local_alexnet_train_step_compiles(one_chip, alexnet, lrn,
+                                           compiled_pallas):
+    """The one-chip fused step chip_smoke.py trains (batch 1024, bf16),
+    with the default lowerings and with the Pallas LRN selected — the
+    kernel must be IN the program then, and the program must fit the
+    chip's 16 GB."""
+    variants.select("lrn", lrn)
+    try:
+        step = alexnet.build_fused_step(compute_dtype="bfloat16")
+        assert step.variant_table()["lrn"] == lrn
+        args = _abstract_step_args(
+            step, BATCH,
+            lambda t: jax.tree_util.tree_map(lambda _: one_chip, t),
+            one_chip)
+        compiled = jax.jit(step.train_callable(),
+                           donate_argnums=(0,)).lower(*args).compile()
+    finally:
+        variants.clear_selection("lrn")
+    assert ("tpu_custom_call" in compiled.as_text()) \
+        == (lrn == "pallas_one_pass")
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < 16 << 30, total
+
+
+def test_dp_zero_alexnet_train_step_compiles_for_2x2(topo, alexnet):
+    """The default multi-chip step (`run_fused(mesh=make_mesh())`: dp +
+    ZeRO over four chips, global batch 1024) compiles for the described
+    2x2 mesh with shard_map's varying-axes check ON, asks for the
+    reduce-scatter and the all-gather, and keeps a 1/4 optimizer slice
+    per chip."""
+    from jax.sharding import PartitionSpec as P
+
+    from veles_tpu.parallel import checkpoint as ck
+    from veles_tpu.parallel.mesh import DATA_AXIS, make_mesh
+    mesh = make_mesh(topo.devices)
+    assert dict(mesh.shape)[DATA_AXIS] == 4
+    step = alexnet.build_fused_step(mesh=mesh, compute_dtype="bfloat16")
+    assert step.mode == "dp" and step.zero_active, step.zero_reason
+    args = _abstract_step_args(
+        step, BATCH, lambda t: ck._target_shardings(step, t),
+        NamedSharding(mesh, P(DATA_AXIS)))
+    lowered = jax.jit(step.train_callable(),
+                      donate_argnums=(0,)).lower(*args)
+    asked = lowered.as_text()
+    # what the step ASKS for: one reduce-scatter (the grad_reduce
+    # registry op) and one invariant all-gather per param leaf
+    assert "reduce_scatter" in asked and "all_gather" in asked
+    compiled = lowered.compile()
+    txt = compiled.as_text()
+    # what the v5e compiler MAKES of it (PR 21: 11 all-gathers and 3
+    # combined all-reduces on the 2x2 — it decomposes the reduce-scatters
+    # into all-reduce + slice; a finding for the first benchmark PR)
+    assert "all-gather" in txt
+    assert "reduce-scatter" in txt or "all-reduce" in txt
+    n_params = sum(int(np.prod(a.shape)) for u in alexnet.forwards
+                   for a in u.param_arrays().values() if a)
+    assert n_params == 62378344
+    mem = compiled.memory_analysis()
+    # per-device arguments: replicated f32 params + a quarter of the
+    # velocity (+ the 256-row batch shard) — far below two full copies
+    assert mem.argument_size_in_bytes < 4 * n_params * 1.5 + (256 << 20)

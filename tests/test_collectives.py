@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu._compat import shard_map
+from jax import shard_map
 from veles_tpu.ops import reference as ref
 from veles_tpu.ops import templates, variants
 from veles_tpu.parallel import make_mesh

@@ -32,6 +32,17 @@ SEARCH_OPS = ["lrn", "flash_attn", "sgd_update"]
 
 
 @pytest.fixture(autouse=True)
+def _interpret_mode():
+    """No TPU here: this file ASKS for interpret-mode kernels (they
+    never fall into it by themselves). Kernel-level only — resolve()'s
+    gating stays as it is off a TPU."""
+    import veles_tpu.ops.pallas_kernels as pk
+    prev, pk._FORCE_INTERPRET = pk._FORCE_INTERPRET, True
+    yield
+    pk._FORCE_INTERPRET = prev
+
+
+@pytest.fixture(autouse=True)
 def _isolated_selection():
     """Selection table and equivalence ledger are process-global:
     snapshot/clear around every test (same contract as

@@ -5,7 +5,7 @@ here)."""
 
 import jax
 
-from veles_tpu._compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

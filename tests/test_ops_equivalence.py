@@ -430,7 +430,7 @@ def test_finite_difference_gradcheck_composite_stack():
 
     from veles_tpu.ops import xla as ox
 
-    from veles_tpu._compat import enable_x64
+    from jax import enable_x64
 
     with enable_x64(True):
         rng = np.random.RandomState(0)

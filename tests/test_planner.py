@@ -41,12 +41,25 @@ R4_ABS_BOUND = 0.10
 R3_SHAPE_BOUND = 0.10
 
 
+#: builder measurement 2026-07-30, predates PRs 1–19 (and the installed
+#: jax): AlexNet training on one "TPU v5 lite", samples/s/chip. The r4
+#: batch sweep the planner's MFU curve was fitted to, and the r3 sweep
+#: (older lowering, same protocol) its curve SHAPE is checked against.
+#: History the model was calibrated on — not a current number.
+_MEASURED = {
+    "batch_sweep": {
+        "512": {"value": 13724.09, "mfu": 0.4745},
+        "1024": {"value": 14408.59, "mfu": 0.4982},
+        "2048": {"value": 15165.81, "mfu": 0.5244},
+    },
+    "r3_batch_sweep_same_protocol": {
+        "128": 6455.91, "256": 8950.94, "512": 9619.56,
+        "1024": 9907.12, "2048": 10042.77},
+}
+
+
 def _measured():
-    path = os.path.join(REPO, "MEASURED.json")
-    if not os.path.exists(path):
-        pytest.skip("MEASURED.json not committed")
-    with open(path) as fh:
-        return json.load(fh)
+    return _MEASURED
 
 
 # ---------------------------------------------------------------------------
@@ -120,18 +133,22 @@ def test_pod_efficiency_recipe_pinned():
     assert abs(eff["batch_per_chip_at_target"] - 708) < 5
 
 
-def test_fusion_gain_uses_matching_record_only():
-    path = os.path.join(REPO, "FUSION_AB_RECORD.json")
-    if not os.path.exists(path):
-        pytest.skip("FUSION_AB_RECORD.json not committed")
-    with open(path) as fh:
-        rec = json.load(fh)
-    gain, src = planner.fusion_gain(rec["device_kind"], path)
-    expected = rec["arms"]["fused"]["samples_per_sec"] \
-        / rec["arms"]["composed"]["samples_per_sec"]
-    assert abs(gain - expected) < 1e-9
-    assert src == path
-    # a different device kind must NOT inherit the record's gain
+def test_fusion_gain_uses_matching_record_only(tmp_path):
+    # no fused-vs-composed A/B has been measured on a chip: the repo
+    # commits no record (the PR-13 one was an interpret-mode CPU timing
+    # of a kernel body the v5e compiler refused), so the planner's
+    # answer is the neutral "no record"
+    assert not os.path.exists(os.path.join(REPO, "FUSION_AB_RECORD.json"))
+    gain, src = planner.fusion_gain("TPU v5 lite")
+    assert gain == 1.0 and "none" in src
+    # a record IS read when one exists, for its own device kind only
+    path = str(tmp_path / "rec.json")
+    with open(path, "w") as fh:
+        json.dump({"device_kind": "TPU v5 lite", "arms": {
+            "composed": {"samples_per_sec": 100.0},
+            "fused": {"samples_per_sec": 110.0}}}, fh)
+    gain, src = planner.fusion_gain("TPU v5 lite", path)
+    assert abs(gain - 1.1) < 1e-9 and src == path
     other, osrc = planner.fusion_gain("TPU v93 hyper", path)
     assert other == 1.0 and "none" in osrc
 

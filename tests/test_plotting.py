@@ -209,6 +209,10 @@ def test_tensorboard_scalar_sink(tmp_path):
     if importlib.util.find_spec("torch") is None \
             or importlib.util.find_spec("tensorboard") is None:
         pytest.skip("tensorboard sink is optional; torch/tb not installed")
+    # import the sink's dependency HERE: its first import takes tens of
+    # seconds on a loaded box, and inside the render thread that races
+    # stop()'s 30 s join (the writer is then never closed: no event file)
+    import torch.utils.tensorboard  # noqa: F401
     # (the root.common.tensorboard_dir -> get_renderer path is covered by
     # the CLI drives; this test exercises the renderer arg directly)
     wf = build(tmp_path, max_epochs=3)

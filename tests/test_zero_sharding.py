@@ -352,7 +352,7 @@ def test_grad_reduce_variants_contract(eight_devices):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from veles_tpu._compat import shard_map
+    from jax import shard_map
     from veles_tpu.ops import variants
     mesh = make_mesh(eight_devices)
     n = 8
@@ -372,8 +372,7 @@ def test_grad_reduce_variants_contract(eight_devices):
     assert variants.resolve("grad_reduce").name == "f32"
 
 
-def test_zero_variant_table_names_grad_reduce(eight_devices,
-                                              monkeypatch):
+def test_zero_variant_table_names_grad_reduce(eight_devices):
     wf = build()
     first_batch(wf)
     mesh = make_mesh(eight_devices[:4])
@@ -382,11 +381,13 @@ def test_zero_variant_table_names_grad_reduce(eight_devices,
     step_off = FusedTrainStep(wf, mesh=mesh, mode="dp",
                               zero_sharding="off")
     assert "grad_reduce" not in step_off.variant_table()
-    # vma-era jax: the traced path slices autodiff's all-reduce, no
-    # registry scatter runs — the table must not fabricate provenance
-    from veles_tpu import _compat
-    monkeypatch.setattr(_compat, "GRAD_TRANSPOSE_PSUM", True)
-    assert "grad_reduce" not in step.variant_table()
+    # reported == traced: the registry scatter is in the step's jaxpr
+    state = step.init_state()
+    x = np.asarray(wf.loader.minibatch_data.mem)
+    y = np.asarray(wf.loader.minibatch_labels.mem)
+    text = str(jax.make_jaxpr(step.train_callable())(
+        state, x, y, np.ones(x.shape[0], np.float32)))
+    assert "reduce_scatter" in text
 
 
 # ---------------------------------------------------------------------------

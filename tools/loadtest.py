@@ -830,7 +830,7 @@ def main(argv=None) -> int:
                 # the SLO — a latency gate must never pass vacuously
                 status = "slo_failed"
     except Exception as e:  # noqa: BLE001 — the compact line must say
-        # failed, never vanish (the BENCH_r05 parsed:null class)
+        # failed, never vanish (a failure line must still parse)
         status = "failed"
         record["error"] = f"{type(e).__name__}: {e!s:.300}"
     record["status"] = status

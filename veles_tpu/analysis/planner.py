@@ -15,7 +15,7 @@ configuration search on top:
 
 - **compute**: `train_flops_per_sample * batch / (peak * MFU(batch))`
   where MFU(b) is a saturating curve `MFU_MAX * b / (b + B_HALF)`
-  calibrated on the committed r4 on-chip batch sweep (MEASURED.json;
+  calibrated on the builders' r4 on-chip batch sweep (2026-07-30;
   see docs/PLANNER.md for the fit and its error). Fusion claims scale
   the whole-step time by the measured fused/composed ratio from
   FUSION_AB_RECORD.json when the record's device kind matches.
@@ -86,7 +86,7 @@ DEVICE_HBM_BYTES: Dict[str, int] = {
 }
 
 #: MFU(b) = MFU_MAX * b / (b + B_HALF), exact fit through the r4
-#: on-chip sweep endpoints (MEASURED.json batch_sweep: 0.4745 @ 512,
+#: on-chip sweep endpoints (builders' 2026-07-30 batch sweep: 0.4745 @ 512,
 #: 0.5244 @ 2048; the interior point 1024 lands within 1.7%). The fit
 #: is per-device-kind in principle; only the v5e family has a
 #: committed sweep, so predictions elsewhere carry calibrated=False.
@@ -97,9 +97,10 @@ MFU_B_HALF = 74.397
 CALIBRATED_KINDS = frozenset({"TPU v5 lite", "TPU v5e"})
 
 #: the fused lrn+maxpool search point the planner's `fusion="fused"`
-#: arm claims (the FUSION_AB_RECORD.json point; its VMEM footprint is
-#: the fused arm's gate input)
-FUSED_LRN_POOL_POINT = "fused[rt=2,io=native,fuse=1]"
+#: arm claims — the one sample tile the v5e compiler admits at both
+#: AlexNet LRN sites (tests/test_chip_compile.py); its VMEM footprint is
+#: the fused arm's gate input
+FUSED_LRN_POOL_POINT = "fused[rt=1,io=native,fuse=1]"
 
 #: bytes of one feed sample beyond the f32 image: int32 label + f32
 #: sample weight (loader minibatch_labels + minibatch_valid)
@@ -235,10 +236,12 @@ def mfu_model(batch_per_chip: float, *, mfu_max: float = MFU_MAX,
 def fusion_gain(device_kind: str,
                 record_path: str = "FUSION_AB_RECORD.json"
                 ) -> Tuple[float, str]:
-    """Whole-step fused/composed speedup claimed by the committed
-    PR-13 A/B record, applied only when the record was measured on
-    the SAME device kind (the CPU-interpret record must not predict
-    chip behavior). Returns (gain, provenance)."""
+    """Whole-step fused/composed speedup claimed by a
+    `tools/ablate.py --fusion` A/B record, applied only when the record
+    was measured on the SAME device kind (a CPU-interpret record must
+    not predict chip behavior). No record is committed — none has been
+    measured on a chip — so the answer today is the neutral
+    "no record". Returns (gain, provenance)."""
     try:
         with open(record_path) as fh:
             rec = json.load(fh)
@@ -312,8 +315,13 @@ def predict_step(cfg: PlanConfig, geom: StepGeometry, *,
     """The model: predicted seconds for one optimizer step of `cfg`
     on `device_kind`, with every term exposed for falsification."""
     peak = _env_float("VELES_PLAN_PEAK_FLOPS", 0.0) \
-        or DEVICE_PEAK_FLOPS.get(device_kind, 0.0) \
-        or DEVICE_PEAK_FLOPS["TPU v5 lite"]
+        or DEVICE_PEAK_FLOPS.get(device_kind, 0.0)
+    if not peak:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAK_FLOPS)}): add it to "
+            "DEVICE_PEAK_FLOPS or set VELES_PLAN_PEAK_FLOPS — an unknown "
+            "chip is never priced as a v5e")
     calibrated = (device_kind in CALIBRATED_KINDS
                   and "VELES_PLAN_PEAK_FLOPS" not in os.environ)
     batch = int(cfg.batch_per_chip)
@@ -705,7 +713,7 @@ def plan_search(geom: Optional[StepGeometry] = None, *,
                 geom.train_flops_per_sample / 1e9,
             "mfu_curve": {"mfu_max": MFU_MAX, "b_half": MFU_B_HALF,
                           "source": "r4 on-chip batch sweep "
-                                    "(MEASURED.json)"},
+                                    "(builder measurement 2026-07-30)"},
         },
         "device_kind": device_kind,
         "n_chips": n_chips,

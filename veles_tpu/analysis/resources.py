@@ -62,7 +62,7 @@ import numpy as np
 from veles_tpu.analysis.findings import SEV_ERROR, SEV_WARN, Finding
 
 __all__ = [
-    "VMEM_BUDGETS", "VMEM_BUDGET_ENV", "HBM_LIMIT_ENV",
+    "SCOPED_VMEM_LIMIT", "VMEM_BUDGETS", "VMEM_BUDGET_ENV", "HBM_LIMIT_ENV",
     "InfeasibleCandidateError", "ResourcePreflightError",
     "vmem_budget", "device_limit", "kernel_footprint", "kernel_verdict",
     "shapes_from_signatures", "kernel_findings", "step_resource_report",
@@ -81,27 +81,27 @@ HBM_LIMIT_ENV = "VELES_HBM_LIMIT"
 #: device limit (the static resident model always runs)
 PREFLIGHT_ENV = "VELES_RESOURCE_PREFLIGHT"
 
+#: the limit every kernel in ops/pallas_kernels.py compiles under: no
+#: pallas_call there passes `vmem_limit_bytes`, so Mosaic holds each one
+#: to its DEFAULT scoped-VMEM limit, whatever the chip's physical VMEM
+#: (128 MiB on a v5e). Asked of the v5e compiler in PR 21 ("Scoped
+#: allocation with size 18.83M and limit 16.00M exceeded scoped vmem
+#: limit"). ONE number: the kernels' own tile heuristics
+#: (pallas_kernels._lrn_row_tile) and the search's pruning both read it.
+SCOPED_VMEM_LIMIT = 16 << 20
+
 #: per-device_kind VMEM budget (bytes) a Pallas kernel's resident blocks
-#: must fit in. Sources: the Pallas TPU pipelining docs (~16 MB/core on
-#: v2-v4) and the v5e/v6e 128 MiB / v7x 64 MiB figures; a small reserve
-#: for Mosaic's own scratch is deliberately NOT subtracted — the
-#: footprint model under-counts in-kernel temporaries by about as much
-#: (blind-spot note in the module docstring). Unknown kinds (CPU
-#: interpret mode, GPUs) get None: no static budget, pruning inactive
-#: unless the env override supplies one.
+#: AND in-kernel temporaries must fit in — the compiler's scoped limit
+#: above, not the physical VMEM. Confirmed against the compiler for
+#: "TPU v5 lite" only; the other kinds carry the same default because
+#: the kernels pass no limit of their own. Unknown kinds (CPU interpret
+#: mode, GPUs) get None: no static budget, pruning inactive unless the
+#: env override supplies one.
 VMEM_BUDGETS: Dict[str, int] = {
-    "TPU v2": 16 << 20,
-    "TPU v3": 16 << 20,
-    "TPU v4": 16 << 20,
-    "TPU v4 lite": 16 << 20,
-    "TPU v5": 128 << 20,
-    "TPU v5p": 128 << 20,
-    "TPU v5 lite": 128 << 20,
-    "TPU v5e": 128 << 20,
-    "TPU v6 lite": 128 << 20,
-    "TPU v6e": 128 << 20,
-    "TPU v7x": 64 << 20,
-}
+    kind: SCOPED_VMEM_LIMIT
+    for kind in ("TPU v2", "TPU v3", "TPU v4", "TPU v4 lite", "TPU v5",
+                 "TPU v5p", "TPU v5 lite", "TPU v5e", "TPU v6 lite",
+                 "TPU v6e", "TPU v7x")}
 
 #: pre-flight warning threshold: predicted high-water above this
 #: fraction of the device limit warns (above 1.0 errors)
@@ -206,6 +206,7 @@ def shapes_from_signatures(op: str, sigs) -> Dict[str, Any]:
     WORST (largest) instance wins, since one registry selection covers
     every instance of the op."""
     out: Dict[str, Any] = {}
+    worst_band = 0
     for sig in sigs or ():
         if not isinstance(sig, dict):
             continue
@@ -228,11 +229,17 @@ def shapes_from_signatures(op: str, sigs) -> Dict[str, Any]:
                     tuple(min(a, b) for a, b in zip(prev, st))
             sig = sig.get("lrn") or {}
         ss = sig.get("sample_shape")
-        if op in ("lrn", "lrn_maxpool") and ss:
+        if op == "lrn_maxpool" and ss and len(ss) == 3:
+            # the fused kernel blocks whole (H, W, C) bands: the worst
+            # instance is the largest lane-padded band, kept together
+            # (a dim-wise max would price a band no layer has)
+            h, w, c = (int(v) for v in ss)
+            vol = h * w * (-(-c // 128) * 128)
+            if vol > worst_band:
+                worst_band = vol
+                out.update(h=h, w=w, c=c)
+        elif op in ("lrn", "lrn_maxpool") and ss:
             out["c"] = max(out.get("c", 0), int(ss[-1]))
-            if len(ss) == 3:
-                out["h"] = max(out.get("h", 0), int(ss[0]))
-                out["w"] = max(out.get("w", 0), int(ss[1]))
         elif op == "flash_attn" and ss:
             out["s"] = max(out.get("s", 0), int(ss[0]))
             if sig.get("head_dim"):
@@ -337,7 +344,8 @@ def _liveness_highwater(jaxpr) -> int:
                 continue
             death[v] = i
     for v in jaxpr.outvars:
-        death[v] = n
+        if type(v).__name__ != "Literal":   # a constant output: no def
+            death[v] = n
     alive: Dict[Any, int] = {}
     peak = 0
     for i, eqn in enumerate(eqns):

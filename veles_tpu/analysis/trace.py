@@ -35,9 +35,6 @@ Rules (docs/ANALYSIS.md):
   selected cross-op fusion winner (lrn_maxpool) claims an adjacent unit
   pair, and the fused kernel's geometry must equal what the claimed
   pass-through unit declared at initialize time (`_fusion_findings`);
-- `pre-vma-numerics` (warn): the structured form of
-  `_compat.warn_pre_vma_numerics` — GPipe / seq×TP builds on pre-vma
-  jax have ~1e-3 trained-loss deviation;
 - `nonfinite-guard-off` (warn): the run is configured without the
   non-finite loss guard, so the supervisor's snapshot rollback
   (exit 81) can never trigger on divergence.
@@ -57,7 +54,7 @@ import numpy as np
 from veles_tpu.analysis.findings import SEV_ERROR, SEV_WARN, Finding
 
 #: substrings of primitive names that force a host round-trip per step
-_HOST_SYNC_MARKERS = ("callback", "infeed", "outfeed")
+_HOST_SYNC_MARKERS = ("callback", "debug_print", "infeed", "outfeed")
 
 #: primitives whose flops dominate — the ones `precision-above-compute`
 #: watches when a sub-f32 compute dtype is configured
@@ -74,7 +71,7 @@ _DONATION_MIN_ELEMS = 32
 # -- jaxpr walking ------------------------------------------------------------
 
 def _sub_jaxprs(params):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for x in vs:
@@ -266,9 +263,6 @@ def _collective_findings(step, mesh) -> List[Finding]:
         return []
     import os
 
-    from veles_tpu import _compat
-    if _compat.GRAD_TRANSPOSE_PSUM:
-        return []
     from veles_tpu.ops import variants as va
     from veles_tpu.parallel.mesh import DATA_AXIS
     name = step._grad_reduce_variant().name
@@ -535,9 +529,6 @@ def audit_fused_step(step, x, y, w=None, state=None,
     only traces."""
     import jax
 
-    from veles_tpu import _compat
-    from veles_tpu.parallel.mesh import MODEL_AXIS
-
     findings: List[Finding] = []
     sharding = _sharding_findings(step)
     sharding += _fusion_findings(step)   # fused-pair geometry (any mode)
@@ -549,13 +540,6 @@ def audit_fused_step(step, x, y, w=None, state=None,
         return findings
     mesh = getattr(step, "mesh", None)
     is_pipeline = hasattr(step, "_microbatch")
-    if not _compat.GRAD_TRANSPOSE_PSUM:
-        if is_pipeline:
-            findings.append(_pre_vma_finding("GPipe pipeline step"))
-        elif (getattr(step, "mode", None) == "seq" and mesh is not None
-                and mesh.shape.get(MODEL_AXIS, 1) > 1):
-            findings.append(_pre_vma_finding("seq x TP (3-axis) "
-                                             "fused step"))
     if nonfinite_guard is not None and not nonfinite_guard:
         findings.append(_guard_off_finding())
 
@@ -613,15 +597,6 @@ def audit_workflow(workflow, step=None,
 
 # -- environment findings (supervisor exit report, --verify-workflow) ---------
 
-def _pre_vma_finding(context: str) -> Finding:
-    from veles_tpu._compat import _jax_version
-    return Finding(
-        "pre-vma-numerics", SEV_WARN, context,
-        f"built on pre-vma jax {_jax_version()}: trained numerics may "
-        "deviate ~1e-3 relative from the single-device trajectory "
-        "(grad-transpose psum semantics); a jax upgrade clears it")
-
-
 def _guard_off_finding() -> Finding:
     return Finding(
         "nonfinite-guard-off", SEV_WARN, "training loop",
@@ -646,7 +621,6 @@ def environment_findings(argv: Optional[Sequence[str]] = None,
                          nonfinite_guard: Optional[bool] = None
                          ) -> List[Finding]:
     """Config-level findings derivable WITHOUT building a step: the
-    pre-vma numerics hazard for GPipe / seq×TP configurations and the
     disabled non-finite guard. Accepts either explicit flag values or a
     child argv to parse them from (the supervisor passes its child
     command line)."""
@@ -672,15 +646,6 @@ def environment_findings(argv: Optional[Sequence[str]] = None,
             nonfinite_guard = ("--nonfinite-guard" in argv
                                or "--debug-nans" in argv)
     out: List[Finding] = []
-    from veles_tpu import _compat
-    if not _compat.GRAD_TRANSPOSE_PSUM:
-        if pp:
-            out.append(_pre_vma_finding("GPipe pipeline step"))
-        if (sp or 1) > 1 and (tp or 1) > 1:
-            out.append(_pre_vma_finding("seq x TP (3-axis) fused step"))
-        for context in sorted(_compat._WARNED):
-            if not any(f.unit == context for f in out):
-                out.append(_pre_vma_finding(context))
     if nonfinite_guard is not None and not nonfinite_guard:
         out.append(_guard_off_finding())
     return out

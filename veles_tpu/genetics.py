@@ -135,6 +135,11 @@ class Population(Logger):
             for m, f in zip(todo, fitnesses):
                 m.fitness = float(f)
         elif self.max_workers > 1:
+            # a chip belongs to one process: a parent that already
+            # holds it cannot hand it to fitness workers
+            from veles_tpu.parallel.memstats import \
+                refuse_spawn_if_chip_held
+            refuse_spawn_if_chip_held("Population(max_workers>1)")
             with cf.ProcessPoolExecutor(self.max_workers) as pool:
                 futs = {pool.submit(self.fitness_fn,
                                     m.overrides(self.tunables)): m

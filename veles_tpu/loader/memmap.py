@@ -214,7 +214,13 @@ class MemmapImageLoader(PrefetchingLoader):
         if self.native == "off":
             return False
         from veles_tpu import native_gather
-        return native_gather.available()
+        use = native_gather.available()
+        if not getattr(self, "_gather_logged", False):
+            self._gather_logged = True
+            self.info("row gather: %s", "native (native/build/"
+                      "libhostgather.so, built by this checkout)"
+                      if use else "numpy")
+        return use
 
     def _produce_rows(self, indices: np.ndarray):
         """Gather + seeded hflip + normalize, with augmentation applied
@@ -280,6 +286,7 @@ class MemmapImageLoader(PrefetchingLoader):
     def __getstate__(self):
         d = super().__getstate__()
         d["_maps"] = []
+        d.pop("_gather_logged", None)   # process-local: log once per run
         return d
 
     def __setstate__(self, d):

@@ -5,13 +5,16 @@ Parity: the native data-path slot of the reference's loaders (SURVEY.md
 packed-memmap pipeline's hot path is a row gather + flip + normalize;
 `native/host_gather.cpp` fans it over threads. Python resolves shard
 bases + row offsets into flat per-row source addresses, so the C++ side
-is shard-agnostic. Falls back cleanly when no toolchain is available
-(`available()` -> False; callers keep the numpy path).
+is shard-agnostic. Built from the committed sources on first use
+(`make` decides staleness; `native/build/` is git-ignored). When no
+toolchain is available `available()` answers False and callers keep
+the numpy path — said ONCE at warning, with make's own error.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -45,11 +48,9 @@ def _load_lib_locked() -> Optional[ctypes.CDLL]:
     if _lib is not None or _lib_failed:   # built while we waited
         return _lib
     try:
-        src = os.path.join(_NATIVE_DIR, "host_gather.cpp")
-        if not os.path.exists(_LIB_PATH) or \
-                os.path.getmtime(_LIB_PATH) < os.path.getmtime(src):
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                           capture_output=True)
+        subprocess.run(["make", "-C", _NATIVE_DIR,
+                        "build/libhostgather.so"], check=True,
+                       capture_output=True)
         lib = ctypes.CDLL(_LIB_PATH)
         lib.hg_gather_u8.argtypes = [
             ctypes.POINTER(ctypes.c_longlong), ctypes.c_int,
@@ -61,8 +62,13 @@ def _load_lib_locked() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p, ctypes.c_float, ctypes.c_float,
             ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_int]
         _lib = lib
-    except (OSError, subprocess.CalledProcessError):
+    except (OSError, subprocess.CalledProcessError) as e:
         _lib_failed = True
+        detail = (getattr(e, "stderr", b"") or b"").decode(
+            errors="replace").strip()[-300:] or str(e)
+        logging.getLogger("veles.native_gather").warning(
+            "native gather not built (%s): loaders use the numpy "
+            "gather instead", detail)
     return _lib
 
 

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from veles_tpu._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 NEG_INF = -1e30
 

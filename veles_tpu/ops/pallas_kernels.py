@@ -7,9 +7,11 @@ flash-attention-style blocks (the ring already handles cross-chip; this
 kernel is the intra-chip tile loop).
 
 Every kernel has a lax twin in ops.xla / ops.attention — these are
-drop-in replacements gated by `available()`, and tests run them in
-interpreter mode on CPU against the golden models, so correctness is
-pinned even where no TPU is attached (SURVEY.md §4 strategy).
+drop-in replacements gated by `available()`. Interpret mode is something
+a test ASKS for (`_FORCE_INTERPRET`, `variants.pallas_interpret()`),
+never something the program falls into: off a TPU an unasked kernel
+call fails in the compiler, and a backend that cannot initialise raises.
+tests/test_chip_compile.py compiles every kernel for a described v5e.
 """
 
 from __future__ import annotations
@@ -39,17 +41,22 @@ _FORCE_INTERPRET = False  # tests set this on CPU
 _LANE = 128
 #: f32 min sublane tile: the floor every row blocking is clamped to
 _MIN_ROW_TILE = 8
-#: LRN row-tile heuristic bounds: start at the min sublane tile, stop
-#: growing at ~1MB VMEM blocks (see _lrn_call docstring)
+#: LRN row-tile heuristic bounds: start at the min sublane tile, grow
+#: while the backward's whole footprint (lrn_vmem_bytes) stays inside
+#: the compiler's scoped-VMEM limit (analysis.resources)
 _LRN_TILE_MAX = 4096
-_LRN_VMEM_BLOCK_BYTES = 1 << 20
+#: f32 (rows, C) temporaries the LRN backward keeps live beside its
+#: pipelined blocks — fitted to what the v5e compiler reports
+#: (18.83M at rt=4096, C=96, bf16: 6M of blocks + 6.4 temporaries)
+_LRN_BWD_F32_TEMPS = 7
 #: fused-SGD row blocking seed (the pre-search hand-written value)
 _SGD_ROW_TILE = 8
 #: fused LRN+maxpool sample tile seed: SAMPLES per VMEM block (each
 #: "row" of this kernel's grid is one sample's whole (H, W, C) band —
-#: the pooling windows never cross it); 2 keeps AlexNet-L1 blocks near
-#: the ~1MB LRN heuristic
-_LRN_POOL_ROW_TILE = 2
+#: the pooling windows never cross it). 1 is what the v5e compiler
+#: admits at AlexNet-L1 (55x55x96): 2 samples need 27.76M of scoped
+#: VMEM in the backward against the 16M limit
+_LRN_POOL_ROW_TILE = 1
 #: flash-attention block seeds (tuned by hand on v5e 2026-07-29; the
 #: search explores the full blk_q x blk_k x kv_order space around them)
 _FLASH_BLK_Q = 512
@@ -70,15 +77,15 @@ def flash_fit_block(s: int, blk: int) -> int:
 
 
 def available() -> bool:
-    """True when the default backend can run compiled Pallas TPU kernels."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+    """True when the default backend can run compiled Pallas TPU kernels.
+    A backend that fails to initialise RAISES here: answering "no TPU"
+    would turn a broken chip into a quiet CPU/interpret run."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _interpret() -> bool:
-    return _FORCE_INTERPRET or not available()
+    from veles_tpu.ops import variants
+    return _FORCE_INTERPRET or variants.pallas_interpret_active()
 
 
 def _pad_rows(x2, row_tile: int):
@@ -179,14 +186,27 @@ def _lrn_bwd_kernel(x_ref, e_ref, out_ref, *, half: int, k: float,
                   - 2.0 * alpha * beta * x * tsum).astype(out_ref.dtype)
 
 
+def lrn_vmem_bytes(row_tile: int, c: int, itemsize: int) -> int:
+    """Scoped-VMEM bytes of the LRN pair's worst direction, the
+    backward: 2 inputs (x, err) + 1 output, each double-buffered by the
+    pipeline, plus its live f32 temporaries — all on lane-PADDED
+    (row_tile, ceil(C/128)·128) tiles (C=96 occupies 128 lanes). The
+    ONE model behind the tile heuristic below and the search's pruning
+    (ops/templates._lrn_vmem)."""
+    c_pad = -(-c // _LANE) * _LANE
+    return row_tile * c_pad * (2 * 3 * itemsize + _LRN_BWD_F32_TEMPS * 4)
+
+
 def _lrn_row_tile(n_rows: int, c: int, itemsize: int) -> int:
-    """The hand-written heuristic: grow the tile until blocks reach
-    ~1MB of VMEM. Conv-activation LRN inputs have a few hundred thousand
-    rows (AlexNet L1: 1024·55·55), so a min-sublane tile dies of grid
-    overhead (measured 3.5× slower than XLA); large tiles amortize it."""
+    """The hand-written heuristic: the largest power-of-two tile whose
+    backward still fits the compiler's scoped-VMEM limit.
+    Conv-activation LRN inputs have a few hundred thousand rows (AlexNet
+    L1: 1024·55·55), so a min-sublane tile dies of grid overhead; large
+    tiles amortize it."""
+    from veles_tpu.analysis.resources import SCOPED_VMEM_LIMIT
     rt = _MIN_ROW_TILE
     while rt < _LRN_TILE_MAX and rt * 2 <= max(n_rows, _MIN_ROW_TILE) \
-            and rt * 2 * c * itemsize <= _LRN_VMEM_BLOCK_BYTES:
+            and lrn_vmem_bytes(rt * 2, c, itemsize) <= SCOPED_VMEM_LIMIT:
         rt *= 2
     return rt
 
@@ -198,7 +218,7 @@ def _lrn_call(kernel, args, c: int, k, alpha, beta, n: int,
 
     HBM traffic is the whole game (LRN is bandwidth-bound). The two
     tuning axes the search owns (ops/templates.py):
-    - `row_tile`: rows per block; None = the ~1MB-VMEM heuristic
+    - `row_tile`: rows per block; None = the scoped-VMEM heuristic
       (_lrn_row_tile), which is the hand-written incumbent.
     - `io_dtype`: "native" moves blocks in the caller's dtype (bf16
       under the fused step — HALF the bytes of the old force-f32
@@ -301,96 +321,104 @@ def _pool_out_hw(h: int, w: int, ky: int, kx: int, sy: int, sx: int):
     return oh, ow
 
 
-def _pool_pad_hw(y, ky: int, kx: int, sy: int, sx: int, fill):
-    """Pad the spatial axes of (nt, H, W, C) so every ceil-mode window
-    is fully resident; returns (padded, oh, ow)."""
-    _, h, w, _ = y.shape
+def _pool_canvas_hw(h: int, w: int, ky: int, kx: int, sy: int, sx: int):
+    """(hp, wp): the padded spatial extent on which every ceil-mode
+    window is fully resident."""
     oh, ow = _pool_out_hw(h, w, ky, kx, sy, sx)
-    hp = (oh - 1) * sy + ky
-    wp = (ow - 1) * sx + kx
-    y = jnp.pad(y, ((0, 0), (0, hp - h), (0, wp - w), (0, 0)),
-                constant_values=fill)
-    return y, oh, ow
+    return (oh - 1) * sy + ky, (ow - 1) * sx + kx
 
 
-def _pool_window_slices(yp, ky, kx, sy, sx, oh, ow):
-    """The ky·kx shifted strided views of the padded block — one per
-    window tap, each (nt, oh, ow, C), in window scan order (the order
-    ties break by, matching the goldens' argmax)."""
-    return [yp[:, dy:dy + (oh - 1) * sy + 1:sy,
-               dx:dx + (ow - 1) * sx + 1:sx, :]
+def _lane_blocks(c: int):
+    """Channel (lane) blocking of the pooling canvases: Mosaic's strided
+    loads/stores need a base ref whose last dim is at most one 128-lane
+    tile ("The last dim size is not 128 in original base memref"), so a
+    C > 128 canvas is kept as C/128 lane blocks. Returns (n_blocks,
+    block_width)."""
+    if c <= _LANE:
+        return 1, c
+    if c % _LANE:
+        raise ValueError(
+            f"lrn_maxpool_pallas: {c} channels is neither <= {_LANE} nor "
+            f"a multiple of it (the strided pooling taps need 128-lane "
+            "channel blocks)")
+    return c // _LANE, _LANE
+
+
+def _canvas_fill(ref, value, h: int, w: int, fill):
+    """Write an (nt, h, w, C) value into the top-left of the lane-blocked
+    (n_blocks, nt, hp, wp, cb) canvas, `fill` everywhere else."""
+    ref[...] = jnp.full(ref.shape, fill, jnp.float32)
+    cb = ref.shape[-1]
+    for j in range(ref.shape[0]):
+        ref[j, :, :h, :w, :] = value[..., j * cb:(j + 1) * cb]
+
+
+def _canvas_taps(ky: int, kx: int, sy: int, sx: int, oh: int, ow: int):
+    """Index tuples (after the lane-block axis) of the ky·kx strided
+    window taps, in window scan order (the order ties break by, matching
+    the goldens' argmax). Strided REF indexing — a strided slice of an
+    in-register VALUE lowers to a gather the v5e compiler refuses
+    ("Only 2D gather is supported")."""
+    return [(slice(None), pl.ds(dy, oh, stride=sy),
+             pl.ds(dx, ow, stride=sx), slice(None))
             for dy in range(ky) for dx in range(kx)]
 
 
-def _dilate_hw(a, sy: int, sx: int):
-    """Stride-dilate the two spatial axes (value at (i, j) lands at
-    (i·sy, j·sx)) via interleave-with-zeros — stack+reshape only, no
-    scatter (Mosaic-friendly)."""
-    nt, oh, ow, c = a.shape
-    if sy > 1:
-        z = jnp.zeros_like(a)
-        a = jnp.stack([a] + [z] * (sy - 1), axis=2) \
-            .reshape(nt, oh * sy, ow, c)
-    if sx > 1:
-        z = jnp.zeros_like(a)
-        a = jnp.stack([a] + [z] * (sx - 1), axis=3) \
-            .reshape(nt, a.shape[1], ow * sx, c)
-    return a
+def _canvas_load(ref, idx):
+    parts = [ref[(j,) + idx] for j in range(ref.shape[0])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, -1)
 
 
-def _place_hw(a, dy: int, dx: int, hp: int, wp: int):
-    """Embed a dilated contribution at spatial offset (dy, dx) of an
-    (hp, wp) canvas (pad, then crop the zero interleave tail)."""
-    a = jnp.pad(a, ((0, 0), (dy, max(0, hp - dy - a.shape[1])),
-                    (dx, max(0, wp - dx - a.shape[2])), (0, 0)))
-    return a[:, :hp, :wp, :]
-
-
-def _lrn_pool_fwd_kernel(x_ref, y_ref, *, half: int, k: float,
+def _lrn_pool_fwd_kernel(x_ref, y_ref, yp_ref, *, half: int, k: float,
                          alpha: float, beta: float, ky: int, kx: int,
                          sy: int, sx: int):
     x = x_ref[...].astype(jnp.float32)
     s = k + alpha * _window_sum_last(x * x, half)
-    y = x * _pow_neg(s, beta)
-    yp, oh, ow = _pool_pad_hw(y, ky, kx, sy, sx, -jnp.inf)
+    _canvas_fill(yp_ref, x * _pow_neg(s, beta), x.shape[1], x.shape[2],
+                 -jnp.inf)
     out = None
-    for sl in _pool_window_slices(yp, ky, kx, sy, sx, oh, ow):
+    for idx in _canvas_taps(ky, kx, sy, sx, y_ref.shape[1],
+                            y_ref.shape[2]):
+        sl = _canvas_load(yp_ref, idx)
         out = sl if out is None else jnp.maximum(out, sl)
     y_ref[...] = out.astype(y_ref.dtype)
 
 
-def _lrn_pool_bwd_kernel(x_ref, g_ref, dx_ref, *, half: int, k: float,
-                         alpha: float, beta: float, ky: int, kx: int,
-                         sy: int, sx: int):
+def _lrn_pool_bwd_kernel(x_ref, g_ref, dx_ref, yp_ref, gp_ref, *,
+                         half: int, k: float, alpha: float, beta: float,
+                         ky: int, kx: int, sy: int, sx: int):
     """One-pass backward of the composed pair: recompute the LRN output,
     route the pooled error to each window's FIRST max (the goldens' and
     select_and_scatter's tie rule — equality routing alone would send a
     tied window's gradient to every tied element, e.g. post-ReLU zeros),
-    then the closed-form LRN backward — all on the resident block."""
+    then the closed-form LRN backward — all on the resident block. The
+    routing accumulates through strided stores into a zeroed canvas."""
     x = x_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
+    h, w = x.shape[1], x.shape[2]
     s = k + alpha * _window_sum_last(x * x, half)
     d = _pow_neg(s, beta)
-    y = x * d
-    yp, oh, ow = _pool_pad_hw(y, ky, kx, sy, sx, -jnp.inf)
-    hp, wp = yp.shape[1], yp.shape[2]
-    slices = _pool_window_slices(yp, ky, kx, sy, sx, oh, ow)
-    m = slices[0]
-    for sl in slices[1:]:
-        m = jnp.maximum(m, sl)
+    _canvas_fill(yp_ref, x * d, h, w, -jnp.inf)
+    taps = _canvas_taps(ky, kx, sy, sx, g.shape[1], g.shape[2])
+    m = None
+    for idx in taps:
+        sl = _canvas_load(yp_ref, idx)
+        m = sl if m is None else jnp.maximum(m, sl)
     n_taps = ky * kx
     win = None
-    for lin, sl in enumerate(slices):
-        cand = jnp.where(sl == m, jnp.int32(lin), jnp.int32(n_taps))
+    for lin, idx in enumerate(taps):
+        cand = jnp.where(_canvas_load(yp_ref, idx) == m, jnp.int32(lin),
+                         jnp.int32(n_taps))
         win = cand if win is None else jnp.minimum(win, cand)
-    g_lrn_p = None
-    for lin, (dy, dx) in enumerate((dy, dx) for dy in range(ky)
-                                   for dx in range(kx)):
-        placed = _place_hw(
-            _dilate_hw(jnp.where(win == lin, g, 0.0), sy, sx),
-            dy, dx, hp, wp)
-        g_lrn_p = placed if g_lrn_p is None else g_lrn_p + placed
-    g_lrn = g_lrn_p[:, :x.shape[1], :x.shape[2], :]
+    gp_ref[...] = jnp.zeros(gp_ref.shape, jnp.float32)
+    cb = gp_ref.shape[-1]
+    for lin, idx in enumerate(taps):
+        routed = jnp.where(win == lin, g, 0.0)
+        for j in range(gp_ref.shape[0]):
+            gp_ref[(j,) + idx] = gp_ref[(j,) + idx] \
+                + routed[..., j * cb:(j + 1) * cb]
+    g_lrn = _canvas_load(gp_ref, (slice(None), slice(0, h), slice(0, w),
+                                  slice(None)))
     tsum = _window_sum_last(g_lrn * x * d / s, half)
     dx_ref[...] = (g_lrn * d
                    - (2.0 * alpha * beta) * x * tsum).astype(dx_ref.dtype)
@@ -398,13 +426,15 @@ def _lrn_pool_bwd_kernel(x_ref, g_ref, dx_ref, *, half: int, k: float,
 
 def _lrn_pool_call(kernel, args, out_hwc, k, alpha, beta, n: int,
                    ksize, stride, row_tile: Optional[int],
-                   io_dtype: str):
+                   io_dtype: str, n_canvas: int):
     """Common wrapper: grid over SAMPLE tiles (each program owns
     `row_tile` whole (H, W, C) bands, so both the channel window and the
     pooling windows stay in-block). `row_tile`/`io_dtype` are the
-    searched axes (ops/templates.py), exactly the LRN pair's."""
+    searched axes (ops/templates.py), exactly the LRN pair's.
+    `n_canvas` f32 VMEM scratch canvases hold the padded LRN output (and,
+    backward, the routed error) for the strided window taps."""
     x = args[0]
-    nb = x.shape[0]
+    nb, h, w, c = x.shape
     blk_dt = jnp.float32 if io_dtype == "f32" else x.dtype
     rt = max(1, int(row_tile if row_tile is not None
                     else _LRN_POOL_ROW_TILE))
@@ -419,16 +449,21 @@ def _lrn_pool_call(kernel, args, out_hwc, k, alpha, beta, n: int,
     in_specs = [pl.BlockSpec((rt,) + a.shape[1:],
                              lambda i: (i, 0, 0, 0),
                              memory_space=pltpu.VMEM) for a in xs]
+    ky, kx, sy, sx = (int(ksize[0]), int(ksize[1]), int(stride[0]),
+                      int(stride[1]))
+    hp, wp = _pool_canvas_hw(h, w, ky, kx, sy, sx)
+    n_cb, cb = _lane_blocks(c)
     out = pl.pallas_call(
         functools.partial(kernel, half=n // 2, k=float(k),
                           alpha=float(alpha), beta=float(beta),
-                          ky=int(ksize[0]), kx=int(ksize[1]),
-                          sy=int(stride[0]), sx=int(stride[1])),
+                          ky=ky, kx=kx, sy=sy, sx=sx),
         out_shape=jax.ShapeDtypeStruct((nb + pad,) + out_hwc, blk_dt),
         grid=((nb + pad) // rt,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((rt,) + out_hwc, lambda i: (i, 0, 0, 0),
                                memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((n_cb, rt, hp, wp, cb), jnp.float32)]
+        * n_canvas,
         interpret=_interpret(),
     )(*xs)
     return out[:nb].astype(x.dtype)
@@ -452,7 +487,7 @@ def lrn_maxpool_pallas(x, k: float = 2.0, alpha: float = 1e-4,
                           stride[0], stride[1])
     return _lrn_pool_call(_lrn_pool_fwd_kernel, (x,),
                           (oh, ow, x.shape[3]), k, alpha, beta, n,
-                          ksize, stride, row_tile, io_dtype)
+                          ksize, stride, row_tile, io_dtype, 1)
 
 
 def _lrn_pool_fwd_rule(x, k, alpha, beta, n, ksize, stride, row_tile,
@@ -465,7 +500,7 @@ def _lrn_pool_bwd_rule(k, alpha, beta, n, ksize, stride, row_tile,
                        io_dtype, x, g):
     return (_lrn_pool_call(_lrn_pool_bwd_kernel, (x, g),
                            tuple(x.shape[1:]), k, alpha, beta, n,
-                           ksize, stride, row_tile, io_dtype),)
+                           ksize, stride, row_tile, io_dtype, 2),)
 
 
 lrn_maxpool_pallas.defvjp(_lrn_pool_fwd_rule, _lrn_pool_bwd_rule)

@@ -184,22 +184,24 @@ def selection_table(include_defaults: bool = False) -> Dict[str, str]:
 
 def pallas_ok() -> bool:
     """Can a pallas variant actually run here? True on a TPU backend, or
-    anywhere while `pallas_interpret()` is active."""
+    anywhere while `pallas_interpret()` is active. A backend that fails
+    to initialise raises (pallas_kernels.available) — never "no"."""
     if _PALLAS_INTERPRET:
         return True
-    try:
-        from veles_tpu.ops import pallas_kernels as pk
-        return pk.available()
-    except Exception:  # noqa: BLE001 — no jax / broken backend: no pallas
-        return False
+    from veles_tpu.ops import pallas_kernels as pk
+    return pk.available()
+
+
+def pallas_interpret_active() -> bool:
+    return _PALLAS_INTERPRET
 
 
 @contextlib.contextmanager
 def pallas_interpret():
-    """Resolve (and run) pallas variants in interpret mode — the CPU
-    autotune/tier-1-test path. pallas_kernels._interpret() already
-    interprets whenever no TPU is attached; this flag only lifts the
-    resolve()-time gating."""
+    """Resolve AND run pallas variants in interpret mode — the CPU
+    autotune/tier-1-test path, and the only way besides
+    pallas_kernels._FORCE_INTERPRET that a kernel is ever interpreted
+    (pallas_kernels._interpret reads this flag)."""
     global _PALLAS_INTERPRET
     prev = _PALLAS_INTERPRET
     _PALLAS_INTERPRET = True
@@ -215,10 +217,14 @@ def resolve(op: str, unit: Any = None) -> Variant:
        knobs like MaxPooling(lowering=...));
     2. the global selection (autotuner cache / tools / legacy shims);
     3. the op's registered default.
-    Pallas variants additionally need `pallas_ok()` AND the unit's
-    `allow_pallas` (FusedTrainStep clears it under GSPMD
-    auto-partitioning — a pallas_call cannot be auto-partitioned);
-    otherwise the op's non-pallas fallback is traced instead.
+    A Pallas variant is swapped for the op's non-pallas fallback in
+    exactly two documented cases, each logged once at WARNING when the
+    variant was explicitly selected: the unit's `allow_pallas` is
+    cleared (FusedTrainStep under GSPMD auto-partitioning — a
+    pallas_call cannot be auto-partitioned), or the initialised backend
+    is not a TPU and interpret mode was not asked for. On a TPU the
+    selected variant is what traces: a kernel the compiler refuses is
+    the compiler's error, never a quiet fallback.
     """
     spec = _spec(op)
     name = getattr(unit, "variant_override", None) if unit is not None \
@@ -226,10 +232,26 @@ def resolve(op: str, unit: Any = None) -> Variant:
     if name is None:
         name = _selection.get(op, spec.default)
     v = get(op, name)
-    if v.pallas and not (pallas_ok()
-                         and getattr(unit, "allow_pallas", True)):
-        v = get(op, spec.fallback)
-    return v
+    if not v.pallas:
+        return v
+    if not getattr(unit, "allow_pallas", True):
+        reason = "the unit cleared allow_pallas (GSPMD auto-partitioning)"
+    elif not pallas_ok():
+        reason = ("the backend is not a TPU and interpret mode was not "
+                  "requested")
+    else:
+        return v
+    if name != spec.default and (op, name, reason) not in _FALLBACK_WARNED:
+        _FALLBACK_WARNED.add((op, name, reason))
+        import logging
+        logging.getLogger("veles.variants").warning(
+            "%s: selected pallas variant %r not traced — %s; tracing "
+            "fallback %r", op, name, reason, spec.fallback)
+    return get(op, spec.fallback)
+
+
+#: (op, variant, reason) fallbacks already warned about (log once)
+_FALLBACK_WARNED: set = set()
 
 
 def warn_deprecated_knob(old: str, new: str) -> None:
@@ -593,8 +615,7 @@ def grad_reduce_apply(cfg: Dict[str, Any]) -> Callable[..., Any]:
         import jax.numpy as jnp
         from jax import lax
 
-        from veles_tpu._compat import axis_size
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         h, loc = grad_reduce_geometry(n)
         two_level = hier and h > 1 and loc > 1
         local = flat.shape[0] // n

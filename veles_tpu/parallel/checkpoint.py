@@ -206,6 +206,14 @@ def _leaf_index(tree) -> Dict[str, Any]:
             for path, leaf in jtu.tree_flatten_with_path(tree)[0]}
 
 
+def _saved_index(ckptr, path: str) -> Dict[str, Any]:
+    """{keypath: ArrayMetadata} of the tree saved at `path`. The
+    installed Orbax (0.11) answers `metadata()` with a StepMetadata
+    whose `item_metadata.tree` is the saved pytree (tuples come back as
+    lists, which `_keystr` spells the same way)."""
+    return _leaf_index(ckptr.metadata(path).item_metadata.tree)
+
+
 def _geometry_error(ckptr, path: str, target, cause):
     """Diff the SAVED tree metadata against the restore target; returns
     a CheckpointGeometryError naming every leaf that exists on only one
@@ -213,7 +221,7 @@ def _geometry_error(ckptr, path: str, target, cause):
     agree (the failure, if any, is something else) or the metadata is
     unreadable (not a checkpoint at all: not a geometry problem)."""
     try:
-        saved = _leaf_index(ckptr.metadata(path))
+        saved = _saved_index(ckptr, path)
     except Exception:  # noqa: BLE001 — no metadata: not a geometry issue
         return cause
     want = _leaf_index(target)
@@ -280,7 +288,7 @@ def _vel_reshard_restore(ckptr, path: str, step, template, key_impl: str):
     original CheckpointGeometryError)."""
     import numpy as np
     try:
-        saved = _leaf_index(ckptr.metadata(path))
+        saved = _saved_index(ckptr, path)
     except Exception:  # noqa: BLE001 — unreadable: not this class
         return None
     want = _leaf_index(template)

@@ -59,7 +59,12 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu._compat import shard_map
+from jax import shard_map
+# private: the Varying -> Invariant all-gather is not exported from
+# jax.lax on the installed jax (0.9.0). The ZeRO update needs exactly
+# that type — fresh params every replica provably agrees on — so the
+# dp step passes shard_map's varying-axes check instead of disabling it.
+from jax._src.lax.parallel import all_gather_invariant
 
 from veles_tpu import prng
 from veles_tpu.ops import optim
@@ -315,12 +320,9 @@ class FusedTrainStep:
 
     def ef_active(self) -> bool:
         """True when the update carries the error-feedback residual
-        slot: ZeRO active, the registry scatter actually traces (not
-        the vma-era slice-after-psum degeneration), and the selected
-        grad_reduce variant is stateful (int8 + EF)."""
-        from veles_tpu import _compat
-        return (self.zero_active and not _compat.GRAD_TRANSPOSE_PSUM
-                and self._grad_reduce_variant().stateful)
+        slot: ZeRO active and the selected grad_reduce variant is
+        stateful (int8 + EF)."""
+        return self.zero_active and self._grad_reduce_variant().stateful
 
     def ef_lens(self):
         """Per-layer {param: per-shard residual length} — the optional
@@ -343,11 +345,9 @@ class FusedTrainStep:
         behind the veles_collective_bytes_total counter family (the
         driver increments once per dispatched step;
         docs/OBSERVABILITY.md). None when no registry collective traces
-        (zero inactive, or the vma-era slice-after-psum path) — a
-        counter fed here can never fabricate provenance, same rule as
-        variant_table."""
-        from veles_tpu import _compat
-        if not self.zero_active or _compat.GRAD_TRANSPOSE_PSUM:
+        (zero inactive) — a counter fed here can never fabricate
+        provenance, same rule as variant_table."""
+        if not self.zero_active:
             return None
         from veles_tpu.ops import variants
         v = self._grad_reduce_variant()
@@ -854,46 +854,23 @@ class FusedTrainStep:
             return loss, (loss, n_err)
 
         (_, (loss, n_err)), grads = jax.value_and_grad(
-            lf, has_aux=True)(state["params"])
-        grads = self._reduce_grads(grads, axes)
+            lf, has_aux=True)(self._grad_params(state["params"]))
         if axes:
             # partials with a global denominator: SUM to the global metric
             loss = lax.psum(loss, axes)
             n_err = lax.psum(n_err, axes)
         return self._apply_update(state, grads), loss, n_err
 
-    def _reduce_grads(self, grads, axes):
-        """Pre-vma jax only (see _compat.GRAD_TRANSPOSE_PSUM): perform
-        the gradient all-reduce that vma-era autodiff would have placed
-        as the transpose of the replicated params' broadcast. Per leaf,
-        psum over the mapped axes the param's spec does NOT shard on —
-        replicated params reduce over all of `axes`, EP expert tensors
-        (sharded over the data axis) and seq-TP megatron shards keep
-        their axis local (their grads arrive via all_to_all/ppermute
-        transposes, which the old shard_map does differentiate
-        correctly). No-op on vma-era jax: the psum would double-count.
-        No-op under ZeRO too: the update's reduce-scatter IS the
-        reduction there — a psum here would leave nothing to scatter
-        (and double the collective bytes)."""
-        from veles_tpu import _compat
-        if not axes or _compat.GRAD_TRANSPOSE_PSUM or self.zero_active:
-            return grads
-        specs = (self._seq_param_specs() if self.mode == "seq"
-                 else self._smap_param_specs())
-        out = []
-        for g_layer, sp_layer in zip(grads, specs):
-            red = {}
-            for k, g in g_layer.items():
-                sharded = set()
-                for part in sp_layer.get(k, P()):
-                    if isinstance(part, str):
-                        sharded.add(part)
-                    elif part is not None:
-                        sharded.update(part)
-                missing = tuple(a for a in axes if a not in sharded)
-                red[k] = lax.psum(g, missing) if missing else g
-            out.append(red)
-        return tuple(out)
+    def _grad_params(self, params):
+        """The params autodiff differentiates against. Under ZeRO they
+        are cast to VARYING over the data axis first: the gradient of a
+        varying value is this shard's partial (no transpose psum), which
+        is what the `grad_reduce` registry op reduce-scatters in
+        _apply_update_zero — one reduction, through the registry."""
+        if not self.zero_active:
+            return params
+        return jax.tree.map(
+            lambda a: lax.pcast(a, DATA_AXIS, to="varying"), params)
 
     def _sgd_variant(self):
         """The sgd_update registry variant this step traces — ONE
@@ -950,15 +927,9 @@ class FusedTrainStep:
         its slice-only momentum/Adam state, and all-gather the fresh
         param slices for the next forward. Same wire bytes as the psum
         it replaces; optimizer state never materializes beyond 1/N per
-        device. On vma-era jax autodiff has already all-reduced the
-        grads of replicated params, so the scatter degenerates to a
-        local slice of the reduced grad: the memory win is kept, but the
-        step pays all-reduce + all-gather — more bytes than either the
-        replicated update or the true scatter path, and no grad_reduce
-        registry op runs (variant_table omits it there). Replacing
-        autodiff's psum with a real psum_scatter is the jax-upgrade
-        follow-on (ROADMAP)."""
-        from veles_tpu import _compat
+        device. The gather is the Varying -> Invariant form, so the
+        returned params are provably identical on every replica
+        (shard_map's varying-axes check stays on)."""
         gr = self._grad_reduce_variant()
         reduce = gr.apply
         # error-feedback residual slot (stateful variants): present in
@@ -989,10 +960,7 @@ class FusedTrainStep:
             for k in p:
                 lp = plan[k]
                 flat_g = zero_flatten(g[k], lp)
-                if _compat.GRAD_TRANSPOSE_PSUM:
-                    g_loc = lax.dynamic_slice(
-                        flat_g, (idx * lp.local,), (lp.local,))
-                elif ef_layer is not None:
+                if ef_layer is not None:
                     g_loc, nef[k] = reduce(flat_g, DATA_AXIS,
                                            ef_layer[k])
                 else:
@@ -1012,8 +980,8 @@ class FusedTrainStep:
                     p_new, v_new = optim.sgd_leaf(p_loc, g_loc, v[k],
                                                   cfg, lr)
                     nv[k] = v_new
-                full = lax.all_gather(p_new, DATA_AXIS, axis=0,
-                                      tiled=True)
+                full = all_gather_invariant(p_new, DATA_AXIS, axis=0,
+                                            tiled=True)
                 np_[k] = zero_unflatten(full, lp)
             new_params.append(np_)
             new_vel.append(nv)
@@ -1050,20 +1018,19 @@ class FusedTrainStep:
                 return loss, (loss, n_err)
 
             (_, (loss, n_err)), grads = jax.value_and_grad(
-                lf, has_aux=True)(state["params"])
+                lf, has_aux=True)(gparams)
             acc = jax.tree.map(lambda a, g: a + g, acc, grads)
             return (acc, loss_a + loss,
                     err_a + n_err.astype(jnp.float32), i + 1), None
 
-        zero = jax.tree.map(jnp.zeros_like, state["params"])
+        gparams = self._grad_params(state["params"])
+        zero = jax.tree.map(jnp.zeros_like, gparams)
         # the metric carries must be device-varying from step 0 under
         # shard_map (they mix with varying per-shard partials); deriving
         # them from ws inherits its varying axes (cf. ring_attention)
         zero_s = ws.reshape(-1)[0].astype(jnp.float32) * 0.0
         (grads, loss, n_err, _), _ = lax.scan(
             micro, (zero, zero_s, zero_s, jnp.int32(0)), (xs, ys, ws))
-        # one reduce over the accumulated sum == per-micro reduces summed
-        grads = self._reduce_grads(grads, axes)
         if axes:
             loss = lax.psum(loss, axes)
             n_err = lax.psum(n_err, axes)
@@ -1178,9 +1145,6 @@ class FusedTrainStep:
                 in_specs=(ssp, P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
                 out_specs=(ssp, P(), P()))
         if self.mode == "seq":
-            if self.mesh.shape.get(MODEL_AXIS, 1) > 1:
-                from veles_tpu._compat import warn_pre_vma_numerics
-                warn_pre_vma_numerics("seq x TP (3-axis) fused step")
             axes = (DATA_AXIS, SEQ_AXIS)
             xspec = P(DATA_AXIS, SEQ_AXIS)  # (N, S, ...) batch x sequence
             ssp = self._seq_state_spec()    # TP-sharded when model axis
@@ -1416,7 +1380,6 @@ class FusedTrainStep:
         op-level entry must never name a lowering no unit traced, and a
         still-composed sibling's (possibly overridden) name must not be
         clobbered by the pair's claim."""
-        from veles_tpu import _compat
         from veles_tpu.ops import variants
         table: Dict[str, str] = {}
         pairs = self.fusion_pairs()           # mirror _forward's claims
@@ -1447,13 +1410,10 @@ class FusedTrainStep:
                 table.setdefault("conv_stem", v.name)
                 table.setdefault(getattr(b, "variant_op", "lrn"),
                                  f"conv_stem/{v.name}")
-        if self.zero_active and not _compat.GRAD_TRANSPOSE_PSUM:
+        if self.zero_active:
             # the ZeRO reduce-scatter resolves through the registry like
             # any tunable lowering: a measured number must name which
-            # grad_reduce variant moved the gradient bytes. On vma-era
-            # jax the traced path slices autodiff's own all-reduce
-            # instead (see _apply_update_zero) — no registry op runs,
-            # so reporting one would fabricate provenance. Read through
+            # grad_reduce variant moved the gradient bytes. Read through
             # the step's cached resolution so reported == traced even
             # across a registry re-selection.
             table["grad_reduce"] = self._grad_reduce_variant().name
@@ -1584,8 +1544,7 @@ class FusedTrainStep:
         (K, batch). A lax.scan over minibatches inside jit — K real
         sequential updates, one host->device round trip. This is the
         dispatch-amortized hot loop (the reference's analog was K×dozens
-        of kernel enqueues; through a remote PJRT tunnel per-step dispatch
-        latency is real money). Works in every mode: local plain scan,
+        of kernel enqueues). Works in every mode: local plain scan,
         "dp" as scan INSIDE the shard_map (collectives fire per scan
         iteration), "gspmd" as a scan whose per-step batch carries the
         data-axis sharding. Returns (state, (losses, n_errs)) with

@@ -66,6 +66,26 @@ def _backend_ready() -> bool:
     return bool(xb._backends)
 
 
+def refuse_spawn_if_chip_held(what: str) -> None:
+    """A chip belongs to ONE process at a time: once this process has
+    created an accelerator backend it holds the chip, and a child
+    process that needs it fails or hangs. Parents that fan work out to
+    child processes (Ensemble.train(parallel=True), genetics workers)
+    call this before they spawn; a CPU backend, or no backend yet, is
+    fine."""
+    if not _backend_ready():
+        return
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"{what}: this process has initialised the {platform!r} "
+            "backend and holds the chip — a child process that needs it "
+            "would fail or hang. Spawn before touching jax, run the "
+            "work serially in this process, or lease it to other hosts "
+            "over the cluster queue (-l/-m)")
+
+
 def device_memory_limits() -> Optional[Dict[str, int]]:
     """{device_id: bytes_limit} where the backend's allocator reports
     one (TPU) — the denominator of every static-HBM-model comparison
@@ -96,7 +116,7 @@ def device_memory_stats() -> Optional[Dict[str, Any]]:
     even imported — or imported but no backend has been CREATED yet —
     in this process (never initializes a backend: live_arrays /
     local_devices would otherwise trigger initialization inside a
-    heartbeat hook, stalling on a locked or tunnel-backed device)."""
+    heartbeat hook, stalling on a device another process holds)."""
     if "jax" not in sys.modules:
         return None
     xb = sys.modules.get("jax._src.xla_bridge")

@@ -47,8 +47,9 @@ from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from veles_tpu._compat import pcast, shard_map
-from veles_tpu._compat import axis_size as _axis_size
+from jax import shard_map
+from jax.lax import axis_size as _axis_size
+from jax.lax import pcast
 
 STAGE_AXIS = "stage"
 
@@ -485,8 +486,6 @@ class PipelineTrainStep:
             out_specs=(ssp, P(), P()))
 
     def _build(self) -> None:
-        from veles_tpu._compat import warn_pre_vma_numerics
-        warn_pre_vma_numerics("GPipe pipeline step")
 
         def eval_body(params, xs, y, w):
             return self._loss(params[0], xs, y, w)

@@ -62,7 +62,7 @@ def predict_dp_scaling(*, grad_bytes: float, step_time_s: float,
 
     `step_time_s` is the measured single-chip step wall time at
     `batch_per_chip`; compute time is assumed to scale linearly with the
-    per-chip batch (true within the measured 512..2048 sweep, MEASURED.json).
+    per-chip batch (true within the builders' 2026-07-30 512..2048 sweep).
     Returns the prediction with every input echoed so a future pod run can
     falsify it term by term.
     """

@@ -493,10 +493,8 @@ class Supervisor(Logger):
                 pass
             try:
                 # structured analyzer findings for the supervised child
-                # config (pre-vma numerics for GPipe/seq×TP argvs, the
-                # non-finite guard left off) — the machine-readable twin
-                # of warn_pre_vma_numerics' log line, landing next to
-                # the variant table. Guarded import like `variants`
+                # config (the non-finite guard left off), landing next
+                # to the variant table. Guarded import like `variants`
                 # above: analysis.trace pulls jax, and the supervisor
                 # must never die on report cosmetics at exit time.
                 from veles_tpu.analysis.trace import environment_findings

@@ -32,6 +32,9 @@ root.alexnet.loader.n_validation = 128
 root.alexnet.loader.n_train = 512
 root.alexnet.loader.input_hw = 227
 root.alexnet.loader.data_path = ""
+#: set a directory to get improvement-gated snapshots (Snapshotter)
+root.alexnet.snapshotter.directory = ""
+root.alexnet.snapshotter.prefix = "alexnet"
 root.alexnet.n_classes = 1000
 root.alexnet.decision.max_epochs = 10
 root.alexnet.decision.fail_iterations = 10
@@ -136,11 +139,13 @@ def create_workflow(minibatch_size: Optional[int] = None,
                           else cfg.loader.n_validation),
             n_train=n_train if n_train is not None else cfg.loader.n_train,
             minibatch_size=mb, noise=0.5)
+    snap = cfg.snapshotter.to_dict()
     return AlexNetWorkflow(
         layers=alexnet_layers(nc, width_mult, fc_width, init=init),
         loader=loader, loss="softmax", n_classes=nc,
         decision_config=cfg.decision.to_dict(),
         gd_config=cfg.gd.to_dict(),
+        snapshot_config=snap if snap.get("directory") else None,
         name="AlexNetWorkflow")
 
 

@@ -47,11 +47,11 @@ AOT_CACHE_ENV = "VELES_SERVING_AOT_CACHE"
 
 
 def default_aot_path() -> str:
-    """Index path — alongside the autotune cache by design (one
-    operator-local cache directory to warm, ship or wipe)."""
-    return (os.environ.get(AOT_CACHE_ENV)
-            or os.path.join(os.path.expanduser("~"), ".cache",
-                            "veles_tpu", "serving_aot.json"))
+    """Index path — alongside the compile and autotune caches by design
+    (veles_tpu/caches.py: one fixed in-checkout directory to warm, ship
+    or wipe)."""
+    from veles_tpu.caches import cache_path
+    return os.environ.get(AOT_CACHE_ENV) or cache_path("serving_aot.json")
 
 
 def model_signature(workflow) -> list:

@@ -30,8 +30,7 @@ Design constraints (the hot-path contract):
 Profile windows (`ProfileController`): ``--profile-window N:M``
 brackets driver steps N..M (inclusive) with ``jax.profiler``
 start/stop — the on-chip capture path — and ``POST /profile`` on the
-web-status control plane arms a window on a LIVE run (the
-tunnel-watcher's remote-capture hook). The driver calls
+web-status control plane arms a window on a LIVE run. The driver calls
 ``controller.on_step(k)`` once per step; the disarmed path is a single
 attribute check.
 """

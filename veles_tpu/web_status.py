@@ -402,8 +402,7 @@ class WebStatusServer:
             def _do_profile(self) -> None:
                 """POST /profile {"steps": K[, "dir": PATH]} — arm a
                 jax.profiler window of K steps at the live run's next
-                step boundary (the tunnel-watcher's on-chip capture
-                path). Auth + bounded body like the heartbeat endpoint
+                step boundary. Auth + bounded body like the heartbeat endpoint
                 (task_queue hardening precedent): arming the profiler
                 on an open port is a writable control surface."""
                 from veles_tpu.http_util import check_shared_token

@@ -263,6 +263,7 @@ class StandardWorkflow(Workflow):
         # digest-keyed idempotent push relies on (resilience/mirror.py)
         d.pop("device_feed", None)
         d.pop("feed_stats", None)
+        d.pop("fused_step", None)      # jitted callables + mesh handles
         # ditto the pre-flight prediction (analysis pass 6): it embeds
         # the HOST's device limit, which must not leak into a snapshot
         # another host restores
@@ -383,6 +384,9 @@ class StandardWorkflow(Workflow):
             mesh=mesh, mode=mode, compute_dtype=compute_dtype, ep=ep,
             input_normalize=wire["normalize"] if wire else None,
             zero_sharding=zero_sharding)
+        #: observability handle (like device_feed/fused_state): the step
+        #: that trained, for variant_table()/collective_accounting()
+        self.fused_step = step
         self._run_with_step(step, accum_steps=accum_steps,
                             nonfinite_guard=nonfinite_guard,
                             wire=wire, feed_ahead=feed_ahead)
