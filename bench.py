@@ -116,29 +116,27 @@ def _memory_record(step, x, y, w=None):
 def _telemetry_overhead(step_time_s: float) -> dict:
     """Measured tracing-on vs tracing-off A/B: the record proves what
     --trace costs relative to THIS run's measured step time. `on` times
-    real begin/end span pairs into a live ring buffer; `off` times the
-    disabled-path guard the driver actually runs when no tracer is
-    installed (pre-bound handle, None check). The driver loop emits at
-    most 8 span pairs per training step (feed.next, dispatch, the
-    in-flight window, decision, prefetch + the produce trio), so
-    overhead_frac = 8 x (on - off) / step_time — the <1% tracing
-    budget, asserted by a slow-marker test. Guarded like the other
-    accounting: telemetry must never cost the measured value."""
+    real `with ring.span(...)` blocks into a live ring buffer; `off`
+    times what the program runs when nothing records: `tracer.span()`
+    returning its shared no-op (no ring installed, no profiler session
+    open). The driver loop emits at most 8 spans per training step
+    (feed.next, dispatch, the in-flight window, decision, prefetch + the
+    produce trio), so overhead_frac = 8 x (on - off) / step_time — the
+    <1% tracing budget, asserted by a slow-marker test. Guarded like the
+    other accounting: telemetry must never cost the measured value."""
     try:
-        from veles_tpu.telemetry.tracer import Tracer
+        from veles_tpu.telemetry import tracer
         n = 2000
-        tr = Tracer(capacity=4096)
+        ring = tracer.Tracer(capacity=4096)
         t0 = time.perf_counter()
         for _ in range(n):
-            tok = tr.begin("bench.overhead", "bench")
-            tr.end(tok)
+            with ring.span("bench.overhead", "bench"):
+                pass
         on_s = (time.perf_counter() - t0) / n
-        off_tr = None
         t0 = time.perf_counter()
         for _ in range(n):
-            if off_tr is not None:
-                tok = off_tr.begin("bench.overhead", "bench")
-                off_tr.end(tok)
+            with tracer.span("bench.overhead", "bench"):
+                pass
         off_s = (time.perf_counter() - t0) / n
         spans_per_step = 8
         per_step_s = spans_per_step * max(0.0, on_s - off_s)
@@ -154,7 +152,7 @@ def _telemetry_overhead(step_time_s: float) -> dict:
 
 
 def _mirror_bench_metrics(n_steps: int, step_time_s: float,
-                          n_examples: float, feed=None) -> None:
+                          n_examples: float) -> None:
     """Route the bench child's measured numbers through the ONE
     telemetry registry and mirror the flush to the JSONL sink next to
     the record file — the same producer every /metrics endpoint
@@ -171,7 +169,6 @@ def _mirror_bench_metrics(n_steps: int, step_time_s: float,
         if step_time_s > 0:
             reg.gauge("veles_examples_per_second").set(
                 n_examples / (n_steps * step_time_s))
-        tmetrics.mirror_feed(feed)
         tmetrics.install_jsonl(RECORD_PATH + ".telemetry.jsonl")
         tmetrics.flush_installed(extra={"source": "bench"})
     except Exception:  # noqa: BLE001
@@ -553,8 +550,7 @@ def e2e_child_main() -> None:
     feed_stats = feed.stats()
     feed.stop()   # also stops the loader's produce threads
     _mirror_bench_metrics(WINDOWS * STEPS_PER_WINDOW, batch / value,
-                          float(batch) * WINDOWS * STEPS_PER_WINDOW,
-                          feed=feed_stats)
+                          float(batch) * WINDOWS * STEPS_PER_WINDOW)
     rec = {
         "metric": "alexnet_e2e_samples_per_sec_per_chip",
         "value": round(value, 2),

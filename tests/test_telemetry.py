@@ -212,8 +212,8 @@ def test_tracer_add_span_uses_perf_counter_clock():
     time.sleep(0.01)
     t1 = time.perf_counter()
     tr.add_span("timed", "cat", t0, t1)
-    (name, _cat, _ts, dur, _tid, ph) = tr.events()[0]
-    assert name == "timed" and ph == "X"
+    (name, _cat, _ts, dur, _tid, ph, seq) = tr.events()[0]
+    assert name == "timed" and ph == "X" and seq is None
     assert dur == pytest.approx((t1 - t0) * 1e6, rel=0.01)
 
 
@@ -436,17 +436,26 @@ def test_label_cardinality_is_bounded():
     assert len(g._children) <= metrics._MAX_CHILDREN + 1
 
 
-def test_mirror_feed_and_mem():
+def test_feed_handles_and_mirror_mem():
+    """The feed's counters have one producer, the feed, through handles
+    bound once (`feed_handles`); memstats are still mirrored in."""
     reg = metrics.MetricsRegistry()
     metrics.register_standard(reg)
-    metrics.mirror_feed({"bytes_h2d": 1024, "loader_block_s": 1.5,
-                         "device_sync_s": 0.25, "on_demand": 2},
-                        reg)
+    h = metrics.feed_handles(reg)
+    h.bytes_h2d.inc(1024)
+    h.device_sync_s.inc(0.25)
+    h.put_s.inc(0.5)
+    h.h2d_late.inc()
+    metrics.loader_handles(reg).waited.inc(2)
     metrics.mirror_mem({"live_bytes": {"0": 100, "1": 200},
                         "live_bytes_max": 200}, reg)
     flat = reg.snapshot_flat()
     assert flat["veles_feed_h2d_bytes_total"] == 1024
     assert flat["veles_feed_device_sync_seconds_total"] == 0.25
+    assert flat["veles_feed_put_seconds_total"] == 0.5
+    assert flat["veles_feed_h2d_late_total"] == 1
+    assert flat["veles_feed_h2d_ready_total"] == 0
+    assert flat["veles_loader_lookahead_waited_total"] == 2
     assert flat["veles_mem_live_bytes_max"] == 200
     fams = parse_prometheus(reg.exposition())
     devs = {lb["device"]: v

@@ -25,6 +25,7 @@ oversampled with replacement), epoch length unchanged.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, List, Optional
 
 import numpy as np
@@ -34,6 +35,8 @@ from veles_tpu.accelerated_units import AcceleratedUnit
 from veles_tpu.distributable import IDistributable
 from veles_tpu.memory import Array
 from veles_tpu.mutable import Bool
+from veles_tpu.telemetry import metrics as _metrics
+from veles_tpu.telemetry import tracer as _tracer
 
 TEST, VALIDATION, TRAIN = 0, 1, 2
 
@@ -42,6 +45,13 @@ class Loader(AcceleratedUnit, IDistributable):
     """Subclasses implement `load_data()` (fill `class_lengths`) and
     `fill_minibatch(indices)` (fill minibatch_data/labels for the given
     global sample indices)."""
+
+    #: sequence number of the batch the last run() delivered, counted
+    #: over the loader's life (-1 before the first); spans carry it
+    #: (class defaults: a loader restored from an older snapshot)
+    batch_seq = -1
+    #: batches delivered by the epochs already finished
+    _seq_base = 0
 
     def __init__(self, workflow=None, minibatch_size: int = 100,
                  shuffle_train: bool = True, on_device: bool = True,
@@ -181,9 +191,15 @@ class Loader(AcceleratedUnit, IDistributable):
                 self._schedule.append((cls, b, b == n_batches - 1))
         self._cursor = 0
 
+    @property
+    def next_batch_seq(self) -> int:
+        """The sequence number the next run()'s batch will carry."""
+        return self._seq_base + self._cursor
+
     def run(self) -> None:
         # (overrides AcceleratedUnit.run: one code path, host index math)
         cls, b, last = self._schedule[self._cursor]
+        self.batch_seq = self.next_batch_seq
         idx = self._indices_per_class[cls]
         lo = b * self.minibatch_size
         take = np.arange(lo, lo + self.minibatch_size) % len(idx)
@@ -212,6 +228,7 @@ class Loader(AcceleratedUnit, IDistributable):
             # the static pass cannot see (docs/ANALYSIS.md blind spots).
             # velint: disable=shared-write-no-lock
             self.epoch_number += 1
+            self._seq_base += len(self._schedule)
             self._start_epoch()
 
     # -- data-parallel partitioning (IDistributable-shaped; SPMD sharding) ---
@@ -271,6 +288,7 @@ class PrefetchingLoader(Loader):
         self.local_rows_fn = None
         #: decoded-row counter (tests/observability)
         self.rows_decoded = 0
+        self._reset_produce_stats()
         #: guards rows_decoded increments from pool workers; created
         #: HERE (and re-created on unpickle), never lazily on the
         #: produce threads — two workers racing the lazy `if None:
@@ -355,6 +373,34 @@ class PrefetchingLoader(Loader):
         self._count_rows(len(indices))
         return x, y
 
+    def _reset_produce_stats(self) -> None:
+        """Process-local observability (feed.stats(), veles_loader_*),
+        never pickled: seconds inside _produce summed over the produce
+        threads and batches it completed (under _count_lock); fills
+        whose lookahead future was done when asked / that had to wait
+        (driver thread); the registry handles, bound when the produce
+        pool starts."""
+        self.produce_s = 0.0
+        self.batches_produced = 0
+        self.lookahead_ready = 0
+        self.lookahead_waited = 0
+        self._m = None
+
+    def _produce_one(self, indices: np.ndarray, seq: int):
+        """`_produce` as the pool (or a fill with no lookahead) runs it:
+        one `loader.produce` span and one count per batch, recorded on
+        the thread that does the work."""
+        t0 = time.perf_counter()
+        with _tracer.span("loader.produce", "loader", seq):
+            out = self._produce(indices)
+        dt = time.perf_counter() - t0
+        with self._count_lock:
+            self.produce_s += dt
+            self.batches_produced += 1
+        self._m.produce_s.inc(dt)
+        self._m.produced.inc()
+        return out
+
     def _count_rows(self, n: int) -> None:
         # _produce runs on pool worker threads: a bare += would lose
         # increments under interleaving
@@ -376,6 +422,9 @@ class PrefetchingLoader(Loader):
             self._pool = ThreadPoolExecutor(
                 max_workers=self.n_workers,
                 thread_name_prefix=f"{self.name}-produce")
+            self._m = _metrics.loader_handles()
+        m = self._m
+        seq = self.next_batch_seq
         pend = self._pending.pop(self._cursor, None)
         # the lookahead future is only valid for the cursor-schedule
         # indices; a caller feeding different indices (e.g. a master's
@@ -385,14 +434,20 @@ class PrefetchingLoader(Loader):
                and np.array_equal(pend[0], indices) else None)
         if pend is not None and fut is None:
             pend[1].cancel()
+        if fut is not None and fut.done() and not fut.cancelled():
+            self.lookahead_ready += 1
+            m.ready.inc()
+        else:
+            self.lookahead_waited += 1
+            m.waited.inc()
         try:
             x, y = (fut.result() if fut is not None
-                    else self._produce(indices))
+                    else self._produce_one(indices, seq))
         except CancelledError:
             # stop() from another thread (manhole, Ctrl-C handler)
             # cancelled the lookahead mid-fill: produce synchronously so
             # the pump loop winds down cleanly instead of crashing
-            x, y = self._produce(indices)
+            x, y = self._produce_one(indices, seq)
         for ahead in range(1, self.prefetch + 1):
             pos = self._cursor + ahead
             if pos in self._pending:
@@ -402,7 +457,7 @@ class PrefetchingLoader(Loader):
                 break
             try:
                 self._pending[pos] = (nxt, self._pool.submit(
-                    self._produce, nxt))
+                    self._produce_one, nxt, seq + ahead))
             except RuntimeError:     # pool shut down by concurrent stop()
                 break
         self.minibatch_data.reset(x)
@@ -446,11 +501,15 @@ class PrefetchingLoader(Loader):
         # pickled as None (locks don't pickle); re-created on the
         # unpickling thread, before any produce pool exists
         self._count_lock = threading.Lock()
+        self._reset_produce_stats()
 
     def __getstate__(self):
         d = super().__getstate__()
         d["_pool"] = None
         d["_pending"] = {}
         d["_count_lock"] = None
+        for k in ("_m", "produce_s", "batches_produced",
+                  "lookahead_ready", "lookahead_waited"):
+            d.pop(k, None)      # timing floats must not reach a pickle
         d["local_rows_fn"] = None   # step-bound closure: re-wired by run
         return d

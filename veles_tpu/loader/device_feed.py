@@ -54,10 +54,14 @@ fuses into the first layer's HBM read. The feed's byte counters make
 this mechanically checkable: `stats()["bytes_per_batch"]` drops 4x.
 
 Overlap observability: the feed counts time blocked on the loader
-(host pipeline too slow), time issuing device puts, batches fed ahead,
-and bytes per batch — surfaced through `loader_throughput()`
-(loader/memmap.py), bench records, and the supervisor's JSON exit
-report (via the per-epoch heartbeat payload).
+(host pipeline too slow), time inside the put call, batches fed ahead,
+bytes per batch, and whether a popped batch had reached the device
+(`is_ready()`, asked without blocking) — in `stats()` (with the
+loader's produce-thread counters beside them) and, written by the feed
+itself per batch, in the one metrics registry (`veles_feed_*`). Spans
+(`feed.produce` > `loader.run`, `feed.device_put`) carry the batch's
+sequence number and land in the `--trace` ring and in any open profiler
+session (telemetry/tracer.py).
 """
 
 from __future__ import annotations
@@ -69,6 +73,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from veles_tpu.loader.base import TRAIN
+from veles_tpu.telemetry import metrics as _metrics
 from veles_tpu.telemetry import tracer as _tracer
 
 #: how many trailing per-epoch counter rows stats() keeps
@@ -82,10 +87,11 @@ class FeedBatch:
 
     __slots__ = ("x", "y", "w", "w_host", "minibatch_class",
                  "last_minibatch", "epoch_ended", "bytes_h2d",
-                 "loader_block_s")
+                 "loader_block_s", "seq")
 
     def __init__(self) -> None:
         self.x = self.y = self.w = None
+        self.seq = -1
         self.w_host: Optional[np.ndarray] = None
         self.minibatch_class = TRAIN
         self.last_minibatch = False
@@ -191,6 +197,8 @@ class DeviceFeed:
         self._loader_block_s = 0.0
         self._put_block_s = 0.0
         self._device_sync_s = 0.0
+        self._h2d_ready = 0
+        self._h2d_late = 0
         self._epoch_acc = {"batches": 0, "bytes_h2d": 0,
                            "loader_block_s": 0.0, "device_sync_s": 0.0}
         #: an epoch-ending batch was CONSUMED but its row not yet rolled
@@ -199,9 +207,8 @@ class DeviceFeed:
         self._pending_roll = False
         self._epoch_log: List[Dict[str, Any]] = []
         self._last_dtype = None
-        #: pre-bound tracer handle (None = tracing off): the hot path
-        #: pays one attribute load + None check per produce
-        self._tr = _tracer.active()
+        #: pre-bound registry counters: this feed is their producer
+        self._m = _metrics.feed_handles()
 
     @classmethod
     def for_step(cls, loader, step, ahead: int = 1) -> "DeviceFeed":
@@ -217,34 +224,33 @@ class DeviceFeed:
     # -- production -----------------------------------------------------------
 
     def _produce(self) -> FeedBatch:
-        ld = self.loader
-        t0 = time.perf_counter()
-        ld.run()
-        t1 = time.perf_counter()
-        x = ld.minibatch_data.mem
-        y = ld.minibatch_labels.mem
-        w = ld.minibatch_valid.mem
+        ld, span, m = self.loader, _tracer.span, self._m
         b = FeedBatch()
-        b.minibatch_class = ld.minibatch_class
-        b.last_minibatch = bool(ld.last_minibatch)
-        b.epoch_ended = bool(ld.epoch_ended)
-        b.w_host = w
-        b.bytes_h2d = int(getattr(x, "nbytes", 0)
-                          + getattr(y, "nbytes", 0)
-                          + getattr(w, "nbytes", 0))
-        if self._put is not None:
-            b.x, b.y, b.w = self._put((x, y, w))
-        else:
-            b.x, b.y, b.w = x, y, w
-        t2 = time.perf_counter()
-        tr = self._tr
-        if tr is not None:
+        b.seq = seq = getattr(ld, "next_batch_seq", self._n)
+        with span("feed.produce", "feed", seq):
+            t0 = time.perf_counter()
+            with span("loader.run", "feed", seq):
+                ld.run()
+            t1 = time.perf_counter()
+            x = ld.minibatch_data.mem
+            y = ld.minibatch_labels.mem
+            w = ld.minibatch_valid.mem
+            b.minibatch_class = ld.minibatch_class
+            b.last_minibatch = bool(ld.last_minibatch)
+            b.epoch_ended = bool(ld.epoch_ended)
+            b.w_host = w
+            b.bytes_h2d = int(getattr(x, "nbytes", 0)
+                              + getattr(y, "nbytes", 0)
+                              + getattr(w, "nbytes", 0))
             # the trace's overlap evidence: this device_put span lies
-            # inside the driver's in-flight "step" span when batch k+1
-            # transfers under step k's executing compute
-            tr.add_span("loader.run", "feed", t0, t1)
-            tr.add_span("feed.device_put", "feed", t1, t2)
-            tr.add_span("feed.produce", "feed", t0, t2)
+            # under step k's device time when batch k+1 transfers
+            # beneath the executing compute
+            with span("feed.device_put", "feed", seq):
+                if self._put is not None:
+                    b.x, b.y, b.w = self._put((x, y, w))
+                else:
+                    b.x, b.y, b.w = x, y, w
+            t2 = time.perf_counter()
         b.loader_block_s = t1 - t0
         self._loader_block_s += t1 - t0
         self._put_block_s += t2 - t1
@@ -252,6 +258,10 @@ class DeviceFeed:
         self._bytes += b.bytes_h2d
         self._bytes_last = b.bytes_h2d
         self._last_dtype = getattr(x, "dtype", None)
+        m.batches.inc()
+        m.bytes_h2d.inc(b.bytes_h2d)
+        m.loader_block_s.inc(t1 - t0)
+        m.put_s.inc(t2 - t1)
         return b
 
     def _flush_epoch(self) -> None:
@@ -281,8 +291,19 @@ class DeviceFeed:
         pending (the first batch, or ahead=0)."""
         if not self._queue:
             self._on_demand += 1
+            self._m.on_demand.inc()
             self._queue.append(self._produce())
         b = self._queue.popleft()
+        if hasattr(b.x, "is_ready"):
+            # had the transfer finished when the loop took the batch?
+            # (asked, never waited for: the step dispatched on a late
+            # batch waits on the device's side)
+            if b.x.is_ready() and b.y.is_ready() and b.w.is_ready():
+                self._h2d_ready += 1
+                self._m.h2d_ready.inc()
+            else:
+                self._h2d_late += 1
+                self._m.h2d_late.inc()
         # per-epoch rows are keyed by CONSUMPTION (a pending batch
         # produced past the boundary must not land in the old epoch's
         # row), and the ending row stays open until the next pop /
@@ -327,6 +348,7 @@ class DeviceFeed:
         decomposes blocked time into loader vs device."""
         self._device_sync_s += seconds
         self._epoch_acc["device_sync_s"] += seconds
+        self._m.device_sync_s.inc(seconds)
 
     def stop(self) -> None:
         """Drop pending batches and stop the loader's produce threads
@@ -345,6 +367,7 @@ class DeviceFeed:
         blocked on the host pipeline vs the device, lookahead health
         (`on_demand` > first batch means the loader fell behind)."""
         self._flush_epoch()
+        ld = self.loader
         return {
             "batches": self._n,
             "epochs": self._epochs,
@@ -359,5 +382,15 @@ class DeviceFeed:
             # batches the consumer had to wait a full produce for: 1 is
             # the unavoidable first batch; growth = loader too slow
             "on_demand": self._on_demand,
+            # popped batches already on the device / still in transfer
+            "h2d_ready": self._h2d_ready,
+            "h2d_late": self._h2d_late,
+            # the loader's own counters (PrefetchingLoader; 0 elsewhere):
+            # produce-thread seconds and batches, and whether the
+            # lookahead future was done when a fill asked for it
+            "produce_s": round(getattr(ld, "produce_s", 0.0), 6),
+            "batches_produced": getattr(ld, "batches_produced", 0),
+            "lookahead_ready": getattr(ld, "lookahead_ready", 0),
+            "lookahead_waited": getattr(ld, "lookahead_waited", 0),
             "epoch_log": list(self._epoch_log),
         }

@@ -83,6 +83,21 @@ def available() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+#: the fixed name of every kernel this module compiles: what a profiler
+#: trace calls the operation, through every recompile (a reduction that
+#: follows a kernel looks for these)
+KERNEL_NAMES = {
+    "_sgd_kernel": "veles_sgd_update",
+    "_lrn_fwd_kernel": "veles_lrn_fwd",
+    "_lrn_bwd_kernel": "veles_lrn_bwd",
+    "_lrn_pool_fwd_kernel": "veles_lrn_maxpool_fwd",
+    "_lrn_pool_bwd_kernel": "veles_lrn_maxpool_bwd",
+    "_flash_kernel": "veles_flash_fwd",
+    "_flash_dq_kernel": "veles_flash_dq",
+    "_flash_dkv_kernel": "veles_flash_dkv",
+}
+
+
 def _interpret() -> bool:
     from veles_tpu.ops import variants
     return _FORCE_INTERPRET or variants.pallas_interpret_active()
@@ -144,6 +159,7 @@ def sgd_update_pallas(p, g, v, lr, momentum=0.0, weight_decay=0.0,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=(spec, spec),
         interpret=_interpret(),
+        name=KERNEL_NAMES["_sgd_kernel"],
     )(p2, g2, v2, scal)
     return (p_new.ravel()[:n].reshape(shape).astype(dtype),
             v_new.ravel()[:n].reshape(shape).astype(dtype))
@@ -247,6 +263,7 @@ def _lrn_call(kernel, args, c: int, k, alpha, beta, n: int,
         in_specs=[spec] * len(x2s_p),
         out_specs=spec,
         interpret=_interpret(),
+        name=KERNEL_NAMES[kernel.__name__],
     )(*x2s_p)
     return out[:rows[0]].reshape(rows_shape + (c,)).astype(x.dtype)
 
@@ -465,6 +482,7 @@ def _lrn_pool_call(kernel, args, out_hwc, k, alpha, beta, n: int,
         scratch_shapes=[pltpu.VMEM((n_cb, rt, hp, wp, cb), jnp.float32)]
         * n_canvas,
         interpret=_interpret(),
+        name=KERNEL_NAMES[kernel.__name__],
     )(*xs)
     return out[:nb].astype(x.dtype)
 
@@ -720,6 +738,7 @@ def _flash_fwd_core(qf, kf, vf, scale, causal, blk_q, blk_k,
             pltpu.VMEM((blk_q, d), jnp.float32),   # unnormalized out
         ],
         interpret=_interpret(),
+        name=KERNEL_NAMES["_flash_kernel"],
     )(*args)
     return out, lse
 
@@ -762,6 +781,7 @@ def _flash_bwd_pallas(qf, kf, vf, do, lse, di, scale, causal,
         out_specs=_qspec(blk_q, d),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         interpret=_interpret(),
+        name=KERNEL_NAMES["_flash_dq_kernel"],
     )(qf, kf, vf, do, lse, di)
     # transposed grid: KV outer, Q inner (indices (b, t, i) name the
     # (kv, q) block pair, so the q-side specs index with the LAST axis)
@@ -780,6 +800,7 @@ def _flash_bwd_pallas(qf, kf, vf, do, lse, di, scale, causal,
         scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
                         pltpu.VMEM((blk_k, d), jnp.float32)],
         interpret=_interpret(),
+        name=KERNEL_NAMES["_flash_dkv_kernel"],
     )(qf, kf, vf, do, lse, di)
     return dq, dk, dv
 

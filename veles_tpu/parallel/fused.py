@@ -42,6 +42,16 @@ legs overlap with compute. Degrades (with a logged reason, see
 `zero_reason`) for local/gspmd/seq modes, EP, single-shard data axes and
 multi-host meshes — those keep the replicated update this PR left alone.
 
+Names in a profile: every operation of the compiled step carries a
+`jax.named_scope` path that depends on the layer table only, never on a
+compile — `input_normalize`, `cast_params`, one `L<index>.<layer type>`
+per forward unit (`L01.norm`; a searched fused pair is one scope,
+`L01.norm+L02.max_pooling`), `loss`, and `update` with the unit's scope
+beneath it (under ZeRO `grad_exchange` and `param_gather` beneath that).
+Autodiff writes the backward's `transpose(jvp(<scope>))` itself. Scopes
+are metadata: the compiled program is the same (docs/OBSERVABILITY.md).
+`train()` records the `train.dispatch` span with the step's number.
+
 Numerics match the granular unit-by-unit path (tested): grads come from
 `jax.grad` over the same `fused_apply` forward math, and the update is the
 same `ops.optim.sgd_update` the GD units use, with each layer keeping its
@@ -72,6 +82,7 @@ from veles_tpu.ops import xla as ox
 from veles_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, SEQ_AXIS,
                                      zero_flatten, zero_plan,
                                      zero_unflatten)
+from veles_tpu.telemetry import tracer as _tracer
 
 
 def _tree_cast(tree, dtype):
@@ -165,6 +176,15 @@ class FusedTrainStep:
         self.input_normalize = (dict(input_normalize)
                                 if input_normalize else None)
         self.forwards = list(workflow.forwards)
+        kinds = [spec.get("type")
+                 for spec in getattr(workflow, "layers_config", ())]
+        if len(kinds) != len(self.forwards) or not all(kinds):
+            kinds = [type(u).__name__.lower() for u in self.forwards]
+        #: each forward unit's named scope: its index and its layer-table
+        #: type — the names a profile of the compiled step carries
+        self.scopes = tuple(f"L{i:02d}.{k}" for i, k in enumerate(kinds))
+        #: train steps dispatched: the number a train.dispatch span carries
+        self.n_dispatched = 0
         self.loss_kind = workflow.loss
         self.n_classes = getattr(workflow, "n_classes", None)
         if compute_dtype is None:
@@ -682,10 +702,13 @@ class FusedTrainStep:
                  local_trace: bool = False):
         # uint8-wire prologue: traced into the step, so it fuses into
         # the first layer's HBM read
-        x = apply_input_normalize(self.input_normalize, x)
+        with jax.named_scope("input_normalize"):
+            x = apply_input_normalize(self.input_normalize, x)
+            if self.compute_dtype is not None:
+                x = x.astype(self.compute_dtype)
         if self.compute_dtype is not None:
-            x = x.astype(self.compute_dtype)
-            params = _tree_cast(params, self.compute_dtype)
+            with jax.named_scope("cast_params"):
+                params = _tree_cast(params, self.compute_dtype)
         # local_trace: trace the DENSE single-program form (no bound
         # collective axis names) for use under plain jit — GSPMD handles
         # any param sharding, gathering EP experts where needed (the
@@ -725,15 +748,19 @@ class FusedTrainStep:
                 continue
             if i in fused:
                 j, v = fused[i]
-                x = self._apply_fused_pair(v, u, self.forwards[j],
-                                           params[i], x)
-                x = self._constrain_tp_act(x, j)
+                with jax.named_scope(f"{self.scopes[i]}+{self.scopes[j]}"):
+                    x = self._apply_fused_pair(v, u, self.forwards[j],
+                                               params[i], x)
+                    x = self._constrain_tp_act(x, j)
                 continue
-            k = jax.random.fold_in(key, i) if u.fused_needs_key else None
-            x = u.fused_apply(params[i], x, key=k, train=train)
-            x = self._constrain_tp_act(x, i)
+            with jax.named_scope(self.scopes[i]):
+                k = (jax.random.fold_in(key, i) if u.fused_needs_key
+                     else None)
+                x = u.fused_apply(params[i], x, key=k, train=train)
+                x = self._constrain_tp_act(x, i)
         if self.compute_dtype is not None:
-            x = x.astype(jnp.float32)
+            with jax.named_scope("loss"):
+                x = x.astype(jnp.float32)
         return x
 
     def input_put_specs(self):
@@ -778,32 +805,33 @@ class FusedTrainStep:
         weight sum so microbatch partials sum to the exact full-batch
         mean (and its gradient)."""
         out = self._forward(params, x, key, train)
-        if self.loss_kind == "softmax":
-            # broadcast per-sample weights over token dims: (N,) classifier
-            # labels, (N, S) per-token LM labels, or flat (N·S,) labels
-            # (the char-LSTM convention) where each sample weight covers
-            # S consecutive tokens
-            if y.ndim == w.ndim and y.shape[0] != w.shape[0] \
-                    and y.shape[0] % w.shape[0] == 0:
-                wt = jnp.repeat(w, y.shape[0] // w.shape[0])
+        with jax.named_scope("loss"):
+            if self.loss_kind == "softmax":
+                # broadcast per-sample weights over token dims: (N,) classifier
+                # labels, (N, S) per-token LM labels, or flat (N·S,) labels
+                # (the char-LSTM convention) where each sample weight covers
+                # S consecutive tokens
+                if y.ndim == w.ndim and y.shape[0] != w.shape[0] \
+                        and y.shape[0] % w.shape[0] == 0:
+                    wt = jnp.repeat(w, y.shape[0] // w.shape[0])
+                else:
+                    wt = jnp.broadcast_to(
+                        w.reshape(w.shape + (1,) * (y.ndim - w.ndim)),
+                        y.shape)
+                wt = wt.astype(jnp.float32)
+                tokens = wt.size // w.size
+                denom = (wsum * tokens if wsum is not None
+                         else self._global_wsum(w, tokens, axes))
+                loss = ox.ce_loss_from_logits(out, y, self.n_classes,
+                                              weights=wt, denom=denom)
+                wrong = (out.reshape(-1, out.shape[-1]).argmax(axis=-1)
+                         != y.reshape(-1))
+                n_err = (wrong & (wt.reshape(-1) > 0)).sum()
             else:
-                wt = jnp.broadcast_to(
-                    w.reshape(w.shape + (1,) * (y.ndim - w.ndim)),
-                    y.shape)
-            wt = wt.astype(jnp.float32)
-            tokens = wt.size // w.size
-            denom = (wsum * tokens if wsum is not None
-                     else self._global_wsum(w, tokens, axes))
-            loss = ox.ce_loss_from_logits(out, y, self.n_classes,
-                                          weights=wt, denom=denom)
-            wrong = (out.reshape(-1, out.shape[-1]).argmax(axis=-1)
-                     != y.reshape(-1))
-            n_err = (wrong & (wt.reshape(-1) > 0)).sum()
-        else:
-            denom = (wsum if wsum is not None
-                     else self._global_wsum(w, 1, axes))
-            loss, _ = ox.mse(out, y, weights=w, denom=denom)
-            n_err = loss
+                denom = (wsum if wsum is not None
+                         else self._global_wsum(w, 1, axes))
+                loss, _ = ox.mse(out, y, weights=w, denom=denom)
+                n_err = loss
         return loss, n_err
 
     def _global_wsum(self, w, tokens_per_sample: int, axes):
@@ -857,8 +885,9 @@ class FusedTrainStep:
             lf, has_aux=True)(self._grad_params(state["params"]))
         if axes:
             # partials with a global denominator: SUM to the global metric
-            loss = lax.psum(loss, axes)
-            n_err = lax.psum(n_err, axes)
+            with jax.named_scope("loss"):
+                loss = lax.psum(loss, axes)
+                n_err = lax.psum(n_err, axes)
         return self._apply_update(state, grads), loss, n_err
 
     def _grad_params(self, params):
@@ -896,20 +925,25 @@ class FusedTrainStep:
         optim.sgd_update; the search-generated pallas row-blocked
         candidates slot in when selected — GSPMD falls back, a
         pallas_call cannot be auto-partitioned)."""
-        if self.zero_active:
-            return self._apply_update_zero(state, grads)
+        with jax.named_scope("update"):
+            if self.zero_active:
+                return self._apply_update_zero(state, grads)
+            return self._apply_update_replicated(state, grads)
+
+    def _apply_update_replicated(self, state, grads):
         sgd_apply = self._sgd_variant().apply
         new_params, new_vel = [], []
-        for p, g, v, cfg in zip(state["params"], grads, state["vel"],
-                                self.cfgs):
-            if p and isinstance(cfg, optim.AdamConfig):
-                np_, nv_ = optim.adam_update(p, g, v, cfg,
-                                             lr_scale=state["lr_scale"])
-            elif p:
-                np_, nv_ = sgd_apply(p, g, v, cfg,
-                                     lr_scale=state["lr_scale"])
-            else:
-                np_, nv_ = p, v
+        for scope, p, g, v, cfg in zip(self.scopes, state["params"], grads,
+                                       state["vel"], self.cfgs):
+            with jax.named_scope(scope):
+                if p and isinstance(cfg, optim.AdamConfig):
+                    np_, nv_ = optim.adam_update(
+                        p, g, v, cfg, lr_scale=state["lr_scale"])
+                elif p:
+                    np_, nv_ = sgd_apply(p, g, v, cfg,
+                                         lr_scale=state["lr_scale"])
+                else:
+                    np_, nv_ = p, v
             new_params.append(np_)
             new_vel.append(nv_)
         new_key = jax.random.fold_in(state["key"], 1)
@@ -949,40 +983,43 @@ class FusedTrainStep:
                 new_vel.append(v)
                 new_ef.append(ef_layer if ef_layer is not None else {})
                 continue
-            adam = isinstance(cfg, optim.AdamConfig)
-            if adam:
-                t = v["t"] + 1
-                b1t, b2t = optim.adam_step_factors(cfg, t)
-                nv: Dict[str, Any] = {"m": {}, "v": {}, "t": t}
-            else:
-                nv = {}
-            np_ = {}
-            for k in p:
-                lp = plan[k]
-                flat_g = zero_flatten(g[k], lp)
-                if ef_layer is not None:
-                    g_loc, nef[k] = reduce(flat_g, DATA_AXIS,
-                                           ef_layer[k])
-                else:
-                    g_loc = reduce(flat_g, DATA_AXIS)
-                p_loc = lax.dynamic_slice(
-                    zero_flatten(p[k], lp), (idx * lp.local,),
-                    (lp.local,))
+            with jax.named_scope(self.scopes[li]):
+                adam = isinstance(cfg, optim.AdamConfig)
                 if adam:
-                    p_new, m_new, v_new = optim.adam_leaf(
-                        p_loc, g_loc, v["m"][k], v["v"][k], cfg,
-                        b1t, b2t, cfg.lr * state["lr_scale"])
-                    nv["m"][k] = m_new
-                    nv["v"][k] = v_new
+                    t = v["t"] + 1
+                    b1t, b2t = optim.adam_step_factors(cfg, t)
+                    nv: Dict[str, Any] = {"m": {}, "v": {}, "t": t}
                 else:
-                    lr = optim.sgd_leaf_lr(cfg, lp.ndim,
-                                           lr_scale=state["lr_scale"])
-                    p_new, v_new = optim.sgd_leaf(p_loc, g_loc, v[k],
-                                                  cfg, lr)
-                    nv[k] = v_new
-                full = all_gather_invariant(p_new, DATA_AXIS, axis=0,
-                                            tiled=True)
-                np_[k] = zero_unflatten(full, lp)
+                    nv = {}
+                np_ = {}
+                for k in p:
+                    lp = plan[k]
+                    with jax.named_scope("grad_exchange"):
+                        flat_g = zero_flatten(g[k], lp)
+                        if ef_layer is not None:
+                            g_loc, nef[k] = reduce(flat_g, DATA_AXIS,
+                                                   ef_layer[k])
+                        else:
+                            g_loc = reduce(flat_g, DATA_AXIS)
+                    p_loc = lax.dynamic_slice(
+                        zero_flatten(p[k], lp), (idx * lp.local,),
+                        (lp.local,))
+                    if adam:
+                        p_new, m_new, v_new = optim.adam_leaf(
+                            p_loc, g_loc, v["m"][k], v["v"][k], cfg,
+                            b1t, b2t, cfg.lr * state["lr_scale"])
+                        nv["m"][k] = m_new
+                        nv["v"][k] = v_new
+                    else:
+                        lr = optim.sgd_leaf_lr(cfg, lp.ndim,
+                                               lr_scale=state["lr_scale"])
+                        p_new, v_new = optim.sgd_leaf(p_loc, g_loc, v[k],
+                                                      cfg, lr)
+                        nv[k] = v_new
+                    with jax.named_scope("param_gather"):
+                        full = all_gather_invariant(p_new, DATA_AXIS, axis=0,
+                                                    tiled=True)
+                        np_[k] = zero_unflatten(full, lp)
             new_params.append(np_)
             new_vel.append(nv)
             new_ef.append(nef)
@@ -1132,45 +1169,54 @@ class FusedTrainStep:
         """The UNJITTED (state, x, y, w) -> (state, loss, n_err)
         callable `_build` wraps in jax.jit — shard_map-wrapped in
         dp/seq modes so the jaxpr auditor (analysis/trace.py) abstractly
-        traces exactly what trains, with zero compile."""
+        traces exactly what trains, with zero compile.
+
+        The callable is NAMED: the compiled program is `jit_train_step`
+        in a profile, through every recompile. (The name is also part of
+        jax's compile cache key, which leaves operation metadata out: an
+        executable cached before the step's named scopes existed would
+        otherwise be loaded with its old, scopeless operation names.)"""
+        axis = {"dp": DATA_AXIS, "seq": (DATA_AXIS, SEQ_AXIS)}.get(
+            self.mode)
+
+        def train_step(s, x, y, w):
+            return self._train_body(s, x, y, w, axis=axis)
+
         if self.mode in ("local", "gspmd"):
-            return lambda s, x, y, w: self._train_body(s, x, y, w,
-                                                       axis=None)
+            return train_step
         if self.mode == "dp":
             ssp = self._smap_state_spec()
             return shard_map(
-                lambda s, x, y, w: self._train_body(s, x, y, w,
-                                                    axis=DATA_AXIS),
-                mesh=self.mesh,
+                train_step, mesh=self.mesh,
                 in_specs=(ssp, P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
                 out_specs=(ssp, P(), P()))
         if self.mode == "seq":
-            axes = (DATA_AXIS, SEQ_AXIS)
             xspec = P(DATA_AXIS, SEQ_AXIS)  # (N, S, ...) batch x sequence
             ssp = self._seq_state_spec()    # TP-sharded when model axis
             return shard_map(
-                lambda s, x, y, w: self._train_body(s, x, y, w,
-                                                    axis=axes),
-                mesh=self.mesh,
+                train_step, mesh=self.mesh,
                 in_specs=(ssp, xspec, xspec, P(DATA_AXIS)),
                 out_specs=(ssp, P(), P()))
         raise ValueError(f"unknown mode {self.mode!r}")
 
     def _build(self) -> None:
         donate = (0,) if self.donate else ()
+        axis = {"dp": DATA_AXIS, "seq": (DATA_AXIS, SEQ_AXIS)}.get(
+            self.mode)
+
+        def eval_step(p, x, y, w):      # named, as train_step is
+            return self._eval_body(p, x, y, w, axis=axis)
+
         if self.mode == "local":
             self._train_fn = jax.jit(self.train_callable(),
                                      donate_argnums=donate)
-            self._eval_fn = jax.jit(
-                lambda p, x, y, w: self._eval_body(p, x, y, w, axis=None))
+            self._eval_fn = jax.jit(eval_step)
         elif self.mode == "dp":
             mesh = self.mesh
             ssp = self._smap_state_spec()
             wsp = P(DATA_AXIS)
             evalf = shard_map(
-                lambda p, x, y, w: self._eval_body(p, x, y, w,
-                                                   axis=DATA_AXIS),
-                mesh=mesh,
+                eval_step, mesh=mesh,
                 in_specs=(ssp["params"], P(DATA_AXIS), P(DATA_AXIS), wsp),
                 out_specs=(P(), P()))
             self._train_fn = jax.jit(self.train_callable(),
@@ -1178,13 +1224,11 @@ class FusedTrainStep:
             self._eval_fn = jax.jit(evalf)
         elif self.mode == "seq":
             mesh = self.mesh
-            axes = (DATA_AXIS, SEQ_AXIS)
             xspec = P(DATA_AXIS, SEQ_AXIS)  # (N, S, ...) batch x sequence
             wsp = P(DATA_AXIS)              # weights stay per-SAMPLE
             ssp = self._seq_state_spec()    # TP-sharded when model axis
             evalf = shard_map(
-                lambda p, x, y, w: self._eval_body(p, x, y, w, axis=axes),
-                mesh=mesh,
+                eval_step, mesh=mesh,
                 in_specs=(ssp["params"], xspec, xspec, wsp),
                 out_specs=(P(), P()))
             self._train_fn = jax.jit(self.train_callable(),
@@ -1207,7 +1251,7 @@ class FusedTrainStep:
                 out_shardings=(ssh, repl, repl),
                 donate_argnums=donate)
             self._eval_fn = jax.jit(
-                lambda p, x, y, w: self._eval_body(p, x, y, w, axis=None),
+                eval_step,
                 in_shardings=(self._param_shardings(), xsh, xsh, xsh))
         else:
             raise ValueError(f"unknown mode {self.mode!r}")
@@ -1318,10 +1362,16 @@ class FusedTrainStep:
         `w` is the Loader's (N,) pad mask (None == all-ones)."""
         if self._train_fn is None:
             self._build()
-        self._check_batch(np.shape(x)[0])
-        x, y = self._seq_xy(x, y)
-        w = self._weights_or_ones(w, np.shape(x)[0])
-        new_state, loss, n_err = self._train_fn(state, x, y, w)
+        # the HOST side of the async dispatch, numbered by this step's
+        # own count of dispatches (in a train-only loop that is the
+        # batch's number too: loader.produce#k -> feed.device_put#k ->
+        # train.dispatch#k)
+        with _tracer.span("train.dispatch", "step", self.n_dispatched):
+            self._check_batch(np.shape(x)[0])
+            x, y = self._seq_xy(x, y)
+            w = self._weights_or_ones(w, np.shape(x)[0])
+            new_state, loss, n_err = self._train_fn(state, x, y, w)
+        self.n_dispatched += 1
         return new_state, (loss, n_err)
 
     def confusion(self, state, x, y, n_classes: int, w=None):
@@ -1450,7 +1500,7 @@ class FusedTrainStep:
             axis = {"dp": DATA_AXIS, "seq": (DATA_AXIS, SEQ_AXIS)}.get(
                 self.mode)
 
-            def rep(state, x, y, w):
+            def train_repeat_steps(state, x, y, w):
                 def step(st, _):
                     st2, loss, n_err = self._train_body(st, x, y, w,
                                                         axis=axis)
@@ -1459,14 +1509,14 @@ class FusedTrainStep:
 
             donate = (0,) if self.donate else ()
             if self.mode == "local":
-                cache[k] = jax.jit(rep, donate_argnums=donate)
+                cache[k] = jax.jit(train_repeat_steps, donate_argnums=donate)
             elif self.mode in ("dp", "seq"):
                 spec = (P(DATA_AXIS, SEQ_AXIS) if self.mode == "seq"
                         else P(DATA_AXIS))
                 ssp = (self._smap_state_spec() if self.mode == "dp"
                        else self._seq_state_spec())
                 sm = shard_map(
-                    rep, mesh=self.mesh,
+                    train_repeat_steps, mesh=self.mesh,
                     in_specs=(ssp, spec, spec, P(DATA_AXIS)),
                     out_specs=(ssp, (P(), P())))
                 cache[k] = jax.jit(sm, donate_argnums=donate)
@@ -1475,7 +1525,7 @@ class FusedTrainStep:
                 ssh = self._state_shardings()
                 repl = NamedSharding(self.mesh, P())
                 cache[k] = jax.jit(
-                    rep, in_shardings=(ssh, xsh, xsh, xsh),
+                    train_repeat_steps, in_shardings=(ssh, xsh, xsh, xsh),
                     out_shardings=(ssh, (repl, repl)),  # see _build: pin
                     # the returned state to the plan, not propagation
                     donate_argnums=donate)
@@ -1509,21 +1559,21 @@ class FusedTrainStep:
             axis = {"dp": DATA_AXIS, "seq": (DATA_AXIS, SEQ_AXIS)}.get(
                 self.mode)
 
-            def acc(state, xs, ys, ws):
+            def train_accum_step(state, xs, ys, ws):
                 st2, loss, n_err = self._accum_body(state, xs, ys, ws,
                                                     axis=axis)
                 return st2, (loss, n_err)
 
             donate = (0,) if self.donate else ()
             if self.mode == "local":
-                cache[k] = jax.jit(acc, donate_argnums=donate)
+                cache[k] = jax.jit(train_accum_step, donate_argnums=donate)
             elif self.mode in ("dp", "seq"):
                 spec = (P(None, DATA_AXIS, SEQ_AXIS)
                         if self.mode == "seq" else P(None, DATA_AXIS))
                 ssp = (self._smap_state_spec() if self.mode == "dp"
                        else self._seq_state_spec())
                 sm = shard_map(
-                    acc, mesh=self.mesh,
+                    train_accum_step, mesh=self.mesh,
                     in_specs=(ssp, spec, spec, P(None, DATA_AXIS)),
                     out_specs=(ssp, (P(), P())))
                 cache[k] = jax.jit(sm, donate_argnums=donate)
@@ -1532,12 +1582,15 @@ class FusedTrainStep:
                 ssh = self._state_shardings()
                 repl = NamedSharding(self.mesh, P())
                 cache[k] = jax.jit(
-                    acc, in_shardings=(ssh, xsh, xsh, xsh),
+                    train_accum_step, in_shardings=(ssh, xsh, xsh, xsh),
                     out_shardings=(ssh, (repl, repl)),  # see _build
                     donate_argnums=donate)
             else:
                 raise ValueError(f"unknown mode {self.mode!r}")
-        return cache[k](state, xs, ys, ws)
+        with _tracer.span("train.dispatch", "step", self.n_dispatched):
+            out = cache[k](state, xs, ys, ws)
+        self.n_dispatched += 1
+        return out
 
     def train_many(self, state, xs, ys, ws=None):
         """K training steps in ONE dispatch: xs (K, batch, ...), ys
@@ -1557,7 +1610,7 @@ class FusedTrainStep:
             axis = {"dp": DATA_AXIS, "seq": (DATA_AXIS, SEQ_AXIS)}.get(
                 self.mode)
 
-            def many(state, xs, ys, ws):
+            def train_many_steps(state, xs, ys, ws):
                 def step(st, xyw):
                     st2, loss, n_err = self._train_body(
                         st, xyw[0], xyw[1], xyw[2], axis=axis)
@@ -1566,7 +1619,8 @@ class FusedTrainStep:
 
             donate = (0,) if self.donate else ()
             if self.mode == "local":
-                self._train_many_fn = jax.jit(many, donate_argnums=donate)
+                self._train_many_fn = jax.jit(train_many_steps,
+                                              donate_argnums=donate)
             elif self.mode in ("dp", "seq"):
                 spec = (P(None, DATA_AXIS, SEQ_AXIS)
                         if self.mode == "seq" else P(None, DATA_AXIS))
@@ -1574,7 +1628,7 @@ class FusedTrainStep:
                 ssp = (self._smap_state_spec() if self.mode == "dp"
                        else self._seq_state_spec())
                 sm = shard_map(
-                    many, mesh=self.mesh,
+                    train_many_steps, mesh=self.mesh,
                     in_specs=(ssp, spec, spec, wspec),
                     out_specs=(ssp, (P(), P())))
                 self._train_many_fn = jax.jit(sm, donate_argnums=donate)
@@ -1583,7 +1637,7 @@ class FusedTrainStep:
                 ssh = self._state_shardings()
                 repl = NamedSharding(self.mesh, P())
                 self._train_many_fn = jax.jit(
-                    many, in_shardings=(ssh, xsh, xsh, xsh),
+                    train_many_steps, in_shardings=(ssh, xsh, xsh, xsh),
                     out_shardings=(ssh, (repl, repl)),  # see _build
                     donate_argnums=donate)
             else:

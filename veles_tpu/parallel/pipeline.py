@@ -51,6 +51,8 @@ from jax import shard_map
 from jax.lax import axis_size as _axis_size
 from jax.lax import pcast
 
+from veles_tpu.telemetry import tracer as _tracer
+
 STAGE_AXIS = "stage"
 
 
@@ -243,6 +245,8 @@ class PipelineTrainStep:
         self.pad_width = max(widths)
         self._build_param_layout()
         self._train_fn = None
+        #: train steps dispatched: the number a train.dispatch span carries
+        self.n_dispatched = 0
         self._eval_fn = None
 
     # -- stage-resident flat parameter layout (v2) ---------------------------
@@ -501,8 +505,11 @@ class PipelineTrainStep:
             self._build()
         if w is None:
             w = np.ones(np.shape(x)[0], np.float32)
-        xs, y, w = self._microbatch(x, y, w)
-        new_state, loss, n_err = self._train_fn(state, self._gid, xs, y, w)
+        with _tracer.span("train.dispatch", "step", self.n_dispatched):
+            xs, y, w = self._microbatch(x, y, w)
+            new_state, loss, n_err = self._train_fn(state, self._gid, xs,
+                                                    y, w)
+        self.n_dispatched += 1
         return new_state, (loss, n_err)
 
     def evaluate(self, state, x, y, w=None):

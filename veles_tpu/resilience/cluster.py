@@ -1208,18 +1208,13 @@ class ClusterMember(Logger):
               ) -> Optional[Dict[str, Any]]:
         from veles_tpu.http_util import http_post_json
         from veles_tpu.telemetry import tracer as _tracer
-        tr = _tracer.active()
-        tok = tr.begin("cluster.beat", "cluster") \
-            if tr is not None else None
         try:
-            return http_post_json(self.coord_host, self.coord_port,
-                                  path, report, token=self.token,
-                                  timeout=max(5.0, self.beat_s * 3))
+            with _tracer.span("cluster.beat", "cluster"):
+                return http_post_json(self.coord_host, self.coord_port,
+                                      path, report, token=self.token,
+                                      timeout=max(5.0, self.beat_s * 3))
         except OSError:
             return None
-        finally:
-            if tok is not None:
-                tr.end(tok)
 
     def _beat(self, status: str, codes: List[Any]
               ) -> Optional[Dict[str, Any]]:

@@ -11,7 +11,9 @@ producers:
   beats), exported as Chrome-trace/Perfetto-loadable ``trace.json``
   (CLI ``--trace PATH``); plus ``--profile-window N:M`` /
   ``POST /profile`` on-chip capture windows bracketing steps with
-  ``jax.profiler``.
+  ``jax.profiler``. Every span is also a ``TraceAnnotation`` while any
+  profiler session is open, so a capture holds the program's spans on
+  the device trace's own timeline.
 - `telemetry.metrics` — ONE metrics registry (counters / gauges /
   histograms) behind a Prometheus text-format ``GET /metrics`` on
   web_status, the cluster coordinator (fleet-aggregated from member

@@ -9,8 +9,9 @@ producer. Everything now routes through a `MetricsRegistry`:
 - the driver loop (`_run_with_step`) records step counts/time, examples
   and loss through PRE-BOUND handles (`step_handles()`; the velint
   ``hot-metric`` rule bans per-record name lookups in hot paths);
-- the DeviceFeed's cumulative counters are MIRRORED in
-  (`mirror_feed()` — the feed's stats dict stays the one producer);
+- the DeviceFeed and the prefetching loader write their own counters
+  (`feed_handles()` / `loader_handles()`, bound once per feed / per
+  produce pool), so a scrape sees them without a driver's help;
 - memstats snapshots land as gauges (`mirror_mem()`);
 - web_status, the cluster coordinator (fleet-aggregated from member
   heartbeats) and serving each mount ``GET /metrics`` rendering
@@ -382,6 +383,27 @@ def register_standard(reg: MetricsRegistry) -> None:
     reg.counter("veles_feed_on_demand_total",
                 "feed pops that had to produce synchronously (1 is the "
                 "unavoidable first batch; growth = loader too slow)")
+    reg.counter("veles_feed_batches_total",
+                "batches the DeviceFeed produced (loader.run + put)")
+    reg.counter("veles_feed_put_seconds_total",
+                "driver time inside the feed's device_put call (the "
+                "async put's host side: layout change and enqueue)")
+    reg.counter("veles_feed_h2d_ready_total",
+                "popped batches whose arrays were already on the device "
+                "(is_ready(), asked without blocking)")
+    reg.counter("veles_feed_h2d_late_total",
+                "popped batches still in transfer when the loop took "
+                "them: the step dispatched on them waits for the link")
+    reg.counter("veles_loader_produce_seconds_total",
+                "seconds inside PrefetchingLoader._produce, summed over "
+                "the produce threads (gather, flip, normalize)")
+    reg.counter("veles_loader_batches_produced_total",
+                "batches PrefetchingLoader._produce completed")
+    reg.counter("veles_loader_lookahead_ready_total",
+                "fills whose lookahead future was done when asked")
+    reg.counter("veles_loader_lookahead_waited_total",
+                "fills that waited: the lookahead future was still "
+                "running, or there was none (first batch of an epoch)")
     reg.gauge("veles_mem_live_bytes", "live jax.Array bytes per device",
               labelnames=("device",))
     reg.gauge("veles_mem_live_bytes_max",
@@ -513,26 +535,37 @@ def collective_handles(acct: Optional[Dict[str, Any]],
         dcn_bytes=float(acct.get("dcn_bytes", 0)),
         ici_bytes=float(acct.get("ici_bytes", 0)),
         ag_dcn_bytes=float(acct.get("allgather_dcn_bytes", 0)),
-        ag_ici_bytes=float(acct.get("allgather_ici_bytes", 0)),
-        mark=f"{acct['op']}:{acct.get('variant', '?')}")
+        ag_ici_bytes=float(acct.get("allgather_ici_bytes", 0)))
 
 
-def mirror_feed(stats: Optional[Dict[str, Any]],
-                reg: Optional[MetricsRegistry] = None) -> None:
-    """Mirror the DeviceFeed's cumulative stats dict into the feed
-    counters — the feed stays the ONE producer; set_total keeps the
-    exposed counters monotone across feed restarts within a process."""
-    if not stats:
-        return
+def feed_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
+    """Pre-bound `veles_feed_*` counters: the DeviceFeed binds them once
+    and is their one producer (per batch: float adds, no name lookup)."""
     reg = reg or default_registry()
-    reg.counter("veles_feed_h2d_bytes_total").set_total(
-        stats.get("bytes_h2d", 0))
-    reg.counter("veles_feed_loader_block_seconds_total").set_total(
-        stats.get("loader_block_s", 0.0))
-    reg.counter("veles_feed_device_sync_seconds_total").set_total(
-        stats.get("device_sync_s", 0.0))
-    reg.counter("veles_feed_on_demand_total").set_total(
-        stats.get("on_demand", 0))
+    return SimpleNamespace(
+        batches=reg.counter("veles_feed_batches_total"),
+        bytes_h2d=reg.counter("veles_feed_h2d_bytes_total"),
+        loader_block_s=reg.counter(
+            "veles_feed_loader_block_seconds_total"),
+        put_s=reg.counter("veles_feed_put_seconds_total"),
+        device_sync_s=reg.counter("veles_feed_device_sync_seconds_total"),
+        on_demand=reg.counter("veles_feed_on_demand_total"),
+        h2d_ready=reg.counter("veles_feed_h2d_ready_total"),
+        h2d_late=reg.counter("veles_feed_h2d_late_total"),
+    )
+
+
+def loader_handles(reg: Optional[MetricsRegistry] = None
+                   ) -> SimpleNamespace:
+    """Pre-bound `veles_loader_*` counters: the PrefetchingLoader binds
+    them when it starts its produce pool and is their one producer."""
+    reg = reg or default_registry()
+    return SimpleNamespace(
+        produce_s=reg.counter("veles_loader_produce_seconds_total"),
+        produced=reg.counter("veles_loader_batches_produced_total"),
+        ready=reg.counter("veles_loader_lookahead_ready_total"),
+        waited=reg.counter("veles_loader_lookahead_waited_total"),
+    )
 
 
 def mirror_mem(mem: Optional[Dict[str, Any]],

@@ -10,16 +10,29 @@ buffer and exports them as a Chrome-trace/Perfetto-loadable
 ``trace.json``, so the overlap becomes a picture: batch k+1's
 ``feed.device_put`` span visibly riding under step k's ``step`` span.
 
+One clock with the device: `span()` (the module function every producer
+in the program records through) opens, besides the ring event, a
+``jax.profiler.TraceAnnotation`` whenever jax is already imported and a
+profiler session is open — whoever opened it (``benchmark/run.py --trace
+1``, ``--profile-window``, ``POST /profile``). The session's host plane
+then holds the program's spans on the device trace's own timeline, and
+an idle gap of the device can be named after the span covering it with
+no alignment step. A span's `seq` (the batch's or step's sequence
+number) rides the annotation as the event's ``seq`` stat and the ring
+event as ``args.seq``: ``loader.produce#k -> feed.device_put#k ->
+train.dispatch#k`` is one chain.
+
 Design constraints (the hot-path contract):
 
 - **Zero host-sync**: spans are host timestamps only
   (``time.perf_counter_ns``, one monotonic clock for the whole
   process); recording never touches a device value.
-- **Pre-bound handle**: hot paths capture ``tracer.active()`` ONCE
-  (None when tracing is off) and guard each record with a plain ``is
-  not None`` check — the disabled path costs one attribute load. The
-  velint ``hot-metric`` rule enforces the same discipline for metric
-  records.
+- **Off costs a call**: with no ring installed and no profiler session
+  open `span()` returns one shared no-op object (a global load, one
+  ``TraceMe.is_enabled()`` call; measured in docs/OBSERVABILITY.md), so
+  producers write plain ``with tracer.span(...)`` blocks and a session
+  opened mid-run is seen by the next span. The velint ``hot-metric``
+  rule enforces pre-binding for metric records.
 - **Bounded memory**: a ring buffer of `capacity` events; overflow
   overwrites the oldest and the export reports how many were dropped
   (``otherData.dropped``) instead of growing without bound on a long
@@ -37,11 +50,12 @@ attribute check.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
+import sys
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 #: default ring capacity (events); env-overridable for long captures
@@ -54,54 +68,55 @@ class Tracer:
 
     def __init__(self, capacity: int = 0) -> None:
         self.capacity = max(256, int(capacity or _DEFAULT_CAPACITY))
-        #: ring slots: (name, cat, ts_us, dur_us, tid, ph)
+        #: ring slots: (name, cat, ts_us, dur_us, tid, ph, seq)
         self._ring: List[Optional[Tuple]] = [None] * self.capacity
         self._n = 0                      # total events ever recorded
         self._lock = threading.Lock()
         #: perf_counter_ns at construction — every ts is relative to it
         self._epoch_ns = time.perf_counter_ns()
-        #: wall-clock twin of the epoch, for correlating with logs
-        self._epoch_unix = time.time()
+        #: wall-clock twin of the epoch (``time.time_ns()``), for
+        #: correlating with logs and with a profiler capture
+        self._epoch_unix_ns = time.time_ns()
         self._pid = os.getpid()
 
     # -- recording ------------------------------------------------------------
 
-    def begin(self, name: str, cat: str = "host") -> Tuple:
+    def begin(self, name: str, cat: str = "host",
+              seq: Optional[int] = None) -> Tuple:
         """Open a span; returns the token `end()` closes. No lock —
         the token carries its own start timestamp."""
         return (name, cat, time.perf_counter_ns(),
-                threading.get_ident())
+                threading.get_ident(), seq)
 
     def end(self, token: Tuple) -> None:
         """Close a span opened by `begin()` and append it."""
-        name, cat, t0, tid = token
+        name, cat, t0, tid, seq = token
         t1 = time.perf_counter_ns()
         self._append((name, cat, (t0 - self._epoch_ns) / 1e3,
-                      (t1 - t0) / 1e3, tid, "X"))
+                      (t1 - t0) / 1e3, tid, "X", seq))
 
     def add_span(self, name: str, cat: str,
                  t0_s: float, t1_s: float) -> None:
         """Record a span from two `time.perf_counter()` readings the
-        caller already took (the feed's existing block timers) —
+        caller already took (the driver's boundary-sync timer) —
         perf_counter and perf_counter_ns share one clock, so no second
-        timestamp is paid."""
+        timestamp is paid. Ring only: an annotation cannot be
+        backdated."""
         self._append((name, cat, (t0_s * 1e9 - self._epoch_ns) / 1e3,
                       max(0.0, (t1_s - t0_s) * 1e6),
-                      threading.get_ident(), "X"))
+                      threading.get_ident(), "X", None))
 
     def instant(self, name: str, cat: str = "host") -> None:
         """A zero-duration marker (Chrome-trace "i" event)."""
         self._append((name, cat,
                       (time.perf_counter_ns() - self._epoch_ns) / 1e3,
-                      0.0, threading.get_ident(), "i"))
+                      0.0, threading.get_ident(), "i", None))
 
-    @contextmanager
-    def span(self, name: str, cat: str = "host"):
-        tok = self.begin(name, cat)
-        try:
-            yield
-        finally:
-            self.end(tok)
+    def span(self, name: str, cat: str = "host",
+             seq: Optional[int] = None) -> "_Span":
+        """A span in THIS ring (and the profiler session, if one is
+        open)."""
+        return _Span(self, name, cat, seq, _annotation())
 
     def _append(self, ev: Tuple) -> None:
         with self._lock:
@@ -128,7 +143,7 @@ class Tracer:
         """Chrome-trace event dicts (the `traceEvents` array)."""
         out: List[Dict[str, Any]] = []
         tids = set()
-        for name, cat, ts, dur, tid, ph in self.events():
+        for name, cat, ts, dur, tid, ph, seq in self.events():
             tids.add(tid)
             ev: Dict[str, Any] = {"name": name, "cat": cat, "ph": ph,
                                   "ts": round(ts, 3),
@@ -137,6 +152,8 @@ class Tracer:
                 ev["dur"] = round(dur, 3)
             else:
                 ev["s"] = "t"           # instant scope: thread
+            if seq is not None:
+                ev["args"] = {"seq": seq}
             out.append(ev)
         # thread-name metadata so Perfetto labels the tracks
         names = {t.ident: t.name for t in threading.enumerate()}
@@ -156,7 +173,8 @@ class Tracer:
             "otherData": {
                 "producer": "veles_tpu.telemetry.tracer",
                 "clock": "perf_counter_ns (us since epoch_unix)",
-                "epoch_unix": round(self._epoch_unix, 6),
+                "epoch_unix": round(self._epoch_unix_ns / 1e9, 6),
+                "epoch_unix_ns": self._epoch_unix_ns,
                 "recorded": self._n,
                 "dropped": self.dropped,
             },
@@ -184,9 +202,8 @@ def install(capacity: int = 0) -> Tracer:
 
 
 def active() -> Optional[Tracer]:
-    """The installed tracer, or None (tracing off). Hot paths capture
-    this ONCE and None-check per record — the pre-bound-handle
-    contract."""
+    """The installed ring, or None (`--trace PATH` off). For exporters
+    and for `add_span`/`instant`; spans go through `span()`."""
     return _ACTIVE
 
 
@@ -197,20 +214,68 @@ def uninstall() -> Optional[Tracer]:
     return tr
 
 
-@contextmanager
-def span(name: str, cat: str = "host"):
-    """Convenience span for COLD paths (no-op when tracing is off).
-    Hot loops pre-bind `active()` instead — this helper pays a module
-    lookup per call."""
-    tr = _ACTIVE
-    if tr is None:
-        yield
-        return
-    tok = tr.begin(name, cat)
-    try:
-        yield
-    finally:
-        tr.end(tok)
+#: jax.profiler.TraceAnnotation, bound the first time jax is found imported
+_ANNOTATION = None
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation` when jax is already imported and a
+    profiler session is open, else None. This module imports nothing of
+    jax itself (jax-free parents record here too)."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        jax = sys.modules.get("jax")
+        ann = getattr(getattr(jax, "profiler", None), "TraceAnnotation",
+                      None)
+        if ann is None:
+            return None
+        _ANNOTATION = ann
+    return ann if ann.is_enabled() else None
+
+
+class _Span:
+    """One open span: a ring event, a profiler annotation, or both."""
+
+    __slots__ = ("_tr", "_tok", "_ann")
+
+    def __init__(self, tr: Optional[Tracer], name: str, cat: str,
+                 seq: Optional[int], ann) -> None:
+        self._tr = tr
+        self._tok = (name, cat, seq)
+        if ann is None:
+            self._ann = None
+        elif seq is None:
+            self._ann = ann(name)
+        else:
+            self._ann = ann(name, seq=seq)
+
+    def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tr is not None:
+            self._tok = self._tr.begin(*self._tok)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._tr is not None:
+            self._tr.end(self._tok)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+#: what `span()` returns when nothing records
+_OFF = contextlib.nullcontext()
+
+
+def span(name: str, cat: str = "host", seq: Optional[int] = None):
+    """THE producer call: a span in the installed ring (`--trace PATH`)
+    and in any open profiler session. `seq` is the batch's or step's
+    sequence number. With neither on it returns a shared no-op."""
+    tr, ann = _ACTIVE, _annotation()
+    if tr is None and ann is None:
+        return _OFF
+    return _Span(tr, name, cat, seq, ann)
 
 
 # -- profile windows ----------------------------------------------------------

@@ -476,12 +476,15 @@ class StandardWorkflow(Workflow):
         from veles_tpu.telemetry import metrics as _tmetrics
         from veles_tpu.telemetry import tracer as _ttracer
         fault_plan = active_plan()   # None in production: zero per-step cost
-        # telemetry plane (docs/OBSERVABILITY.md): the tracer handle and
-        # the metric instruments are PRE-BOUND here, outside the loop —
-        # the hot path pays None checks and float adds, never a name
-        # lookup (the velint hot-metric contract). tr is None when no
-        # --trace is active; the profile controller's disarmed on_step
-        # is one attribute check.
+        # telemetry plane (docs/OBSERVABILITY.md): the metric instruments
+        # are PRE-BOUND here, outside the loop — the hot path pays float
+        # adds, never a name lookup (the velint hot-metric contract).
+        # Spans go through _ttracer.span (the --trace ring AND any open
+        # profiler session; a shared no-op when neither is on). tr is
+        # the ring alone, for the one span no `with` can hold: the
+        # in-flight "step" window. The profile controller's disarmed
+        # on_step is one attribute check.
+        span = _ttracer.span
         tr = _ttracer.active()
         prof = _ttracer.profile_controller()
         mh = _tmetrics.step_handles()
@@ -550,43 +553,30 @@ class StandardWorkflow(Workflow):
             t_epoch = t_iter
             while not bool(dec.complete):
                 prof.on_step(step_idx)
-                if tr is not None:
-                    tok = tr.begin("feed.next", "feed")
-                b = feed.next()
-                if tr is not None:
-                    tr.end(tok)
+                with span("feed.next", "feed"):
+                    b = feed.next()
                 x, y, w = b.x, b.y, b.w
                 if tr is not None and step_tok is not None:
                     tr.end(step_tok)     # step k-1's window closes at
                     step_tok = None      # the next dispatch
                 if b.minibatch_class == TRAIN:
-                    if tr is not None:
-                        tok = tr.begin("train.dispatch", "step")
+                    # the step records its own train.dispatch span
                     state, (loss, n_err) = step.train(state, x, y, w)
                     if ch is not None:
                         # the exchange rides inside the step just
-                        # dispatched; count its modeled bytes now and
-                        # mark the step on the timeline (an instant:
-                        # its device duration is not host-observable
-                        # without a sync — docs/OBSERVABILITY.md)
+                        # dispatched; count its modeled bytes now (when
+                        # it ran on the device, only a profiler capture
+                        # says: the collective ops under the step's
+                        # grad_exchange / param_gather scopes)
                         ch.dcn.inc(ch.dcn_bytes)
                         ch.ici.inc(ch.ici_bytes)
                         ch.ag_dcn.inc(ch.ag_dcn_bytes)
                         ch.ag_ici.inc(ch.ag_ici_bytes)
-                        if tr is not None:
-                            tr.instant(ch.mark, "collective")
-                    if tr is not None:
-                        tr.end(tok)
-                        step_tok = tr.begin("step", "step")
                     if fault_plan is not None and fault_plan.nan_at_step():
                         loss = float("nan")   # deterministic divergence
                 else:
-                    if tr is not None:
-                        tok = tr.begin("eval.dispatch", "step")
-                    loss, n_err = step.evaluate(state, x, y, w)
-                    if tr is not None:
-                        tr.end(tok)
-                        step_tok = tr.begin("step", "step")
+                    with span("eval.dispatch", "step"):
+                        loss, n_err = step.evaluate(state, x, y, w)
                     # fused-mode confusion accumulation (the granular
                     # graph's evaluator fills it per minibatch; without
                     # this the confusion plot would silently skip).
@@ -604,6 +594,8 @@ class StandardWorkflow(Workflow):
                         if m is not None:
                             acc_conf = (m if acc_conf is None
                                         else acc_conf + m)
+                if tr is not None:
+                    step_tok = tr.begin("step", "step")
                 # step losses are weighted MEANS over the minibatch; scale
                 # by the batch's valid-row weight so the class-pass total
                 # is the EXACT weighted mean (a wrapped final minibatch
@@ -669,21 +661,17 @@ class StandardWorkflow(Workflow):
                     # the heartbeat, which carries these counters to the
                     # supervisor's exit report
                     self.feed_stats = feed.stats()
-                    # the one registry mirrors the feed's counters (the
-                    # feed stays the producer) and the epoch-boundary
-                    # rates; a JSONL sink (if installed) gets one line
-                    # per epoch for offline analysis
-                    _tmetrics.mirror_feed(self.feed_stats)
+                    # the feed writes its own counters to the one
+                    # registry; the epoch-boundary rates go in here, and
+                    # a JSONL sink (if installed) gets one line per
+                    # epoch for offline analysis
                     t_ep = _time.perf_counter()
                     if ep_examples and t_ep > t_epoch:
                         mh.examples_per_s.set(
                             ep_examples / (t_ep - t_epoch))
                     ep_examples, t_epoch = 0.0, t_ep
-                if tr is not None:
-                    tok = tr.begin("decision", "bookkeeping")
-                dec.run()
-                if tr is not None:
-                    tr.end(tok)
+                with span("decision", "bookkeeping"):
+                    dec.run()
                 if b.epoch_ended:
                     mh.epoch.set(dec.epoch_number)
                     _tmetrics.flush_installed(
@@ -705,29 +693,22 @@ class StandardWorkflow(Workflow):
                 # gating is applied here by hand: same improved-gated
                 # behavior as granular mode (run_fused's contract)
                 if self.snapshotter is not None and bool(dec.improved):
-                    if tr is not None:
-                        tok = tr.begin("snapshot", "bookkeeping")
-                    step.write_back(state)
-                    self.snapshotter.run()
-                    if tr is not None:
-                        tr.end(tok)
+                    with span("snapshot", "bookkeeping"):
+                        step.write_back(state)
+                        self.snapshotter.run()
                 # NOW produce batch k+1 and issue its async put: the
                 # step dispatched above is still executing on device,
                 # so the H2D transfer hides under it — and the snapshot
                 # (if any) already pickled the pristine loader cursor
                 if not bool(dec.complete):
-                    if tr is not None:
-                        tok = tr.begin("feed.prefetch", "feed")
-                    feed.prefetch()
-                    if tr is not None:
-                        tr.end(tok)
+                    with span("feed.prefetch", "feed"):
+                        feed.prefetch()
         finally:
             if tr is not None and step_tok is not None:
                 tr.end(step_tok)
             prof.finalize()
             feed.stop()
             self.feed_stats = feed.stats()
-            _tmetrics.mirror_feed(self.feed_stats)
             loader.on_device = prev_on_device
             if wire is not None and hasattr(loader, "set_emit") \
                     and prev_emit is not None:
