@@ -356,6 +356,88 @@ def test_uint8_wire_false_pins_float_emission(tmp_path):
     assert wf.loader.emit == "uint8"              # restored afterwards
 
 
+def _boundary_feed(out, restored=None):
+    """16 validation + 48 train rows at batch 16 (a 4-batch epoch,
+    reshuffled and flipped anew each epoch, three batches of lookahead)
+    behind the feed as `_run_with_step` builds it."""
+    from veles_tpu.loader import memmap as mm
+    if restored is None:
+        prng.seed_all(29)
+        loader = mm.MemmapImageLoader(
+            data_path=out, minibatch_size=16, mean_normalize=False,
+            emit="uint8", hflip=True, n_workers=2, prefetch=3)
+    else:
+        loader = restored
+    loader.initialize(device=None)
+    return DeviceFeed(loader, put=None, ahead=1)
+
+
+def _consume(feed, n, snapshot_after=()):
+    """`n` passes of the driver's loop; (batches, pickles taken in the
+    snapshot window after the named passes)."""
+    import pickle
+    ld, got, blobs = feed.loader, [], {}
+    for k in range(n):
+        b = feed.next()
+        got.append((b.seq, b.minibatch_class, b.last_minibatch,
+                    b.epoch_ended, np.array(b.x), np.array(b.y),
+                    np.array(b.w), ld.minibatch_class,
+                    bool(ld.last_minibatch), bool(ld.epoch_ended)))
+        if k in snapshot_after:
+            blobs[k] = pickle.dumps((ld, prng.snapshot_registry()))
+        feed.prefetch()
+    return got, blobs
+
+
+@pytest.mark.parametrize("after", [0, 1, 2, 3, 4])
+def test_resume_near_the_epoch_boundary_is_exact(tmp_path, after,
+                                                 monkeypatch):
+    """A loader pickled in the snapshot window after batch `after` (the
+    last three places of a 4-batch epoch hold lookahead into the next
+    one; 3 is the rollover, 4 the next epoch's first place) delivers the
+    remaining batches bit for bit as the uninterrupted run does, and the
+    next epoch's order, held in the pickle, is not drawn again."""
+    import pickle
+
+    from veles_tpu.loader import memmap as mm
+    rng = np.random.RandomState(4)
+    out = mm.pack_arrays(
+        str(tmp_path / "resume"),
+        rng.randint(0, 256, (64, 6, 6, 3), dtype=np.uint8),
+        (np.arange(64) % 4).astype(np.int64), [0, 16, 48], shard_mb=0.002)
+    feed = _boundary_feed(out)
+    try:
+        whole, blobs = _consume(feed, 12, snapshot_after=(after,))
+    finally:
+        feed.stop()
+    assert [b[0] for b in whole] == list(range(12))
+
+    loader, registry = pickle.loads(blobs[after])
+    prng.restore_registry(registry)
+    assert loader._pending == {} and loader._cursor == (after + 1) % 4
+    # the fill at place 2 looked ahead to place 5, epoch 1's first TRAIN
+    # batch (place 4 is its validation batch: no order needed)
+    held = sorted(loader._orders)
+    assert held == ([1] if after == 2 else [])
+    drawn = []
+    real = np.random.RandomState
+    monkeypatch.setattr(
+        np.random, "RandomState",
+        lambda seed=None: drawn.append(seed) or real(seed))
+    feed = _boundary_feed(out, restored=loader)
+    try:
+        rest, _ = _consume(feed, 11 - after)
+    finally:
+        feed.stop()
+    for a, b in zip(whole[after + 1:], rest, strict=True):
+        assert a[:4] == b[:4] and a[7:] == b[7:]
+        for u, v in zip(a[4:7], b[4:7]):
+            np.testing.assert_array_equal(u, v)
+    epochs_drawn = [int(s[1]) for s in drawn]
+    assert len(set(epochs_drawn)) == len(epochs_drawn)    # each once
+    assert not set(epochs_drawn) & set(held) and 0 not in epochs_drawn
+
+
 def test_feed_ahead_clamped_when_snapshotting(tmp_path):
     """feed_ahead >= 2 would leave pending batches across the snapshot
     window (a restore would skip them): with a live snapshotter the run

@@ -210,14 +210,13 @@ def test_hflip_train_only_and_seeded(tmp_path):
     raw = data.astype(np.float32) / 127.5 - 1.0
 
     flipped_any = unflipped_any = 0
-    # 1 validation + 2 train batches; the epoch's LAST batch is excluded
-    # because run() rolls epoch_number, which legitimately re-draws the
-    # flip coins for a late re-produce
-    for _ in range(3):
+    # 1 validation + 3 train batches: the whole epoch (the coins belong
+    # to the batch's epoch, which a re-produce names)
+    for _ in range(4):
         loader.run()
         idx = loader.minibatch_indices.mem
         x = loader.minibatch_data.mem
-        again = loader._produce(idx)[0]     # re-produce: must match exactly
+        again = loader._produce(idx, 0)[0]  # re-produce: must match exactly
         np.testing.assert_array_equal(x, again)
         for row, i in zip(x, idx):
             if np.array_equal(row, raw[i]):
@@ -307,3 +306,174 @@ def test_native_gather_matches_numpy(tmp_path):
                 np.testing.assert_array_equal(
                     xa, xb, err_msg=f"emit={emit} hflip={hflip}")
                 np.testing.assert_array_equal(ya, yb)
+
+
+# -- the lookahead runs on across the epoch boundary (ISSUE 25) ------------------
+
+
+def boundary_loader(out, prefetch, seed=11, before_initialize=None, **kw):
+    """16 validation + 48 train rows at batch 16: a 4-batch epoch of raw
+    bytes."""
+    prng.seed_all(seed)
+    loader = mm.MemmapImageLoader(
+        data_path=out, minibatch_size=16, mean_normalize=False,
+        emit="uint8", n_workers=2, prefetch=prefetch, **kw)
+    if before_initialize is not None:
+        before_initialize(loader)
+    loader.initialize(device=None)
+    return loader
+
+
+def delivered(loader):
+    """Everything one run() hands the rest of the system."""
+    return {"x": loader.minibatch_data.mem.copy(),
+            "y": loader.minibatch_labels.mem.copy(),
+            "valid": loader.minibatch_valid.mem.copy(),
+            "indices": loader.minibatch_indices.mem.copy(),
+            "meta": (loader.minibatch_class, bool(loader.last_minibatch),
+                     bool(loader.epoch_ended), loader.epoch_number,
+                     loader.batch_seq)}
+
+
+def assert_same_batch(a, b):
+    assert a["meta"] == b["meta"]
+    for k in ("x", "y", "valid", "indices"):
+        np.testing.assert_array_equal(a[k], b[k], err_msg=f"{k} {a['meta']}")
+
+
+def test_every_fill_after_the_first_finds_a_future(tmp_path):
+    """Over three epochs no fill but the run's first gathers on the
+    caller's thread: the first batch of each later epoch was submitted
+    by the last fills of the epoch before, and `_pending` never holds
+    more than `prefetch` futures."""
+    import threading
+    out, _data, _labels = make_packed(tmp_path)
+    made_on = {}
+
+    def watch(loader):          # (the shape probe submits lookahead too)
+        inner = loader._produce_one
+
+        def spy(indices, seq, epoch):
+            made_on.setdefault(seq, []).append(
+                threading.current_thread().name)
+            return inner(indices, seq, epoch)
+        loader._produce_one = spy
+
+    loader = boundary_loader(out, prefetch=3, before_initialize=watch)
+    epochs = 3
+    try:
+        for k in range(4 * epochs):
+            loader.run()
+            assert loader.batch_seq == k
+            assert len(loader._pending) <= 3
+    finally:
+        loader.stop()
+    me = threading.current_thread().name
+    # seq 0 twice on this thread: the first run() refills the probe
+    assert made_on[0] == [me, me]
+    for seq in range(1, 4 * epochs):
+        assert len(made_on[seq]) == 1 and made_on[seq][0] != me, (
+            seq, made_on[seq])
+    # 12 runs + the shape probe asked; 13 fills were answered
+    assert loader.lookahead_ready + loader.lookahead_waited == 13
+    assert loader.lookahead_cross_epoch >= 3 * (epochs - 1)
+    assert loader.lookahead_cross_epoch == 3 * epochs     # the last too
+
+
+@pytest.mark.parametrize("case", ["shuffle_train", "balanced_train",
+                                  "hflip"])
+def test_batches_do_not_depend_on_prefetch(tmp_path, case):
+    """Rows, labels, valid mask and the per-batch bookkeeping over three
+    epochs (two boundaries) are the same for `prefetch` 0, 1 and 3, and
+    the order is drawn without touching the shared `prng.get()` stream,
+    so WHEN it is drawn cannot matter."""
+    out, _data, labels = make_packed(tmp_path)
+    kw = {"shuffle_train": dict(shuffle_train=True),
+          "balanced_train": dict(balanced_train=True),
+          "hflip": dict(shuffle_train=True, hflip=True)}[case]
+    runs = {}
+    for prefetch in (0, 1, 3):
+        loader = boundary_loader(out, prefetch, **kw)
+        shared = prng.get().state.get_state()[1].copy()
+        try:
+            got = []
+            for _ in range(12):
+                loader.run()
+                got.append(delivered(loader))
+        finally:
+            loader.stop()
+        np.testing.assert_array_equal(prng.get().state.get_state()[1],
+                                      shared)
+        runs[prefetch] = got
+    for prefetch in (1, 3):
+        for a, b in zip(runs[0], runs[prefetch]):
+            assert_same_batch(a, b)
+    train = [np.concatenate([b["indices"] for b in runs[0][e + 1:e + 4]])
+             for e in (0, 4, 8)]
+    # every epoch has an order of its own
+    assert not np.array_equal(train[0], train[1])
+    assert not np.array_equal(train[1], train[2])
+    if case != "balanced_train":
+        for order in train:         # each a permutation of the train set
+            np.testing.assert_array_equal(np.sort(order),
+                                          np.arange(16, 64))
+    if case == "hflip":             # the coins are the batch's epoch's
+        flips = [sum(not np.array_equal(b["x"][r], _data[i])
+                     for b in runs[0][e + 1:e + 4]
+                     for r, i in enumerate(b["indices"]))
+                 for e in (0, 4, 8)]
+        assert all(0 < f < 48 for f in flips), flips
+
+
+def test_a_plain_loader_draws_the_same_order(tmp_path):
+    """The order is the Loader's, not the produce pool's: a
+    FullBatchLoader over the same rows and seed walks the same
+    indices."""
+    from veles_tpu.loader.fullbatch import FullBatchLoader
+    out, data, labels = make_packed(tmp_path)
+    loader = boundary_loader(out, prefetch=3)
+    prng.seed_all(11)
+    plain = FullBatchLoader(minibatch_size=16, on_device=False)
+    plain.load_data = lambda: plain.bind_arrays(data, labels, 0, 16, 48)
+    plain.initialize(device=None)
+    try:
+        for _ in range(9):
+            loader.run()
+            plain.run()
+            np.testing.assert_array_equal(loader.minibatch_indices.mem,
+                                          plain.minibatch_indices.mem)
+            assert loader.epoch_number == plain.epoch_number
+    finally:
+        loader.stop()
+
+
+@pytest.mark.parametrize("how", ["stop", "set_emit", "foreign_indices"])
+def test_dropping_the_lookahead_drops_the_next_epochs_too(tmp_path, how):
+    from concurrent.futures import wait
+    out, _data, _labels = make_packed(tmp_path)
+    loader = boundary_loader(out, prefetch=3)
+    ref = boundary_loader(out, prefetch=0)
+    try:
+        for _ in range(3):
+            loader.run()
+            ref.run()
+        # at the epoch's last place: two of the three belong to epoch 1
+        assert sorted(loader._pending) == [3, 4, 5]
+        assert loader.lookahead_cross_epoch == 2
+        futures = list(loader._pending.values())
+        if how == "stop":
+            loader.stop()
+        elif how == "set_emit":
+            loader.set_emit("float32")
+            ref.set_emit("float32")
+        else:
+            loader.fill_minibatch(np.arange(16, dtype=np.int64)[::-1])
+        assert loader._pending == {}
+        assert not wait(futures, timeout=10).not_done   # cancelled, or made
+        for _ in range(3):          # over the boundary, from nothing
+            loader.run()
+            ref.run()
+            assert_same_batch(delivered(loader), delivered(ref))
+    finally:
+        loader.stop()
+        ref.stop()

@@ -327,7 +327,8 @@ def flat():
 
 @pytest.mark.parametrize("delay,moves", [(0.0, "ready"), (0.1, "waited")])
 def test_the_lookahead_counters_add_up(delay, moves):
-    """Every fill asks once and consumes one produced batch. A fast
+    """Every fill asks once and consumes one produced batch; the epoch's
+    last fill submits one more, for the next epoch's first batch. A fast
     producer is ready when asked; a slow one, asked at once, is waited
     for."""
     ld = RowsLoader(delay=delay, n_workers=1, prefetch=1)
@@ -338,19 +339,22 @@ def test_the_lookahead_counters_add_up(delay, moves):
             assert ld.batch_seq == k
             assert ld.minibatch_data.mem[0, 0] == 10 * k
             while not delay and not all(
-                    f.done() for _i, f in list(ld._pending.values())):
+                    f.done() for f in list(ld._pending.values())):
                 time.sleep(0.001)           # the worker finishes meanwhile
     finally:
         ld.stop()
     asked = ld.lookahead_ready + ld.lookahead_waited
     assert asked == 13                      # 12 runs + the shape probe
-    assert ld.batches_produced == asked
+    assert ld.lookahead_cross_epoch == 1
+    # (stop() cancels the next epoch's batch unless it was being made)
+    assert asked <= ld.batches_produced <= asked + 1
     other = "waited" if moves == "ready" else "ready"
     assert getattr(ld, "lookahead_" + moves) >= 10
     assert getattr(ld, "lookahead_" + other) <= 3
     assert ld.produce_s >= 13 * delay
     f = flat()
-    assert f["veles_loader_batches_produced_total"] == 13
+    assert f["veles_loader_batches_produced_total"] == ld.batches_produced
+    assert f["veles_loader_lookahead_cross_epoch_total"] == 1
     assert f["veles_loader_lookahead_ready_total"] == ld.lookahead_ready
     assert f["veles_loader_lookahead_waited_total"] == ld.lookahead_waited
     assert f["veles_loader_produce_seconds_total"] == pytest.approx(

@@ -2,7 +2,8 @@
 
 Parity: reference `veles/loader/base.py` — three sample classes
 (TEST=0, VALIDATION=1, TRAIN=2, the reference's ordering), per-epoch global
-shuffle of the train set with the seeded PRNG, `minibatch_class` /
+shuffle of the train set (a function of the loader's seeded order seed and
+the epoch number: `_train_order`), `minibatch_class` /
 `last_minibatch` / `epoch_ended` / `epoch_number` bookkeeping consumed by
 the Decision unit, and `IDistributable`-shaped index partitioning (on TPU
 the data-parallel shard split — see `shard_batch`).
@@ -52,6 +53,8 @@ class Loader(AcceleratedUnit, IDistributable):
     batch_seq = -1
     #: batches delivered by the epochs already finished
     _seq_base = 0
+    #: seed of the train orders (drawn once by initialize())
+    _order_seed = 0
 
     def __init__(self, workflow=None, minibatch_size: int = 100,
                  shuffle_train: bool = True, on_device: bool = True,
@@ -76,8 +79,12 @@ class Loader(AcceleratedUnit, IDistributable):
         #: shared gate object for GD units: True on non-train minibatches
         self.not_train = Bool(False)
         self.epoch_number = 0
-        self._order: List[int] = []     # (class, offset) cursor state
         self._cursor = 0
+        #: train orders of epochs still to come, by epoch number: drawn
+        #: when a lookahead reaches past the schedule's end, adopted at
+        #: the rollover; pickled, so a snapshot taken near an epoch's
+        #: end resumes into the same next epoch
+        self._orders: dict = {}
         self._indices_per_class: List[np.ndarray] = [
             np.empty(0, np.int64)] * 3
 
@@ -109,6 +116,7 @@ class Loader(AcceleratedUnit, IDistributable):
 
     def __setstate__(self, d):
         self.__dict__.update(d)
+        self.__dict__.setdefault("_orders", {})     # an older snapshot
         #: unpickled from a snapshot: the next initialize() preserves the
         #: carried schedule/cursor/shuffle (explicit marker — a second
         #: initialize() of a LIVE loader must still re-derive them)
@@ -149,8 +157,11 @@ class Loader(AcceleratedUnit, IDistributable):
                 self._indices_per_class[cls] = np.arange(
                     offset, offset + n, dtype=np.int64)
                 offset += n
-            #: pristine train index list: balanced sampling redraws from it
+            #: pristine train index list: every epoch's order is drawn
+            #: from it
             self._train_base = self._indices_per_class[TRAIN].copy()
+            self._order_seed = int(prng.get().randint(0, 2 ** 31))
+            self._orders = {}
             self._start_epoch()
         self.total_samples = sum(self.class_lengths)
         # Shape-probe fill: downstream units size their buffers off
@@ -158,16 +169,24 @@ class Loader(AcceleratedUnit, IDistributable):
         # minibatch Arrays in Loader.initialize too). The first run() refills
         # the same indices, so this is idempotent.
         cls, b, _ = self._schedule[0]
-        idx = self._indices_per_class[cls]
-        take = np.arange(0, self.minibatch_size) % len(idx)
-        self.fill_minibatch(idx[take])
-        self.minibatch_indices.reset(idx[take])
-        self.minibatch_valid.reset(
-            (np.arange(self.minibatch_size) < len(idx))
-            .astype(np.float32))
+        chosen, valid = self._batch_rows(cls, b, self._indices_per_class[cls])
+        self.fill_minibatch(chosen)
+        self.minibatch_indices.reset(chosen)
+        self.minibatch_valid.reset(valid)
         return super().initialize(device=device, **kwargs)
 
-    def _start_epoch(self) -> None:
+    def _train_order(self, epoch: int) -> np.ndarray:
+        """The train index order of `epoch`: a function of the order
+        seed and the epoch number alone, in an array of its own. It is
+        the same whenever it is asked for (at the rollover, or up to
+        `prefetch` fills earlier by a lookahead that reaches into the
+        epoch) and takes nothing from the shared `prng.get()` stream,
+        whose draws belong to the units between two rollovers. Held in
+        `_orders` until the rollover adopts it."""
+        order = self._orders.get(epoch)
+        if order is not None:
+            return order
+        gen = np.random.RandomState([self._order_seed, epoch])
         if self.balanced_train and self.class_lengths[TRAIN]:
             labels = self.train_labels()
             if labels is None:
@@ -177,10 +196,18 @@ class Loader(AcceleratedUnit, IDistributable):
             counts = np.bincount(labels).astype(np.float64)
             p = 1.0 / counts[labels]
             p /= p.sum()
-            pick = prng.get().choice(len(labels), size=len(labels), p=p)
-            self._indices_per_class[TRAIN] = self._train_base[pick]
+            order = self._train_base[
+                gen.choice(len(labels), size=len(labels), p=p)]
         elif self.shuffle_train:
-            prng.get().shuffle(self._indices_per_class[TRAIN])
+            order = self._train_base[gen.permutation(len(self._train_base))]
+        else:
+            order = self._train_base
+        self._orders[epoch] = order
+        return order
+
+    def _start_epoch(self) -> None:
+        self._indices_per_class[TRAIN] = self._train_order(self.epoch_number)
+        del self._orders[self.epoch_number]
         self._schedule = []
         for cls in (TEST, VALIDATION, TRAIN):
             n = self.class_lengths[cls]
@@ -191,6 +218,13 @@ class Loader(AcceleratedUnit, IDistributable):
                 self._schedule.append((cls, b, b == n_batches - 1))
         self._cursor = 0
 
+    def _batch_rows(self, cls: int, b: int, idx: np.ndarray):
+        """(global indices, 0/1 valid mask) of batch `b` of class `cls`
+        under the index list `idx`: the last batch of a class wraps to
+        the list's start, and the wrapped rows are not valid."""
+        at = np.arange(b * self.minibatch_size, (b + 1) * self.minibatch_size)
+        return idx[at % len(idx)], (at < len(idx)).astype(np.float32)
+
     @property
     def next_batch_seq(self) -> int:
         """The sequence number the next run()'s batch will carry."""
@@ -200,17 +234,12 @@ class Loader(AcceleratedUnit, IDistributable):
         # (overrides AcceleratedUnit.run: one code path, host index math)
         cls, b, last = self._schedule[self._cursor]
         self.batch_seq = self.next_batch_seq
-        idx = self._indices_per_class[cls]
-        lo = b * self.minibatch_size
-        take = np.arange(lo, lo + self.minibatch_size) % len(idx)
-        chosen = idx[take]
+        chosen, valid = self._batch_rows(cls, b, self._indices_per_class[cls])
         self.minibatch_class = cls
         self.last_minibatch <<= last
         self.not_train <<= (cls != TRAIN)
         self.minibatch_indices.reset(chosen)
-        self.minibatch_valid.reset(
-            (np.arange(lo, lo + self.minibatch_size) < len(idx))
-            .astype(np.float32))
+        self.minibatch_valid.reset(valid)
         self.fill_minibatch(chosen)
         if self.on_device and self.device is not None \
                 and getattr(self.device, "backend_name", "") == "xla":
@@ -220,13 +249,11 @@ class Loader(AcceleratedUnit, IDistributable):
         at_end = self._cursor >= len(self._schedule)
         self.epoch_ended <<= at_end
         if at_end:
-            # Produce-thread readers (the hflip coin hash) never run
-            # across an epoch boundary: fill_minibatch's lookahead
-            # stops at the schedule end and PrefetchingLoader.run
-            # clears every pending future at rollover, so epoch_number
-            # is stable while any producer is live — a happens-before
-            # the static pass cannot see (docs/ANALYSIS.md blind spots).
-            # velint: disable=shared-write-no-lock
+            # Only this thread reads epoch_number. A producer is handed
+            # the epoch of its batch together with the batch's indices
+            # (PrefetchingLoader._produce_one), and the lookahead of an
+            # epoch's last fills already works for the next epoch: what
+            # the rollover changes, no produce thread reads.
             self.epoch_number += 1
             self._seq_base += len(self._schedule)
             self._start_epoch()
@@ -257,12 +284,16 @@ class Loader(AcceleratedUnit, IDistributable):
 
 class PrefetchingLoader(Loader):
     """Loader whose minibatch production runs on background threads with
-    `prefetch` batches of exact lookahead (the within-epoch schedule is
-    deterministic, so future index sets are known). Subclasses implement
-    `_produce_batch(indices) -> (x, y)` — an image decode, a memmap
-    gather, … — and inherit the overlap machinery: host input prep runs
-    concurrently with device compute (the property that matters on TPU;
-    SURVEY.md §2.7)."""
+    `prefetch` batches of exact lookahead at EVERY fill. The schedule is
+    deterministic and an epoch's train order is a function of the order
+    seed and the epoch number (`Loader._train_order`), so future index
+    sets are known across the epoch boundary too: the lookahead of an
+    epoch's last `prefetch` fills produces the next epoch's first
+    batches, and the rollover finds them in `_pending` under their
+    batch numbers. Subclasses implement `_produce_batch(indices) ->
+    (x, y)` — an image decode, a memmap gather, … — and inherit the
+    overlap machinery: host input prep runs concurrently with device
+    compute (the property that matters on TPU; SURVEY.md §2.7)."""
 
     def __init__(self, workflow=None, n_workers: int = 2,
                  prefetch: int = 2, hflip: bool = False,
@@ -276,6 +307,7 @@ class PrefetchingLoader(Loader):
         self.hflip = hflip
         self._hflip_seed = 0
         self._pool = None
+        #: lookahead in production: batch number -> future
         self._pending: dict = {}
         #: multi-host input sharding: when set (by run_fused on a mesh
         #: spanning processes), `local_rows_fn(n) -> bool (n,)` marks the
@@ -302,24 +334,30 @@ class PrefetchingLoader(Loader):
         # schedule/cursor preservation in Loader.initialize)
         if self.hflip and not getattr(self, "_restored", False):
             self._hflip_seed = int(prng.get("hflip").randint(0, 2 ** 31))
+        # a live loader initialized again draws its orders anew: what
+        # is still pending was produced for the old ones
+        self._drop_pending()
         return super().initialize(device=device, **kwargs)
 
     def _produce_batch(self, indices: np.ndarray):
         raise NotImplementedError
 
-    def _flip_mask(self, indices: np.ndarray) -> Optional[np.ndarray]:
+    def _flip_mask(self, indices: np.ndarray,
+                   epoch: int) -> Optional[np.ndarray]:
         """Per-(sample, epoch) horizontal-flip coins for TRAIN rows, or
         None when augmentation is off. A stateless integer hash decides
         each coin so produce threads need no shared RNG state and
         re-visits flip identically within an epoch but differently
-        across epochs. Shared by the numpy `_augment` path and the
+        across epochs; `epoch` is the batch's own (a lookahead batch
+        may belong to the epoch after the loader's current one).
+        Shared by the numpy `_augment` path and the
         native gather (loader/memmap.py), which folds the flip into its
         row copy."""
         if not self.hflip:
             return None
         train_lo = self.class_lengths[TEST] + self.class_lengths[VALIDATION]
         h = (indices.astype(np.uint64) * np.uint64(2654435761)
-             + np.uint64(self.epoch_number + 1) * np.uint64(0x9E3779B9)
+             + np.uint64(epoch + 1) * np.uint64(0x9E3779B9)
              + np.uint64(self._hflip_seed))
         h ^= h >> np.uint64(15)
         h *= np.uint64(0x2545F4914F6CDD1D)
@@ -327,21 +365,23 @@ class PrefetchingLoader(Loader):
         flip &= indices >= train_lo
         return flip
 
-    def _augment(self, x: np.ndarray, indices: np.ndarray) -> np.ndarray:
+    def _augment(self, x: np.ndarray, indices: np.ndarray,
+                 epoch: int) -> np.ndarray:
         """Seeded horizontal flip of TRAIN rows (see _flip_mask)."""
         if x.ndim < 3:
             return x
-        flip = self._flip_mask(indices)
+        flip = self._flip_mask(indices, epoch)
         if flip is not None and flip.any():
             x = np.ascontiguousarray(x)
             x[flip] = x[flip, :, ::-1]
         return x
 
-    def _produce_rows(self, indices: np.ndarray):
-        """Materialize rows for exactly these indices (subclass hook for
-        custom gather paths; the default decodes + augments)."""
+    def _produce_rows(self, indices: np.ndarray, epoch: int):
+        """Materialize rows for exactly these indices as epoch `epoch`
+        sees them (subclass hook for custom gather paths; the default
+        decodes + augments)."""
         x, y = self._produce_batch(indices)
-        return self._augment(x, indices), y
+        return self._augment(x, indices, epoch), y
 
     def local_rows_mask(self, n: int) -> np.ndarray:
         """The partition kernel behind `generate_data_for_slave`: which
@@ -358,18 +398,18 @@ class PrefetchingLoader(Loader):
         piece["local_rows"] = self.local_rows_mask(self.minibatch_size)
         return piece
 
-    def _produce(self, indices: np.ndarray):
+    def _produce(self, indices: np.ndarray, epoch: int):
         if self.local_rows_fn is not None:
             mask = self.local_rows_mask(len(indices))
             if not mask.all():
-                x, y = self._produce_rows(indices[mask])
+                x, y = self._produce_rows(indices[mask], epoch)
                 self._count_rows(int(mask.sum()))
                 fx = np.zeros((len(indices),) + x.shape[1:], x.dtype)
                 fy = np.zeros((len(indices),) + y.shape[1:], y.dtype)
                 fx[mask] = x
                 fy[mask] = y
                 return fx, fy
-        x, y = self._produce_rows(indices)
+        x, y = self._produce_rows(indices, epoch)
         self._count_rows(len(indices))
         return x, y
 
@@ -377,22 +417,25 @@ class PrefetchingLoader(Loader):
         """Process-local observability (feed.stats(), veles_loader_*),
         never pickled: seconds inside _produce summed over the produce
         threads and batches it completed (under _count_lock); fills
-        whose lookahead future was done when asked / that had to wait
-        (driver thread); the registry handles, bound when the produce
-        pool starts."""
+        whose lookahead future was done when asked / that had to wait,
+        and futures submitted for a batch of a later epoch (driver
+        thread); the registry handles, bound when the produce pool
+        starts."""
         self.produce_s = 0.0
         self.batches_produced = 0
         self.lookahead_ready = 0
         self.lookahead_waited = 0
+        self.lookahead_cross_epoch = 0
         self._m = None
 
-    def _produce_one(self, indices: np.ndarray, seq: int):
+    def _produce_one(self, indices: np.ndarray, seq: int, epoch: int):
         """`_produce` as the pool (or a fill with no lookahead) runs it:
         one `loader.produce` span and one count per batch, recorded on
-        the thread that does the work."""
+        the thread that does the work. The batch's epoch comes with its
+        indices: a producer reads nothing that a rollover changes."""
         t0 = time.perf_counter()
         with _tracer.span("loader.produce", "loader", seq):
-            out = self._produce(indices)
+            out = self._produce(indices, epoch)
         dt = time.perf_counter() - t0
         with self._count_lock:
             self.produce_s += dt
@@ -407,14 +450,21 @@ class PrefetchingLoader(Loader):
         with self._count_lock:
             self.rows_decoded += n
 
-    def _indices_at(self, cursor: int) -> Optional[np.ndarray]:
-        if cursor >= len(self._schedule):
-            return None
-        cls, b, _ = self._schedule[cursor]
-        idx = self._indices_per_class[cls]
-        lo = b * self.minibatch_size
-        take = np.arange(lo, lo + self.minibatch_size) % len(idx)
-        return idx[take]
+    def _batch_at(self, pos: int):
+        """(indices, epoch) of the batch `pos` places into the current
+        epoch's schedule. Past its end lie the next epochs (every epoch
+        has the same schedule), whose train order is drawn here, now."""
+        ahead, at = divmod(pos, len(self._schedule))
+        epoch = self.epoch_number + ahead
+        cls, b, _ = self._schedule[at]
+        idx = (self._train_order(epoch) if ahead and cls == TRAIN
+               else self._indices_per_class[cls])
+        return self._batch_rows(cls, b, idx)[0], epoch
+
+    def _drop_pending(self) -> None:
+        for fut in self._pending.values():
+            fut.cancel()
+        self._pending.clear()
 
     def fill_minibatch(self, indices: np.ndarray) -> None:
         from concurrent.futures import CancelledError, ThreadPoolExecutor
@@ -425,15 +475,14 @@ class PrefetchingLoader(Loader):
             self._m = _metrics.loader_handles()
         m = self._m
         seq = self.next_batch_seq
-        pend = self._pending.pop(self._cursor, None)
-        # the lookahead future is only valid for the cursor-schedule
-        # indices; a caller feeding different indices (e.g. a master's
-        # apply_data_from_master) must get THOSE indices, not the
-        # prefetched batch
-        fut = (pend[1] if pend is not None
-               and np.array_equal(pend[0], indices) else None)
-        if pend is not None and fut is None:
-            pend[1].cancel()
+        # the lookahead is valid for the schedule's indices only; a
+        # caller feeding different ones (e.g. a master's
+        # apply_data_from_master) must get THOSE indices, and nothing
+        # is produced ahead for a caller the schedule does not describe
+        scheduled = np.array_equal(indices, self._batch_at(self._cursor)[0])
+        if not scheduled:
+            self._drop_pending()
+        fut = self._pending.pop(seq, None)
         if fut is not None and fut.done() and not fut.cancelled():
             self.lookahead_ready += 1
             m.ready.inc()
@@ -441,35 +490,29 @@ class PrefetchingLoader(Loader):
             self.lookahead_waited += 1
             m.waited.inc()
         try:
-            x, y = (fut.result() if fut is not None
-                    else self._produce_one(indices, seq))
+            x, y = (fut.result() if fut is not None else
+                    self._produce_one(indices, seq, self.epoch_number))
         except CancelledError:
             # stop() from another thread (manhole, Ctrl-C handler)
             # cancelled the lookahead mid-fill: produce synchronously so
             # the pump loop winds down cleanly instead of crashing
-            x, y = self._produce_one(indices, seq)
-        for ahead in range(1, self.prefetch + 1):
-            pos = self._cursor + ahead
-            if pos in self._pending:
-                continue
-            nxt = self._indices_at(pos)
-            if nxt is None:
-                break
-            try:
-                self._pending[pos] = (nxt, self._pool.submit(
-                    self._produce_one, nxt, seq + ahead))
-            except RuntimeError:     # pool shut down by concurrent stop()
-                break
+            x, y = self._produce_one(indices, seq, self.epoch_number)
         self.minibatch_data.reset(x)
         self.minibatch_labels.reset(y)
-
-    def run(self) -> None:
-        super().run()
-        if bool(self.epoch_ended):
-            # schedule was rebuilt (new shuffle): drop stale lookahead
-            for _, fut in self._pending.values():
-                fut.cancel()
-            self._pending.clear()
+        if not scheduled:
+            return
+        for ahead in range(1, self.prefetch + 1):
+            if seq + ahead in self._pending:
+                continue
+            nxt, epoch = self._batch_at(self._cursor + ahead)
+            try:
+                self._pending[seq + ahead] = self._pool.submit(
+                    self._produce_one, nxt, seq + ahead, epoch)
+            except RuntimeError:     # pool shut down by concurrent stop()
+                break
+            if epoch != self.epoch_number:
+                self.lookahead_cross_epoch += 1
+                m.cross_epoch.inc()
 
     def set_emit(self, emit: str) -> None:
         """Flip the wire dtype mid-run (the device feed's uint8-wire
@@ -486,9 +529,7 @@ class PrefetchingLoader(Loader):
         # whose result is discarded with the cancelled future.
         # velint: disable=shared-write-no-lock
         self.emit = emit
-        for _, fut in self._pending.values():
-            fut.cancel()
-        self._pending.clear()
+        self._drop_pending()
 
     def stop(self) -> None:
         if self._pool is not None:
@@ -508,8 +549,8 @@ class PrefetchingLoader(Loader):
         d["_pool"] = None
         d["_pending"] = {}
         d["_count_lock"] = None
-        for k in ("_m", "produce_s", "batches_produced",
-                  "lookahead_ready", "lookahead_waited"):
+        for k in ("_m", "produce_s", "batches_produced", "lookahead_ready",
+                  "lookahead_waited", "lookahead_cross_epoch"):
             d.pop(k, None)      # timing floats must not reach a pickle
         d["local_rows_fn"] = None   # step-bound closure: re-wired by run
         return d

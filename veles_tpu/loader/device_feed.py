@@ -57,7 +57,9 @@ Overlap observability: the feed counts time blocked on the loader
 (host pipeline too slow), time inside the put call, batches fed ahead,
 bytes per batch, and whether a popped batch had reached the device
 (`is_ready()`, asked without blocking) — in `stats()` (with the
-loader's produce-thread counters beside them) and, written by the feed
+loader's own counters beside them: `produce_s`, `batches_produced`,
+`lookahead_ready`, `lookahead_waited`, `lookahead_cross_epoch`) and,
+written by the feed
 itself per batch, in the one metrics registry (`veles_feed_*`). Spans
 (`feed.produce` > `loader.run`, `feed.device_put`) carry the batch's
 sequence number and land in the `--trace` ring and in any open profiler
@@ -386,11 +388,13 @@ class DeviceFeed:
             "h2d_ready": self._h2d_ready,
             "h2d_late": self._h2d_late,
             # the loader's own counters (PrefetchingLoader; 0 elsewhere):
-            # produce-thread seconds and batches, and whether the
-            # lookahead future was done when a fill asked for it
+            # produce-thread seconds and batches, whether the lookahead
+            # future was done when a fill asked for it, and futures
+            # submitted for a batch of the next epoch
             "produce_s": round(getattr(ld, "produce_s", 0.0), 6),
             "batches_produced": getattr(ld, "batches_produced", 0),
             "lookahead_ready": getattr(ld, "lookahead_ready", 0),
             "lookahead_waited": getattr(ld, "lookahead_waited", 0),
+            "lookahead_cross_epoch": getattr(ld, "lookahead_cross_epoch", 0),
             "epoch_log": list(self._epoch_log),
         }

@@ -8,7 +8,8 @@ names.
 TPU-first: the decode path is a host-CPU concern; what matters for the
 chip is that input preparation OVERLAPS device compute. `ImageDirectory
 Loader` therefore prefetches the next minibatches on background threads
-(the schedule is deterministic within an epoch, so lookahead is exact) —
+(schedule and train order are known ahead, across the epoch boundary too,
+so lookahead is exact) —
 the analog of the reference's jpegtran-cffi fast path, built on PIL +
 a thread pool instead of a C extension.
 """
@@ -142,14 +143,14 @@ class ImageDirectoryLoader(PrefetchingLoader):
             return None
         return self.path_labels[self._train_base]
 
-    def _produce_rows(self, indices: np.ndarray):
+    def _produce_rows(self, indices: np.ndarray, epoch: int):
         """Decode + seeded hflip + normalize, with augmentation applied
         to the RAW pixels BEFORE normalization — the memmap.py
         convention (a flipped training image is normalized exactly like
         any other; the mean image is not flipped with it), so the uint8
         wire and the float path train the same trajectory. Supersedes
         the base post-normalize `_augment` hook."""
-        return self._decode_batch(indices, self._flip_mask(indices))
+        return self._decode_batch(indices, self._flip_mask(indices, epoch))
 
     def _produce_batch(self, indices: np.ndarray) -> Tuple[np.ndarray,
                                                            np.ndarray]:

@@ -222,7 +222,7 @@ class MemmapImageLoader(PrefetchingLoader):
                       if use else "numpy")
         return use
 
-    def _produce_rows(self, indices: np.ndarray):
+    def _produce_rows(self, indices: np.ndarray, epoch: int):
         """Gather + seeded hflip + normalize, with augmentation applied
         to the RAW BYTES before normalization (a flipped training image
         must be normalized exactly like any other image — the mean image
@@ -231,8 +231,7 @@ class MemmapImageLoader(PrefetchingLoader):
         superseded, so it must not run again. Overriding THIS hook (not
         `_produce`) keeps the base's multi-host local-rows sharding and
         decode accounting."""
-        x, y = self._gather(indices, self._flip_mask(indices))
-        return x, y
+        return self._gather(indices, self._flip_mask(indices, epoch))
 
     def _produce_batch(self, indices: np.ndarray):
         return self._gather(indices, None)

@@ -403,7 +403,10 @@ def register_standard(reg: MetricsRegistry) -> None:
                 "fills whose lookahead future was done when asked")
     reg.counter("veles_loader_lookahead_waited_total",
                 "fills that waited: the lookahead future was still "
-                "running, or there was none (first batch of an epoch)")
+                "running, or there was none (first batch of a run)")
+    reg.counter("veles_loader_lookahead_cross_epoch_total",
+                "lookahead futures submitted for a batch of the next "
+                "epoch (the produce pool runs on across the boundary)")
     reg.gauge("veles_mem_live_bytes", "live jax.Array bytes per device",
               labelnames=("device",))
     reg.gauge("veles_mem_live_bytes_max",
@@ -565,6 +568,7 @@ def loader_handles(reg: Optional[MetricsRegistry] = None
         produced=reg.counter("veles_loader_batches_produced_total"),
         ready=reg.counter("veles_loader_lookahead_ready_total"),
         waited=reg.counter("veles_loader_lookahead_waited_total"),
+        cross_epoch=reg.counter("veles_loader_lookahead_cross_epoch_total"),
     )
 
 
