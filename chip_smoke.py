@@ -676,7 +676,10 @@ def phase_four_chips(seed: int, clock: CompileClock) -> None:
             losses.append(float(loss))
         return state, ev0, losses
 
-    dp = wf.build_fused_step(mesh=mesh)
+    # sharded on request: AlexNet's state (0.75 GB) asks for no ZeRO by
+    # itself, and no benchmark cell runs that path (the default step,
+    # the replicated update, is run_fused's below and vgg16.dp4's)
+    dp = wf.build_fused_step(mesh=mesh, zero_sharding="on")
     say(f"four: dp step mode={dp.mode} zero_active={dp.zero_active} "
         f"({dp.zero_reason}) variant_table={json.dumps(dp.variant_table())}")
     check(dp.mode == "dp" and dp.zero_active, "four: not the dp/ZeRO step")
@@ -735,7 +738,8 @@ def phase_four_chips(seed: int, clock: CompileClock) -> None:
     del state, xb
 
     # the documented multi-chip path end to end (README): the DeviceFeed
-    # puts sharded batches, Decision closes one epoch
+    # puts sharded batches, Decision closes one epoch; zero_sharding is
+    # the default here, so memory decides and the update is replicated
     clock.take()
     t0 = time.time()
     wf.run_fused(mesh=make_mesh())

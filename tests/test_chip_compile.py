@@ -5,9 +5,10 @@ every interpret-mode test were refused on the chip for scoped-VMEM
 overflow and an unsupported gather. The TPU compiler is installed here
 and compiles for a chip that is DESCRIBED, not attached — so the main
 path's kernels at AlexNet's real widths, the generated points the search
-would try, and the whole fused train step (one chip, and the dp/ZeRO
-step over the 2x2 mesh) are compiled here, at no chip time, on every
-tier-1 run. A compile that passes is not a chip run; `chip_smoke.py` is.
+would try, and the whole fused train step (one chip, and the dp step
+over the 2x2 mesh, with the replicated update and with ZeRO) are
+compiled here, at no chip time, on every tier-1 run. A compile that
+passes is not a chip run; `chip_smoke.py` is.
 
 Rules this file keeps (on-chip-measurement guide, section 2): the
 topology is described ONLY inside the module-scoped fixture below —
@@ -256,40 +257,80 @@ def test_local_alexnet_train_step_compiles(one_chip, alexnet, lrn,
     assert 0 < total < 16 << 30, total
 
 
-def test_dp_zero_alexnet_train_step_compiles_for_2x2(topo, alexnet):
-    """The default multi-chip step (`run_fused(mesh=make_mesh())`: dp +
-    ZeRO over four chips, global batch 1024) compiles for the described
-    2x2 mesh with shard_map's varying-axes check ON, asks for the
-    reduce-scatter and the all-gather, and keeps a 1/4 optimizer slice
-    per chip."""
+def _compile_dp_step_for_2x2(topo, alexnet, **step_kw):
+    """(step, lowered, compiled) of AlexNet's dp step over the described
+    2x2 at global batch 1024, shard_map's varying-axes check ON."""
     from jax.sharding import PartitionSpec as P
 
     from veles_tpu.parallel import checkpoint as ck
     from veles_tpu.parallel.mesh import DATA_AXIS, make_mesh
     mesh = make_mesh(topo.devices)
     assert dict(mesh.shape)[DATA_AXIS] == 4
-    step = alexnet.build_fused_step(mesh=mesh, compute_dtype="bfloat16")
-    assert step.mode == "dp" and step.zero_active, step.zero_reason
+    step = alexnet.build_fused_step(mesh=mesh, compute_dtype="bfloat16",
+                                    **step_kw)
+    assert step.mode == "dp"
     args = _abstract_step_args(
         step, BATCH, lambda t: ck._target_shardings(step, t),
         NamedSharding(mesh, P(DATA_AXIS)))
     lowered = jax.jit(step.train_callable(),
                       donate_argnums=(0,)).lower(*args)
+    return step, lowered, lowered.compile()
+
+
+def _n_alexnet_params(alexnet):
+    n_params = sum(int(np.prod(a.shape)) for u in alexnet.forwards
+                   for a in u.param_arrays().values() if a)
+    assert n_params == 62378344
+    return n_params
+
+
+def test_dp_zero_alexnet_train_step_compiles_for_2x2(topo, alexnet):
+    """The dp step with the update sharded on request (ZeRO over four
+    chips, global batch 1024) compiles for the described 2x2 mesh, asks
+    for the reduce-scatter and the all-gather, and keeps a 1/4 optimizer
+    slice per chip."""
+    step, lowered, compiled = _compile_dp_step_for_2x2(
+        topo, alexnet, zero_sharding="on")
+    assert step.zero_active, step.zero_reason
     asked = lowered.as_text()
     # what the step ASKS for: one reduce-scatter (the grad_reduce
     # registry op) and one invariant all-gather per param leaf
     assert "reduce_scatter" in asked and "all_gather" in asked
-    compiled = lowered.compile()
     txt = compiled.as_text()
     # what the v5e compiler MAKES of it (PR 21: 11 all-gathers and 3
     # combined all-reduces on the 2x2 — it decomposes the reduce-scatters
-    # into all-reduce + slice; a finding for the first benchmark PR)
+    # into all-reduce + slice, so ZeRO saves no byte of the exchange here)
     assert "all-gather" in txt
     assert "reduce-scatter" in txt or "all-reduce" in txt
-    n_params = sum(int(np.prod(a.shape)) for u in alexnet.forwards
-                   for a in u.param_arrays().values() if a)
-    assert n_params == 62378344
     mem = compiled.memory_analysis()
     # per-device arguments: replicated f32 params + a quarter of the
     # velocity (+ the 256-row batch shard) — far below two full copies
-    assert mem.argument_size_in_bytes < 4 * n_params * 1.5 + (256 << 20)
+    assert mem.argument_size_in_bytes \
+        < 4 * _n_alexnet_params(alexnet) * 1.5 + (256 << 20)
+
+
+def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
+                                                        monkeypatch):
+    """The default multi-chip step (`run_fused(mesh=make_mesh())`) held
+    against a v5e's limit: AlexNet's 0.75 GB of state asks for no
+    sharding, so every chip applies the full update to float32 gradients
+    all-reduced leaf by leaf, and nothing is gathered."""
+    monkeypatch.setenv(res.HBM_LIMIT_ENV, str(16_900_000_000))
+    step, lowered, compiled = _compile_dp_step_for_2x2(topo, alexnet)
+    assert not step.zero_active, step.zero_reason
+    n_params = _n_alexnet_params(alexnet)
+    assert str(12 * n_params) in step.zero_reason
+    asked = lowered.as_text()
+    assert "all_reduce" in asked
+    assert "all_gather" not in asked and "reduce_scatter" not in asked
+    txt = compiled.as_text()
+    assert "all-reduce" in txt and "all-gather" not in txt
+    # every all-reduced gradient leaf is float32
+    reduced = [line.split(" all-reduce(")[0] for line in txt.splitlines()
+               if " all-reduce(" in line]
+    assert any("f32[9216,4096]" in r for r in reduced), reduced
+    assert not any("bf16[" in r for r in reduced), reduced
+    mem = compiled.memory_analysis()
+    # per-device arguments: f32 params and the whole velocity
+    assert 8 * n_params <= mem.argument_size_in_bytes \
+        < 8 * n_params + (256 << 20)

@@ -58,7 +58,8 @@ def first_batch(wf):
                     ld.minibatch_labels.mem.copy())
 
 
-def steps_pair(eight_devices, n_data=4, optimizer="sgd", hidden=33):
+def steps_pair(eight_devices, n_data=4, optimizer="sgd", hidden=33,
+               compute_dtype=None):
     """(replicated step+state, zero step+state, batch) with identical
     seeds on an n_data-way dp mesh."""
     mesh = make_mesh(eight_devices[:n_data])
@@ -68,7 +69,8 @@ def steps_pair(eight_devices, n_data=4, optimizer="sgd", hidden=33):
         x, y = first_batch(wf)
         for g in wf.gds:
             g.optimizer = optimizer
-        step = FusedTrainStep(wf, mesh=mesh, mode="dp", zero_sharding=zs)
+        step = FusedTrainStep(wf, mesh=mesh, mode="dp", zero_sharding=zs,
+                              compute_dtype=compute_dtype)
         out.append((wf, step, step.init_state()))
     (wf_a, step_a, sa), (wf_b, step_b, sb) = out
     assert not step_a.zero_active
@@ -103,12 +105,16 @@ def test_zero_leaf_remainder_rule():
 # trajectory equivalence (the ISSUE's stated contract)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
 @pytest.mark.parametrize("optimizer", ["sgd", "adam"])
 @pytest.mark.parametrize("hidden", [32, 33])   # divisible and ragged
 def test_zero_matches_replicated_trajectory(optimizer, hidden,
-                                            eight_devices):
+                                            compute_dtype, eight_devices):
+    """Both updates take the same float32 partial gradients and sum
+    them in float32, whatever the forward computes in."""
     (_, step_a, sa), (_, step_b, sb), (x, y) = steps_pair(
-        eight_devices, n_data=4, optimizer=optimizer, hidden=hidden)
+        eight_devices, n_data=4, optimizer=optimizer, hidden=hidden,
+        compute_dtype=compute_dtype)
     for _ in range(5):
         sa, (la, ea) = step_a.train(sa, x, y)
         sb, (lb, eb) = step_b.train(sb, x, y)
@@ -439,6 +445,87 @@ def test_zero_degrades_for_ep(eight_devices):
                           zero_sharding="on")
     assert not step.zero_active
     assert "ep" in step.zero_reason
+
+
+# ---------------------------------------------------------------------------
+# "auto": memory decides (the state against the device's limit)
+# ---------------------------------------------------------------------------
+
+#: build()'s state under SGD with momentum: 2,453 parameters x 12 B
+STATE_BYTES = 12 * (64 * 33 + 33 + 33 * 10 + 10)
+
+
+@pytest.mark.parametrize("req,limit,optimizer,active", [
+    ("auto", 16 << 30, "sgd", False),           # a v5e's limit: fits
+    ("auto", 2 * STATE_BYTES, "sgd", False),    # exactly the share: fits
+    ("auto", 2 * STATE_BYTES - 2, "sgd", True),     # past the share: shard
+    ("auto", 2 * STATE_BYTES, "adam", True),    # 16 B a parameter: past it
+    ("auto", None, "sgd", False),               # no limit known (the CPU)
+    ("on", 16 << 30, "sgd", True),              # on request, whatever memory
+    ("on", None, "sgd", True),
+    ("off", 1, "sgd", False),
+])
+def test_auto_decides_from_memory(req, limit, optimizer, active,
+                                  eight_devices, monkeypatch):
+    from veles_tpu.analysis.resources import HBM_LIMIT_ENV
+    from veles_tpu.parallel.fused import ZERO_AUTO_STATE_SHARE
+    if limit is None:
+        monkeypatch.delenv(HBM_LIMIT_ENV, raising=False)
+    else:
+        monkeypatch.setenv(HBM_LIMIT_ENV, str(limit))
+    wf = build()
+    first_batch(wf)
+    for g in wf.gds:
+        g.optimizer = optimizer
+    step = FusedTrainStep(wf, mesh=make_mesh(eight_devices[:4]),
+                          mode="dp", zero_sharding=req)
+    assert step.zero_active is active, step.zero_reason
+    assert step.resource_profile()["zero_active"] is active
+    if req != "auto":
+        return
+    # the reason names the quantity that decided, with both numbers
+    state = STATE_BYTES // 12 * (16 if optimizer == "adam" else 12)
+    assert str(state) in step.zero_reason
+    if limit is None:
+        assert "no device memory limit" in step.zero_reason
+    else:
+        assert str(limit) in step.zero_reason
+        assert str(int(ZERO_AUTO_STATE_SHARE * limit)) in step.zero_reason
+
+
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+def test_default_dp_step_all_reduces_float32_leaves(compute_dtype,
+                                                    eight_devices):
+    """The default dp step on the CPU mesh (no limit: replicated) asks
+    for one float32 all-reduce per parameter leaf, of the leaf's shape,
+    under the unit's grad_exchange scope, and for no all-gather — at
+    bfloat16 compute too, where autodiff's own psum would reduce the
+    bfloat16 cotangent."""
+    import re
+    wf = build(hidden=32)
+    x, y = first_batch(wf)
+    step = FusedTrainStep(wf, mesh=make_mesh(eight_devices[:4]),
+                          mode="dp", compute_dtype=compute_dtype)
+    assert not step.zero_active
+    text = jax.jit(step.train_callable()).lower(
+        step.init_state(), x, y,
+        np.ones(x.shape[0], np.float32)).as_text(debug_info=True)
+    assert "all_gather" not in text and "reduce_scatter" not in text
+    where = dict(re.findall(r'(#loc\d+) = loc\("([^"]*)"', text))
+    reduced = [(operand, where.get(loc, "")) for operand, loc in re.findall(
+        r'"stablehlo\.all_reduce".*?\}\) : \((tensor<[^>]*>)\) -> '
+        r'tensor<[^>]*> loc\((#loc\d+)\)', text, re.S)]
+    exchanged = sorted(t for t, scope in reduced
+                       if "/grad_exchange/" in scope)
+    assert exchanged == sorted(["tensor<64x32xf32>", "tensor<32xf32>",
+                                "tensor<32x10xf32>", "tensor<10xf32>"]), \
+        reduced
+    # and nothing else of a leaf's size is reduced (the loss's scalars)
+    assert all(t in ("tensor<f32>", "tensor<i32>") for t, scope in reduced
+               if "/grad_exchange/" not in scope), reduced
+    assert {scope.split("/grad_exchange/")[0] for _t, scope in reduced
+            if "/grad_exchange/" in scope} \
+        == {"update/L00.all2all_tanh", "update/L01.softmax"}
 
 
 # ---------------------------------------------------------------------------

@@ -266,11 +266,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "dp step (arxiv 2004.13336): reduce-scatter "
                         "grads, update this replica's 1/N slice of "
                         "params + optimizer state, all-gather fresh "
-                        "params — optimizer-state memory /N, same "
-                        "collective bytes. Default auto = on wherever "
-                        "the dp shard_map update runs single-host; "
-                        "degrades with a logged reason for GPipe, "
-                        "gspmd/seq, EP and multi-host meshes. Bare "
+                        "params — optimizer-state memory /N, at the "
+                        "price of the gather. Default auto = on where "
+                        "the replicated update's state (12-16 B a "
+                        "parameter) passes half the device's memory "
+                        "limit, off below it; on/auto degrade with a "
+                        "logged reason for GPipe, gspmd/seq, EP and "
+                        "multi-host meshes. Bare "
                         "--zero-sharding means 'on' — place it AFTER "
                         "the positional workflow/config arguments (or "
                         "spell the value) so it cannot swallow them")

@@ -30,24 +30,29 @@ broadcast-psum. The EP group IS the DP group (DeepSpeed-MoE layout).
 A mesh of one device degrades to plain jit (same code path, collectives
 are no-ops) — SURVEY.md §7: build size-agnostically.
 
-ZeRO weight-update sharding ("dp" mode, on by default there; arxiv
-2004.13336 — the decomposition that became XLA's weight-update sharding):
-instead of every replica applying the full update after the grad
-all-reduce, the gradient is reduce-SCATTERED (via the `grad_reduce`
-registry op), each replica updates only its 1/N slice of params +
-momentum/Adam state under the per-leaf plan in `parallel.mesh.zero_plan`,
-and the fresh params are all-gathered for the next forward. Same bytes
-moved as the all-reduce, optimizer-state memory ÷N, and the two collective
-legs overlap with compute. Degrades (with a logged reason, see
+ZeRO weight-update sharding ("dp" mode; arxiv 2004.13336 — the
+decomposition that became XLA's weight-update sharding): instead of every
+replica applying the full update after the grad all-reduce, the gradient
+is reduce-SCATTERED (via the `grad_reduce` registry op), each replica
+updates only its 1/N slice of params + momentum/Adam state under the
+per-leaf plan in `parallel.mesh.zero_plan`, and the fresh params are
+all-gathered for the next forward. Optimizer-state memory ÷N, paid for
+with the gather and a relayout of every leaf: on a v5e 2x2 that is 6.3 ms
+of a 75.4 ms VGG-16 step (PERF.md, PR 26), so `zero_sharding="auto"`
+shards only where memory asks for it (`ZERO_AUTO_STATE_SHARE`) and traces
+the replicated update otherwise. Degrades (with a logged reason, see
 `zero_reason`) for local/gspmd/seq modes, EP, single-shard data axes and
-multi-host meshes — those keep the replicated update this PR left alone.
+multi-host meshes. The replicated dp update exchanges what the sharded one
+does: each leaf's per-shard partial gradient, summed in float32 by an
+explicit all-reduce under `update/<unit>/grad_exchange`.
 
 Names in a profile: every operation of the compiled step carries a
 `jax.named_scope` path that depends on the layer table only, never on a
 compile — `input_normalize`, `cast_params`, one `L<index>.<layer type>`
 per forward unit (`L01.norm`; a searched fused pair is one scope,
 `L01.norm+L02.max_pooling`), `loss`, and `update` with the unit's scope
-beneath it (under ZeRO `grad_exchange` and `param_gather` beneath that).
+beneath it (on a dp mesh `grad_exchange` beneath that, and under ZeRO
+`param_gather` too).
 Autodiff writes the backward's `transpose(jvp(<scope>))` itself. Scopes
 are metadata: the compiled program is the same (docs/OBSERVABILITY.md).
 `train()` records the `train.dispatch` span with the step's number.
@@ -83,6 +88,22 @@ from veles_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, SEQ_AXIS,
                                      zero_flatten, zero_plan,
                                      zero_unflatten)
 from veles_tpu.telemetry import tracer as _tracer
+
+
+#: `zero_sharding="auto"` shards the weight update where the replicated
+#: update's state (parameters, one gradient, optimizer state: 12 B a
+#: parameter under SGD with momentum, 16 under Adam) passes this share of
+#: the device's memory limit. The other half is what a step holds beside
+#: that state: activations and the compiler's temporaries (3.7 to 4.1 GB
+#: of a v5e's 16.9 GB in the benchmark's cells, a quarter of the limit:
+#: `hbm_peak_gb` less state, ledger PR 25), the compute-dtype copy of the
+#: parameters (2 B beside each 12 to 16 B of state, 6 to 8 % of the limit
+#: at this share), and the 20 % `analysis.resources.NEAR_LIMIT_FRAC` keeps
+#: free. Below it sharding buys memory nobody needs at the price of a
+#: parameter all-gather and a relayout of every leaf each step (6.3 ms of
+#: VGG-16's 75.4 on a v5e 2x2, 831 against 905 samples/s a chip; PERF.md,
+#: PR 26).
+ZERO_AUTO_STATE_SHARE = 0.5
 
 
 def _tree_cast(tree, dtype):
@@ -278,9 +299,11 @@ class FusedTrainStep:
     def _resolve_zero(self, req: Any) -> Tuple[bool, str]:
         """Gate the ZeRO sharded update: active only where this build
         covers it (explicit shard_map "dp" over a >1-shard single-host
-        data axis, no EP). `req` is the CLI surface: "on"/True forces a
-        WARNING when it cannot apply, "auto" (the default — zero IS the
-        default dp update) degrades quietly, "off"/False disables."""
+        data axis, no EP). `req` is the CLI surface: "on"/True shards
+        wherever that is covered and WARNs where it is not, "auto" (the
+        default) shards where the replicated update's state passes
+        ZERO_AUTO_STATE_SHARE of the device's memory limit and degrades
+        quietly, "off"/False disables."""
         from veles_tpu.parallel.mesh import is_multihost
         if req in (False, "off"):
             return False, "zero-sharding disabled by request"
@@ -303,12 +326,48 @@ class FusedTrainStep:
             reason = ("zero-sharding inactive: multi-host mesh "
                       "(cross-process sharded optimizer state is not "
                       "covered by this build)")
-        else:
+        elif req in (True, "on"):
             return True, "active"
+        else:
+            return self._zero_from_memory()
         import logging
         log = logging.getLogger("veles.fused")
         (log.warning if req in (True, "on") else log.debug)("%s", reason)
         return False, reason
+
+    def _replicated_state_bytes(self) -> Tuple[int, int]:
+        """(parameter bytes, optimizer-state bytes) one chip holds under
+        the replicated update, from the units' HOST-side arrays: one
+        velocity per parameter under SGD, two moments under Adam."""
+        params = opt = 0
+        for u, cfg in zip(self.forwards, self.cfgs):
+            lb = sum(int(np.asarray(a.mem).nbytes)
+                     for a in u.param_arrays().values() if a)
+            params += lb
+            opt += lb * (2 if isinstance(cfg, optim.AdamConfig) else 1)
+        return params, opt
+
+    def _zero_from_memory(self) -> Tuple[bool, str]:
+        """`zero_sharding="auto"` on a mesh that could shard: does the
+        replicated update's state (parameters, one gradient, optimizer
+        state) pass ZERO_AUTO_STATE_SHARE of the device's limit?"""
+        from veles_tpu.analysis.resources import device_limit
+        params, opt = self._replicated_state_bytes()
+        state = 2 * params + opt
+        limit = device_limit()
+        if limit is None:
+            return False, (
+                f"zero-sharding inactive: no device memory limit is known "
+                f"here, so the replicated update's state of {state} B a "
+                "chip has nothing to be held against")
+        budget = int(ZERO_AUTO_STATE_SHARE * limit)
+        held = (f"the replicated update's state is {state} B a chip "
+                f"(parameters, one gradient, optimizer state) against "
+                f"{budget} B, {ZERO_AUTO_STATE_SHARE:.0%} of the device's "
+                f"limit of {limit} B")
+        if state > budget:
+            return True, f"active: {held}"
+        return False, f"zero-sharding inactive: {held}"
 
     # -- ZeRO update-sharding plan (parallel.mesh.zero_plan) ----------------
 
@@ -391,30 +450,16 @@ class FusedTrainStep:
         from veles_tpu.parallel.mesh import zero_plan_local_elems
         n = (self.mesh.shape.get(DATA_AXIS, 1)
              if self.mesh is not None else 1)
-        params = 0
-        per_layer: List[int] = []
-        for u in self.forwards:
-            lb = 0
-            for a in u.param_arrays().values():
-                if a:
-                    arr = np.asarray(a.mem)
-                    lb += int(arr.size) * arr.itemsize
-            per_layer.append(lb)
-            params += lb
+        params, opt = self._replicated_state_bytes()
+        ef = 0
         if self.zero_active:
             opt = sum(
                 zero_plan_local_elems(plan)
                 * (2 if isinstance(cfg, optim.AdamConfig) else 1) * 4
                 for plan, cfg in zip(self.zero_plans(), self.cfgs))
-            ef = 0
             if self.ef_active():
                 ef = sum(rl for lens in self.ef_lens()
                          for rl in lens.values()) * 4
-        else:
-            opt = sum(
-                lb * (2 if isinstance(cfg, optim.AdamConfig) else 1)
-                for lb, cfg in zip(per_layer, self.cfgs))
-            ef = 0
         return {"n_data_shards": n, "params_bytes": params,
                 "grads_bytes": params, "optimizer_state_bytes": opt,
                 "ef_bytes": ef, "zero_active": self.zero_active}
@@ -869,14 +914,15 @@ class FusedTrainStep:
         step_key = self._shard_step_key(state, axes)
 
         def lf(p):
-            # Under shard_map the params are unvarying (replicated), so the
-            # transpose of their broadcast IS a psum over the data axis —
-            # jax inserts the gradient all-reduce automatically (vma
-            # semantics). _loss_metrics normalizes by the GLOBAL weight
-            # sum, so that psum of per-shard partials IS the exact
+            # _loss_metrics normalizes by the GLOBAL weight sum, so the
+            # sum of the per-shard partial gradients IS the exact
             # global-mean gradient: THE north-star collective
-            # (BASELINE.json:5), placed by autodiff right where the
-            # reference shipped pickled deltas.
+            # (BASELINE.json:5), right where the reference shipped
+            # pickled deltas. Where the params are unvarying (seq, EP's
+            # replicated leaves) the transpose of their broadcast is
+            # that psum and jax inserts it (vma semantics); on the dp
+            # mesh _grad_params makes them varying and the update sums
+            # the partials itself, in float32.
             loss, n_err = self._loss_metrics(p, x, y, step_key, True,
                                              w, axes)
             return loss, (loss, n_err)
@@ -890,13 +936,26 @@ class FusedTrainStep:
                 n_err = lax.psum(n_err, axes)
         return self._apply_update(state, grads), loss, n_err
 
+    def _exchanges_partials(self) -> bool:
+        """True where the update itself sums the per-shard partial
+        gradients: the dp step without EP, sharded update or replicated.
+        (Under EP the expert tensors are sharded over the data axis and
+        their gradients arrive through the all_to_all transpose, so
+        autodiff's own psum of the replicated leaves stays; local, gspmd
+        and seq have no hand-placed exchange.)"""
+        return self.mode == "dp" and not self.ep
+
     def _grad_params(self, params):
-        """The params autodiff differentiates against. Under ZeRO they
-        are cast to VARYING over the data axis first: the gradient of a
-        varying value is this shard's partial (no transpose psum), which
-        is what the `grad_reduce` registry op reduce-scatters in
-        _apply_update_zero — one reduction, through the registry."""
-        if not self.zero_active:
+        """The params autodiff differentiates against. Where the update
+        sums the gradients itself they are cast to VARYING over the data
+        axis first: the gradient of a varying value is this shard's
+        partial (no transpose psum), a float32 leaf whatever the compute
+        dtype. Left to autodiff, the psum lands on the cotangent of
+        `cast_params`' output and reduces in the compute dtype. The ZeRO
+        update reduce-scatters the partials through the `grad_reduce`
+        registry op, the replicated one all-reduces them — one reduction
+        either way, of the same float32 bytes."""
+        if not self._exchanges_partials():
             return params
         return jax.tree.map(
             lambda a: lax.pcast(a, DATA_AXIS, to="varying"), params)
@@ -916,11 +975,12 @@ class FusedTrainStep:
                 allow_pallas=self.mode != "gspmd"))
 
     def _apply_update(self, state, grads):
-        """One optimizer step from already-reduced grads; advances the
-        carried key identically on every shard (fold_in of the *unfolded*
-        state key keeps it replicated). Under ZeRO the grads arrive
-        UNREDUCED per-shard partials and the sharded update performs the
-        reduction itself (reduce-scatter). The SGD leg resolves through
+        """One optimizer step; advances the carried key identically on
+        every shard (fold_in of the *unfolded* state key keeps it
+        replicated). On a dp mesh the grads arrive UNREDUCED per-shard
+        partials and the update performs the reduction itself
+        (reduce-scatter under ZeRO, all-reduce otherwise); elsewhere
+        they arrive reduced. The SGD leg resolves through
         the `sgd_update` registry op (default xla_tree IS
         optim.sgd_update; the search-generated pallas row-blocked
         candidates slot in when selected — GSPMD falls back, a
@@ -931,11 +991,20 @@ class FusedTrainStep:
             return self._apply_update_replicated(state, grads)
 
     def _apply_update_replicated(self, state, grads):
+        """Every replica applies the full update. On a dp mesh the grads
+        arrive as per-shard partials (`_grad_params`) and each leaf is
+        all-reduced here in its own dtype, float32; everywhere else they
+        arrive already reduced."""
         sgd_apply = self._sgd_variant().apply
+        exchange = self._exchanges_partials()
         new_params, new_vel = [], []
         for scope, p, g, v, cfg in zip(self.scopes, state["params"], grads,
                                        state["vel"], self.cfgs):
             with jax.named_scope(scope):
+                if p and exchange:
+                    with jax.named_scope("grad_exchange"):
+                        g = {k: lax.psum(a, DATA_AXIS)
+                             for k, a in g.items()}
                 if p and isinstance(cfg, optim.AdamConfig):
                     np_, nv_ = optim.adam_update(
                         p, g, v, cfg, lr_scale=state["lr_scale"])
@@ -1037,9 +1106,10 @@ class FusedTrainStep:
         `gradient_accumulation`/`apply_gradients` gate (SURVEY.md §2.8
         GradientDescentBase row). Each microbatch is normalized by the
         full batch's global weight sum, so the scanned grad SUM equals
-        the full-batch mean gradient exactly (pad masks included); under
-        sharding the per-shard gradient psum fires once per microbatch
-        inside the scan, exactly as the per-step path."""
+        the full-batch mean gradient exactly (pad masks included); on the
+        dp mesh the accumulated partials are exchanged once, by the
+        update (where autodiff places the psum, seq and EP, it fires
+        once per microbatch inside the scan)."""
         axes = (axis,) if isinstance(axis, str) else axis
         step_key = self._shard_step_key(state, axes)
         wsum = self._global_wsum(ws.reshape(-1), 1, axes)

@@ -292,8 +292,8 @@ class StandardWorkflow(Workflow):
         additionally shards MoE expert tensors over the data axis).
         `input_normalize` is the uint8-wire prologue spec (see
         `_wire_spec`); `zero_sharding` gates the ZeRO sharded weight
-        update (on by default in dp mode — CLI `--zero-sharding`). See
-        parallel.fused.FusedTrainStep."""
+        update ("auto": in dp mode where memory asks for it — CLI
+        `--zero-sharding`). See parallel.fused.FusedTrainStep."""
         from veles_tpu.parallel.fused import FusedTrainStep
         return FusedTrainStep(self, mesh=mesh, mode=mode,
                               compute_dtype=compute_dtype, ep=ep,
