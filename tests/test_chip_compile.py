@@ -7,7 +7,8 @@ and compiles for a chip that is DESCRIBED, not attached — so the main
 path's kernels at AlexNet's real widths, the generated points the search
 would try, and the whole fused train step (one chip, and the dp step
 over the 2x2 mesh, with the replicated update and with ZeRO) are
-compiled here, at no chip time, on every tier-1 run. A compile that
+compiled here — and the Pallas LRN is held to taking the activation in
+the layout the convs emit, with no relayout beside it (ISSUE 27) —, at no chip time, on every tier-1 run. A compile that
 passes is not a chip run; `chip_smoke.py` is.
 
 Rules this file keeps (on-chip-measurement guide, section 2): the
@@ -230,31 +231,85 @@ def _abstract_step_args(step, batch, shardings, xsh):
             jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=xsh))
 
 
+@pytest.fixture(scope="module")
+def local_step_programs(one_chip, alexnet):
+    """lrn variant -> the compiled one-chip fused step chip_smoke.py
+    trains (batch 1024, bf16), each compiled once for the module."""
+    programs = {}
+
+    def get(lrn):
+        if lrn not in programs:
+            variants.select("lrn", lrn)
+            try:
+                with pytest.MonkeyPatch.context() as mp:
+                    mp.setattr(pk, "available", lambda: True)
+                    step = alexnet.build_fused_step(compute_dtype="bfloat16")
+                    assert step.variant_table()["lrn"] == lrn
+                    args = _abstract_step_args(
+                        step, BATCH,
+                        lambda t: jax.tree_util.tree_map(lambda _: one_chip,
+                                                         t),
+                        one_chip)
+                    programs[lrn] = jax.jit(
+                        step.train_callable(),
+                        donate_argnums=(0,)).lower(*args).compile()
+            finally:
+                variants.clear_selection("lrn")
+        return programs[lrn]
+    return get
+
+
 @pytest.mark.parametrize("lrn", ("banded_matmul", "pallas_one_pass"))
-def test_local_alexnet_train_step_compiles(one_chip, alexnet, lrn,
-                                           compiled_pallas):
-    """The one-chip fused step chip_smoke.py trains (batch 1024, bf16),
-    with the default lowerings and with the Pallas LRN selected — the
-    kernel must be IN the program then, and the program must fit the
-    chip's 16 GB."""
-    variants.select("lrn", lrn)
-    try:
-        step = alexnet.build_fused_step(compute_dtype="bfloat16")
-        assert step.variant_table()["lrn"] == lrn
-        args = _abstract_step_args(
-            step, BATCH,
-            lambda t: jax.tree_util.tree_map(lambda _: one_chip, t),
-            one_chip)
-        compiled = jax.jit(step.train_callable(),
-                           donate_argnums=(0,)).lower(*args).compile()
-    finally:
-        variants.clear_selection("lrn")
+def test_local_alexnet_train_step_compiles(local_step_programs, lrn):
+    """With the XLA closed form and with the Pallas LRN (the default on a
+    TPU since PR 27) — the kernel must be IN the program then, and the
+    program must fit the chip's 16 GB."""
+    compiled = local_step_programs(lrn)
     assert ("tpu_custom_call" in compiled.as_text()) \
         == (lrn == "pallas_one_pass")
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < 16 << 30, total
+
+
+def _relayouts_of(txt, shapes):
+    """The compiled program's `copy`, `pad`, `slice` and `transpose`
+    instructions whose result has one of `shapes` (an LRN site's
+    activation, logical or in a kernel's view)."""
+    import re
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    pat = re.compile(rf"= \w+\[({dims})\]\S* (copy|pad|slice|transpose)\(")
+    return [ln.strip()[:160] for ln in txt.splitlines() if pat.search(ln)]
+
+
+def test_pallas_lrn_takes_the_activation_where_it_lies(local_step_programs):
+    """ISSUE 27's finding, kept: a kernel over the flattened NHWC array
+    cost eight relayout copies of 595 / 382 MB, a pad and a slice. The
+    kernels take the view whose row-major order is the layout the convs
+    emit, so around all four of them NOTHING moves an activation of an
+    LRN site, and the program needs no more memory than the XLA form's
+    (it needs less: no `s` residual)."""
+    compiled = local_step_programs("pallas_one_pass")
+    txt = compiled.as_text()
+    for name, view in (("veles_lrn_fwd", "bf16[3025,96,1024]"),
+                       ("veles_lrn_bwd", "bf16[3025,96,1024]"),
+                       ("veles_lrn_fwd", "bf16[746496,256]"),
+                       ("veles_lrn_bwd", "bf16[746496,256]")):
+        assert any(name in ln and "tpu_custom_call" in ln
+                   and ln.split(" = ")[1].startswith(view)
+                   for ln in txt.splitlines() if " = " in ln), (name, view)
+    sites = [(BATCH,) + s for s in LRN_SITES]
+    views = [(55 * 55, 96, BATCH), (27 * 27 * BATCH, 256),
+             (55, 55, 96, BATCH), (27, 27, BATCH, 256)]
+    assert _relayouts_of(txt, sites + views) == []
+    # the reader itself finds such a line when there is one
+    assert _relayouts_of(
+        "%copy.61 = bf16[1024,55,55,96]{3,2,1,0:T(8,128)(2,1)} copy(%x)",
+        sites) != []
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= local_step_programs("banded_matmul") \
+        .memory_analysis().temp_size_in_bytes
 
 
 def _compile_dp_step_for_2x2(topo, alexnet, **step_kw):
@@ -310,13 +365,28 @@ def test_dp_zero_alexnet_train_step_compiles_for_2x2(topo, alexnet):
 
 
 def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
-                                                        monkeypatch):
+                                                        monkeypatch,
+                                                        compiled_pallas):
     """The default multi-chip step (`run_fused(mesh=make_mesh())`) held
     against a v5e's limit: AlexNet's 0.75 GB of state asks for no
     sharding, so every chip applies the full update to float32 gradients
-    all-reduced leaf by leaf, and nothing is gathered."""
+    all-reduced leaf by leaf, and nothing is gathered. Its LRN is the
+    default too: the kernels under shard_map at 256 a chip, LRN1 still
+    batch in lanes, and no relayout around them."""
     monkeypatch.setenv(res.HBM_LIMIT_ENV, str(16_900_000_000))
     step, lowered, compiled = _compile_dp_step_for_2x2(topo, alexnet)
+    assert step.variant_table()["lrn"] == "pallas_one_pass"
+    kernels = [ln.split(" = ")[1].split("{")[0]
+               for ln in compiled.as_text().splitlines()
+               if "tpu_custom_call" in ln and "veles_lrn_" in ln]
+    assert sorted(kernels) == ["bf16[186624,256]"] * 2 \
+        + ["bf16[3025,96,256]"] * 2, kernels
+    per_chip = BATCH // 4
+    assert _relayouts_of(
+        compiled.as_text(),
+        [(per_chip,) + s for s in LRN_SITES]
+        + [(55 * 55, 96, per_chip), (27 * 27 * per_chip, 256),
+           (55, 55, 96, per_chip), (27, 27, per_chip, 256)]) == []
     assert not step.zero_active, step.zero_reason
     n_params = _n_alexnet_params(alexnet)
     assert str(12 * n_params) in step.zero_reason

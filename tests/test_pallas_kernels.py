@@ -50,6 +50,113 @@ def test_lrn_backward_matches_golden():
     np.testing.assert_allclose(got, gold, rtol=1e-4, atol=1e-5)
 
 
+# -- LRN in the layout the convs emit (ISSUE 27): the view follows the shape ---
+
+#: case -> (NHWC shape, the walk `lrn_view` must pick, None = no view: the
+#: XLA closed form is traced and no kernel)
+LRN_VIEW_CASES = {
+    "batch_in_lanes": ((128, 2, 3, 96), "_walk_batch_lanes"),
+    "channels_in_lanes": ((16, 2, 3, 256), "_walk_channel_lanes"),
+    "batch_256_rows_per_iteration": ((256, 5, 5, 32), "_walk_batch_lanes"),
+    "falls_back_batch_100": ((100, 2, 3, 96), None),
+    "falls_back_channels_40": ((128, 2, 3, 40), None),
+    "falls_back_rows_not_tiles": ((3, 1, 1, 256), None),
+}
+#: a window that matters (alpha 0.3) beside AlexNet's constants, and a
+#: beta that is no multiple of a quarter (the divide form of d/s)
+LRN_SCALARS = {"alexnet": (2.0, 1e-4, 0.75, 5), "heavy": (1.0, 0.3, 0.75, 5),
+               "generic_beta": (1.0, 0.3, 0.6, 3)}
+
+
+def _lrn_kernels_traced(fn, *args):
+    import jax
+    txt = str(jax.make_jaxpr(fn)(*args))
+    return [n for n in ("veles_lrn_fwd", "veles_lrn_bwd") if n in txt]
+
+
+@pytest.mark.parametrize("scalars", sorted(LRN_SCALARS))
+@pytest.mark.parametrize("case", sorted(LRN_VIEW_CASES))
+def test_lrn_view_forward_matches_golden(case, scalars):
+    shape, walk = LRN_VIEW_CASES[case]
+    k, alpha, beta, n = LRN_SCALARS[scalars]
+    view = pk.lrn_view(shape, 4)
+    assert (view and view[0].__name__) == walk
+    x = np.random.RandomState(5).randn(*shape).astype(np.float32)
+    fn = lambda a: pk.lrn_pallas(a, k, alpha, beta, n)  # noqa: E731
+    assert _lrn_kernels_traced(fn, x) == (["veles_lrn_fwd"] if walk else [])
+    np.testing.assert_allclose(np.asarray(fn(x)),
+                               ref.lrn_forward(x, k, alpha, beta, n),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("scalars", sorted(LRN_SCALARS))
+@pytest.mark.parametrize("case", sorted(LRN_VIEW_CASES))
+def test_lrn_view_backward_matches_golden(case, scalars):
+    import jax
+    shape, walk = LRN_VIEW_CASES[case]
+    k, alpha, beta, n = LRN_SCALARS[scalars]
+    rs = np.random.RandomState(6)
+    x = rs.randn(*shape).astype(np.float32)
+    g = rs.randn(*shape).astype(np.float32)
+    fn = lambda a, e: jax.vjp(  # noqa: E731
+        lambda v: pk.lrn_pallas(v, k, alpha, beta, n), a)[1](e)[0]
+    assert _lrn_kernels_traced(fn, x, g) == (
+        ["veles_lrn_fwd", "veles_lrn_bwd"] if walk else [])
+    np.testing.assert_allclose(np.asarray(fn(x, g)),
+                               ref.lrn_backward(x, g, k, alpha, beta, n),
+                               rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("case", ["batch_in_lanes", "channels_in_lanes"])
+def test_lrn_view_bfloat16_activation(case):
+    """What the fused step hands the kernels: a bfloat16 activation goes
+    to the MXU rounded once, the arithmetic around it is float32, the
+    result is rounded to bfloat16 once."""
+    import jax
+    import jax.numpy as jnp
+    shape, _ = LRN_VIEW_CASES[case]
+    k, alpha, beta, n = LRN_SCALARS["heavy"]
+    rs = np.random.RandomState(7)
+    x = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
+    g = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
+    y, vjp = jax.vjp(lambda v: pk.lrn_pallas(v, k, alpha, beta, n), x)
+    (dx,) = vjp(g)
+    assert y.dtype == dx.dtype == jnp.bfloat16
+    xf, gf = (np.asarray(a, np.float32) for a in (x, g))
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               ref.lrn_forward(xf, k, alpha, beta, n),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(dx, np.float32),
+                               ref.lrn_backward(xf, gf, k, alpha, beta, n),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("shape,itemsize,block", [
+    ((1024, 55, 55, 96), 2, (5, 96, 1024)),     # AlexNet LRN1, one chip
+    ((256, 55, 55, 96), 2, (25, 96, 256)),      # ... at 256 a chip (dp)
+    ((1024, 27, 27, 256), 2, (2592, 256)),      # LRN2
+    ((256, 27, 27, 256), 2, (2592, 256)),
+    ((1024, 55, 55, 96), 4, (1, 96, 1024)),     # float32: half the rows
+])
+def test_lrn_view_blocks_divide_exactly_and_fit(shape, itemsize, block):
+    """No pad and no slice: a block divides its view in every dimension,
+    and its double-buffered operands stay inside half the scoped VMEM."""
+    from veles_tpu.analysis.resources import SCOPED_VMEM_LIMIT
+    _, vshape, got = pk.lrn_view(shape, itemsize)
+    assert got == block
+    assert int(np.prod(vshape)) == int(np.prod(shape))
+    assert all(v % b == 0 for v, b in zip(vshape, got))
+    assert pk.lrn_view_vmem_bytes(got, itemsize) <= SCOPED_VMEM_LIMIT // 2
+
+
+def test_lrn_view_vmem_rule_prices_whole_tiles():
+    """A bfloat16 tile of the batch-in-lanes view is 16 channels x 128
+    samples: 24 channels occupy 32, 130 lanes occupy 256."""
+    assert pk.lrn_view_vmem_bytes((1, 16, 128), 2) == 6 * 16 * 128 * 2
+    assert pk.lrn_view_vmem_bytes((3, 24, 130), 2) == 6 * 3 * 32 * 256 * 2
+    assert pk.lrn_view_vmem_bytes((8, 128), 4) == 6 * 8 * 128 * 4
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_matches_golden(causal):
     rng = np.random.RandomState(3)

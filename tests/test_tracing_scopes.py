@@ -177,8 +177,12 @@ PALLAS_SITES = {
     "lrn_fwd": (lambda x: pk.lrn_forward_pallas(x), X4, ["veles_lrn_fwd"]),
     "lrn_bwd": (lambda x: pk.lrn_backward_pallas(x, x), X4,
                 ["veles_lrn_bwd"]),
-    "lrn_custom_vjp": (jax.grad(lambda x: pk.lrn_pallas(x).sum()), X4,
+    # lrn_pallas traces the kernels where the shape has a lane-dense
+    # view (here batch in lanes) and the XLA closed form elsewhere
+    "lrn_custom_vjp": (jax.grad(lambda x: pk.lrn_pallas(x).sum()),
+                       np.ones((128, 2, 2, 16), np.float32),
                        ["veles_lrn_fwd", "veles_lrn_bwd"]),
+    "lrn_no_view": (jax.grad(lambda x: pk.lrn_pallas(x).sum()), X4, []),
     "lrn_maxpool": (jax.grad(lambda x: pk.lrn_maxpool_pallas(x).sum()), X4,
                     ["veles_lrn_maxpool_fwd", "veles_lrn_maxpool_bwd"]),
     "flash": (jax.grad(lambda q: pk.flash_attention_pallas(

@@ -72,6 +72,61 @@ def test_lrn_variants_match_reference(name):
         atol=2e-5)
 
 
+#: the default `lrn` lowering follows what it can observe (ISSUE 27):
+#: case -> (shape, unit allows pallas, kernels the trace must hold)
+LRN_DEFAULT_CASES = {
+    "batch_in_lanes": ((128, 2, 2, 96), True, 2),
+    "channels_in_lanes": ((16, 2, 2, 256), True, 2),
+    "falls_back_batch_100": ((100, 2, 2, 96), True, 0),
+    "falls_back_channels_40": ((128, 2, 2, 40), True, 0),
+    "falls_back_allow_pallas_cleared": ((128, 2, 2, 96), False, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LRN_DEFAULT_CASES))
+def test_default_lrn_lowering_follows_the_shape(case):
+    """No selection made: on a TPU (here: interpret mode) the op's
+    default is the one-pass kernel pair where the shape has a lane-dense
+    view, the XLA closed form for any other shape and for a unit whose
+    `allow_pallas` is cleared (GSPMD) — and every case matches the
+    reference, forward and backward."""
+    import types
+    shape, allow, n_kernels = LRN_DEFAULT_CASES[case]
+    assert variants.selected("lrn") is None
+    assert variants.effective("lrn") == "pallas_one_pass"
+    rs = np.random.RandomState(11)
+    x = rs.randn(*shape).astype(np.float32)
+    g = rs.randn(*shape).astype(np.float32)
+    k, alpha, beta, n = 2.0, 1e-4, 0.75, 5
+    unit = types.SimpleNamespace(allow_pallas=allow)
+    with variants.pallas_interpret():
+        v = variants.resolve("lrn", unit=unit)
+        assert v.name == ("pallas_one_pass" if allow else "banded_matmul")
+
+        def fwd_bwd(xx, gg):
+            y, vjp = jax.vjp(lambda a: v.apply(a, k=k, alpha=alpha,
+                                               beta=beta, n=n), xx)
+            return y, vjp(gg)[0]
+        assert str(jax.make_jaxpr(fwd_bwd)(x, g)).count("veles_lrn_") \
+            == n_kernels
+        y, dx = fwd_bwd(x, g)
+    np.testing.assert_allclose(
+        np.asarray(y), ref.lrn_forward(x, k, alpha, beta, n), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(dx), ref.lrn_backward(x, g, k, alpha, beta, n),
+        atol=2e-5)
+
+
+def test_default_lrn_resolves_quietly_off_a_tpu(caplog):
+    """The default names a Pallas lowering; off a TPU with no interpret
+    mode asked for it resolves to `banded_matmul` with no warning (a
+    SELECTED pallas variant warns once)."""
+    import logging
+    with caplog.at_level(logging.WARNING, logger="veles.variants"):
+        assert variants.resolve("lrn").name == "banded_matmul"
+    assert not caplog.records
+
+
 @pytest.mark.parametrize("name", ["reduce_window", "slices"])
 @pytest.mark.parametrize("use_abs", [False, True])
 def test_maxpool_variants_match_reference(name, use_abs):

@@ -49,6 +49,16 @@ _LRN_TILE_MAX = 4096
 #: pipelined blocks — fitted to what the v5e compiler reports
 #: (18.83M at rt=4096, C=96, bf16: 6M of blocks + 6.4 temporaries)
 _LRN_BWD_F32_TEMPS = 7
+#: the LRN view kernels (ISSUE 27): the most rows of the channels-in-lanes
+#: view one in-kernel slab holds; independent (C, 128) slabs of the
+#: batch-in-lanes view one loop iteration holds; the channel multiple
+#: that view asks for (a bfloat16 tile is 16 channels x 128 samples);
+#: the most lanes of batch one of its blocks spans (the whole batch at
+#: 1024 a chip: one contiguous DMA a block)
+_LRN_SLAB_ROWS_MAX = 512
+_LRN_SLABS_IN_FLIGHT = 10
+_LRN_BATCH_VIEW_C = 16
+_LRN_LANE_MAX = 1024
 #: fused-SGD row blocking seed (the pre-search hand-written value)
 _SGD_ROW_TILE = 8
 #: fused LRN+maxpool sample tile seed: SAMPLES per VMEM block (each
@@ -166,12 +176,27 @@ def sgd_update_pallas(p, g, v, lr, momentum=0.0, weight_decay=0.0,
 
 
 # ---------------------------------------------------------------------------
-# LRN forward + backward: both sliding channel-window sums in one pass
+# LRN forward + backward: one streaming pass each, in the layout the
+# convs emit (ISSUE 27)
+#
+# The v5e compiler holds a conv activation whose channel count is no
+# multiple of 128 with the BATCH in the lanes (AlexNet LRN1:
+# bf16[1024,55,55,96]{0,3,2,1:T(8,128)(2,1)}, 16 channels x 128 samples
+# a tile) and one whose channel count is with the channels in the lanes
+# and the batch next ({3,0,2,1}, LRN2). A kernel that flattens the
+# logical NHWC array to (N*H*W, C) makes XLA relayout every operand and
+# result (eight copies of 595 / 382 MB in AlexNet's step), pad C to 128
+# lanes and pad/slice the rows. So the kernels take the activation
+# through the VIEW whose row-major order is that physical layout (the
+# transposes below compile to bitcasts), in blocks that divide it
+# exactly, and walk each VMEM block slab by slab so that the float32
+# intermediates live in registers and the pass stays bound by HBM.
 # ---------------------------------------------------------------------------
 
 
 def _window_sum(a, half: int):
-    """±half across-channel window sum on a (rows, C) VMEM block."""
+    """±half across-channel window sum on a whole (rows, C) VMEM block
+    (the flat path: C may be any width, so pads and slices)."""
     out = a
     for d in range(1, half + 1):
         out = out + jnp.pad(a[:, d:], ((0, 0), (0, d))) \
@@ -179,31 +204,117 @@ def _window_sum(a, half: int):
     return out
 
 
+def _band_window_sum(a, band, axis: int, exact: bool):
+    """±half window sum along `axis` of a float32 slab as a product with
+    the 0/1 band on the MXU, accumulated in float32. The chip's rolls
+    and masks cost the VPU more than the pass's DMA takes (measured, PR
+    27: 2.19 / 3.39 ms against 1.95 / 2.82 at LRN1, 3.1 / 5.1 against
+    1.37 / 1.88 at LRN2). The MXU takes bfloat16: a slab of a bfloat16
+    activation goes in rounded once (as XLA's banded matmul rounds x²);
+    a float32 activation (`exact`) goes in as two bfloat16 pieces, which
+    carry 16 bits of it."""
+    def dot(v):
+        # bfloat16 operands are exact in one MXU pass: no matmul-precision
+        # setting of the caller's has a say
+        return jnp.dot(*((band, v) if axis == 0 else (v, band)),
+                       precision=lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32)
+
+    hi = a.astype(jnp.bfloat16)
+    if not exact:
+        return dot(hi)
+    return dot(hi) + dot((a - hi.astype(jnp.float32)).astype(jnp.bfloat16))
+
+
 # s^(−β) via sqrt/rsqrt products instead of exp/log — the SAME routine
 # the XLA lowering uses, imported so both lowerings share numerics
+from veles_tpu.ops.xla import _lrn_band  # noqa: E402
 from veles_tpu.ops.xla import _pow_neg_quarters as _pow_neg  # noqa: E402
 
 
-def _lrn_fwd_kernel(x_ref, y_ref, *, half: int, k: float, alpha: float,
-                    beta: float):
-    x = x_ref[:].astype(jnp.float32)
-    ssum = _window_sum(x * x, half)
-    y_ref[:] = (x * _pow_neg(k + alpha * ssum, beta)).astype(y_ref.dtype)
+def _lrn_fwd_math(x, *, wsum, k: float, alpha: float, beta: float):
+    x = x.astype(jnp.float32)
+    return x * _pow_neg(k + alpha * wsum(x * x), beta)
 
 
-def _lrn_bwd_kernel(x_ref, e_ref, out_ref, *, half: int, k: float,
-                    alpha: float, beta: float):
-    x = x_ref[:].astype(jnp.float32)
-    err = e_ref[:].astype(jnp.float32)
-    s = k + alpha * _window_sum(x * x, half)
-    d = _pow_neg(s, beta)                     # s^(−β)
-    tsum = _window_sum(err * x * d / s, half)  # W(g·x·s^(−β−1))
-    out_ref[:] = (err * d
-                  - 2.0 * alpha * beta * x * tsum).astype(out_ref.dtype)
+def _lrn_bwd_math(x, err, *, wsum, k: float, alpha: float, beta: float):
+    """err_x = g·d − 2αβ·x·W(g·x·d/s), d = s^(−β), all in float32."""
+    x = x.astype(jnp.float32)
+    err = err.astype(jnp.float32)
+    s = k + alpha * wsum(x * x)
+    # s^(−β−1) the same way as d: where 4β is an integer both are products
+    # of ONE t = s^(−1/4) (the common terms merge), and nothing divides
+    d, d_over_s = _pow_neg(s, beta), _pow_neg(s, beta + 1.0)
+    return err * d - (2.0 * alpha * beta) * x * wsum(err * x * d_over_s)
+
+
+def _walk_whole(ins, out, math, half: int):
+    """The flat path: the whole (row_tile, C) block at once, the window
+    by pads and slices."""
+    out[:] = math(*(a[:] for a in ins),
+                  wsum=functools.partial(_window_sum, half=half)
+                  ).astype(out.dtype)
+
+
+def _walk_batch_lanes(ins, out, math):
+    """Block (R, C, NB) of the batch-in-lanes view: one (C, 128) slab —
+    every channel of 128 samples at one pixel — at a time, band @ slab.
+    An iteration takes enough rows for _LRN_SLABS_IN_FLIGHT slabs: the
+    slabs are independent, and with too few of them the MXU's latency
+    shows (measured: 2 an iteration ran at half the rate of 8)."""
+    *ins, band = ins
+    exact = ins[0].dtype.itemsize > 2
+    lanes = out.shape[2] // _LANE
+    rows = _largest_divisor(out.shape[0], 1,
+                            max(1, _LRN_SLABS_IN_FLIGHT // lanes))
+
+    def step(i, carry):
+        wsum = functools.partial(_band_window_sum, band=band[...], axis=0,
+                                 exact=exact)
+        for r in range(rows):
+            for j in range(lanes):
+                at = (i * rows + r, slice(None), pl.ds(j * _LANE, _LANE))
+                out[at] = math(*(a[at] for a in ins),
+                               wsum=wsum).astype(out.dtype)
+        return carry
+
+    lax.fori_loop(0, out.shape[0] // rows, step, 0)
+
+
+def _walk_channel_lanes(ins, out, math):
+    """Block (rows, C) of the channels-in-lanes view: one slab of rows at
+    a time, slab @ band. The band stays in the MXU while a slab's rows
+    stream through it, so a slab is as tall as the chip rewards (128
+    rows ran at 0.53 of the roofline, 256 at 0.68, 512 at 0.71): the
+    most whole tiles of rows, up to _LRN_SLAB_ROWS_MAX, that divide the
+    block."""
+    *ins, band = ins
+    exact = ins[0].dtype.itemsize > 2
+    rows = _largest_divisor(out.shape[0], _sublanes(out.dtype.itemsize),
+                            _LRN_SLAB_ROWS_MAX)
+
+    def step(i, carry):
+        wsum = functools.partial(_band_window_sum, band=band[...], axis=1,
+                                 exact=exact)
+        at = (pl.ds(pl.multiple_of(i * rows, rows), rows), slice(None))
+        out[at] = math(*(a[at] for a in ins), wsum=wsum).astype(out.dtype)
+        return carry
+
+    lax.fori_loop(0, out.shape[0] // rows, step, 0)
+
+
+def _lrn_fwd_kernel(*refs, walk, **scalars):
+    *ins, y_ref = refs
+    walk(ins, y_ref, functools.partial(_lrn_fwd_math, **scalars))
+
+
+def _lrn_bwd_kernel(*refs, walk, **scalars):
+    *ins, out_ref = refs
+    walk(ins, out_ref, functools.partial(_lrn_bwd_math, **scalars))
 
 
 def lrn_vmem_bytes(row_tile: int, c: int, itemsize: int) -> int:
-    """Scoped-VMEM bytes of the LRN pair's worst direction, the
+    """Scoped-VMEM bytes of the FLAT path's worst direction, the
     backward: 2 inputs (x, err) + 1 output, each double-buffered by the
     pipeline, plus its live f32 temporaries — all on lane-PADDED
     (row_tile, ceil(C/128)·128) tiles (C=96 occupies 128 lanes). The
@@ -213,8 +324,60 @@ def lrn_vmem_bytes(row_tile: int, c: int, itemsize: int) -> int:
     return row_tile * c_pad * (2 * 3 * itemsize + _LRN_BWD_F32_TEMPS * 4)
 
 
+def _sublanes(itemsize: int) -> int:
+    """Rows of one (sublanes, 128) tile: 8 of float32, 16 of bfloat16."""
+    return _MIN_ROW_TILE * 4 // itemsize
+
+
+def lrn_view_vmem_bytes(block: Tuple[int, ...], itemsize: int) -> int:
+    """Scoped-VMEM bytes of a view block in the backward: 2 inputs + 1
+    output, double-buffered, on whole tiles — a bfloat16 tile of the
+    batch-in-lanes view is 16 channels x 128 samples. The float32 work
+    is done slab by slab and adds nothing a block's size moves."""
+    sub = _sublanes(itemsize)
+    tiles = -(-block[-2] // sub) * -(-block[-1] // _LANE)
+    return 2 * 3 * int(np.prod(block[:-2])) * tiles * sub * _LANE * itemsize
+
+
+def _largest_divisor(n: int, unit: int, cap: int) -> Optional[int]:
+    """The largest multiple of `unit` that divides n and is <= cap."""
+    for d in range(min(cap, n) // unit * unit, 0, -unit):
+        if n % d == 0:
+            return d
+    return None
+
+
+def lrn_view(shape, itemsize: int):
+    """How the one-pass kernels take an NHWC activation: (walk, view
+    shape, block), or None where no lane-dense view of it divides into
+    whole blocks (the caller then traces the XLA closed form).
+
+    - batch in lanes, (H·W, C, N): C is no multiple of 128 but of 16, N
+      is a multiple of 128 — AlexNet's LRN1 (96 channels) at 1024 a
+      chip, and at 256 a chip under shard_map;
+    - channels in lanes, (H·W·N, C) with rows ordered H, W, N: C is a
+      multiple of 128 — LRN2 (256 channels)."""
+    from veles_tpu.analysis.resources import SCOPED_VMEM_LIMIT
+    if len(shape) != 4:
+        return None
+    n, h, w, c = shape
+    budget = SCOPED_VMEM_LIMIT // 2
+    if c % _LANE == 0:
+        sub = _sublanes(itemsize)
+        rows = _largest_divisor(
+            h * w * n, sub,
+            budget // lrn_view_vmem_bytes((sub, c), itemsize) * sub)
+        return rows and (_walk_channel_lanes, (h * w * n, c), (rows, c))
+    if n % _LANE == 0 and c % _LRN_BATCH_VIEW_C == 0:
+        nb = _largest_divisor(n, _LANE, _LRN_LANE_MAX)
+        r = _largest_divisor(
+            h * w, 1, budget // lrn_view_vmem_bytes((1, c, nb), itemsize))
+        return r and (_walk_batch_lanes, (h * w, c, n), (r, c, nb))
+    return None
+
+
 def _lrn_row_tile(n_rows: int, c: int, itemsize: int) -> int:
-    """The hand-written heuristic: the largest power-of-two tile whose
+    """The flat path's heuristic: the largest power-of-two tile whose
     backward still fits the compiler's scoped-VMEM limit.
     Conv-activation LRN inputs have a few hundred thousand rows (AlexNet
     L1: 1024·55·55), so a min-sublane tile dies of grid overhead; large
@@ -227,13 +390,61 @@ def _lrn_row_tile(n_rows: int, c: int, itemsize: int) -> int:
     return rt
 
 
+def _lrn_pallas_call(kernel, walk, args, spec, grid, k, alpha, beta,
+                     band=None):
+    """`band`: the (C, C) 0/1 window matrix the view walks multiply by,
+    fetched once (its block index never moves)."""
+    consts = [] if band is None else [band]
+    return pl.pallas_call(
+        functools.partial(kernel, walk=walk, k=float(k),
+                          alpha=float(alpha), beta=float(beta)),
+        # under shard_map (the dp step) the result varies over the mesh
+        # axes its operand varies over
+        out_shape=jax.ShapeDtypeStruct(args[0].shape, args[0].dtype,
+                                       vma=jax.typeof(args[0]).vma),
+        grid=grid,
+        in_specs=[spec] * len(args) + [
+            pl.BlockSpec(c.shape, lambda *_: (0, 0),
+                         memory_space=pltpu.VMEM) for c in consts],
+        out_specs=spec,
+        interpret=_interpret(),
+        name=KERNEL_NAMES[kernel.__name__],
+    )(*args, *consts)
+
+
+def _lrn_view_call(kernel, args, view, k, alpha, beta, n: int):
+    """One pass over the activation in the view `lrn_view` picked. The
+    transposes name the physical order the compiler already holds, so
+    they cost nothing; blocks divide the view exactly (no pad, no
+    slice)."""
+    walk, vshape, block = view
+    nb, h, w, c = args[0].shape
+    if walk is _walk_batch_lanes:
+        perm, back, mid = (1, 2, 3, 0), (3, 0, 1, 2), (h, w, c, nb)
+        grid = (vshape[0] // block[0], vshape[2] // block[2])
+        spec = pl.BlockSpec(block, lambda i, j: (i, 0, j),
+                            memory_space=pltpu.VMEM)
+    else:
+        perm, back, mid = (1, 2, 0, 3), (2, 0, 1, 3), (h, w, nb, c)
+        grid = (vshape[0] // block[0],)
+        spec = pl.BlockSpec(block, lambda i: (i, 0),
+                            memory_space=pltpu.VMEM)
+    out = _lrn_pallas_call(
+        kernel, walk, [jnp.transpose(a, perm).reshape(vshape) for a in args],
+        spec, grid, k, alpha, beta,
+        band=_lrn_band(c, n).astype(jnp.bfloat16))
+    return jnp.transpose(out.reshape(mid), back)
+
+
 def _lrn_call(kernel, args, c: int, k, alpha, beta, n: int,
               row_tile: Optional[int] = None, io_dtype: str = "native"):
-    """Common wrapper: flatten leading dims to rows, one row-block per
-    program, full channel width per block (windows stay in-block).
-
-    HBM traffic is the whole game (LRN is bandwidth-bound). The two
-    tuning axes the search owns (ops/templates.py):
+    """The kernels' common wrapper. With no tuning point asked for
+    (`row_tile` None, `io_dtype` "native") an activation that has a
+    lane-dense view goes through it (_lrn_view_call). Otherwise the
+    FLAT path: leading dims flattened to rows, one row-block per
+    program, full channel width per block (windows stay in-block), rows
+    padded to the tile. Its two tuning axes the search owns
+    (ops/templates.py):
     - `row_tile`: rows per block; None = the scoped-VMEM heuristic
       (_lrn_row_tile), which is the hand-written incumbent.
     - `io_dtype`: "native" moves blocks in the caller's dtype (bf16
@@ -243,6 +454,10 @@ def _lrn_call(kernel, args, c: int, k, alpha, beta, n: int,
     Scalars are compile-time constants (lets the pow decompose into
     sqrt/rsqrt — see _pow_neg)."""
     x = args[0]
+    view = row_tile is None and io_dtype == "native" \
+        and lrn_view(x.shape, x.dtype.itemsize)
+    if view:
+        return _lrn_view_call(kernel, args, view, k, alpha, beta, n)
     rows_shape = x.shape[:-1]
     blk_dt = jnp.float32 if io_dtype == "f32" else x.dtype
     x2s = [a.reshape(-1, c).astype(blk_dt) for a in args]
@@ -255,16 +470,9 @@ def _lrn_call(kernel, args, c: int, k, alpha, beta, n: int,
     padded = x2s_p[0].shape[0]
     spec = pl.BlockSpec((row_tile, c), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(kernel, half=n // 2, k=float(k),
-                          alpha=float(alpha), beta=float(beta)),
-        out_shape=jax.ShapeDtypeStruct((padded, c), blk_dt),
-        grid=(padded // row_tile,),
-        in_specs=[spec] * len(x2s_p),
-        out_specs=spec,
-        interpret=_interpret(),
-        name=KERNEL_NAMES[kernel.__name__],
-    )(*x2s_p)
+    out = _lrn_pallas_call(
+        kernel, functools.partial(_walk_whole, half=n // 2), x2s_p, spec,
+        (padded // row_tile,), k, alpha, beta)
     return out[:rows[0]].reshape(rows_shape + (c,)).astype(x.dtype)
 
 
@@ -285,15 +493,27 @@ def lrn_backward_pallas(x, err_y, k: float = 2.0, alpha: float = 1e-4,
                      io_dtype=io_dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
 def lrn_pallas(x, k: float = 2.0, alpha: float = 1e-4, beta: float = 0.75,
                n: int = 5, row_tile: Optional[int] = None,
                io_dtype: str = "native"):
-    """Differentiable fused LRN: Pallas forward AND backward (one VMEM
-    pass each vs several XLA reduce_windows). Measured on v5e 2026-07-29:
-    LRN was ~26% of the AlexNet fused-step time on the XLA path.
-    `row_tile`/`io_dtype` are the searched tuning axes (both passes use
-    the same point — one decision per candidate)."""
+    """Differentiable LRN, forward AND backward one streaming Pallas
+    pass each over the activation in the layout the convs emit — the
+    registry's `pallas_one_pass`. What it traces follows the input: the
+    batch-in-lanes or the channels-in-lanes view where `lrn_view` finds
+    one, and the XLA closed form (`banded_matmul`) for any other shape
+    (a serving ring batch that is no multiple of 128, a narrow test
+    array). `row_tile`/`io_dtype` name a point of the flat path's
+    search space instead (ops/templates.py; both passes use the same
+    point — one decision per candidate)."""
+    if row_tile is None and io_dtype == "native" \
+            and not lrn_view(x.shape, x.dtype.itemsize):
+        from veles_tpu.ops import xla as ox
+        return ox.lrn_forward(x, k, alpha, beta, n)
+    return _lrn_pallas_vjp(x, k, alpha, beta, n, row_tile, io_dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _lrn_pallas_vjp(x, k, alpha, beta, n, row_tile, io_dtype):
     return lrn_forward_pallas(x, k, alpha, beta, n, row_tile, io_dtype)
 
 
@@ -306,7 +526,7 @@ def _lrn_bwd_rule(k, alpha, beta, n, row_tile, io_dtype, x, g):
                                 io_dtype),)
 
 
-lrn_pallas.defvjp(_lrn_fwd_rule, _lrn_bwd_rule)
+_lrn_pallas_vjp.defvjp(_lrn_fwd_rule, _lrn_bwd_rule)
 
 
 # ---------------------------------------------------------------------------

@@ -286,9 +286,10 @@ def _lrn_pallas(x, *, k, alpha, beta, n):
 
 
 register_op(
-    "lrn", default="banded_matmul", fallback="banded_matmul",
+    "lrn", default="pallas_one_pass", fallback="banded_matmul",
     doc="AlexNet across-channel LRN, forward + custom-VJP backward "
-        "(~24% of the AlexNet step after the r4 banded-matmul rewrite)")
+        "(a third of the AlexNet step with its pooling; off a TPU and "
+        "under GSPMD the default resolves to banded_matmul)")
 register(Variant("lrn", "banded_matmul", _lrn_banded,
                  doc="XLA banded-matmul window sum; bwd recomputes s/d"))
 register(Variant("lrn", "cached_residual", _lrn_cached,
@@ -296,8 +297,9 @@ register(Variant("lrn", "cached_residual", _lrn_cached,
                      "residuals: bwd drops one window dot + the pow chain "
                      "for two activation-sized residuals"))
 register(Variant("lrn", "pallas_one_pass", _lrn_pallas, pallas=True,
-                 doc="one-VMEM-pass Pallas kernel pair (native-dtype HBM "
-                     "I/O, sqrt/rsqrt pow)"))
+                 doc="one streaming Pallas pass each way in the layout the "
+                     "convs emit (batch or channels in lanes by the shape; "
+                     "any other shape traces banded_matmul)"))
 
 
 # -- max pooling (fused-step lowering; the knob is the BACKWARD shape) ------
