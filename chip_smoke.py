@@ -50,7 +50,7 @@ ALEXNET = os.path.join(REPO, "veles_tpu", "samples", "alexnet.py")
 
 #: sizes; a scratch rehearsal on the CPU may shrink them (never the repo)
 CFG = {
-    "batch": 1024,          # per chip — the size bench.py settled on
+    "batch": 1024,          # per chip — the `alexnet` cells' size
     "n_train": 2048, "n_validation": 1024, "epochs": 2,
     "extra_steps": 4,       # per-step losses after the CLI run
     "input_hw": 227, "n_classes": 1000,

@@ -146,38 +146,39 @@ def test_lrn_maxpool_pallas_fwd_bwd_compiles(one_chip, site,
 
 # -- the ledger and the compiler agree ----------------------------------------
 
-def _lrn_point(one_chip, site, rt, io):
-    x = _sds(one_chip, (BATCH,) + site, jnp.bfloat16)
-    return (lambda a: jax.grad(lambda v: pk.lrn_pallas(
-        v, 2.0, 1e-4, 0.75, 5, rt, io).astype(jnp.float32).sum())(a)), x
+def _lrn_bwd_through(view):
+    return lambda a, g: pk._lrn_view_call(
+        pk._lrn_bwd_kernel, (a, g), view, 2.0, 1e-4, 0.75, 5)
 
 
-@pytest.mark.parametrize("site,rt,io,fits", [
-    ((55, 55, 96), 2048, "native", True),
-    ((55, 55, 96), 2048, "f32", True),
-    ((27, 27, 256), 1024, "f32", True),
-    ((27, 27, 256), 2048, "native", False),  # 18.74M > 16M
-    ((27, 27, 256), 2048, "f32", False),     # 21.71M > 16M
-])
-def test_lrn_ledger_matches_the_compiler(one_chip, site, rt, io, fits,
-                                         compiled_pallas):
+@pytest.mark.parametrize("batch", (BATCH, BATCH // 4))
+@pytest.mark.parametrize("site", LRN_SITES, ids=lambda s: "x".join(map(str, s)))
+def test_lrn_view_block_matches_the_compiler(one_chip, site, batch,
+                                             compiled_pallas):
+    """The block `lrn_view` picks is priced by `lrn_view_vmem_bytes`
+    under half the limit the kernels compile under, and Mosaic admits
+    its backward (the worst direction) at a chip's batch alone and at
+    its quarter of the 2x2 mesh's."""
+    x = _sds(one_chip, (batch,) + site, jnp.bfloat16)
+    view = pk.lrn_view(x.shape, 2)
+    assert pk.lrn_view_vmem_bytes(view[2], 2) <= res.SCOPED_VMEM_LIMIT // 2
+    assert "tpu_custom_call" in _compile(_lrn_bwd_through(view), x, x)
+
+
+def test_lrn_view_rule_prices_what_the_compiler_refuses(one_chip,
+                                                        compiled_pallas):
     """VMEM_BUDGETS holds the limit the kernels compile under, and the
-    footprint rule prices what Mosaic allocates: a point the compiler
-    refuses is a point the ledger prunes (and the template's axis
-    values that fit do compile)."""
-    name = f"pallas[rt={rt},io={io}]"
-    verdict = res.kernel_verdict(
-        "lrn", name, shapes={"c": site[-1]}, dtype="bfloat16",
-        budget=res.vmem_budget(V5E))
-    assert (verdict is None) == fits, verdict
-    fn, x = _lrn_point(one_chip, site, rt, io)
-    if fits:
-        assert "tpu_custom_call" in _compile(fn, x)
-    else:
-        msg = _refusal(fn, x)
-        assert "exceeded scoped vmem limit" in msg
-        assert "limit 16.00M" in msg
-        assert res.vmem_budget(V5E) == res.SCOPED_VMEM_LIMIT == 16 << 20
+    view rule prices what Mosaic allocates: a hand-made block the rule
+    puts at twice that limit is refused for it."""
+    x = _sds(one_chip, (BATCH,) + LRN_SITES[1], jnp.bfloat16)
+    walk, vshape, _ = pk.lrn_view(x.shape, 2)
+    block = (11664, 256)
+    assert vshape[0] % block[0] == 0
+    assert pk.lrn_view_vmem_bytes(block, 2) >= 2 * res.SCOPED_VMEM_LIMIT
+    msg = _refusal(_lrn_bwd_through((walk, vshape, block)), x, x)
+    assert "exceeded scoped vmem limit" in msg
+    assert "limit 16.00M" in msg
+    assert res.vmem_budget(V5E) == res.SCOPED_VMEM_LIMIT == 16 << 20
 
 
 def test_lrn_maxpool_ledger_prunes_what_the_compiler_refuses(

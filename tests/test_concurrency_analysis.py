@@ -495,9 +495,7 @@ def test_concurrency_and_protocol_repo_clean():
     positive the passes surface in resilience/, the loaders, serving,
     task_queue, web_status and telemetry is fixed or suppressed with a
     written justification."""
-    paths = [os.path.join(REPO, p)
-             for p in ("veles_tpu", "tools")] + \
-        [os.path.join(REPO, "bench.py")]
+    paths = [os.path.join(REPO, p) for p in ("veles_tpu", "tools")]
     assert concurrency.analyze_paths(paths, root=REPO) == []
     assert protocol.analyze_paths(paths, root=REPO) == []
 

@@ -9,8 +9,8 @@ Mechanical off-chip verification of the overlap contract:
 - memmap-fed fused training ships uint8 over the wire (per-batch H2D
   bytes exactly /4 on the image tensor vs the float path, asserted on
   the feed's byte counter) while matching the float path's numerics;
-- bench e2e and _run_with_step consume the SAME feed implementation
-  (contract test — no bespoke loops);
+- the benchmark's fed cell and _run_with_step consume the SAME feed
+  implementation (contract test — no bespoke loops);
 - clean stop() releases the loader's produce threads (the conftest
   leaked-thread check enforces it for every test in the suite).
 """
@@ -475,8 +475,8 @@ def test_feed_ahead_clamped_when_snapshotting(tmp_path):
 
 
 def test_explicit_input_normalize_layer_skips_negotiation(tmp_path):
-    """Graphs that already carry an input_normalize layer (the bench
-    e2e config) keep their own on-device normalize — the negotiation
+    """Graphs that already carry an input_normalize layer keep their
+    own on-device normalize — the negotiation
     must not stack a second prologue on top."""
     from veles_tpu.loader import memmap as mm
     from veles_tpu.znicz.standard_workflow import StandardWorkflow
@@ -568,18 +568,22 @@ def test_heartbeat_carries_feed_counters(tmp_path):
 
 
 def test_contract_bench_and_production_share_the_feed():
-    """ISSUE 5 contract: bench.py's e2e child and the production loop
-    (_run_with_step) consume the SAME DeviceFeed implementation — no
-    bespoke double-buffer loop remains anywhere."""
-    import bench
+    """ISSUE 5 contract: the benchmark's `feed` traffic (the
+    `alexnet.feed` cell) and the production loop (_run_with_step) build
+    the SAME DeviceFeed the same way — no bespoke double-buffer loop
+    remains anywhere."""
+    import os
+
     from veles_tpu.znicz.standard_workflow import StandardWorkflow
 
-    e2e_src = inspect.getsource(bench.e2e_child_main)
+    driver = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "drivers", "train.py")
+    with open(driver) as f:
+        bench_src = f.read()
     run_src = inspect.getsource(StandardWorkflow._run_with_step)
-    assert "DeviceFeed" in e2e_src
-    assert "DeviceFeed" in run_src
+    assert "DeviceFeed.for_step(self.loader, self.step" in bench_src
+    assert "DeviceFeed.for_step(loader, step" in run_src
     # the bespoke transfer the feed replaced must not creep back in
-    assert "jax.device_put(" not in e2e_src
     assert "jax.device_put(" not in run_src
     # and the serving warm path issues its probe through the same put
     from veles_tpu import serving

@@ -177,7 +177,7 @@ def test_two_process_sharded_checkpoint_exact_resume(tmp_path):
 
 
 def test_two_process_input_sharding_halves_host_decode(tmp_path):
-    """Multi-host input sharding (the BASELINE.md per-host claim, made
+    """Multi-host input sharding (the per-host claim, made
     real): with the mesh spanning 2 processes, run_fused wires
     `loader.local_rows_fn` and each host DECODES only the rows its
     shards own — about half — while the trained params match the

@@ -28,7 +28,7 @@ from veles_tpu.ops import templates
 from veles_tpu.ops import variants
 from veles_tpu.znicz.standard_workflow import StandardWorkflow
 
-SEARCH_OPS = ["lrn", "flash_attn", "sgd_update"]
+SEARCH_OPS = ["lrn_maxpool", "flash_attn", "sgd_update"]
 
 
 @pytest.fixture(autouse=True)
@@ -122,8 +122,8 @@ def test_materialize_from_name_alone():
 
 
 @pytest.mark.parametrize("op,name", [
-    ("lrn", "pallas[rt=64,io=f32]"),
-    ("lrn", "pallas[rt=2048,io=native]"),
+    ("maxpool", "gen[algo=slices,fold=linear]"),
+    ("conv_stem", "gen[pack=direct,acc=f32,epi=none]"),
     ("flash_attn", "pallas[blk_q=128,blk_k=256,kv_order=rev,drop=0]"),
     ("flash_attn", "pallas[blk_q=512,blk_k=1024,kv_order=fwd,drop=0]"),
     ("sgd_update", "pallas_rows[rt=8]"),
@@ -342,7 +342,9 @@ def test_autotune_workflow_budget_searches_in_graph(tmp_path):
     that is the whole discovered registry here — maxpool/conv_stem
     gained templates, closing the carried ROADMAP item), sgd_update and
     grad_reduce ride the same budget via their microbenches, and the
-    whole report stays one dict. The budget is deliberately too small
+    whole report stays one dict — `lrn`, which has no template (its
+    two lowerings are chosen by platform and shape), rides the flat
+    enumeration into it. The budget is deliberately too small
     to floor every op: allocation is priority-ordered, so the
     first-discovered ops search and the tail reports 'skipped' — never
     'error'."""
@@ -355,17 +357,20 @@ def test_autotune_workflow_budget_searches_in_graph(tmp_path):
     # budget; the in-graph timer serves the workflow-discovered ops
     assert rep["conv_stem"]["source"] == "searched"
     assert rep["conv_stem"]["timer"] == "in_graph"
-    assert rep["lrn"]["source"] == "searched"
-    assert rep["lrn"]["timer"] == "in_graph"
-    assert rep["lrn"]["trials"] <= 6
+    assert rep["maxpool"]["source"] == "searched"
+    assert rep["maxpool"]["timer"] == "in_graph"
+    assert rep["maxpool"]["trials"] <= 6
     # hand-written incumbents were timed first
-    first = rep["lrn"]["trace"][0]["variant"]
+    first = rep["maxpool"]["trace"][0]["variant"]
     assert "[" not in first
     # the remaining ops ride the same budget — with 6 total trials
     # they are allocated zero and SKIP, never error
-    for op in ("maxpool", "sgd_update", "grad_reduce"):
+    for op in ("lrn_maxpool", "sgd_update", "grad_reduce"):
         assert rep[op]["source"] in ("searched", "skipped"), (op, rep[op])
-    for op in ("lrn", "conv_stem"):
+    assert rep["lrn"]["source"] == "tuned"
+    assert set(rep["lrn"]["timings_s"]) == {"banded_matmul",
+                                            "pallas_one_pass"}
+    for op in ("lrn", "maxpool", "conv_stem"):
         assert variants.effective(op) == rep[op]["variant"]
 
 
@@ -379,9 +384,10 @@ def test_autotune_workflow_budget_covers_whole_registry(tmp_path):
     rep = at.autotune_workflow(wf, steps=1, repeats=1, batch=4,
                                cache_path=str(tmp_path / "c.json"),
                                budget=19)
-    for op in ("lrn", "maxpool", "conv_stem", "sgd_update",
+    for op in ("lrn_maxpool", "maxpool", "conv_stem", "sgd_update",
                "grad_reduce"):
         assert rep[op]["source"] == "searched", (op, rep[op])
+    assert rep["lrn"]["source"] == "tuned"
     assert rep["maxpool"]["timer"] == "in_graph"
     assert rep["grad_reduce"]["timer"] == "microbench"
     # the grad_reduce key is salted with the link geometry: the same
@@ -454,14 +460,16 @@ def test_budget_allocation_weights_by_share():
 def test_search_spends_budget_by_profile_priority(tmp_path):
     templates.clear_ledger()
     prof = tmp_path / "prof.json"
-    prof.write_text(json.dumps({"ops": {"lrn": 0.8,
+    prof.write_text(json.dumps({"ops": {"sgd_update": 0.8,
                                         "flash_attn": 0.1}}))
     rep = at.search_workflow(
         budget=16, ops=SEARCH_OPS, profile_path=str(prof),
         cache=at.AutotuneCache(str(tmp_path / "c.json")))
-    assert rep["lrn"]["priority_share"] == 0.8
-    assert rep["lrn"]["budget"] > rep["flash_attn"]["budget"]
-    assert rep["sgd_update"]["budget"] >= 2
+    assert rep["sgd_update"]["priority_share"] == 0.8
+    assert rep["sgd_update"]["budget"] > rep["flash_attn"]["budget"]
+    # an op the profile does not name still gets its incumbent and one
+    # generated point
+    assert rep["lrn_maxpool"]["budget"] >= 2
 
 
 # ---------------------------------------------------------------------------
@@ -600,7 +608,7 @@ def test_attention_unit_traces_selected_flash_variant():
 
 
 def test_apply_cached_inherits_searched_winners(tmp_path, monkeypatch):
-    """BENCH_AUTOTUNE / standalone --fused inherit SEARCHED decisions:
+    """A standalone --fused start inherits SEARCHED decisions:
     apply_cached probes the searched key (workflow sigs + space
     signature) and applies below-graph ops (sgd_update/flash_attn) by
     their space key — zero timing, generated names re-materialize."""
@@ -608,11 +616,11 @@ def test_apply_cached_inherits_searched_winners(tmp_path, monkeypatch):
     cache_path = str(tmp_path / "c.json")
     wf = _tiny_workflow("ApplyT")
     at.autotune_workflow(wf, steps=1, repeats=1, batch=4,
-                         cache_path=cache_path, budget=5)      # lrn
+                         cache_path=cache_path, budget=5)  # conv_stem
     at.search_op("sgd_update", budget=4,
                  cache=at.AutotuneCache(cache_path))
     searched = {op: variants.effective(op)
-                for op in ("lrn", "sgd_update")}
+                for op in ("conv_stem", "sgd_update")}
     variants.clear_selection()
 
     def boom(*a, **k):
@@ -622,7 +630,7 @@ def test_apply_cached_inherits_searched_winners(tmp_path, monkeypatch):
         monkeypatch.setitem(templates.BENCHES, op, boom)
     wf2 = _tiny_workflow("ApplyT2")
     applied = at.apply_cached(wf2, cache_path=cache_path)
-    assert applied["lrn"] == searched["lrn"]
+    assert applied["conv_stem"] == searched["conv_stem"]
     assert applied["sgd_update"] == searched["sgd_update"]
     for op, name in applied.items():
         assert variants.effective(op) == name
@@ -858,7 +866,7 @@ def test_member_search_suspends_fusion_claim(monkeypatch, tmp_path):
     monkeypatch.setattr(at, "_time_variant", spy_timer)
     templates.clear_ledger()
     wf = _tiny_workflow("SuspendT")
-    at.search_workflow(wf, ops=["lrn"], budget=4,
+    at.search_workflow(wf, ops=["maxpool"], budget=4,
                        cache=at.AutotuneCache(str(tmp_path / "c.json")))
     assert seen and all(s is None for s in seen)
     assert variants.selected("lrn_maxpool") \
@@ -884,8 +892,8 @@ def test_members_tune_before_their_fusion_op(tmp_path, monkeypatch):
     at.search_workflow(budget=8, ops=["lrn_maxpool", "lrn", "maxpool"],
                        profile_path=str(prof),
                        cache=at.AutotuneCache(str(tmp_path / "c.json")))
-    assert order.index("lrn_maxpool") > order.index("lrn")
     assert order.index("lrn_maxpool") > order.index("maxpool")
+    assert "lrn" not in order       # no template: nothing to search
 
 
 def test_variant_table_keeps_unclaimed_sibling_entry():

@@ -35,19 +35,25 @@ def test_sgd_update_matches_host_math():
 
 def test_lrn_forward_matches_golden():
     rng = np.random.RandomState(1)
-    x = rng.randn(2, 5, 5, 16).astype(np.float32)
+    x = rng.randn(16, 2, 3, 256).astype(np.float32)
     gold = ref.lrn_forward(x, 2.0, 1e-4, 0.75, 5)
     got = np.asarray(pk.lrn_forward_pallas(x, 2.0, 1e-4, 0.75, 5))
     np.testing.assert_allclose(got, gold, rtol=1e-4, atol=1e-5)
+    # the kernels take a lane-dense view or nothing: the fallback by
+    # shape is lrn_pallas's, not theirs
+    with pytest.raises(ValueError, match="lrn_view"):
+        pk.lrn_forward_pallas(x[:2, :, :, :16])
 
 
 def test_lrn_backward_matches_golden():
     rng = np.random.RandomState(2)
-    x = rng.randn(2, 4, 4, 16).astype(np.float32)
-    err = rng.randn(2, 4, 4, 16).astype(np.float32)
+    x = rng.randn(16, 2, 3, 256).astype(np.float32)
+    err = rng.randn(16, 2, 3, 256).astype(np.float32)
     gold = ref.lrn_backward(x, err, 2.0, 1e-4, 0.75, 5)
     got = np.asarray(pk.lrn_backward_pallas(x, err, 2.0, 1e-4, 0.75, 5))
     np.testing.assert_allclose(got, gold, rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="lrn_view"):
+        pk.lrn_backward_pallas(x[:2, :, :, :16], err[:2, :, :, :16])
 
 
 # -- LRN in the layout the convs emit (ISSUE 27): the view follows the shape ---
@@ -58,6 +64,10 @@ LRN_VIEW_CASES = {
     "batch_in_lanes": ((128, 2, 3, 96), "_walk_batch_lanes"),
     "channels_in_lanes": ((16, 2, 3, 256), "_walk_channel_lanes"),
     "batch_256_rows_per_iteration": ((256, 5, 5, 32), "_walk_batch_lanes"),
+    # C a multiple of 128 that is no power of two (AlexNet conv3's width)
+    "channels_384": ((8, 3, 3, 384), "_walk_channel_lanes"),
+    # nb is capped at _LRN_LANE_MAX: the grid's second axis is 2
+    "batch_2048_two_lane_blocks": ((2048, 1, 2, 32), "_walk_batch_lanes"),
     "falls_back_batch_100": ((100, 2, 3, 96), None),
     "falls_back_channels_40": ((128, 2, 3, 40), None),
     "falls_back_rows_not_tiles": ((3, 1, 1, 256), None),

@@ -269,7 +269,7 @@ def test_every_template_point_resolves_a_footprint():
                 fp = resources.kernel_footprint(t.op, name)
                 assert fp is not None and fp >= 0, (t.op, name)
                 seen += 1
-    assert seen >= 80       # the registry's current point count
+    assert seen >= 72       # the registry's current point count
 
 
 # ---------------------------------------------------------------------------
@@ -338,19 +338,6 @@ def test_plan_search_timer_includes_incumbent():
     assert any(c.wire == "f32" and c.batch_per_chip == 2048
                for c in timed)
     assert plan["measured_top1"]["config"]["wire"] == "f32"
-
-
-def test_predict_for_bench_block_shape():
-    rec = planner.predict_for_bench(
-        n_params=62378344, train_flops_per_sample=6.81e9,
-        device_kind="TPU v5 lite", n_chips=1, batch_per_chip=1024,
-        zero_active=False)
-    for key in ("step_time_s", "samples_per_sec_per_chip", "comms_s",
-                "comms_bytes", "hbm_highwater_per_device",
-                "memory_verdict", "calibrated"):
-        assert key in rec
-    assert rec["calibrated"] is True
-    assert rec["hbm_highwater_per_device"] > 3 * 4 * 62378344
 
 
 # ---------------------------------------------------------------------------

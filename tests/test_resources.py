@@ -95,32 +95,6 @@ def test_vmem_budget_table_and_overrides(monkeypatch):
     assert res.vmem_budget("cpu", override=77) == 77   # arg beats env
 
 
-def test_lrn_footprint_tracks_blockspec():
-    """(rt, C padded to 128 lanes) blocks x 3 refs x double buffer plus
-    the backward's 7 live f32 temporaries; io width follows the staging
-    dtype, native follows the compute dtype."""
-    f = res.kernel_footprint("lrn", "pallas[rt=512,io=f32]",
-                             shapes={"c": 96})
-    assert f == 512 * 128 * (2 * 3 * 4 + 7 * 4)
-    half = res.kernel_footprint("lrn", "pallas[rt=512,io=native]",
-                                shapes={"c": 96}, dtype="bfloat16")
-    assert half == 512 * 128 * (2 * 3 * 2 + 7 * 4)
-    big = res.kernel_footprint("lrn", "pallas[rt=2048,io=f32]",
-                               shapes={"c": 96})
-    assert big == 4 * f
-    # what the v5e compiler said (PR 21, tests/test_chip_compile.py):
-    # bf16 rt=4096 at C=96 needs 18.83M and is refused, rt=2048 compiles
-    from veles_tpu.ops import pallas_kernels as pk
-    assert pk.lrn_vmem_bytes(4096, 96, 2) > res.SCOPED_VMEM_LIMIT \
-        > pk.lrn_vmem_bytes(2048, 96, 2)
-    assert pk._lrn_row_tile(1024 * 55 * 55, 96, 2) == 2048
-    assert pk._lrn_row_tile(1024 * 27 * 27, 256, 2) == 1024
-    # hand-written incumbents carry no declarative rule: unknown, and
-    # unknown is never pruned
-    assert res.kernel_footprint("lrn", "banded_matmul") is None
-    assert res.kernel_footprint("lrn", "pallas_one_pass") is None
-
-
 def test_flash_footprint_clamps_like_the_kernel():
     """A requested block that flash_fit_block would shrink at the given
     S must cost exactly what the shrunken kernel costs — the pruned
@@ -148,6 +122,10 @@ def test_flash_footprint_clamps_like_the_kernel():
         "flash_attn", "pallas[blk_q=128,blk_k=128,kv_order=fwd,drop=0]",
         shapes={"s": 8192, "d": 64})
     assert small < plain
+    # hand-written incumbents carry no declarative rule: unknown, and
+    # unknown is never pruned
+    assert res.kernel_footprint("lrn", "banded_matmul") is None
+    assert res.kernel_footprint("lrn", "pallas_one_pass") is None
 
 
 def test_fused_composed_point_has_zero_footprint():
@@ -159,15 +137,14 @@ def test_fused_composed_point_has_zero_footprint():
 
 
 def test_kernel_verdict_seeded_and_clean():
-    over = res.kernel_verdict("lrn", "pallas[rt=2048,io=f32]",
-                              shapes={"c": 96}, budget=1 << 20)
+    over = res.kernel_verdict("sgd_update", "pallas_rows[rt=1024]",
+                              budget=1 << 20)
     assert over is not None
     assert over["footprint"] > over["vmem_budget"] == 1 << 20
-    assert res.kernel_verdict("lrn", "pallas[rt=32,io=f32]",
-                              shapes={"c": 96}, budget=1 << 20) is None
+    assert res.kernel_verdict("sgd_update", "pallas_rows[rt=32]",
+                              budget=1 << 20) is None
     # no budget -> no verdict, ever
-    assert res.kernel_verdict("lrn", "pallas[rt=2048,io=f32]",
-                              shapes={"c": 96}) is None
+    assert res.kernel_verdict("sgd_update", "pallas_rows[rt=1024]") is None
 
 
 def test_vmem_over_budget_finding_seeded_and_clean(monkeypatch):
@@ -178,21 +155,23 @@ def test_vmem_over_budget_finding_seeded_and_clean(monkeypatch):
     clean = res.kernel_findings(wf, device_kind="cpu",
                                 budget=1 << 20)
     assert [f for f in clean if f.rule == "vmem-over-budget"] == []
-    variants.get("lrn", "pallas[rt=2048,io=f32]")   # materialize
-    variants.select("lrn", "pallas[rt=2048,io=f32]")
+    point = "fused[rt=8,io=f32,fuse=1]"
+    variants.get("lrn_maxpool", point)   # materialize
+    variants.select("lrn_maxpool", point)
+    band = {"sample_shape": [27, 27, 96]}
     seeded = res.kernel_findings(
-        wf, sigs={"lrn": [{"sample_shape": [27, 27, 96]}]},
+        wf, sigs={"lrn_maxpool": [{"lrn": band, "maxpool": band}]},
         device_kind="cpu", budget=1 << 20)
     hits = [f for f in seeded if f.rule == "vmem-over-budget"]
     assert len(hits) == 1 and hits[0].severity == "error"
-    assert "lrn/pallas[rt=2048,io=f32]" in hits[0].unit
+    assert f"lrn_maxpool/{point}" in hits[0].unit
 
 
 def test_shapes_from_signatures_takes_the_worst_instance():
     sigs = [{"sample_shape": [55, 55, 96]},
             {"sample_shape": [27, 27, 256]}]
-    s = res.shapes_from_signatures("lrn", sigs)
-    assert s == {"c": 256}
+    # an op no footprint rule reads shapes for carries none
+    assert res.shapes_from_signatures("lrn", sigs) == {}
     # the fused pair blocks whole bands: the worst is the largest
     # lane-padded one, kept together (55x55x96 pads to 128 lanes)
     sp = res.shapes_from_signatures(
@@ -228,8 +207,9 @@ def test_shapes_from_signatures_takes_the_worst_instance():
                 "ksize": (3, 3), "stride": (1, 1)})
     assert wide > base
     s3 = res.shapes_from_signatures(
-        "flash_attn", [{"sample_shape": [4096, 512], "head_dim": 64}])
-    assert s3 == {"s": 4096, "d": 64}
+        "flash_attn", [{"sample_shape": [4096, 512], "head_dim": 64},
+                       {"sample_shape": [8192, 256], "head_dim": 32}])
+    assert s3 == {"s": 8192, "d": 64}
 
 
 # ---------------------------------------------------------------------------
@@ -237,18 +217,25 @@ def test_shapes_from_signatures_takes_the_worst_instance():
 # ---------------------------------------------------------------------------
 
 
-def _deterministic_lrn_timer():
+#: the op the pruning tests search: one axis (`rt`, 8..1024 rows of the
+#: update grid) and a footprint rule that needs no shapes — 5,120 B a
+#: row, so a 2 MiB budget makes exactly rt=512 and rt=1024 infeasible
+PRUNED_OP = "sgd_update"
+PRUNE_BUDGET = 2 << 20
+PRUNED_POINTS = {"pallas_rows[rt=512]", "pallas_rows[rt=1024]"}
+
+
+def _deterministic_timer():
     """In-graph-timer stand-in keyed on the SELECTED config — both the
     pruned and unpruned searches elect the same winner deterministically
     (real timings are noise; this test pins the pruning mechanics)."""
-    t = templates.templates_for("lrn")[0]
+    t = templates.templates_for(PRUNED_OP)[0]
 
     def timer():
-        cfg = t.parse(variants.effective("lrn"))
+        cfg = t.parse(variants.effective(PRUNED_OP))
         if cfg is None:                      # a hand-written incumbent
             return 0.5
-        return abs(cfg["rt"] - 128) / 1e5 \
-            + (0.01 if cfg["io"] == "f32" else 0.0)
+        return abs(cfg["rt"] - 128) / 1e5
     return timer
 
 
@@ -258,26 +245,24 @@ def test_pruned_search_times_fewer_trials_same_winner(tmp_path):
     never times a pruned point, and spends NO budget on pruned points;
     outcomes route through veles_autotune_trials_total{outcome}."""
     counter = at._trials_counter()
-    before = counter.labels(op="lrn", outcome="pruned").value
+    before = counter.labels(op=PRUNED_OP, outcome="pruned").value
     templates.clear_ledger()
-    free = at.search_op("lrn", budget=48,
+    free = at.search_op(PRUNED_OP, budget=48,
                         cache=at.AutotuneCache(str(tmp_path / "a.json")),
-                        in_graph_timer=_deterministic_lrn_timer(),
-                        vmem_shapes={"c": 64})
+                        in_graph_timer=_deterministic_timer())
     assert free["source"] == "searched" and free["pruned"] == []
 
-    variants.clear_selection("lrn")
+    variants.clear_selection(PRUNED_OP)
     templates.clear_ledger()
     pruned = at.search_op(
-        "lrn", budget=48,
+        PRUNED_OP, budget=48,
         cache=at.AutotuneCache(str(tmp_path / "b.json")),
-        in_graph_timer=_deterministic_lrn_timer(),
-        vmem_shapes={"c": 64}, vmem_budget=8 << 20)
+        in_graph_timer=_deterministic_timer(),
+        vmem_budget=PRUNE_BUDGET)
     assert pruned["source"] == "searched"
-    # 8 MiB at c=64 makes exactly the rt=2048 points infeasible
-    # (2048 * 128 lanes * 52 B = 13 MiB; rt=1024 is 6.5 MiB)
-    assert set(pruned["pruned"]) == {"pallas[rt=2048,io=f32]",
-                                     "pallas[rt=2048,io=native]"}
+    # 2 MiB makes exactly the rt=512 and rt=1024 points infeasible
+    # (512 rows * 5,120 B = 2.5 MiB; rt=256 is 1.25 MiB)
+    assert set(pruned["pruned"]) == PRUNED_POINTS
     assert pruned["variant"] == free["variant"]          # same winner
     assert pruned["trials"] < free["trials"]             # fewer timed
     # no budget burnt on pruned points: every counted trial is a real
@@ -285,10 +270,10 @@ def test_pruned_search_times_fewer_trials_same_winner(tmp_path):
     prows = [t for t in pruned["trace"] if t["outcome"] == "pruned"]
     assert len(prows) == 2
     for row in prows:
-        assert row["footprint"] > row["vmem_budget"] == 8 << 20
+        assert row["footprint"] > row["vmem_budget"] == PRUNE_BUDGET
     assert pruned["trials"] == len(
         [t for t in pruned["trace"] if t["outcome"] != "pruned"])
-    assert counter.labels(op="lrn", outcome="pruned").value \
+    assert counter.labels(op=PRUNED_OP, outcome="pruned").value \
         == before + 2
 
 
@@ -298,16 +283,16 @@ def test_pruned_point_is_never_timed_property(tmp_path):
     pruned list (no silent caps)."""
     templates.clear_ledger()
     rep = at.search_op(
-        "lrn", budget=48,
+        PRUNED_OP, budget=48,
         cache=at.AutotuneCache(str(tmp_path / "c.json")),
-        in_graph_timer=_deterministic_lrn_timer(),
-        vmem_shapes={"c": 64}, vmem_budget=8 << 20)
+        in_graph_timer=_deterministic_timer(),
+        vmem_budget=PRUNE_BUDGET)
     timed = {t["variant"] for t in rep["trace"]
              if t["outcome"] == "timed"}
     assert timed and not (timed & set(rep["pruned"]))
     for name in rep["pruned"]:
-        assert res.kernel_verdict("lrn", name, shapes={"c": 64},
-                                  budget=8 << 20) is not None
+        assert res.kernel_verdict(PRUNED_OP, name,
+                                  budget=PRUNE_BUDGET) is not None
     with open(tmp_path / "c.json") as f:
         persisted = list(json.load(f)["entries"].values())[0]
     assert set(persisted["pruned"]) == set(rep["pruned"])
@@ -322,10 +307,10 @@ def test_prune_bypass_raises_infeasible_error(tmp_path, monkeypatch):
                         lambda *a, **k: None)
     templates.clear_ledger()
     with pytest.raises(res.InfeasibleCandidateError):
-        at.search_op("lrn", budget=48,
+        at.search_op(PRUNED_OP, budget=48,
                      cache=at.AutotuneCache(str(tmp_path / "d.json")),
-                     in_graph_timer=_deterministic_lrn_timer(),
-                     vmem_shapes={"c": 64}, vmem_budget=8 << 20)
+                     in_graph_timer=_deterministic_timer(),
+                     vmem_budget=PRUNE_BUDGET)
 
 
 def test_search_op_cache_hit_refuses_unfitting_winner(tmp_path):
@@ -335,27 +320,25 @@ def test_search_op_cache_hit_refuses_unfitting_winner(tmp_path):
     apply_cached and falls through to a fresh (pruned) search."""
     cache = at.AutotuneCache(str(tmp_path / "cache.json"))
     templates.clear_ledger()
-    free = at.search_op("lrn", budget=48, cache=cache,
-                        in_graph_timer=_deterministic_lrn_timer(),
-                        vmem_shapes={"c": 64})
+    free = at.search_op(PRUNED_OP, budget=48, cache=cache,
+                        in_graph_timer=_deterministic_timer())
     assert free["source"] == "searched"
     # loosened re-run: the persisted winner fits -> pure cache hit
-    hit = at.search_op("lrn", budget=48, cache=cache,
-                       in_graph_timer=_deterministic_lrn_timer(),
-                       vmem_shapes={"c": 64}, vmem_budget=64 << 20)
+    hit = at.search_op(PRUNED_OP, budget=48, cache=cache,
+                       in_graph_timer=_deterministic_timer(),
+                       vmem_budget=64 << 20)
     assert hit["source"] == "cache" and hit["trials"] == 0
     # tightened re-run below the persisted winner's footprint: the hit
     # is refused and a real search runs, electing a point that fits
-    win_fp = res.kernel_footprint("lrn", free["variant"],
-                                  shapes={"c": 64})
+    win_fp = res.kernel_footprint(PRUNED_OP, free["variant"])
     tight = max(1, win_fp - 1)
-    rerun = at.search_op("lrn", budget=48, cache=cache,
-                         in_graph_timer=_deterministic_lrn_timer(),
-                         vmem_shapes={"c": 64}, vmem_budget=tight)
+    rerun = at.search_op(PRUNED_OP, budget=48, cache=cache,
+                         in_graph_timer=_deterministic_timer(),
+                         vmem_budget=tight)
     assert rerun["source"] == "searched" and rerun["trials"] > 0
     assert free["variant"] in rerun["pruned"]
-    assert res.kernel_verdict("lrn", rerun["variant"],
-                              shapes={"c": 64}, budget=tight) is None
+    assert res.kernel_verdict(PRUNED_OP, rerun["variant"],
+                              budget=tight) is None
 
 
 def test_apply_cached_refuses_unfitting_winner(tmp_path, monkeypatch):

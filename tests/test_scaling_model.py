@@ -57,7 +57,7 @@ def test_overlap_and_bigger_batch_help():
 
 
 def test_flagship_prediction_meets_target():
-    """The headline claim written into ROOFLINE.md: measured r4 numbers
+    """The model's headline use: the round-4 chip numbers
     (62.38M-param AlexNet, 71.07 ms step @1024/chip) predict >=90%
     weak-scaling on a v5e-64 even with zero comm/compute overlap."""
     p = predict_dp_scaling(grad_bytes=62378344 * 4,

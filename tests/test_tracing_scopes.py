@@ -170,12 +170,15 @@ def kernel_names(fn, *args):
 
 
 X4 = np.ones((2, 7, 7, 16), np.float32)
+XV = np.ones((8, 2, 2, 128), np.float32)
 QKV = np.ones((2, 32, 2, 8), np.float32)
 PALLAS_SITES = {
     "sgd_update": (lambda p: pk.sgd_update_pallas(p, p, p, 0.1),
                    np.ones((33, 17), np.float32), ["veles_sgd_update"]),
-    "lrn_fwd": (lambda x: pk.lrn_forward_pallas(x), X4, ["veles_lrn_fwd"]),
-    "lrn_bwd": (lambda x: pk.lrn_backward_pallas(x, x), X4,
+    # the kernels themselves take only a shape with a lane-dense view
+    # (here channels in lanes)
+    "lrn_fwd": (lambda x: pk.lrn_forward_pallas(x), XV, ["veles_lrn_fwd"]),
+    "lrn_bwd": (lambda x: pk.lrn_backward_pallas(x, x), XV,
                 ["veles_lrn_bwd"]),
     # lrn_pallas traces the kernels where the shape has a lane-dense
     # view (here batch in lanes) and the XLA closed form elsewhere

@@ -13,7 +13,6 @@ Three contracts, all CPU-runnable (Pallas via interpret mode):
 """
 
 import json
-import warnings
 
 import jax
 import numpy as np
@@ -53,8 +52,7 @@ def _unique_abs(rs, shape):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("name", ["banded_matmul", "cached_residual",
-                                  "pallas_one_pass"])
+@pytest.mark.parametrize("name", ["banded_matmul", "pallas_one_pass"])
 def test_lrn_variants_match_reference(name):
     rs = np.random.RandomState(3)
     x = rs.randn(2, 3, 3, 16).astype(np.float32)
@@ -210,13 +208,13 @@ def test_registry_validation():
 # ---------------------------------------------------------------------------
 
 
-def _tiny_workflow():
+def _tiny_workflow(n_kernels=8):
     prng.seed_all(1)
     loader = SyntheticClassifierLoader(
         n_classes=4, sample_shape=(12, 12, 3), n_validation=8,
         n_train=16, minibatch_size=4, noise=0.5)
     return StandardWorkflow(
-        layers=[{"type": "conv_strictrelu", "n_kernels": 8, "kx": 5,
+        layers=[{"type": "conv_strictrelu", "n_kernels": n_kernels, "kx": 5,
                  "ky": 5, "stride": (2, 2), "s2d": "auto",
                  "weights_stddev": 0.1},
                 {"type": "norm", "n": 5},
@@ -252,7 +250,7 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     assert all(r["source"] == "tuned" for r in report.values())
     # every candidate was actually timed — incl. pallas in interpret mode
     assert set(report["lrn"]["timings_s"]) == {
-        "banded_matmul", "cached_residual", "pallas_one_pass"}
+        "banded_matmul", "pallas_one_pass"}
     # winners are live registry selections
     for op, r in report.items():
         assert variants.selected(op) == r["variant"]
@@ -408,11 +406,12 @@ def test_registry_choice_changes_traced_lowering():
 def test_fused_step_gspmd_never_traces_pallas():
     """GSPMD auto-partitioning cannot shard a pallas_call: even with the
     pallas LRN selected (and resolvable), a gspmd-mode step must report
-    and trace the non-pallas fallback."""
+    and trace the non-pallas fallback (128 kernels: the LRN input then
+    has a lane-dense view, so the local step does trace the kernels)."""
     import jax as _jax
     from veles_tpu.parallel.mesh import make_mesh
     variants.select("lrn", "pallas_one_pass")
-    wf = _tiny_workflow()
+    wf = _tiny_workflow(n_kernels=128)
     wf.initialize(device=None)
     mesh = make_mesh(_jax.devices()[:1])
     with variants.pallas_interpret():
@@ -422,30 +421,22 @@ def test_fused_step_gspmd_never_traces_pallas():
         assert local.variant_table()["lrn"] == "pallas_one_pass"
 
 
-def test_legacy_knobs_are_deprecation_shims():
-    from veles_tpu.znicz.normalization import LRNormalizerForward
-    from veles_tpu.znicz.pooling import MaxPooling
-    with pytest.deprecated_call():
-        LRNormalizerForward.prefer_pallas = True
-    assert variants.effective("lrn") == "pallas_one_pass"
-    with pytest.deprecated_call():
-        LRNormalizerForward.prefer_pallas = False
-    with pytest.deprecated_call():
-        LRNormalizerForward.cache_bwd = True
-    assert variants.effective("lrn") == "cached_residual"
-    assert LRNormalizerForward.cache_bwd is True
-    with pytest.deprecated_call():
-        LRNormalizerForward.cache_bwd = False
-    assert variants.effective("lrn") == "banded_matmul"
-    with pytest.deprecated_call():
-        MaxPooling.lowering = "slices"
-    assert variants.effective("maxpool") == "slices"
-    assert MaxPooling.lowering == "slices"
-    # the shim validates like select() does
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        with pytest.raises(KeyError):
-            MaxPooling.lowering = "no_such_lowering"
+def test_variant_table_names_the_lrn_lowering_the_shape_traces():
+    """`pallas_one_pass` falls back by shape inside `lrn_pallas`: where
+    resolve gives it but the LRN input has no lane-dense view (8
+    channels, a batch of 4), the table names the XLA form that the step
+    traces — a record never names a lowering no unit traced."""
+    wf = _tiny_workflow()
+    wf.initialize(device=None)
+    with variants.pallas_interpret():
+        step = wf.build_fused_step()
+        lrn = next(u for u in step.forwards
+                   if getattr(u, "variant_op", None) == "lrn")
+        assert variants.resolve("lrn", unit=lrn).name == "pallas_one_pass"
+        assert step.variant_table()["lrn"] == "banded_matmul"
+        x = np.zeros(lrn.input.shape, np.float32)
+        assert "veles_lrn_" not in str(jax.make_jaxpr(
+            lambda a: lrn.fused_apply(None, a))(x))
 
 
 def test_pre_registry_pickles_resolve_without_variant_override():

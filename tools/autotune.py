@@ -1,12 +1,10 @@
 """Autotune the lowering-variant registry for the flagship AlexNet step.
 
-The systematic replacement for the hand-flipped one-offs (tools/ablate.py
-variant flags, tools/ablate_lrn.py): every tunable op the step contains
-(LRN fwd/bwd lowering, max-pooling backward shape, s2d stem, dropout RNG)
-is timed candidate-by-candidate in-graph — the same donated train_repeat
-protocol bench.py measures — and the winner is selected AND persisted in
-the on-disk decision cache, so the next run (bench, training, a second
-autotune) is a pure cache hit. See docs/AUTOTUNE.md.
+Every tunable op the step contains (LRN fwd/bwd lowering, max-pooling
+backward shape, s2d stem, dropout RNG) is timed candidate-by-candidate
+in-graph — the donated train_repeat loop — and the winner is selected AND
+persisted in the on-disk decision cache, so the next run (training, a
+second autotune) is a pure cache hit. See docs/AUTOTUNE.md.
 
 Usage (TPU, full geometry):
     python tools/autotune.py
@@ -55,7 +53,7 @@ def main(argv=None) -> int:
                    help="budgeted coordinate-descent search over the "
                         "GENERATED kernel candidates (ops.templates): "
                         "spend up to N trials across the template-"
-                        "backed ops — workflow ops (lrn) timed in-graph,"
+                        "backed ops — workflow ops (maxpool, …) timed in-graph,"
                         " below-graph ops (flash_attn, sgd_update) via "
                         "their template microbench — priority-ordered "
                         "by LAYER_PROFILE.json; every point equivalence-"
@@ -126,7 +124,7 @@ def main(argv=None) -> int:
     compute_dtype = None if on_cpu else "bfloat16"
     only = [o for o in args.ops.split(",") if o] or None
     if args.budget:
-        # budgeted search across EVERY template-backed op (lrn in-graph
+        # budgeted search across EVERY template-backed op (maxpool in-graph
         # through the flagship step, flash_attn/sgd_update via their
         # microbenches), then the flat enumeration for the rest
         searched = [op for op in templates.template_ops()

@@ -7,7 +7,7 @@ LAYER_PROFILE.json (override: --json PATH or $VELES_LAYER_PROFILE_PATH)
 — the budgeted kernel search (ops.autotune.search_workflow, CLI
 `--autotune-budget`) reads the per-OP cost shares from that file as its
 priority order, so the trial budget is spent on the ops that own the
-roofline gap (ROOFLINE.md). `--trace-json` folds a PR-7 `--trace`
+roofline gap. `--trace-json` folds a PR-7 `--trace`
 capture's span totals into the record, so an on-chip profile carries the
 driver-level context (step/feed/device_sync) next to the per-unit table.
 

@@ -43,8 +43,8 @@ Modes:
 
 The record lands in LOADTEST_RECORD.json (``--swap``:
 SWAP_RECORD.json; env ``VELES_LOADTEST_RECORD_PATH``) and the LAST
-stdout line is the compact ``LOADTEST {...}`` JSON (the bench.py
-driver-parse contract).
+stdout line is the compact ``LOADTEST {...}`` JSON (one parseable
+line, whatever happened).
 """
 
 from __future__ import annotations

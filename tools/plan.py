@@ -8,7 +8,7 @@ model and gates it through the PR-14 VMEM/HBM ledgers; nothing here
 traces, compiles, or touches a device. The compact PLAN line carries
 `jax_backends=<n>` as the per-run proof: it reads the jax backend
 cache AFTER planning, and a static plan must report 0 (tier-1 pins
-it; tools/ablate.py --plan is the measured counterpart).
+it).
 
     python tools/plan.py --chips 8 --kind "TPU v5 lite" --budget 32
 

@@ -2,7 +2,7 @@
 """velint — the project static gate (analysis passes 3-5;
 docs/ANALYSIS.md).
 
-Default run lints `veles_tpu/` + `tools/` + `bench.py` — the per-file
+Default run lints `veles_tpu/` + `tools/` — the per-file
 AST rules (pass 3), the whole-program concurrency pass (pass 4:
 shared-state races, lock-order cycles, wait-under-lock) and the
 protocol pass (pass 5: HTTP endpoint token/body contracts, thread-owner
@@ -41,9 +41,7 @@ from veles_tpu.analysis import protocol  # noqa: E402
 #: ratchet baseline, one suppression syntax
 PASSES = ("lint", "concurrency", "protocol")
 
-#: bench.py rides along since the sync-feed rule exists exactly to keep
-#: step-driver loops (the bench protocol included) on the DeviceFeed
-DEFAULT_PATHS = ("veles_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("veles_tpu", "tools")
 DEFAULT_BASELINE = os.path.join(_REPO_ROOT, "tools",
                                 "velint_baseline.json")
 

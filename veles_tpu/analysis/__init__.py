@@ -20,13 +20,11 @@ Eight passes (docs/ANALYSIS.md has the full rule catalogue):
 - `resources` — static VMEM/HBM footprint pass: kernel VMEM verdicts
   that PRUNE the budgeted search (`--verify-workflow=resources`), and
   the per-device workflow HBM model behind the launcher pre-flight,
-  bench "memory" records and the serving capacity hint.
+  the supervisor's "memory" report and the serving capacity hint.
 - `planner` — the whole-system performance model + budgeted config
   search (docs/PLANNER.md): predicted step time (compute roofline +
   wire-aware comms + feed) over (mesh, batch, ZeRO, wire, fusion),
-  gated by the `resources` ledgers, behind `tools/plan.py`,
-  `tools/ablate.py --plan` and bench's `predicted`/`pred_err`
-  calibration block.
+  gated by the `resources` ledgers, behind `tools/plan.py`.
 - `modelcheck` — bounded protocol model checker: exhaustive
   interleaving + fault-injection exploration of the REAL election /
   membership / hot-swap logic (resilience/cluster.py, serving_watch)

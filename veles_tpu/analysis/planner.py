@@ -67,7 +67,7 @@ from veles_tpu.parallel import scaling_model
 # device constants
 # --------------------------------------------------------------------
 
-#: dense bf16 peak FLOP/s by device kind (bench.py PEAK_TFLOPS)
+#: dense bf16 peak FLOP/s by device kind (v5e: benchmark/peaks.json)
 DEVICE_PEAK_FLOPS: Dict[str, float] = {
     "TPU v5 lite": 197e12,
     "TPU v5e": 197e12,
@@ -236,12 +236,13 @@ def mfu_model(batch_per_chip: float, *, mfu_max: float = MFU_MAX,
 def fusion_gain(device_kind: str,
                 record_path: str = "FUSION_AB_RECORD.json"
                 ) -> Tuple[float, str]:
-    """Whole-step fused/composed speedup claimed by a
-    `tools/ablate.py --fusion` A/B record, applied only when the record
-    was measured on the SAME device kind (a CPU-interpret record must
-    not predict chip behavior). No record is committed — none has been
-    measured on a chip — so the answer today is the neutral
-    "no record". Returns (gain, provenance)."""
+    """Whole-step fused/composed speedup claimed by an A/B record
+    (`arms.composed` / `arms.fused` samples/s), applied only when the
+    record was measured on the SAME device kind (a CPU-interpret record
+    must not predict chip behavior). No record is committed and nothing
+    in the repo writes one — none has been measured on a chip — so the
+    answer today is the neutral "no record". Returns (gain,
+    provenance)."""
     try:
         with open(record_path) as fh:
             rec = json.load(fh)
@@ -731,44 +732,3 @@ def plan_search(geom: Optional[StepGeometry] = None, *,
                                  "measured_step_s":
                                      measured_top1["measured_step_s"]}
     return plan
-
-
-# --------------------------------------------------------------------
-# bench bridge: one predicted block per measured record
-# --------------------------------------------------------------------
-
-def predict_for_bench(*, n_params: int, train_flops_per_sample: float,
-                      device_kind: str, n_chips: int,
-                      batch_per_chip: int, zero_active: bool,
-                      wire: str = "f32", fused: bool = False,
-                      input_hw: int = 227) -> Dict[str, Any]:
-    """The compact `predicted` block bench.py embeds next to every
-    measured record — geometry taken from the bench's OWN counts so
-    the comparison isolates the time model, not the FLOP walk."""
-    geom = StepGeometry(
-        n_params=int(n_params),
-        fwd_flops_per_sample=train_flops_per_sample / 3.0,
-        train_flops_per_sample=float(train_flops_per_sample),
-        per_op_fwd_flops={}, lrn_sites=[], input_hw=int(input_hw),
-        name="bench")
-    cfg = PlanConfig(mesh_shape=(int(n_chips),),
-                     batch_per_chip=int(batch_per_chip),
-                     zero="on" if zero_active else "off",
-                     wire=wire or "f32",
-                     fusion="fused" if fused else "composed")
-    pred = predict_step(cfg, geom, device_kind=device_kind)
-    mem = plan_memory_report(cfg, geom, device_kind=device_kind)
-    return {
-        "step_time_s": pred["step_time_s"],
-        "samples_per_sec": pred["samples_per_sec"],
-        "samples_per_sec_per_chip": pred["samples_per_sec_per_chip"],
-        "compute_s": pred["compute_s"],
-        "comms_s": pred["comms_s"],
-        "comms_bytes": {"dcn": pred["comms"]["dcn_bytes"],
-                        "ici": pred["comms"]["ici_bytes"]},
-        "hbm_highwater_per_device":
-            mem["report"]["highwater_per_device"],
-        "memory_verdict": mem["verdict"],
-        "mfu_at_batch": pred["mfu_at_batch"],
-        "calibrated": pred["calibrated"],
-    }

@@ -86,8 +86,8 @@ PREFLIGHT_ENV = "VELES_RESOURCE_PREFLIGHT"
 #: to its DEFAULT scoped-VMEM limit, whatever the chip's physical VMEM
 #: (128 MiB on a v5e). Asked of the v5e compiler in PR 21 ("Scoped
 #: allocation with size 18.83M and limit 16.00M exceeded scoped vmem
-#: limit"). ONE number: the kernels' own tile heuristics
-#: (pallas_kernels._lrn_row_tile) and the search's pruning both read it.
+#: limit"). ONE number: the kernels' own block rule
+#: (pallas_kernels.lrn_view) and the search's pruning both read it.
 SCOPED_VMEM_LIMIT = 16 << 20
 
 #: per-device_kind VMEM budget (bytes) a Pallas kernel's resident blocks
@@ -238,7 +238,7 @@ def shapes_from_signatures(op: str, sigs) -> Dict[str, Any]:
             if vol > worst_band:
                 worst_band = vol
                 out.update(h=h, w=w, c=c)
-        elif op in ("lrn", "lrn_maxpool") and ss:
+        elif op == "lrn_maxpool" and ss:
             out["c"] = max(out.get("c", 0), int(ss[-1]))
         elif op == "flash_attn" and ss:
             out["s"] = max(out.get("s", 0), int(ss[0]))

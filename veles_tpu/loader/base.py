@@ -314,8 +314,7 @@ class PrefetchingLoader(Loader):
         #: GLOBAL batch rows whose device shards this process owns. Only
         #: those rows are decoded; the rest are zero-filled — the jit's
         #: data-axis in_shardings never transfer or read them, so host
-        #: decode cost divides by the host count (the BASELINE.md
-        #: per-host-sharding claim, made real). Not pickled: re-wired by
+        #: decode cost divides by the host count. Not pickled: re-wired by
         #: the next run.
         self.local_rows_fn = None
         #: decoded-row counter (tests/observability)

@@ -1,14 +1,13 @@
 """DeviceFeed: the one async host->device input pipeline.
 
-The north-star metric is END-TO-END samples/s (BASELINE.md:18 — "the
-north-star metric includes the host pipeline"), and the loader contract
-the reference established is host prep overlapped with device compute
-(SURVEY.md §2.7). Before this module, only `bench.py`'s e2e child got
-the overlap — a hand-rolled async `jax.device_put` double buffer — while
-the production loop (`StandardWorkflow._run_with_step`, everything
-`run_fused`/`run_pipelined`/`--supervise` actually executes) passed host
-numpy straight into the jitted step, paying the H2D transfer
-synchronously inside dispatch, on the critical path.
+The north-star metric is END-TO-END samples/s, host pipeline included
+(the `alexnet.feed` cell of BENCHMARK.json), and the loader contract the
+reference established is host prep overlapped with device compute
+(SURVEY.md §2.7). Without this module the production loop
+(`StandardWorkflow._run_with_step`, everything `run_fused`/
+`run_pipelined`/`--supervise` actually executes) would pass host numpy
+straight into the jitted step, paying the H2D transfer synchronously
+inside dispatch, on the critical path.
 
 `DeviceFeed` wraps any `Loader` and yields device-resident batches ONE
 step ahead: right after step *k* is DISPATCHED (dispatch is async — the

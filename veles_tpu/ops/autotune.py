@@ -5,8 +5,8 @@ Two tiers, one cache:
 
 1. Flat enumeration (PR 2): for each tunable op a workflow contains,
    time every registered hand-written candidate IN-GRAPH — a short
-   donated `train_repeat` microbench of the whole fused step, the same
-   scanned hot loop bench.py measures — pick the fastest.
+   donated `train_repeat` microbench of the whole fused step — pick
+   the fastest.
 2. Budgeted search (`budget=N` / CLI `--autotune-budget N`): ops with a
    registered `KernelTemplate` get coordinate descent over the template
    config space, seeded from the hand-written incumbents, spending a
@@ -501,7 +501,7 @@ def priority_order(ops: List[str],
     capture feeds the same file). Ops the profile doesn't name keep
     their relative order with share 0 — no profile degrades to the
     given order, never to an error. This is how the budget is spent on
-    the ops that own the roofline gap (ROOFLINE.md)."""
+    the ops that own the roofline gap."""
     shares: Dict[str, float] = {}
     path = profile_path or default_profile_path()
     try:
@@ -862,7 +862,7 @@ def search_workflow(wf=None, *, ops: Optional[List[str]] = None,
                     vmem_budget: Optional[int] = None
                     ) -> Dict[str, Dict[str, Any]]:
     """Budgeted search across every template-backed op: workflow ops
-    (lrn, …) time IN-GRAPH through `wf`'s fused step, ops below the unit
+    (maxpool, …) time IN-GRAPH through `wf`'s fused step, ops below the unit
     graph (flash_attn, sgd_update) through their template microbench.
     Priority order and budget split come from LAYER_PROFILE.json. The
     per-op reports include the full trial trace; winners are selected

@@ -1,10 +1,11 @@
 """Pallas TPU kernels for ops where manual fusion/control beats stock XLA.
 
-SURVEY.md §7 lists the candidates: LRN backward (two sliding window sums +
-elementwise chain — one VMEM pass here vs several XLA reduce_windows),
-the fused SGD/momentum update (single read-modify-write over params), and
-flash-attention-style blocks (the ring already handles cross-chip; this
-kernel is the intra-chip tile loop).
+SURVEY.md §7 lists the candidates: LRN forward and backward (one
+streaming pass each in the layout the convs emit — the one kernel pair a
+benchmark cell runs), the fused SGD/momentum update (single
+read-modify-write over params), and flash-attention-style blocks (the
+ring already handles cross-chip; this kernel is the intra-chip tile
+loop).
 
 Every kernel has a lax twin in ops.xla / ops.attention — these are
 drop-in replacements gated by `available()`. Interpret mode is something
@@ -29,26 +30,19 @@ from jax.experimental.pallas import tpu as pltpu
 _FORCE_INTERPRET = False  # tests set this on CPU
 
 # ---------------------------------------------------------------------------
-# Tuning-axis defaults and hardware bounds. Every per-kernel block/tile
-# choice below is a PARAMETER fed from the template config spaces in
-# ops/templates.py (the budgeted autotuner searches them); these module
-# constants are the documented seeds/bounds of those spaces, not
-# per-call-site magic numbers (velint rule `pallas-magic-number` keeps it
-# that way).
+# Hardware bounds and block seeds. The SGD, LRN+maxpool and flash blockings
+# are PARAMETERS fed from the template config spaces in ops/templates.py
+# (the budgeted autotuner searches them), and their constants here are the
+# seeds of those spaces; the LRN kernels' blocks follow from the shape
+# alone (`lrn_view`), and their constants are bounds the chip was measured
+# against. None is a per-call-site magic number (velint rule
+# `pallas-magic-number` keeps it that way).
 # ---------------------------------------------------------------------------
 
 #: VPU/MXU lane width — hardware-fixed, NOT a tuning axis
 _LANE = 128
 #: f32 min sublane tile: the floor every row blocking is clamped to
 _MIN_ROW_TILE = 8
-#: LRN row-tile heuristic bounds: start at the min sublane tile, grow
-#: while the backward's whole footprint (lrn_vmem_bytes) stays inside
-#: the compiler's scoped-VMEM limit (analysis.resources)
-_LRN_TILE_MAX = 4096
-#: f32 (rows, C) temporaries the LRN backward keeps live beside its
-#: pipelined blocks — fitted to what the v5e compiler reports
-#: (18.83M at rt=4096, C=96, bf16: 6M of blocks + 6.4 temporaries)
-_LRN_BWD_F32_TEMPS = 7
 #: the LRN view kernels (ISSUE 27): the most rows of the channels-in-lanes
 #: view one in-kernel slab holds; independent (C, 128) slabs of the
 #: batch-in-lanes view one loop iteration holds; the channel multiple
@@ -111,14 +105,6 @@ KERNEL_NAMES = {
 def _interpret() -> bool:
     from veles_tpu.ops import variants
     return _FORCE_INTERPRET or variants.pallas_interpret_active()
-
-
-def _pad_rows(x2, row_tile: int):
-    rows = x2.shape[0]
-    pad = (-rows) % row_tile
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    return x2, rows
 
 
 # ---------------------------------------------------------------------------
@@ -194,16 +180,6 @@ def sgd_update_pallas(p, g, v, lr, momentum=0.0, weight_decay=0.0,
 # ---------------------------------------------------------------------------
 
 
-def _window_sum(a, half: int):
-    """±half across-channel window sum on a whole (rows, C) VMEM block
-    (the flat path: C may be any width, so pads and slices)."""
-    out = a
-    for d in range(1, half + 1):
-        out = out + jnp.pad(a[:, d:], ((0, 0), (0, d))) \
-            + jnp.pad(a[:, :-d], ((0, 0), (d, 0)))
-    return out
-
-
 def _band_window_sum(a, band, axis: int, exact: bool):
     """±half window sum along `axis` of a float32 slab as a product with
     the 0/1 band on the MXU, accumulated in float32. The chip's rolls
@@ -246,14 +222,6 @@ def _lrn_bwd_math(x, err, *, wsum, k: float, alpha: float, beta: float):
     # of ONE t = s^(−1/4) (the common terms merge), and nothing divides
     d, d_over_s = _pow_neg(s, beta), _pow_neg(s, beta + 1.0)
     return err * d - (2.0 * alpha * beta) * x * wsum(err * x * d_over_s)
-
-
-def _walk_whole(ins, out, math, half: int):
-    """The flat path: the whole (row_tile, C) block at once, the window
-    by pads and slices."""
-    out[:] = math(*(a[:] for a in ins),
-                  wsum=functools.partial(_window_sum, half=half)
-                  ).astype(out.dtype)
 
 
 def _walk_batch_lanes(ins, out, math):
@@ -313,17 +281,6 @@ def _lrn_bwd_kernel(*refs, walk, **scalars):
     walk(ins, out_ref, functools.partial(_lrn_bwd_math, **scalars))
 
 
-def lrn_vmem_bytes(row_tile: int, c: int, itemsize: int) -> int:
-    """Scoped-VMEM bytes of the FLAT path's worst direction, the
-    backward: 2 inputs (x, err) + 1 output, each double-buffered by the
-    pipeline, plus its live f32 temporaries — all on lane-PADDED
-    (row_tile, ceil(C/128)·128) tiles (C=96 occupies 128 lanes). The
-    ONE model behind the tile heuristic below and the search's pruning
-    (ops/templates._lrn_vmem)."""
-    c_pad = -(-c // _LANE) * _LANE
-    return row_tile * c_pad * (2 * 3 * itemsize + _LRN_BWD_F32_TEMPS * 4)
-
-
 def _sublanes(itemsize: int) -> int:
     """Rows of one (sublanes, 128) tile: 8 of float32, 16 of bfloat16."""
     return _MIN_ROW_TILE * 4 // itemsize
@@ -376,47 +333,13 @@ def lrn_view(shape, itemsize: int):
     return None
 
 
-def _lrn_row_tile(n_rows: int, c: int, itemsize: int) -> int:
-    """The flat path's heuristic: the largest power-of-two tile whose
-    backward still fits the compiler's scoped-VMEM limit.
-    Conv-activation LRN inputs have a few hundred thousand rows (AlexNet
-    L1: 1024·55·55), so a min-sublane tile dies of grid overhead; large
-    tiles amortize it."""
-    from veles_tpu.analysis.resources import SCOPED_VMEM_LIMIT
-    rt = _MIN_ROW_TILE
-    while rt < _LRN_TILE_MAX and rt * 2 <= max(n_rows, _MIN_ROW_TILE) \
-            and lrn_vmem_bytes(rt * 2, c, itemsize) <= SCOPED_VMEM_LIMIT:
-        rt *= 2
-    return rt
-
-
-def _lrn_pallas_call(kernel, walk, args, spec, grid, k, alpha, beta,
-                     band=None):
-    """`band`: the (C, C) 0/1 window matrix the view walks multiply by,
-    fetched once (its block index never moves)."""
-    consts = [] if band is None else [band]
-    return pl.pallas_call(
-        functools.partial(kernel, walk=walk, k=float(k),
-                          alpha=float(alpha), beta=float(beta)),
-        # under shard_map (the dp step) the result varies over the mesh
-        # axes its operand varies over
-        out_shape=jax.ShapeDtypeStruct(args[0].shape, args[0].dtype,
-                                       vma=jax.typeof(args[0]).vma),
-        grid=grid,
-        in_specs=[spec] * len(args) + [
-            pl.BlockSpec(c.shape, lambda *_: (0, 0),
-                         memory_space=pltpu.VMEM) for c in consts],
-        out_specs=spec,
-        interpret=_interpret(),
-        name=KERNEL_NAMES[kernel.__name__],
-    )(*args, *consts)
-
-
 def _lrn_view_call(kernel, args, view, k, alpha, beta, n: int):
     """One pass over the activation in the view `lrn_view` picked. The
     transposes name the physical order the compiler already holds, so
     they cost nothing; blocks divide the view exactly (no pad, no
-    slice)."""
+    slice). Scalars are compile-time constants (lets the pow decompose
+    into sqrt/rsqrt — see _pow_neg); the (C, C) 0/1 band the walks
+    multiply by is fetched once (its block index never moves)."""
     walk, vshape, block = view
     nb, h, w, c = args[0].shape
     if walk is _walk_batch_lanes:
@@ -429,101 +352,75 @@ def _lrn_view_call(kernel, args, view, k, alpha, beta, n: int):
         grid = (vshape[0] // block[0],)
         spec = pl.BlockSpec(block, lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
-    out = _lrn_pallas_call(
-        kernel, walk, [jnp.transpose(a, perm).reshape(vshape) for a in args],
-        spec, grid, k, alpha, beta,
-        band=_lrn_band(c, n).astype(jnp.bfloat16))
+    views = [jnp.transpose(a, perm).reshape(vshape) for a in args]
+    band = _lrn_band(c, n).astype(jnp.bfloat16)
+    out = pl.pallas_call(
+        functools.partial(kernel, walk=walk, k=float(k),
+                          alpha=float(alpha), beta=float(beta)),
+        # under shard_map (the dp step) the result varies over the mesh
+        # axes its operand varies over
+        out_shape=jax.ShapeDtypeStruct(vshape, views[0].dtype,
+                                       vma=jax.typeof(views[0]).vma),
+        grid=grid,
+        in_specs=[spec] * len(views) + [
+            pl.BlockSpec(band.shape, lambda *_: (0, 0),
+                         memory_space=pltpu.VMEM)],
+        out_specs=spec,
+        interpret=_interpret(),
+        name=KERNEL_NAMES[kernel.__name__],
+    )(*views, band)
     return jnp.transpose(out.reshape(mid), back)
 
 
-def _lrn_call(kernel, args, c: int, k, alpha, beta, n: int,
-              row_tile: Optional[int] = None, io_dtype: str = "native"):
-    """The kernels' common wrapper. With no tuning point asked for
-    (`row_tile` None, `io_dtype` "native") an activation that has a
-    lane-dense view goes through it (_lrn_view_call). Otherwise the
-    FLAT path: leading dims flattened to rows, one row-block per
-    program, full channel width per block (windows stay in-block), rows
-    padded to the tile. Its two tuning axes the search owns
-    (ops/templates.py):
-    - `row_tile`: rows per block; None = the scoped-VMEM heuristic
-      (_lrn_row_tile), which is the hand-written incumbent.
-    - `io_dtype`: "native" moves blocks in the caller's dtype (bf16
-      under the fused step — HALF the bytes of the old force-f32
-      wrapper) and promotes to f32 only inside VMEM; "f32" stages
-      f32 blocks through HBM (more traffic, no in-kernel casts).
-    Scalars are compile-time constants (lets the pow decompose into
-    sqrt/rsqrt — see _pow_neg)."""
+def _lrn_kernel_call(kernel, args, k, alpha, beta, n: int):
     x = args[0]
-    view = row_tile is None and io_dtype == "native" \
-        and lrn_view(x.shape, x.dtype.itemsize)
-    if view:
-        return _lrn_view_call(kernel, args, view, k, alpha, beta, n)
-    rows_shape = x.shape[:-1]
-    blk_dt = jnp.float32 if io_dtype == "f32" else x.dtype
-    x2s = [a.reshape(-1, c).astype(blk_dt) for a in args]
-    n_rows = x2s[0].shape[0]
-    if row_tile is None:
-        itemsize = max(jnp.dtype(blk_dt).itemsize, 2)
-        row_tile = _lrn_row_tile(n_rows, c, itemsize)
-    row_tile = max(_MIN_ROW_TILE, int(row_tile))
-    x2s_p, rows = zip(*(_pad_rows(a, row_tile) for a in x2s))
-    padded = x2s_p[0].shape[0]
-    spec = pl.BlockSpec((row_tile, c), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
-    out = _lrn_pallas_call(
-        kernel, functools.partial(_walk_whole, half=n // 2), x2s_p, spec,
-        (padded // row_tile,), k, alpha, beta)
-    return out[:rows[0]].reshape(rows_shape + (c,)).astype(x.dtype)
+    view = lrn_view(x.shape, x.dtype.itemsize)
+    if not view:
+        raise ValueError(
+            f"the LRN kernels have no lane-dense view of {x.dtype} "
+            f"{tuple(x.shape)} (pallas_kernels.lrn_view): call lrn_pallas, "
+            "which traces the XLA closed form for such a shape")
+    return _lrn_view_call(kernel, args, view, k, alpha, beta, n)
 
 
 def lrn_forward_pallas(x, k: float = 2.0, alpha: float = 1e-4,
-                       beta: float = 0.75, n: int = 5,
-                       row_tile: Optional[int] = None,
-                       io_dtype: str = "native"):
-    return _lrn_call(_lrn_fwd_kernel, (x,), x.shape[-1], k, alpha, beta,
-                     n, row_tile=row_tile, io_dtype=io_dtype)
+                       beta: float = 0.75, n: int = 5):
+    """The forward kernel; a shape `lrn_view` has no view of raises."""
+    return _lrn_kernel_call(_lrn_fwd_kernel, (x,), k, alpha, beta, n)
 
 
 def lrn_backward_pallas(x, err_y, k: float = 2.0, alpha: float = 1e-4,
-                        beta: float = 0.75, n: int = 5,
-                        row_tile: Optional[int] = None,
-                        io_dtype: str = "native"):
-    return _lrn_call(_lrn_bwd_kernel, (x, err_y), x.shape[-1],
-                     k, alpha, beta, n, row_tile=row_tile,
-                     io_dtype=io_dtype)
+                        beta: float = 0.75, n: int = 5):
+    """The backward kernel; a shape `lrn_view` has no view of raises."""
+    return _lrn_kernel_call(_lrn_bwd_kernel, (x, err_y), k, alpha, beta, n)
 
 
 def lrn_pallas(x, k: float = 2.0, alpha: float = 1e-4, beta: float = 0.75,
-               n: int = 5, row_tile: Optional[int] = None,
-               io_dtype: str = "native"):
+               n: int = 5):
     """Differentiable LRN, forward AND backward one streaming Pallas
     pass each over the activation in the layout the convs emit — the
     registry's `pallas_one_pass`. What it traces follows the input: the
     batch-in-lanes or the channels-in-lanes view where `lrn_view` finds
     one, and the XLA closed form (`banded_matmul`) for any other shape
     (a serving ring batch that is no multiple of 128, a narrow test
-    array). `row_tile`/`io_dtype` name a point of the flat path's
-    search space instead (ops/templates.py; both passes use the same
-    point — one decision per candidate)."""
-    if row_tile is None and io_dtype == "native" \
-            and not lrn_view(x.shape, x.dtype.itemsize):
+    array)."""
+    if not lrn_view(x.shape, x.dtype.itemsize):
         from veles_tpu.ops import xla as ox
         return ox.lrn_forward(x, k, alpha, beta, n)
-    return _lrn_pallas_vjp(x, k, alpha, beta, n, row_tile, io_dtype)
+    return _lrn_pallas_vjp(x, k, alpha, beta, n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def _lrn_pallas_vjp(x, k, alpha, beta, n, row_tile, io_dtype):
-    return lrn_forward_pallas(x, k, alpha, beta, n, row_tile, io_dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _lrn_pallas_vjp(x, k, alpha, beta, n):
+    return lrn_forward_pallas(x, k, alpha, beta, n)
 
 
-def _lrn_fwd_rule(x, k, alpha, beta, n, row_tile, io_dtype):
-    return lrn_forward_pallas(x, k, alpha, beta, n, row_tile, io_dtype), x
+def _lrn_fwd_rule(x, k, alpha, beta, n):
+    return lrn_forward_pallas(x, k, alpha, beta, n), x
 
 
-def _lrn_bwd_rule(k, alpha, beta, n, row_tile, io_dtype, x, g):
-    return (lrn_backward_pallas(x, g, k, alpha, beta, n, row_tile,
-                                io_dtype),)
+def _lrn_bwd_rule(k, alpha, beta, n, x, g):
+    return (lrn_backward_pallas(x, g, k, alpha, beta, n),)
 
 
 _lrn_pallas_vjp.defvjp(_lrn_fwd_rule, _lrn_bwd_rule)
@@ -541,7 +438,8 @@ _lrn_pallas_vjp.defvjp(_lrn_fwd_rule, _lrn_bwd_rule)
 
 def _window_sum_last(a, half: int):
     """±half across-channel window sum over the LAST axis of an N-d
-    block (the 4-D twin of `_window_sum`)."""
+    block (the fused pair's blocks are whole samples of any channel
+    width, so pads and slices)."""
     zeros = [(0, 0)] * (a.ndim - 1)
     out = a
     for d in range(1, half + 1):
@@ -667,7 +565,8 @@ def _lrn_pool_call(kernel, args, out_hwc, k, alpha, beta, n: int,
     """Common wrapper: grid over SAMPLE tiles (each program owns
     `row_tile` whole (H, W, C) bands, so both the channel window and the
     pooling windows stay in-block). `row_tile`/`io_dtype` are the
-    searched axes (ops/templates.py), exactly the LRN pair's.
+    searched axes (ops/templates.py): samples a block, and whether the
+    blocks move in the caller's dtype ("native") or float32 ("f32").
     `n_canvas` f32 VMEM scratch canvases hold the padded LRN output (and,
     backward, the routed error) for the strided window taps."""
     x = args[0]

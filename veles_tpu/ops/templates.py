@@ -8,8 +8,8 @@ doesn't contain. Following "Agentic Operator Generation for ML ASICs"
 
 - a `KernelTemplate` names an op's tuning axes (a typed config space —
   the frozen constants of ops/pallas_kernels.py turned parameters:
-  LRN row-tile + dtype staging, flash-attention blk_q/blk_k/KV-stream
-  order, fused-SGD row blocking) and builds a concrete candidate
+  flash-attention blk_q/blk_k/KV-stream order, fused-SGD row blocking,
+  the fused LRN+maxpool sample tile) and builds a concrete candidate
   callable from any point in the space;
 - every generated point registers through `ops.variants` under a
   parseable name (``base[axis=value,...]``), so resolve()/select()/
@@ -339,8 +339,8 @@ def ledger_table() -> Dict[str, str]:
 # ===========================================================================
 # Microbenches — how a candidate is timed when the op is not reachable
 # through a workflow's fused step (flash_attn / sgd_update live below
-# the unit graph). Workflow ops (lrn) time IN-GRAPH via the PR-2
-# protocol instead; see ops.autotune.
+# the unit graph). An op the workflow's fused step reaches times IN-GRAPH
+# via the PR-2 protocol instead; see ops.autotune.
 # ===========================================================================
 
 BENCHES: Dict[str, Callable[[Callable, int], float]] = {}
@@ -395,76 +395,6 @@ def _dtype_width(dtype) -> int:
 # ===========================================================================
 # Registered templates: the tuning axes of ops/pallas_kernels.py
 # ===========================================================================
-
-# -- lrn: row tile + HBM staging dtype --------------------------------------
-
-def _lrn_build(cfg):
-    def apply(x, *, k, alpha, beta, n):
-        from veles_tpu.ops import pallas_kernels as pk
-        return pk.lrn_pallas(x, k, alpha, beta, n,
-                             row_tile=cfg["rt"], io_dtype=cfg["io"])
-    return apply
-
-
-def _lrn_contract(apply):
-    import jax
-    import numpy as np
-
-    from veles_tpu.ops import reference as ref
-    rs = np.random.RandomState(3)
-    x = rs.randn(2, 4, 4, 16).astype(np.float32)
-    g = rs.randn(2, 4, 4, 16).astype(np.float32)
-    k, alpha, beta, n = 2.0, 1e-4, 0.75, 5
-    y, vjp = jax.vjp(
-        lambda xx: apply(xx, k=k, alpha=alpha, beta=beta, n=n), x)
-    (dx,) = vjp(g)
-    np.testing.assert_allclose(
-        np.asarray(y), ref.lrn_forward(x, k, alpha, beta, n), atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(dx), ref.lrn_backward(x, g, k, alpha, beta, n),
-        atol=2e-5)
-    return {"checked": "lrn fwd+bwd vs ops.reference, atol 2e-5"}
-
-
-def _lrn_bench(apply, repeats):
-    import jax
-    import jax.numpy as jnp
-    shape = (8, 6, 6, 16) if _on_cpu() else (256, 27, 27, 96)
-    x = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.float32)
-
-    def fwd_bwd(xx):
-        y, vjp = jax.vjp(
-            lambda a: apply(a, k=2.0, alpha=1e-4, beta=0.75, n=5), xx)
-        return y, vjp(y)[0]
-
-    return _time_jitted(fwd_bwd, (x,), repeats)
-
-
-def _lrn_vmem(cfg, shapes, dtype):
-    """Both LRN passes block (rt, C); the backward is the worst
-    direction — the kernel file's own model (blocks + f32 temporaries
-    on lane-padded tiles), so the pruned set IS what the compiler
-    refuses (tests/test_chip_compile.py)."""
-    from veles_tpu.ops import pallas_kernels as pk
-    c = int(shapes.get("c") or (16 if _on_cpu() else 96))
-    w = 4 if cfg["io"] == "f32" else _dtype_width(dtype)
-    return pk.lrn_vmem_bytes(cfg["rt"], c, w)
-
-
-register_template(KernelTemplate(
-    op="lrn", base="pallas",
-    axes=(Axis("rt", (32, 64, 128, 256, 512, 1024, 2048),
-               doc="rows per VMEM block (both passes)"),
-          Axis("io", ("native", "f32"),
-               doc="HBM staging dtype: caller's dtype (bf16 under the "
-                   "fused step — half the bytes) vs f32 blocks")),
-    build=_lrn_build, seed={"rt": 512, "io": "native"},
-    vmem_footprint=_lrn_vmem,
-    doc="one-VMEM-pass LRN pair over row-tile x staging-dtype (the "
-        "hand-written pallas_one_pass uses the scoped-VMEM heuristic tile)"))
-CONTRACTS["lrn"] = _lrn_contract
-BENCHES["lrn"] = _lrn_bench
-
 
 # -- flash_attn: block shapes + KV streaming order --------------------------
 
@@ -1219,8 +1149,8 @@ register_template(KernelTemplate(
                    "(H, W, C) band, so channel and pooling windows "
                    "never cross blocks)"),
           Axis("io", ("native", "f32"),
-               doc="HBM staging dtype (the LRN template's axis: "
-                   "caller's dtype vs f32 blocks)"),
+               doc="HBM staging dtype: caller's dtype (bf16 under the "
+                   "fused step) vs f32 blocks"),
           Axis("fuse", (0, 1),
                doc="FUSE axis: 0 = the composed member lowerings (the "
                    "incumbent), 1 = one row-streaming Pallas pass "

@@ -1,25 +1,26 @@
 """Lowering-variant registry: every tunable op's candidate lowerings.
 
-The round-4 headline (+43–51% samples/s) came entirely from swapping op
-lowerings — banded-matmul LRN, the s2d conv stem — yet each variant was a
-hand-flipped class attribute (`LRNormalizerForward.prefer_pallas`,
-`MaxPooling.lowering`, conv `s2d`) exercised only by one-off scripts when
-a chip happened to be up. This module makes the choice systematic, the
-same way VELES solved kernel selection with its per-backend unit registry
-(SURVEY.md §4) and TorchInductor solves it with autotuned lowering choice
-plus a persistent cache (Ansel et al., PAPERS.md):
+The largest early gains came from swapping op lowerings — banded-matmul
+LRN, the s2d conv stem — yet each variant was a hand-flipped class
+attribute exercised only by one-off scripts when a chip happened to be
+up. This module makes the choice systematic, the same way VELES solved
+kernel selection with its per-backend unit registry (SURVEY.md §4) and
+TorchInductor solves it with autotuned lowering choice plus a persistent
+cache (Ansel et al., PAPERS.md):
 
 - every tunable op registers its NAMED candidate lowerings here, each
   carrying an equivalence contract against `ops.reference` (enforced by
   tests/test_variants_autotune.py: fwd AND bwd, Pallas via interpret
   mode on CPU);
-- units consult `resolve()` at fused-step trace time instead of reading
-  scattered class attributes (those attributes survive as deprecation
-  shims that write through to `select()`);
+- units consult `resolve()` at fused-step trace time; nothing else
+  chooses a lowering (the class attributes that once did are gone — a
+  per-instance constructor argument such as `MaxPooling(lowering=...)`
+  is the unit's `variant_override`);
 - the autotuner (`ops.autotune`, `tools/autotune.py`, `--autotune`)
   times candidates in-graph and persists the winner; `selection_table()`
-  is embedded into bench records and the supervisor's exit report so a
-  measured number always names the lowerings that produced it.
+  goes into the supervisor's exit report and the benchmark prints the
+  step's `variant_table()` as its `variants:` line, so a measured number
+  always names the lowerings that produced it.
 
 Adding a variant is ONE `register()` call (see docs/AUTOTUNE.md) — it is
 then automatically equivalence-tested, tunable, cacheable and reported.
@@ -33,7 +34,6 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -41,7 +41,7 @@ __all__ = [
     "Variant", "register_op", "register", "ops", "variants_for", "get",
     "has", "select", "selected", "effective", "clear_selection",
     "selection_table", "resolve", "pallas_ok", "pallas_interpret",
-    "warn_deprecated_knob", "grad_reduce_apply", "grad_reduce_config",
+    "grad_reduce_apply", "grad_reduce_config",
     "grad_reduce_geometry", "grad_reduce_local_request",
     "grad_reduce_resid_len", "grad_reduce_bytes", "q8_encode",
     "q8_decode", "GRAD_REDUCE_LOCAL_ENV", "serve_forward_apply",
@@ -85,7 +85,7 @@ class _OpSpec:
 
 
 _OPS: Dict[str, _OpSpec] = {}
-#: global op -> variant-name selection (autotuner / tools / shims write it)
+#: global op -> variant-name selection (autotuner / tools write it)
 _selection: Dict[str, str] = {}
 _lock = threading.Lock()
 #: tests and the CPU autotune path set this so pallas variants resolve in
@@ -215,7 +215,7 @@ def resolve(op: str, unit: Any = None) -> Variant:
     """The variant a unit must trace NOW. Precedence:
     1. the unit's explicit per-instance `variant_override` (constructor
        knobs like MaxPooling(lowering=...));
-    2. the global selection (autotuner cache / tools / legacy shims);
+    2. the global selection (autotuner cache / tools);
     3. the op's registered default.
     A Pallas variant is swapped for the op's non-pallas fallback in
     exactly two documented cases, each logged once at WARNING when the
@@ -254,14 +254,6 @@ def resolve(op: str, unit: Any = None) -> Variant:
 _FALLBACK_WARNED: set = set()
 
 
-def warn_deprecated_knob(old: str, new: str) -> None:
-    warnings.warn(
-        f"{old} is deprecated: the fused-step build path no longer reads "
-        f"it; this write is shimmed onto the lowering-variant registry "
-        f"({new}). See docs/AUTOTUNE.md.",
-        DeprecationWarning, stacklevel=3)
-
-
 # ===========================================================================
 # Registered ops. apply() bodies lazy-import jax-bearing modules so this
 # module stays importable from jax-free processes (resilience supervisor).
@@ -272,12 +264,7 @@ def warn_deprecated_knob(old: str, new: str) -> None:
 
 def _lrn_banded(x, *, k, alpha, beta, n):
     from veles_tpu.ops import xla as ox
-    return ox.lrn_forward(x, k, alpha, beta, n, cache_bwd=False)
-
-
-def _lrn_cached(x, *, k, alpha, beta, n):
-    from veles_tpu.ops import xla as ox
-    return ox.lrn_forward(x, k, alpha, beta, n, cache_bwd=True)
+    return ox.lrn_forward(x, k, alpha, beta, n)
 
 
 def _lrn_pallas(x, *, k, alpha, beta, n):
@@ -292,10 +279,6 @@ register_op(
         "under GSPMD the default resolves to banded_matmul)")
 register(Variant("lrn", "banded_matmul", _lrn_banded,
                  doc="XLA banded-matmul window sum; bwd recomputes s/d"))
-register(Variant("lrn", "cached_residual", _lrn_cached,
-                 doc="same lowering, forward d=s^(-beta) and s stashed as "
-                     "residuals: bwd drops one window dot + the pow chain "
-                     "for two activation-sized residuals"))
 register(Variant("lrn", "pallas_one_pass", _lrn_pallas, pallas=True,
                  doc="one streaming Pallas pass each way in the layout the "
                      "convs emit (batch or channels in lanes by the shape; "
@@ -353,8 +336,7 @@ register_op(
     doc="searched cross-op fusion of an adjacent (lrn, maxpool) unit "
         "pair: both ops stream the same activation rows, so the fused "
         "Pallas point does LRN then pooling in ONE VMEM pass "
-        "(ops/templates.py; LRN alone was ~24% of the AlexNet step "
-        "pre-Pallas — ROOFLINE.md)")
+        "(ops/templates.py)")
 register(Variant("lrn_maxpool", "composed", _lrn_maxpool_composed,
                  doc="the unfused incumbent: member lowerings traced "
                      "separately (XLA LRN + reduce_window pooling)"))

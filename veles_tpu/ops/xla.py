@@ -315,8 +315,8 @@ def maxpool_forward_slices(x, ksize: Tuple[int, int],
     the (−inf-padded) input — numerically identical to the reduce_window
     flavor, but reverse-mode differentiates into selects + zero-pads
     (elementwise, fusion-friendly) instead of XLA's select_and_scatter.
-    Candidate lowering for the fused step's backward; A/B'd on chip via
-    tools/ablate.py "slicepool" before becoming a default. Each window
+    Candidate lowering for the fused step's backward (the registry's
+    `maxpool` `slices`; `reduce_window` is the default). Each window
     always covers ≥1 real pixel (ceil-mode pads only trailing edges), so
     the fill never wins a window: −inf for plain max; 0 for the abs
     flavor (|−inf| = +inf would win every edge window; |0| only ties an
@@ -446,11 +446,10 @@ def _lrn_band(c: int, n: int):
 
 def _lrn_window_sum(a, n: int):
     """±half across-channel window sum as a BANDED MATMUL on the MXU:
-    a @ B with B the 0/1 band matrix. The r3 shifted-adds lowering (pad+
-    slice per tap) left ~20 intermediate tensors the compiler would not
-    fuse — r4's on-chip ablation measured LRN at 37% of the AlexNet step,
-    i.e. HBM-bound, not compute-bound. As a dot, the window costs
-    negligible MXU FLOPs (C·C per element-row, C∈{96,256}), the x²
+    a @ B with B the 0/1 band matrix. A shifted-adds lowering (pad+
+    slice per tap) leaves ~20 intermediate tensors the compiler will not
+    fuse, so the pass is bound by HBM, not compute. As a dot, the window
+    costs negligible MXU FLOPs (C·C per element-row, C∈{96,256}), the x²
     producer fuses into the operand read, ONE output hits HBM, and the
     f32 accumulator is numerically better than chained low-precision
     adds. The symmetric window is SELF-ADJOINT: its vjp/transpose is
@@ -482,8 +481,7 @@ def _pow_neg_quarters(s, beta: float):
     q=3), decompose into sqrt/rsqrt + multiplies: s^(-q/4) as products of
     squarings of s^(-1/4)=sqrt(rsqrt(s)). The VPU has fast sqrt/rsqrt;
     the generic pow lowers to exp(−beta·log s) — two transcendentals over
-    the full activation, measured as a large slice of the AlexNet step
-    (tools/ablate.py r4: LRN was 37% of the step with the pow form)."""
+    the full activation."""
     q4 = 4.0 * beta
     q = int(round(q4))
     if abs(q4 - q) < 1e-12 and 1 <= q <= 16:
@@ -500,7 +498,7 @@ def _pow_neg_quarters(s, beta: float):
 
 
 def lrn_forward(x, k: float = 2.0, alpha: float = 1e-4, beta: float = 0.75,
-                n: int = 5, cache_bwd: bool = False):
+                n: int = 5):
     """AlexNet-style across-channel LRN: y = x·(k + α·W(x²))^(−β) with W
     the ±half shifted-add window (odd n only — even n would silently
     widen to n+1 taps; the Pallas and C++ twins share the ±half
@@ -508,19 +506,12 @@ def lrn_forward(x, k: float = 2.0, alpha: float = 1e-4, beta: float = 0.75,
 
     custom-VJP: backward is the closed form
         err_x = g·d − 2αβ · x · W(g·x·d/s),  d = s^(−β)
-    (W self-adjoint). Two residual policies, same math:
-    - cache_bwd=False (default): recompute s and d from x in the
-      backward — no residual memory beyond x, but the bwd pays a second
-      window dot (W(x²)) plus the pow chain;
-    - cache_bwd=True: stash d and s from the forward — bwd drops to ONE
-      window dot and zero pow at the cost of two activation-sized
-      residuals (the ROOFLINE.md "cache the forward window-dot" attack;
-      whether the HBM saved beats the residual traffic is an on-chip
-      A/B, tools/ablate_lrn.py)."""
+    (W self-adjoint), with x the only residual: the backward writes s and
+    d again from x, and inside a jitted step XLA's CSE keeps the
+    forward's s where that is cheaper (read in the compiled AlexNet step,
+    PR 27), so stashing them by hand adds a write and removes nothing."""
     if n % 2 == 0:
         raise ValueError(f"LRN window n must be odd, got {n}")
-    if cache_bwd:
-        return _lrn_cvjp_cached(x, k, alpha, beta, n)
     return _lrn_cvjp(x, k, alpha, beta, n)
 
 
@@ -542,27 +533,6 @@ def _lrn_bwd_rule(k, alpha, beta, n, x, g):
 
 
 _lrn_cvjp.defvjp(_lrn_fwd_rule, _lrn_bwd_rule)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def _lrn_cvjp_cached(x, k, alpha, beta, n):
-    s = k + alpha * _lrn_window_sum(x * x, n)
-    return x * _pow_neg_quarters(s, beta)
-
-
-def _lrn_fwd_rule_cached(x, k, alpha, beta, n):
-    s = k + alpha * _lrn_window_sum(x * x, n)
-    d = _pow_neg_quarters(s, beta)
-    return x * d, (x, d, s)
-
-
-def _lrn_bwd_rule_cached(k, alpha, beta, n, res, g):
-    x, d, s = res
-    core = _lrn_window_sum(g * x * d / s, n)
-    return (g * d - (2.0 * alpha * beta) * x * core,)
-
-
-_lrn_cvjp_cached.defvjp(_lrn_fwd_rule_cached, _lrn_bwd_rule_cached)
 
 
 # ---------------------------------------------------------------------------

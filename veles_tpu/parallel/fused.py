@@ -248,7 +248,7 @@ class FusedTrainStep:
         # GSPMD auto-partitioning cannot shard a pallas_call: _forward
         # clears each unit's `allow_pallas` at trace time, and
         # variants.resolve() then substitutes the op's non-pallas
-        # fallback (the registry replaces the old prefer_pallas flip)
+        # fallback
         self.mode = mode
         #: cached identity-jit that gathers cross-process shards to a
         #: replicated array (write_back's host() path); built lazily
@@ -467,9 +467,9 @@ class FusedTrainStep:
     def optimizer_state_bytes(self, state) -> Dict[int, int]:
         """{device_id: bytes} the optimizer-state pytree (state["vel"])
         occupies per device — the measured form of the ZeRO memory claim
-        (bench records, tools/ablate.py --zero, tests), attributed by
-        the SAME shard rule as parallel.memstats (one ledger: a bench
-        record's "device_memory" and this can never silently diverge).
+        (chip_smoke.py --four-chips, tests), attributed by the SAME
+        shard rule as parallel.memstats (one ledger: the two can never
+        silently diverge).
         Host (numpy) leaves occupy zero device bytes and are skipped —
         a measurement must never ALLOCATE device memory to take."""
         from veles_tpu.parallel.memstats import bytes_per_device

@@ -34,7 +34,7 @@ from typing import Any, Dict, Sequence
 
 #: public v5e numbers (scaling-book / cloud docs): one-way ICI bandwidth
 #: per link 4.5e10 B/s, 2 links per torus axis -> 9e10 B/s bidirectional
-#: per axis; dense bf16 peak 197 TFLOP/s (bench.py PEAK_TFLOPS).
+#: per axis; dense bf16 peak 197 TFLOP/s (benchmark/peaks.json).
 V5E_ICI_BW_AXIS_BIDIR = 9.0e10
 
 
@@ -93,7 +93,7 @@ def predict_dp_scaling(*, grad_bytes: float, step_time_s: float,
     }
 
 
-#: v5e dense bf16 peak, FLOP/s (bench.py PEAK_TFLOPS)
+#: v5e dense bf16 peak, FLOP/s (benchmark/peaks.json)
 V5E_PEAK_FLOPS = 197e12
 
 

@@ -422,11 +422,6 @@ def register_standard(reg: MetricsRegistry) -> None:
                 "FusedTrainStep.collective_accounting (byte model in "
                 "docs/SCALING.md)",
                 labelnames=("op", "leg"))
-    reg.counter("veles_collective_seconds_total",
-                "measured wall seconds inside timed collective windows "
-                "(tools/ablate.py --collectives harness; the driver "
-                "models bytes, never syncs for time)",
-                labelnames=("op",))
     reg.gauge("veles_serving_queue_depth",
               "predict requests queued for the serving dispatch loop "
               "(ring admission / merge batcher), sampled at every "

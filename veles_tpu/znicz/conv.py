@@ -50,8 +50,6 @@ class Conv(Forward):
         #: (ops.xla.conv2d_space_to_depth — exact, MXU-tile-friendly):
         #: "auto" = on when stride is square >1 and cin < 8; "on"/"off"
         #: force. Numerics identical either way (equivalence-tested).
-        #: DEFAULT "auto" since r4's on-chip A/B: the rewrite won the
-        #: AlexNet step 8,656 → 9,377 samples/s (tools/ablate.py).
         if s2d not in ("off", "on", "auto"):
             raise ValueError(f"s2d must be 'off'|'on'|'auto', got {s2d!r}")
         if s2d == "on" and not (self.stride[0] == self.stride[1]

@@ -3,10 +3,12 @@
 Parity: reference `veles/znicz/normalization.py` — forward + dedicated
 backward kernel (SURVEY.md §2.8; "normalization" named in BASELINE.json:4).
 
-TPU-first: forward is a reduce_window over the channel axis inside jit; the
-backward is `jax.vjp` of the forward (SURVEY.md §7 listed LRN backward as a
-Pallas candidate — vjp-of-reduce_window fuses well enough on XLA that no
-hand kernel is needed).
+TPU-first: the window sum is a banded matmul on the MXU and the backward
+a closed-form custom VJP (`ops.xla.lrn_forward`). Inside the fused step
+the lowering is the `lrn` registry op's: on a TPU one streaming Pallas
+pass each way where the activation has a lane-dense view
+(`pallas_kernels.lrn_view`), that XLA form for any other shape, backend
+or partitioning.
 """
 
 from __future__ import annotations
@@ -23,48 +25,7 @@ from veles_tpu.ops import xla as ox
 from veles_tpu.znicz.nn_units import Forward, GradientDescentBase, register_gd
 
 
-def _lrn_shim_select() -> None:
-    """Map the legacy two-bool knob state onto ONE registry selection."""
-    variants.select(
-        "lrn",
-        "pallas_one_pass" if LRNormalizerForward._shim_prefer_pallas
-        else ("cached_residual" if LRNormalizerForward._shim_cache_bwd
-              else "banded_matmul"))
-
-
-class _LRNShimMeta(type):
-    """Deprecation shims: `LRNormalizerForward.prefer_pallas = x` /
-    `.cache_bwd = x` (the r4/r5 hand-flip knobs) write through to the
-    lowering-variant registry — the fused-step build path no longer
-    reads these attributes (it consults `variants.resolve("lrn")` at
-    trace time)."""
-
-    @property
-    def prefer_pallas(cls) -> bool:
-        return cls._shim_prefer_pallas
-
-    @prefer_pallas.setter
-    def prefer_pallas(cls, value) -> None:
-        variants.warn_deprecated_knob(
-            "LRNormalizerForward.prefer_pallas",
-            'variants.select("lrn", "pallas_one_pass")')
-        cls._shim_prefer_pallas = bool(value)
-        _lrn_shim_select()
-
-    @property
-    def cache_bwd(cls) -> bool:
-        return cls._shim_cache_bwd
-
-    @cache_bwd.setter
-    def cache_bwd(cls, value) -> None:
-        variants.warn_deprecated_knob(
-            "LRNormalizerForward.cache_bwd",
-            'variants.select("lrn", "cached_residual")')
-        cls._shim_cache_bwd = bool(value)
-        _lrn_shim_select()
-
-
-class LRNormalizerForward(Forward, metaclass=_LRNShimMeta):
+class LRNormalizerForward(Forward):
     """y = x · (k + α·Σ_window x²)^(−β), window of n channels.
 
     Cross-op fusion (ISSUE 13): when the searched `lrn_maxpool` winner
@@ -78,8 +39,7 @@ class LRNormalizerForward(Forward, metaclass=_LRNShimMeta):
     PRECEDING stem conv claim THIS unit's work as its epilogue."""
 
     #: lowering-variant registry op this unit consults at fused trace
-    #: time (candidates: banded_matmul | cached_residual |
-    #: pallas_one_pass; tools/autotune.py picks and persists the winner)
+    #: time (banded_matmul | pallas_one_pass)
     variant_op = "lrn"
 
     def __init__(self, workflow=None, k: float = 2.0, alpha: float = 1e-4,
@@ -110,19 +70,6 @@ class LRNormalizerForward(Forward, metaclass=_LRNShimMeta):
                                     n=self.n))
         return None
 
-    #: DEPRECATED shim state (see _LRNShimMeta): the variant choice lives
-    #: in the registry now; these only back the legacy attribute reads.
-    _shim_prefer_pallas = False
-    _shim_cache_bwd = False
-
-    @property
-    def prefer_pallas(self) -> bool:
-        return type(self)._shim_prefer_pallas
-
-    @property
-    def cache_bwd(self) -> bool:
-        return type(self)._shim_cache_bwd
-
     def variant_signature(self):
         """Autotune cache-key payload (None = not tunable as configured).
         Batch dim excluded ON PURPOSE: winners tuned at one batch must
@@ -134,6 +81,19 @@ class LRNormalizerForward(Forward, metaclass=_LRNShimMeta):
                 "dtype": str(np.asarray(self.input.mem).dtype),
                 "params": {"k": self.k, "alpha": self.alpha,
                            "beta": self.beta, "n": self.n}}
+
+    def variant_effective(self):
+        """The lrn lowering this unit traces, for variant_table(): the
+        Pallas variant falls back by SHAPE inside `lrn_pallas`, so where
+        the input has no lane-dense view the report names the XLA form
+        that runs."""
+        v = variants.resolve("lrn", unit=self)
+        if v.pallas and self.input:
+            from veles_tpu.ops import pallas_kernels as pk
+            if not pk.lrn_view(self.input.shape,
+                               np.asarray(self.input.mem).dtype.itemsize):
+                return "banded_matmul"
+        return v.name
 
     def fused_apply(self, params, x, *, key=None, train=True):
         v = variants.resolve("lrn", unit=self)

@@ -55,23 +55,7 @@ class Pooling(Forward):
         return super().initialize(device=device, **kwargs)
 
 
-class _PoolShimMeta(type):
-    """Deprecation shim: `MaxPooling.lowering = "slices"` (the hand-flip
-    knob) writes through to the lowering-variant registry; the fused
-    build path consults `variants.resolve("maxpool")` at trace time."""
-
-    @property
-    def lowering(cls) -> str:
-        return variants.effective("maxpool")
-
-    @lowering.setter
-    def lowering(cls, value) -> None:
-        variants.warn_deprecated_knob(
-            "MaxPooling.lowering", f'variants.select("maxpool", {value!r})')
-        variants.select("maxpool", value)   # validates the name
-
-
-class MaxPooling(Pooling, metaclass=_PoolShimMeta):
+class MaxPooling(Pooling):
     """Cross-op fusion note (ISSUE 13): when the searched `lrn_maxpool`
     winner is a FUSED point and this unit immediately follows an LRN in
     the fused chain (max flavor only — MaxAbsPooling never fuses — and
