@@ -370,10 +370,12 @@ def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
                                                         compiled_pallas):
     """The default multi-chip step (`run_fused(mesh=make_mesh())`) held
     against a v5e's limit: AlexNet's 0.75 GB of state asks for no
-    sharding, so every chip applies the full update to float32 gradients
-    all-reduced leaf by leaf, and nothing is gathered. Its LRN is the
-    default too: the kernels under shard_map at 256 a chip, LRN1 still
-    batch in lanes, and no relayout around them."""
+    sharding, so every chip applies the full update: to float32 gradients
+    all-reduced leaf by leaf, except FC1's and FC2's (151 and 67 MB
+    against 27 and 17 MB of operands at 256 a chip), which every chip
+    forms whole from all-gathered operands (PR 29); no parameter is
+    gathered. Its LRN is the default too: the kernels under shard_map at
+    256 a chip, LRN1 still batch in lanes, and no relayout around them."""
     monkeypatch.setenv(res.HBM_LIMIT_ENV, str(16_900_000_000))
     step, lowered, compiled = _compile_dp_step_for_2x2(topo, alexnet)
     assert step.variant_table()["lrn"] == "pallas_one_pass"
@@ -391,16 +393,34 @@ def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
     assert not step.zero_active, step.zero_reason
     n_params = _n_alexnet_params(alexnet)
     assert str(12 * n_params) in step.zero_reason
+    assert step.variant_table()["grad_exchange"].startswith(
+        "2 of 8 units gather at 256 rows x 4 chips: 44.0 MB of operands "
+        "all-gathered for 218.1 MB of gradient")
     asked = lowered.as_text()
-    assert "all_reduce" in asked
-    assert "all_gather" not in asked and "reduce_scatter" not in asked
+    assert "all_reduce" in asked and "all_gather" in asked
+    assert "reduce_scatter" not in asked
     txt = compiled.as_text()
-    assert "all-reduce" in txt and "all-gather" not in txt
-    # every all-reduced gradient leaf is float32
+    # every all-reduced gradient leaf is float32, and the two large
+    # dense layers' are not among them: their operands are gathered
     reduced = [line.split(" all-reduce(")[0] for line in txt.splitlines()
                if " all-reduce(" in line]
-    assert any("f32[9216,4096]" in r for r in reduced), reduced
+    assert any("f32[4096,1000]" in r for r in reduced), reduced
+    assert not any("f32[9216,4096]" in r or "f32[4096,4096]" in r
+                   for r in reduced), reduced
     assert not any("bf16[" in r for r in reduced), reduced
+    gathered = [line.split(" all-gather(")[0] for line in txt.splitlines()
+                if " all-gather(" in line]
+    assert any("bf16[1024,9216]" in g for g in gathered), gathered
+    assert any("bf16[1024,4096]" in g for g in gathered), gathered
+    assert not any("f32[" in g for g in gathered), gathered
+    # each of the two updates rides in its weight-gradient fusion, as on
+    # one chip: a fusion that takes the gathered operands and returns the
+    # new float32 weights and velocity
+    for shape in ("f32[9216,4096]", "f32[4096,4096]"):
+        assert any(
+            line.split(" = ")[1].startswith(f"({shape}") and " fusion(" in line
+            and "kind=kOutput" in line
+            for line in txt.splitlines() if " = " in line), shape
     mem = compiled.memory_analysis()
     # per-device arguments: f32 params and the whole velocity
     assert 8 * n_params <= mem.argument_size_in_bytes \

@@ -533,3 +533,243 @@ def test_fused_adam_trains(mesh_kw, mode, eight_devices):
             np.testing.assert_allclose(np.asarray(pa[k]),
                                        np.asarray(pb[k]),
                                        rtol=2e-5, atol=2e-6)
+
+
+# -- a dense layer's weight gradient from gathered operands (PR 29) ----------
+# On a dp mesh the replicated update sums float32 partial gradients with an
+# all-reduce, except for a dense layer whose weights outweigh its batch of
+# activations: there every chip all-gathers the layer's operands and forms
+# the whole gradient itself (ops.xla.dense_gathered_grad), chosen per layer
+# from shapes and the mesh (parallel.fused.dense_grad_form).
+
+#: name -> (layers, sample shape, global batch, units that gather on 4 chips)
+GATHER_NETS = {
+    # 2 rows a chip: 64x512 and the 512x10 head both outweigh their operands
+    "every_dense_gathers": (
+        [{"type": "all2all_tanh", "output_sample_shape": 512,
+          "weights_stddev": 0.05},
+         {"type": "softmax", "output_sample_shape": 10,
+          "weights_stddev": 0.05}], (8, 8), 8, {0, 1}),
+    # 8 rows a chip: the head's 20 KB of gradient is cheaper all-reduced
+    "one_gathers_one_sums": (
+        [{"type": "all2all_tanh", "output_sample_shape": 512,
+          "weights_stddev": 0.05},
+         {"type": "softmax", "output_sample_shape": 10,
+          "weights_stddev": 0.05}], (8, 8), 32, {0}),
+    # a conv leaf never gathers; the 288x256 dense layer behind it does
+    "conv_then_dense": (
+        [{"type": "conv_tanh", "n_kernels": 8, "kx": 3, "ky": 3,
+          "weights_stddev": 0.1},
+         {"type": "all2all_tanh", "output_sample_shape": 256,
+          "weights_stddev": 0.05},
+         {"type": "softmax", "output_sample_shape": 10,
+          "weights_stddev": 0.05}], (8, 8, 1), 32, {1}),
+}
+
+
+def _gather_net(name, **step_kw):
+    """(workflow, step, state) of one of GATHER_NETS, same seed each call."""
+    layers, shape, batch, _ = GATHER_NETS[name]
+    prng.seed_all(1234)
+    loader = SyntheticClassifierLoader(
+        n_classes=10, sample_shape=shape, n_validation=batch,
+        n_train=4 * batch, minibatch_size=batch, noise=0.6)
+    wf = StandardWorkflow(
+        layers=layers, loader=loader, loss="softmax", n_classes=10,
+        decision_config={"max_epochs": 1, "fail_iterations": 50},
+        gd_config={"learning_rate": 0.1, "gradient_moment": 0.9},
+        name="Gather")
+    wf.initialize(device=None)
+    step = wf.build_fused_step(**step_kw)
+    return wf, step, step.init_state()
+
+
+def _gather_batch(name, seed=0):
+    _, shape, batch, _ = GATHER_NETS[name]
+    rng = np.random.RandomState(seed)
+    return (rng.randn(batch, *shape).astype(np.float32),
+            rng.randint(0, 10, batch))
+
+
+def _assert_same_state(local, dp):
+    """Parameters and velocity agree with the local step's, and every
+    chip's copy of every dp leaf is the same to the bit."""
+    for part in ("params", "vel"):
+        for la, lb in zip(local[part], dp[part]):
+            for k in la:
+                np.testing.assert_allclose(
+                    np.asarray(la[k]), np.asarray(lb[k]),
+                    rtol=1e-5, atol=1e-6, err_msg=f"{part} {k}")
+                copies = [np.asarray(s.data)
+                          for s in lb[k].addressable_shards]
+                assert len(copies) == 4
+                for c in copies[1:]:
+                    np.testing.assert_array_equal(copies[0], c)
+
+
+def _collectives(step, state, x, y):
+    """(shapes all-reduced, shapes all-gathered) in the compiled step."""
+    import re
+    txt = step._train_fn.lower(
+        state, x, y, np.ones(len(x), np.float32)).compile().as_text()
+    found = {"all-reduce": [], "all-gather": []}
+    for line in txt.splitlines():
+        m = re.search(r" = (.*?) (all-reduce|all-gather)(-start)?\(", line)
+        if m:
+            found[m.group(2)] += re.findall(r"\w+\[[\d,]*\]", m.group(1))
+    return found["all-reduce"], found["all-gather"]
+
+
+@pytest.mark.parametrize("net", sorted(GATHER_NETS))
+def test_dp_gathered_grad_matches_local(net, eight_devices):
+    """Three dp steps over four chips == three local steps at the global
+    batch, whichever way each leaf's gradient crossed the mesh; the
+    compiled step all-reduces no gathered layer's [in, out] gradient and
+    all-gathers its operands; variant_table() reports what was traced."""
+    gathers = GATHER_NETS[net][3]
+    x, y = _gather_batch(net)
+    _, local, sa = _gather_net(net)
+    wf, dp, sb = _gather_net(
+        net, mesh=make_mesh(eight_devices[:4], data=4), mode="dp")
+    assert "grad_exchange" not in local.variant_table()
+    before = dp.variant_table()["grad_exchange"]
+    for _ in range(3):
+        sa, (loss_a, _) = local.train(sa, x, y)
+        sb, (loss_b, _) = dp.train(sb, x, y)
+        assert float(loss_a) == pytest.approx(float(loss_b), rel=1e-5)
+    _assert_same_state(sa, sb)
+
+    rows = len(x) // 4
+    assert dp._gathered_units(rows) == frozenset(gathers)
+    reduced, gathered = _collectives(dp, sb, x, y)
+    for i, u in enumerate(wf.forwards):
+        w = u.weights.mem
+        shape = "f32[" + ",".join(map(str, w.shape)) + "]"
+        if i in gathers:
+            fan_in, fan_out = w.shape
+            assert shape not in reduced, (shape, reduced)
+            assert f"f32[{len(x)},{fan_in}]" in gathered, gathered
+            assert f"f32[{len(x)},{fan_out}]" in gathered, gathered
+        else:
+            assert shape in reduced, (shape, reduced)
+    assert len(gathered) == 2 * len(gathers)
+    after = dp.variant_table()["grad_exchange"]
+    assert after == before      # the loader's batch is the batch fed
+    n_units = sum(1 for u in wf.forwards if u.weights)
+    assert after.startswith(
+        f"{len(gathers)} of {n_units} units gather at {rows} rows x 4 chips")
+
+
+@pytest.mark.parametrize("case", ["accum", "pad_mask"])
+def test_dp_gathered_grad_accum_and_pad_mask(case, eight_devices):
+    """The gathered gradient under gradient accumulation (two microbatches:
+    each one's gathered gradient is global already, the sum is exchanged
+    by nobody) and with a pad mask that zeroes the last rows of one shard
+    (their dY is zero, so they drop out of the gathered product too)."""
+    net = "one_gathers_one_sums"
+    x, y = _gather_batch(net, seed=3)
+    w = np.ones(len(x), np.float32)
+    w[12:16] = 0.0              # the last rows of the second chip's shard
+    _, local, sa = _gather_net(net)
+    _, dp, sb = _gather_net(
+        net, mesh=make_mesh(eight_devices[:4], data=4), mode="dp")
+    for _ in range(3):
+        if case == "accum":
+            sa, (loss_a, _) = local.train_accum(sa, x, y, 2, w)
+            sb, (loss_b, _) = dp.train_accum(sb, x, y, 2, w)
+        else:
+            sa, (loss_a, _) = local.train(sa, x, y, w)
+            sb, (loss_b, _) = dp.train(sb, x, y, w)
+        assert float(loss_a) == pytest.approx(float(loss_b), rel=1e-5)
+    _assert_same_state(sa, sb)
+    assert dp._gathered_units(len(x) // (8 if case == "accum" else 4)) \
+        == frozenset({0})
+
+
+@pytest.mark.parametrize("n,rows,fan_in,fan_out,itemsize,form", [
+    # VGG-16 at 64 a chip x 4, bfloat16: 411 MB against 15 MB, 67 against
+    # 4.2, 16 against 2.6 (ISSUE 29)
+    (4, 64, 25088, 4096, 2, "gather"),
+    (4, 64, 4096, 4096, 2, "gather"),
+    (4, 64, 4096, 1000, 2, "gather"),
+    # AlexNet at 256 a chip x 4: 151 MB against 27 MB, 67 against 17; the
+    # head's 16 MB against 10 MB of operands and 6 GFLOP is under the
+    # margin
+    (4, 256, 9216, 4096, 2, "gather"),
+    (4, 256, 4096, 4096, 2, "gather"),
+    (4, 256, 4096, 1000, 2, "psum"),
+    # a small layer under a large batch: 0.3 MB against 7 MB
+    (4, 1024, 784, 100, 4, "psum"),
+    # one chip sends nothing either way
+    (1, 64, 25088, 4096, 2, "psum"),
+    (1, 2, 64, 512, 4, "psum"),
+])
+def test_dense_grad_form_table(n, rows, fan_in, fan_out, itemsize, form):
+    from veles_tpu.parallel.fused import dense_grad_form
+    assert dense_grad_form(n, rows, fan_in, fan_out, itemsize) == form
+
+
+@pytest.mark.parametrize("kind", ["zero_on", "ep", "one_shard"])
+def test_grad_exchange_absent_where_nothing_gathers(kind, eight_devices):
+    """variant_table() names `grad_exchange` only where the replicated dp
+    update traced it: not under ZeRO (the reduce-scatter stays), not
+    under EP (autodiff's own psum stays); on a one-chip data axis it
+    reports that nothing gathers."""
+    mesh4 = make_mesh(eight_devices[:4], data=4)
+    if kind == "zero_on":
+        _, step, _ = _gather_net("one_gathers_one_sums", mesh=mesh4,
+                                 mode="dp", zero_sharding="on")
+        assert step.zero_active
+    elif kind == "ep":
+        prng.seed_all(1234)
+        loader = SyntheticClassifierLoader(
+            n_classes=4, sample_shape=(12,), n_validation=32, n_train=128,
+            minibatch_size=32, noise=0.3)
+        wf = StandardWorkflow(
+            layers=[{"type": "moe", "n_experts": 4, "hidden": 16,
+                     "capacity_factor": 4.0, "weights_stddev": 0.2},
+                    {"type": "softmax", "output_sample_shape": 4,
+                     "weights_stddev": 0.05}],
+            loader=loader, loss="softmax", n_classes=4,
+            decision_config={"max_epochs": 1, "fail_iterations": 50},
+            gd_config={"learning_rate": 0.1, "gradient_moment": 0.9},
+            name="GatherEP")
+        wf.initialize(device=None)
+        step = wf.build_fused_step(mesh=mesh4, mode="dp", ep=True)
+    else:
+        _, step, state = _gather_net(
+            "every_dense_gathers",
+            mesh=make_mesh(eight_devices[:1], data=1), mode="dp")
+        x, y = _gather_batch("every_dense_gathers")
+        step.train(state, x, y)
+        assert step.variant_table()["grad_exchange"].startswith(
+            "0 of 2 units gather at 8 rows x 1 chips")
+        return
+    assert step._gathered_units(8) == frozenset()
+    assert "grad_exchange" not in step.variant_table()
+    assert all(u.grad_gather_axis_name is None for u in step.forwards
+               if hasattr(u, "grad_gather_axis_name"))
+
+
+@pytest.mark.parametrize("activation", ["linear", "strictrelu"])
+def test_dense_without_axis_is_plain_autodiff(activation):
+    """With no axis name `all2all_forward` IS what it was: the jaxpr of
+    its value and gradient is that of `act(x2 @ w + b)` written out, so
+    local, gspmd, seq and the one-chip cells trace what they traced."""
+    from veles_tpu.ops import xla as ox
+    rng = np.random.RandomState(0)
+    x = rng.randn(4, 3, 2).astype(np.float32)
+    w = rng.randn(6, 5).astype(np.float32)
+    b = rng.randn(5).astype(np.float32)
+
+    def plain(x, w, b):
+        return ox.act_forward(activation,
+                              x.reshape(x.shape[0], -1) @ w + b).sum()
+
+    def dense(x, w, b):
+        return ox.all2all_forward(x, w, b, activation).sum()
+
+    grad = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2))  # noqa: E731
+    assert str(jax.make_jaxpr(grad(dense))(x, w, b)) \
+        == str(jax.make_jaxpr(grad(plain))(x, w, b))
+    assert "custom_vjp" not in str(jax.make_jaxpr(grad(dense))(x, w, b))

@@ -20,6 +20,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+# private: not exported from jax.lax on the installed jax (0.9.0). The
+# Varying -> Invariant all-gather, whose result shard_map's varying-axes
+# check knows to be the same on every shard (parallel/fused.py takes it
+# from here)
+from jax._src.lax.parallel import all_gather_invariant
 
 TANH_A = 1.7159
 TANH_B = 0.6666
@@ -71,12 +76,53 @@ def act_backward(name: str, y, err, x=None):
 # ---------------------------------------------------------------------------
 
 
-def all2all_forward(x, w, b, activation: str = "linear"):
+def all2all_forward(x, w, b, activation: str = "linear",
+                    grad_gather_axis: Optional[str] = None):
     """y = act(x @ W + b). Flattens trailing dims of x (parity: All2All
     accepts image inputs). The matmul is the MXU hot path — callers feed
-    bf16 inputs under mixed precision; accumulation stays f32."""
+    bf16 inputs under mixed precision; accumulation stays f32.
+
+    `grad_gather_axis` names the mesh axis the batch is sharded over where
+    the BACKWARD is to form the global weight gradient from gathered
+    operands (`dense_gathered_grad`); the fused dp step sets it per layer
+    from shapes (parallel/fused.py, `dense_grad_form`). None, the
+    default, is plain autodiff: this shard's rows only."""
     x2 = x.reshape(x.shape[0], -1)
-    return act_forward(activation, x2 @ w + b)
+    if grad_gather_axis is None:
+        return act_forward(activation, x2 @ w + b)
+    return act_forward(activation,
+                       dense_gathered_grad(x2, w, b, grad_gather_axis))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dense_gathered_grad(x2, w, b, axis_name: str):
+    """x2 @ w + b for rows sharded over `axis_name` under `shard_map`, `w`
+    and `b` the same on every shard (invariant). The backward exchanges
+    the layer's OPERANDS, not its gradient (Krizhevsky 2014,
+    arXiv:1404.5997): every shard all-gathers the rows of `x2` and of
+    `dy` and forms the whole `dW = X^T dY` in one matmul whose
+    contraction runs over the global batch — the sum the one-device
+    program forms at that batch, in the dtype autodiff gives it there.
+    `dW` and `db` come out invariant over the axis: whoever sums
+    per-shard partials must leave these two alone (a `psum` on top would
+    count them once a shard). `dX` stays local."""
+    return x2 @ w + b
+
+
+def _dense_gathered_fwd(x2, w, b, axis_name):
+    return x2 @ w + b, (x2, w, b)
+
+
+def _dense_gathered_bwd(axis_name, res, dy):
+    x2, w, b = res
+    dx = jnp.einsum("bo,io->bi", dy, w).astype(x2.dtype)
+    x_all = all_gather_invariant(x2, axis_name, axis=0, tiled=True)
+    dy_all = all_gather_invariant(dy, axis_name, axis=0, tiled=True)
+    dw = jnp.einsum("bi,bo->io", x_all, dy_all).astype(w.dtype)
+    return dx, dw, dy_all.sum(axis=0).astype(b.dtype)
+
+
+dense_gathered_grad.defvjp(_dense_gathered_fwd, _dense_gathered_bwd)
 
 
 def softmax(x):

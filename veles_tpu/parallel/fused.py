@@ -44,7 +44,11 @@ the replicated update otherwise. Degrades (with a logged reason, see
 `zero_reason`) for local/gspmd/seq modes, EP, single-shard data axes and
 multi-host meshes. The replicated dp update exchanges what the sharded one
 does: each leaf's per-shard partial gradient, summed in float32 by an
-explicit all-reduce under `update/<unit>/grad_exchange`.
+explicit all-reduce under `update/<unit>/grad_exchange` — except for a
+dense layer whose weights outweigh its batch of activations
+(`dense_grad_form`): there every chip all-gathers the layer's operands and
+forms the whole weight gradient itself (Krizhevsky 2014,
+arXiv:1404.5997), and the update has nothing left to exchange.
 
 Names in a profile: every operation of the compiled step carries a
 `jax.named_scope` path that depends on the layer table only, never on a
@@ -75,15 +79,14 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
-# private: the Varying -> Invariant all-gather is not exported from
-# jax.lax on the installed jax (0.9.0). The ZeRO update needs exactly
-# that type — fresh params every replica provably agrees on — so the
-# dp step passes shard_map's varying-axes check instead of disabling it.
-from jax._src.lax.parallel import all_gather_invariant
-
 from veles_tpu import prng
 from veles_tpu.ops import optim
 from veles_tpu.ops import xla as ox
+# the Varying -> Invariant all-gather (private on the installed jax,
+# imported once, in ops/xla.py). The ZeRO update needs exactly that
+# type — fresh params every replica provably agrees on — so the dp step
+# passes shard_map's varying-axes check instead of disabling it.
+from veles_tpu.ops.xla import all_gather_invariant
 from veles_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, SEQ_AXIS,
                                      zero_flatten, zero_plan,
                                      zero_unflatten)
@@ -104,6 +107,60 @@ from veles_tpu.telemetry import tracer as _tracer
 #: VGG-16's 75.4 on a v5e 2x2, 831 against 905 samples/s a chip; PERF.md,
 #: PR 26).
 ZERO_AUTO_STATE_SHARE = 0.5
+
+#: What one FLOP of the gathered form's redundant matmul costs, in bytes
+#: a chip sends over the mesh: the rate a v5e 2x2 sustains on the wire
+#: over the rate its MXU sustains on a training step's matmuls. Wire:
+#: VGG-16 FC1's float32 gradient, 411 MB all-reduced in 7.22 ms, of which
+#: a ring sends 2 x 3/4 a chip: 85 GB/s (PERF.md, PR 26). MXU: 52 % of
+#: 197 TFLOP/s over the whole of `vgg16.step` (ledger, PR 28): 100
+#: TFLOP/s. (Where the matmul's time is the float32 weights and velocity
+#: it reads and writes with its fused update, the redundant FLOP cost
+#: nothing: FC1's 52.6 GFLOP at 256 rows run 2.45 ms for 1.64 GB, my chip
+#: run, PR 29. The ratio prices them where nothing hides them.)
+GRAD_GATHER_WIRE_BYTES_PER_FLOP = 85e9 / 100e12
+
+#: The gathered form has to be this many times cheaper than the
+#: all-reduce before a layer takes it. The chip has confirmed ratios of 6
+#: and more (VGG-16's head, 16.4 MB of gradient against 2.6 MB of
+#: operands, is the nearest: PERF.md, PR 29); nothing nearer to break-even
+#: has been measured, and there the all-reduce has an edge the byte count
+#: does not show: a summed leaf rides in the compiler's one combined
+#: all-reduce, a gathered one adds two collectives of its own.
+GRAD_GATHER_MIN_GAIN = 2.0
+
+
+def dense_grad_wire(n: int, rows: int, fan_in: int, fan_out: int,
+                    itemsize: int) -> Tuple[int, int, int]:
+    """What the two ways of forming a dense layer's weight gradient over
+    `n` chips of `rows` rows each put on the wire and on the MXU a step:
+    (bytes all-reduced: the float32 gradient; bytes all-gathered: the
+    operands `X` and `dY` of all `n` chips in the compute dtype; FLOP of
+    the gathered form's matmul beyond the local one)."""
+    return (4 * fan_in * fan_out,
+            n * rows * (fan_in + fan_out) * itemsize,
+            2 * (n - 1) * rows * fan_in * fan_out)
+
+
+def dense_grad_form(n: int, rows: int, fan_in: int, fan_out: int,
+                    itemsize: int) -> str:
+    """"gather" or "psum" for one dense layer, from shapes and the mesh
+    alone. A ring all-reduce sends 2 (n-1)/n of its bytes a chip, a ring
+    all-gather (n-1)/n; the redundant FLOP are priced in wire bytes. At
+    n == 1 nothing is sent either way, and the form is "psum"."""
+    reduced, gathered, flop = dense_grad_wire(n, rows, fan_in, fan_out,
+                                              itemsize)
+    psum_cost = 2 * (n - 1) / n * reduced
+    gather_cost = ((n - 1) / n * gathered
+                   + GRAD_GATHER_WIRE_BYTES_PER_FLOP * flop)
+    return ("gather" if GRAD_GATHER_MIN_GAIN * gather_cost < psum_cost
+            else "psum")
+
+
+def _unit_param_bytes(u) -> int:
+    """Bytes of a forward unit's parameters, from its HOST-side arrays."""
+    return sum(int(np.asarray(a.mem).nbytes)
+               for a in u.param_arrays().values() if a)
 
 
 def _tree_cast(tree, dtype):
@@ -291,6 +348,11 @@ class FusedTrainStep:
         #: on it, so a mid-life registry re-selection must not split
         #: the state layout from the traced collective)
         self._gr_cache = None
+        #: rows a chip of the last traced train step (what
+        #: variant_table()'s `grad_exchange` reports), and the gathered
+        #: units per row count (see _gathered_units)
+        self._rows_traced = None
+        self._gathered_cache: Dict[int, frozenset] = {}
         self.donate = donate
         self._train_fn = None
         self._eval_fn = None
@@ -341,8 +403,7 @@ class FusedTrainStep:
         velocity per parameter under SGD, two moments under Adam."""
         params = opt = 0
         for u, cfg in zip(self.forwards, self.cfgs):
-            lb = sum(int(np.asarray(a.mem).nbytes)
-                     for a in u.param_arrays().values() if a)
+            lb = _unit_param_bytes(u)
             params += lb
             opt += lb * (2 if isinstance(cfg, optim.AdamConfig) else 1)
         return params, opt
@@ -761,7 +822,16 @@ class FusedTrainStep:
         seq_axis = (SEQ_AXIS if self.mode == "seq" and not local_trace
                     else None)
         ep_axis = DATA_AXIS if self.ep and not local_trace else None
-        for u in self.forwards:
+        gathered = (self._gathered_units(x.shape[0])
+                    if train and not local_trace else frozenset())
+        for i, u in enumerate(self.forwards):
+            if hasattr(u, "grad_gather_axis_name"):
+                # the dense units whose backward forms the GLOBAL weight
+                # gradient from gathered operands: the same set that
+                # _grad_params leaves invariant and the update does not
+                # sum (one predicate, _gathered_units)
+                u.grad_gather_axis_name = (DATA_AXIS if i in gathered
+                                           else None)
             if hasattr(u, "seq_axis_name"):
                 # set at trace time so several step objects (different
                 # modes) over one workflow each trace the right kernel
@@ -803,6 +873,11 @@ class FusedTrainStep:
                      else None)
                 x = u.fused_apply(params[i], x, key=k, train=train)
                 x = self._constrain_tp_act(x, i)
+        for i in gathered:
+            # the traced backward holds the axis name itself; a later
+            # caller of fused_apply outside a dp trace (the pipeline
+            # step) must not find it on the unit
+            self.forwards[i].grad_gather_axis_name = None
         if self.compute_dtype is not None:
             with jax.named_scope("loss"):
                 x = x.astype(jnp.float32)
@@ -922,19 +997,23 @@ class FusedTrainStep:
             # replicated leaves) the transpose of their broadcast is
             # that psum and jax inserts it (vma semantics); on the dp
             # mesh _grad_params makes them varying and the update sums
-            # the partials itself, in float32.
+            # the partials itself, in float32 — all but the leaves of
+            # the dense units in `gathered`, whose backward has formed
+            # the global gradient on every chip already.
             loss, n_err = self._loss_metrics(p, x, y, step_key, True,
                                              w, axes)
             return loss, (loss, n_err)
 
+        gathered = self._gathered_units(x.shape[0])
+        self._rows_traced = x.shape[0]
         (_, (loss, n_err)), grads = jax.value_and_grad(
-            lf, has_aux=True)(self._grad_params(state["params"]))
+            lf, has_aux=True)(self._grad_params(state["params"], gathered))
         if axes:
             # partials with a global denominator: SUM to the global metric
             with jax.named_scope("loss"):
                 loss = lax.psum(loss, axes)
                 n_err = lax.psum(n_err, axes)
-        return self._apply_update(state, grads), loss, n_err
+        return self._apply_update(state, grads, gathered), loss, n_err
 
     def _exchanges_partials(self) -> bool:
         """True where the update itself sums the per-shard partial
@@ -945,7 +1024,43 @@ class FusedTrainStep:
         and seq have no hand-placed exchange.)"""
         return self.mode == "dp" and not self.ep
 
-    def _grad_params(self, params):
+    def _dense_case(self, i: int, rows: int
+                    ) -> Optional[Tuple[int, int, int, int, int]]:
+        """The arguments of `dense_grad_form` / `dense_grad_wire` for
+        forward unit `i` at `rows` rows a chip: (chips on the data axis,
+        rows, fan_in, fan_out, the compute dtype's itemsize) if it is a
+        dense layer that can form its weight gradient from gathered
+        operands (it has the `grad_gather_axis_name` hook and a 2-D
+        weight), else None. A conv never is: its operands are whole
+        feature maps."""
+        u = self.forwards[i]
+        w = u.param_arrays().get("weights")
+        if not hasattr(u, "grad_gather_axis_name") or not w \
+                or len(w.shape) != 2:
+            return None
+        return (self.mesh.shape[DATA_AXIS], rows, int(w.shape[0]),
+                int(w.shape[1]),
+                jnp.dtype(self.compute_dtype or jnp.float32).itemsize)
+
+    def _gathered_units(self, rows: int) -> frozenset:
+        """Indices of the dense units whose weight gradient the backward
+        forms from gathered operands at `rows` rows a chip. THE predicate:
+        `_forward` hands these units the data axis, `_grad_params` leaves
+        their leaves invariant, the update does not sum them, and
+        `variant_table()` reports them. Engages only where the update
+        itself sums partials and every replica applies it whole: the dp
+        step without EP and without ZeRO (whose reduce-scatter would want
+        only the shard's slice of such a gradient; no cell runs it)."""
+        if not self._exchanges_partials() or self.zero_active:
+            return frozenset()
+        if rows not in self._gathered_cache:
+            self._gathered_cache[rows] = frozenset(
+                i for i in range(len(self.forwards))
+                if (case := self._dense_case(i, rows)) is not None
+                and dense_grad_form(*case) == "gather")
+        return self._gathered_cache[rows]
+
+    def _grad_params(self, params, gathered: frozenset):
         """The params autodiff differentiates against. Where the update
         sums the gradients itself they are cast to VARYING over the data
         axis first: the gradient of a varying value is this shard's
@@ -954,11 +1069,16 @@ class FusedTrainStep:
         `cast_params`' output and reduces in the compute dtype. The ZeRO
         update reduce-scatters the partials through the `grad_reduce`
         registry op, the replicated one all-reduces them — one reduction
-        either way, of the same float32 bytes."""
+        either way, of the same float32 bytes. The units in `gathered`
+        keep their leaves INVARIANT: their backward returns the global
+        gradient, the same on every chip (ops.xla.dense_gathered_grad),
+        and nothing is left to reduce."""
         if not self._exchanges_partials():
             return params
-        return jax.tree.map(
-            lambda a: lax.pcast(a, DATA_AXIS, to="varying"), params)
+        return tuple(
+            p if i in gathered else jax.tree.map(
+                lambda a: lax.pcast(a, DATA_AXIS, to="varying"), p)
+            for i, p in enumerate(params))
 
     def _sgd_variant(self):
         """The sgd_update registry variant this step traces — ONE
@@ -974,12 +1094,13 @@ class FusedTrainStep:
             unit=types.SimpleNamespace(
                 allow_pallas=self.mode != "gspmd"))
 
-    def _apply_update(self, state, grads):
+    def _apply_update(self, state, grads, gathered: frozenset):
         """One optimizer step; advances the carried key identically on
         every shard (fold_in of the *unfolded* state key keeps it
         replicated). On a dp mesh the grads arrive UNREDUCED per-shard
         partials and the update performs the reduction itself
-        (reduce-scatter under ZeRO, all-reduce otherwise); elsewhere
+        (reduce-scatter under ZeRO, all-reduce otherwise), except the
+        leaves of the units in `gathered`, which arrive global; elsewhere
         they arrive reduced. The SGD leg resolves through
         the `sgd_update` registry op (default xla_tree IS
         optim.sgd_update; the search-generated pallas row-blocked
@@ -988,20 +1109,24 @@ class FusedTrainStep:
         with jax.named_scope("update"):
             if self.zero_active:
                 return self._apply_update_zero(state, grads)
-            return self._apply_update_replicated(state, grads)
+            return self._apply_update_replicated(state, grads, gathered)
 
-    def _apply_update_replicated(self, state, grads):
-        """Every replica applies the full update. On a dp mesh the grads
-        arrive as per-shard partials (`_grad_params`) and each leaf is
-        all-reduced here in its own dtype, float32; everywhere else they
-        arrive already reduced."""
+    def _apply_update_replicated(self, state, grads, gathered: frozenset):
+        """Every replica applies the full update. On a dp mesh a unit's
+        grads arrive as per-shard partials (`_grad_params`) and each leaf
+        is all-reduced here in its own dtype, float32 — unless the unit
+        is in `gathered`: its backward formed the global gradient from
+        gathered operands, on every chip the same, and a sum over the
+        axis would count it once a chip. Everywhere else grads arrive
+        already reduced."""
         sgd_apply = self._sgd_variant().apply
         exchange = self._exchanges_partials()
         new_params, new_vel = [], []
-        for scope, p, g, v, cfg in zip(self.scopes, state["params"], grads,
-                                       state["vel"], self.cfgs):
+        for i, (scope, p, g, v, cfg) in enumerate(
+                zip(self.scopes, state["params"], grads, state["vel"],
+                    self.cfgs)):
             with jax.named_scope(scope):
-                if p and exchange:
+                if p and exchange and i not in gathered:
                     with jax.named_scope("grad_exchange"):
                         g = {k: lax.psum(a, DATA_AXIS)
                              for k, a in g.items()}
@@ -1108,11 +1233,14 @@ class FusedTrainStep:
         full batch's global weight sum, so the scanned grad SUM equals
         the full-batch mean gradient exactly (pad masks included); on the
         dp mesh the accumulated partials are exchanged once, by the
-        update (where autodiff places the psum, seq and EP, it fires
-        once per microbatch inside the scan)."""
+        update, and a gathered unit's gradient (global in every
+        microbatch already) by nobody (where autodiff places the psum,
+        seq and EP, it fires once per microbatch inside the scan)."""
         axes = (axis,) if isinstance(axis, str) else axis
         step_key = self._shard_step_key(state, axes)
         wsum = self._global_wsum(ws.reshape(-1), 1, axes)
+        gathered = self._gathered_units(xs.shape[1])
+        self._rows_traced = xs.shape[1]
 
         def micro(carry, xyw):
             acc, loss_a, err_a, i = carry
@@ -1130,7 +1258,7 @@ class FusedTrainStep:
             return (acc, loss_a + loss,
                     err_a + n_err.astype(jnp.float32), i + 1), None
 
-        gparams = self._grad_params(state["params"])
+        gparams = self._grad_params(state["params"], gathered)
         zero = jax.tree.map(jnp.zeros_like, gparams)
         # the metric carries must be device-varying from step 0 under
         # shard_map (they mix with varying per-shard partials); deriving
@@ -1143,7 +1271,7 @@ class FusedTrainStep:
             n_err = lax.psum(n_err, axes)
         if self.loss_kind == "softmax":
             n_err = n_err.astype(jnp.int32)
-        return self._apply_update(state, grads), loss, n_err
+        return self._apply_update(state, grads, gathered), loss, n_err
 
     def _eval_body(self, params, x, y, w, *, axis):
         axes = (axis,) if isinstance(axis, str) else axis
@@ -1542,7 +1670,44 @@ class FusedTrainStep:
             # the replicated SGD leg resolves through the registry (see
             # _apply_update); ZeRO's slice-wise update does not.
             table["sgd_update"] = self._sgd_variant().name
+        exchange = self.grad_exchange()
+        if exchange is not None:
+            table["grad_exchange"] = exchange
         return table
+
+    def grad_exchange(self) -> Optional[str]:
+        """How the replicated dp update's gradients cross the mesh a step,
+        at the rows a chip the step last traced (before any trace: the
+        rows the units were initialised for): the dense units whose
+        weight gradient is formed from gathered operands, over the units
+        with parameters; the operand bytes that puts on the wire and the
+        gradient bytes it takes off; the gradient bytes still all-reduced.
+        None where the update exchanges no partials of its own or shards
+        them (local, gspmd, seq, EP, ZeRO): a report never names what the
+        step did not trace."""
+        if not self._exchanges_partials() or self.zero_active:
+            return None
+        n = self.mesh.shape[DATA_AXIS]
+        rows = self._rows_traced
+        if rows is None:
+            first = getattr(self.forwards[0], "input", None)
+            if not first:
+                return None
+            rows = first.shape[0] // n
+        gathered = self._gathered_units(rows)
+        off = on = summed = with_params = 0
+        for i, u in enumerate(self.forwards):
+            lb = _unit_param_bytes(u)
+            with_params += bool(lb)
+            if i in gathered:
+                off += lb
+                on += dense_grad_wire(*self._dense_case(i, rows))[1]
+            else:
+                summed += lb
+        return (f"{len(gathered)} of {with_params} units gather at {rows} "
+                f"rows x {n} chips: {on / 1e6:.1f} MB of operands "
+                f"all-gathered for {off / 1e6:.1f} MB of gradient not "
+                f"all-reduced, {summed / 1e6:.1f} MB all-reduced")
 
     def evaluate(self, state, x, y, w=None):
         """Forward-only metrics (validation/test minibatches)."""

@@ -29,6 +29,13 @@ class All2All(Forward):
 
     activation = "linear"
 
+    #: the mesh axis over which `fused_apply`'s backward gathers its
+    #: operands to form the GLOBAL weight gradient (ops.xla.
+    #: dense_gathered_grad), or None for plain autodiff. Set by
+    #: FusedTrainStep._forward at trace time, as `seq_axis_name` and
+    #: `ep_axis_name` are on the units that have them.
+    grad_gather_axis_name = None
+
     def __init__(self, workflow=None,
                  output_sample_shape: Union[int, Sequence[int]] = 10,
                  **kwargs: Any) -> None:
@@ -59,7 +66,8 @@ class All2All(Forward):
 
     def fused_apply(self, params, x, *, key=None, train=True):
         y = ox.all2all_forward(x, params["weights"], params["bias"],
-                               self.activation)
+                               self.activation,
+                               grad_gather_axis=self.grad_gather_axis_name)
         return y.reshape((-1,) + self.output_sample_shape)
 
     def numpy_run(self) -> None:
@@ -127,4 +135,6 @@ class All2AllSoftmax(All2All):
     fused_emits_logits = True
 
     def fused_apply(self, params, x, *, key=None, train=True):
-        return ox.all2all_forward(x, params["weights"], params["bias"])
+        return ox.all2all_forward(
+            x, params["weights"], params["bias"],
+            grad_gather_axis=self.grad_gather_axis_name)
