@@ -425,3 +425,70 @@ def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
     # per-device arguments: f32 params and the whole velocity
     assert 8 * n_params <= mem.argument_size_in_bytes \
         < 8 * n_params + (256 << 20)
+
+
+# -- the sparse-expert language model's step at its published widths (ISSUE 32) --
+
+def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip):
+    """`benchmark/configs/xing4_ep8.json` through the sample's layer table,
+    `StandardWorkflow` and `FusedTrainStep`: 8,192 tokens, bfloat16, one
+    `jax.checkpoint` a block. The grouped products of the held experts
+    lower to the TPU's grouped-matmul kernel (`lax.ragged_dot`), so the
+    program holds custom calls without a `pallas_call` of this repo's; a
+    shape or memory fault shows here before a chip is asked. The units
+    hold zeros (`init_std` 0: no draw), nothing is put on a device."""
+    import json
+
+    from veles_tpu.loader.fullbatch import FullBatchLoader
+    from veles_tpu.samples import xing4
+    from veles_tpu.znicz.standard_workflow import StandardWorkflow
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4_ep8.json")) as f:
+        cfg = json.load(f)
+    batch, seq = cfg["batch_per_chip"], cfg["seq_len"]
+
+    class ShapeOnlyLoader(FullBatchLoader):
+        def load_data(self):
+            self.bind_arrays(np.zeros((batch, seq), np.int32),
+                             np.zeros((batch, seq, 2), np.int32), 0, 0,
+                             batch)
+
+    wf = StandardWorkflow(
+        layers=xing4.layer_table({**cfg, "init_std": 0.0}),
+        loader=ShapeOnlyLoader(minibatch_size=batch, on_device=False),
+        loss="softmax", n_classes=cfg["vocab_size"],
+        decision_config={"max_epochs": 1, "fail_iterations": 1},
+        gd_config=dict(cfg["optimizer"]), name="xing4_compile")
+    wf.initialize(device=None)
+    step = wf.build_fused_step(compute_dtype=cfg["compute_dtype"])
+    assert step.has_aux and step.unit_loss
+    state = _abstract_step_args(
+        step, batch, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t),
+        one_chip)[0]
+    assert sum(int(np.prod(a.shape)) for layer in state["params"]
+               for a in layer.values()) == cfg["n_params"]
+    args = (state,
+            jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((batch, seq, 2), jnp.int32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=one_chip))
+    # at the platform's default precision, as the benchmark runs it
+    # (conftest.py pins "highest" for golden comparisons, and the TPU's
+    # grouped-matmul kernel takes no bfloat16 operands at fp32 precision)
+    with jax.default_matmul_precision("bfloat16"):
+        compiled = jax.jit(step.train_callable(),
+                           donate_argnums=(0,)).lower(*args).compile()
+    txt = compiled.as_text()
+    assert "ragged-dot" in txt and "tpu_custom_call" in txt
+    for scope in ("/mla/", "/moe/experts/", "/hc_pre/", "/hc_post/",
+                  "update/balance", "rematted_computation"):
+        assert scope in txt, scope
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # parameters and velocity, float32: 8 B a parameter of arguments
+    assert mem.argument_size_in_bytes > 8 * cfg["n_params"]
+    # what a v5e's allocator offers: `bytes_limit` of its memory
+    # statistics (chip runs of PR 32)
+    assert total < 16909336064, total

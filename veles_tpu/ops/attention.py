@@ -185,3 +185,54 @@ def ulysses_attention(q, k, v, axis_name: str,
     qh, kh, vh = seq_to_heads(q), seq_to_heads(k), seq_to_heads(v)
     oh = mha_forward(qh, kh, vh, scale, causal)
     return heads_to_seq(oh)
+
+
+#: queries a block of `latent_attention`: a sequence that divides into
+#: such blocks is computed block by block, a shorter one whole
+LATENT_QUERY_BLOCK = 1024
+
+
+def latent_attention(p, h, *, n_heads: int, nope: int, rope: int,
+                     v_dim: int, cos, sin, scale: float,
+                     norm_eps: float = 1e-6):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434,
+    section 2.1) over the `n_heads` heads whose up-projections `p` holds:
+    h (N, S, C) -> (N, S, C), causal. Queries and keys-values pass
+    through low-rank latents with their own norms (`w_dq`, `q_norm`,
+    `w_uq`; `w_dkv`, `kv_norm`, `w_ukv`); a head scores on `nope`
+    dimensions of its own plus a `rope`-wide rotary part whose key is
+    shared by all heads, and reads values of `v_dim`. The down-projections
+    are whole whatever the number of heads held, so a share of the heads
+    gives its part of the sum `concat(P v) W_O`: nothing is exchanged here.
+    Plain XLA: scores and softmax in float32, a block of queries against
+    its keys at a time."""
+    from veles_tpu.ops.lm import apply_rope, mm, rms_norm
+    n, s, _ = h.shape
+    c_q = rms_norm(mm(h, p["w_dq"]), p["q_norm"], norm_eps)
+    q = mm(c_q, p["w_uq"]).reshape(n, s, n_heads, nope + rope)
+    dkv = mm(h, p["w_dkv"])
+    kv_rank = dkv.shape[-1] - rope
+    c_kv = rms_norm(dkv[..., :kv_rank], p["kv_norm"], norm_eps)
+    kv = mm(c_kv, p["w_ukv"]).reshape(n, s, n_heads, nope + v_dim)
+    q_rope = apply_rope(q[..., nope:], cos, sin)
+    k_rope = apply_rope(dkv[..., kv_rank:], cos, sin)       # (N, S, rope)
+    # a block of queries meets the keys up to its own end only: the
+    # blocks above the diagonal are never formed (5/8 of the square at
+    # four blocks), and only the diagonal block needs the mask
+    block = LATENT_QUERY_BLOCK if s % LATENT_QUERY_BLOCK == 0 else s
+    outs = []
+    for lo in range(0, s, block):
+        hi = lo + block
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q[:, lo:hi, :, :nope],
+                             kv[:, :hi, :, :nope],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q_rope[:, lo:hi],
+                               k_rope[:, :hi],
+                               preferred_element_type=jnp.float32)) * scale
+        causal = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
+        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+        outs.append(jnp.einsum("bhqk,bkhd->bqhd", probs.astype(h.dtype),
+                               kv[:, :hi, :, nope:],
+                               preferred_element_type=jnp.float32))
+    out = jnp.concatenate(outs, axis=1)
+    return mm(out.astype(h.dtype).reshape(n, s, n_heads * v_dim), p["w_o"])

@@ -1,0 +1,188 @@
+"""Language-model ops: RMSNorm, SwiGLU, yarn rotary frequencies, the
+Sinkhorn-projected hyper-connection around a sub-layer, and the chunked
+next-token cross-entropy of an untied head.
+
+Not in the reference (a 2015 codebase). Pure `jax.numpy`: every function
+is differentiable by `jax.grad`, traces into the fused step and knows no
+unit; the units are `znicz/lm.py`. The benchmark's plain float32 reference
+of the same equations is `benchmark/xing4_reference.py`, which imports
+nothing from here.
+
+Layouts are chosen for the TPU's (8, 128) tiles. The `n` residual streams
+of a token lie side by side in ONE row of `n * C` features (stream `i` is
+columns `i*C:(i+1)*C`), never as a trailing `(n, C)` pair, whose
+second-minor dimension of 4 would pad to 16 in bfloat16. The per-token
+mixing matrices keep the tokens in the minor dimension, `(n, n, T)`, so
+that twenty Sinkhorn iterations are dense elementwise passes.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+
+def rms_norm(x, scale=None, eps: float = 1e-6):
+    """x / sqrt(mean(x^2) + eps) over the last axis, in float32, times
+    `scale` (none: the plain normalisation); back in x's dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    if scale is not None:
+        y = y * scale.astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+def mm(x, w):
+    """x @ w accumulated in float32, rounded to x's dtype."""
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32
+                      ).astype(x.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """(silu(x W_g) * (x W_u)) W_d."""
+    return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+
+
+# -- rotary embedding with yarn frequencies ------------------------------------
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1.0 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """The `dim / 2` rotary frequencies under yarn (Peng et al.,
+    arXiv:2309.00071, as DeepSeek-V2's code states it): dimensions that
+    turn more than `beta_fast` times over the original context keep their
+    frequency, those that turn less than `beta_slow` times are divided by
+    `factor`, and a linear ramp joins them."""
+    def turns_at(n_rot: float) -> float:
+        return dim * math.log(original / (n_rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    freq = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return ((1.0 / freq) * (1.0 - ramp) + (1.0 / (factor * freq)) * ramp
+            ).astype(np.float32)
+
+
+def rope_tables(seq_len: int, inv_freq: np.ndarray,
+                factor: float = 1.0) -> Tuple[Any, Any]:
+    """cos and sin, (S, dim / 2) float32, of position x frequency."""
+    ang = np.arange(seq_len, dtype=np.float32)[:, None] * inv_freq[None, :]
+    return (jnp.asarray(np.cos(ang) * factor, jnp.float32),
+            jnp.asarray(np.sin(ang) * factor, jnp.float32))
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the pairs (x[..., i], x[..., i + dim/2]) of x (N, S, ..., dim)
+    by the position's angles (the two-halves layout)."""
+    half = x.shape[-1] // 2
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+    c, s = cos.reshape(shape), sin.reshape(shape)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
+# -- hyper-connections ------------------------------------------------------------
+
+def sinkhorn(logits, iters: int, eps: float):
+    """`exp`, then `iters` times: every row divided by (its sum + eps),
+    every column by (its sum + eps). `logits` is (n, n, ...): rows on
+    axis 0, columns on axis 1, anything after rides along."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (m.sum(axis=1, keepdims=True) + eps)
+        m = m / (m.sum(axis=0, keepdims=True) + eps)
+    return m
+
+
+def hc_init_biases(n: int) -> Dict[str, np.ndarray]:
+    """Biases at which, with x~ = 0, every stream is read at 1/n, the
+    sub-layer's output is written at 1 and the streams stay apart."""
+    return {"b_pre": np.full((n,), math.log(1.0 / (n - 1)) if n > 1
+                             else 30.0, np.float32),
+            "b_post": np.zeros((n,), np.float32),
+            "b_res": (8.0 * np.eye(n)).astype(np.float32)}
+
+
+def hc_maps(p: Dict[str, Any], x, n: int, *, iters: int, eps: float,
+            clamp: Tuple[float, float], norm_eps: float):
+    """The three mixing maps of one hyper-connection from the token's
+    streams x (T, n*C): (Hpre (n, T), Hpost (n, T), Hres (n, n, T)),
+    float32. `p` holds `p_pre`, `p_post` (n*C, n) and `p_res` (n*C, n*n), the
+    scalars `a_pre`, `a_post`, `a_res` (shape (1,)) and the biases `b_pre`
+    (n,), `b_post` (n,), `b_res` (n, n)."""
+    xn = rms_norm(x, eps=norm_eps)
+    # one product for the three maps, (2n + n*n, T): tokens in the minor
+    # dimension from here on
+    p_maps = jnp.concatenate([p["p_pre"], p["p_post"], p["p_res"]], axis=1)
+    raw = lax.dot_general(p_maps, xn, (((0,), (1,)), ((), ())),
+                          preferred_element_type=jnp.float32)
+    f32 = lambda name: p[name].astype(jnp.float32)  # noqa: E731
+    pre = f32("a_pre") * raw[:n] + f32("b_pre")[:, None]
+    post = f32("a_post") * raw[n:2 * n] + f32("b_post")[:, None]
+    res = f32("a_res") * raw[2 * n:].reshape(n, n, -1) \
+        + f32("b_res")[:, :, None]
+    return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
+            sinkhorn(jnp.clip(res, clamp[0], clamp[1]), iters, eps))
+
+
+def hc_read(x, h_pre, n: int):
+    """Hpre X: the sub-layer's input (T, C) from the streams (T, n*C)."""
+    c = x.shape[-1] // n
+    acc = sum(h_pre[i][:, None] * x[:, i * c:(i + 1) * c].astype(jnp.float32)
+              for i in range(n))
+    return acc.astype(x.dtype)
+
+
+def hc_write(x, y, h_post, h_res, n: int):
+    """Hres X + Hpost^T y: the streams (T, n*C) after the sub-layer's
+    output y (T, C)."""
+    c = x.shape[-1] // n
+    xs = [x[:, i * c:(i + 1) * c].astype(jnp.float32) for i in range(n)]
+    yf = y.astype(jnp.float32)
+    out = [sum(h_res[j, i][:, None] * xs[i] for i in range(n))
+           + h_post[j][:, None] * yf for j in range(n)]
+    return jnp.concatenate(out, axis=-1).astype(x.dtype)
+
+
+# -- the head and its loss ------------------------------------------------------------
+
+def chunked_ce(h, w_head, targets, weights, chunk: int):
+    """Sum over tokens of weight x cross-entropy of softmax(h W) against
+    the target, and the count of weighted tokens whose largest logit is
+    not the target: h (T, C), w_head (C, V), targets and weights (T,).
+    The logits exist a `chunk` of tokens at a time, in float32, and are
+    recomputed in the backward pass."""
+    t = h.shape[0]
+    if t % chunk:
+        raise ValueError(f"{t} tokens do not divide into chunks of {chunk}")
+
+    @jax.checkpoint
+    def one(hc, yc, wc):
+        logits = jnp.matmul(hc, w_head, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, yc[:, None], 1)[:, 0]
+        wrong = (logits.argmax(axis=-1) != yc) & (wc > 0)
+        return ((lse - picked) * wc).sum(), wrong.sum()
+
+    def body(carry, xs):
+        loss, n_err = one(*xs)
+        return (carry[0] + loss, carry[1] + n_err), None
+
+    n = t // chunk
+    zero = (weights[0] * 0.0, (targets[0] * 0).astype(jnp.int32))
+    (loss, n_err), _ = lax.scan(
+        body, zero, (h.reshape(n, chunk, -1), targets.reshape(n, chunk),
+                     weights.reshape(n, chunk)))
+    return loss, n_err

@@ -129,6 +129,13 @@ def _abstract_state(step, key_impl: str) -> Dict[str, Any]:
     out = {"params": params, "vel": vel,
            "key": jax.ShapeDtypeStruct(key_shape.shape, key_shape.dtype),
            "lr_scale": jax.ShapeDtypeStruct((), jnp.float32)}
+    if getattr(step, "has_aux", False):
+        # step state no gradient touches (an expert layer's selection
+        # bias and counters), one dict per forward unit
+        out["aux"] = tuple(
+            {k: jax.ShapeDtypeStruct(a.shape, a.mem.dtype)
+             for k, a in u.aux_arrays().items()}
+            if hasattr(u, "aux_arrays") else {} for u in step.forwards)
     if getattr(step, "ef_active", lambda: False)():
         # stateful (int8+EF) grad_reduce: the error-feedback residual
         # slot rides the checkpoint so a same-geometry resume carries

@@ -275,6 +275,16 @@ class FusedTrainStep:
             if pt and pt != "float32":
                 compute_dtype = pt
         self.compute_dtype = compute_dtype
+        #: the last unit computes the loss itself from the targets (a
+        #: head whose logits exist a chunk of tokens at a time, a loss
+        #: with a second term): `_loss_metrics` hands it targets, weights
+        #: and the global weight sum and gets (loss, n_err) back
+        self.unit_loss = bool(getattr(self.forwards[-1],
+                                      "fused_emits_loss", False))
+        #: units that carry step state beside parameters and velocity,
+        #: which no gradient touches (an expert layer's selection bias
+        #: and load counters): state["aux"], one dict per forward unit
+        self.has_aux = any(hasattr(u, "aux_arrays") for u in self.forwards)
         if self.loss_kind == "softmax" and not getattr(
                 self.forwards[-1], "fused_emits_logits", False):
             raise ValueError(
@@ -293,6 +303,12 @@ class FusedTrainStep:
                 mode = "dp"
         if mode in ("dp", "gspmd", "seq") and mesh is None:
             raise ValueError(f"mode={mode!r} requires a mesh")
+        if self.has_aux and mode != "local":
+            raise ValueError(
+                f"mode={mode!r}: step state beside parameters and velocity "
+                "(an expert layer's selection bias, moved on the loads of "
+                "ALL the step's tokens) is covered on one device only; a "
+                "mesh needs the loads summed over it first")
         if mode == "seq":
             for u in self.forwards:
                 if getattr(u, "parallel_mode", None) == "local":
@@ -595,6 +611,10 @@ class FusedTrainStep:
         state = {"params": params, "vel": vel,
                  "key": prng.get().next_key(),
                  "lr_scale": jnp.float32(1.0)}
+        if self.has_aux:
+            state["aux"] = tuple(
+                {k: jnp.asarray(a.mem) for k, a in u.aux_arrays().items()}
+                if hasattr(u, "aux_arrays") else {} for u in self.forwards)
         if self.ef_active():
             # error-feedback residuals (stateful grad_reduce variants):
             # one flat per-shard vector per param leaf, zero at start,
@@ -660,6 +680,10 @@ class FusedTrainStep:
                         lp = plan[k]
                         hv = hv.reshape(-1)[:lp.size].reshape(lp.shape)
                     getattr(g, vname).reset(hv)
+        for u, a in zip(self.forwards, state.get("aux", ())):
+            for k, arr in (u.aux_arrays().items() if a else ()):
+                if not deleted(a[k]):
+                    arr.reset(host(a[k]))
 
     def local_rows(self, n: int):
         """Boolean (n,) mask of GLOBAL batch rows whose data-axis shards
@@ -806,12 +830,25 @@ class FusedTrainStep:
 
     def _forward(self, params, x, key, train: bool,
                  local_trace: bool = False):
+        """The forward chain's output alone (serving, export, the
+        confusion companion): step state is read, not moved."""
+        return self._chain(params, x, key, train, local_trace)[0]
+
+    def _chain(self, params, x, key, train: bool,
+               local_trace: bool = False, aux=None, loss_args=None):
+        """(output of the last unit, what each unit with step state
+        counted this step: one dict per forward unit). `loss_args`
+        (targets, weights, denom) go to a last unit that owns its loss,
+        whose output is then (loss, n_err)."""
         # uint8-wire prologue: traced into the step, so it fuses into
-        # the first layer's HBM read
+        # the first layer's HBM read. A first unit that says it takes
+        # token ids (`fused_integer_input`) gets them as they are.
+        ids = getattr(self.forwards[0], "fused_integer_input", False)
         with jax.named_scope("input_normalize"):
-            x = apply_input_normalize(self.input_normalize, x)
-            if self.compute_dtype is not None:
-                x = x.astype(self.compute_dtype)
+            if not ids:
+                x = apply_input_normalize(self.input_normalize, x)
+                if self.compute_dtype is not None:
+                    x = x.astype(self.compute_dtype)
         if self.compute_dtype is not None:
             with jax.named_scope("cast_params"):
                 params = _tree_cast(params, self.compute_dtype)
@@ -858,6 +895,7 @@ class FusedTrainStep:
         # draw identical RNG streams.
         fused = {i: (j, v) for i, j, v in self.fusion_pairs()}
         skip = {j for j, _ in fused.values()}
+        counted = [{} for _ in self.forwards]
         for i, u in enumerate(self.forwards):
             if i in skip:
                 continue
@@ -871,17 +909,32 @@ class FusedTrainStep:
             with jax.named_scope(self.scopes[i]):
                 k = (jax.random.fold_in(key, i) if u.fused_needs_key
                      else None)
-                x = u.fused_apply(params[i], x, key=k, train=train)
+                kw = {}
+                if aux is not None and aux[i]:
+                    kw["aux"] = aux[i]
+                if loss_args is not None and i == len(self.forwards) - 1:
+                    kw.update(loss_args)
+
+                def apply(p, xx, kw, u=u, k=k):
+                    return u.fused_apply(p, xx, key=k, train=train, **kw)
+
+                if getattr(u, "fused_remat", False) and train:
+                    # the step keeps this unit's input and recomputes its
+                    # inside in the backward pass
+                    apply = jax.checkpoint(apply)
+                x = apply(params[i], x, kw)
+                if "aux" in kw:
+                    x, counted[i] = x
                 x = self._constrain_tp_act(x, i)
         for i in gathered:
             # the traced backward holds the axis name itself; a later
             # caller of fused_apply outside a dp trace (the pipeline
             # step) must not find it on the unit
             self.forwards[i].grad_gather_axis_name = None
-        if self.compute_dtype is not None:
+        if self.compute_dtype is not None and loss_args is None:
             with jax.named_scope("loss"):
                 x = x.astype(jnp.float32)
-        return x
+        return x, tuple(counted)
 
     def input_put_specs(self):
         """Leading-dim PartitionSpecs for the device feed's async
@@ -910,8 +963,9 @@ class FusedTrainStep:
             x, NamedSharding(self.mesh, spec))
 
     def _loss_metrics(self, params, x, y, key, train: bool, w, axes,
-                      wsum=None):
-        """PARTIAL (loss, n_err): the loss is normalized by the GLOBAL
+                      wsum=None, aux=None):
+        """PARTIAL (loss, n_err, what the units with step state counted):
+        the loss is normalized by the GLOBAL
         weight sum (psum over `axes` when sharded), so per-shard partials
         SUM to the exact global weighted mean — and because each shard's
         partial objective contributes additively, the gradient transpose
@@ -924,7 +978,16 @@ class FusedTrainStep:
         globally reduced): gradient accumulation passes the FULL batch's
         weight sum so microbatch partials sum to the exact full-batch
         mean (and its gradient)."""
-        out = self._forward(params, x, key, train)
+        if self.unit_loss:
+            # the last unit owns the loss: per-sample weights and their
+            # global sum go in, the partial (loss, n_err) comes out
+            denom = (wsum if wsum is not None
+                     else self._global_wsum(w, 1, axes))
+            (loss, n_err), counted = self._chain(
+                params, x, key, train, aux=aux,
+                loss_args={"targets": y, "weights": w, "denom": denom})
+            return loss, n_err, counted
+        out, counted = self._chain(params, x, key, train, aux=aux)
         with jax.named_scope("loss"):
             if self.loss_kind == "softmax":
                 # broadcast per-sample weights over token dims: (N,) classifier
@@ -952,7 +1015,7 @@ class FusedTrainStep:
                          else self._global_wsum(w, 1, axes))
                 loss, _ = ox.mse(out, y, weights=w, denom=denom)
                 n_err = loss
-        return loss, n_err
+        return loss, n_err, counted
 
     def _global_wsum(self, w, tokens_per_sample: int, axes):
         """Global token-weight sum. The mask `w` is per-SAMPLE and varies
@@ -1000,20 +1063,21 @@ class FusedTrainStep:
             # the partials itself, in float32 — all but the leaves of
             # the dense units in `gathered`, whose backward has formed
             # the global gradient on every chip already.
-            loss, n_err = self._loss_metrics(p, x, y, step_key, True,
-                                             w, axes)
-            return loss, (loss, n_err)
+            loss, n_err, counted = self._loss_metrics(
+                p, x, y, step_key, True, w, axes, aux=state.get("aux"))
+            return loss, (loss, n_err, counted)
 
         gathered = self._gathered_units(x.shape[0])
         self._rows_traced = x.shape[0]
-        (_, (loss, n_err)), grads = jax.value_and_grad(
+        (_, (loss, n_err, counted)), grads = jax.value_and_grad(
             lf, has_aux=True)(self._grad_params(state["params"], gathered))
         if axes:
             # partials with a global denominator: SUM to the global metric
             with jax.named_scope("loss"):
                 loss = lax.psum(loss, axes)
                 n_err = lax.psum(n_err, axes)
-        return self._apply_update(state, grads, gathered), loss, n_err
+        return (self._apply_update(state, grads, gathered, counted), loss,
+                n_err)
 
     def _exchanges_partials(self) -> bool:
         """True where the update itself sums the per-shard partial
@@ -1094,7 +1158,8 @@ class FusedTrainStep:
             unit=types.SimpleNamespace(
                 allow_pallas=self.mode != "gspmd"))
 
-    def _apply_update(self, state, grads, gathered: frozenset):
+    def _apply_update(self, state, grads, gathered: frozenset,
+                      counted=()):
         """One optimizer step; advances the carried key identically on
         every shard (fold_in of the *unfolded* state key keeps it
         replicated). On a dp mesh the grads arrive UNREDUCED per-shard
@@ -1107,9 +1172,18 @@ class FusedTrainStep:
         candidates slot in when selected — GSPMD falls back, a
         pallas_call cannot be auto-partitioned)."""
         with jax.named_scope("update"):
-            if self.zero_active:
-                return self._apply_update_zero(state, grads)
-            return self._apply_update_replicated(state, grads, gathered)
+            new = (self._apply_update_zero(state, grads) if self.zero_active
+                   else self._apply_update_replicated(state, grads, gathered))
+            if "aux" in state:
+                # state no gradient touches moves by its unit's own rule
+                # from what the step counted (an expert layer's selection
+                # bias from the loads)
+                with jax.named_scope("balance"):
+                    new["aux"] = tuple(
+                        u.fused_aux_update(a, c) if a else a
+                        for u, a, c in zip(self.forwards, state["aux"],
+                                           counted))
+            return new
 
     def _apply_update_replicated(self, state, grads, gathered: frozenset):
         """Every replica applies the full update. On a dp mesh a unit's
@@ -1236,6 +1310,11 @@ class FusedTrainStep:
         update, and a gathered unit's gradient (global in every
         microbatch already) by nobody (where autodiff places the psum,
         seq and EP, it fires once per microbatch inside the scan)."""
+        if self.has_aux:
+            raise NotImplementedError(
+                "gradient accumulation over units with step state (an "
+                "expert layer's selection bias moves on a whole step's "
+                "loads) is not covered by this build")
         axes = (axis,) if isinstance(axis, str) else axis
         step_key = self._shard_step_key(state, axes)
         wsum = self._global_wsum(ws.reshape(-1), 1, axes)
@@ -1247,7 +1326,7 @@ class FusedTrainStep:
             x, y, w = xyw
 
             def lf(p):
-                loss, n_err = self._loss_metrics(
+                loss, n_err, _ = self._loss_metrics(
                     p, x, y, jax.random.fold_in(step_key, i), True, w,
                     axes, wsum=wsum)
                 return loss, (loss, n_err)
@@ -1276,7 +1355,8 @@ class FusedTrainStep:
     def _eval_body(self, params, x, y, w, *, axis):
         axes = (axis,) if isinstance(axis, str) else axis
         key = jax.random.PRNGKey(0)  # unused: eval paths need no RNG
-        loss, n_err = self._loss_metrics(params, x, y, key, False, w, axes)
+        loss, n_err, _ = self._loss_metrics(params, x, y, key, False, w,
+                                            axes)
         if axes:
             loss = lax.psum(loss, axes)
             n_err = lax.psum(n_err, axes)
@@ -1554,6 +1634,19 @@ class FusedTrainStep:
         if shape not in cache:
             cache[shape] = jnp.ones(shape, jnp.float32)
         return cache[shape]
+
+    def release(self) -> None:
+        """Unload the compiled programs. A loaded executable keeps its
+        temporaries reserved on the device for as long as it lives (6.7 GB
+        for a language model that fills the chip, chip runs of PR 32):
+        whoever needs the device for something else afterwards calls this
+        once the last state of this step is gone. The next `train` or
+        `evaluate` compiles again."""
+        self._train_fn = self._eval_fn = self._train_many_fn = None
+        self._gather_fn = None
+        self._train_accum_fns = None
+        # (jax's own caches hold an executable past its function)
+        jax.clear_caches()
 
     def train(self, state, x, y, w=None):
         """One fused training step. Returns (new_state, (loss, n_err)).

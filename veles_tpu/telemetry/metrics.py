@@ -567,6 +567,44 @@ def loader_handles(reg: Optional[MetricsRegistry] = None
     )
 
 
+def moe_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
+    """The `veles_moe_*` families of a top-k expert layer that computes
+    the experts it holds: slots are (token, selected expert) pairs,
+    counted inside the step into int32 state (`znicz/lm.py`) and
+    published from it by whoever reads that state
+    (`znicz.lm.publish_moe_counters`), never per step. Registered on
+    first use: a program without such a layer exposes none."""
+    reg = reg or default_registry()
+    by_layer = ("layer",)
+    return SimpleNamespace(
+        steps=reg.counter("veles_moe_steps_total",
+                          "train steps the slot counters cover", by_layer),
+        slots=reg.counter("veles_moe_slots_total",
+                          "slots routed, over all experts", by_layer),
+        held=reg.counter("veles_moe_held_slots_total",
+                         "slots routed to the experts held here", by_layer),
+        fullest=reg.counter(
+            "veles_moe_fullest_held_slots_total",
+            "sum over steps of the fullest held expert's slots", by_layer),
+        dropped=reg.counter("veles_moe_slots_dropped_total",
+                            "held slots the layer did not compute"),
+        reached=reg.gauge(
+            "veles_moe_balance_reached",
+            "1 if every layer's held share of the slots was within the "
+            "band around its even share when counting began, else 0"))
+
+
+def family_values(name: str, reg: Optional[MetricsRegistry] = None
+                  ) -> Optional[Dict[Tuple[str, ...], float]]:
+    """{label values: value} of one family's children, or None where no
+    producer has registered it."""
+    fam = (reg or default_registry())._families.get(name)
+    if fam is None:
+        return None
+    with fam._lock:
+        return {k: c.value for k, c in fam._children.items()}
+
+
 def mirror_mem(mem: Optional[Dict[str, Any]],
                reg: Optional[MetricsRegistry] = None) -> None:
     """Mirror a memstats snapshot (parallel/memstats.py — the one

@@ -1,0 +1,559 @@
+"""Units of a sparse-expert language model with hyper-connected residual
+streams: token embedding, the block, and the head with its losses.
+
+Not in the reference (SURVEY.md §5.7). House pattern: Forward unit with
+`fused_apply`, the vjp-driven GD twin, parameters as named Arrays. These
+units train through `FusedTrainStep` (the `--fused` path); the granular
+unit-by-unit path has no evaluator for a head that owns two losses, and
+`numpy_run`/`xla_run` raise.
+
+What flows between the units is a dict, not an array: `x`, the streams
+(N, S, n*C) with a token's `n` residual streams side by side in one row
+(`ops/lm.py` says why); `ids`, the tokens; `table`, the embedding in the
+compute dtype, which the head's multi-token-prediction module reads a
+second time (the SHARED embedding: one leaf, two uses, one gradient).
+
+`HCBlock` is one transformer block: a hyper-connection around latent
+attention, another around a dense SwiGLU MLP or an expert layer. It is
+what `jax.checkpoint` wraps (`fused_remat`): the step keeps a block's
+input and recomputes its inside in the backward pass. An expert layer
+routes over ALL `n_experts` and computes the `held = (first, count)` it
+holds (`ops/moe.py`); its selection bias and its load counters are step
+state that no gradient touches (`aux_arrays`, `fused_aux_update`), moved
+after every step under the scope `update/balance`.
+
+Scopes inside a block, for a profile: `hc_pre` (the three maps, Sinkhorn,
+reading the sub-layer's input from the streams), `mla`, `hc_post` (writing
+the streams), `mlp`, or `moe/router`, `moe/dispatch`, `moe/experts`,
+`moe/combine`, `moe/shared`; in the head `head` and `mtp/...`.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from veles_tpu.memory import Array
+from veles_tpu.ops import attention as oa
+from veles_tpu.ops import lm as ol
+from veles_tpu.ops import moe as om
+from veles_tpu.znicz.nn_units import (Forward, GradientDescentVJP,
+                                      register_gd)
+
+#: rows of an expert layer's sorted (token, slot) buffer over the rows a
+#: perfectly balanced router fills (`ops.moe.held_experts_swiglu`'s
+#: `fast_rows`): held loads within a half of the even share take the
+#: fast path, anything beyond is computed on the whole buffer. Under the
+#: selection-bias rule the held experts of one layer were given at most
+#: 1.150 times the even load in one step, from the first step on (ten
+#: seeds x 83 steps x 5 layers on a v5e, PERF.md section 6, PR 32), and a
+#: step's load stands 4 % around the even one: 1.5 is twelve of those away
+FAST_ROWS_HEADROOM = 1.5
+
+
+class BlockSpec:
+    """The static description of one block and its pure forward. Shared
+    by `HCBlock` and by the head's multi-token-prediction module."""
+
+    def __init__(self, *, features: int, streams: int, n_heads: int,
+                 q_rank: int, kv_rank: int, nope: int, rope: int,
+                 v_dim: int, ffn: str, width: int, n_experts: int = 0,
+                 held: Sequence[int] = (0, 0), top_k: int = 0,
+                 routed_scaling: float = 1.0,
+                 bias_update_speed: float = 0.001,
+                 rope_theta: float = 10000.0,
+                 rope_scaling: Optional[Dict[str, Any]] = None,
+                 sinkhorn_iters: int = 20, hc_eps: float = 1e-6,
+                 hc_clamp: Sequence[float] = (-30.0, 30.0),
+                 norm_eps: float = 1e-6, init_std: float = 0.02) -> None:
+        if ffn not in ("dense", "experts"):
+            raise ValueError(f"ffn must be dense or experts, not {ffn!r}")
+        self.c, self.n = features, streams
+        self.n_heads, self.q_rank, self.kv_rank = n_heads, q_rank, kv_rank
+        self.nope, self.rope, self.v_dim = nope, rope, v_dim
+        self.ffn, self.width = ffn, width
+        self.n_experts, self.top_k = n_experts, top_k
+        self.held = (int(held[0]), int(held[1]))
+        self.routed_scaling = routed_scaling
+        self.bias_update_speed = bias_update_speed
+        self.rope_theta = rope_theta
+        self.rope_scaling = dict(rope_scaling or {})
+        self.sinkhorn_iters, self.hc_eps = sinkhorn_iters, hc_eps
+        self.hc_clamp = (float(hc_clamp[0]), float(hc_clamp[1]))
+        self.norm_eps, self.init_std = norm_eps, init_std
+
+    # -- parameters ----------------------------------------------------------
+
+    def shapes(self) -> Dict[str, Tuple[int, ...]]:
+        c, n, h = self.c, self.n, self.n_heads
+        out: Dict[str, Tuple[int, ...]] = {}
+        for hc in ("hca_", "hcm_"):
+            out.update({hc + "p_pre": (n * c, n), hc + "p_post": (n * c, n),
+                        hc + "p_res": (n * c, n * n), hc + "a_pre": (1,),
+                        hc + "a_post": (1,), hc + "a_res": (1,),
+                        hc + "b_pre": (n,), hc + "b_post": (n,),
+                        hc + "b_res": (n, n)})
+        out.update({
+            "attn_norm": (c,), "attn_w_dq": (c, self.q_rank),
+            "attn_q_norm": (self.q_rank,),
+            "attn_w_uq": (self.q_rank, h * (self.nope + self.rope)),
+            "attn_w_dkv": (c, self.kv_rank + self.rope),
+            "attn_kv_norm": (self.kv_rank,),
+            "attn_w_ukv": (self.kv_rank, h * (self.nope + self.v_dim)),
+            "attn_w_o": (h * self.v_dim, c)})
+        w = self.width
+        if self.ffn == "dense":
+            out.update({"mlp_norm": (c,), "mlp_w_gate": (c, w),
+                        "mlp_w_up": (c, w), "mlp_w_down": (w, c)})
+        else:
+            e = self.held[1]
+            out.update({
+                "moe_norm": (c,), "moe_w_router": (c, self.n_experts),
+                "moe_shared_gate": (c, w), "moe_shared_up": (c, w),
+                "moe_shared_down": (w, c), "moe_experts_gate": (e, c, w),
+                "moe_experts_up": (e, c, w), "moe_experts_down": (e, w, c)})
+        return out
+
+    def initial(self, name: str, shape: Tuple[int, ...], fill) -> np.ndarray:
+        """A leaf's initial value; `fill(shape, std)` draws the normal
+        ones from the unit's generator."""
+        if name.endswith("norm"):
+            return np.ones(shape, np.float32)
+        if name[-5:] in ("a_pre", "a_res") or name.endswith("a_post"):
+            return np.full(shape, 0.01, np.float32)
+        for b, value in ol.hc_init_biases(self.n).items():
+            if name.endswith(b):
+                return value
+        return fill(shape, self.init_std)
+
+    def aux_shapes(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """Step state beside the parameters: the selection bias, the slots
+        every expert was given so far, the sum over steps of the fullest
+        held expert's slots, slots not computed, steps counted."""
+        if self.ffn != "experts":
+            return {}
+        e = self.n_experts
+        return {"bias": ((e,), np.float32), "load": ((e,), np.int32),
+                "fullest": ((1,), np.int32), "dropped": ((1,), np.int32),
+                "steps": ((1,), np.int32),
+                "picked": (None, np.int32)}     # (tokens, top_k): see unit
+
+    # -- forward -----------------------------------------------------------------
+
+    def _hc(self, p: Dict[str, Any], prefix: str, x, f):
+        """One hyper-connection around `f`: x (T, n*C) -> (x, f's extra)."""
+        with jax.named_scope("hc_pre"):
+            h_pre, h_post, h_res = ol.hc_maps(
+                {k[len(prefix):]: v for k, v in p.items()
+                 if k.startswith(prefix)}, x, self.n,
+                iters=self.sinkhorn_iters, eps=self.hc_eps,
+                clamp=self.hc_clamp, norm_eps=self.norm_eps)
+            h = ol.hc_read(x, h_pre, self.n)
+        y, extra = f(h)
+        with jax.named_scope("hc_post"):
+            return ol.hc_write(x, y, h_post, h_res, self.n), extra
+
+    def _attention(self, p: Dict[str, Any], h, batch: int):
+        with jax.named_scope("mla"):
+            seq = h.shape[0] // batch
+            rs = self.rope_scaling
+            factor = rs.get("factor", 1.0)
+            inv_freq = ol.yarn_inv_freq(
+                self.rope, self.rope_theta, factor,
+                rs.get("original_max_position_embeddings", seq),
+                rs.get("beta_fast", 32), rs.get("beta_slow", 1))
+            all_dim = ol.yarn_mscale(factor, rs.get("mscale_all_dim", 0))
+            cos, sin = ol.rope_tables(
+                seq, inv_freq,
+                ol.yarn_mscale(factor, rs.get("mscale", 1)) / all_dim)
+            hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
+            y = oa.latent_attention(
+                {k[len("attn_"):]: v for k, v in p.items()
+                 if k.startswith("attn_")},
+                hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
+                nope=self.nope, rope=self.rope, v_dim=self.v_dim, cos=cos,
+                sin=sin, scale=(self.nope + self.rope) ** -0.5
+                * all_dim * all_dim, norm_eps=self.norm_eps)
+            return y.reshape(h.shape), None
+
+    def fast_rows(self, tokens: int) -> int:
+        even = tokens * self.top_k * self.held[1] / max(self.n_experts, 1)
+        return int(np.ceil(FAST_ROWS_HEADROOM * even))
+
+    def _experts(self, p: Dict[str, Any], h, bias):
+        with jax.named_scope("moe"):
+            hn = ol.rms_norm(h, p["moe_norm"], self.norm_eps)
+            with jax.named_scope("router"):
+                scores = jax.nn.sigmoid(jnp.matmul(
+                    hn, p["moe_w_router"],
+                    preferred_element_type=jnp.float32))
+                idx, picked = om.route_topk(
+                    scores, 0.0 if bias is None else bias, self.top_k)
+                gates = self.routed_scaling * picked \
+                    / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+                load = om.expert_loads(idx, self.n_experts)
+            with jax.named_scope("experts"):
+                y, dropped = om.held_experts_swiglu(
+                    hn, idx, gates, p["moe_experts_gate"],
+                    p["moe_experts_up"], p["moe_experts_down"], self.held,
+                    self.fast_rows(h.shape[0]))
+            with jax.named_scope("shared"):
+                y = y + ol.swiglu(hn, p["moe_shared_gate"],
+                                  p["moe_shared_up"], p["moe_shared_down"])
+        return y, {"load": load, "dropped": dropped,
+                   "picked": idx.astype(jnp.int32)}
+
+    def _mlp(self, p: Dict[str, Any], h):
+        with jax.named_scope("mlp"):
+            hn = ol.rms_norm(h, p["mlp_norm"], self.norm_eps)
+            return ol.swiglu(hn, p["mlp_w_gate"], p["mlp_w_up"],
+                             p["mlp_w_down"]), None
+
+    def apply(self, p: Dict[str, Any], x, bias=None):
+        """x (N, S, n*C) -> (x, what the expert layer counted or None)."""
+        batch = x.shape[0]
+        flat = x.reshape(-1, x.shape[-1])
+        flat, _ = self._hc(p, "hca_", flat,
+                           lambda h: self._attention(p, h, batch))
+        if self.ffn == "dense":
+            flat, out = self._hc(p, "hcm_", flat, lambda h: self._mlp(p, h))
+        else:
+            flat, out = self._hc(p, "hcm_", flat,
+                                 lambda h: self._experts(p, h, bias))
+        return flat.reshape(x.shape), out
+
+    def aux_update(self, aux: Dict[str, Any], out: Dict[str, Any]
+                   ) -> Dict[str, Any]:
+        """After a step: b_e <- b_e + u sign(mean load - load_e) from the
+        step's loads over all experts, and the counters."""
+        load = out["load"]
+        lf = load.astype(jnp.float32)
+        first, count = self.held
+        return {"bias": aux["bias"] + self.bias_update_speed
+                * jnp.sign(lf.mean() - lf),
+                "load": aux["load"] + load,
+                "fullest": aux["fullest"] + load[first:first + count].max(),
+                "dropped": aux["dropped"] + out["dropped"].astype(jnp.int32),
+                "steps": aux["steps"] + 1,
+                "picked": out["picked"]}
+
+
+def _gaussian(unit):
+    """Normal at `std` from the unit's generator; zeros at `std` 0 (a
+    caller that brings its own weights pays for no draw)."""
+    return lambda shape, std: (unit._fill(shape, "gaussian", std) if std
+                               else np.zeros(shape, np.float32))
+
+
+class _LMUnit(Forward):
+    """Parameters by name from a shape table; the fused step only."""
+
+    def _make_arrays(self, names: Sequence[str], aux: Sequence[str] = ()
+                     ) -> None:
+        self._pnames, self._anames = tuple(names), tuple(aux)
+        for name in self._pnames + tuple("aux_" + a for a in self._anames):
+            setattr(self, name, Array())
+
+    def param_arrays(self) -> Dict[str, Array]:
+        return {name: getattr(self, name) for name in self._pnames}
+
+    def _apply(self, params, x):
+        raise NotImplementedError(
+            f"{type(self).__name__} trains through the fused step only")
+
+    def numpy_run(self) -> None:
+        self._apply(None, None)
+
+    xla_run = numpy_run
+
+    def xla_init(self):
+        return None
+
+
+class TokenEmbedding(_LMUnit):
+    """ids (N, S) int32 -> the dict the blocks pass on: `x`, every
+    token's row of `weights` (vocab, features) copied to the `streams`
+    residual streams, (N, S, streams * features); `ids`; `table`."""
+
+    #: the fused step hands this unit its input as it came: no input
+    #: normalisation, no cast to the compute dtype
+    fused_integer_input = True
+
+    def __init__(self, workflow=None, vocab: int = 256, features: int = 64,
+                 streams: int = 1, init_std: float = 0.02,
+                 **kwargs: Any) -> None:
+        super().__init__(workflow, **kwargs)
+        self.vocab, self.features, self.streams = vocab, features, streams
+        self.init_std = init_std
+        self._make_arrays(("weights",))
+
+    def initialize(self, device=None, **kwargs: Any):
+        if not self.input:
+            return False
+        n, s = self.input.shape[:2]
+        if not self.weights:
+            self.weights.reset(_gaussian(self)(
+                (self.vocab, self.features), self.init_std))
+        shape = (n, s, self.streams * self.features)
+        if not self.output or self.output.shape != shape:
+            self.output.reset(np.zeros(shape, np.float32))
+        return super().initialize(device=device, **kwargs)
+
+    def fused_apply(self, params, x, *, key=None, train=True):
+        table = params["weights"]
+        rows = jnp.take(table, x.astype(jnp.int32), axis=0)
+        return {"x": jnp.tile(rows, (1, 1, self.streams)),
+                "ids": x.astype(jnp.int32), "table": table}
+
+
+class HCBlock(_LMUnit):
+    """One block (module docstring). Layer-table keys are `BlockSpec`'s,
+    `features` aside, which the input gives."""
+
+    #: the fused step wraps this unit's forward in `jax.checkpoint`
+    fused_remat = True
+
+    def __init__(self, workflow=None, streams: int = 1, **kwargs: Any
+                 ) -> None:
+        spec_keys = ("n_heads", "q_rank", "kv_rank", "nope", "rope",
+                     "v_dim", "ffn", "width", "n_experts", "held", "top_k",
+                     "routed_scaling", "bias_update_speed", "rope_theta",
+                     "rope_scaling", "sinkhorn_iters", "hc_eps", "hc_clamp",
+                     "norm_eps", "init_std")
+        self._spec_kw = {k: kwargs.pop(k) for k in spec_keys if k in kwargs}
+        super().__init__(workflow, **kwargs)
+        self.streams = streams
+        self.spec: Optional[BlockSpec] = None
+        probe = BlockSpec(features=1, streams=streams, **self._spec_kw)
+        self._make_arrays(tuple(probe.shapes()), tuple(probe.aux_shapes()))
+
+    def aux_arrays(self) -> Dict[str, Array]:
+        return {a: getattr(self, "aux_" + a) for a in self._anames}
+
+    def initialize(self, device=None, **kwargs: Any):
+        if not self.input:
+            return False
+        n, s, width = self.input.shape
+        self.spec = BlockSpec(features=width // self.streams,
+                              streams=self.streams, **self._spec_kw)
+        _init_leaves(self, self.spec, "", n * s)
+        if not self.output or self.output.shape != (n, s, width):
+            self.output.reset(np.zeros((n, s, width), np.float32))
+        return super().initialize(device=device, **kwargs)
+
+    def fused_apply(self, params, x, *, key=None, train=True, aux=None):
+        y, out = self.spec.apply(params, x["x"],
+                                 None if aux is None else aux["bias"])
+        y = {**x, "x": y}
+        return y if aux is None else (y, out)
+
+    def fused_aux_update(self, aux, out):
+        return self.spec.aux_update(aux, out)
+
+
+def _init_leaves(unit: _LMUnit, spec: BlockSpec, prefix: str,
+                 tokens: int) -> None:
+    """Fill the unit's still empty Arrays of one block."""
+    for name, shape in spec.shapes().items():
+        arr = getattr(unit, prefix + name)
+        if not arr:
+            arr.reset(spec.initial(name, shape, _gaussian(unit)))
+    for name, (shape, dtype) in spec.aux_shapes().items():
+        arr = getattr(unit, "aux_" + prefix + name)
+        if not arr:
+            arr.reset(np.zeros((tokens, spec.top_k) if shape is None
+                               else shape, dtype))
+
+
+class LMHead(_LMUnit):
+    """The read-out and the losses. The streams are summed, normed and
+    multiplied by the untied head `weights` (features, vocab); the loss is
+    the mean cross-entropy of the next token, a `loss_chunk` of tokens at a
+    time (`ops.lm.chunked_ce`). With `mtp` (a dict of `BlockSpec`'s keys,
+    `ffn` "experts") one multi-token-prediction module (DeepSeek-V3,
+    arXiv:2412.19437, section 2.2) adds `mtp_weight` times the mean
+    cross-entropy of the next-next token: h' = W [RMSNorm(h);
+    RMSNorm(Emb(next token))] through one block of its own and a final
+    norm of its own, then the SAME head. Targets are (N, S, 2): the next
+    and the next-next token (`(N, S)` without `mtp`).
+
+    It owns its loss (`fused_emits_loss`): the fused step hands it the
+    targets, the per-sample weights and the global weight sum, and gets
+    (loss, tokens wrong) back. Both cross-entropies of the last step are
+    kept as step state (`ce_main`, `ce_mtp`)."""
+
+    fused_emits_loss = True
+    fused_emits_logits = True       # n_classes is the vocabulary
+    fused_remat = False
+
+    def __init__(self, workflow=None, vocab: int = 256, streams: int = 1,
+                 loss_chunk: int = 1024, mtp: Optional[Dict[str, Any]] = None,
+                 mtp_weight: float = 0.3, norm_eps: float = 1e-6,
+                 init_std: float = 0.02, **kwargs: Any) -> None:
+        super().__init__(workflow, **kwargs)
+        self.vocab, self.streams = vocab, streams
+        self.loss_chunk, self.mtp_weight = loss_chunk, mtp_weight
+        self.norm_eps, self.init_std = norm_eps, init_std
+        self.mtp_kw = dict(mtp) if mtp else None
+        self.spec: Optional[BlockSpec] = None
+        names, aux = ["final_norm", "weights"], ["ce_main", "ce_mtp"]
+        if self.mtp_kw:
+            probe = BlockSpec(features=1, streams=streams, **self.mtp_kw)
+            names += ["mtp_norm_h", "mtp_norm_e", "mtp_w_proj",
+                      "mtp_final_norm"]
+            names += ["mtp_" + k for k in probe.shapes()]
+            aux += ["mtp_" + k for k in probe.aux_shapes()]
+        self._make_arrays(names, aux)
+
+    def aux_arrays(self) -> Dict[str, Array]:
+        return {a: getattr(self, "aux_" + a) for a in self._anames}
+
+    def initialize(self, device=None, **kwargs: Any):
+        if not self.input:
+            return False
+        n, s, width = self.input.shape
+        c = width // self.streams
+        fill = _gaussian(self)
+        own = {"final_norm": (c,), "weights": (c, self.vocab)}
+        if self.mtp_kw:
+            own.update({"mtp_norm_h": (c,), "mtp_norm_e": (c,),
+                        "mtp_w_proj": (2 * c, c), "mtp_final_norm": (c,)})
+            self.spec = BlockSpec(features=c, streams=self.streams,
+                                  **self.mtp_kw)
+            _init_leaves(self, self.spec, "mtp_", n * s)
+        for name, shape in own.items():
+            arr = getattr(self, name)
+            if not arr:
+                arr.reset(np.ones(shape, np.float32) if len(shape) == 1
+                          else fill(shape, self.init_std))
+        for name in ("aux_ce_main", "aux_ce_mtp"):
+            if not getattr(self, name):
+                getattr(self, name).reset(np.zeros((1,), np.float32))
+        if not self.output or self.output.shape != (n, self.vocab):
+            # the evaluator's view; the fused step never fills it
+            self.output.reset(np.zeros((n, self.vocab), np.float32))
+        return super().initialize(device=device, **kwargs)
+
+    def _read_out(self, x):
+        c = x.shape[-1] // self.streams
+        return sum(x[..., i * c:(i + 1) * c].astype(jnp.float32)
+                   for i in range(self.streams)).astype(x.dtype)
+
+    def fused_apply(self, params, x, *, key=None, train=True, aux=None,
+                    targets=None, weights=None, denom=None):
+        p = params
+        n, s = x["ids"].shape
+        trunk = self._read_out(x["x"]).reshape(n * s, -1)
+        targets = targets.reshape(n, s, -1).astype(jnp.int32)
+        wt = jnp.broadcast_to(weights.astype(jnp.float32)[:, None],
+                              (n, s)).reshape(-1)
+        denom = jnp.maximum(denom * s, 1e-9)
+        chunk = min(self.loss_chunk, n * s)
+        with jax.named_scope("head"):
+            ce, n_err = ol.chunked_ce(
+                ol.rms_norm(trunk, p["final_norm"], self.norm_eps),
+                p["weights"], targets[..., 0].reshape(-1), wt, chunk)
+            ce = ce / denom
+        out = {"ce_main": ce, "ce_mtp": jnp.zeros_like(ce)}
+        if self.spec is None:
+            return (ce, n_err) if aux is None else ((ce, n_err), out)
+        with jax.named_scope("mtp"):
+            with jax.named_scope("proj"):
+                nxt = jnp.take(x["table"], targets[..., 0].reshape(-1),
+                               axis=0)
+                joined = jnp.concatenate(
+                    [ol.rms_norm(trunk, p["mtp_norm_h"], self.norm_eps),
+                     ol.rms_norm(nxt, p["mtp_norm_e"], self.norm_eps)],
+                    axis=-1)
+                h = ol.mm(joined, p["mtp_w_proj"])
+                x2 = jnp.tile(h, (1, self.streams)).reshape(n, s, -1)
+            block = jax.checkpoint(self.spec.apply)
+            x2, counted = block(
+                {k[len("mtp_"):]: v for k, v in p.items()
+                 if k.startswith("mtp_")}, x2,
+                None if aux is None else aux["mtp_bias"])
+            with jax.named_scope("head"):
+                ce2, _ = ol.chunked_ce(
+                    ol.rms_norm(self._read_out(x2).reshape(n * s, -1),
+                                p["mtp_final_norm"], self.norm_eps),
+                    p["weights"], targets[..., 1].reshape(-1), wt, chunk)
+                ce2 = ce2 / denom
+        out["ce_mtp"] = ce2
+        out.update({"mtp_" + k: v for k, v in counted.items()})
+        loss = ce + self.mtp_weight * ce2
+        return (loss, n_err) if aux is None else ((loss, n_err), out)
+
+    def fused_aux_update(self, aux, out):
+        new = {"ce_main": out["ce_main"].reshape(1),
+               "ce_mtp": out["ce_mtp"].reshape(1)}
+        if self.spec is not None:
+            cut = len("mtp_")
+            moved = self.spec.aux_update(
+                {k[cut:]: v for k, v in aux.items() if k.startswith("mtp_")},
+                {k[cut:]: v for k, v in out.items() if k.startswith("mtp_")})
+            new.update({"mtp_" + k: v for k, v in moved.items()})
+        return new
+
+
+def moe_counts(step, aux) -> Dict[str, Dict[str, int]]:
+    """{layer: {steps, slots, held, fullest, dropped}} so far, from the
+    host copy `aux` of a fused step's `state["aux"]`: one entry per expert
+    layer, named by its unit's scope (`L02`), the head's module `mtp`."""
+    out = {}
+    for scope, u, a in zip(step.scopes, step.forwards, aux):
+        spec = getattr(u, "spec", None)
+        if spec is None or spec.ffn != "experts":
+            continue
+        name, pre = (("mtp", "mtp_") if isinstance(u, LMHead)
+                     else (scope.split(".")[0], ""))
+        load = np.asarray(a[pre + "load"], np.int64)
+        first, count = spec.held
+        out[name] = {"steps": int(a[pre + "steps"][0]),
+                     "slots": int(load.sum()),
+                     "held": int(load[first:first + count].sum()),
+                     "fullest": int(a[pre + "fullest"][0]),
+                     "dropped": int(a[pre + "dropped"][0])}
+    return out
+
+
+def publish_moe_counters(now: Dict[str, Dict[str, int]],
+                         base: Optional[Dict[str, Dict[str, int]]] = None,
+                         balance_reached: Optional[bool] = None) -> None:
+    """Set the `veles_moe_*` counters (`telemetry/metrics.py`) to what
+    `moe_counts` read `now`, less what it read at `base`."""
+    from veles_tpu.telemetry import metrics
+    h = metrics.moe_handles()
+    dropped = 0
+    for layer, c in now.items():
+        b = (base or {}).get(layer, dict.fromkeys(c, 0))
+        for key, fam in (("steps", h.steps), ("slots", h.slots),
+                         ("held", h.held), ("fullest", h.fullest)):
+            fam.labels(layer=layer).set_total(c[key] - b[key])
+        dropped += c["dropped"] - b["dropped"]
+    h.dropped.set_total(dropped)
+    if balance_reached is not None:
+        h.reached.set(1.0 if balance_reached else 0.0)
+
+
+@register_gd(TokenEmbedding)
+class GDTokenEmbedding(GradientDescentVJP):
+    pass
+
+
+@register_gd(HCBlock)
+class GDHCBlock(GradientDescentVJP):
+    pass
+
+
+@register_gd(LMHead)
+class GDLMHead(GradientDescentVJP):
+    pass
+
+
+from veles_tpu.znicz import standard_workflow as _sw  # noqa: E402
+
+_sw.LAYER_TYPES.update({"token_embedding": TokenEmbedding,
+                        "hc_block": HCBlock, "lm_head": LMHead})

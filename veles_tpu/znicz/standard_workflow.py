@@ -89,7 +89,12 @@ class StandardWorkflow(Workflow):
 
         # -- evaluator ------------------------------------------------------
         if loss == "softmax":
-            self.evaluator = EvaluatorSoftmax(self, n_classes=n_classes)
+            # a head that owns its loss (fused only) gives the evaluator
+            # nothing to count: no vocabulary-squared confusion matrix
+            owns = getattr(prev, "fused_emits_loss", False)
+            self.evaluator = EvaluatorSoftmax(
+                self, n_classes=1 if owns else n_classes,
+                compute_confusion=not owns)
             self.evaluator.link_attrs(self.loader,
                                       ("labels", "minibatch_labels"))
         elif loss == "mse":
