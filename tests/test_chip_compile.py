@@ -131,6 +131,44 @@ def test_flash_attention_pallas_fwd_bwd_compiles(one_chip, compiled_pallas):
     assert "tpu_custom_call" in _compile(fwd_bwd, q, q, q)
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_hyper_connection_kernels_compile_at_published_widths(
+        one_chip, dtype, compiled_pallas):
+    """One connection of `xing4_ep8`, 8,192 tokens x 4 streams of 3,584
+    (ISSUE 34): forward, and forward + backward by autodiff through both
+    `custom_vjp`s, hold the four kernels under their scopes; the float32
+    streams of a float32 run fit the same 64 MB of VMEM (`hc_view` is a
+    rule of the shape alone)."""
+    from veles_tpu.ops import lm as ol
+    n, c, tokens = 4, 3584, 8192
+    assert pk.hc_view(tokens, c, n) * 2 * (3 * n + 2) * c * 4 \
+        <= pk._HC_BLOCK_BUDGET < pk._HC_VMEM_LIMIT
+    dt = jnp.dtype(dtype)
+    p = {"p_pre": (n * c, n), "p_post": (n * c, n), "p_res": (n * c, n * n),
+         "a_pre": (1,), "a_post": (1,), "a_res": (1,), "b_pre": (n,),
+         "b_post": (n,), "b_res": (n, n)}
+    p = {k: _sds(one_chip, s, dt) for k, s in p.items()}
+    x = _sds(one_chip, (tokens, n * c), dt)
+    apply = variants.get("hc", "pallas_one_pass").apply
+
+    def fwd(pp, xx):
+        return apply(pp, xx, lambda h: (h, None), n, iters=20, eps=1e-6,
+                     clamp=(-30.0, 30.0), norm_eps=1e-6)[0]
+
+    def fwd_bwd(pp, xx):
+        return jax.vjp(fwd, pp, xx)[1](xx)     # the cotangent: x itself
+
+    assert ol.hc_pallas_takes(x, n)
+    txt = _compile(fwd, p, x)
+    assert txt.count("tpu_custom_call") >= 2
+    txt += _compile(fwd_bwd, p, x)
+    for name in ("veles_hc_pre_fwd", "veles_hc_post_fwd",
+                 "veles_hc_post_bwd", "veles_hc_pre_bwd"):
+        assert name in txt, name
+    for scope in ("/hc_pre/", "/hc_post/"):
+        assert scope in txt, scope
+
+
 @pytest.mark.parametrize("site", LRN_SITES, ids=lambda s: "x".join(map(str, s)))
 def test_lrn_maxpool_pallas_fwd_bwd_compiles(one_chip, site,
                                              compiled_pallas):
@@ -429,64 +467,79 @@ def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
 
 # -- the sparse-expert language model's step at its published widths (ISSUE 32) --
 
-def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip):
+def _trace_cost():
+    """`tools/trace_cost.py` as a module (tools/ is no package)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "trace_cost", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "trace_cost.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip,
+                                                         compiled_pallas):
     """`benchmark/configs/xing4_ep8.json` through the sample's layer table,
     `StandardWorkflow` and `FusedTrainStep`: 8,192 tokens, bfloat16, one
     `jax.checkpoint` a block. The grouped products of the held experts
-    lower to the TPU's grouped-matmul kernel (`lax.ragged_dot`), so the
-    program holds custom calls without a `pallas_call` of this repo's; a
+    lower to the TPU's grouped-matmul kernel (`lax.ragged_dot`); the twelve
+    hyper-connections run the four `veles_hc_*` kernels (ISSUE 34). A
     shape or memory fault shows here before a chip is asked. The units
-    hold zeros (`init_std` 0: no draw), nothing is put on a device."""
-    import json
+    hold zeros (`init_std` 0: no draw), nothing is put on a device.
 
-    from veles_tpu.loader.fullbatch import FullBatchLoader
-    from veles_tpu.samples import xing4
-    from veles_tpu.znicz.standard_workflow import StandardWorkflow
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "xing4_ep8.json")) as f:
-        cfg = json.load(f)
-    batch, seq = cfg["batch_per_chip"], cfg["seq_len"]
-
-    class ShapeOnlyLoader(FullBatchLoader):
-        def load_data(self):
-            self.bind_arrays(np.zeros((batch, seq), np.int32),
-                             np.zeros((batch, seq, 2), np.int32), 0, 0,
-                             batch)
-
-    wf = StandardWorkflow(
-        layers=xing4.layer_table({**cfg, "init_std": 0.0}),
-        loader=ShapeOnlyLoader(minibatch_size=batch, on_device=False),
-        loss="softmax", n_classes=cfg["vocab_size"],
-        decision_config={"max_epochs": 1, "fail_iterations": 1},
-        gd_config=dict(cfg["optimizer"]), name="xing4_compile")
-    wf.initialize(device=None)
-    step = wf.build_fused_step(compute_dtype=cfg["compute_dtype"])
+    What Python pays before XLA sees the step is held to COUNTS, which do
+    not wobble under xdist as seconds do (PR 33 inlined a `pallas_call` a
+    site, 72 bodies, and was refused for 15 s of `setup_s` that no compile
+    clock held): each kernel is jitted once and called a site, and the
+    traced step is no larger than under the `xla` lowering, traced here
+    too. The seconds of both are printed; PERF.md quotes them."""
+    tc = _trace_cost()
+    xla = tc.measure("xla", one_chip)
+    one = tc.measure("pallas_one_pass", one_chip)
+    assert (xla["hc"], one["hc"]) == ("xla", "pallas_one_pass")
+    for row in (xla, one):
+        print("trace_cost", {k: row[k] for k in (
+            "hc", "trace_s", "lower_s", "equations", "stablehlo_bytes",
+            "kernels")})
+    assert not xla["kernels"]
+    assert one["equations"] <= xla["equations"]
+    assert one["stablehlo_bytes"] <= xla["stablehlo_bytes"]
+    # a backward kernel's body once; a forward kernel's at most twice: the
+    # plain one of the first forward and the one `jax.checkpoint`'s partial
+    # evaluation stages for the recomputed forward (derived once, cached)
+    assert {k: v["bodies"] for k, v in one["kernels"].items()} == {
+        "veles_hc_pre_fwd": 2, "veles_hc_post_fwd": 2,
+        "veles_hc_post_bwd": 1, "veles_hc_pre_bwd": 1}, one["kernels"]
+    assert all(v["sites"] >= 12 for v in one["kernels"].values()), \
+        one["kernels"]
+    cfg, step = one["config"], one["step"]
     assert step.has_aux and step.unit_loss
-    state = _abstract_step_args(
-        step, batch, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t),
-        one_chip)[0]
-    assert sum(int(np.prod(a.shape)) for layer in state["params"]
-               for a in layer.values()) == cfg["n_params"]
-    args = (state,
-            jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip),
-            jax.ShapeDtypeStruct((batch, seq, 2), jnp.int32,
-                                 sharding=one_chip),
-            jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=one_chip))
-    # at the platform's default precision, as the benchmark runs it
-    # (conftest.py pins "highest" for golden comparisons, and the TPU's
-    # grouped-matmul kernel takes no bfloat16 operands at fp32 precision)
-    with jax.default_matmul_precision("bfloat16"):
-        compiled = jax.jit(step.train_callable(),
-                           donate_argnums=(0,)).lower(*args).compile()
+    compiled = one["lowered"].compile()
     txt = compiled.as_text()
     assert "ragged-dot" in txt and "tpu_custom_call" in txt
     for scope in ("/mla/", "/moe/experts/", "/hc_pre/", "/hc_post/",
                   "update/balance", "rematted_computation"):
         assert scope in txt, scope
+    # after XLA inlines the calls every site's kernel carries its own path
+    # under the scope `step_hc_ms` reads
+    import re
+    for kernel, side in (("veles_hc_pre_fwd", "hc_pre"),
+                         ("veles_hc_pre_bwd", "hc_pre"),
+                         ("veles_hc_post_fwd", "hc_post"),
+                         ("veles_hc_post_bwd", "hc_post")):
+        paths = set(re.findall(
+            r'op_name="([^"]*/%s/[^"]*%s[^"]*)"' % (side, kernel), txt))
+        units = {m for p_ in paths for m in re.findall(r"L\d\d\.\w+", p_)}
+        assert len(units) >= 5, (kernel, sorted(paths)[:3])
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print("xing4 step: generated code",
+          mem.generated_code_size_in_bytes, "B, temporaries",
+          mem.temp_size_in_bytes, "B")
+    assert sum(int(np.prod(a.shape)) for layer in one["args"][0]["params"]
+               for a in layer.values()) == cfg["n_params"]
     # parameters and velocity, float32: 8 B a parameter of arguments
     assert mem.argument_size_in_bytes > 8 * cfg["n_params"]
     # what a v5e's allocator offers: `bytes_limit` of its memory

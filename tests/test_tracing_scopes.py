@@ -191,7 +191,28 @@ PALLAS_SITES = {
     "flash": (jax.grad(lambda q: pk.flash_attention_pallas(
         q, q, q, blk_q=16, blk_k=16).sum()), QKV,
         ["veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv"]),
+    # one hyper-connection of 2 streams of 128 around the identity: the
+    # pre and the post side forward, the post side's and the pre side's
+    # backward, each through its module-level jit
+    "hc": (jax.grad(lambda x: _connection(x).sum()),
+           np.ones((128, 256), np.float32),
+           ["veles_hc_pre_fwd", "veles_hc_post_fwd", "veles_hc_post_bwd",
+            "veles_hc_pre_bwd"]),
 }
+
+
+def _connection(x, n=2):
+    from veles_tpu.ops import lm as ol
+    c = x.shape[1] // n
+    p = {"p_pre": np.ones((n * c, n), np.float32),
+         "p_post": np.ones((n * c, n), np.float32),
+         "p_res": np.ones((n * c, n * n), np.float32),
+         **{k: np.ones((1,), np.float32)
+            for k in ("a_pre", "a_post", "a_res")},
+         **ol.hc_init_biases(n)}
+    return ol.hyper_connection(
+        ol.hc_pre_pallas, ol.hc_post_pallas, p, x, lambda h: (h, None), n,
+        iters=2, eps=1e-6, clamp=(-30.0, 30.0), norm_eps=1e-6)[0]
 
 
 @pytest.mark.parametrize("site", sorted(PALLAS_SITES))
@@ -205,9 +226,9 @@ def test_every_pallas_call_has_its_fixed_name(site):
     assert set(want) <= set(pk.KERNEL_NAMES.values())
 
 
-def test_all_eight_kernels_are_named_and_no_name_twice():
+def test_all_twelve_kernels_are_named_and_no_name_twice():
     names = list(pk.KERNEL_NAMES.values())
-    assert len(names) == 8 == len(set(names))
+    assert len(names) == 12 == len(set(names))
     with open(pk.__file__) as f:
         src = f.read()
     assert src.count("pl.pallas_call(") == src.count("name=KERNEL_NAMES[")

@@ -1,0 +1,170 @@
+#!/usr/bin/env python3
+"""What Python pays before XLA sees `xing4_ep8.step`'s train step: the
+seconds of `.trace()` and `.lower()`, the traced step's top-level
+equations, the bytes of StableHLO, and how many bodies and call sites the
+`veles_hc_*` kernels leave in the lowered module.
+
+No chip and no device buffer: the step is built from
+`benchmark/configs/xing4_ep8.json` with zero weights and traced at
+abstract arguments (ISSUE 34: PR 33 lost 15 s of `setup_s` that neither
+the compile clock nor the device held; tracing and lowering are what the
+persistent compile cache does not skip). The seconds are of THIS host;
+the counts are the same everywhere, and
+`tests/test_chip_compile.py::test_xing4_ep8_train_step_compiles_and_fits_one_chip`
+asserts on them through `measure`.
+
+    python tools/trace_cost.py                  # what this platform traces
+    python tools/trace_cost.py --described --hc xla --hc pallas_one_pass
+
+`--described` places the arguments on a described v5e (no chip needed) and
+answers the kernels' `available()` as that chip would, so that the Pallas
+lowering is what traces and lowers here.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+import time
+from typing import Any, Dict, Optional, Tuple
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def xing4_step(sharding=None) -> Tuple[Any, tuple, Dict[str, Any]]:
+    """(step, abstract (state, ids, targets, weights), config) of the
+    cell's program; `sharding` places every argument (a described chip's
+    `SingleDeviceSharding`), None leaves them unplaced."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from veles_tpu.loader.fullbatch import FullBatchLoader
+    from veles_tpu.parallel import checkpoint as ck
+    from veles_tpu.samples import xing4
+    from veles_tpu.znicz.standard_workflow import StandardWorkflow
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "xing4_ep8.json")) as f:
+        cfg = json.load(f)
+    batch, seq = cfg["batch_per_chip"], cfg["seq_len"]
+
+    class ShapeOnlyLoader(FullBatchLoader):
+        def load_data(self):
+            self.bind_arrays(np.zeros((batch, seq), np.int32),
+                             np.zeros((batch, seq, 2), np.int32), 0, 0,
+                             batch)
+
+    wf = StandardWorkflow(
+        layers=xing4.layer_table({**cfg, "init_std": 0.0}),
+        loader=ShapeOnlyLoader(minibatch_size=batch, on_device=False),
+        loss="softmax", n_classes=cfg["vocab_size"],
+        decision_config={"max_epochs": 1, "fail_iterations": 1},
+        gd_config=dict(cfg["optimizer"]), name="xing4_compile")
+    wf.initialize(device=None)
+    step = wf.build_fused_step(compute_dtype=cfg["compute_dtype"])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        ck._abstract_state(step, "threefry2x32"))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state["key"] = sds(key.shape, key.dtype)
+    args = (state, sds((batch, seq), jnp.int32),
+            sds((batch, seq, 2), jnp.int32), sds((batch,), jnp.float32))
+    return step, args, cfg
+
+
+def kernel_counts(stablehlo: str, prefix: str = "veles_hc_"
+                  ) -> Dict[str, Dict[str, int]]:
+    """{kernel: {"bodies": custom calls of that name in the module's text,
+    "sites": times a function holding one is reached from `main`}}. A
+    kernel jitted once is ONE body in a private function called at every
+    site; a kernel inlined a site is a body a site."""
+    heads = [(m.group(1), m.start()) for m in re.finditer(
+        r"func\.func (?:public |private )?@([\w.]+)\(", stablehlo)]
+    funcs = {name: stablehlo[a:b] for (name, a), (_, b) in zip(
+        heads, heads[1:] + [("", len(stablehlo))])}
+    calls = {n: re.findall(r"call @([\w.]+)\(", body)
+             for n, body in funcs.items()}
+    reached: Dict[str, int] = {}
+
+    def walk(name: str, times: int) -> None:
+        reached[name] = reached.get(name, 0) + times
+        for callee in calls.get(name, ()):
+            walk(callee, times)
+
+    walk("main", 1)
+    out: Dict[str, Dict[str, int]] = {}
+    for fname, body in funcs.items():
+        for k in re.findall(r'kernel_name = "(%s\w+)"' % prefix, body):
+            row = out.setdefault(k, {"bodies": 0, "sites": 0})
+            row["bodies"] += 1
+            row["sites"] += reached.get(fname, 0)
+    return out
+
+
+def measure(hc: Optional[str] = None, sharding=None) -> Dict[str, Any]:
+    """Trace and lower the step once under the `hc` lowering named (None:
+    what the platform resolves) and count. Returns the numbers and the
+    `lowered` object, so a caller can go on to compile it."""
+    import jax
+
+    from veles_tpu.ops import variants
+    if hc is not None:
+        variants.select("hc", hc)
+    try:
+        step, args, cfg = xing4_step(sharding)
+        table = step.variant_table()
+        # at the platform's default precision, as the benchmark runs it
+        with jax.default_matmul_precision("bfloat16"):
+            fn = jax.jit(step.train_callable(), donate_argnums=(0,))
+            t0 = time.perf_counter()
+            traced = fn.trace(*args)
+            t1 = time.perf_counter()
+            lowered = traced.lower()
+            t2 = time.perf_counter()
+    finally:
+        if hc is not None:
+            variants.clear_selection("hc")
+    text = lowered.as_text()
+    return {"hc": table.get("hc"), "trace_s": t1 - t0, "lower_s": t2 - t1,
+            "equations": len(traced.jaxpr.eqns),
+            "stablehlo_bytes": len(text), "kernels": kernel_counts(text),
+            "text": text, "lowered": lowered, "config": cfg, "step": step,
+            "args": args}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--hc", action="append", default=None,
+                    help="an `hc` lowering to trace under (repeatable)")
+    ap.add_argument("--described", action="store_true",
+                    help="lower for a described v5e instead of the "
+                         "platform's own device")
+    ns = ap.parse_args(argv)
+    sys.path.insert(0, REPO)
+    sharding = None
+    if ns.described:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        from veles_tpu.ops import pallas_kernels as pk
+        sharding = SingleDeviceSharding(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0])
+        pk.available = lambda: True
+    for hc in ns.hc or [None]:
+        row = measure(hc, sharding)
+        print("TRACE_COST " + json.dumps(
+            {k: v for k, v in row.items()
+             if k not in ("text", "lowered", "config", "step", "args")}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

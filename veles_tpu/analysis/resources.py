@@ -81,8 +81,10 @@ HBM_LIMIT_ENV = "VELES_HBM_LIMIT"
 #: device limit (the static resident model always runs)
 PREFLIGHT_ENV = "VELES_RESOURCE_PREFLIGHT"
 
-#: the limit every kernel in ops/pallas_kernels.py compiles under: no
-#: pallas_call there passes `vmem_limit_bytes`, so Mosaic holds each one
+#: the limit the kernels in ops/pallas_kernels.py compile under: their
+#: pallas_calls pass no `vmem_limit_bytes` (the four hyper-connection
+#: kernels aside, which ask for `_HC_VMEM_LIMIT` and are no tuning axis),
+#: so Mosaic holds each one
 #: to its DEFAULT scoped-VMEM limit, whatever the chip's physical VMEM
 #: (128 MiB on a v5e). Asked of the v5e compiler in PR 21 ("Scoped
 #: allocation with size 18.83M and limit 16.00M exceeded scoped vmem

@@ -2,11 +2,18 @@
 Sinkhorn-projected hyper-connection around a sub-layer, and the chunked
 next-token cross-entropy of an untied head.
 
-Not in the reference (a 2015 codebase). Pure `jax.numpy`: every function
-is differentiable by `jax.grad`, traces into the fused step and knows no
+Not in the reference (a 2015 codebase). `jax.numpy`: every function is
+differentiable by `jax.grad`, traces into the fused step and knows no
 unit; the units are `znicz/lm.py`. The benchmark's plain float32 reference
 of the same equations is `benchmark/xing4_reference.py`, which imports
 nothing from here.
+
+One op has two lowerings (`ops/variants.py`, op `hc`; the section "the
+same connection, one pass a side" below says why): `xla`, the three
+expressions `hc_maps` / `hc_read` / `hc_write` under autodiff, and
+`pallas_one_pass`, two `jax.custom_vjp` functions over four kernels tiled
+over tokens. `hyper_connection` runs either; the platform and the shape
+choose, no option does.
 
 Layouts are chosen for the TPU's (8, 128) tiles. The `n` residual streams
 of a token lie side by side in ONE row of `n * C` features (stream `i` is
@@ -18,6 +25,7 @@ that twenty Sinkhorn iterations are dense elementwise passes.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -154,6 +162,150 @@ def hc_write(x, y, h_post, h_res, n: int):
     out = [sum(h_res[j, i][:, None] * xs[i] for i in range(n))
            + h_post[j][:, None] * yf for j in range(n)]
     return jnp.concatenate(out, axis=-1).astype(x.dtype)
+
+
+# -- the same connection, one pass a side (ISSUE 34) ------------------------------
+#
+# `hc_maps` / `hc_read` / `hc_write` above are the op `hc`'s `xla` lowering:
+# three `jax.numpy` expressions that autodiff differentiates, 37 passes
+# over the streams a connection on a v5e (a normed copy of x before the
+# maps' product, x read again by the read and by the write, float32
+# cotangents of every slice of x, twenty unrolled Sinkhorn iterations and
+# their transposes as fusions of their own). The `pallas_one_pass` lowering
+# below is the same mathematics as two `jax.custom_vjp` functions, one a
+# side, whose work is four kernels (`ops/pallas_kernels.py`, `veles_hc_*`,
+# each behind ONE module-level `jax.jit` that every site of a step calls):
+# x is read once a side and direction, the maps with their Sinkhorn
+# iterations are made and differentiated inside the kernels, and nothing
+# stream-sized exists in float32 outside one. The RMS norm of x is a
+# per-token scalar r, so it is applied AFTER the product, raw = (P^T x) r:
+# the equation of `rms_norm` then `dot_general` with one rounding to
+# bfloat16 fewer and no second copy of x. Outside the kernels stay the
+# operands' assembly (P^T, the scalars and biases by column) and the two
+# sums over tokens that are the scalars' and biases' gradients. What
+# chooses between the two is `ops/variants.py::resolve` (platform) and
+# `pallas_kernels.hc_view` (shape); no option does.
+
+def _hc_operands(p: Dict[str, Any], n: int, dtype):
+    """(P^T (kp, n*C) in the streams' dtype, the affine pair (2, kp)
+    float32: a raw + b by column, 0 past the maps) for the kernels."""
+    from veles_tpu.ops import pallas_kernels as pk
+    k, kp = pk.hc_maps_width(n)
+    pt = jnp.concatenate([p["p_pre"], p["p_post"], p["p_res"]],
+                         axis=1).astype(dtype).T
+    f32 = lambda name: p[name].astype(jnp.float32)  # noqa: E731
+    a = jnp.concatenate([jnp.broadcast_to(f32("a_pre"), (n,)),
+                         jnp.broadcast_to(f32("a_post"), (n,)),
+                         jnp.broadcast_to(f32("a_res"), (n * n,))])
+    b = jnp.concatenate([f32("b_pre"), f32("b_post"),
+                         f32("b_res").reshape(-1)])
+    return (jnp.pad(pt, ((0, kp - k), (0, 0))),
+            jnp.pad(jnp.stack([a, b]), ((0, 0), (0, kp - k))))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _hc_pre_core(x, pt, aff, static):
+    """-> (the maps m (T, kp), h (T, C), x). x goes through untouched for
+    the post side to take: what its backward sends to x then arrives HERE,
+    as the third cotangent, and the pre side's backward kernel adds it
+    into its own dx in float32; handed x itself, the post side would leave
+    autodiff two stream-sized cotangents to add in a pass of its own.
+    `static` is the kernels' keywords as a sorted tuple of pairs."""
+    return _hc_pre_core_fwd(x, pt, aff, static)[0]
+
+
+def _hc_pre_core_fwd(x, pt, aff, static):
+    from veles_tpu.ops import pallas_kernels as pk
+    raw, m, h = pk.hc_pre_forward_pallas(x, pt, aff, **dict(static))
+    return (m, h, x), (x, pt, aff, raw)
+
+
+def _hc_pre_core_bwd(static, res, cts):
+    from veles_tpu.ops import pallas_kernels as pk
+    x, pt, aff, raw = res
+    dm, dh, gx = cts
+    # a custom_vjp's backward is traced outside the forward's scope
+    with jax.named_scope("hc_pre"):
+        dx, dlin, dpt = pk.hc_pre_backward_pallas(
+            x, gx, dh, raw, dm, pt, aff,
+            **{k: v for k, v in static if k != "norm_eps"})
+        daff = jnp.stack([(dlin * raw).sum(axis=0), dlin.sum(axis=0)])
+        return dx, dpt.astype(pt.dtype), daff
+
+
+_hc_pre_core.defvjp(_hc_pre_core_fwd, _hc_pre_core_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _hc_post_core(x, y, m, n: int, interpret: bool):
+    from veles_tpu.ops import pallas_kernels as pk
+    return pk.hc_post_forward_pallas(x, y, m, n=n, interpret=interpret)
+
+
+def _hc_post_core_fwd(x, y, m, n, interpret):
+    return _hc_post_core(x, y, m, n, interpret), (x, y, m)
+
+
+def _hc_post_core_bwd(n, interpret, res, g):
+    from veles_tpu.ops import pallas_kernels as pk
+    with jax.named_scope("hc_post"):        # as in `_hc_pre_core_bwd`
+        return pk.hc_post_backward_pallas(g, *res, n=n, interpret=interpret)
+
+
+_hc_post_core.defvjp(_hc_post_core_fwd, _hc_post_core_bwd)
+
+
+def hc_pallas_takes(x, n: int) -> bool:
+    """Whether the kernels take streams x (T, n*C): the shape half of the
+    rule (`pallas_kernels.hc_view`)."""
+    from veles_tpu.ops import pallas_kernels as pk
+    return x.ndim == 2 and x.shape[1] % n == 0 and bool(pk.hc_view(
+        x.shape[0], x.shape[1] // n, n))
+
+
+def hc_pre_xla(p: Dict[str, Any], x, n: int, **kw):
+    """(h, what the post side takes: (Hpost, Hres), x) of one connection,
+    the `xla` lowering."""
+    h_pre, h_post, h_res = hc_maps(p, x, n, **kw)
+    return hc_read(x, h_pre, n), (h_post, h_res), x
+
+
+def hc_post_xla(x, y, maps, n: int):
+    return hc_write(x, y, *maps, n)
+
+
+def hc_pre_pallas(p: Dict[str, Any], x, n: int, **kw):
+    """The same in one pass over x (`pallas_one_pass`); the XLA form for
+    streams the kernels do not take."""
+    if not hc_pallas_takes(x, n):
+        return hc_pre_xla(p, x, n, **kw)
+    from veles_tpu.ops import pallas_kernels as pk
+    static = dict(kw, n=n, interpret=pk._interpret(),
+                  clamp=tuple(float(v) for v in kw["clamp"]))
+    m, h, x = _hc_pre_core(x, *_hc_operands(p, n, x.dtype),
+                           tuple(sorted(static.items())))
+    return h, m, x
+
+
+def hc_post_pallas(x, y, maps, n: int):
+    """`hc_write` as one pass (`pallas_one_pass`)."""
+    if not hc_pallas_takes(x, n):
+        return hc_post_xla(x, y, maps, n)
+    from veles_tpu.ops import pallas_kernels as pk
+    return _hc_post_core(x, y, maps, n, pk._interpret())
+
+
+def hyper_connection(pre, post, p: Dict[str, Any], x, f, n: int, **kw):
+    """One hyper-connection around `f` through a lowering's two sides:
+    x (T, n*C) -> (Hres X + Hpost^T f(Hpre X), f's extra), under the
+    scopes `hc_pre` and `hc_post` (the backwards of `pallas_one_pass` open
+    them again: a `custom_vjp`'s backward is traced outside the
+    forward's)."""
+    with jax.named_scope("hc_pre"):
+        h, maps, x = pre(p, x, n, **kw)
+    y, extra = f(h)
+    with jax.named_scope("hc_post"):
+        return post(x, y, maps, n), extra
 
 
 # -- the head and its loss ------------------------------------------------------------

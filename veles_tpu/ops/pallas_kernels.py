@@ -1,11 +1,14 @@
 """Pallas TPU kernels for ops where manual fusion/control beats stock XLA.
 
 SURVEY.md §7 lists the candidates: LRN forward and backward (one
-streaming pass each in the layout the convs emit — the one kernel pair a
-benchmark cell runs), the fused SGD/momentum update (single
+streaming pass each in the layout the convs emit — the kernel pair the
+AlexNet cells run), the fused SGD/momentum update (single
 read-modify-write over params), and flash-attention-style blocks (the
 ring already handles cross-chip; this kernel is the intra-chip tile
-loop).
+loop). Since ISSUE 34 also the four one-pass sides of a hyper-connection
+over the residual streams of the sparse-expert language model
+(`veles_hc_*`, what `xing4_ep8.step` runs at 12 sites a step, each
+traced and lowered once).
 
 Every kernel has a lax twin in ops.xla / ops.attention — these are
 drop-in replacements gated by `available()`. Interpret mode is something
@@ -18,6 +21,7 @@ tests/test_chip_compile.py compiles every kernel for a described v5e.
 from __future__ import annotations
 
 import functools
+import inspect
 from typing import Optional, Tuple
 
 import jax
@@ -53,6 +57,25 @@ _LRN_SLAB_ROWS_MAX = 512
 _LRN_SLABS_IN_FLIGHT = 10
 _LRN_BATCH_VIEW_C = 16
 _LRN_LANE_MAX = 1024
+#: the hyper-connection sides (ISSUE 34). A grid step holds a tile of
+#: tokens' rows: the largest multiple of 128 tokens (a token's maps are
+#: mixed by Sinkhorn with the tokens in the LANES) that divides the tokens
+#: and whose rows in the widest of the four kernels, the post side's
+#: backward (three stream-sized blocks in, one out), counted in float32
+#: whatever the compute dtype (one rule a shape) and double-buffered, fit
+#: _HC_BLOCK_BUDGET: from the row's bytes. On a v5e 128, 256 and 512
+#: tokens ran the pre side's forward at 0.485, 0.506 and 0.476 ms and left
+#: the other three where they were (my chip run, PR 34), so one tile serves
+#: all four, and _HC_TOKEN_TILE_MAX only bounds what the in-kernel
+#: temporaries of a narrow model's tile take. The kernels ask for _HC_VMEM_LIMIT of scoped VMEM, over the
+#: compiler's default of 16 MB and well inside a v5e's 128 MiB (25.7 MB of
+#: blocks at 128 tokens of 4 x 3584 bfloat16 features; the rest is the
+#: in-kernel temporaries'). A slab is the most lanes of one stream a
+#: kernel's inner step spans.
+_HC_TOKEN_TILE_MAX = 512
+_HC_LANE_SLAB = 512
+_HC_VMEM_LIMIT = 64 << 20
+_HC_BLOCK_BUDGET = 52 << 20
 #: fused-SGD row blocking seed (the pre-search hand-written value)
 _SGD_ROW_TILE = 8
 #: fused LRN+maxpool sample tile seed: SAMPLES per VMEM block (each
@@ -99,6 +122,10 @@ KERNEL_NAMES = {
     "_flash_kernel": "veles_flash_fwd",
     "_flash_dq_kernel": "veles_flash_dq",
     "_flash_dkv_kernel": "veles_flash_dkv",
+    "_hc_pre_fwd_kernel": "veles_hc_pre_fwd",
+    "_hc_pre_bwd_kernel": "veles_hc_pre_bwd",
+    "_hc_post_fwd_kernel": "veles_hc_post_fwd",
+    "_hc_post_bwd_kernel": "veles_hc_post_bwd",
 }
 
 
@@ -1004,3 +1031,395 @@ def flash_attention_pallas(q, k, v, scale: Optional[float] = None,
             heads_first(jnp.asarray(drop_mask)).astype(jnp.float32),
             float(scale), causal, blk_q, blk_k, kv_order)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# hyper-connection sides (ISSUE 34): one Sinkhorn-mixed hyper-connection
+# around a sub-layer (ops/lm.py) as four kernels tiled over tokens, each
+# ONE pass over the rows it touches. A grid step holds a tile of tokens'
+# rows of the streams (T, n*C) in VMEM and walks them a slab of lanes at a
+# time; every product and every sum is float32, the streams are rounded
+# once on the way out.
+#
+# Passes over the streams a connection (one pass = the (T, n*C) array once;
+# an array of ONE stream, h, y, dh, dy, is a quarter at n = 4):
+#   pre  forward   x in, h out                          1.25
+#   post forward   x, y in, the streams out             2.25
+#   the same two recomputed under the block's jax.checkpoint (the block's
+#   last post side feeds nothing the backward reads, and goes)  <= 3.5
+#   post backward  dout, x, y in, dx, dy out            3.5
+#   pre  backward  x, gx, dh in, dx out                 3.25
+# 13.75 of the 16 the issue allows (37 as XLA traced the same equations),
+# plus six (T, kp) float32 arrays of per-token maps, 1.7 % of a pass each.
+#
+# The per-token maps live in HBM with the tokens in SUBLANES, (T, kp)
+# float32, so that a token's coefficient is a column that broadcasts along
+# the lanes of its row. Column layout, k = 2n + n*n: Hpre_i at i, Hpost_j
+# at n + j, Hres[j, i] at 2n + j*n + i; in `raw` (the maps before their
+# affine pairs and squashings) column k holds the token's rsqrt. The n x n
+# mixing map is turned inside the kernel to tokens-in-LANES, n slabs of
+# (n, tile), where the 20 Sinkhorn iterations are dense elementwise steps
+# (a row's sum is a sum over a slab's sublanes, a column's a sum over the
+# slabs), and turned back.
+#
+# Each of the four entry points is ONE module-level `jax.jit`: the 12 sites
+# of a step share its trace and the step's module holds its kernel body
+# once, called a site (PR 33 inlined them: 72 bodies traced and lowered by
+# Python, 15 s of `setup_s` that no compile clock held).
+# ---------------------------------------------------------------------------
+
+
+def hc_maps_width(n: int) -> Tuple[int, int]:
+    """(k, kp): the maps of a token, 2n + n*n numbers, and the columns of
+    the arrays that carry them: one more for the token's rsqrt, on whole
+    bfloat16 tiles of 16 rows of P^T."""
+    k = 2 * n + n * n
+    return k, -(-(k + 1) // 16) * 16
+
+
+def hc_view(tokens: int, c: int, n: int) -> Optional[int]:
+    """The token tile the four kernels take streams (tokens, n*c) with
+    (the block comment at _HC_TOKEN_TILE_MAX has the rule), or None where they
+    take none (the caller then traces the XLA form): a stream is whole
+    lanes and a multiple of 128 tokens that fits divides the tokens. A
+    rule of the shape alone: what a unit reports is what it traces in any
+    compute dtype."""
+    if c % _LANE or n < 2:
+        return None
+    return _largest_divisor(tokens, _LANE, min(
+        _HC_TOKEN_TILE_MAX, _HC_BLOCK_BUDGET // (2 * 4 * (3 * n + 2) * c)))
+
+
+def _fold_lanes(a):
+    """(rows, L) -> (rows, 128): its 128-lane groups added, whole
+    registers at a time; the one reduction across lanes comes after the
+    last slab."""
+    out = a[:, :_LANE]
+    for j in range(1, a.shape[1] // _LANE):
+        out = out + a[:, j * _LANE:(j + 1) * _LANE]
+    return out
+
+
+def _lane_sum(a):
+    return jnp.sum(a, axis=1, keepdims=True)
+
+
+def _place(cols, rows: int, width: int):
+    """(rows, width) float32 whose column k is `cols[k]` (rows, 1)."""
+    lane = lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    out = jnp.zeros((rows, width), jnp.float32)
+    for k, col in cols.items():
+        out = jnp.where(lane == k, col, out)
+    return out
+
+
+def _sigmoid(z):
+    return 1.0 / (1.0 + jnp.exp(-z))
+
+
+def _hc_dot(a, b, dims):
+    """A product on the MXU accumulated in float32. bfloat16 operands are
+    exact in one pass, whatever matmul precision the caller has set; a
+    float32 caller's setting holds."""
+    exact = a.dtype == jnp.bfloat16 and b.dtype == jnp.bfloat16
+    return lax.dot_general(
+        a, b, (dims, ((), ())),
+        precision=lax.Precision.DEFAULT if exact else None,
+        preferred_element_type=jnp.float32)
+
+
+def _res_slabs(t_ref, n: int):
+    """The n x n map of a tile from the turned scratch (kp, tile): slab j
+    is row j of every token's matrix, (n, tile)."""
+    return [t_ref[(2 + j) * n:(3 + j) * n, :] for j in range(n)]
+
+
+def _sinkhorn_slabs(m, iters: int, eps: float):
+    """`ops.lm.sinkhorn` on slabs: every iterate, 2 * iters + 1 lists of n
+    slabs (n, tile); rows before columns."""
+    out = [m]
+    for _ in range(iters):
+        m = [s / (jnp.sum(s, axis=0, keepdims=True) + eps) for s in m]
+        out.append(m)
+        d = functools.reduce(jnp.add, m) + eps
+        m = [s / d for s in m]
+        out.append(m)
+    return out
+
+
+def _sinkhorn_slabs_transpose(its, g, iters: int, eps: float):
+    """The cotangent of the first iterate from `g`, that of the last:
+    y = m / d, d = sum m + eps gives dm = (dy - sum(dy y)) / d."""
+    for step in reversed(range(iters)):
+        prev, y = its[2 * step + 1], its[2 * step + 2]
+        d = functools.reduce(jnp.add, prev) + eps
+        t = functools.reduce(jnp.add, [a * b for a, b in zip(g, y)])
+        g = [(a - t) / d for a in g]
+        prev, y = its[2 * step], its[2 * step + 1]
+        g = [(a - jnp.sum(a * b, axis=0, keepdims=True))
+             / (jnp.sum(p, axis=0, keepdims=True) + eps)
+             for a, b, p in zip(g, y, prev)]
+    return g
+
+
+def _hc_pre_fwd_kernel(x_ref, pt_ref, aff_ref, raw_ref, m_ref, h_ref, t_ref,
+                       *, n: int, slab: int, iters: int, eps: float,
+                       clamp: Tuple[float, float], norm_eps: float):
+    """One read of a tile's rows: u = x P (rows, kp) on the MXU, the sum
+    of squares, raw = u rsqrt(mean x^2 + eps) (the norm is a per-token
+    scalar: applied after the product it is the same equation with no
+    normed copy of x), the three maps (`m_ref`) and h = sum_i Hpre_i x_i
+    from the rows still in VMEM."""
+    rows, width = x_ref.shape
+    c, (k, _) = width // n, hc_maps_width(n)
+    u = _hc_dot(x_ref[...], pt_ref[...], ((1,), (1,)))
+    ssq = jnp.zeros((rows, _LANE), jnp.float32)
+    for j in range(width // slab):
+        xf = x_ref[:, j * slab:(j + 1) * slab].astype(jnp.float32)
+        ssq = ssq + _fold_lanes(xf * xf)
+    r = lax.rsqrt(_lane_sum(ssq) / width + norm_eps)
+    lane = lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    raw = jnp.where(lane == k, r, u * r)
+    raw_ref[...] = raw
+    lin = raw * aff_ref[0:1, :] + aff_ref[1:2, :]
+    s = _sigmoid(lin)
+    for j in range(c // slab):
+        acc = s[:, 0:1] * x_ref[:, j * slab:(j + 1) * slab].astype(
+            jnp.float32)
+        for i in range(1, n):
+            at = slice(i * c + j * slab, i * c + (j + 1) * slab)
+            acc = acc + s[:, i:i + 1] * x_ref[:, at].astype(jnp.float32)
+        h_ref[:, j * slab:(j + 1) * slab] = acc.astype(h_ref.dtype)
+    t_ref[...] = lin.T
+    res = _sinkhorn_slabs(
+        [jnp.exp(jnp.clip(z, clamp[0], clamp[1])) for z in _res_slabs(
+            t_ref, n)], iters, eps)[-1]
+    for j in range(n):
+        t_ref[(2 + j) * n:(3 + j) * n, :] = res[j]
+    m_ref[...] = jnp.where(lane < n, s, jnp.where(
+        lane < 2 * n, 2.0 * s, jnp.where(lane < k, t_ref[...].T, 0.0)))
+
+
+def _hc_pre_bwd_kernel(x_ref, gx_ref, dh_ref, raw_ref, dm_ref, pt_ref,
+                       aff_ref, dx_ref, dlin_ref, dpt_ref, t_ref, *, n: int,
+                       slab: int, iters: int, eps: float,
+                       clamp: Tuple[float, float]):
+    """One read of x and dh: dHpre_i = <dh, x_i> joins the cotangent
+    `dm_ref` the post side sent to the maps; back through the sigmoids
+    and, with the tokens in the lanes, through the Sinkhorn iterations
+    (run again from `raw_ref`) into `dlin_ref`, the cotangent of a raw + b
+    (a's and b's gradients are sums of it); dx_i = gx_i + Hpre_i dh +
+    ((draw r) P^T)_i - x_i r^2 <draw, raw> / (n C), `gx_ref` being what
+    the post side's backward sent to the same x (added here, in float32,
+    where autodiff would add two stream-sized arrays in a pass of its
+    own); dP^T += (draw r)^T x, float32, over the grid."""
+    rows, width = x_ref.shape
+    c, (k, kp) = width // n, hc_maps_width(n)
+    raw, a = raw_ref[...], aff_ref[0:1, :]
+    r = raw[:, k:k + 1]
+    lin = raw * a + aff_ref[1:2, :]
+    s = _sigmoid(lin)
+    acc = [jnp.zeros((rows, _LANE), jnp.float32) for _ in range(n)]
+    for j in range(c // slab):
+        dh = dh_ref[:, j * slab:(j + 1) * slab].astype(jnp.float32)
+        for i in range(n):
+            at = slice(i * c + j * slab, i * c + (j + 1) * slab)
+            acc[i] = acc[i] + _fold_lanes(
+                dh * x_ref[:, at].astype(jnp.float32))
+    dm = dm_ref[...] + _place({i: _lane_sum(acc[i]) for i in range(n)},
+                              rows, kp)
+    lane = lax.broadcasted_iota(jnp.int32, raw.shape, 1)
+    dsig = jnp.where(lane < n, dm, jnp.where(lane < 2 * n, 2.0 * dm, 0.0)) \
+        * s * (1.0 - s)
+    t_ref[...] = lin.T
+    z = _res_slabs(t_ref, n)
+    its = _sinkhorn_slabs([jnp.exp(jnp.clip(v, clamp[0], clamp[1]))
+                           for v in z], iters, eps)
+    t_ref[...] = dm.T
+    g = _sinkhorn_slabs_transpose(its, _res_slabs(t_ref, n), iters, eps)
+    for j in range(n):
+        inside = (z[j] >= clamp[0]) & (z[j] <= clamp[1])
+        t_ref[(2 + j) * n:(3 + j) * n, :] = jnp.where(
+            inside, g[j] * its[0][j], 0.0)
+    dlin = jnp.where(lane < 2 * n, dsig,
+                     jnp.where(lane < k, t_ref[...].T, 0.0))
+    dlin_ref[...] = dlin
+    draw = dlin * a
+    cx = r * r * _lane_sum(draw * raw) / width
+    w = (draw * r).astype(x_ref.dtype)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dpt_ref[...] = jnp.zeros(dpt_ref.shape, dpt_ref.dtype)
+
+    dpt_ref[...] += _hc_dot(w, x_ref[...], ((0,), (0,)))
+    for i in range(n):
+        for j in range(c // slab):
+            at = slice(i * c + j * slab, i * c + (j + 1) * slab)
+            dx = gx_ref[:, at].astype(jnp.float32) \
+                + s[:, i:i + 1] * dh_ref[:, j * slab:(j + 1) * slab].astype(
+                    jnp.float32) \
+                + _hc_dot(w, pt_ref[:, at], ((1,), (0,))) \
+                - cx * x_ref[:, at].astype(jnp.float32)
+            dx_ref[:, at] = dx.astype(dx_ref.dtype)
+
+
+def _hc_post_fwd_kernel(x_ref, y_ref, m_ref, out_ref, *, n: int, slab: int):
+    """out_j = sum_i Hres[j, i] x_i + Hpost_j y."""
+    c, m = y_ref.shape[1], m_ref[...]
+    for l in range(c // slab):
+        y = y_ref[:, l * slab:(l + 1) * slab].astype(jnp.float32)
+        xs = [x_ref[:, i * c + l * slab:i * c + (l + 1) * slab].astype(
+            jnp.float32) for i in range(n)]
+        for j in range(n):
+            acc = m[:, n + j:n + j + 1] * y
+            for i in range(n):
+                col = 2 * n + j * n + i
+                acc = acc + m[:, col:col + 1] * xs[i]
+            out_ref[:, j * c + l * slab:j * c + (l + 1) * slab] = \
+                acc.astype(out_ref.dtype)
+
+
+def _hc_post_bwd_kernel(g_ref, x_ref, y_ref, m_ref, dx_ref, dy_ref, dm_ref,
+                        *, n: int, slab: int):
+    """One read of dout, x and y: dx_i = sum_j Hres[j, i] dout_j, dy =
+    sum_j Hpost_j dout_j, and per token dHpost_j = <dout_j, y>, dHres[j,
+    i] = <dout_j, x_i> in `m_ref`'s columns."""
+    rows, c, m = y_ref.shape[0], y_ref.shape[1], m_ref[...]
+    acc = {col: jnp.zeros((rows, _LANE), jnp.float32)
+           for col in range(n, 2 * n + n * n)}
+    for l in range(c // slab):
+        at = lambda i: slice(i * c + l * slab,  # noqa: E731
+                             i * c + (l + 1) * slab)
+        gs = [g_ref[:, at(j)].astype(jnp.float32) for j in range(n)]
+        y = y_ref[:, at(0)].astype(jnp.float32)
+        dy = m[:, n:n + 1] * gs[0]
+        for j in range(1, n):
+            dy = dy + m[:, n + j:n + j + 1] * gs[j]
+        dy_ref[:, at(0)] = dy.astype(dy_ref.dtype)
+        for j in range(n):
+            acc[n + j] = acc[n + j] + _fold_lanes(gs[j] * y)
+        for i in range(n):
+            xi = x_ref[:, at(i)].astype(jnp.float32)
+            dx = m[:, 2 * n + i:2 * n + i + 1] * gs[0]
+            for j in range(1, n):
+                col = 2 * n + j * n + i
+                dx = dx + m[:, col:col + 1] * gs[j]
+            dx_ref[:, at(i)] = dx.astype(dx_ref.dtype)
+            for j in range(n):
+                col = 2 * n + j * n + i
+                acc[col] = acc[col] + _fold_lanes(gs[j] * xi)
+    dm_ref[...] = _place({col: _lane_sum(a) for col, a in acc.items()},
+                         rows, m.shape[1])
+
+
+def _hc_call(kernel, args, moves, outs, out_moves, tile: int,
+             interpret: bool, scratch=(), **scalars):
+    """One of the four over the token tiles. `moves` / `out_moves` say
+    whether an operand moves with the tile (True) or stays (False: P^T,
+    the affine pair, the dP^T accumulator). No result takes an input's
+    buffer: asked for (dx in the place of the cotangent it is made
+    from), XLA copied the input first and the step's temporaries grew by
+    one stream-sized array (compiled for a described v5e, PR 33)."""
+    tokens = args[0].shape[0]
+
+    def spec(shape, moving):
+        return pl.BlockSpec((tile if moving else shape[0], shape[1]),
+                            (lambda t: (t, 0)) if moving
+                            else (lambda t: (0, 0)),
+                            memory_space=pltpu.VMEM)
+
+    vma = jax.typeof(args[0]).vma       # as the LRN kernels: under shard_map
+    return pl.pallas_call(
+        functools.partial(kernel, **scalars),
+        out_shape=[jax.ShapeDtypeStruct(s, d, vma=vma) for s, d in outs],
+        grid=(tokens // tile,),
+        in_specs=[spec(a.shape, mv) for a, mv in zip(args, moves)],
+        out_specs=[spec(s, mv) for (s, _), mv in zip(outs, out_moves)],
+        scratch_shapes=list(scratch),
+        # the dP^T block stays over the whole grid and is added to
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_HC_VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_NAMES[kernel.__name__],
+    )(*args)
+
+
+def _hc_geometry(x, n: int) -> Tuple[int, int]:
+    """(token tile, lane slab) of streams the kernels take; streams they
+    have no view of are the caller's fault."""
+    tokens, width = x.shape
+    c = width // n
+    tile = hc_view(tokens, c, n)
+    if not tile:
+        raise ValueError(
+            f"the hyper-connection kernels take no {x.dtype} streams "
+            f"{tuple(x.shape)} of {n} (pallas_kernels.hc_view): call "
+            "ops.lm.hc_pre_pallas / hc_post_pallas, which trace the XLA "
+            "form for such a shape")
+    return tile, _largest_divisor(c, _LANE, _HC_LANE_SLAB)
+
+
+def _hc_jit(fn):
+    """One trace and one lowering for every site of a step: the arrays
+    are the arguments, every keyword is static."""
+    return jax.jit(fn, static_argnames=tuple(
+        name for name, p in inspect.signature(fn).parameters.items()
+        if p.kind is p.KEYWORD_ONLY))
+
+
+@_hc_jit
+def hc_pre_forward_pallas(x, pt, aff, *, n: int, iters: int, eps: float,
+                          clamp: Tuple[float, float], norm_eps: float,
+                          interpret: bool = False):
+    """x (T, n*C), pt = [P_pre P_post P_res]^T padded to (kp, n*C) in x's
+    dtype, aff (2, kp) float32 (row 0: the maps' scalars a by column, row
+    1: their biases b) -> (raw (T, kp) float32, the maps m (T, kp)
+    float32, h (T, C))."""
+    tile, slab = _hc_geometry(x, n)
+    tokens, width = x.shape
+    kp = pt.shape[0]
+    return _hc_call(
+        _hc_pre_fwd_kernel, (x, pt, aff), (True, False, False),
+        [((tokens, kp), jnp.float32), ((tokens, kp), jnp.float32),
+         ((tokens, width // n), x.dtype)], (True, True, True), tile,
+        interpret, scratch=[pltpu.VMEM((kp, tile), jnp.float32)], n=n,
+        slab=slab, iters=iters, eps=eps, clamp=clamp, norm_eps=norm_eps)
+
+
+@_hc_jit
+def hc_pre_backward_pallas(x, gx, dh, raw, dm, pt, aff, *, n: int,
+                           iters: int, eps: float,
+                           clamp: Tuple[float, float],
+                           interpret: bool = False):
+    """`gx` (T, n*C): the cotangent x has from the post side, added in;
+    `dm` (T, kp): the maps'. -> (dx (T, n*C), dlin (T, kp) float32, dP^T
+    (kp, n*C) float32)."""
+    tile, slab = _hc_geometry(x, n)
+    return _hc_call(
+        _hc_pre_bwd_kernel, (x, gx, dh, raw, dm, pt, aff),
+        (True, True, True, True, True, False, False),
+        [(x.shape, x.dtype), (raw.shape, jnp.float32),
+         (pt.shape, jnp.float32)], (True, True, False), tile, interpret,
+        scratch=[pltpu.VMEM((pt.shape[0], tile), jnp.float32)], n=n,
+        slab=slab, iters=iters, eps=eps, clamp=clamp)
+
+
+@_hc_jit
+def hc_post_forward_pallas(x, y, m, *, n: int, interpret: bool = False):
+    """x (T, n*C), y (T, C), the maps m (T, kp) float32 -> the streams."""
+    tile, slab = _hc_geometry(x, n)
+    return _hc_call(
+        _hc_post_fwd_kernel, (x, y, m), (True, True, True),
+        [(x.shape, x.dtype)], (True,), tile, interpret, n=n, slab=slab)[0]
+
+
+@_hc_jit
+def hc_post_backward_pallas(g, x, y, m, *, n: int, interpret: bool = False):
+    """-> (dx (T, n*C), dy (T, C), dm (T, kp) float32)."""
+    tile, slab = _hc_geometry(x, n)
+    return _hc_call(
+        _hc_post_bwd_kernel, (g, x, y, m), (True, True, True, True),
+        [(x.shape, x.dtype), (y.shape, y.dtype), (m.shape, jnp.float32)],
+        (True, True, True), tile, interpret, n=n, slab=slab)

@@ -285,6 +285,40 @@ register(Variant("lrn", "pallas_one_pass", _lrn_pallas, pallas=True,
                      "any other shape traces banded_matmul)"))
 
 
+# -- hyper-connection around a sub-layer (ISSUE 34) -------------------------
+#    apply(p, x, f, n, *, iters, eps, clamp, norm_eps) -> (streams, f's
+#    extra); differentiable. x is (T, n*C), `f` maps the read (T, C) to
+#    ((T, C), anything). No autotuner times this op: the platform
+#    (`resolve`) and the shape (`pallas_kernels.hc_view`) decide.
+
+def _hc_xla(p, x, f, n, **kw):
+    from veles_tpu.ops import lm
+    return lm.hyper_connection(lm.hc_pre_xla, lm.hc_post_xla, p, x, f, n,
+                               **kw)
+
+
+def _hc_pallas(p, x, f, n, **kw):
+    from veles_tpu.ops import lm
+    return lm.hyper_connection(lm.hc_pre_pallas, lm.hc_post_pallas, p, x,
+                               f, n, **kw)
+
+
+register_op(
+    "hc", default="pallas_one_pass", fallback="xla",
+    doc="one Sinkhorn-mixed hyper-connection around a sub-layer "
+        "(ops/lm.py): the maps, the read and the write over the streams "
+        "(T, n*C), memory-bound (28 % of the xing4 step as XLA traced it; "
+        "off a TPU and under GSPMD the default resolves to xla)")
+register(Variant("hc", "xla", _hc_xla,
+                 doc="hc_maps / hc_read / hc_write as jax.numpy under "
+                     "autodiff"))
+register(Variant("hc", "pallas_one_pass", _hc_pallas, pallas=True,
+                 doc="two custom_vjp functions over four kernels tiled "
+                     "over tokens, x read once a side and direction, each "
+                     "kernel jitted once for all sites (128 | C and a "
+                     "token tile | T; any other shape traces xla)"))
+
+
 # -- max pooling (fused-step lowering; the knob is the BACKWARD shape) ------
 #    apply(x, ksize, stride, use_abs) -> y; differentiable.
 

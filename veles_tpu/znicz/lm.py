@@ -25,7 +25,10 @@ after every step under the scope `update/balance`.
 Scopes inside a block, for a profile: `hc_pre` (the three maps, Sinkhorn,
 reading the sub-layer's input from the streams), `mla`, `hc_post` (writing
 the streams), `mlp`, or `moe/router`, `moe/dispatch`, `moe/experts`,
-`moe/combine`, `moe/shared`; in the head `head` and `mtp/...`.
+`moe/combine`, `moe/shared`; in the head `head` and `mtp/...`. The two
+`hc_*` scopes are opened by `ops.lm.hyper_connection`, whose lowering
+(`xla` or the kernels of `pallas_one_pass`) the registry resolves at trace
+time from the platform and `BlockSpec.hc_lowering` reports by the shape.
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ from veles_tpu.memory import Array
 from veles_tpu.ops import attention as oa
 from veles_tpu.ops import lm as ol
 from veles_tpu.ops import moe as om
+from veles_tpu.ops import variants
 from veles_tpu.znicz.nn_units import (Forward, GradientDescentVJP,
                                       register_gd)
 
@@ -84,6 +88,22 @@ class BlockSpec:
         self.sinkhorn_iters, self.hc_eps = sinkhorn_iters, hc_eps
         self.hc_clamp = (float(hc_clamp[0]), float(hc_clamp[1]))
         self.norm_eps, self.init_std = norm_eps, init_std
+
+    #: whether a Pallas lowering may be traced (`variants.resolve` reads
+    #: it): the unit that owns the spec hands on the fused step's word
+    #: before every trace
+    allow_pallas = True
+
+    def hc_lowering(self, tokens: int) -> str:
+        """The `hc` lowering a trace of `tokens` tokens takes: the
+        registry's by platform, `xla` where the kernels have no view of
+        the shape."""
+        v = variants.resolve("hc", unit=self)
+        if v.pallas:
+            from veles_tpu.ops import pallas_kernels as pk
+            if not pk.hc_view(tokens, self.c, self.n):
+                return "xla"
+        return v.name
 
     # -- parameters ----------------------------------------------------------
 
@@ -145,16 +165,11 @@ class BlockSpec:
 
     def _hc(self, p: Dict[str, Any], prefix: str, x, f):
         """One hyper-connection around `f`: x (T, n*C) -> (x, f's extra)."""
-        with jax.named_scope("hc_pre"):
-            h_pre, h_post, h_res = ol.hc_maps(
-                {k[len(prefix):]: v for k, v in p.items()
-                 if k.startswith(prefix)}, x, self.n,
-                iters=self.sinkhorn_iters, eps=self.hc_eps,
-                clamp=self.hc_clamp, norm_eps=self.norm_eps)
-            h = ol.hc_read(x, h_pre, self.n)
-        y, extra = f(h)
-        with jax.named_scope("hc_post"):
-            return ol.hc_write(x, y, h_post, h_res, self.n), extra
+        return variants.resolve("hc", unit=self).apply(
+            {k[len(prefix):]: v for k, v in p.items()
+             if k.startswith(prefix)}, x, f, self.n,
+            iters=self.sinkhorn_iters, eps=self.hc_eps,
+            clamp=self.hc_clamp, norm_eps=self.norm_eps)
 
     def _attention(self, p: Dict[str, Any], h, batch: int):
         with jax.named_scope("mla"):
@@ -248,6 +263,16 @@ def _gaussian(unit):
                                else np.zeros(shape, np.float32))
 
 
+def _hc_effective(unit) -> Optional[str]:
+    """What the unit's hyper-connections trace, for `variant_table()`;
+    None for a unit that holds none (a head without its MTP block)."""
+    if unit.spec is None or not unit.input:
+        return None
+    unit.spec.allow_pallas = getattr(unit, "allow_pallas", True)
+    n, s = unit.input.shape[:2]
+    return unit.spec.hc_lowering(n * s)
+
+
 class _LMUnit(Forward):
     """Parameters by name from a shape table; the fused step only."""
 
@@ -315,6 +340,11 @@ class HCBlock(_LMUnit):
 
     #: the fused step wraps this unit's forward in `jax.checkpoint`
     fused_remat = True
+    #: the registry op its two hyper-connections resolve at trace time
+    #: (xla | pallas_one_pass); no `variant_signature`: the autotuner times
+    #: nothing here, the platform and the shape decide
+    variant_op = "hc"
+    variant_effective = _hc_effective
 
     def __init__(self, workflow=None, streams: int = 1, **kwargs: Any
                  ) -> None:
@@ -345,6 +375,7 @@ class HCBlock(_LMUnit):
         return super().initialize(device=device, **kwargs)
 
     def fused_apply(self, params, x, *, key=None, train=True, aux=None):
+        self.spec.allow_pallas = getattr(self, "allow_pallas", True)
         y, out = self.spec.apply(params, x["x"],
                                  None if aux is None else aux["bias"])
         y = {**x, "x": y}
@@ -388,6 +419,8 @@ class LMHead(_LMUnit):
     fused_emits_loss = True
     fused_emits_logits = True       # n_classes is the vocabulary
     fused_remat = False
+    variant_op = "hc"               # the MTP block's two (`HCBlock`)
+    variant_effective = _hc_effective
 
     def __init__(self, workflow=None, vocab: int = 256, streams: int = 1,
                  loss_chunk: int = 1024, mtp: Optional[Dict[str, Any]] = None,
@@ -470,6 +503,7 @@ class LMHead(_LMUnit):
                     axis=-1)
                 h = ol.mm(joined, p["mtp_w_proj"])
                 x2 = jnp.tile(h, (1, self.streams)).reshape(n, s, -1)
+            self.spec.allow_pallas = getattr(self, "allow_pallas", True)
             block = jax.checkpoint(self.spec.apply)
             x2, counted = block(
                 {k[len("mtp_"):]: v for k, v in p.items()
