@@ -1,0 +1,9 @@
+"""Share of the chip's bf16 peak that `veles_dsa_attend_dq` reaches (the queries' gradient: scores, the outputs' cotangent by the values, dS by the keys;
+`keye2_scopes.kernel_roofline`): the operations it executes, masked pairs
+among them, over its device time. Compute bounds it; it cannot pass 100."""
+
+from benchmark import keye2_scopes as K
+
+
+def read(ctx):
+    return K.kernel_roofline(ctx, "veles_dsa_attend_dq")

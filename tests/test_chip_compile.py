@@ -131,6 +131,58 @@ def test_flash_attention_pallas_fwd_bwd_compiles(one_chip, compiled_pallas):
     assert "tpu_custom_call" in _compile(fwd_bwd, q, q, q)
 
 
+def test_dsa_kernels_compile_at_published_widths(one_chip, compiled_pallas):
+    """The main attention of `keye2_ep8.long16k` (ISSUE 35): 32 query heads
+    on 4 key-value heads of 128, one sequence of 16,384 tokens, bfloat16,
+    the selection as int8: the four `veles_dsa_*` kernels, each under its
+    fixed name."""
+    h, hkv, t, d = 32, 4, 16384, 128
+    assert pk.dsa_view(t, d) and not pk.dsa_view(32, 16)
+    q = _sds(one_chip, (h, t, d), jnp.bfloat16)
+    kv = _sds(one_chip, (hkv, t, d), jnp.bfloat16)
+    mask = _sds(one_chip, (t, t), jnp.int8)
+    row = _sds(one_chip, (h, t, 1), jnp.float32)
+    scale = d ** -0.5
+    for fn, args, names in (
+            (lambda *a: pk.dsa_attend_forward_pallas(*a, scale=scale),
+             (q, kv, kv, mask), ("veles_dsa_attend_fwd", "f32[32,1,16384]")),
+            (lambda *a: pk.dsa_pmean_pallas(*a, scale=scale),
+             (q, kv, row, mask), ("veles_dsa_pmean",)),
+            (lambda *a: pk.dsa_attend_backward_pallas(*a, scale=scale),
+             (q, kv, kv, q, row, row, mask),
+             ("veles_dsa_attend_dq", "veles_dsa_attend_dkv"))):
+        txt = _compile(fn, *args)
+        assert "tpu_custom_call" in txt
+        for name in names:
+            assert name in txt, name
+
+
+def test_grouped_product_kernels_compile_at_published_widths(
+        one_chip, compiled_pallas):
+    """The held experts' products of `keye2_ep8.long16k` (ISSUE 35): 16
+    matrices of 2048 x 768 and of 768 x 2048 against the sorted buffer's
+    49,152 rows (and the 131,072 of the whole one), bfloat16: `veles_gmm`
+    either way round and `veles_tgmm`, each under its fixed name; the
+    widths of `xing4_ep8`'s experts are within their view too."""
+    from veles_tpu.ops import moe as om
+    assert pk.gmm_view(6144, 3584, 1024, 2) == 512
+    for rows, a, b in ((49152, 2048, 768), (49152, 768, 2048),
+                       (131072, 2048, 768)):
+        tile = pk.gmm_view(rows, a, b, 2)
+        assert tile == 512
+
+        def fwd_bwd(x, w, sizes, dy):
+            dot = om._grouped_product(sizes, rows, x, w, True, False)
+            y, vjp = jax.vjp(dot, x, w)
+            return y, vjp(dy)
+        txt = _compile(fwd_bwd, _sds(one_chip, (rows, a), jnp.bfloat16),
+                       _sds(one_chip, (16, a, b), jnp.bfloat16),
+                       _sds(one_chip, (16,), jnp.int32),
+                       _sds(one_chip, (rows, b), jnp.bfloat16))
+        assert "tpu_custom_call" in txt and "ragged-dot" not in txt
+        assert txt.count("veles_gmm") >= 2 and "veles_tgmm" in txt
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_hyper_connection_kernels_compile_at_published_widths(
         one_chip, dtype, compiled_pallas):
@@ -540,6 +592,112 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip,
           mem.temp_size_in_bytes, "B")
     assert sum(int(np.prod(a.shape)) for layer in one["args"][0]["params"]
                for a in layer.values()) == cfg["n_params"]
+    # parameters and velocity, float32: 8 B a parameter of arguments
+    assert mem.argument_size_in_bytes > 8 * cfg["n_params"]
+    # what a v5e's allocator offers: `bytes_limit` of its memory
+    # statistics (chip runs of PR 32)
+    assert total < 16909336064, total
+
+
+def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
+                                                         compiled_pallas):
+    """`benchmark/configs/keye2_ep8.json` through the sample's layer table,
+    `StandardWorkflow` and `FusedTrainStep`: ONE sequence of 16,384
+    tokens, bfloat16, one `jax.checkpoint` a block that saves the indexed
+    attention's thresholds, logsumexps and outputs; the main attention as
+    the four `veles_dsa_*` kernels (`dsa: pallas_flash`), the held experts'
+    products as `veles_gmm` / `veles_tgmm` (`grouped: pallas`). ONE compile:
+    memory is known before the first chip call (ISSUE 35). The units hold
+    zeros (`init_std` 0: no draw), nothing is put on a device."""
+    import json
+
+    from veles_tpu.loader.fullbatch import FullBatchLoader
+    from veles_tpu.parallel import checkpoint as ck
+    from veles_tpu.samples import keye2
+    from veles_tpu.znicz.standard_workflow import StandardWorkflow
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye2_ep8.json")) as f:
+        cfg = json.load(f)
+    batch, seq = cfg["batch_per_chip"], cfg["seq_len"]
+
+    class ShapeOnlyLoader(FullBatchLoader):
+        def load_data(self):
+            self.bind_arrays(np.zeros((batch, seq), np.int32),
+                             np.zeros((batch, seq), np.int32), 0, 0, batch)
+
+    wf = StandardWorkflow(
+        layers=keye2.layer_table({**cfg, "init_std": 0.0}),
+        loader=ShapeOnlyLoader(minibatch_size=batch, on_device=False),
+        loss="softmax", n_classes=cfg["vocab_size"],
+        decision_config={"max_epochs": 1, "fail_iterations": 1},
+        gd_config=dict(cfg["optimizer"]), name="keye2_compile")
+    wf.initialize(device=None)
+    step = wf.build_fused_step(compute_dtype=cfg["compute_dtype"])
+    assert step.has_aux and step.unit_loss
+    assert step.variant_table()["dsa"] == "pallas_flash"
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        ck._abstract_state(step, "threefry2x32"))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state["key"] = sds(key.shape, key.dtype)
+    assert sum(int(np.prod(a.shape)) for layer in state["params"]
+               for a in layer.values()) == cfg["n_params"] == 659190016
+    import time
+    t0 = time.perf_counter()
+    # at the platform's default precision, as the benchmark runs it (the
+    # grouped-matmul kernel refuses bfloat16 operands under "highest")
+    with jax.default_matmul_precision("bfloat16"):
+        lowered = jax.jit(step.train_callable(), donate_argnums=(0,)).lower(
+            state, sds((batch, seq), jnp.int32),
+            sds((batch, seq), jnp.int32), sds((batch,), jnp.float32))
+    t1 = time.perf_counter()
+    compiled = lowered.compile()
+    txt = compiled.as_text()
+    import re
+    assert "ragged-dot" not in txt and "tpu_custom_call" in txt
+    # (a block of queries is the body of a `lax.map`: `dsa/while/body/
+    # closed_call/select/...`; the readers match whole components)
+    for scope in ("/dsa/qkv/", "/dsa/indexer/", "/select/", "/attend/",
+                  "/index_loss/", "/moe/router/", "/moe/experts/",
+                  "/moe/balance_loss/", "rematted_computation",
+                  "veles_dsa_attend_fwd", "veles_dsa_pmean",
+                  "veles_dsa_attend_dq", "veles_dsa_attend_dkv",
+                  "veles_gmm", "veles_tgmm"):
+        assert scope in txt, scope
+    # every kernel's body once in the module, called a site (one jit each,
+    # ONE checkpoint policy object for the six blocks); the mean-head
+    # probabilities by band of queries, four shapes, forward and backward
+    lowered_text = lowered.as_text()
+    for kernel, bodies in (("veles_dsa_attend_fwd", 1),
+                           ("veles_dsa_pmean", 8),
+                           ("veles_dsa_attend_dq", 1),
+                           ("veles_dsa_attend_dkv", 1),
+                           # either width first, on the fast rows and on
+                           # the whole buffer: forward, again where the
+                           # backward recomputes, and the other way round
+                           ("veles_gmm", 12), ("veles_tgmm", 4)):
+        assert lowered_text.count(
+            f'kernel_name = "{kernel}"') == bodies, kernel
+        paths = set(re.findall(r'op_name="([^"]*%s[^"]*)"' % kernel, txt))
+        assert len({m for p_ in paths for m in re.findall(
+            r"L\d\d\.\w+", p_)}) == 6, (kernel, sorted(paths)[:3])
+    # the recomputed forward neither selects nor attends again: the
+    # thresholds and outputs are saved (`ops.attention.DSA_SAVED`)
+    assert not re.search(r'op_name="[^"]*rematted_computation[^"]*/dsa/while',
+                         txt)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print("keye2 step: lowered in", round(t1 - t0, 1), "s, compiled in",
+          round(time.perf_counter() - t1, 1), "s; generated code",
+          mem.generated_code_size_in_bytes, "B, arguments",
+          mem.argument_size_in_bytes, "B, temporaries",
+          mem.temp_size_in_bytes, "B, in all", total, "B")
     # parameters and velocity, float32: 8 B a parameter of arguments
     assert mem.argument_size_in_bytes > 8 * cfg["n_params"]
     # what a v5e's allocator offers: `bytes_limit` of its memory

@@ -448,11 +448,12 @@ def test_templates_cover_whole_registry_but_dropout():
     through the ledger — it carries a contract but no searched space
     or bench (there is nothing to time outside a serving round). `lrn`
     has two lowerings and no axes: platform (`variants.resolve`) and
-    shape (`pallas_kernels.lrn_view`) choose between them; so has `hc`
-    (ISSUE 34; `pallas_kernels.hc_view`)."""
+    shape (`pallas_kernels.lrn_view`) choose between them; so have `hc`
+    (ISSUE 34; `pallas_kernels.hc_view`) and `dsa` (ISSUE 35;
+    `pallas_kernels.dsa_view`)."""
     covered = set(templates.template_ops())
     assert covered == set(variants.ops()) - {"dropout", "serve_forward",
-                                             "lrn", "hc"}
+                                             "lrn", "hc", "dsa"}
     for op in covered:
         assert op in templates.CONTRACTS and op in templates.BENCHES
     assert "serve_forward" in templates.CONTRACTS

@@ -198,7 +198,18 @@ PALLAS_SITES = {
            np.ones((128, 256), np.float32),
            ["veles_hc_pre_fwd", "veles_hc_post_fwd", "veles_hc_post_bwd",
             "veles_hc_pre_bwd"]),
+    # two groups' products over 64 sorted rows and their gradients: the
+    # product, the same kernel the other way round, the transposed one
+    "gmm": (jax.grad(lambda x: _grouped(x).sum()),
+            np.ones((64, 128), np.float32),
+            ["veles_gmm", "veles_gmm", "veles_tgmm"]),
 }
+
+
+def _grouped(x):
+    w = x[:2, :, None] * np.ones((1, 1, 128), np.float32)
+    items = pk.gmm_items(np.asarray([40, 9], np.int32), 64, 64)
+    return pk.grouped_matmul(x, w, *items, True)
 
 
 def _connection(x, n=2):
@@ -226,9 +237,9 @@ def test_every_pallas_call_has_its_fixed_name(site):
     assert set(want) <= set(pk.KERNEL_NAMES.values())
 
 
-def test_all_twelve_kernels_are_named_and_no_name_twice():
+def test_all_eighteen_kernels_are_named_and_no_name_twice():
     names = list(pk.KERNEL_NAMES.values())
-    assert len(names) == 12 == len(set(names))
+    assert len(names) == 18 == len(set(names))
     with open(pk.__file__) as f:
         src = f.read()
     assert src.count("pl.pallas_call(") == src.count("name=KERNEL_NAMES[")

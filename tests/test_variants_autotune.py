@@ -194,7 +194,7 @@ def test_registry_validation():
     table = variants.selection_table(include_defaults=True)
     assert set(table) == {"lrn", "maxpool", "conv_stem", "dropout",
                           "grad_reduce", "flash_attn", "sgd_update",
-                          "lrn_maxpool", "serve_forward", "hc"}
+                          "lrn_maxpool", "serve_forward", "hc", "dsa"}
     # pallas variants resolve to the op's non-pallas fallback on CPU...
     variants.select("lrn", "pallas_one_pass")
     assert variants.resolve("lrn").name == "banded_matmul"

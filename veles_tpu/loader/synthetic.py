@@ -65,3 +65,31 @@ class SyntheticClassifierLoader(FullBatchLoader):
             self.data_seed)
         self.bind_arrays(data, data.copy() if self.autoencoder else labels,
                          *self.split)
+
+
+class RandomTokenLoader(FullBatchLoader):
+    """Sequences of i.i.d. uniform token ids with, for every position,
+    the next `n_targets` tokens as its targets: (N, S, n_targets), or
+    (N, S) for one. The language-model samples' data (`samples/xing4.py`
+    trains on the next and the next-next token, `samples/keye2.py` on the
+    next)."""
+
+    def __init__(self, workflow=None, vocab: int = 64, seq_len: int = 16,
+                 n_train: int = 32, n_validation: int = 8,
+                 n_targets: int = 2, **kwargs) -> None:
+        super().__init__(workflow, **kwargs)
+        self.vocab, self.seq_len, self.n_targets = vocab, seq_len, n_targets
+        self.n_train, self.n_validation = n_train, n_validation
+
+    def load_data(self) -> None:
+        from veles_tpu import prng
+        n, s = self.n_validation + self.n_train, self.seq_len
+        ids = prng.get().fill_uniform((n, s + 2), 0, self.vocab,
+                                      np.float32).astype(np.int32)
+        ids = np.clip(ids, 0, self.vocab - 1)
+        targets = np.stack([ids[:, 1 + i:s + 1 + i]
+                            for i in range(self.n_targets)], axis=-1)
+        if self.n_targets == 1:
+            targets = targets[..., 0]
+        self.bind_arrays(ids[:, :s], targets, 0, self.n_validation,
+                         self.n_train)

@@ -21,6 +21,7 @@ and degrade to plain attention on a 1-device axis. Tested against
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -236,3 +237,458 @@ def latent_attention(p, h, *, n_heads: int, nope: int, rope: int,
                                preferred_element_type=jnp.float32))
     out = jnp.concatenate(outs, axis=1)
     return mm(out.astype(h.dtype).reshape(n, s, n_heads * v_dim), p["w_o"])
+
+
+# -- grouped-query attention over the keys a learned indexer selects -------------
+#
+# DeepSeek-V3.2-Exp's sparse attention (its "lightning indexer"), around
+# grouped-query attention with per-head QK-norm: a small indexer scores
+# every causal (query, key) pair, I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+# kI[s]); a query attends to the `topk` keys of highest score only. The
+# selection of a query is fully described by ONE number, the score of its
+# `topk`-th key: `kth_largest_key` finds it by counting passes over the
+# scores and no sort, and `mask = causal & (score >= threshold)` is the
+# selection. Two lowerings (`ops/variants.py`, op `dsa`). `xla`, here: the
+# main attention scores every causal pair of a block of queries and masks
+# (nothing is approximated). `pallas_flash`, further down: the same as four
+# kernels. A kernel that visits only tiles holding a selected key is
+# ROADMAP 2.17's.
+#
+# In `xla` nothing (S, S)-sized exists: a sequence is cut into `bands` of queries,
+# band b meeting the keys up to its own end only, and a band is walked a
+# block of `query_block` queries at a time by ONE `lax.map`. A band is a
+# `jax.custom_vjp`: its backward walks the same blocks, recomputes a block
+# from the saved threshold (no second selection) and differentiates it by
+# `jax.vjp`, summing the keys' and values' gradients in float32; so scores
+# are formed twice a step (forward, backward) whatever `jax.checkpoint`
+# wraps around, given a policy that saves the names below.
+
+#: `jax.ad_checkpoint.checkpoint_name`s of what a backward needs and a
+#: surrounding `jax.checkpoint` should save, not recompute: the heads'
+#: outputs and the index loss; of the `xla` lowering the thresholds (one
+#: uint32 a query), of `pallas_flash` the queries' logsumexps and the
+#: selection itself, 8 keys a byte
+DSA_SAVED = ("dsa_threshold", "dsa_out", "dsa_index_loss", "dsa_lse",
+             "dsa_selected")
+
+
+def float_order_key(x):
+    """float32 -> uint32 with the same order; no finite value or infinity
+    maps to 0, which is free to mean "not a candidate"."""
+    b = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+#: bits of a threshold one pass of `kth_largest_key` settles. In the step
+#: of keye2_ep8.long16k on a v5e (blocks of 256 queries x up to 16,384
+#: keys, six layers) 2 bits a pass gave a step of 1,552 ms, 4 bits 1,631,
+#: 1 bit (plain bisection) 1,844 (my chip runs, PR 35, one seed, the rest
+#: of the step the same). The search timed ALONE said otherwise (0.161 ms a
+#: block at 1 bit, 0.222 at 2, 0.618 at 4, 3.69 at 8; why was not looked
+#: into): decide in the step
+SEARCH_DIGIT_BITS = 2
+
+
+def kth_largest_key(keys, k: int, digit_bits: int = SEARCH_DIGIT_BITS):
+    """keys (..., K) uint32 -> (...): the largest u with at least `k` keys
+    >= u, which is the `k`-th largest key; 0 where the row is shorter than
+    `k` (then every key is >= u). A search by digits of `digit_bits` bits
+    from the top: each pass counts the keys at or above the 2^bits - 1
+    candidates that extend the prefix found so far, one read of `keys`."""
+    if 32 % digit_bits:
+        raise ValueError(f"{digit_bits} bits a digit do not divide 32")
+    digits = jnp.arange(1, 1 << digit_bits, dtype=jnp.uint32)
+
+    def one_pass(i, prefix):
+        shift = (32 - digit_bits * (i + 1)).astype(jnp.uint32)
+        cands = prefix[..., None] | (digits << shift)
+        counts = (keys[..., None, :] >= cands[..., :, None]).sum(
+            axis=-1, dtype=jnp.int32)
+        # the counts fall as the digit rises: as many digits pass as the
+        # largest that does
+        d = (counts >= k).sum(axis=-1).astype(jnp.uint32)
+        return prefix | (d << shift)
+
+    return lax.fori_loop(0, 32 // digit_bits, one_pass,
+                         jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def index_scores(qi, w, ki):
+    """I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s]): qi (Tq, Hi, Di),
+    w (Tq, Hi) float32, ki (K, Di) -> (Tq, K) float32."""
+    s = jnp.einsum("qhd,kd->hqk", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * w.T[:, :, None]).sum(axis=0)
+
+
+def select_topk(index, causal, topk: int, thr=None):
+    """(mask (Tq, K): the `topk` causal keys of highest `index` of every
+    query, all of them where there are fewer; the threshold (Tq,) uint32
+    that describes it). Given `thr`, the mask it describes: nothing is
+    searched. Ties at the threshold are all selected."""
+    key = float_order_key(lax.stop_gradient(index))
+    if thr is None:
+        thr = kth_largest_key(jnp.where(causal, key, jnp.uint32(0)), topk)
+    return causal & (key >= thr[:, None]), thr
+
+
+def _dsa_block(static, q, w, qi, pos, k, v, ki, thr=None):
+    """One block of queries of one sequence against the keys [0, K):
+    q (Tq, H, D), w (Tq, Hi), qi (Tq, Hi, Di), pos (Tq,) the queries'
+    positions, k and v (K, Hkv, D), ki (K, Di). Returns (the heads'
+    outputs (Tq, H*D), sum over the queries of KL(p || softmax_S(I)), the
+    thresholds, the selection (Tq, K))."""
+    topk, scale = static
+    tq, h, d = q.shape
+    kvh = k.shape[1]
+    causal = jnp.arange(k.shape[0])[None, :] <= pos[:, None]
+    with jax.named_scope("indexer"):
+        index = index_scores(qi, w, ki)
+    with jax.named_scope("select"):
+        mask, thr = select_topk(index, causal, topk, thr)
+    with jax.named_scope("attend"):
+        scores = jnp.einsum("qhgd,khd->hgqk", q.reshape(tq, kvh, h // kvh, d),
+                            k, preferred_element_type=jnp.float32) * scale
+        probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        out = jnp.einsum("hgqk,khd->qhgd", probs.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        out = out.astype(q.dtype).reshape(tq, h * d)
+    with jax.named_scope("index_loss"):
+        p = lax.stop_gradient(probs.mean(axis=(0, 1)))
+        log_q = index - jax.nn.logsumexp(
+            jnp.where(mask, index, -jnp.inf), axis=-1, keepdims=True)
+        live = mask & (p > 0)
+        kl = jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_q),
+                       0.0).sum()
+    return out, kl, thr, mask
+
+
+def _blocks(a, tq: int):
+    return a.reshape((a.shape[0] // tq, tq) + a.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dsa_band(static, q, w, qi, k, v, ki):
+    """The queries [q0, q0 + Tb) of one sequence against the keys [0, K),
+    `static = (topk, scale, query block, q0)`. Returns (outputs (Tb, H*D),
+    the index loss's sum over the band, thresholds (Tb,) uint32, selected
+    pairs, the selection packed 8 keys a byte (Tb, K / 8))."""
+    topk, scale, tq, q0 = static
+    pos = q0 + jnp.arange(q.shape[0], dtype=jnp.int32)
+
+    def one(xs):
+        out, kl, thr, mask = _dsa_block((topk, scale), *xs, k, v, ki)
+        return (out, kl, thr, mask.sum(dtype=jnp.int32),
+                jnp.packbits(mask, axis=-1))
+
+    out, kl, thr, n_sel, bits = lax.map(
+        one, tuple(_blocks(a, tq) for a in (q, w, qi, pos)))
+    flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731
+    return flat(out), kl.sum(), flat(thr), n_sel.sum(), flat(bits)
+
+
+def _dsa_band_fwd(static, q, w, qi, k, v, ki):
+    from jax.ad_checkpoint import checkpoint_name
+    out, kl, thr, n_sel, bits = _dsa_band(static, q, w, qi, k, v, ki)
+    out = checkpoint_name(out, "dsa_out")
+    kl = checkpoint_name(kl, "dsa_index_loss")
+    thr = checkpoint_name(thr, "dsa_threshold")
+    return (out, kl, thr, n_sel, bits), (q, w, qi, k, v, ki, thr)
+
+
+def _dsa_band_bwd(static, res, cts):
+    topk, scale, tq, q0 = static
+    q, w, qi, k, v, ki, thr = res
+    d_out, d_kl = cts[0], cts[1]
+    pos = q0 + jnp.arange(q.shape[0], dtype=jnp.int32)
+
+    def one(carry, xs):
+        qb, wb, qib, pb, thrb, db = xs
+
+        def block(qb, wb, qib, k, v, ki):
+            return _dsa_block((topk, scale), qb, wb, qib, pb, k, v, ki,
+                              thr=thrb)[:2]
+
+        _, vjp = jax.vjp(block, qb, wb, qib, k, v, ki)
+        dq, dw, dqi, *dkv = vjp((db, d_kl))
+        return tuple(c + g.astype(jnp.float32)
+                     for c, g in zip(carry, dkv)), (dq, dw, dqi)
+
+    # a custom_vjp's backward is traced outside the forward's scope
+    with jax.named_scope("dsa"):
+        zeros = tuple(jnp.zeros(a.shape, jnp.float32) for a in (k, v, ki))
+        dkv, dqs = lax.scan(one, zeros, tuple(
+            _blocks(a, tq) for a in (q, w, qi, pos, thr, d_out)))
+    flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731
+    return (*(flat(a) for a in dqs),
+            *(g.astype(a.dtype) for g, a in zip(dkv, (k, v, ki))))
+
+
+_dsa_band.defvjp(_dsa_band_fwd, _dsa_band_bwd)
+
+
+def dsa_tiling(seq: int, query_block: int, key_bands: int):
+    """(queries a block, bands) a sequence of `seq` tokens is walked in:
+    `query_block` where it divides the sequence, else the sequence whole;
+    the most bands up to `key_bands` that hold whole blocks."""
+    tq = query_block if seq % query_block == 0 else seq
+    bands = max(b for b in range(1, max(key_bands, 1) + 1)
+                if seq % (b * tq) == 0)
+    return tq, bands
+
+
+def _dsa_sequence_xla(static, q, w, qi, k, v, ki):
+    """One sequence through the bands of the `xla` lowering: (outputs
+    (S, H*D), the index loss's sum, selected pairs, the selection packed
+    (S, S/8))."""
+    topk, scale, tq, bands, _interpret = static
+    s = q.shape[0]
+    per = s // bands
+    outs = []
+    for b in range(bands):
+        lo, hi = b * per, (b + 1) * per
+        out, kl, _thr, n_sel, bits = _dsa_band(
+            (topk, scale, tq, lo), q[lo:hi], w[lo:hi], qi[lo:hi], k[:hi],
+            v[:hi], ki[:hi])
+        outs.append((out, kl, n_sel,
+                     jnp.pad(bits, ((0, 0), (0, (s - hi) // 8)))))
+    out, kl, n_sel, bits = zip(*outs)
+    return jnp.concatenate(out), sum(kl), sum(n_sel), jnp.concatenate(bits)
+
+
+# -- the same attention, its scores never in HBM (`pallas_flash`) ----------------
+#
+# The `xla` lowering above writes the 32 heads' float32 scores of a block
+# to HBM and reads them back for every pass of the softmax: 5.4 s a step of
+# keye2_ep8.long16k on a v5e (my chip run, PR 35). Here the main attention
+# is four kernels (`ops/pallas_kernels.py`, `veles_dsa_*`): the flash
+# recurrence over the selection (int8, (S, S): the one thing of that size
+# beside the index scores and the mean-head probabilities the index loss
+# reads, each one head's worth), forward; the mean-head probabilities from
+# the saved logsumexp; dQ; dK and dV. The indexer, the threshold search and
+# the index loss stay plain XLA, a block of queries at a time: XLA's TPU
+# compiler fuses the index scores' relu and head sum into the product.
+
+def _dsa_bands(static, s: int) -> int:
+    """Bands of queries the XLA parts beside the kernels walk a sequence
+    in: as `dsa_tiling` says, each a band a kernel takes (whole tiles of
+    128 keys)."""
+    from veles_tpu.ops import pallas_kernels as pk
+    tq, bands = static[2], static[3]
+    return max(b for b in range(1, bands + 1)
+               if s % (b * tq) == 0 and pk.dsa_view(s // b, 128))
+
+
+def _dsa_index_pass(static, qi, w, ki):
+    """The selection (S, S) int8 of one sequence: a band of queries
+    against the keys up to the band's end, a block of queries at a time."""
+    topk, _scale, tq, _bands, _interpret = static
+    s = qi.shape[0]
+    bands = _dsa_bands(static, s)
+    per = s // bands
+    masks = []
+    for b in range(bands):
+        lo, hi = b * per, (b + 1) * per
+        keys, kib = jnp.arange(hi)[None, :], ki[:hi]
+
+        def one(xs, keys=keys, kib=kib):
+            qib, wb, pb = xs
+            with jax.named_scope("indexer"):
+                index = index_scores(qib, wb, kib)
+            with jax.named_scope("select"):
+                mask, _ = select_topk(index, keys <= pb[:, None], topk)
+            return mask.astype(jnp.int8)
+
+        mask = lax.map(one, tuple(_blocks(a, tq) for a in (
+            qi[lo:hi], w[lo:hi], jnp.arange(lo, hi, dtype=jnp.int32))))
+        masks.append(jnp.pad(mask.reshape(per, hi), ((0, 0), (0, s - hi))))
+    return jnp.concatenate(masks)
+
+
+def _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki, d_kl=None):
+    """The index loss's sum over one sequence or, given its cotangent
+    `d_kl`, its gradient by (qi, w, ki). A band of queries at a time
+    against the keys up to the band's end: the mean-head probabilities of
+    a band (`veles_dsa_pmean`) are the one float32 array of (queries,
+    keys) there is, 256 MB of it at 16,384 tokens in four bands; the
+    index scores are formed again a block at a time beside them."""
+    from veles_tpu.ops import pallas_kernels as pk
+    _topk, scale, tq, _bands, interpret = static
+    s = qi.shape[0]
+    bands = _dsa_bands(static, s)
+    per = s // bands
+    total = jnp.zeros((), jnp.float32)
+    dki = jnp.zeros(ki.shape, jnp.float32)
+    dqis, dws = [], []
+    for b in range(bands):
+        lo, hi = b * per, (b + 1) * per
+        with jax.named_scope("index_loss"):
+            p = pk.dsa_pmean_pallas(
+                qh[:, lo:hi], kh[:, :hi], lse[:, lo:hi, None],
+                mask[lo:hi, :hi], scale=scale, q0=lo, interpret=interpret)
+        kib = ki[:hi]
+
+        def kl_of(qib, wb, mb, pb):
+            keep = mb != 0
+            with jax.named_scope("indexer"):
+                index = index_scores(qib, wb, kib)
+            with jax.named_scope("index_loss"):
+                log_q = index - jax.nn.logsumexp(
+                    jnp.where(keep, index, -jnp.inf), axis=-1, keepdims=True)
+                live = keep & (pb > 0)
+                return jnp.where(live, pb * (jnp.log(jnp.where(
+                    live, pb, 1.0)) - log_q), 0.0).sum()
+
+        def grad_of(carry, xs):
+            qib, wb, mb, pb = xs
+            keep = mb != 0
+            with jax.named_scope("indexer"):
+                index, vjp = jax.vjp(index_scores, qib, wb, kib)
+            with jax.named_scope("index_loss"):
+                # d/dI of sum_s p (log p - I + logsumexp_S(I))
+                soft = jax.nn.softmax(jnp.where(keep, index, -jnp.inf),
+                                      axis=-1)
+                d_index = d_kl * (soft * pb.sum(axis=-1, keepdims=True) - pb)
+            with jax.named_scope("indexer"):
+                dqib, dwb, dkib = vjp(d_index)
+            return carry + dkib.astype(jnp.float32), (dqib, dwb)
+
+        xs = tuple(_blocks(a, tq) for a in (qi[lo:hi], w[lo:hi],
+                                            mask[lo:hi, :hi], p))
+        if d_kl is None:
+            total = total + lax.map(lambda x: kl_of(*x), xs).sum()
+        else:
+            dkib, (dqib, dwb) = lax.scan(
+                grad_of, jnp.zeros(kib.shape, jnp.float32), xs)
+            dki = dki.at[:hi].add(dkib)
+            dqis.append(dqib.reshape((-1,) + dqib.shape[2:]))
+            dws.append(dwb.reshape((-1,) + dwb.shape[2:]))
+    if d_kl is None:
+        return total
+    return jnp.concatenate(dqis), jnp.concatenate(dws), dki.astype(ki.dtype)
+
+
+def _heads_first(a, heads: int):
+    """(S, H*D) or (S, H, D) -> (H, S, D)."""
+    return a.reshape(a.shape[0], heads, -1).transpose(1, 0, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dsa_sequence_pallas(static, q, w, qi, k, v, ki):
+    """One sequence through the kernels; what `_dsa_sequence_xla`
+    returns."""
+    return _dsa_sequence_pallas_fwd(static, q, w, qi, k, v, ki)[0]
+
+
+def _dsa_sequence_pallas_fwd(static, q, w, qi, k, v, ki):
+    from jax.ad_checkpoint import checkpoint_name
+
+    from veles_tpu.ops import pallas_kernels as pk
+    _topk, scale, _tq, _bands, interpret = static
+    s = q.shape[0]
+    mask = _dsa_index_pass(static, qi, w, ki)
+    qh, kh, vh = (a.transpose(1, 0, 2) for a in (q, k, v))
+    with jax.named_scope("attend"):
+        out, lse = pk.dsa_attend_forward_pallas(
+            qh, kh, vh, mask, scale=scale, interpret=interpret)
+        out = out.transpose(1, 0, 2).reshape(s, -1)
+        lse = lse[:, 0, :]      # (H, S): what the backward keeps
+    kl = _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki)
+    keep = mask != 0
+    out = checkpoint_name(out, "dsa_out")
+    kl = checkpoint_name(kl, "dsa_index_loss")
+    lse = checkpoint_name(lse, "dsa_lse")
+    # the selection, 8 keys a byte (32 MB a block of the model at 16,384
+    # tokens): what the step state shows, and what the backward unpacks
+    # in the place of index scores formed a second time
+    bits = checkpoint_name(jnp.packbits(keep, axis=-1), "dsa_selected")
+    return ((out, kl, keep.sum(dtype=jnp.int32), bits),
+            (q, w, qi, k, v, ki, bits, out, lse))
+
+
+def _dsa_sequence_pallas_bwd(static, res, cts):
+    from veles_tpu.ops import pallas_kernels as pk
+    _topk, scale, _tq, _bands, interpret = static
+    d_out, d_kl = cts[0], cts[1]
+    q, w, qi, k, v, ki, bits, out, lse = res
+    h = q.shape[1]
+    # a custom_vjp's backward is traced outside the forward's scope
+    with jax.named_scope("dsa"):
+        with jax.named_scope("select"):
+            mask = jnp.unpackbits(bits, axis=-1).astype(jnp.int8)
+        qh, kh, vh = (a.transpose(1, 0, 2) for a in (q, k, v))
+        dqi, dw, dki = _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki,
+                                       d_kl)
+        with jax.named_scope("attend"):
+            do = _heads_first(d_out, h)
+            di = (do.astype(jnp.float32)
+                  * _heads_first(out, h).astype(jnp.float32)
+                  ).sum(axis=-1, keepdims=True)
+            dq, dk, dv = pk.dsa_attend_backward_pallas(
+                qh, kh, vh, do, lse[..., None], di, mask, scale=scale,
+                interpret=interpret)
+    return (dq.transpose(1, 0, 2), dw, dqi, dk.transpose(1, 0, 2),
+            dv.transpose(1, 0, 2), dki)
+
+
+_dsa_sequence_pallas.defvjp(_dsa_sequence_pallas_fwd,
+                            _dsa_sequence_pallas_bwd)
+
+#: the `dsa` op's lowerings (`ops/variants.py`): one sequence's (outputs,
+#: index loss sum, selected pairs, packed selection)
+DSA_LOWERINGS = {"xla": _dsa_sequence_xla,
+                 "pallas_flash": _dsa_sequence_pallas}
+
+
+def indexed_attention(p, h, *, n_heads: int, kv_heads: int, head_dim: int,
+                      index_heads: int, index_dim: int, topk: int,
+                      rope_theta: float, query_block: int = 256,
+                      key_bands: int = 4, norm_eps: float = 1e-6,
+                      lowering: str = "xla", interpret: bool = False):
+    """Grouped-query attention over the `topk` keys a learned indexer
+    selects for every query (section comments above): h (N, S, C) ->
+    (y (N, S, C), {`index_loss`: mean over the tokens of KL(mean-head
+    attention || softmax of the index scores, both over the selected
+    keys), whose gradient reaches the indexer alone; `pairs_selected`
+    int32; `selected` (N * S, S / 8) uint8, the selection packed as
+    `numpy.packbits` packs}). `p`: `w_q` (C, H*D), `w_k`, `w_v` (C,
+    Hkv*D), `q_norm`, `k_norm` (D,) the RMS scales of a head, `w_o`
+    (H*D, C); the indexer's `idx_w_q` (C, Hi*Di), `idx_w_k` (C, Di),
+    `idx_k_norm`, `idx_k_bias` (Di,) its key's LayerNorm, `idx_w_w`
+    (C, Hi). Query head j reads key-value head j // (H / Hkv); rotary
+    embedding over the whole head (and the whole indexer head), two-halves
+    layout. The indexer reads `stop_gradient(h)`. `lowering` names one of
+    `DSA_LOWERINGS` (the caller resolves it: `pallas_flash` takes
+    sequences `pallas_kernels.dsa_view` admits)."""
+    from veles_tpu.ops.lm import (apply_rope, layer_norm, mm, rms_norm,
+                                  rope_inv_freq, rope_tables)
+    n, s, _ = h.shape
+    with jax.named_scope("qkv"):
+        cos, sin = rope_tables(s, rope_inv_freq(head_dim, rope_theta))
+        q = mm(h, p["w_q"]).reshape(n, s, n_heads, head_dim)
+        k = mm(h, p["w_k"]).reshape(n, s, kv_heads, head_dim)
+        v = mm(h, p["w_v"]).reshape(n, s, kv_heads, head_dim)
+        q = apply_rope(rms_norm(q, p["q_norm"], norm_eps), cos, sin)
+        k = apply_rope(rms_norm(k, p["k_norm"], norm_eps), cos, sin)
+    with jax.named_scope("indexer"):
+        cos, sin = rope_tables(s, rope_inv_freq(index_dim, rope_theta))
+        hs = lax.stop_gradient(h)
+        qi = apply_rope(mm(hs, p["idx_w_q"]).reshape(
+            n, s, index_heads, index_dim), cos, sin)
+        ki = apply_rope(layer_norm(mm(hs, p["idx_w_k"]), p["idx_k_norm"],
+                                   p["idx_k_bias"], norm_eps), cos, sin)
+        w = jnp.matmul(hs, p["idx_w_w"], preferred_element_type=jnp.float32
+                       ) * (index_heads ** -0.5 * index_dim ** -0.5)
+    tq, bands = dsa_tiling(s, query_block, key_bands)
+    one_sequence = functools.partial(
+        DSA_LOWERINGS[lowering],
+        (topk, head_dim ** -0.5, tq, bands, interpret))
+    # a sequence at a time: attention does not cross sequences
+    outs = [one_sequence(q[i], w[i], qi[i], k[i], v[i], ki[i])
+            for i in range(n)]
+    out, kl, n_sel, bits = (jnp.stack(a) for a in zip(*outs))
+    with jax.named_scope("attend"):
+        y = mm(out.reshape(n, s, n_heads * head_dim), p["w_o"])
+    return y, {"index_loss": kl.sum() / (n * s),
+               "pairs_selected": n_sel.sum(),
+               "selected": bits.reshape(n * s, s // 8)}

@@ -45,6 +45,16 @@ def rms_norm(x, scale=None, eps: float = 1e-6):
     return y.astype(x.dtype)
 
 
+def layer_norm(x, scale, bias, eps: float = 1e-6):
+    """(x - mean) / sqrt(var + eps) over the last axis, in float32, times
+    `scale` plus `bias`; back in x's dtype."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    y = xc * lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
 def mm(x, w):
     """x @ w accumulated in float32, rounded to x's dtype."""
     return jnp.matmul(x, w, preferred_element_type=jnp.float32
@@ -78,6 +88,12 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
     ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
                    / max(high - low, 1e-3), 0.0, 1.0)
     return ((1.0 / freq) * (1.0 - ramp) + (1.0 / (factor * freq)) * ramp
+            ).astype(np.float32)
+
+
+def rope_inv_freq(dim: int, theta: float) -> np.ndarray:
+    """The `dim / 2` plain rotary frequencies theta^(-2i/dim)."""
+    return (1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
             ).astype(np.float32)
 
 

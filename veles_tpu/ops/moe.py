@@ -123,6 +123,31 @@ def route_topk(scores, bias, k: int):
     return idx, jnp.take_along_axis(scores, idx, axis=1)
 
 
+def softmax_topk_gates(logits, k: int):
+    """Softmax scoring with renormalised top-k gates (Qwen3-MoE's router
+    under `norm_topk_prob`): r = softmax(logits) over all experts in
+    float32; the `k` highest; gates r_e / (sum of the k). Returns
+    (r (T, E), idx (T, k) int32, gates (T, k))."""
+    r = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    idx, picked = route_topk(r, 0.0, k)
+    return r, idx, picked / picked.sum(axis=-1, keepdims=True)
+
+
+def balance_loss(r, idx, batch: int = 1):
+    """The auxiliary load-balancing loss of one expert layer (Switch
+    Transformer, arXiv:2101.03961, eq. 4, as Qwen3-MoE sums it over the k
+    slots): E sum_e (slots_e / S) mean_t r[t, e] over the S tokens of a
+    sequence, mean over the `batch` sequences (a sequence is a sample). r
+    (T, E) are the router's probabilities, idx (T, k) the selected
+    experts, whose counts carry no gradient. k at perfect balance."""
+    t, e = r.shape
+    r = r.reshape(batch, t // batch, e)
+    share = jax.vmap(lambda i: expert_loads(i, e))(
+        idx.reshape(batch, t // batch, -1)).astype(jnp.float32) \
+        / (t // batch)
+    return e * jnp.sum(share * r.mean(axis=1)) / batch
+
+
 def expert_loads(idx, n_experts: int):
     """Slots each of ALL the experts was given on these tokens: (E,) int32."""
     return (idx[..., None] == jnp.arange(n_experts, dtype=idx.dtype)
@@ -141,13 +166,39 @@ def _take_rows(h, token_of, slot_of, n_live):
     return jnp.where(live, jnp.take(h, token_of, axis=0), 0)
 
 
+#: the most bytes of a buffer one gather of `_sum_rows` reads from: a v5e
+#: gathered the 131,072 (token, slot) rows of 2,048 bfloat16 out of a
+#: 28,672-row buffer (112 MiB) in 1.8 ms and out of a 32,704-row one in 5.9;
+#: out of two halves of the columns of a 49,152-row one in 2.6, out of
+#: four quarters in 3.4, out of eight eighths of a 131,072-row one in 10.5
+#: against 5.5 whole (my chip runs, PR 35): up to _GATHER_PARTS_MAX parts
+_GATHER_OPERAND_MAX = 112 << 20
+_GATHER_PARTS_MAX = 4
+
+
+def _gather_width(rows: int, width: int, itemsize: int) -> int:
+    """Columns of a (rows, width) buffer one gather of `_sum_rows` reads:
+    the widest whole number of 128-lane tiles that divides the width, in
+    at most _GATHER_PARTS_MAX parts, whose part of the buffer is within
+    _GATHER_OPERAND_MAX; the whole width where there is none."""
+    for parts in range(1, _GATHER_PARTS_MAX + 1):
+        if width % (parts * 128) == 0 and \
+                rows * (width // parts) * itemsize <= _GATHER_OPERAND_MAX:
+            return width // parts
+    return width
+
+
 @jax.custom_vjp
 def _sum_rows(y, token_of, slot_of, n_live):
     """Token t's sum of its live sorted rows of y (R, C): (T, C)."""
-    rows = jnp.take(y, jnp.minimum(slot_of, y.shape[0] - 1), axis=0)
+    at = jnp.minimum(slot_of, y.shape[0] - 1)
     live = (slot_of < n_live)[..., None]
-    return jnp.where(live, rows, 0).astype(jnp.float32).sum(axis=1
-                                                             ).astype(y.dtype)
+    step = _gather_width(*y.shape, y.dtype.itemsize)
+    parts = [jnp.where(live, jnp.take(part, at, axis=0), 0
+                       ).astype(jnp.float32).sum(axis=1).astype(y.dtype)
+             for part in ([y] if step == y.shape[1] else [
+                 y[:, c:c + step] for c in range(0, y.shape[1], step)])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 _take_rows.defvjp(
@@ -158,8 +209,26 @@ _sum_rows.defvjp(
     lambda res, g: (_take_rows(g, *res), None, None, None))
 
 
+def _grouped_product(sizes, rows: int, like, w, kernels: bool,
+                     interpret: bool):
+    """f(x, w) -> x's rows of group g times w[g] over a sorted buffer of
+    `rows` rows whose groups hold `sizes`: `lax.ragged_dot` (a grouped
+    product of XLA's own on a TPU, (512, 512, 256) tiles), or with
+    `kernels` the `veles_gmm` / `veles_tgmm` pair where it has a view of
+    the shape (`pallas_kernels.gmm_view`): one work list for the three
+    products and their backward. Neither writes the rows past the last
+    group."""
+    if kernels:
+        from veles_tpu.ops import pallas_kernels as pk
+        tile = pk.gmm_view(rows, w.shape[1], w.shape[2], like.dtype.itemsize)
+        if tile:
+            items = pk.gmm_items(sizes, rows, tile)
+            return lambda x, w: pk.grouped_matmul(x, w, *items, interpret)
+    return lambda x, w: lax.ragged_dot(x, w, sizes)
+
+
 def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
-                       sizes):
+                      sizes, kernels: bool = False, interpret: bool = False):
     """The grouped SwiGLU over the first `rows` rows of the sorted
     buffer: (T, C). Differentiable in h, gates and the weights."""
     t, k = gates.shape
@@ -168,12 +237,12 @@ def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
     slot_of = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
     live = (jnp.arange(rows) < n_live)[:, None]
     xs = _take_rows(h, token_of, slot_of, n_live)
+    dot = _grouped_product(sizes, rows, h, w_gate, kernels, interpret)
     # rows past the last group are no expert's: whatever a grouped product
     # leaves there must not reach the sum or, through it, a gradient
-    a = jnp.where(live, lax.ragged_dot(xs, w_gate, sizes), 0)
-    b = jnp.where(live, lax.ragged_dot(xs, w_up, sizes), 0)
-    y = jnp.where(live, lax.ragged_dot(
-        (jax.nn.silu(a) * b).astype(h.dtype), w_down, sizes), 0)
+    a = jnp.where(live, dot(xs, w_gate), 0)
+    b = jnp.where(live, dot(xs, w_up), 0)
+    y = jnp.where(live, dot((jax.nn.silu(a) * b).astype(h.dtype), w_down), 0)
     # (masked BEFORE the gate multiplies it: the gate's gradient is a sum
     # over y, and 0 x whatever-lies-there is not 0 if it is not finite)
     gate_of = jnp.where(live, jnp.take(gates.reshape(-1), order[:rows]
@@ -182,39 +251,43 @@ def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_swiglu(fast_rows: int, all_rows: int, h, gates, w_gate, w_up,
-                 w_down, order, sizes):
-    """`_held_rows_swiglu` on `fast_rows` rows where the held pairs fit
-    them, on `all_rows` where they do not: one `lax.cond` forward and one
-    backward, each branch the same code at another static size. The
+def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool], h,
+                 gates, w_gate, w_up, w_down, order, sizes):
+    """`_held_rows_swiglu` on `sizes_of[0]` rows where the held pairs fit
+    them, on `sizes_of[1]` where they do not: one `lax.cond` forward and
+    one backward, each branch the same code at another static size
+    (`lowering`: the grouped products' (kernels, interpret)). The
     backward recomputes its branch from the inputs, so that no branch's
     intermediates cross the `cond` (autodiff through it would write the
     untaken branch's residuals as zeros, at the large size)."""
+    fast_rows, all_rows = sizes_of
     run = functools.partial(_held_rows_swiglu, h=h, gates=gates,
                             w_gate=w_gate, w_up=w_up, w_down=w_down,
-                            order=order, sizes=sizes)
+                            order=order, sizes=sizes, kernels=lowering[0],
+                            interpret=lowering[1])
     return lax.cond(sizes.sum() <= fast_rows, lambda: run(fast_rows),
                     lambda: run(all_rows))
 
 
-def _held_swiglu_fwd(fast_rows, all_rows, h, gates, w_gate, w_up, w_down,
+def _held_swiglu_fwd(sizes_of, lowering, h, gates, w_gate, w_up, w_down,
                      order, sizes):
     args = (h, gates, w_gate, w_up, w_down, order, sizes)
-    return _held_swiglu(fast_rows, all_rows, *args), args
+    return _held_swiglu(sizes_of, lowering, *args), args
 
 
-def _held_swiglu_bwd(fast_rows, all_rows, args, dy):
+def _held_swiglu_bwd(sizes_of, lowering, args, dy):
     *diff, order, sizes = args
 
     def grads_at(rows: int):
         def branch():
             _, vjp = jax.vjp(lambda *a: _held_rows_swiglu(
-                rows, *a, order=order, sizes=sizes), *diff)
+                rows, *a, order=order, sizes=sizes, kernels=lowering[0],
+                interpret=lowering[1]), *diff)
             return vjp(dy)
         return branch
 
-    grads = lax.cond(sizes.sum() <= fast_rows, grads_at(fast_rows),
-                     grads_at(all_rows))
+    grads = lax.cond(sizes.sum() <= sizes_of[0], grads_at(sizes_of[0]),
+                     grads_at(sizes_of[1]))
     return (*grads, None, None)
 
 
@@ -223,7 +296,8 @@ _held_swiglu.defvjp(_held_swiglu_fwd, _held_swiglu_bwd)
 
 def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
                         held: Tuple[int, int],
-                        fast_rows: Optional[int] = None):
+                        fast_rows: Optional[int] = None,
+                        kernels: bool = False, interpret: bool = False):
     """The held experts' part of a top-k expert layer, nothing dropped:
     sum over the (token, slot) pairs whose expert is one of
     `held = (first, count)` of gate x SwiGLU_expert(token). h (T, C), idx
@@ -232,7 +306,9 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
     The pairs are sorted by expert, the held ones first, and the three
     products run as grouped products over the `count` groups
     (`lax.ragged_dot`: a grouped-matmul kernel on a TPU, which passes over
-    the row tiles past the last group). Shapes are static, so the sorted
+    the row tiles past the last group; with `kernels` the `veles_gmm` /
+    `veles_tgmm` pair of `pallas_kernels`, a group's whole matrix a grid
+    step, `interpret`ed where a test asks). Shapes are static, so the sorted
     buffer has `fast_rows` rows where the held pairs fit them (what a
     balanced router gives, sized by the caller: everything beside the
     products, the gathers, masks and activations, costs by the buffer's
@@ -254,7 +330,8 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
     total = sizes.sum()
     args = (h, gates, w_gate, w_up, w_down, order, sizes)
     if fast_rows is None or fast_rows >= all_rows:
-        y = _held_rows_swiglu(all_rows, *args)
+        y = _held_rows_swiglu(all_rows, *args, kernels, interpret)
     else:
-        y = _held_swiglu(int(fast_rows), all_rows, *args)
+        y = _held_swiglu((int(fast_rows), all_rows), (kernels, interpret),
+                         *args)
     return y, total - jnp.minimum(total, all_rows)

@@ -8,7 +8,9 @@ ring already handles cross-chip; this kernel is the intra-chip tile
 loop). Since ISSUE 34 also the four one-pass sides of a hyper-connection
 over the residual streams of the sparse-expert language model
 (`veles_hc_*`, what `xing4_ep8.step` runs at 12 sites a step, each
-traced and lowered once).
+traced and lowered once), and since ISSUE 35 attention over selected keys
+(`veles_dsa_*`) and the held experts' grouped products (`veles_gmm`,
+`veles_tgmm`), what `keye2_ep8.long16k` runs.
 
 Every kernel has a lax twin in ops.xla / ops.attention — these are
 drop-in replacements gated by `available()`. Interpret mode is something
@@ -76,6 +78,26 @@ _HC_TOKEN_TILE_MAX = 512
 _HC_LANE_SLAB = 512
 _HC_VMEM_LIMIT = 64 << 20
 _HC_BLOCK_BUDGET = 52 << 20
+#: attention over selected keys (ISSUE 35): queries and keys a grid step of
+#: the four `veles_dsa_*` kernels holds (shrunk to divide the sequence,
+#: `flash_fit_block`): a (512, 1024) float32 score tile is 2 MB, its
+#: probabilities and the int8 selection tile 1.5 MB more, the operands'
+#: double buffers under 2 MB; the kernels ask for _DSA_VMEM_LIMIT
+_DSA_BLK_Q = 512
+_DSA_BLK_K = 1024
+_DSA_VMEM_LIMIT = 48 << 20
+#: grouped products over a sorted buffer (ISSUE 35): rows of the buffer a
+#: grid step of `veles_gmm` / `veles_tgmm` holds, against one group's WHOLE
+#: weight matrix (XLA's own grouped product walks (512, 512, 256) tiles,
+#: four accumulating steps and three passes over the rows a product of
+#: 2048 x 768: 0.81 ms for 16,384 live rows of 24,576 on a v5e, 32 % of
+#: the peak, where `veles_gmm` took 0.49 at 512 rows, 0.43 at 256 and 0.60
+#: at 1,024, and another 512-row tile 7 us, the peak's time; my chip runs,
+#: PR 35); the blocks' bytes a shape may ask for, double buffers and the
+#: float32 result counted, and the scoped VMEM the kernels ask for
+_GMM_ROW_TILE = 512
+_GMM_BLOCK_BUDGET = 48 << 20
+_GMM_VMEM_LIMIT = 64 << 20
 #: fused-SGD row blocking seed (the pre-search hand-written value)
 _SGD_ROW_TILE = 8
 #: fused LRN+maxpool sample tile seed: SAMPLES per VMEM block (each
@@ -126,6 +148,12 @@ KERNEL_NAMES = {
     "_hc_pre_bwd_kernel": "veles_hc_pre_bwd",
     "_hc_post_fwd_kernel": "veles_hc_post_fwd",
     "_hc_post_bwd_kernel": "veles_hc_post_bwd",
+    "_dsa_fwd_kernel": "veles_dsa_attend_fwd",
+    "_dsa_pmean_kernel": "veles_dsa_pmean",
+    "_dsa_dq_kernel": "veles_dsa_attend_dq",
+    "_dsa_dkv_kernel": "veles_dsa_attend_dkv",
+    "_gmm_kernel": "veles_gmm",
+    "_tgmm_kernel": "veles_tgmm",
 }
 
 
@@ -1423,3 +1451,490 @@ def hc_post_backward_pallas(g, x, y, m, *, n: int, interpret: bool = False):
         _hc_post_bwd_kernel, (g, x, y, m), (True, True, True, True),
         [(x.shape, x.dtype), (y.shape, y.dtype), (m.shape, jnp.float32)],
         (True, True, True), tile, interpret, n=n, slab=slab)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention over selected keys (ISSUE 35): the main attention
+# of `ops.attention.indexed_attention` as four kernels. A query attends to
+# the keys its row of `mask` (T, T) int8 names (the indexer's selection,
+# causal already); query head h reads key-value head h // (H / Hkv). The
+# flash recurrence of the kernels above with bfloat16 operands, float32
+# scores and accumulators, and the selection in the place of the causal
+# rule: a tile above the diagonal holds no selected key and is passed over,
+# its operands not fetched (the index maps stay on the last tile that is
+# needed). Nothing (T, T)-sized exists in float32 but the mean-head
+# probabilities the index loss reads (`veles_dsa_pmean`, one head's worth).
+# Each entry point is ONE module-level `jax.jit` (PR 33's lesson).
+# ---------------------------------------------------------------------------
+
+_NEG = -1e30
+
+
+def dsa_view(seq: int, head_dim: int) -> bool:
+    """Whether the kernels take a sequence of `seq` tokens and heads of
+    `head_dim`: whole (8, 128) tiles of keys and of a head."""
+    return seq % _LANE == 0 and head_dim % _LANE == 0
+
+
+def _dsa_scores(q, kb, keep, scale):
+    return jnp.where(keep, _dsa_dot(q, kb, 1, 1) * scale, _NEG)
+
+
+def _dsa_dot(a, b, ca: int, cb: int):
+    """a . b over axis `ca` of a and `cb` of b, accumulated in float32; at
+    the MXU's own precision whatever the ambient default says (under
+    "highest" Mosaic refuses bfloat16 operands)."""
+    return lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                           precision=lax.Precision.DEFAULT,
+                           preferred_element_type=jnp.float32)
+
+
+def _dsa_fwd_kernel(q_ref, k_ref, v_ref, mk_ref, o_ref, lse_ref, m_scr,
+                    l_scr, acc_scr, *, scale: float):
+    """Grid (H, q tiles, k tiles), keys innermost: the online softmax over
+    the selected keys of a tile of queries."""
+    qi, ki, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _():
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ki * blk_k <= qi * blk_q + blk_q - 1)
+    def _():
+        keep = mk_ref[...].astype(jnp.int32) != 0
+        s = _dsa_scores(q_ref[0], k_ref[0], keep, scale)
+        m = m_scr[:]
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        # (a row none of whose keys so far is selected has m_new = _NEG,
+        # where exp(s - m_new) would be 1)
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        a = jnp.exp(m - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * a + p.sum(axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * a + _dsa_dot(
+            p.astype(v_ref.dtype), v_ref[0], 1, 0)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
+        # the queries' logsumexps leave as a ROW, the queries in the lanes:
+        # as the column they are computed in, (H, T, 1) pads to 128 lanes
+        # in HBM, 256 MB a layer of what the backward keeps
+        col = m_scr[:] + jnp.log(l_scr[:])
+        eye = lax.broadcasted_iota(jnp.int32, (blk_q, blk_q), 0) \
+            == lax.broadcasted_iota(jnp.int32, (blk_q, blk_q), 1)
+        lse_ref[0] = jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _dsa_pmean_kernel(q_ref, k_ref, lse_ref, mk_ref, p_ref, *, scale: float,
+                      inv_heads: float, q0: int):
+    """Grid (q tiles, k tiles, H), heads innermost: the mean over the
+    heads of the attention probabilities of one tile, added up in the
+    output block, which stays. The first query is the sequence's `q0`-th."""
+    qi, ki, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(h == 0)
+    def _():
+        p_ref[...] = jnp.zeros_like(p_ref)
+
+    @pl.when(ki * blk_k <= q0 + qi * blk_q + blk_q - 1)
+    def _():
+        keep = mk_ref[...].astype(jnp.int32) != 0
+        s = _dsa_scores(q_ref[0], k_ref[0], keep, scale)
+        p_ref[...] += jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0) \
+            * inv_heads
+
+
+def _dsa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, mk_ref,
+                   dq_ref, dq_scr, *, scale: float):
+    """Grid (H, q tiles, k tiles), keys innermost: P from the saved
+    logsumexp, dS = P (dO V^T - D), dQ += dS K scale."""
+    qi, ki, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(ki * blk_k <= qi * blk_q + blk_q - 1)
+    def _():
+        keep = mk_ref[...].astype(jnp.int32) != 0
+        kb = k_ref[0]
+        s = _dsa_scores(q_ref[0], kb, keep, scale)
+        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
+        dp = _dsa_dot(do_ref[0], v_ref[0], 1, 1)
+        ds = p * (dp - di_ref[0]) * scale
+        dq_scr[:] = dq_scr[:] + _dsa_dot(ds.astype(kb.dtype), kb, 1, 0)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _dsa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, mk_ref,
+                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float):
+    """Grid (Hkv, k tiles, heads of the group, q tiles): a tile of keys and
+    values stays while the queries of every head that reads it stream
+    past. dV += P^T dO, dK += dS^T Q scale."""
+    ki, g, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ng, nq = pl.num_programs(2), pl.num_programs(3)
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
+
+    @pl.when((g == 0) & (qi == 0))
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(qi * blk_q + blk_q - 1 >= ki * blk_k)
+    def _():
+        keep = mk_ref[...].astype(jnp.int32) != 0
+        q, do = q_ref[0], do_ref[0]
+        s = _dsa_scores(q, k_ref[0], keep, scale)
+        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
+        dv_scr[:] = dv_scr[:] + _dsa_dot(p.astype(do.dtype), do, 0, 0)
+        dp = _dsa_dot(do, v_ref[0], 1, 1)
+        ds = p * (dp - di_ref[0]) * scale
+        dk_scr[:] = dk_scr[:] + _dsa_dot(ds.astype(q.dtype), q, 0, 0)
+
+    @pl.when((g == ng - 1) & (qi == nq - 1))
+    def _():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _dsa_blocks(seq: int) -> Tuple[int, int]:
+    return flash_fit_block(seq, _DSA_BLK_Q), flash_fit_block(seq, _DSA_BLK_K)
+
+
+def _dsa_params(semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_DSA_VMEM_LIMIT)
+
+
+def _vmem(block, index_map):
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+
+@_hc_jit
+def dsa_attend_forward_pallas(q, k, v, mask, *, scale: float,
+                              interpret: bool = False):
+    """q (H, T, D), k and v (Hkv, T, D), mask (T, T) int8 -> (the heads'
+    outputs (H, T, D) in q's dtype, the logsumexp of every query's selected
+    scores (H, 1, T) float32; the other kernels take it as (H, T, 1))."""
+    h, t, d = q.shape
+    group = h // k.shape[0]
+    bq, bk = _dsa_blocks(t)
+
+    def last(i):                # the last tile of keys a tile of queries needs
+        return (i * bq + bq - 1) // bk
+
+    kv = _vmem((1, bk, d), lambda b, i, j: (b // group,
+                                            jnp.minimum(j, last(i)), 0))
+    return pl.pallas_call(
+        functools.partial(_dsa_fwd_kernel, scale=scale),
+        out_shape=(jax.ShapeDtypeStruct((h, t, d), q.dtype),
+                   jax.ShapeDtypeStruct((h, 1, t), jnp.float32)),
+        grid=(h, t // bq, t // bk),
+        in_specs=[_vmem((1, bq, d), lambda b, i, j: (b, i, 0)), kv, kv,
+                  _vmem((bq, bk), lambda b, i, j: (
+                      i, jnp.minimum(j, last(i))))],
+        out_specs=(_vmem((1, bq, d), lambda b, i, j: (b, i, 0)),
+                   _vmem((1, 1, bq), lambda b, i, j: (b, 0, i))),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_dsa_params(("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name=KERNEL_NAMES["_dsa_fwd_kernel"],
+    )(q, k, v, mask)
+
+
+@_hc_jit
+def dsa_pmean_pallas(q, k, lse, mask, *, scale: float, q0: int = 0,
+                     interpret: bool = False):
+    """The queries q (H, Tq, D), the sequence's from its `q0`-th on, with
+    their logsumexps (H, Tq, 1), against the keys k (Hkv, Tk, D), mask
+    (Tq, Tk) -> (Tq, Tk) float32: the mean over the H heads of every
+    query's attention probabilities, 0 where a key is not selected."""
+    h, t, d = q.shape
+    tk = k.shape[1]
+    group = h // k.shape[0]
+    bq, bk = flash_fit_block(t, _DSA_BLK_Q), flash_fit_block(tk, _DSA_BLK_K)
+    return pl.pallas_call(
+        functools.partial(_dsa_pmean_kernel, scale=scale,
+                          inv_heads=1.0 / h, q0=q0),
+        out_shape=jax.ShapeDtypeStruct((t, tk), jnp.float32),
+        grid=(t // bq, tk // bk, h),
+        in_specs=[_vmem((1, bq, d), lambda i, j, b: (b, i, 0)),
+                  _vmem((1, bk, d), lambda i, j, b: (b // group, j, 0)),
+                  _vmem((1, bq, 1), lambda i, j, b: (b, i, 0)),
+                  _vmem((bq, bk), lambda i, j, b: (i, j))],
+        out_specs=_vmem((bq, bk), lambda i, j, b: (i, j)),
+        compiler_params=_dsa_params(("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name=KERNEL_NAMES["_dsa_pmean_kernel"],
+    )(q, k, lse, mask)
+
+
+@_hc_jit
+def dsa_attend_backward_pallas(q, k, v, do, lse, di, mask, *, scale: float,
+                               interpret: bool = False):
+    """`do` (H, T, D) the outputs' cotangent, `di` (H, T, 1) float32 its
+    row sums against the outputs -> (dq (H, T, D), dk, dv (Hkv, T, D)), in
+    the operands' dtypes."""
+    h, t, d = q.shape
+    hkv = k.shape[0]
+    group = h // hkv
+    bq, bk = _dsa_blocks(t)
+
+    def last(i):
+        return (i * bq + bq - 1) // bk
+
+    row = lambda b, i, j: (b, i, 0)  # noqa: E731
+    kv = _vmem((1, bk, d), lambda b, i, j: (b // group,
+                                            jnp.minimum(j, last(i)), 0))
+    dq = pl.pallas_call(
+        functools.partial(_dsa_dq_kernel, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((h, t, d), q.dtype),
+        grid=(h, t // bq, t // bk),
+        in_specs=[_vmem((1, bq, d), row), kv, kv, _vmem((1, bq, d), row),
+                  _vmem((1, bq, 1), row), _vmem((1, bq, 1), row),
+                  _vmem((bq, bk), lambda b, i, j: (
+                      i, jnp.minimum(j, last(i))))],
+        out_specs=_vmem((1, bq, d), row),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_dsa_params(("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name=KERNEL_NAMES["_dsa_dq_kernel"],
+    )(q, k, v, do, lse, di, mask)
+
+    def first(j):               # the first tile of queries a tile of keys meets
+        return (j * bk) // bq
+
+    qrow = lambda b, j, g, i: (b * group + g,  # noqa: E731
+                               jnp.maximum(i, first(j)), 0)
+    krow = lambda b, j, g, i: (b, j, 0)  # noqa: E731
+    dk, dv = pl.pallas_call(
+        functools.partial(_dsa_dkv_kernel, scale=scale),
+        out_shape=(jax.ShapeDtypeStruct((hkv, t, d), k.dtype),
+                   jax.ShapeDtypeStruct((hkv, t, d), v.dtype)),
+        grid=(hkv, t // bk, group, t // bq),
+        in_specs=[_vmem((1, bq, d), qrow), _vmem((1, bk, d), krow),
+                  _vmem((1, bk, d), krow), _vmem((1, bq, d), qrow),
+                  _vmem((1, bq, 1), qrow), _vmem((1, bq, 1), qrow),
+                  _vmem((bq, bk), lambda b, j, g, i: (
+                      jnp.maximum(i, first(j)), j))],
+        out_specs=(_vmem((1, bk, d), krow), _vmem((1, bk, d), krow)),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_dsa_params(("parallel", "parallel", "arbitrary",
+                                     "arbitrary")),
+        interpret=interpret, name=KERNEL_NAMES["_dsa_dkv_kernel"],
+    )(q, k, v, do, lse, di, mask)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
+# grouped products over a sorted buffer (ISSUE 35): what `ops.moe`'s held
+# experts multiply by. Rows [lo_g, hi_g) of x (R, A) are group g's, in
+# order; w is (G, A, B). `veles_gmm`: y[rows of g] = x[rows of g] @ w[g]
+# (or @ w[g]^T, the product the backward needs for x); `veles_tgmm`:
+# dw[g] = x[rows of g]^T @ dy[rows of g]. The work is a LIST of (group,
+# row tile) items, a tile that two groups share standing once for each
+# (`gmm_items`; the scheme of Gale et al., MegaBlocks, arXiv:2211.15841,
+# as jax's `megablox` walks it), read from SMEM by the index maps: a grid
+# step holds a tile of rows and the group's whole matrix, so a matrix is
+# fetched once a group and no step accumulates over the inner dimension.
+# The grid is as long as the list can get (row tiles + groups - 1); past
+# the list's end a step computes nothing and its blocks stay where they
+# are, so a buffer's empty tail costs a third of a microsecond a tile.
+# Rows of y that are no group's are NOT written: the caller masks them.
+# Each entry point is ONE module-level `jax.jit`.
+# ---------------------------------------------------------------------------
+
+def gmm_view(rows: int, a: int, b: int, itemsize: int) -> Optional[int]:
+    """The row tile the grouped-product kernels take a buffer of `rows`
+    rows against (G, a, b) matrices with, or None where they have no view
+    of the shape: whole 128-lane tiles of both widths, a row tile of whole
+    sublane tiles that divides the rows, and the widest kernel's blocks
+    (the transposed product: a float32 accumulator and a double-buffered
+    result of a whole matrix) within _GMM_BLOCK_BUDGET."""
+    if a % _LANE or b % _LANE:
+        return None
+    tile = _largest_divisor(rows, _sublanes(itemsize), _GMM_ROW_TILE)
+    if not tile:
+        return None
+    blocks = 4 * a * b + 2 * itemsize * (a * b + tile * (a + b))
+    return tile if blocks <= _GMM_BLOCK_BUDGET else None
+
+
+def gmm_items(sizes, rows: int, tile: int):
+    """The work list of a buffer of `rows` rows whose groups hold `sizes`
+    (G,) int32 rows in order: (group of item i, row tile of item i, first
+    row of every group, the row past its last, the list's length (1,)),
+    the first two (rows // tile + G - 1,) long and past the list's end
+    standing on its last item. A group gets the tiles its rows touch; an
+    EMPTY group one tile of which it keeps no row, so that the transposed
+    product writes its zeros."""
+    g, tiles = sizes.shape[0], rows // tile
+    hi = jnp.minimum(jnp.cumsum(sizes), rows).astype(jnp.int32)
+    lo = jnp.concatenate([jnp.zeros((1,), jnp.int32), hi[:-1]])
+    first = jnp.minimum(lo // tile, tiles - 1)
+    last = jnp.minimum(jnp.maximum(hi - 1, lo) // tile, tiles - 1)
+    count = last - first + 1
+    start = jnp.cumsum(count) - count
+    n = count.sum()
+    at = jnp.arange(tiles + g - 1, dtype=jnp.int32)
+    group = jnp.repeat(jnp.arange(g, dtype=jnp.int32), count,
+                       total_repeat_length=at.shape[0])
+    tile_of = first[group] + at - start[group]
+    end = jnp.minimum(at, n - 1)        # the items past the end: the last
+    return (group[end], tile_of[end].astype(jnp.int32), lo, hi,
+            n.astype(jnp.int32)[None])
+
+
+def _gmm_rows_kept(tile_ref, lo_ref, hi_ref, i, g, shape):
+    """Which rows of item i's tile are group g's, as a mask of `shape`."""
+    row = tile_ref[i] * shape[0] + lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (row >= lo_ref[g]) & (row < hi_ref[g])
+
+
+def _gmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, n_ref, x_ref, w_ref,
+                y_ref, *, transposed: bool):
+    """Grid (items,): one tile of rows by its group's whole matrix. The
+    rows of the tile that are another group's keep what the block holds
+    (that group's item wrote it, or will)."""
+    i = pl.program_id(0)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        y = _dsa_dot(x_ref[...], w_ref[0], 1, 1 if transposed else 0)
+        keep = _gmm_rows_kept(tile_ref, lo_ref, hi_ref, i, group_ref[i],
+                              y.shape)
+        y_ref[...] = jnp.where(keep, y, y_ref[...].astype(jnp.float32)
+                               ).astype(y_ref.dtype)
+
+
+def _tgmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, n_ref, x_ref, dy_ref,
+                 dw_ref, acc, *, mask_x: bool):
+    """Grid (items,): a group's items follow one another, their products
+    added up in `acc` and written with the group's last. The rows of
+    another group are zeroed in the narrower operand (`mask_x` says
+    which)."""
+    i, n = pl.program_id(0), n_ref[0]
+
+    @pl.when(i < n)
+    def _():
+        g = group_ref[i]
+
+        @pl.when((i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != g))
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        x, dy = x_ref[...], dy_ref[...]
+        narrow = x if mask_x else dy
+        keep = _gmm_rows_kept(tile_ref, lo_ref, hi_ref, i, g, narrow.shape)
+        narrow = jnp.where(keep, narrow.astype(jnp.float32), 0.0
+                           ).astype(narrow.dtype)
+        x, dy = (narrow, dy) if mask_x else (x, narrow)
+        acc[...] += _dsa_dot(x, dy, 0, 0)
+
+        @pl.when((i == n - 1) | (group_ref[jnp.minimum(
+            i + 1, group_ref.shape[0] - 1)] != g))
+        def _():
+            dw_ref[0] = acc[...].astype(dw_ref.dtype)
+
+
+def _gmm_call(kernel, items, arrays, in_blocks, out_shape, out_block,
+              scratch, interpret: bool):
+    """One grouped-product kernel over the work list `items`; a block is
+    (shape, what of an item names it: a function of (group, tile))."""
+    def spec(block):
+        shape, of = block
+        return pl.BlockSpec(shape, lambda i, group, tile, *_: of(
+            group[i], tile[i]))
+
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(items), grid=(items[0].shape[0],),
+            in_specs=[spec(b) for b in in_blocks],
+            out_specs=spec(out_block), scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GMM_VMEM_LIMIT),
+        interpret=interpret, name=KERNEL_NAMES[kernel.func.__name__],
+    )(*items, *arrays)
+
+
+def _gmm_tile(rows: int, a: int, b: int, dtype) -> int:
+    """The kernels' row tile; a shape they have no view of is the
+    caller's fault."""
+    tile = gmm_view(rows, a, b, jnp.dtype(dtype).itemsize)
+    if not tile:
+        raise ValueError(
+            f"the grouped-product kernels take no {rows} rows against "
+            f"({a}, {b}) matrices of {dtype} (pallas_kernels.gmm_view): "
+            "call lax.ragged_dot for such a shape, as ops.moe does")
+    return tile
+
+
+@_hc_jit
+def gmm_pallas(x, w, group, tile_of, lo, hi, n, *, transposed: bool = False,
+               interpret: bool = False):
+    """x (R, A) and w (G, A, B) -> (R, B) in x's dtype; `transposed`: x
+    (R, B) -> (R, A). The items are `gmm_items`' at the tile `gmm_view`
+    names."""
+    rows, inner = x.shape
+    _, a, b = w.shape
+    out = a if transposed else b
+    tile = _gmm_tile(rows, a, b, x.dtype)
+    return _gmm_call(
+        functools.partial(_gmm_kernel, transposed=transposed),
+        (group, tile_of, lo, hi, n), (x, w),
+        [((tile, inner), lambda g, t: (t, 0)),
+         ((1, a, b), lambda g, t: (g, 0, 0))],
+        jax.ShapeDtypeStruct((rows, out), x.dtype),
+        ((tile, out), lambda g, t: (t, 0)), [], interpret)
+
+
+@_hc_jit
+def tgmm_pallas(x, dy, group, tile_of, lo, hi, n, *, groups: int,
+                interpret: bool = False):
+    """x (R, A) and dy (R, B) -> (groups, A, B) in x's dtype: every
+    group's x^T dy over its rows, zeros for a group without rows."""
+    rows, a = x.shape
+    b = dy.shape[1]
+    tile = _gmm_tile(rows, a, b, x.dtype)
+    return _gmm_call(
+        functools.partial(_tgmm_kernel, mask_x=a < b),
+        (group, tile_of, lo, hi, n), (x, dy),
+        [((tile, a), lambda g, t: (t, 0)), ((tile, b), lambda g, t: (t, 0))],
+        jax.ShapeDtypeStruct((groups, a, b), x.dtype),
+        ((1, a, b), lambda g, t: (g, 0, 0)),
+        [pltpu.VMEM((a, b), jnp.float32)], interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def grouped_matmul(x, w, group, tile_of, lo, hi, n, interpret=False):
+    """`lax.ragged_dot(x, w, sizes)` on the work list `gmm_items(sizes,
+    ...)` gives, but for the rows that are no group's, which are not
+    written; differentiable in x and w."""
+    return gmm_pallas(x, w, group, tile_of, lo, hi, n, interpret=interpret)
+
+
+def _grouped_matmul_fwd(x, w, group, tile_of, lo, hi, n, interpret):
+    items = (group, tile_of, lo, hi, n)
+    return gmm_pallas(x, w, *items, interpret=interpret), (x, w, items)
+
+
+def _grouped_matmul_bwd(interpret, res, dy):
+    x, w, items = res
+    return (gmm_pallas(dy, w, *items, transposed=True, interpret=interpret),
+            tgmm_pallas(x, dy, *items, groups=w.shape[0],
+                        interpret=interpret).astype(w.dtype),
+            None, None, None, None, None)
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

@@ -319,6 +319,42 @@ register(Variant("hc", "pallas_one_pass", _hc_pallas, pallas=True,
                      "token tile | T; any other shape traces xla)"))
 
 
+# -- attention over the keys an indexer selects (ISSUE 35) -------------------
+#    apply(p, h, **kw) -> (y, {index_loss, pairs_selected, selected});
+#    differentiable. h is (N, S, C). No autotuner times this op: the
+#    platform (`resolve`) and the shape (`pallas_kernels.dsa_view`) decide.
+
+def _dsa_xla(p, h, **kw):
+    from veles_tpu.ops import attention as oa
+    return oa.indexed_attention(p, h, lowering="xla", **kw)
+
+
+def _dsa_pallas(p, h, **kw):
+    from veles_tpu.ops import attention as oa
+    from veles_tpu.ops import pallas_kernels as pk
+    if not pk.dsa_view(h.shape[1], kw["head_dim"]):
+        return _dsa_xla(p, h, **kw)
+    return oa.indexed_attention(p, h, lowering="pallas_flash",
+                                interpret=pk._interpret(), **kw)
+
+
+register_op(
+    "dsa", default="pallas_flash", fallback="xla",
+    doc="grouped-query attention over the keys a learned indexer selects "
+        "(ops/attention.py): index scores, threshold search, attention "
+        "over the selection, index loss (off a TPU and under GSPMD the "
+        "default resolves to xla)")
+register(Variant("dsa", "xla", _dsa_xla,
+                 doc="every causal pair of a block of queries scored and "
+                     "masked, bands of keys, one lax.map a band; the "
+                     "scores pass through HBM"))
+register(Variant("dsa", "pallas_flash", _dsa_pallas, pallas=True,
+                 doc="the main attention as four flash kernels over the "
+                     "int8 selection, each jitted once for all sites; "
+                     "indexer, search and index loss stay XLA (128 | S and "
+                     "128 | head size; any other shape traces xla)"))
+
+
 # -- max pooling (fused-step lowering; the knob is the BACKWARD shape) ------
 #    apply(x, ksize, stride, use_abs) -> y; differentiable.
 

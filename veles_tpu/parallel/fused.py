@@ -920,8 +920,10 @@ class FusedTrainStep:
 
                 if getattr(u, "fused_remat", False) and train:
                     # the step keeps this unit's input and recomputes its
-                    # inside in the backward pass
-                    apply = jax.checkpoint(apply)
+                    # inside in the backward pass, but for what the unit's
+                    # policy names
+                    apply = jax.checkpoint(apply, policy=getattr(
+                        u, "fused_remat_policy", None))
                 x = apply(params[i], x, kw)
                 if "aux" in kw:
                     x, counted[i] = x

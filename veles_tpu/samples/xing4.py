@@ -26,10 +26,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List
 
-import numpy as np
-
 from veles_tpu.config import root
-from veles_tpu.loader.fullbatch import FullBatchLoader
+from veles_tpu.loader.synthetic import RandomTokenLoader
 from veles_tpu.znicz import lm  # noqa: F401 (registers the layer types)
 from veles_tpu.znicz.standard_workflow import StandardWorkflow
 
@@ -103,28 +101,6 @@ def layer_table(cfg: Dict[str, Any]) -> List[Dict[str, Any]]:
             + [dict(sparse, type="hc_block")
                for _ in range(cfg["num_hidden_layers"] - n_dense)]
             + [head])
-
-
-class RandomTokenLoader(FullBatchLoader):
-    """Sequences of i.i.d. uniform token ids with, for every position,
-    the next and the next-next token as its two targets."""
-
-    def __init__(self, workflow=None, vocab: int = 64, seq_len: int = 16,
-                 n_train: int = 32, n_validation: int = 8, **kwargs: Any
-                 ) -> None:
-        super().__init__(workflow, **kwargs)
-        self.vocab, self.seq_len = vocab, seq_len
-        self.n_train, self.n_validation = n_train, n_validation
-
-    def load_data(self) -> None:
-        from veles_tpu import prng
-        n, s = self.n_validation + self.n_train, self.seq_len
-        ids = prng.get().fill_uniform((n, s + 2), 0, self.vocab,
-                                      np.float32).astype(np.int32)
-        ids = np.clip(ids, 0, self.vocab - 1)
-        targets = np.stack([ids[:, 1:s + 1], ids[:, 2:s + 2]], axis=-1)
-        self.bind_arrays(ids[:, :s], targets, 0, self.n_validation,
-                         self.n_train)
 
 
 class Xing4Workflow(StandardWorkflow):

@@ -594,6 +594,27 @@ def moe_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
             "band around its even share when counting began, else 0"))
 
 
+def dsa_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
+    """The `veles_dsa_*` families of attention over the keys an indexer
+    selects (`ops/attention.py::indexed_attention`): (query, key) pairs,
+    counted inside the step into int32 state (`znicz/lm.py`) and published
+    from it by whoever reads that state (`znicz.lm.publish_dsa_counters`),
+    never per step. Registered on first use."""
+    reg = reg or default_registry()
+    by_layer = ("layer",)
+    return SimpleNamespace(
+        steps=reg.counter("veles_dsa_steps_total",
+                          "train steps the pair counters cover", by_layer),
+        causal=reg.counter("veles_dsa_pairs_causal_total",
+                           "pairs with key <= query", by_layer),
+        selected=reg.counter("veles_dsa_pairs_selected_total",
+                             "pairs the indexer selected", by_layer),
+        scored=reg.counter(
+            "veles_dsa_pairs_scored_total",
+            "pairs whose main-attention score the program formed: the "
+            "causal pairs of the tiles it visited", by_layer))
+
+
 def family_values(name: str, reg: Optional[MetricsRegistry] = None
                   ) -> Optional[Dict[Tuple[str, ...], float]]:
     """{label values: value} of one family's children, or None where no

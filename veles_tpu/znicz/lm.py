@@ -49,35 +49,65 @@ from veles_tpu.znicz.nn_units import (Forward, GradientDescentVJP,
 
 #: rows of an expert layer's sorted (token, slot) buffer over the rows a
 #: perfectly balanced router fills (`ops.moe.held_experts_swiglu`'s
-#: `fast_rows`): held loads within a half of the even share take the
-#: fast path, anything beyond is computed on the whole buffer. Under the
-#: selection-bias rule the held experts of one layer were given at most
-#: 1.150 times the even load in one step, from the first step on (ten
-#: seeds x 83 steps x 5 layers on a v5e, PERF.md section 6, PR 32), and a
-#: step's load stands 4 % around the even one: 1.5 is twelve of those away
-FAST_ROWS_HEADROOM = 1.5
+#: `fast_rows`), by what balances the router: held loads within it take
+#: the fast path, anything beyond is computed on the whole buffer. Under
+#: the selection-bias rule the held experts of one layer were given at
+#: most 1.150 times the even load in one step, from the first step on
+#: (ten seeds x 83 steps x 5 layers on a v5e, PERF.md section 6, PR 32),
+#: and a step's load stands 4 % around the even one: 1.5 is twelve of
+#: those away. Softmax scores under a balance loss alone are held to
+#: nothing: from a random start a handful of the experts take most of
+#: the slots, the held sixteen's share of a layer is a draw of the seed,
+#: and four-step means of it reached 2.03 times the even one (16 seeds x
+#: 6 layers, PR 35): past 1.5 were a tenth of the layers' steps, each
+#: 28 ms slower on the whole buffer (a layer's 13 ms at 24,576 rows, 45
+#: at 131,072), which was most of what the seed did to a step's time. A
+#: buffer row costs 0.18 us a layer and step beside the products
+FAST_ROWS_HEADROOM = {"sigmoid_bias": 1.5, "softmax": 3.0}
 
 
 class BlockSpec:
     """The static description of one block and its pure forward. Shared
     by `HCBlock` and by the head's multi-token-prediction module."""
 
-    def __init__(self, *, features: int, streams: int, n_heads: int,
-                 q_rank: int, kv_rank: int, nope: int, rope: int,
-                 v_dim: int, ffn: str, width: int, n_experts: int = 0,
+    def __init__(self, *, features: int, streams: int = 1, n_heads: int,
+                 ffn: str, width: int, residual: str = "hc",
+                 attention: str = "latent", q_rank: int = 0,
+                 kv_rank: int = 0, nope: int = 0, rope: int = 0,
+                 v_dim: int = 0, kv_heads: int = 0, head_dim: int = 0,
+                 index_heads: int = 0, index_dim: int = 0,
+                 index_topk: int = 0, query_block: int = 256,
+                 key_bands: int = 4, n_experts: int = 0,
                  held: Sequence[int] = (0, 0), top_k: int = 0,
-                 routed_scaling: float = 1.0,
+                 scoring: str = "sigmoid_bias", shared: bool = True,
+                 grouped: str = "ragged_dot", routed_scaling: float = 1.0,
                  bias_update_speed: float = 0.001,
                  rope_theta: float = 10000.0,
                  rope_scaling: Optional[Dict[str, Any]] = None,
                  sinkhorn_iters: int = 20, hc_eps: float = 1e-6,
                  hc_clamp: Sequence[float] = (-30.0, 30.0),
                  norm_eps: float = 1e-6, init_std: float = 0.02) -> None:
-        if ffn not in ("dense", "experts"):
-            raise ValueError(f"ffn must be dense or experts, not {ffn!r}")
+        for what, value, known in (
+                ("ffn", ffn, ("dense", "experts")),
+                ("residual", residual, ("hc", "plain")),
+                ("attention", attention, ("latent", "indexed")),
+                ("scoring", scoring, ("sigmoid_bias", "softmax")),
+                ("grouped", grouped, ("ragged_dot", "pallas"))):
+            if value not in known:
+                raise ValueError(f"{what} must be one of {known}, "
+                                 f"not {value!r}")
+        if residual == "plain" and streams != 1:
+            raise ValueError("a plain residual path has one stream")
         self.c, self.n = features, streams
+        self.residual, self.attention = residual, attention
+        self.scoring, self.shared = scoring, bool(shared)
+        self.grouped = grouped
         self.n_heads, self.q_rank, self.kv_rank = n_heads, q_rank, kv_rank
         self.nope, self.rope, self.v_dim = nope, rope, v_dim
+        self.kv_heads, self.head_dim = kv_heads, head_dim
+        self.index_heads, self.index_dim = index_heads, index_dim
+        self.index_topk = index_topk
+        self.query_block, self.key_bands = query_block, key_bands
         self.ffn, self.width = ffn, width
         self.n_experts, self.top_k = n_experts, top_k
         self.held = (int(held[0]), int(held[1]))
@@ -89,19 +119,37 @@ class BlockSpec:
         self.hc_clamp = (float(hc_clamp[0]), float(hc_clamp[1]))
         self.norm_eps, self.init_std = norm_eps, init_std
 
+    #: what a block hands on to the head that owns the loss, by the key
+    #: its `apply` counts it under: differentiable terms of the loss
+    LOSS_TERMS = {"balance_loss": "balance", "index_loss": "index"}
+
     #: whether a Pallas lowering may be traced (`variants.resolve` reads
     #: it): the unit that owns the spec hands on the fused step's word
     #: before every trace
     allow_pallas = True
 
-    def hc_lowering(self, tokens: int) -> str:
+    def hc_lowering(self, tokens: int) -> Optional[str]:
         """The `hc` lowering a trace of `tokens` tokens takes: the
         registry's by platform, `xla` where the kernels have no view of
-        the shape."""
+        the shape; None on a plain residual path."""
+        if self.residual != "hc":
+            return None
         v = variants.resolve("hc", unit=self)
         if v.pallas:
             from veles_tpu.ops import pallas_kernels as pk
             if not pk.hc_view(tokens, self.c, self.n):
+                return "xla"
+        return v.name
+
+    def dsa_lowering(self, seq: int) -> Optional[str]:
+        """The `dsa` lowering a trace of sequences of `seq` tokens takes;
+        None for another kind of attention."""
+        if self.attention != "indexed":
+            return None
+        v = variants.resolve("dsa", unit=self)
+        if v.pallas:
+            from veles_tpu.ops import pallas_kernels as pk
+            if not pk.dsa_view(seq, self.head_dim):
                 return "xla"
         return v.name
 
@@ -110,20 +158,31 @@ class BlockSpec:
     def shapes(self) -> Dict[str, Tuple[int, ...]]:
         c, n, h = self.c, self.n, self.n_heads
         out: Dict[str, Tuple[int, ...]] = {}
-        for hc in ("hca_", "hcm_"):
+        for hc in ("hca_", "hcm_") if self.residual == "hc" else ():
             out.update({hc + "p_pre": (n * c, n), hc + "p_post": (n * c, n),
                         hc + "p_res": (n * c, n * n), hc + "a_pre": (1,),
                         hc + "a_post": (1,), hc + "a_res": (1,),
                         hc + "b_pre": (n,), hc + "b_post": (n,),
                         hc + "b_res": (n, n)})
-        out.update({
-            "attn_norm": (c,), "attn_w_dq": (c, self.q_rank),
-            "attn_q_norm": (self.q_rank,),
-            "attn_w_uq": (self.q_rank, h * (self.nope + self.rope)),
-            "attn_w_dkv": (c, self.kv_rank + self.rope),
-            "attn_kv_norm": (self.kv_rank,),
-            "attn_w_ukv": (self.kv_rank, h * (self.nope + self.v_dim)),
-            "attn_w_o": (h * self.v_dim, c)})
+        if self.attention == "latent":
+            out.update({
+                "attn_norm": (c,), "attn_w_dq": (c, self.q_rank),
+                "attn_q_norm": (self.q_rank,),
+                "attn_w_uq": (self.q_rank, h * (self.nope + self.rope)),
+                "attn_w_dkv": (c, self.kv_rank + self.rope),
+                "attn_kv_norm": (self.kv_rank,),
+                "attn_w_ukv": (self.kv_rank, h * (self.nope + self.v_dim)),
+                "attn_w_o": (h * self.v_dim, c)})
+        else:
+            d, kv = self.head_dim, self.kv_heads
+            hi, di = self.index_heads, self.index_dim
+            out.update({
+                "attn_norm": (c,), "attn_w_q": (c, h * d),
+                "attn_w_k": (c, kv * d), "attn_w_v": (c, kv * d),
+                "attn_q_norm": (d,), "attn_k_norm": (d,),
+                "attn_w_o": (h * d, c), "attn_idx_w_q": (c, hi * di),
+                "attn_idx_w_k": (c, di), "attn_idx_k_norm": (di,),
+                "attn_idx_k_bias": (di,), "attn_idx_w_w": (c, hi)})
         w = self.width
         if self.ffn == "dense":
             out.update({"mlp_norm": (c,), "mlp_w_gate": (c, w),
@@ -132,9 +191,12 @@ class BlockSpec:
             e = self.held[1]
             out.update({
                 "moe_norm": (c,), "moe_w_router": (c, self.n_experts),
-                "moe_shared_gate": (c, w), "moe_shared_up": (c, w),
-                "moe_shared_down": (w, c), "moe_experts_gate": (e, c, w),
+                "moe_experts_gate": (e, c, w),
                 "moe_experts_up": (e, c, w), "moe_experts_down": (e, w, c)})
+            if self.shared:
+                out.update({"moe_shared_gate": (c, w),
+                            "moe_shared_up": (c, w),
+                            "moe_shared_down": (w, c)})
         return out
 
     def initial(self, name: str, shape: Tuple[int, ...], fill) -> np.ndarray:
@@ -142,6 +204,8 @@ class BlockSpec:
         ones from the unit's generator."""
         if name.endswith("norm"):
             return np.ones(shape, np.float32)
+        if name.endswith("_bias"):
+            return np.zeros(shape, np.float32)
         if name[-5:] in ("a_pre", "a_res") or name.endswith("a_post"):
             return np.full(shape, 0.01, np.float32)
         for b, value in ol.hc_init_biases(self.n).items():
@@ -149,22 +213,42 @@ class BlockSpec:
                 return value
         return fill(shape, self.init_std)
 
-    def aux_shapes(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
-        """Step state beside the parameters: the selection bias, the slots
-        every expert was given so far, the sum over steps of the fullest
-        held expert's slots, slots not computed, steps counted."""
-        if self.ffn != "experts":
-            return {}
-        e = self.n_experts
-        return {"bias": ((e,), np.float32), "load": ((e,), np.int32),
-                "fullest": ((1,), np.int32), "dropped": ((1,), np.int32),
-                "steps": ((1,), np.int32),
-                "picked": (None, np.int32)}     # (tokens, top_k): see unit
+    def aux_shapes(self, batch: int = 1, seq: int = 8
+                   ) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """Step state beside the parameters, for `batch` sequences of
+        `seq` tokens. An expert layer: the selection bias (under that
+        rule), the slots every expert was given so far, the sum over
+        steps of the fullest held expert's slots, slots not computed,
+        steps counted, the last step's selected experts. Indexed
+        attention: the (query, key) pairs that were causal, selected and
+        scored so far, each as (2^20s, rest) since int32 holds 16 steps
+        of them, and the last step's selection, 8 keys a byte."""
+        out: Dict[str, Tuple[Tuple[int, ...], Any]] = {}
+        if self.attention == "indexed":
+            out.update({"pairs_" + k: ((2,), np.int32)
+                        for k in ("causal", "selected", "scored")})
+            out["selected"] = ((batch * seq, seq // 8), np.uint8)
+            if self.ffn != "experts":
+                out["steps"] = ((1,), np.int32)
+        if self.ffn == "experts":
+            e = self.n_experts
+            if self.scoring == "sigmoid_bias":
+                out["bias"] = ((e,), np.float32)
+            out.update({"load": ((e,), np.int32),
+                        "fullest": ((1,), np.int32),
+                        "dropped": ((1,), np.int32),
+                        "steps": ((1,), np.int32),
+                        "picked": ((batch * seq, self.top_k), np.int32)})
+        return out
 
     # -- forward -----------------------------------------------------------------
 
     def _hc(self, p: Dict[str, Any], prefix: str, x, f):
-        """One hyper-connection around `f`: x (T, n*C) -> (x, f's extra)."""
+        """The residual path around the sub-layer `f`: x (T, n*C) ->
+        (x, f's extra). One hyper-connection, or x + f(x)."""
+        if self.residual == "plain":
+            y, extra = f(x)
+            return x + y, extra
         return variants.resolve("hc", unit=self).apply(
             {k[len(prefix):]: v for k, v in p.items()
              if k.startswith(prefix)}, x, f, self.n,
@@ -172,6 +256,8 @@ class BlockSpec:
             clamp=self.hc_clamp, norm_eps=self.norm_eps)
 
     def _attention(self, p: Dict[str, Any], h, batch: int):
+        if self.attention == "indexed":
+            return self._indexed_attention(p, h, batch)
         with jax.named_scope("mla"):
             seq = h.shape[0] // batch
             rs = self.rope_scaling
@@ -194,31 +280,66 @@ class BlockSpec:
                 * all_dim * all_dim, norm_eps=self.norm_eps)
             return y.reshape(h.shape), None
 
+    def _indexed_attention(self, p: Dict[str, Any], h, batch: int):
+        with jax.named_scope("dsa"):
+            seq = h.shape[0] // batch
+            hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
+            y, extra = variants.resolve("dsa", unit=self).apply(
+                {k[len("attn_"):]: v for k, v in p.items()
+                 if k.startswith("attn_")},
+                hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
+                kv_heads=self.kv_heads, head_dim=self.head_dim,
+                index_heads=self.index_heads, index_dim=self.index_dim,
+                topk=self.index_topk, rope_theta=self.rope_theta,
+                query_block=self.query_block, key_bands=self.key_bands,
+                norm_eps=self.norm_eps)
+            return y.reshape(h.shape), extra
+
     def fast_rows(self, tokens: int) -> int:
         even = tokens * self.top_k * self.held[1] / max(self.n_experts, 1)
-        return int(np.ceil(FAST_ROWS_HEADROOM * even))
+        return int(np.ceil(FAST_ROWS_HEADROOM[self.scoring] * even))
+
+    def grouped_kernels(self) -> bool:
+        """Whether the held experts' products trace the `veles_gmm` /
+        `veles_tgmm` kernels: where the layer table asks for them
+        (`grouped="pallas"`) and, as for every Pallas lowering
+        (`variants.resolve`), the step allows them and the platform runs
+        them (a TPU, or interpret mode asked for); else `lax.ragged_dot`."""
+        return (self.grouped == "pallas" and self.allow_pallas
+                and variants.pallas_ok())
 
     def _experts(self, p: Dict[str, Any], h, bias):
         with jax.named_scope("moe"):
             hn = ol.rms_norm(h, p["moe_norm"], self.norm_eps)
+            out = {}
             with jax.named_scope("router"):
-                scores = jax.nn.sigmoid(jnp.matmul(
-                    hn, p["moe_w_router"],
-                    preferred_element_type=jnp.float32))
-                idx, picked = om.route_topk(
-                    scores, 0.0 if bias is None else bias, self.top_k)
-                gates = self.routed_scaling * picked \
-                    / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+                logits = jnp.matmul(hn, p["moe_w_router"],
+                                    preferred_element_type=jnp.float32)
+                if self.scoring == "softmax":
+                    probs, idx, gates = om.softmax_topk_gates(
+                        logits, self.top_k)
+                    gates = self.routed_scaling * gates
+                else:
+                    idx, picked = om.route_topk(
+                        jax.nn.sigmoid(logits),
+                        0.0 if bias is None else bias, self.top_k)
+                    gates = self.routed_scaling * picked \
+                        / (picked.sum(axis=-1, keepdims=True) + 1e-20)
                 load = om.expert_loads(idx, self.n_experts)
+            if self.scoring == "softmax":
+                out["router_probs"] = probs     # for `apply`'s balance loss
             with jax.named_scope("experts"):
                 y, dropped = om.held_experts_swiglu(
                     hn, idx, gates, p["moe_experts_gate"],
                     p["moe_experts_up"], p["moe_experts_down"], self.held,
-                    self.fast_rows(h.shape[0]))
-            with jax.named_scope("shared"):
-                y = y + ol.swiglu(hn, p["moe_shared_gate"],
-                                  p["moe_shared_up"], p["moe_shared_down"])
-        return y, {"load": load, "dropped": dropped,
+                    self.fast_rows(h.shape[0]), self.grouped_kernels(),
+                    variants.pallas_interpret_active())
+            if self.shared:
+                with jax.named_scope("shared"):
+                    y = y + ol.swiglu(hn, p["moe_shared_gate"],
+                                      p["moe_shared_up"],
+                                      p["moe_shared_down"])
+        return y, {**out, "load": load, "dropped": dropped,
                    "picked": idx.astype(jnp.int32)}
 
     def _mlp(self, p: Dict[str, Any], h):
@@ -228,32 +349,80 @@ class BlockSpec:
                              p["mlp_w_down"]), None
 
     def apply(self, p: Dict[str, Any], x, bias=None):
-        """x (N, S, n*C) -> (x, what the expert layer counted or None)."""
+        """x (N, S, n*C) -> (x, what the block counted or None: an expert
+        layer's loads and selected experts, indexed attention's selection;
+        with them the block's terms of the loss, `LOSS_TERMS`)."""
         batch = x.shape[0]
         flat = x.reshape(-1, x.shape[-1])
-        flat, _ = self._hc(p, "hca_", flat,
-                           lambda h: self._attention(p, h, batch))
+        flat, seen = self._hc(p, "hca_", flat,
+                              lambda h: self._attention(p, h, batch))
         if self.ffn == "dense":
             flat, out = self._hc(p, "hcm_", flat, lambda h: self._mlp(p, h))
         else:
             flat, out = self._hc(p, "hcm_", flat,
                                  lambda h: self._experts(p, h, bias))
+        if out and "router_probs" in out:
+            # of each sequence, which the expert layer does not know
+            with jax.named_scope("moe"), jax.named_scope("balance_loss"):
+                out["balance_loss"] = om.balance_loss(
+                    out.pop("router_probs"), out["picked"], batch)
+        if seen is not None:
+            out = {**seen, **(out or {})}
         return flat.reshape(x.shape), out
 
     def aux_update(self, aux: Dict[str, Any], out: Dict[str, Any]
                    ) -> Dict[str, Any]:
         """After a step: b_e <- b_e + u sign(mean load - load_e) from the
         step's loads over all experts, and the counters."""
-        load = out["load"]
-        lf = load.astype(jnp.float32)
-        first, count = self.held
-        return {"bias": aux["bias"] + self.bias_update_speed
-                * jnp.sign(lf.mean() - lf),
+        new = {"steps": aux["steps"] + 1}
+        if "selected" in aux:
+            s = aux["selected"].shape[1] * 8
+            causal = aux["selected"].shape[0] // s * (s * (s + 1) // 2)
+            # (every causal pair of a tile it visits is scored: the masked
+            # dense form visits them all)
+            new.update({
+                "pairs_causal": _add_wide(aux["pairs_causal"], causal),
+                "pairs_scored": _add_wide(aux["pairs_scored"], causal),
+                "pairs_selected": _add_wide(aux["pairs_selected"],
+                                            out["pairs_selected"]),
+                "selected": out["selected"]})
+        if "load" in aux:
+            load = out["load"]
+            lf = load.astype(jnp.float32)
+            first, count = self.held
+            if "bias" in aux:
+                new["bias"] = aux["bias"] + self.bias_update_speed \
+                    * jnp.sign(lf.mean() - lf)
+            new.update({
                 "load": aux["load"] + load,
                 "fullest": aux["fullest"] + load[first:first + count].max(),
-                "dropped": aux["dropped"] + out["dropped"].astype(jnp.int32),
-                "steps": aux["steps"] + 1,
-                "picked": out["picked"]}
+                "dropped": aux["dropped"]
+                + out["dropped"].astype(jnp.int32),
+                "picked": out["picked"]})
+        return new
+
+
+WIDE = 20
+
+
+def _add_wide(acc, n):
+    """acc (2,) int32 = (2^20s, rest below 2^20) plus `n`, a count below
+    2^31 (a Python int or an int32)."""
+    rest = acc[1] + (n & ((1 << WIDE) - 1))
+    return jnp.stack([acc[0] + (n >> WIDE) + (rest >> WIDE),
+                      rest & ((1 << WIDE) - 1)]).astype(jnp.int32)
+
+
+def _wide(acc) -> int:
+    return (int(acc[0]) << WIDE) + int(acc[1])
+
+
+#: ONE object for every block: `jax.checkpoint` splits a jitted kernel's
+#: jaxpr by its policy and caches the split by the policy's identity, so a
+#: policy made anew a block leaves the step with a copy of every kernel's
+#: body a block (28 of `veles_dsa_pmean` in the six-block step, lowered
+#: here for a described v5e)
+_DSA_POLICY = jax.checkpoint_policies.save_only_these_names(*oa.DSA_SAVED)
 
 
 def _gaussian(unit):
@@ -264,12 +433,16 @@ def _gaussian(unit):
 
 
 def _hc_effective(unit) -> Optional[str]:
-    """What the unit's hyper-connections trace, for `variant_table()`;
-    None for a unit that holds none (a head without its MTP block)."""
+    """What the unit's registry op traces, for `variant_table()`: its
+    hyper-connections' lowering, or its indexed attention's (a unit's
+    `variant_op` names which); None for a unit that holds none (a head
+    without its MTP block)."""
     if unit.spec is None or not unit.input:
         return None
     unit.spec.allow_pallas = getattr(unit, "allow_pallas", True)
     n, s = unit.input.shape[:2]
+    if unit.variant_op == "dsa":
+        return unit.spec.dsa_lowering(s)
     return unit.spec.hc_lowering(n * s)
 
 
@@ -352,11 +525,16 @@ class HCBlock(_LMUnit):
                      "v_dim", "ffn", "width", "n_experts", "held", "top_k",
                      "routed_scaling", "bias_update_speed", "rope_theta",
                      "rope_scaling", "sinkhorn_iters", "hc_eps", "hc_clamp",
-                     "norm_eps", "init_std")
+                     "norm_eps", "init_std", "residual", "attention",
+                     "scoring", "shared", "kv_heads", "head_dim",
+                     "index_heads", "index_dim", "index_topk",
+                     "query_block", "key_bands", "grouped")
         self._spec_kw = {k: kwargs.pop(k) for k in spec_keys if k in kwargs}
         super().__init__(workflow, **kwargs)
         self.streams = streams
         self.spec: Optional[BlockSpec] = None
+        if self._spec_kw.get("attention") == "indexed":
+            self.variant_op = "dsa"     # (one op a unit: the costlier one)
         probe = BlockSpec(features=1, streams=streams, **self._spec_kw)
         self._make_arrays(tuple(probe.shapes()), tuple(probe.aux_shapes()))
 
@@ -369,16 +547,32 @@ class HCBlock(_LMUnit):
         n, s, width = self.input.shape
         self.spec = BlockSpec(features=width // self.streams,
                               streams=self.streams, **self._spec_kw)
-        _init_leaves(self, self.spec, "", n * s)
+        _init_leaves(self, self.spec, "", n, s)
         if not self.output or self.output.shape != (n, s, width):
             self.output.reset(np.zeros((n, s, width), np.float32))
         return super().initialize(device=device, **kwargs)
 
+    @property
+    def fused_remat_policy(self):
+        """What the step's `jax.checkpoint` around this unit saves:
+        indexed attention's thresholds and outputs, so that the backward
+        pass neither selects nor attends a second time."""
+        if self.spec is None or self.spec.attention != "indexed":
+            return None
+        return _DSA_POLICY
+
     def fused_apply(self, params, x, *, key=None, train=True, aux=None):
         self.spec.allow_pallas = getattr(self, "allow_pallas", True)
         y, out = self.spec.apply(params, x["x"],
-                                 None if aux is None else aux["bias"])
+                                 None if aux is None else aux.get("bias"))
         y = {**x, "x": y}
+        terms = {name: out.pop(key) for key, name in
+                 BlockSpec.LOSS_TERMS.items() if out and key in out}
+        if terms:
+            # differentiable, so they ride with the activations to the
+            # head that owns the loss (`counted` is state no gradient
+            # touches)
+            y["terms"] = x.get("terms", ()) + (terms,)
         return y if aux is None else (y, out)
 
     def fused_aux_update(self, aux, out):
@@ -386,17 +580,16 @@ class HCBlock(_LMUnit):
 
 
 def _init_leaves(unit: _LMUnit, spec: BlockSpec, prefix: str,
-                 tokens: int) -> None:
+                 batch: int, seq: int) -> None:
     """Fill the unit's still empty Arrays of one block."""
     for name, shape in spec.shapes().items():
         arr = getattr(unit, prefix + name)
         if not arr:
             arr.reset(spec.initial(name, shape, _gaussian(unit)))
-    for name, (shape, dtype) in spec.aux_shapes().items():
+    for name, (shape, dtype) in spec.aux_shapes(batch, seq).items():
         arr = getattr(unit, "aux_" + prefix + name)
         if not arr:
-            arr.reset(np.zeros((tokens, spec.top_k) if shape is None
-                               else shape, dtype))
+            arr.reset(np.zeros(shape, dtype))
 
 
 class LMHead(_LMUnit):
@@ -424,15 +617,22 @@ class LMHead(_LMUnit):
 
     def __init__(self, workflow=None, vocab: int = 256, streams: int = 1,
                  loss_chunk: int = 1024, mtp: Optional[Dict[str, Any]] = None,
-                 mtp_weight: float = 0.3, norm_eps: float = 1e-6,
-                 init_std: float = 0.02, **kwargs: Any) -> None:
+                 mtp_weight: float = 0.3,
+                 term_weights: Optional[Dict[str, float]] = None,
+                 norm_eps: float = 1e-6, init_std: float = 0.02,
+                 **kwargs: Any) -> None:
         super().__init__(workflow, **kwargs)
         self.vocab, self.streams = vocab, streams
         self.loss_chunk, self.mtp_weight = loss_chunk, mtp_weight
         self.norm_eps, self.init_std = norm_eps, init_std
+        #: {term: weight} of the blocks' terms of the loss
+        #: (`BlockSpec.LOSS_TERMS`): `balance` is weighted as the MEAN
+        #: over the blocks that hand one on, any other as their SUM
+        self.term_weights = dict(term_weights or {})
         self.mtp_kw = dict(mtp) if mtp else None
         self.spec: Optional[BlockSpec] = None
         names, aux = ["final_norm", "weights"], ["ce_main", "ce_mtp"]
+        aux += ["term_" + t for t in sorted(self.term_weights)]
         if self.mtp_kw:
             probe = BlockSpec(features=1, streams=streams, **self.mtp_kw)
             names += ["mtp_norm_h", "mtp_norm_e", "mtp_w_proj",
@@ -456,15 +656,17 @@ class LMHead(_LMUnit):
                         "mtp_w_proj": (2 * c, c), "mtp_final_norm": (c,)})
             self.spec = BlockSpec(features=c, streams=self.streams,
                                   **self.mtp_kw)
-            _init_leaves(self, self.spec, "mtp_", n * s)
+            _init_leaves(self, self.spec, "mtp_", n, s)
         for name, shape in own.items():
             arr = getattr(self, name)
             if not arr:
                 arr.reset(np.ones(shape, np.float32) if len(shape) == 1
                           else fill(shape, self.init_std))
-        for name in ("aux_ce_main", "aux_ce_mtp"):
-            if not getattr(self, name):
-                getattr(self, name).reset(np.zeros((1,), np.float32))
+        for name in self._anames:
+            if not name.startswith("mtp_") and not getattr(self,
+                                                           "aux_" + name):
+                getattr(self, "aux_" + name).reset(np.zeros((1,),
+                                                            np.float32))
         if not self.output or self.output.shape != (n, self.vocab):
             # the evaluator's view; the fused step never fills it
             self.output.reset(np.zeros((n, self.vocab), np.float32))
@@ -491,8 +693,17 @@ class LMHead(_LMUnit):
                 p["weights"], targets[..., 0].reshape(-1), wt, chunk)
             ce = ce / denom
         out = {"ce_main": ce, "ce_mtp": jnp.zeros_like(ce)}
+        extra = 0.0
+        for name, weight in sorted(self.term_weights.items()):
+            with jax.named_scope("terms"):
+                parts = [t[name] for t in x.get("terms", ()) if name in t]
+                term = jnp.asarray(sum(parts), jnp.float32) \
+                    / (len(parts) if name == "balance" and parts else 1)
+                out["term_" + name] = term
+                extra = extra + weight * term
         if self.spec is None:
-            return (ce, n_err) if aux is None else ((ce, n_err), out)
+            loss = ce + extra
+            return (loss, n_err) if aux is None else ((loss, n_err), out)
         with jax.named_scope("mtp"):
             with jax.named_scope("proj"):
                 nxt = jnp.take(x["table"], targets[..., 0].reshape(-1),
@@ -517,12 +728,12 @@ class LMHead(_LMUnit):
                 ce2 = ce2 / denom
         out["ce_mtp"] = ce2
         out.update({"mtp_" + k: v for k, v in counted.items()})
-        loss = ce + self.mtp_weight * ce2
+        loss = ce + self.mtp_weight * ce2 + extra
         return (loss, n_err) if aux is None else ((loss, n_err), out)
 
     def fused_aux_update(self, aux, out):
-        new = {"ce_main": out["ce_main"].reshape(1),
-               "ce_mtp": out["ce_mtp"].reshape(1)}
+        new = {k: v.reshape(1).astype(jnp.float32) for k, v in out.items()
+               if not k.startswith("mtp_")}
         if self.spec is not None:
             cut = len("mtp_")
             moved = self.spec.aux_update(
@@ -570,6 +781,38 @@ def publish_moe_counters(now: Dict[str, Dict[str, int]],
     h.dropped.set_total(dropped)
     if balance_reached is not None:
         h.reached.set(1.0 if balance_reached else 0.0)
+
+
+def dsa_counts(step, aux) -> Dict[str, Dict[str, int]]:
+    """{layer: {steps, causal, selected, scored}} so far, from the host
+    copy `aux` of a fused step's `state["aux"]`: one entry per block of
+    indexed attention, named by its unit's scope (`L02`); the counts are
+    (query, key) pairs."""
+    out = {}
+    for scope, u, a in zip(step.scopes, step.forwards, aux):
+        spec = getattr(u, "spec", None)
+        if spec is None or spec.attention != "indexed" \
+                or isinstance(u, LMHead):
+            continue
+        out[scope.split(".")[0]] = {
+            "steps": int(a["steps"][0]),
+            **{k: _wide(a["pairs_" + k])
+               for k in ("causal", "selected", "scored")}}
+    return out
+
+
+def publish_dsa_counters(now: Dict[str, Dict[str, int]],
+                         base: Optional[Dict[str, Dict[str, int]]] = None
+                         ) -> None:
+    """Set the `veles_dsa_*` counters (`telemetry/metrics.py`) to what
+    `dsa_counts` read `now`, less what it read at `base`."""
+    from veles_tpu.telemetry import metrics
+    h = metrics.dsa_handles()
+    for layer, c in now.items():
+        b = (base or {}).get(layer, dict.fromkeys(c, 0))
+        for key, fam in (("steps", h.steps), ("causal", h.causal),
+                         ("selected", h.selected), ("scored", h.scored)):
+            fam.labels(layer=layer).set_total(c[key] - b[key])
 
 
 @register_gd(TokenEmbedding)
