@@ -1,0 +1,10 @@
+"""Share of the chip's bf16 peak that `veles_dsa_index_bwd` reaches (the index scores' gradient by queries, weights and keys in one kernel: the scores again and two products more a head and tile;
+`keye2_index_count.index_kernel_roofline`): the operations it executes
+over its device time. Compute bounds it, at a contraction or a width of
+64 that half-fills the array: near 50 at best; it cannot pass 100."""
+
+from benchmark import keye2_index_count as K
+
+
+def read(ctx):
+    return K.index_kernel_roofline(ctx, "veles_dsa_index_bwd")

@@ -135,9 +135,31 @@ def test_dsa_kernels_compile_at_published_widths(one_chip, compiled_pallas):
     """The main attention of `keye2_ep8.long16k` (ISSUE 35): 32 query heads
     on 4 key-value heads of 128, one sequence of 16,384 tokens, bfloat16,
     the selection as int8: the four `veles_dsa_*` kernels, each under its
-    fixed name."""
+    fixed name. And the indexer's two (ISSUE 36): 16 index heads of 64, a
+    block of 256 queries (what a `lax.map` body hands them, its place an
+    int32 they read from SMEM) and a whole band of 4,096 against all
+    16,384 keys, a block against the first band's 4,096, the keys' whole
+    gradient resident in the backward, under the VMEM the kernels ask
+    for."""
     h, hkv, t, d = 32, 4, 16384, 128
     assert pk.dsa_view(t, d) and not pk.dsa_view(32, 16)
+    hi, di = 16, 64
+    assert pk.dsa_index_view(t, hi, di)
+    assert pk._DSA_INDEX_VMEM_LIMIT <= 100 << 20      # of a v5e's 128 MiB
+    q0 = _sds(one_chip, (), jnp.int32)
+    for block, keys in ((256, t), (4096, t), (256, 4096)):
+        qi = _sds(one_chip, (hi, block, di), jnp.bfloat16)
+        w = _sds(one_chip, (block, hi), jnp.float32)
+        ki = _sds(one_chip, (keys, di), jnp.bfloat16)
+        ct = _sds(one_chip, (block, keys), jnp.float32)
+        txt = _compile(pk.dsa_index_forward_pallas, qi, w, ki, q0)
+        assert "tpu_custom_call" in txt and "veles_dsa_index_fwd" in txt
+        assert f"f32[{block},{keys}]" in txt
+        txt = _compile(pk.dsa_index_backward_pallas, qi, w, ki, q0, ct)
+        assert "tpu_custom_call" in txt and "veles_dsa_index_bwd" in txt
+        for shape in (f"bf16[{hi},{block},{di}]", f"f32[{block},{hi}]",
+                      f"f32[{keys},{di}]"):
+            assert shape in txt, shape
     q = _sds(one_chip, (h, t, d), jnp.bfloat16)
     kv = _sds(one_chip, (hkv, t, d), jnp.bfloat16)
     mask = _sds(one_chip, (t, t), jnp.int8)
@@ -605,56 +627,19 @@ def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
     `StandardWorkflow` and `FusedTrainStep`: ONE sequence of 16,384
     tokens, bfloat16, one `jax.checkpoint` a block that saves the indexed
     attention's thresholds, logsumexps and outputs; the main attention as
-    the four `veles_dsa_*` kernels (`dsa: pallas_flash`), the held experts'
-    products as `veles_gmm` / `veles_tgmm` (`grouped: pallas`). ONE compile:
+    the four `veles_dsa_*` kernels (`dsa: pallas_flash`), the indexer's
+    scores and their gradient as two more (ISSUE 36: nothing of (16 heads,
+    queries, keys) is left in the step), the held experts' products as
+    `veles_gmm` / `veles_tgmm` (`grouped: pallas`). ONE compile:
     memory is known before the first chip call (ISSUE 35). The units hold
     zeros (`init_std` 0: no draw), nothing is put on a device."""
-    import json
-
-    from veles_tpu.loader.fullbatch import FullBatchLoader
-    from veles_tpu.parallel import checkpoint as ck
-    from veles_tpu.samples import keye2
-    from veles_tpu.znicz.standard_workflow import StandardWorkflow
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "keye2_ep8.json")) as f:
-        cfg = json.load(f)
-    batch, seq = cfg["batch_per_chip"], cfg["seq_len"]
-
-    class ShapeOnlyLoader(FullBatchLoader):
-        def load_data(self):
-            self.bind_arrays(np.zeros((batch, seq), np.int32),
-                             np.zeros((batch, seq), np.int32), 0, 0, batch)
-
-    wf = StandardWorkflow(
-        layers=keye2.layer_table({**cfg, "init_std": 0.0}),
-        loader=ShapeOnlyLoader(minibatch_size=batch, on_device=False),
-        loss="softmax", n_classes=cfg["vocab_size"],
-        decision_config={"max_epochs": 1, "fail_iterations": 1},
-        gd_config=dict(cfg["optimizer"]), name="keye2_compile")
-    wf.initialize(device=None)
-    step = wf.build_fused_step(compute_dtype=cfg["compute_dtype"])
-    assert step.has_aux and step.unit_loss
-    assert step.variant_table()["dsa"] == "pallas_flash"
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    state = jax.tree_util.tree_map(
-        lambda a: sds(a.shape, a.dtype),
-        ck._abstract_state(step, "threefry2x32"))
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    state["key"] = sds(key.shape, key.dtype)
-    assert sum(int(np.prod(a.shape)) for layer in state["params"]
-               for a in layer.values()) == cfg["n_params"] == 659190016
     import time
-    t0 = time.perf_counter()
-    # at the platform's default precision, as the benchmark runs it (the
-    # grouped-matmul kernel refuses bfloat16 operands under "highest")
-    with jax.default_matmul_precision("bfloat16"):
-        lowered = jax.jit(step.train_callable(), donate_argnums=(0,)).lower(
-            state, sds((batch, seq), jnp.int32),
-            sds((batch, seq), jnp.int32), sds((batch,), jnp.float32))
+    row = _trace_cost().measure(None, one_chip, "keye2_ep8")
+    cfg, step, lowered = row["config"], row["step"], row["lowered"]
+    assert step.has_aux and step.unit_loss
+    assert row["dsa"] == "pallas_flash"
+    assert sum(int(np.prod(a.shape)) for layer in row["args"][0]["params"]
+               for a in layer.values()) == cfg["n_params"] == 659190016
     t1 = time.perf_counter()
     compiled = lowered.compile()
     txt = compiled.as_text()
@@ -667,22 +652,25 @@ def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
                   "/moe/balance_loss/", "rematted_computation",
                   "veles_dsa_attend_fwd", "veles_dsa_pmean",
                   "veles_dsa_attend_dq", "veles_dsa_attend_dkv",
+                  "veles_dsa_index_fwd", "veles_dsa_index_bwd",
                   "veles_gmm", "veles_tgmm"):
         assert scope in txt, scope
     # every kernel's body once in the module, called a site (one jit each,
     # ONE checkpoint policy object for the six blocks); the mean-head
-    # probabilities by band of queries, four shapes, forward and backward
-    lowered_text = lowered.as_text()
+    # probabilities by band of queries, four shapes, forward and backward;
+    # the index scores likewise (the selection's and the loss's calls of a
+    # band share one body), their gradient in the backward alone
     for kernel, bodies in (("veles_dsa_attend_fwd", 1),
                            ("veles_dsa_pmean", 8),
+                           ("veles_dsa_index_fwd", 8),
+                           ("veles_dsa_index_bwd", 4),
                            ("veles_dsa_attend_dq", 1),
                            ("veles_dsa_attend_dkv", 1),
                            # either width first, on the fast rows and on
                            # the whole buffer: forward, again where the
                            # backward recomputes, and the other way round
                            ("veles_gmm", 12), ("veles_tgmm", 4)):
-        assert lowered_text.count(
-            f'kernel_name = "{kernel}"') == bodies, kernel
+        assert row["kernels"][kernel]["bodies"] == bodies, row["kernels"]
         paths = set(re.findall(r'op_name="([^"]*%s[^"]*)"' % kernel, txt))
         assert len({m for p_ in paths for m in re.findall(
             r"L\d\d\.\w+", p_)}) == 6, (kernel, sorted(paths)[:3])
@@ -690,10 +678,21 @@ def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
     # thresholds and outputs are saved (`ops.attention.DSA_SAVED`)
     assert not re.search(r'op_name="[^"]*rematted_computation[^"]*/dsa/while',
                          txt)
+    # the index heads' scores of a block of queries exist in VMEM only: no
+    # (16 heads, queries, keys) tensor is left under the indexer's scope
+    # (a band's mean-head probabilities cut into its 16 blocks are (16, 256,
+    # keys) too, under `index_loss`)
+    heads = cfg["sa_config"]["indexer_num_heads"]
+    assert cfg["query_block"] == 256 and heads == 16
+    for line in txt.splitlines():
+        if "/dsa/" in line and "/indexer/" in line:
+            assert not re.search(r"\b(f32|pred|bf16)\[16,(256|4096),"
+                                 r"(4096|8192|12288|16384)\]", line), line[:300]
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print("keye2 step: lowered in", round(t1 - t0, 1), "s, compiled in",
+    print("keye2 step: traced and lowered in",
+          round(row["trace_s"] + row["lower_s"], 1), "s, compiled in",
           round(time.perf_counter() - t1, 1), "s; generated code",
           mem.generated_code_size_in_bytes, "B, arguments",
           mem.argument_size_in_bytes, "B, temporaries",
@@ -703,3 +702,8 @@ def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
     # what a v5e's allocator offers: `bytes_limit` of its memory
     # statistics (chip runs of PR 32)
     assert total < 16909336064, total
+    # the step's temporaries: 6.45 GB at PR 35, 6.53 with the indexer's
+    # kernels a block of queries a call; a band a call read 10.79 here and
+    # 16.1 GB of peak on the chip where 12.6 were (PR 36): XLA kept four
+    # bands' scores alive across the attention
+    assert mem.temp_size_in_bytes < 7 << 30, mem.temp_size_in_bytes

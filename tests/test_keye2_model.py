@@ -160,8 +160,11 @@ def test_the_kernels_counts_are_the_kernels():
         for blk in keye2_ops_count.DSA_BLOCKS:
             assert keye2_ops_count._fit(seq, blk) == pk.flash_fit_block(
                 seq, blk)
+    # (the main attention's kernels; the indexer's count themselves,
+    # `benchmark/keye2_index_count.py`)
     assert set(keye2_ops_count.DSA_KERNEL_CALLS) == {
-        v for k, v in pk.KERNEL_NAMES.items() if k.startswith("_dsa")}
+        v for k, v in pk.KERNEL_NAMES.items()
+        if k.startswith("_dsa") and not k.startswith("_dsa_index")}
     for seq, bands in ((2048, 1), (4096, 4), (16384, 4)):
         per = seq // bands
         want = 0
@@ -174,6 +177,111 @@ def test_the_kernels_counts_are_the_kernels():
                         want += bq * bk
         assert keye2_ops_count.pairs_visited(seq, bands) == want
         assert want >= keye2_ops_count.pairs_causal(seq)
+
+
+# (queries, keys, first position, index heads, width of one, the tiles asked
+# for): tiles that divide; tiles that have to shrink to divide (384 keys
+# under 256, 192 queries under 128: down to 128); a band that starts at
+# `q0 > 0`, in several rows of tiles, whose tiles above the diagonal are
+# passed over; the widths of the published indexer, one tile
+INDEX_CASES = {
+    "tiles_divide": (256, 256, 0, 16, 8, (128, 128)),
+    "tiles_shrink": (384, 384, 0, 16, 8, (256, 256)),
+    "band_from_q0": (256, 512, 256, 4, 16, (128, 128)),
+    "band_wide_key_tiles": (256, 768, 256, 16, 8, (128, 256)),
+    "published_widths": (128, 256, 128, 16, 64, (512, 1024)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_the_index_kernels_are_the_index_scores(case):
+    """`index_scores_pallas` and its three gradients against
+    `index_scores` and `jax.vjp(index_scores)`, float32, interpreted, at
+    the causal pairs (a tile wholly above the diagonal is not computed: it
+    reads 0 and takes no cotangent). Weights of either sign; a key of
+    zeros, whose scores are exactly 0 and hand no gradient on."""
+    from veles_tpu.ops import pallas_kernels as pk
+    tq, keys, q0, hi, di, blocks = INDEX_CASES[case]
+    assert pk.dsa_index_view(keys, hi, di)
+    rng = np.random.default_rng(len(case))
+    g = lambda *sh: jnp.asarray(rng.normal(size=sh), jnp.float32)  # noqa: E731
+    qi, w, ki, ct = g(tq, hi, di), g(tq, hi), g(keys, di), g(tq, keys)
+    assert (np.asarray(w) < 0).any()
+    ki = ki.at[3].set(0.0)
+    causal = np.arange(keys)[None, :] <= (q0 + np.arange(tq))[:, None]
+    ct = jnp.where(causal, ct, 0.0)
+    saved = pk._DSA_INDEX_BLK_Q, pk._DSA_INDEX_BLK_K
+    pk._DSA_INDEX_BLK_Q, pk._DSA_INDEX_BLK_K = blocks
+    try:
+        bq = pk.flash_fit_block(tq, blocks[0])
+        bk = pk.flash_fit_block(keys, blocks[1])
+        assert (case == "tiles_shrink") == ((bq, bk) != tuple(
+            min(b, n) for b, n in zip(blocks, (tq, keys))))
+        want, vjp = jax.vjp(oa.index_scores, qi, w, ki)
+        got, vjp_k = jax.vjp(
+            lambda *a: pk.index_scores_pallas(*a, q0, True), qi, w, ki)
+        grads, grads_k = vjp(ct), vjp_k(ct)
+    finally:
+        pk._DSA_INDEX_BLK_Q, pk._DSA_INDEX_BLK_K = saved
+    want, got = np.asarray(want), np.asarray(got)
+    np.testing.assert_allclose(got[causal], want[causal], rtol=1e-5,
+                               atol=1e-5)
+    assert (want[:, 3] == 0).all() and (got[:, 3][causal[:, 3]] == 0).all()
+    # a tile above the diagonal is written 0, never left as it was
+    above = np.zeros_like(causal)
+    for i in range(0, tq, bq):
+        for j in range(0, keys, bk):
+            above[i:i + bq, j:j + bk] = j > q0 + i + bq - 1
+    assert above.any() == (case.startswith("band") or case in (
+        "tiles_divide", "tiles_shrink")) and not got[above].any()
+    for a, b, name in zip(grads, grads_k, ("dqi", "dw", "dki")):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-4 * float(
+            np.abs(a).max()), err_msg=name)
+    # relu's gradient at a score of exactly 0 is 0, as `jax.nn.relu`'s
+    assert not np.asarray(grads_k[2])[3].any()
+    assert not np.asarray(grads[2])[3].any()
+
+
+def test_a_shape_off_the_index_kernels_view_traces_the_index_scores():
+    """An index head of 12 is no whole sublane tile: inside the
+    `pallas_flash` lowering such an indexer is scored by `index_scores`
+    (no kernel of the indexer's in the trace), the main attention's
+    kernels as before, and the result is the `xla` lowering's."""
+    from veles_tpu.ops import pallas_kernels as pk
+    assert not pk.dsa_index_view(256, 16, 12)
+    assert not pk.dsa_index_view(200, 16, 8)
+    assert pk.dsa_index_view(16384, 16, 64) and pk.dsa_index_view(256, 16, 8)
+    c, h, kv, d, hi = 32, 2, 1, 128, 4
+
+    def run(di, lowering):
+        rng = np.random.default_rng(5)
+        g = lambda *sh: jnp.asarray(rng.normal(size=sh) * 0.3,  # noqa: E731
+                                    jnp.float32)
+        p = dict(w_q=g(c, h * d), w_k=g(c, kv * d), w_v=g(c, kv * d),
+                 q_norm=1 + g(d), k_norm=1 + g(d), w_o=g(h * d, c),
+                 idx_w_q=g(c, hi * di), idx_w_k=g(c, di),
+                 idx_k_norm=1 + g(di), idx_k_bias=g(di), idx_w_w=g(c, hi))
+        x = g(1, 128, c)
+
+        def f(p, x):
+            y, ex = oa.indexed_attention(
+                p, x, n_heads=h, kv_heads=kv, head_dim=d, index_heads=hi,
+                index_dim=di, topk=24, rope_theta=1e4, query_block=64,
+                key_bands=1, lowering=lowering, interpret=True)
+            return y.sum() + 3 * ex["index_loss"]
+        jaxpr = str(jax.make_jaxpr(jax.grad(f))(p, x))
+        return jaxpr, jax.grad(f)(p, x)
+
+    jaxpr, grads = run(12, "pallas_flash")
+    assert "veles_dsa_attend_fwd" in jaxpr and "veles_dsa_pmean" in jaxpr
+    assert "veles_dsa_index" not in jaxpr
+    _, want = run(12, "xla")
+    for name in want:
+        np.testing.assert_allclose(grads[name], want[name], atol=2e-5 * float(
+            np.abs(want[name]).max() + 1), err_msg=name)
+    jaxpr, _ = run(16, "pallas_flash")
+    assert "veles_dsa_index_fwd" in jaxpr and "veles_dsa_index_bwd" in jaxpr
 
 
 def test_the_float8_control_fails_the_heads_gradient():

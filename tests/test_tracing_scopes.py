@@ -203,6 +203,12 @@ PALLAS_SITES = {
     "gmm": (jax.grad(lambda x: _grouped(x).sum()),
             np.ones((64, 128), np.float32),
             ["veles_gmm", "veles_gmm", "veles_tgmm"]),
+    # the indexer's scores of 128 queries against 128 keys under 2 heads
+    # of 8 and their gradient by the queries' side
+    "dsa_index": (jax.grad(lambda w: pk.index_scores_pallas(
+        np.ones((128, 2, 8), np.float32), w, np.ones((128, 8), np.float32),
+        0).sum()), np.ones((128, 2), np.float32),
+        ["veles_dsa_index_fwd", "veles_dsa_index_bwd"]),
 }
 
 
@@ -237,9 +243,9 @@ def test_every_pallas_call_has_its_fixed_name(site):
     assert set(want) <= set(pk.KERNEL_NAMES.values())
 
 
-def test_all_eighteen_kernels_are_named_and_no_name_twice():
+def test_all_twenty_kernels_are_named_and_no_name_twice():
     names = list(pk.KERNEL_NAMES.values())
-    assert len(names) == 18 == len(set(names))
+    assert len(names) == 20 == len(set(names))
     with open(pk.__file__) as f:
         src = f.read()
     assert src.count("pl.pallas_call(") == src.count("name=KERNEL_NAMES[")

@@ -1,11 +1,12 @@
 #!/usr/bin/env python3
-"""What Python pays before XLA sees `xing4_ep8.step`'s train step: the
+"""What Python pays before XLA sees a language-model cell's train step
+(`xing4_ep8.step`; `--config keye2_ep8`: `keye2_ep8.long16k`): the
 seconds of `.trace()` and `.lower()`, the traced step's top-level
 equations, the bytes of StableHLO, and how many bodies and call sites the
-`veles_hc_*` kernels leave in the lowered module.
+`veles_*` kernels leave in the lowered module.
 
 No chip and no device buffer: the step is built from
-`benchmark/configs/xing4_ep8.json` with zero weights and traced at
+`benchmark/configs/<config>.json` with zero weights and traced at
 abstract arguments (ISSUE 34: PR 33 lost 15 s of `setup_s` that neither
 the compile clock nor the device held; tracing and lowering are what the
 persistent compile cache does not skip). The seconds are of THIS host;
@@ -15,6 +16,7 @@ asserts on them through `measure`.
 
     python tools/trace_cost.py                  # what this platform traces
     python tools/trace_cost.py --described --hc xla --hc pallas_one_pass
+    python tools/trace_cost.py --described --config keye2_ep8
 
 `--described` places the arguments on a described v5e (no chip needed) and
 answers the kernels' `available()` as that chip would, so that the Pallas
@@ -34,35 +36,45 @@ from typing import Any, Dict, Optional, Tuple
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def xing4_step(sharding=None) -> Tuple[Any, tuple, Dict[str, Any]]:
+#: the configurations this tool builds: the sample whose `layer_table`
+#: makes the program, and a sequence's targets beside its (batch, seq)
+#: ids (xing4's head also reads the next-next token)
+CONFIGS = {"xing4_ep8": ("xing4", (2,)), "keye2_ep8": ("keye2", ())}
+
+
+def cell_step(sharding=None, config: str = "xing4_ep8"
+              ) -> Tuple[Any, tuple, Dict[str, Any]]:
     """(step, abstract (state, ids, targets, weights), config) of the
     cell's program; `sharding` places every argument (a described chip's
     `SingleDeviceSharding`), None leaves them unplaced."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from veles_tpu.loader.fullbatch import FullBatchLoader
     from veles_tpu.parallel import checkpoint as ck
-    from veles_tpu.samples import xing4
     from veles_tpu.znicz.standard_workflow import StandardWorkflow
+    sample, target_tail = CONFIGS[config]
+    sample = importlib.import_module("veles_tpu.samples." + sample)
     with open(os.path.join(REPO, "benchmark", "configs",
-                           "xing4_ep8.json")) as f:
+                           config + ".json")) as f:
         cfg = json.load(f)
     batch, seq = cfg["batch_per_chip"], cfg["seq_len"]
 
     class ShapeOnlyLoader(FullBatchLoader):
         def load_data(self):
             self.bind_arrays(np.zeros((batch, seq), np.int32),
-                             np.zeros((batch, seq, 2), np.int32), 0, 0,
-                             batch)
+                             np.zeros((batch, seq) + target_tail, np.int32),
+                             0, 0, batch)
 
     wf = StandardWorkflow(
-        layers=xing4.layer_table({**cfg, "init_std": 0.0}),
+        layers=sample.layer_table({**cfg, "init_std": 0.0}),
         loader=ShapeOnlyLoader(minibatch_size=batch, on_device=False),
         loss="softmax", n_classes=cfg["vocab_size"],
         decision_config={"max_epochs": 1, "fail_iterations": 1},
-        gd_config=dict(cfg["optimizer"]), name="xing4_compile")
+        gd_config=dict(cfg["optimizer"]), name=config + "_compile")
     wf.initialize(device=None)
     step = wf.build_fused_step(compute_dtype=cfg["compute_dtype"])
 
@@ -75,11 +87,12 @@ def xing4_step(sharding=None) -> Tuple[Any, tuple, Dict[str, Any]]:
     key = jax.eval_shape(lambda: jax.random.key(0))
     state["key"] = sds(key.shape, key.dtype)
     args = (state, sds((batch, seq), jnp.int32),
-            sds((batch, seq, 2), jnp.int32), sds((batch,), jnp.float32))
+            sds((batch, seq) + target_tail, jnp.int32),
+            sds((batch,), jnp.float32))
     return step, args, cfg
 
 
-def kernel_counts(stablehlo: str, prefix: str = "veles_hc_"
+def kernel_counts(stablehlo: str, prefix: str = "veles_"
                   ) -> Dict[str, Dict[str, int]]:
     """{kernel: {"bodies": custom calls of that name in the module's text,
     "sites": times a function holding one is reached from `main`}}. A
@@ -108,17 +121,18 @@ def kernel_counts(stablehlo: str, prefix: str = "veles_hc_"
     return out
 
 
-def measure(hc: Optional[str] = None, sharding=None) -> Dict[str, Any]:
-    """Trace and lower the step once under the `hc` lowering named (None:
-    what the platform resolves) and count. Returns the numbers and the
-    `lowered` object, so a caller can go on to compile it."""
+def measure(hc: Optional[str] = None, sharding=None,
+            config: str = "xing4_ep8") -> Dict[str, Any]:
+    """Trace and lower `config`'s step once under the `hc` lowering named
+    (None: what the platform resolves) and count. Returns the numbers and
+    the `lowered` object, so a caller can go on to compile it."""
     import jax
 
     from veles_tpu.ops import variants
     if hc is not None:
         variants.select("hc", hc)
     try:
-        step, args, cfg = xing4_step(sharding)
+        step, args, cfg = cell_step(sharding, config)
         table = step.variant_table()
         # at the platform's default precision, as the benchmark runs it
         with jax.default_matmul_precision("bfloat16"):
@@ -132,7 +146,8 @@ def measure(hc: Optional[str] = None, sharding=None) -> Dict[str, Any]:
         if hc is not None:
             variants.clear_selection("hc")
     text = lowered.as_text()
-    return {"hc": table.get("hc"), "trace_s": t1 - t0, "lower_s": t2 - t1,
+    return {"hc": table.get("hc"), "dsa": table.get("dsa"),
+            "trace_s": t1 - t0, "lower_s": t2 - t1,
             "equations": len(traced.jaxpr.eqns),
             "stablehlo_bytes": len(text), "kernels": kernel_counts(text),
             "text": text, "lowered": lowered, "config": cfg, "step": step,
@@ -143,6 +158,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--hc", action="append", default=None,
                     help="an `hc` lowering to trace under (repeatable)")
+    ap.add_argument("--config", choices=sorted(CONFIGS), default="xing4_ep8",
+                    help="the benchmark configuration whose step to build")
     ap.add_argument("--described", action="store_true",
                     help="lower for a described v5e instead of the "
                          "platform's own device")
@@ -159,7 +176,7 @@ def main(argv=None) -> int:
             platform="tpu", topology_name="v5e:2x2").devices[0])
         pk.available = lambda: True
     for hc in ns.hc or [None]:
-        row = measure(hc, sharding)
+        row = measure(hc, sharding, ns.config)
         print("TRACE_COST " + json.dumps(
             {k: v for k, v in row.items()
              if k not in ("text", "lowered", "config", "step", "args")}))

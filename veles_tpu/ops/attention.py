@@ -281,11 +281,14 @@ def float_order_key(x):
 
 #: bits of a threshold one pass of `kth_largest_key` settles. In the step
 #: of keye2_ep8.long16k on a v5e (blocks of 256 queries x up to 16,384
-#: keys, six layers) 2 bits a pass gave a step of 1,552 ms, 4 bits 1,631,
-#: 1 bit (plain bisection) 1,844 (my chip runs, PR 35, one seed, the rest
-#: of the step the same). The search timed ALONE said otherwise (0.161 ms a
-#: block at 1 bit, 0.222 at 2, 0.618 at 4, 3.69 at 8; why was not looked
-#: into): decide in the step
+#: keys, six layers), the index scores coming from a kernel (ISSUE 36: no
+#: producer is left for XLA to fuse the search's reads into), 2 bits a pass
+#: gave a step of 1,321 ms, 4 bits 1,400, 1 bit (plain bisection) 1,621 (my
+#: chip runs, PR 36, one seed, the rest of the step the same; PR 35 read
+#: 1,552 / 1,631 / 1,844 with the scores as an XLA fusion: the same order).
+#: The selection pass timed ALONE still says otherwise (a layer's 13.6 ms
+#: at 1 bit, 14.3 at 2, 28.5 at 4; PR 35: 0.161 / 0.222 / 0.618 ms a
+#: block): decide in the step
 SEARCH_DIGIT_BITS = 2
 
 
@@ -461,28 +464,46 @@ def _dsa_sequence_xla(static, q, w, qi, k, v, ki):
 # The `xla` lowering above writes the 32 heads' float32 scores of a block
 # to HBM and reads them back for every pass of the softmax: 5.4 s a step of
 # keye2_ep8.long16k on a v5e (my chip run, PR 35). Here the main attention
-# is four kernels (`ops/pallas_kernels.py`, `veles_dsa_*`): the flash
-# recurrence over the selection (int8, (S, S): the one thing of that size
-# beside the index scores and the mean-head probabilities the index loss
-# reads, each one head's worth), forward; the mean-head probabilities from
-# the saved logsumexp; dQ; dK and dV. The indexer, the threshold search and
-# the index loss stay plain XLA, a block of queries at a time: XLA's TPU
-# compiler fuses the index scores' relu and head sum into the product.
+# is four kernels (`ops/pallas_kernels.py`, `veles_dsa_attend_*`,
+# `veles_dsa_pmean`): the flash recurrence over the selection (int8, (S, S):
+# the one thing of that size beside the mean-head probabilities the index
+# loss reads, one head's worth), forward; the mean-head probabilities from
+# the saved logsumexp; dQ; dK and dV. The indexer's scores and their
+# gradient are two more (`veles_dsa_index_fwd`, `_bwd`, ISSUE 36), a block
+# of queries a call inside the loops below: the index heads' score tiles
+# stay in VMEM, where as plain XLA the (16 heads, 256, keys) float32
+# scores, their sign and their cotangent went through HBM, 388 ms of the
+# 617 the mechanism cost a step; a shape `pallas_kernels.dsa_index_view`
+# refuses traces `index_scores` in their place. The threshold search and
+# the index loss stay plain XLA over a block's scores (one head's worth).
 
 def _dsa_bands(static, s: int) -> int:
-    """Bands of queries the XLA parts beside the kernels walk a sequence
-    in: as `dsa_tiling` says, each a band a kernel takes (whole tiles of
-    128 keys)."""
+    """Bands of queries the parts beside the main attention walk a
+    sequence in: as `dsa_tiling` says, each a band a kernel takes (whole
+    tiles of 128 keys)."""
     from veles_tpu.ops import pallas_kernels as pk
     tq, bands = static[2], static[3]
     return max(b for b in range(1, bands + 1)
                if s % (b * tq) == 0 and pk.dsa_view(s // b, 128))
 
 
+def _block_index_scores(static, qi, w, ki, q0):
+    """`index_scores` of a block of queries, the sequence's from its
+    `q0`-th on (an int32 scalar, traced or not), against the keys [0, K),
+    right at every causal pair: through the kernels where they take the
+    shape (`pallas_kernels.index_scores_pallas`: a tile wholly above the
+    diagonal reads 0), differentiable either way."""
+    from veles_tpu.ops import pallas_kernels as pk
+    with jax.named_scope("indexer"):
+        if pk.dsa_index_view(ki.shape[0], *qi.shape[1:]):
+            return pk.index_scores_pallas(qi, w, ki, q0, static[4])
+        return index_scores(qi, w, ki)
+
+
 def _dsa_index_pass(static, qi, w, ki):
     """The selection (S, S) int8 of one sequence: a band of queries
     against the keys up to the band's end, a block of queries at a time."""
-    topk, _scale, tq, _bands, _interpret = static
+    topk, tq = static[0], static[2]
     s = qi.shape[0]
     bands = _dsa_bands(static, s)
     per = s // bands
@@ -493,8 +514,7 @@ def _dsa_index_pass(static, qi, w, ki):
 
         def one(xs, keys=keys, kib=kib):
             qib, wb, pb = xs
-            with jax.named_scope("indexer"):
-                index = index_scores(qib, wb, kib)
+            index = _block_index_scores(static, qib, wb, kib, pb[0])
             with jax.named_scope("select"):
                 mask, _ = select_topk(index, keys <= pb[:, None], topk)
             return mask.astype(jnp.int8)
@@ -511,7 +531,8 @@ def _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki, d_kl=None):
     against the keys up to the band's end: the mean-head probabilities of
     a band (`veles_dsa_pmean`) are the one float32 array of (queries,
     keys) there is, 256 MB of it at 16,384 tokens in four bands; the
-    index scores are formed again a block at a time beside them."""
+    index scores are formed again a block at a time beside them, and in
+    the gradient once more (`jax.vjp`: the kernels keep no scores)."""
     from veles_tpu.ops import pallas_kernels as pk
     _topk, scale, tq, _bands, interpret = static
     s = qi.shape[0]
@@ -528,10 +549,9 @@ def _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki, d_kl=None):
                 mask[lo:hi, :hi], scale=scale, q0=lo, interpret=interpret)
         kib = ki[:hi]
 
-        def kl_of(qib, wb, mb, pb):
+        def kl_of(qib, wb, mb, pb, q0b):
             keep = mb != 0
-            with jax.named_scope("indexer"):
-                index = index_scores(qib, wb, kib)
+            index = _block_index_scores(static, qib, wb, kib, q0b)
             with jax.named_scope("index_loss"):
                 log_q = index - jax.nn.logsumexp(
                     jnp.where(keep, index, -jnp.inf), axis=-1, keepdims=True)
@@ -540,10 +560,10 @@ def _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki, d_kl=None):
                     live, pb, 1.0)) - log_q), 0.0).sum()
 
         def grad_of(carry, xs):
-            qib, wb, mb, pb = xs
+            qib, wb, mb, pb, q0b = xs
             keep = mb != 0
-            with jax.named_scope("indexer"):
-                index, vjp = jax.vjp(index_scores, qib, wb, kib)
+            index, vjp = jax.vjp(
+                lambda *a: _block_index_scores(static, *a, q0b), qib, wb, kib)
             with jax.named_scope("index_loss"):
                 # d/dI of sum_s p (log p - I + logsumexp_S(I))
                 soft = jax.nn.softmax(jnp.where(keep, index, -jnp.inf),
@@ -553,8 +573,9 @@ def _dsa_index_loss(static, qh, kh, lse, mask, qi, w, ki, d_kl=None):
                 dqib, dwb, dkib = vjp(d_index)
             return carry + dkib.astype(jnp.float32), (dqib, dwb)
 
-        xs = tuple(_blocks(a, tq) for a in (qi[lo:hi], w[lo:hi],
-                                            mask[lo:hi, :hi], p))
+        xs = tuple(_blocks(a, tq) for a in (
+            qi[lo:hi], w[lo:hi], mask[lo:hi, :hi], p)) \
+            + (jnp.arange(lo, hi, tq, dtype=jnp.int32),)
         if d_kl is None:
             total = total + lax.map(lambda x: kl_of(*x), xs).sum()
         else:

@@ -86,6 +86,22 @@ _HC_BLOCK_BUDGET = 52 << 20
 _DSA_BLK_Q = 512
 _DSA_BLK_K = 1024
 _DSA_VMEM_LIMIT = 48 << 20
+#: the indexer's scores (ISSUE 36): queries and keys a grid step of
+#: `veles_dsa_index_fwd` / `_bwd` holds at most (a block of 256 queries of
+#: a `lax.map` is one row of tiles), all the index heads of them at once:
+#: the heads' float32 score tiles follow one another in VMEM and leave it
+#: as their weighted sum. On a v5e the last band of keye2_ep8.long16k in
+#: ONE call (4,096 queries x 16,384 keys, 16 heads of 64) took 1.99 ms
+#: forward and 5.08 backward at (512, 512), 2.11 / 5.22 at (512, 1024),
+#: 2.09 / 5.14 at (256, 1024), 2.13 / 5.65 at (1024, 1024), 2.07 / 5.76 at
+#: (512, 2048) (my chip run, PR 36): the tile hardly matters, a smaller
+#: one wastes less above the diagonal and compiles in a third of the time.
+#: The backward keeps the keys' whole gradient beside them (16,384 x 64
+#: float32, its lanes padded to 128: 8 MB a buffer), so the pair asks for
+#: more scoped VMEM than the other `dsa` kernels
+_DSA_INDEX_BLK_Q = 512
+_DSA_INDEX_BLK_K = 512
+_DSA_INDEX_VMEM_LIMIT = 64 << 20
 #: grouped products over a sorted buffer (ISSUE 35): rows of the buffer a
 #: grid step of `veles_gmm` / `veles_tgmm` holds, against one group's WHOLE
 #: weight matrix (XLA's own grouped product walks (512, 512, 256) tiles,
@@ -152,6 +168,8 @@ KERNEL_NAMES = {
     "_dsa_pmean_kernel": "veles_dsa_pmean",
     "_dsa_dq_kernel": "veles_dsa_attend_dq",
     "_dsa_dkv_kernel": "veles_dsa_attend_dkv",
+    "_dsa_index_fwd_kernel": "veles_dsa_index_fwd",
+    "_dsa_index_bwd_kernel": "veles_dsa_index_bwd",
     "_gmm_kernel": "veles_gmm",
     "_tgmm_kernel": "veles_tgmm",
 }
@@ -1733,6 +1751,191 @@ def dsa_attend_backward_pallas(q, k, v, do, lse, di, mask, *, scale: float,
         interpret=interpret, name=KERNEL_NAMES["_dsa_dkv_kernel"],
     )(q, k, v, do, lse, di, mask)
     return dq, dk, dv
+
+
+# -- the indexer's scores (ISSUE 36) -------------------------------------------
+# I[t, s] = sum_j w[t, j] relu(qi[t, j] . ki[s]) and its gradient, the index
+# heads' scores of a (queries, keys) tile in VMEM only: nothing of shape
+# (heads, queries, keys) reaches HBM, forward or backward (as plain XLA the
+# float32 score tensor, its sign and its cotangent did, 388 ms a step of
+# keye2_ep8.long16k). The queries are the sequence's from its `q0`-th on,
+# an int32 the kernels read from SMEM (a block of a `lax.map` knows its
+# place only as it runs): a tile wholly above the diagonal is passed over,
+# its operands not fetched: its scores are written 0 (every reader masks
+# them by `causal`), its cotangent is not read.
+
+def dsa_index_view(seq: int, index_heads: int, index_dim: int) -> bool:
+    """Whether `veles_dsa_index_fwd` / `_bwd` take the keys of (a band of)
+    a sequence of `seq` tokens under `index_heads` index heads of
+    `index_dim`: whole 128-lane tiles of keys, the heads' weights in one
+    tile's lanes, a head's width in whole sublanes."""
+    return (seq % _LANE == 0 and 0 < index_heads <= _LANE
+            and index_dim % _MIN_ROW_TILE == 0)
+
+
+def _dsa_index_below(q0_ref, blk_q: int, blk_k: int):
+    """Whether this grid step's (queries, keys) tile holds a causal pair."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    return j * blk_k <= q0_ref[0] + i * blk_q + blk_q - 1
+
+
+def _dsa_index_fwd_kernel(q0_ref, qi_ref, w_ref, ki_ref, o_ref):
+    """Grid (q tiles, k tiles): the heads one after another, each one
+    product, relu, times its column of `w`, added in the heads' order."""
+    below = _dsa_index_below(q0_ref, *o_ref.shape)
+
+    @pl.when(below)
+    def _():
+        kb, w = ki_ref[...], w_ref[...]
+        acc = None
+        for h in range(qi_ref.shape[0]):
+            t = jnp.maximum(_dsa_dot(qi_ref[h], kb, 1, 1), 0.0) \
+                * w[:, h:h + 1]
+            acc = t if acc is None else acc + t
+        o_ref[...] = acc
+
+    @pl.when(jnp.logical_not(below))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _dsa_index_bwd_kernel(q0_ref, qi_ref, w_ref, ki_ref, g_ref, dqi_ref,
+                          dw_ref, dki_ref, dqi_scr):
+    """Grid (q tiles, k tiles), keys innermost, BOTH sequential: the keys'
+    gradient (K, Di) float32 is one block that stays for the whole grid,
+    so one kernel forms a head's score once and spends it on all three
+    gradients (three products a head and tile; cut in two in the manner of
+    `veles_dsa_attend_dq` / `_dkv` it would be four). With m = dI (s > 0):
+    dqi_j += (m w_j) ki, dki += (m w_j)^T qi_j, dw_j += sum_s m s."""
+    i, j, nj = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    blk_q, blk_k = g_ref.shape
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dki_ref[...] = jnp.zeros_like(dki_ref)
+
+    @pl.when(j == 0)
+    def _():
+        dqi_scr[...] = jnp.zeros_like(dqi_scr)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(_dsa_index_below(q0_ref, blk_q, blk_k))
+    def _():
+        kb, w, d = ki_ref[...], w_ref[...], g_ref[...]
+        head = lax.broadcasted_iota(jnp.int32, w.shape, 1)
+        dw = jnp.zeros_like(w)
+        dk = jnp.zeros((blk_k, kb.shape[1]), jnp.float32)
+        for h in range(qi_ref.shape[0]):
+            qh = qi_ref[h]
+            s = _dsa_dot(qh, kb, 1, 1)
+            m = jnp.where(s > 0, d, 0.0)
+            dw = dw + jnp.where(
+                head == h, (m * s).sum(axis=1, keepdims=True), 0.0)
+            g = (m * w[:, h:h + 1]).astype(kb.dtype)
+            dqi_scr[h] = dqi_scr[h] + _dsa_dot(g, kb, 1, 0)
+            dk = dk + _dsa_dot(g, qh, 0, 0)
+        dw_ref[...] += dw
+        rows = pl.ds(pl.multiple_of(j * blk_k, blk_k), blk_k)
+        dki_ref[rows, :] += dk
+
+    @pl.when(j == nj - 1)
+    def _():
+        dqi_ref[...] = dqi_scr[...].astype(dqi_ref.dtype)
+
+
+def _dsa_index_call(kernel, q0, qi, w, ki, more, out_shape, out_blocks,
+                    scratch, interpret: bool):
+    """One of the two kernels over (Tq / bq, K / bk) tiles: qi (Hi, Tq,
+    Di) a row of tiles, w (Tq, Hi) likewise, ki (K, Di) a tile of keys and
+    every array of `more` a (queries, keys) tile, both of which stay on the
+    last tile a row of tiles needs; `out_blocks` name the results' blocks
+    as "heads", "rows", "pairs" or "keys" (whole, resident)."""
+    hi, tq, di = qi.shape
+    bq = flash_fit_block(tq, _DSA_INDEX_BLK_Q)
+    bk = flash_fit_block(ki.shape[0], _DSA_INDEX_BLK_K)
+    nk = ki.shape[0] // bk
+
+    def last(i, q0_ref):
+        return jnp.minimum((q0_ref[0] + i * bq + bq - 1) // bk, nk - 1)
+
+    blocks = {
+        "heads": _vmem((hi, bq, di), lambda i, j, q0_ref: (0, i, 0)),
+        "rows": _vmem((bq, hi), lambda i, j, q0_ref: (i, 0)),
+        "pairs": _vmem((bq, bk), lambda i, j, q0_ref: (i, j)),
+        "keys": _vmem(ki.shape, lambda i, j, q0_ref: (0, 0)),
+    }
+    key_tile = _vmem((bk, di), lambda i, j, q0_ref: (
+        jnp.minimum(j, last(i, q0_ref)), 0))
+    pair_tile = _vmem((bq, bk), lambda i, j, q0_ref: (
+        i, jnp.minimum(j, last(i, q0_ref))))
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(tq // bq, nk),
+            in_specs=[blocks["heads"], blocks["rows"], key_tile]
+            + [pair_tile] * len(more),
+            out_specs=tuple(blocks[b] for b in out_blocks),
+            scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_DSA_INDEX_VMEM_LIMIT),
+        interpret=interpret, name=KERNEL_NAMES[kernel.__name__],
+    )(jnp.asarray(q0, jnp.int32).reshape(1), qi, w, ki, *more)
+
+
+@_hc_jit
+def dsa_index_forward_pallas(qi, w, ki, q0, *, interpret: bool = False):
+    """qi (Hi, Tq, Di) heads first, w (Tq, Hi) float32, ki (K, Di), q0 an
+    int32 scalar -> the index scores (Tq, K) float32 of the queries [q0,
+    q0 + Tq) against the keys [0, K); 0 in the tiles above the diagonal."""
+    return _dsa_index_call(
+        _dsa_index_fwd_kernel, q0, qi, w, ki, (),
+        (jax.ShapeDtypeStruct((qi.shape[1], ki.shape[0]), jnp.float32),),
+        ("pairs",), [], interpret)[0]
+
+
+@_hc_jit
+def dsa_index_backward_pallas(qi, w, ki, q0, d_index, *,
+                              interpret: bool = False):
+    """The same operands and the scores' cotangent (Tq, K) float32 -> (dqi
+    (Hi, Tq, Di) in qi's dtype, dw (Tq, Hi) float32, dki (K, Di) float32,
+    summed over all the queries before anything rounds it)."""
+    return _dsa_index_call(
+        _dsa_index_bwd_kernel, q0, qi, w, ki, (d_index,),
+        (jax.ShapeDtypeStruct(qi.shape, qi.dtype),
+         jax.ShapeDtypeStruct(w.shape, jnp.float32),
+         jax.ShapeDtypeStruct(ki.shape, jnp.float32)),
+        ("heads", "rows", "keys"),
+        [pltpu.VMEM(qi.shape[:1] + (flash_fit_block(
+            qi.shape[1], _DSA_INDEX_BLK_Q), qi.shape[2]), jnp.float32)],
+        interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def index_scores_pallas(qi, w, ki, q0=0, interpret: bool = False):
+    """`ops.attention.index_scores` of the queries [q0, q0 + Tq) against
+    the keys [0, K) through the two kernels: qi (Tq, Hi, Di), w (Tq, Hi)
+    float32, ki (K, Di), q0 an int32 scalar (traced or not) -> (Tq, K)
+    float32, right at every causal pair (a tile wholly above the diagonal
+    reads 0, and hands no gradient on); differentiable in qi, w and ki."""
+    return dsa_index_forward_pallas(jnp.transpose(qi, (1, 0, 2)), w, ki, q0,
+                                    interpret=interpret)
+
+
+def _index_scores_fwd(qi, w, ki, q0, interpret):
+    return index_scores_pallas(qi, w, ki, q0, interpret), (qi, w, ki, q0)
+
+
+def _index_scores_bwd(interpret, res, d_index):
+    qi, w, ki, q0 = res
+    dqi, dw, dki = dsa_index_backward_pallas(
+        jnp.transpose(qi, (1, 0, 2)), w, ki, q0, d_index,
+        interpret=interpret)
+    return (dqi.transpose(1, 0, 2), dw.astype(w.dtype), dki.astype(ki.dtype),
+            None)
+
+
+index_scores_pallas.defvjp(_index_scores_fwd, _index_scores_bwd)
 
 
 # ---------------------------------------------------------------------------
