@@ -196,29 +196,28 @@ def phase_device(want_count: int):
             "count": len(devs)}
 
 
-class CompileClock:
-    """Seconds XLA spent compiling, from jax.monitoring's own events."""
+class CompileCounters:
+    """The program's own compile counters (`veles_compile_*`, written by
+    `veles_tpu/telemetry/compile_stages.py` since `phase_device` turned
+    the compile cache on), as what they grew by since the last `take()`."""
 
     def __init__(self) -> None:
-        import jax.monitoring as mon
-        self.backend = 0.0
-        self.n = 0
-        mon.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event: str, secs: float, **_kw) -> None:
-        if event.endswith("backend_compile_duration"):
-            self.backend += secs
-            self.n += 1
+        self.seen = (0.0, 0)
 
     def take(self):
-        out = (round(self.backend, 2), self.n)
-        self.backend, self.n = 0.0, 0
+        from veles_tpu.telemetry import metrics
+        secs = metrics.family_values("veles_compile_seconds_total") or {}
+        progs = metrics.family_values("veles_compile_programs_total") or {}
+        now = (sum(v for (stage, _), v in secs.items() if stage == "backend"),
+               int(sum(progs.values())))
+        out = (round(now[0] - self.seen[0], 2), now[1] - self.seen[1])
+        self.seen = now
         return out
 
 
 # -- phase: train ------------------------------------------------------------
 
-def phase_train(seed: int, clock: CompileClock):
+def phase_train(seed: int, clock: CompileCounters):
     import jax
     import numpy as np
     c = CFG
@@ -252,6 +251,12 @@ def phase_train(seed: int, clock: CompileClock):
     check(dec.epoch_number == c["epochs"], "train: epochs not completed")
     say(f"train: compile seconds (XLA backend, {n_comp} programs): "
         f"{compile_s}")
+    from veles_tpu.telemetry import metrics
+    for family in ("veles_setup_seconds_total", "veles_compile_seconds_total",
+                   "veles_compile_cache_total"):
+        say(f"train: {family} " + json.dumps(
+            {"/".join(k): round(v, 3) for k, v in sorted(
+                (metrics.family_values(family) or {}).items())}))
     say(f"train: feed_stats={json.dumps(wf.feed_stats, default=str)}")
     check(wf.feed_stats and wf.feed_stats.get("batches", 0) > 0,
           "train: the DeviceFeed fed nothing")
@@ -427,7 +432,7 @@ def _serve_once(snapshot: str, inputs, seed: int) -> dict:
     return got
 
 
-def phase_serve(snapshot: str, seed: int, clock: CompileClock) -> None:
+def phase_serve(snapshot: str, seed: int, clock: CompileCounters) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -625,7 +630,7 @@ def phase_kernels(seed: int, wf) -> None:
 
 # -- four chips: the data-parallel / ZeRO path -------------------------------
 
-def phase_four_chips(seed: int, clock: CompileClock) -> None:
+def phase_four_chips(seed: int, clock: CompileCounters) -> None:
     import jax
     import numpy as np
 
@@ -780,7 +785,7 @@ def main(argv=None) -> int:
     os.makedirs(WORK, exist_ok=True)
     try:
         device = phase_device(4 if args.four_chips else 1)
-        clock = CompileClock()
+        clock = CompileCounters()
         if args.four_chips:
             phase_four_chips(args.seed, clock)
         else:

@@ -23,7 +23,13 @@ mount was empty at survey time (SURVEY.md §"Evidence & Provenance"), so no
 file:line numbers exist to cite.
 """
 
-__version__ = "0.1.0"
+from veles_tpu.telemetry import tracer as _tracer  # stdlib only
 
-from veles_tpu.config import root, Config  # noqa: F401
-from veles_tpu.mutable import Bool  # noqa: F401
+# set-up is measured from here (docs/OBSERVABILITY.md): the phase is this
+# file, first line to last, and the gauge is what came before it
+_tracer.mark_import_age()
+with _tracer.phase("setup.import"):
+    __version__ = "0.1.0"
+
+    from veles_tpu.config import root, Config  # noqa: F401
+    from veles_tpu.mutable import Bool  # noqa: F401

@@ -17,6 +17,14 @@ import numpy as np
 
 from veles_tpu.config import root
 from veles_tpu.logger import Logger
+from veles_tpu.telemetry import tracer as _tracer
+
+
+def _devices():
+    """`jax.devices()`: the program's own first device query starts the
+    backend, unless a caller has started it first."""
+    with _tracer.phase("setup.backend"):
+        return jax.devices()
 
 
 class Device(Logger):
@@ -54,7 +62,7 @@ class XLADevice(Device):
     def __init__(self, devices: Optional[Sequence[Any]] = None,
                  mesh: Optional["jax.sharding.Mesh"] = None) -> None:
         super().__init__()
-        self.devices = list(devices) if devices is not None else jax.devices()
+        self.devices = list(devices) if devices is not None else _devices()
         self.mesh = mesh
         self.platform = self.devices[0].platform if self.devices else "cpu"
 
@@ -75,7 +83,7 @@ class XLADevice(Device):
 
     def __setstate__(self, state):
         self.pid = None
-        self.devices = jax.devices()
+        self.devices = _devices()
         self.platform = self.devices[0].platform if self.devices else "cpu"
         self.mesh = None
         axes = state.get("mesh_axes")

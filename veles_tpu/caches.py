@@ -32,8 +32,14 @@ def enable_compilation_cache() -> str:
     fixed in-checkout directory. First AlexNet compile is tens of
     seconds; later launches in the same place hit the cache (parity
     slot: the reference's on-disk kernel-binary cache, SURVEY.md §2.2).
-    Touches jax.config only — no backend is initialised."""
+    Touches jax.config only — no backend is initialised. Every entry
+    point passes here, so this is also where the process starts to count
+    jax's compile stages and the cache's hits and misses
+    (`telemetry/compile_stages.py`)."""
     import jax
+
+    from veles_tpu.telemetry import compile_stages
+    compile_stages.listen()
     directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not directory:
         directory = cache_path("xla")

@@ -354,10 +354,12 @@ class Launcher(Logger):
         if self.mode == "standalone":
             return
         from veles_tpu.parallel.distributed import initialize_distributed
+        from veles_tpu.telemetry import tracer as _ttracer
         addr = self.listen or self.master
-        initialize_distributed(coordinator=addr,
-                               process_id=self.process_id,
-                               n_processes=self.n_processes)
+        with _ttracer.phase("setup.backend"):   # the wait for the job
+            initialize_distributed(coordinator=addr,
+                                   process_id=self.process_id,
+                                   n_processes=self.n_processes)
 
     # -- the reference's run(load, main) module convention --------------------
 

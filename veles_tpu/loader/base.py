@@ -140,6 +140,7 @@ class Loader(AcceleratedUnit, IDistributable):
             d["emit"] = pristine
         return d
 
+    @_tracer.in_phase("setup.loader")
     def initialize(self, device=None, **kwargs: Any):
         self.load_data()
         # A restored (snapshot-unpickled) loader arrives with its shuffle

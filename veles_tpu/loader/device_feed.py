@@ -212,6 +212,7 @@ class DeviceFeed:
         self._m = _metrics.feed_handles()
 
     @classmethod
+    @_tracer.in_phase("setup.loader")
     def for_step(cls, loader, step, ahead: int = 1) -> "DeviceFeed":
         """Feed wired to `step`'s input shardings (multi-host meshes
         degrade to host handoff — see make_batch_put)."""

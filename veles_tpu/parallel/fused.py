@@ -69,6 +69,7 @@ own hyperparameters from its GD twin.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -556,6 +557,7 @@ class FusedTrainStep:
 
     # -- state <-> unit Arrays ----------------------------------------------
 
+    @_tracer.in_phase("setup.init_state")
     def init_state(self) -> Dict[str, Any]:
         params = tuple(
             {k: jnp.asarray(a.mem) for k, a in u.param_arrays().items()}
@@ -1479,6 +1481,7 @@ class FusedTrainStep:
                 out_specs=(ssp, P(), P()))
         raise ValueError(f"unknown mode {self.mode!r}")
 
+    @_tracer.in_phase("setup.build_step")
     def _build(self) -> None:
         donate = (0,) if self.donate else ()
         axis = {"dp": DATA_AXIS, "seq": (DATA_AXIS, SEQ_AXIS)}.get(
@@ -1535,6 +1538,9 @@ class FusedTrainStep:
                 in_shardings=(self._param_shardings(), xsh, xsh, xsh))
         else:
             raise ValueError(f"unknown mode {self.mode!r}")
+        # each behind its first call's phase (`setup.first_dispatch`)
+        _tracer.FirstCall.on(self, "_train_fn")
+        _tracer.FirstCall.on(self, "_eval_fn")
 
     # -- GSPMD shardings: params TP-sharded over "model", batch over "data" --
 
@@ -1861,6 +1867,8 @@ class FusedTrainStep:
                     donate_argnums=donate)
             else:
                 raise ValueError(f"unknown mode {self.mode!r}")
+            cache[k] = _tracer.FirstCall(
+                cache[k], functools.partial(cache.__setitem__, k))
         return cache[k](state, x, y, w)
 
     def train_accum(self, state, x, y, k: int, w=None):
@@ -1917,6 +1925,8 @@ class FusedTrainStep:
                     donate_argnums=donate)
             else:
                 raise ValueError(f"unknown mode {self.mode!r}")
+            cache[k] = _tracer.FirstCall(
+                cache[k], functools.partial(cache.__setitem__, k))
         with _tracer.span("train.dispatch", "step", self.n_dispatched):
             out = cache[k](state, xs, ys, ws)
         self.n_dispatched += 1
@@ -1972,4 +1982,5 @@ class FusedTrainStep:
                     donate_argnums=donate)
             else:
                 raise ValueError(f"unknown mode {self.mode!r}")
+            _tracer.FirstCall.on(self, "_train_many_fn")
         return self._train_many_fn(state, xs, ys, ws)

@@ -306,6 +306,7 @@ class PipelineTrainStep:
             return jax.jit(lambda t: t, out_shardings=sh)(x)
         return jax.device_put(x, sh)
 
+    @_tracer.in_phase("setup.init_state")
     def init_state(self) -> Dict[str, Any]:
         from veles_tpu import prng
         s = len(self.stages)
@@ -489,6 +490,7 @@ class PipelineTrainStep:
             in_specs=(ssp, P(STAGE_AXIS), P(), P(), P()),
             out_specs=(ssp, P(), P()))
 
+    @_tracer.in_phase("setup.build_step")
     def _build(self) -> None:
 
         def eval_body(params, xs, y, w):
@@ -499,6 +501,9 @@ class PipelineTrainStep:
             eval_body, mesh=self.mesh,
             in_specs=(P(STAGE_AXIS), P(), P(), P()),
             out_specs=(P(), P())))
+        # each behind its first call's phase (`setup.first_dispatch`)
+        _tracer.FirstCall.on(self, "_train_fn")
+        _tracer.FirstCall.on(self, "_eval_fn")
 
     def train(self, state, x, y, w=None):
         if self._train_fn is None:

@@ -615,6 +615,53 @@ def dsa_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
             "causal pairs of the tiles it visited", by_layer))
 
 
+def setup_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
+    """The `veles_setup_*` families `telemetry.tracer.phase` writes at a
+    phase's close, and the gauge `veles_tpu/__init__.py` sets once."""
+    reg = reg or default_registry()
+    by_phase = ("phase",)
+    return SimpleNamespace(
+        seconds=reg.counter(
+            "veles_setup_seconds_total",
+            "a set-up phase's own seconds: its duration less the phases "
+            "it caused on its thread", by_phase),
+        phases=reg.counter("veles_setup_phases_total",
+                           "set-up phases closed", by_phase),
+        age_at_import=reg.gauge(
+            "veles_process_age_at_import_seconds",
+            "the process's age when the package was first imported: "
+            "interpreter start and whatever the caller imported and "
+            "started before it"))
+
+
+def compile_handles(reg: Optional[MetricsRegistry] = None
+                    ) -> SimpleNamespace:
+    """The `veles_compile_*` families `telemetry.compile_stages` writes
+    from jax.monitoring's events; `during` is the innermost set-up phase
+    open on the thread the event arrived on, or ``none``."""
+    reg = reg or default_registry()
+    during = ("during",)
+    return SimpleNamespace(
+        seconds=reg.counter(
+            "veles_compile_seconds_total",
+            "seconds inside jax's trace / lower / backend stages, each "
+            "stage and thread the union of its spans (a function jitted "
+            "once and traced inside another's trace counts once)",
+            ("stage", "during")),
+        programs=reg.counter(
+            "veles_compile_programs_total",
+            "programs handed to the backend: compiled, or read from the "
+            "persistent cache", during),
+        cache=reg.counter(
+            "veles_compile_cache_total",
+            "persistent compile cache: programs read from it (hit) and "
+            "programs compiled and written to it (miss)",
+            ("result", "during")),
+        cache_read_s=reg.counter(
+            "veles_compile_cache_read_seconds_total",
+            "seconds reading and deserialising cache hits", during))
+
+
 def family_values(name: str, reg: Optional[MetricsRegistry] = None
                   ) -> Optional[Dict[Tuple[str, ...], float]]:
     """{label values: value} of one family's children, or None where no

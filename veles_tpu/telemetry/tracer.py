@@ -40,6 +40,17 @@ Design constraints (the hot-path contract):
 - **Thread-safe**: one lock around the ring append; begin/end tokens
   carry their own timestamps so the lock is held for the append only.
 
+Set-up (`phase()`): what happens once, between the package's import and
+a step's first dispatch, is recorded ALWAYS, into one small ring this
+module owns (`setup_ring()`; a process opens some dozens of phases) and
+into ``veles_setup_*`` counters of the default registry. A phase knows its
+cause, the innermost phase open on its thread when it began, and
+`telemetry/compile_stages.py` counts jax's trace, lower and compile
+stages under the phase that is open when they end. Every ring shares ONE
+epoch pair (`perf_counter_ns`, `time_ns`, read when this module is
+imported), so an export of the ``--trace`` ring holds the set-up ring's
+events on its own timeline, to the left of the first ``train.dispatch``.
+
 Profile windows (`ProfileController`): ``--profile-window N:M``
 brackets driver steps N..M (inclusive) with ``jax.profiler``
 start/stop — the on-chip capture path — and ``POST /profile`` on the
@@ -51,6 +62,7 @@ attribute check.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import sys
@@ -58,9 +70,19 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from veles_tpu.telemetry import metrics as _metrics
+
 #: default ring capacity (events); env-overridable for long captures
 _DEFAULT_CAPACITY = int(os.environ.get("VELES_TRACE_CAPACITY",
                                        str(1 << 16)))
+#: the set-up ring's capacity: a process opens some dozens of phases and
+#: jax reports a few hundred compile stages under them
+_SETUP_CAPACITY = 4096
+#: the ONE epoch pair of every ring of this process, (perf_counter_ns,
+#: time_ns) read together: a span's ts is relative to the first, and a
+#: span that arrives as unix seconds (jax's compile stages) is placed by
+#: the second. The only place the two clocks meet.
+_EPOCH = (time.perf_counter_ns(), time.time_ns())
 
 
 class Tracer:
@@ -68,15 +90,15 @@ class Tracer:
 
     def __init__(self, capacity: int = 0) -> None:
         self.capacity = max(256, int(capacity or _DEFAULT_CAPACITY))
-        #: ring slots: (name, cat, ts_us, dur_us, tid, ph, seq)
+        #: ring slots: (name, cat, ts_us, dur_us, tid, ph, seq); `seq` is
+        #: a span's sequence number, or a dict of args (set-up events)
         self._ring: List[Optional[Tuple]] = [None] * self.capacity
         self._n = 0                      # total events ever recorded
         self._lock = threading.Lock()
-        #: perf_counter_ns at construction — every ts is relative to it
-        self._epoch_ns = time.perf_counter_ns()
-        #: wall-clock twin of the epoch (``time.time_ns()``), for
-        #: correlating with logs and with a profiler capture
-        self._epoch_unix_ns = time.time_ns()
+        #: perf_counter_ns every ts is relative to, and its wall-clock
+        #: twin (``time.time_ns()``), for correlating with logs, with a
+        #: profiler capture and with jax's own unix stamps
+        self._epoch_ns, self._epoch_unix_ns = _EPOCH
         self._pid = os.getpid()
 
     # -- recording ------------------------------------------------------------
@@ -105,6 +127,18 @@ class Tracer:
         self._append((name, cat, (t0_s * 1e9 - self._epoch_ns) / 1e3,
                       max(0.0, (t1_s - t0_s) * 1e6),
                       threading.get_ident(), "X", None))
+
+    def add_unix_span(self, name: str, cat: str, start_unix_s: float,
+                      end_unix_s: float, args: Dict[str, Any]) -> None:
+        """Record a span the caller knows by its unix start and end
+        (`time.time()`, as jax.monitoring reports a compile stage): placed
+        on the ring's timeline through the epoch pair, so it is good to
+        what the wall clock drifted from the monotonic one since the
+        module was imported (docs/OBSERVABILITY.md)."""
+        self._append((name, cat,
+                      (start_unix_s * 1e9 - self._epoch_unix_ns) / 1e3,
+                      max(0.0, (end_unix_s - start_unix_s) * 1e6),
+                      threading.get_ident(), "X", args))
 
     def instant(self, name: str, cat: str = "host") -> None:
         """A zero-duration marker (Chrome-trace "i" event)."""
@@ -141,9 +175,12 @@ class Tracer:
 
     def trace_events(self) -> List[Dict[str, Any]]:
         """Chrome-trace event dicts (the `traceEvents` array)."""
+        return self._chrome(self.events())
+
+    def _chrome(self, events: List[Tuple]) -> List[Dict[str, Any]]:
         out: List[Dict[str, Any]] = []
         tids = set()
-        for name, cat, ts, dur, tid, ph, seq in self.events():
+        for name, cat, ts, dur, tid, ph, seq in events:
             tids.add(tid)
             ev: Dict[str, Any] = {"name": name, "cat": cat, "ph": ph,
                                   "ts": round(ts, 3),
@@ -153,7 +190,7 @@ class Tracer:
             else:
                 ev["s"] = "t"           # instant scope: thread
             if seq is not None:
-                ev["args"] = {"seq": seq}
+                ev["args"] = seq if isinstance(seq, dict) else {"seq": seq}
             out.append(ev)
         # thread-name metadata so Perfetto labels the tracks
         names = {t.ident: t.name for t in threading.enumerate()}
@@ -166,9 +203,12 @@ class Tracer:
     def export(self, path: str) -> str:
         """Write the Perfetto/chrome://tracing-loadable JSON (atomic
         replace — a killed run leaves the previous file intact, not a
-        torn one). Returns `path`."""
+        torn one). The installed ring's file (``--trace PATH``) holds
+        the set-up ring's events too: every ring has one epoch, so set-up
+        lies to the left of the first ``train.dispatch``. Returns `path`."""
         doc = {
-            "traceEvents": self.trace_events(),
+            "traceEvents": self._chrome(
+                (_SETUP.events() if self is _ACTIVE else []) + self.events()),
             "displayTimeUnit": "ms",
             "otherData": {
                 "producer": "veles_tpu.telemetry.tracer",
@@ -177,6 +217,8 @@ class Tracer:
                 "epoch_unix_ns": self._epoch_unix_ns,
                 "recorded": self._n,
                 "dropped": self.dropped,
+                "setup_recorded": _SETUP._n,
+                "setup_dropped": _SETUP.dropped,
             },
         }
         tmp = f"{path}.tmp.{os.getpid()}"
@@ -189,6 +231,14 @@ class Tracer:
 # -- process-global tracer (the --trace flag's target) ------------------------
 
 _ACTIVE: Optional[Tracer] = None
+#: the set-up ring: always there, small, holds `phase()`s and the compile
+#: stages counted under them
+_SETUP = Tracer(_SETUP_CAPACITY)
+
+
+def setup_ring() -> Tracer:
+    """The ring that holds this process's set-up (never None)."""
+    return _SETUP
 
 
 def install(capacity: int = 0) -> Tracer:
@@ -276,6 +326,138 @@ def span(name: str, cat: str = "host", seq: Optional[int] = None):
     if tr is None and ann is None:
         return _OFF
     return _Span(tr, name, cat, seq, ann)
+
+
+# -- set-up phases ------------------------------------------------------------
+
+#: the phases open on each thread, outermost first
+_OPEN = threading.local()
+
+
+def current_phase() -> Optional[str]:
+    """The innermost phase open on this thread, or None."""
+    stack = getattr(_OPEN, "stack", None)
+    return stack[-1].name if stack else None
+
+
+class _Phase:
+    """One open set-up phase (see `phase()`)."""
+
+    __slots__ = ("name", "cause", "_t0", "_caused_ns", "_ann")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.cause: Optional[str] = None
+        self._caused_ns = 0     # the phases this one caused, summed
+
+    def __enter__(self) -> "_Phase":
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        self.cause = stack[-1].name if stack else None
+        stack.append(self)
+        ann = _annotation()
+        self._ann = ann(self.name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = _OPEN.stack
+        stack.remove(self)
+        ring, dur = _SETUP, t1 - self._t0
+        ring._append((self.name, "setup", (self._t0 - ring._epoch_ns) / 1e3,
+                      dur / 1e3, threading.get_ident(), "X",
+                      {"cause": self.cause or "none"}))
+        if stack:
+            stack[-1]._caused_ns += dur
+        # by name, at every close: a phase is rare, and a registry a test
+        # has reset must not be written through a stale handle
+        h = _metrics.setup_handles()
+        h.seconds.labels(phase=self.name).inc(
+            max(0, dur - self._caused_ns) / 1e9)
+        h.phases.labels(phase=self.name).inc()
+
+
+def phase(name: str) -> _Phase:
+    """A span of category ``setup`` that records ALWAYS: into the set-up
+    ring (name, start, end, thread and its cause: the innermost phase
+    open on this thread when it began), into any open profiler session,
+    and at its close into ``veles_setup_seconds_total{phase}`` (its OWN
+    seconds: its duration less the phases it caused, so the phases of a
+    thread add up to the time they covered) and
+    ``veles_setup_phases_total{phase}``. For what happens once; the hot
+    spans go through `span()` and pay nothing for this."""
+    return _Phase(name)
+
+
+def in_phase(name: str):
+    """Decorator: every call of the function is the phase `name`."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with _Phase(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
+
+
+class FirstCall:
+    """A compiled function before its first call. That call is the phase
+    `name` (it is where jax traces, lowers and compiles or reads the
+    cache) and hands the function itself to `put`, which stores it where
+    this object stood: every later call is the plain path. Everything but
+    the call (`lower`, `clear_cache`, ...) is the function's own."""
+
+    __slots__ = ("_fn", "_put", "_name")
+
+    def __init__(self, fn, put, name: str = "setup.first_dispatch") -> None:
+        self._fn, self._put, self._name = fn, put, name
+
+    @classmethod
+    def on(cls, owner, attr: str) -> None:
+        """Put the compiled function `owner.<attr>` behind its first
+        call, which stores the function itself back there."""
+        setattr(owner, attr, cls(getattr(owner, attr),
+                                 functools.partial(setattr, owner, attr)))
+
+    def __call__(self, *args):
+        self._put(self._fn)
+        with _Phase(self._name):
+            return self._fn(*args)
+
+    def __getattr__(self, attr):
+        return getattr(self._fn, attr)
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since this process started, from the kernel's own record
+    (`/proc/self/stat`'s start time against `/proc/uptime`); None where
+    there is no such record."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command's closing bracket: state is
+            # the 3rd of the line, the start time (in ticks) the 22nd
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return max(0.0, uptime - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def mark_import_age() -> None:
+    """Set ``veles_process_age_at_import_seconds``: the package's first
+    line calls this, so the gauge is what came before the program:
+    interpreter start, the caller's own imports, and a backend the caller
+    started first."""
+    age = process_age_s()
+    if age is not None:
+        _metrics.setup_handles().age_at_import.set(age)
 
 
 # -- profile windows ----------------------------------------------------------

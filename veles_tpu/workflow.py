@@ -19,6 +19,7 @@ import time
 from collections import deque
 from typing import Any, Optional
 
+from veles_tpu.telemetry import tracer as _tracer
 from veles_tpu.units import Container, TrivialUnit, Unit
 
 
@@ -54,6 +55,7 @@ class Workflow(Container):
 
     # -- lifecycle -----------------------------------------------------------
 
+    @_tracer.in_phase("setup.initialize")
     def initialize(self, device=None, **kwargs: Any) -> None:
         """Initialize all units. Units may return False to be retried after
         the others (mirrors the reference's deferred-initialization loop).

@@ -26,6 +26,7 @@ import numpy as np
 
 from veles_tpu.loader.base import Loader
 from veles_tpu.mutable import Bool
+from veles_tpu.telemetry import tracer as _ttracer
 from veles_tpu.units import Unit
 from veles_tpu.workflow import Repeater, Workflow
 from veles_tpu.znicz import all2all, gd  # noqa: F401 (gd registers pairs)
@@ -289,6 +290,7 @@ class StandardWorkflow(Workflow):
 
     # -- fused/sharded execution (veles_tpu.parallel) -------------------------
 
+    @_ttracer.in_phase("setup.build_step")
     def build_fused_step(self, mesh=None, mode: str = "auto",
                          compute_dtype=None, ep: bool = False,
                          input_normalize=None, zero_sharding="auto"):
@@ -316,6 +318,7 @@ class StandardWorkflow(Workflow):
         return autotune_workflow(self, mesh=mesh,
                                  compute_dtype=compute_dtype, **kwargs)
 
+    @_ttracer.in_phase("setup.build_step")
     def build_pipeline_step(self, mesh, n_microbatches: int = 4,
                             boundaries=None, compute_dtype=None,
                             input_normalize=None):
@@ -479,7 +482,6 @@ class StandardWorkflow(Workflow):
         from veles_tpu.loader.device_feed import DeviceFeed
         from veles_tpu.resilience.faults import active_plan
         from veles_tpu.telemetry import metrics as _tmetrics
-        from veles_tpu.telemetry import tracer as _ttracer
         fault_plan = active_plan()   # None in production: zero per-step cost
         # telemetry plane (docs/OBSERVABILITY.md): the metric instruments
         # are PRE-BOUND here, outside the loop — the hot path pays float
