@@ -552,13 +552,30 @@ def _trace_cost():
     return mod
 
 
-def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip,
-                                                         compiled_pallas):
+@pytest.fixture(scope="module")
+def xing4_step(one_chip):
+    """`xing4_ep8`'s step traced and lowered under the XLA forms and under
+    the kernels, the latter compiled: ONE of each for the tests below
+    (`compiled_pallas`'s answer, held here for the module)."""
+    tc = _trace_cost()
+    prev, pk.available = pk.available, lambda: True
+    try:
+        xla = tc.measure("xla", one_chip, flash="xla_mha")
+        one = tc.measure("pallas_one_pass", one_chip)
+        compiled = one["lowered"].compile()
+    finally:
+        pk.available = prev
+    return {"xla": xla, "one": one, "compiled": compiled,
+            "text": compiled.as_text()}
+
+
+def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_step):
     """`benchmark/configs/xing4_ep8.json` through the sample's layer table,
     `StandardWorkflow` and `FusedTrainStep`: 8,192 tokens, bfloat16, one
     `jax.checkpoint` a block. The grouped products of the held experts
     lower to the TPU's grouped-matmul kernel (`lax.ragged_dot`); the twelve
-    hyper-connections run the four `veles_hc_*` kernels (ISSUE 34). A
+    hyper-connections run the four `veles_hc_*` kernels (ISSUE 34), the
+    six latent-attention sites the three `veles_flash_*` (ISSUE 38). A
     shape or memory fault shows here before a chip is asked. The units
     hold zeros (`init_std` 0: no draw), nothing is put on a device.
 
@@ -566,31 +583,30 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip,
     not wobble under xdist as seconds do (PR 33 inlined a `pallas_call` a
     site, 72 bodies, and was refused for 15 s of `setup_s` that no compile
     clock held): each kernel is jitted once and called a site, and the
-    traced step is no larger than under the `xla` lowering, traced here
+    traced step is no larger than under the XLA lowerings, traced here
     too. The seconds of both are printed; PERF.md quotes them."""
-    tc = _trace_cost()
-    xla = tc.measure("xla", one_chip)
-    one = tc.measure("pallas_one_pass", one_chip)
+    xla, one = xing4_step["xla"], xing4_step["one"]
     assert (xla["hc"], one["hc"]) == ("xla", "pallas_one_pass")
+    assert (xla["flash_attn"], one["flash_attn"]) == ("xla_blocked",
+                                                      "pallas")
     for row in (xla, one):
         print("trace_cost", {k: row[k] for k in (
-            "hc", "trace_s", "lower_s", "equations", "stablehlo_bytes",
-            "kernels")})
+            "hc", "flash_attn", "trace_s", "lower_s", "equations",
+            "stablehlo_bytes", "kernels")})
     assert not xla["kernels"]
     assert one["equations"] <= xla["equations"]
     assert one["stablehlo_bytes"] <= xla["stablehlo_bytes"]
     # a backward kernel's body once; a forward kernel's at most twice: the
     # plain one of the first forward and the one `jax.checkpoint`'s partial
     # evaluation stages for the recomputed forward (derived once, cached)
-    assert {k: v["bodies"] for k, v in one["kernels"].items()} == {
+    hc = {k: v for k, v in one["kernels"].items() if k.startswith("veles_hc")}
+    assert {k: v["bodies"] for k, v in hc.items()} == {
         "veles_hc_pre_fwd": 2, "veles_hc_post_fwd": 2,
         "veles_hc_post_bwd": 1, "veles_hc_pre_bwd": 1}, one["kernels"]
-    assert all(v["sites"] >= 12 for v in one["kernels"].values()), \
-        one["kernels"]
+    assert all(v["sites"] >= 12 for v in hc.values()), one["kernels"]
     cfg, step = one["config"], one["step"]
     assert step.has_aux and step.unit_loss
-    compiled = one["lowered"].compile()
-    txt = compiled.as_text()
+    compiled, txt = xing4_step["compiled"], xing4_step["text"]
     assert "ragged-dot" in txt and "tpu_custom_call" in txt
     for scope in ("/mla/", "/moe/experts/", "/hc_pre/", "/hc_post/",
                   "update/balance", "rematted_computation"):
@@ -619,6 +635,33 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(one_chip,
     # what a v5e's allocator offers: `bytes_limit` of its memory
     # statistics (chip runs of PR 32)
     assert total < 16909336064, total
+
+
+def test_xing4_ep8_attention_core_is_three_kernels_traced_once(xing4_step):
+    """The six latent-attention sites (five blocks and the MTP block) call
+    ONE body of each `veles_flash_*` kernel: each is a module-level jit,
+    and the blocks and the head share ONE `jax.checkpoint` policy object.
+    The policy saves the heads' outputs and the logsumexps, so no forward
+    kernel is left in the recomputed forward; the backward's kernels stand
+    under `mla`, which `step_attn_ms` reads; no (heads, queries, keys)
+    score block is left in the step; and the step's temporaries, 7.13 GB
+    with the blocked XLA form (compiled here for a v5e, PR 38), are 6.11."""
+    import re
+    one, txt = xing4_step["one"], xing4_step["text"]
+    flash = {k: v for k, v in one["kernels"].items()
+             if k.startswith("veles_flash")}
+    assert flash == {k: {"bodies": 1, "sites": 6} for k in (
+        "veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv")}, flash
+    for kernel in flash:
+        paths = set(re.findall(r'op_name="([^"]*%s[^"]*)"' % kernel, txt))
+        assert len(paths) == 6 and all("mla" in re.split(r"[/()]", p_)
+                                       for p_ in paths), sorted(paths)[:3]
+        assert not any("rematted_computation" in p_ for p_ in paths), kernel
+    seq = one["config"]["seq_len"]
+    assert not re.search(r"f32\[\d+,\d+,(1024|%d),(1024|2048|3072|%d)\]"
+                         % (seq, seq), txt)
+    mem = xing4_step["compiled"].memory_analysis()
+    assert mem.temp_size_in_bytes < 6_600_000_000, mem.temp_size_in_bytes
 
 
 def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,

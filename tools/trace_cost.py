@@ -16,6 +16,7 @@ asserts on them through `measure`.
 
     python tools/trace_cost.py                  # what this platform traces
     python tools/trace_cost.py --described --hc xla --hc pallas_one_pass
+    python tools/trace_cost.py --described --flash xla_mha
     python tools/trace_cost.py --described --config keye2_ep8
 
 `--described` places the arguments on a described v5e (no chip needed) and
@@ -122,15 +123,19 @@ def kernel_counts(stablehlo: str, prefix: str = "veles_"
 
 
 def measure(hc: Optional[str] = None, sharding=None,
-            config: str = "xing4_ep8") -> Dict[str, Any]:
-    """Trace and lower `config`'s step once under the `hc` lowering named
-    (None: what the platform resolves) and count. Returns the numbers and
-    the `lowered` object, so a caller can go on to compile it."""
+            config: str = "xing4_ep8", flash: Optional[str] = None
+            ) -> Dict[str, Any]:
+    """Trace and lower `config`'s step once under the `hc` and the
+    `flash_attn` lowering named (None: what the platform resolves) and
+    count. Returns the numbers and the `lowered` object, so a caller can
+    go on to compile it."""
     import jax
 
     from veles_tpu.ops import variants
-    if hc is not None:
-        variants.select("hc", hc)
+    chosen = {op: name for op, name in (("hc", hc), ("flash_attn", flash))
+              if name is not None}
+    for op, name in chosen.items():
+        variants.select(op, name)
     try:
         step, args, cfg = cell_step(sharding, config)
         table = step.variant_table()
@@ -143,10 +148,11 @@ def measure(hc: Optional[str] = None, sharding=None,
             lowered = traced.lower()
             t2 = time.perf_counter()
     finally:
-        if hc is not None:
-            variants.clear_selection("hc")
+        for op in chosen:
+            variants.clear_selection(op)
     text = lowered.as_text()
     return {"hc": table.get("hc"), "dsa": table.get("dsa"),
+            "flash_attn": table.get("flash_attn"),
             "trace_s": t1 - t0, "lower_s": t2 - t1,
             "equations": len(traced.jaxpr.eqns),
             "stablehlo_bytes": len(text), "kernels": kernel_counts(text),
@@ -158,6 +164,10 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--hc", action="append", default=None,
                     help="an `hc` lowering to trace under (repeatable)")
+    ap.add_argument("--flash", default=None,
+                    help="the `flash_attn` lowering to trace latent "
+                         "attention's core under (xla_mha: its blocked "
+                         "XLA form)")
     ap.add_argument("--config", choices=sorted(CONFIGS), default="xing4_ep8",
                     help="the benchmark configuration whose step to build")
     ap.add_argument("--described", action="store_true",
@@ -176,7 +186,7 @@ def main(argv=None) -> int:
             platform="tpu", topology_name="v5e:2x2").devices[0])
         pk.available = lambda: True
     for hc in ns.hc or [None]:
-        row = measure(hc, sharding, ns.config)
+        row = measure(hc, sharding, ns.config, ns.flash)
         print("TRACE_COST " + json.dumps(
             {k: v for k, v in row.items()
              if k not in ("text", "lowered", "config", "step", "args")}))

@@ -188,14 +188,63 @@ def ulysses_attention(q, k, v, axis_name: str,
     return heads_to_seq(oh)
 
 
-#: queries a block of `latent_attention`: a sequence that divides into
-#: such blocks is computed block by block, a shorter one whole
+#: queries a block of `latent_attention`'s XLA form: a sequence that
+#: divides into such blocks is computed block by block, a shorter one whole
 LATENT_QUERY_BLOCK = 1024
+
+#: `jax.ad_checkpoint.checkpoint_name`s of what the flash kernels' backward
+#: (`pallas_kernels.flash_attention_pallas`) needs beside its operands and
+#: a surrounding `jax.checkpoint` should save, not recompute: the heads'
+#: outputs and the queries' logsumexps (8 MB + 0.13 MB a site of
+#: xing4_ep8.step); a policy that saves them leaves the backward pass no
+#: second forward kernel
+FLASH_SAVED = ("flash_out", "flash_lse")
+
+
+def _latent_core_xla(q_nope, q_rope, k_nope, k_rope, v, scale: float):
+    """The core of latent attention, plain XLA: q_nope, k_nope (N, S, H,
+    nope), q_rope (N, S, H, rope), k_rope (N, S, rope) shared by the
+    heads, v (N, S, H, Dv) -> (N, S, H, Dv). A block of queries meets the
+    keys up to its own end only: the blocks above the diagonal are never
+    formed (5/8 of the square at four blocks), and only the diagonal
+    block needs the mask; a block's float32 scores pass through HBM."""
+    s = q_nope.shape[1]
+    block = LATENT_QUERY_BLOCK if s % LATENT_QUERY_BLOCK == 0 else s
+    outs = []
+    for lo in range(0, s, block):
+        hi = lo + block
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope[:, lo:hi],
+                             k_nope[:, :hi],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q_rope[:, lo:hi],
+                               k_rope[:, :hi],
+                               preferred_element_type=jnp.float32)) * scale
+        causal = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
+        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+        outs.append(jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype),
+                               v[:, :hi],
+                               preferred_element_type=jnp.float32))
+    return jnp.concatenate(outs, axis=1).astype(v.dtype)
+
+
+def _latent_core_flash(flash, q_nope, q_rope, k_nope, k_rope, v,
+                       scale: float):
+    """The same through `flash`, a lowering of the registry op
+    `flash_attn`: no (queries, keys) array exists in HBM, forward or
+    backward. A head's key is its own dimensions and the rotary ones all
+    heads share, side by side: 12.6 MB a site of xing4_ep8.step, which
+    the kernels then read as one operand (a contraction of 192)."""
+    n, s, h, _ = q_nope.shape
+    return flash(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, :, None], (n, s, h, k_rope.shape[-1]))], axis=-1),
+        v, scale=scale, causal=True, scope="mla")
 
 
 def latent_attention(p, h, *, n_heads: int, nope: int, rope: int,
                      v_dim: int, cos, sin, scale: float,
-                     norm_eps: float = 1e-6):
+                     norm_eps: float = 1e-6, flash=None):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434,
     section 2.1) over the `n_heads` heads whose up-projections `p` holds:
     h (N, S, C) -> (N, S, C), causal. Queries and keys-values pass
@@ -205,8 +254,11 @@ def latent_attention(p, h, *, n_heads: int, nope: int, rope: int,
     shared by all heads, and reads values of `v_dim`. The down-projections
     are whole whatever the number of heads held, so a share of the heads
     gives its part of the sum `concat(P v) W_O`: nothing is exchanged here.
-    Plain XLA: scores and softmax in float32, a block of queries against
-    its keys at a time."""
+    Scores and softmax in float32, the probabilities rounded to the
+    compute dtype before they meet the values, in both forms of the core:
+    the blocked XLA one, or the flash kernels where the caller hands on
+    `flash`, the registry's `flash_attn` lowering (its rule:
+    `znicz/lm.py::BlockSpec.mla_lowering`)."""
     from veles_tpu.ops.lm import apply_rope, mm, rms_norm
     n, s, _ = h.shape
     c_q = rms_norm(mm(h, p["w_dq"]), p["q_norm"], norm_eps)
@@ -217,26 +269,11 @@ def latent_attention(p, h, *, n_heads: int, nope: int, rope: int,
     kv = mm(c_kv, p["w_ukv"]).reshape(n, s, n_heads, nope + v_dim)
     q_rope = apply_rope(q[..., nope:], cos, sin)
     k_rope = apply_rope(dkv[..., kv_rank:], cos, sin)       # (N, S, rope)
-    # a block of queries meets the keys up to its own end only: the
-    # blocks above the diagonal are never formed (5/8 of the square at
-    # four blocks), and only the diagonal block needs the mask
-    block = LATENT_QUERY_BLOCK if s % LATENT_QUERY_BLOCK == 0 else s
-    outs = []
-    for lo in range(0, s, block):
-        hi = lo + block
-        scores = (jnp.einsum("bqhd,bkhd->bhqk", q[:, lo:hi, :, :nope],
-                             kv[:, :hi, :, :nope],
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bqhd,bkd->bhqk", q_rope[:, lo:hi],
-                               k_rope[:, :hi],
-                               preferred_element_type=jnp.float32)) * scale
-        causal = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
-        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd", probs.astype(h.dtype),
-                               kv[:, :hi, :, nope:],
-                               preferred_element_type=jnp.float32))
-    out = jnp.concatenate(outs, axis=1)
-    return mm(out.astype(h.dtype).reshape(n, s, n_heads * v_dim), p["w_o"])
+    core = _latent_core_xla if flash is None \
+        else functools.partial(_latent_core_flash, flash)
+    out = core(q[..., :nope], q_rope, kv[..., :nope], k_rope,
+               kv[..., nope:], scale)
+    return mm(out.reshape(n, s, n_heads * v_dim), p["w_o"])
 
 
 # -- grouped-query attention over the keys a learned indexer selects -------------

@@ -10,7 +10,9 @@ over the residual streams of the sparse-expert language model
 (`veles_hc_*`, what `xing4_ep8.step` runs at 12 sites a step, each
 traced and lowered once), and since ISSUE 35 attention over selected keys
 (`veles_dsa_*`) and the held experts' grouped products (`veles_gmm`,
-`veles_tgmm`), what `keye2_ep8.long16k` runs.
+`veles_tgmm`), what `keye2_ep8.long16k` runs; since ISSUE 38 the flash
+kernels take keys and values of different widths in the dtype they are
+given, and are the core of `xing4_ep8.step`'s latent attention.
 
 Every kernel has a lax twin in ops.xla / ops.attention — these are
 drop-in replacements gated by `available()`. Interpret mode is something
@@ -22,6 +24,7 @@ tests/test_chip_compile.py compiles every kernel for a described v5e.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import inspect
 from typing import Optional, Tuple
@@ -123,9 +126,14 @@ _SGD_ROW_TILE = 8
 #: VMEM in the backward against the 16M limit
 _LRN_POOL_ROW_TILE = 1
 #: flash-attention block seeds (tuned by hand on v5e 2026-07-29; the
-#: search explores the full blk_q x blk_k x kv_order space around them)
+#: search explores the full blk_q x blk_k x kv_order space around them),
+#: and the scoped VMEM the three kernels ask for: at (512, 1024) the
+#: backward holds four float32 (queries, keys) tiles of 2 MB and their
+#: rounded copies beside the operands' double buffers, over the compiler's
+#: default of 16 MB
 _FLASH_BLK_Q = 512
 _FLASH_BLK_K = 1024
+_FLASH_VMEM_LIMIT = 48 << 20
 
 
 def flash_fit_block(s: int, blk: int) -> int:
@@ -178,6 +186,22 @@ KERNEL_NAMES = {
 def _interpret() -> bool:
     from veles_tpu.ops import variants
     return _FORCE_INTERPRET or variants.pallas_interpret_active()
+
+
+def _kernel_jit(fn):
+    """One trace and one lowering for every site of a step: the arrays
+    are the arguments, every keyword is static."""
+    return jax.jit(fn, static_argnames=tuple(
+        name for name, p in inspect.signature(fn).parameters.items()
+        if p.kind is p.KEYWORD_ONLY))
+
+
+def _vmem(block, index_map):
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+
+#: a masked score: exp(_NEG - m) is 0 for every finite m
+_NEG = -1e30
 
 
 # ---------------------------------------------------------------------------
@@ -717,8 +741,46 @@ lrn_maxpool_pallas.defvjp(_lrn_pool_fwd_rule, _lrn_pool_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
-# blocked (flash-style) attention: tile over KV inside one chip
+# blocked (flash-style) attention: tile over KV inside one chip. Three
+# kernels (forward, dQ, dK/dV) that take their operands in the dtype they
+# are given, bfloat16 products under float32 scores, softmax and
+# accumulators where the model computes in bfloat16, keys of one width and
+# values of another (latent attention: 192 and 128), and under `causal`
+# pass over the tiles above the diagonal, their operands not fetched (the
+# index maps stay on the last tile that is needed). Each is ONE
+# module-level `jax.jit` that every site of a step calls (PR 33's lesson);
+# since ISSUE 38 the six latent-attention sites of `xing4_ep8.step` do.
 # ---------------------------------------------------------------------------
+
+
+def flash_view(seq: int, key_dim: int, value_dim: int) -> bool:
+    """Whether the kernels take a sequence of `seq` tokens under keys of
+    `key_dim` and values of `value_dim` in a compiled step: whole tiles of
+    128 keys, a key in halves of the MXU's depth, values in whole lanes."""
+    return (seq % _LANE == 0 and key_dim % (_LANE // 2) == 0
+            and value_dim % _LANE == 0)
+
+
+def _flash_dot(a, b, ca: int, cb: int):
+    """a . b over axis `ca` of a and `cb` of b, accumulated in float32.
+    bfloat16 operands at the MXU's own precision whatever the ambient
+    default says (under "highest" Mosaic refuses them); float32 ones, the
+    search's and the goldens', at the ambient one."""
+    return lax.dot_general(
+        a, b, (((ca,), (cb,)), ((), ())),
+        precision=lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None,
+        preferred_element_type=jnp.float32)
+
+
+def _flash_scores(q, kb, scale, causal, q0, k0):
+    """The float32 scores of a tile whose first query and key are the
+    sequence's `q0`-th and `k0`-th; -1e30 above the diagonal."""
+    s = _flash_dot(q, kb, 1, 1) * scale
+    if causal:
+        q_idx = q0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_idx = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_idx <= q_idx, s, _NEG)
+    return s
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
@@ -732,9 +794,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
     online softmax is order-invariant, so numerics match to fp rounding;
     the axis exists for the search to probe prefetch locality. With
     `dropped` (the searched `drop` fusion axis, ops/templates.py) a
-    pre-scaled dropout mask streams as a fourth input blocked like Q and
-    multiplies the OUTPUT block in the same final write — the composed
-    path's extra HBM round trip over the attention output disappears."""
+    pre-scaled dropout mask streams as a fourth input blocked like the
+    output and multiplies the OUTPUT block in the same final write — the
+    composed path's extra HBM round trip over the attention output
+    disappears. The probabilities are rounded to the values' dtype before
+    their product, as the XLA forms round them."""
     if dropped:
         mk_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -744,25 +808,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
     nk = pl.num_programs(2)
     # the KV tile actually resident this step (≠ ki under reverse_kv)
     kt = (nk - 1 - ki) if reverse_kv else ki
-    q = q_ref[0]                      # (blk_q, d)
-    kb = k_ref[0]                     # (blk_k, d)
-    vb = v_ref[0]
-    blk_q, blk_k = q.shape[0], kb.shape[0]
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(ki == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, -1e30)
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def compute():
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_idx = qi * blk_q \
-                + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            k_idx = kt * blk_k \
-                + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(k_idx <= q_idx, s, -1e30)
+        vb = v_ref[0]
+        s = _flash_scores(q_ref[0], k_ref[0], scale, causal, qi * blk_q,
+                          kt * blk_k)
         m = m_scr[:]
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -775,8 +832,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
         a = jnp.exp(m - m_new)
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * a + p.sum(axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * a \
-            + jnp.dot(p, vb, preferred_element_type=jnp.float32)
+        acc_scr[:] = acc_scr[:] * a + _flash_dot(p.astype(vb.dtype), vb,
+                                                 1, 0)
 
     if causal:
         # a KV tile whose first key is beyond this Q tile's last query is
@@ -791,41 +848,39 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
         o = acc_scr[:] / l_scr[:]
         if dropped:
             o = o * mk_ref[0].astype(jnp.float32)
-        o_ref[0] = o
-        lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
+        o_ref[0] = o.astype(o_ref.dtype)
+        # the queries' logsumexps leave as a ROW, the queries in the lanes:
+        # as the column they are computed in, (B·H, S, 1) pads to 128
+        # lanes in HBM, 128 times what a surrounding `jax.checkpoint` keeps
+        col = m_scr[:] + jnp.log(l_scr[:])
+        eye = lax.broadcasted_iota(jnp.int32, (blk_q, blk_q), 0) \
+            == lax.broadcasted_iota(jnp.int32, (blk_q, blk_q), 1)
+        lse_ref[0] = jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                      dq_ref, dq_scr, *, scale: float, causal: bool):
     """dQ with the SAME grid/streaming as the forward (KV innermost):
     recompute P = exp(S·scale − lse) per tile from the saved logsumexp,
-    dS = P ⊙ (dO·Vᵀ − D), dQ += dS·K·scale. O(blk) VMEM footprint."""
+    dS = P ⊙ (dO·Vᵀ − D), dQ += dS·K·scale, dS rounded to the keys' dtype
+    before the product. O(blk) VMEM footprint."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
-    q = q_ref[0]
-    kb = k_ref[0]
-    vb = v_ref[0]
-    blk_q, blk_k = q.shape[0], kb.shape[0]
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(ki == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def compute():
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_idx = qi * blk_q \
-                + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            k_idx = ki * blk_k \
-                + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(k_idx <= q_idx, s, -1e30)
+        kb = k_ref[0]
+        s = _flash_scores(q_ref[0], kb, scale, causal, qi * blk_q,
+                          ki * blk_k)
         p = jnp.exp(s - lse_ref[0])                       # (blk_q, blk_k)
-        dp = jnp.dot(do_ref[0], vb.T,
-                     preferred_element_type=jnp.float32)  # (blk_q, blk_k)
+        dp = _flash_dot(do_ref[0], v_ref[0], 1, 1)        # (blk_q, blk_k)
         ds = p * (dp - di_ref[0]) * scale
-        dq_scr[:] = dq_scr[:] + jnp.dot(
-            ds, kb, preferred_element_type=jnp.float32)
+        dq_scr[:] = dq_scr[:] + _flash_dot(ds.astype(kb.dtype), kb, 1, 0)
 
     if causal:
         pl.when(ki * blk_k <= qi * blk_q + blk_q - 1)(compute)
@@ -834,7 +889,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[0] = dq_scr[:]
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
@@ -842,14 +897,12 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                       scale: float, causal: bool):
     """dK/dV with the transposed streaming order — grid (B·H, k_blocks,
     q_blocks), Q innermost: each KV tile stays VMEM-resident while Q/dO
-    tiles stream past. dV += Pᵀ·dO, dK += dSᵀ·Q·scale."""
+    tiles stream past. dV += Pᵀ·dO, dK += dSᵀ·Q·scale, P and dS rounded to
+    the operands' dtype before their products."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
-    q = q_ref[0]
-    kb = k_ref[0]
-    vb = v_ref[0]
-    blk_q, blk_k = q.shape[0], kb.shape[0]
+    blk_q, blk_k = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(qi == 0)
     def _():
@@ -857,21 +910,14 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def compute():
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_idx = qi * blk_q \
-                + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            k_idx = ki * blk_k \
-                + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(k_idx <= q_idx, s, -1e30)
+        q, do = q_ref[0], do_ref[0]
+        s = _flash_scores(q, k_ref[0], scale, causal, qi * blk_q,
+                          ki * blk_k)
         p = jnp.exp(s - lse_ref[0])
-        do = do_ref[0]
-        dv_scr[:] = dv_scr[:] + jnp.dot(
-            p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
+        dv_scr[:] = dv_scr[:] + _flash_dot(p.astype(do.dtype), do, 0, 0)
+        dp = _flash_dot(do, v_ref[0], 1, 1)
         ds = p * (dp - di_ref[0]) * scale
-        dk_scr[:] = dk_scr[:] + jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32)
+        dk_scr[:] = dk_scr[:] + _flash_dot(ds.astype(q.dtype), q, 0, 0)
 
     if causal:
         # a Q tile entirely BEFORE this KV tile contributes nothing
@@ -881,179 +927,192 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
     @pl.when(qi == nq - 1)
     def _():
-        dk_ref[0] = dk_scr[:]
-        dv_ref[0] = dv_scr[:]
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _qspec(blk_q, d):
-    return pl.BlockSpec((1, blk_q, d), lambda bh, i, t: (bh, i, 0),
-                        memory_space=pltpu.VMEM)
+def _flash_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_FLASH_VMEM_LIMIT)
 
 
-def _kspec(blk_k, d):
-    return pl.BlockSpec((1, blk_k, d), lambda bh, i, t: (bh, t, 0),
-                        memory_space=pltpu.VMEM)
-
-
-def _flash_fwd_core(qf, kf, vf, scale, causal, blk_q, blk_k,
-                    kv_order: str = "fwd", mask=None):
-    """(B·H, S, D) f32 in -> (out, lse); lse is (B·H, S, 1). `kv_order`
-    "rev" streams KV tiles last-to-first (searched axis). `mask` (same
-    shape as qf, pre-scaled 0-or-1/keep) applies dropout to the output
-    block inside the kernel's final write (searched `drop` axis)."""
-    bh, s, d = qf.shape
+@_kernel_jit
+def flash_forward_pallas(q, k, v, mask=None, *, scale: float, causal: bool,
+                         blk_q: int, blk_k: int, kv_order: str = "fwd",
+                         interpret: bool = False):
+    """q and k (B·H, S, D), v (B·H, S, Dv) -> (out (B·H, S, Dv) in q's
+    dtype, the queries' logsumexps (B·H, 1, S) float32; the backward
+    kernels take them as (B·H, S, 1)). `kv_order` "rev" streams KV tiles
+    last-to-first (searched axis). `mask` (the output's shape, pre-scaled
+    0-or-1/keep) applies dropout to the output block inside the kernel's
+    final write (searched `drop` axis)."""
+    bh, s, d = q.shape
+    dv = v.shape[-1]
     rev = kv_order == "rev"
     nk = s // blk_k
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
-                               reverse_kv=rev, dropped=mask is not None)
-    if rev:
-        kvspec = pl.BlockSpec((1, blk_k, d),
-                              lambda b, i, t: (b, nk - 1 - t, 0),
-                              memory_space=pltpu.VMEM)
-    else:
-        kvspec = _kspec(blk_k, d)
-    in_specs = [_qspec(blk_q, d), kvspec, kvspec]
-    args = [qf, kf, vf]
+
+    def tile(i, t):
+        """The KV tile of step t of query tile i: under `causal` no later
+        than the last one the queries need."""
+        kt = nk - 1 - t if rev else t
+        return jnp.minimum(kt, (i * blk_q + blk_q - 1) // blk_k) \
+            if causal else kt
+
+    row = lambda b, i, t: (b, i, 0)  # noqa: E731
+    kv = lambda b, i, t: (b, tile(i, t), 0)  # noqa: E731
+    in_specs = [_vmem((1, blk_q, d), row), _vmem((1, blk_k, d), kv),
+                _vmem((1, blk_k, dv), kv)]
+    args = [q, k, v]
     if mask is not None:
-        in_specs.append(_qspec(blk_q, d))
+        in_specs.append(_vmem((1, blk_q, dv), row))
         args.append(mask)
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
+    return pl.pallas_call(
+        functools.partial(_flash_kernel, scale=scale, causal=causal,
+                          reverse_kv=rev, dropped=mask is not None),
+        out_shape=(jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)),
         grid=(bh, s // blk_q, nk),
         in_specs=in_specs,
-        out_specs=(_qspec(blk_q, d), _qspec(blk_q, 1)),
+        out_specs=(_vmem((1, blk_q, dv), row),
+                   _vmem((1, 1, blk_q), lambda b, i, t: (b, 0, i))),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),   # running max
             pltpu.VMEM((blk_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((blk_q, d), jnp.float32),   # unnormalized out
+            pltpu.VMEM((blk_q, dv), jnp.float32),  # unnormalized out
         ],
-        interpret=_interpret(),
+        compiler_params=_flash_params(), interpret=interpret,
         name=KERNEL_NAMES["_flash_kernel"],
     )(*args)
-    return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attn(qf, kf, vf, scale, causal, blk_q, blk_k, kv_order):
-    return _flash_fwd_core(qf, kf, vf, scale, causal, blk_q, blk_k,
-                           kv_order)[0]
+@_kernel_jit
+def flash_dq_pallas(q, k, v, do, lse, di, *, scale: float, causal: bool,
+                    blk_q: int, blk_k: int, interpret: bool = False):
+    """`do` (B·H, S, Dv) the outputs' cotangent, `lse` and `di` (B·H, S, 1)
+    float32 the logsumexps and `do`'s row sums against the outputs ->
+    dq (B·H, S, D) in q's dtype."""
+    bh, s, d = q.shape
+    dv = v.shape[-1]
 
+    def tile(i, t):
+        return jnp.minimum(t, (i * blk_q + blk_q - 1) // blk_k) \
+            if causal else t
 
-def _flash_attn_fwd(qf, kf, vf, scale, causal, blk_q, blk_k, kv_order):
-    out, lse = _flash_fwd_core(qf, kf, vf, scale, causal, blk_q, blk_k,
-                               kv_order)
-    return out, (qf, kf, vf, out, lse)
-
-
-def _flash_attn_bwd(scale, causal, blk_q, blk_k, kv_order, res, do):
-    qf, kf, vf, out, lse = res
-    do = do.astype(jnp.float32)
-    # D_i = rowsum(dO ⊙ O) — the softmax-jacobian diagonal term; tiny
-    # elementwise reduce, XLA fuses it, no kernel needed
-    di = jnp.sum(do * out, axis=-1, keepdims=True)        # (bh, s, 1)
-    return _flash_bwd_pallas(qf, kf, vf, do, lse, di, scale, causal,
-                             blk_q, blk_k)
-
-
-def _flash_bwd_pallas(qf, kf, vf, do, lse, di, scale, causal,
-                      blk_q, blk_k):
-    """The two backward pallas_calls (dQ, then dK/dV on the transposed
-    grid) — shared by the plain and dropout-fused custom-VJP pairs."""
-    bh, s, d = qf.shape
-    lspec = pl.BlockSpec((1, blk_q, 1), lambda b, i, t: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
+    row = lambda b, i, t: (b, i, 0)  # noqa: E731
+    kv = lambda b, i, t: (b, tile(i, t), 0)  # noqa: E731
+    return pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         grid=(bh, s // blk_q, s // blk_k),
-        in_specs=[_qspec(blk_q, d), _kspec(blk_k, d), _kspec(blk_k, d),
-                  _qspec(blk_q, d), lspec, lspec],
-        out_specs=_qspec(blk_q, d),
+        in_specs=[_vmem((1, blk_q, d), row), _vmem((1, blk_k, d), kv),
+                  _vmem((1, blk_k, dv), kv), _vmem((1, blk_q, dv), row),
+                  _vmem((1, blk_q, 1), row), _vmem((1, blk_q, 1), row)],
+        out_specs=_vmem((1, blk_q, d), row),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        interpret=_interpret(),
+        compiler_params=_flash_params(), interpret=interpret,
         name=KERNEL_NAMES["_flash_dq_kernel"],
-    )(qf, kf, vf, do, lse, di)
-    # transposed grid: KV outer, Q inner (indices (b, t, i) name the
-    # (kv, q) block pair, so the q-side specs index with the LAST axis)
-    qspec_t = pl.BlockSpec((1, blk_q, d), lambda b, t, i: (b, i, 0),
-                           memory_space=pltpu.VMEM)
-    kspec_t = pl.BlockSpec((1, blk_k, d), lambda b, t, i: (b, t, 0),
-                           memory_space=pltpu.VMEM)
-    lspec_t = pl.BlockSpec((1, blk_q, 1), lambda b, t, i: (b, i, 0),
-                           memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
+    )(q, k, v, do, lse, di)
+
+
+@_kernel_jit
+def flash_dkv_pallas(q, k, v, do, lse, di, *, scale: float, causal: bool,
+                     blk_q: int, blk_k: int, interpret: bool = False):
+    """-> (dk (B·H, S, D), dv (B·H, S, Dv)) in the operands' dtypes, on the
+    transposed grid: KV outer, Q inner (the grid indices (b, t, i) name
+    the (kv, q) block pair)."""
+    bh, s, d = q.shape
+    dv = v.shape[-1]
+
+    def tile(t, i):
+        """The Q tile of step i of KV tile t: under `causal` no earlier
+        than the first one that meets the keys."""
+        return jnp.maximum(i, (t * blk_k) // blk_q) if causal else i
+
+    row = lambda b, t, i: (b, tile(t, i), 0)  # noqa: E731
+    kv = lambda b, t, i: (b, t, 0)  # noqa: E731
+    return pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, causal=causal),
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),) * 2,
+        out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
+                   jax.ShapeDtypeStruct((bh, s, dv), v.dtype)),
         grid=(bh, s // blk_k, s // blk_q),
-        in_specs=[qspec_t, kspec_t, kspec_t, qspec_t, lspec_t, lspec_t],
-        out_specs=(kspec_t, kspec_t),
+        in_specs=[_vmem((1, blk_q, d), row), _vmem((1, blk_k, d), kv),
+                  _vmem((1, blk_k, dv), kv), _vmem((1, blk_q, dv), row),
+                  _vmem((1, blk_q, 1), row), _vmem((1, blk_q, 1), row)],
+        out_specs=(_vmem((1, blk_k, d), kv), _vmem((1, blk_k, dv), kv)),
         scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
-                        pltpu.VMEM((blk_k, d), jnp.float32)],
-        interpret=_interpret(),
+                        pltpu.VMEM((blk_k, dv), jnp.float32)],
+        compiler_params=_flash_params(), interpret=interpret,
         name=KERNEL_NAMES["_flash_dkv_kernel"],
-    )(qf, kf, vf, do, lse, di)
-    return dq, dk, dv
+    )(q, k, v, do, lse, di)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_attn(qf, kf, vf, mf, static, scope):
+    """(B·H, S, D) operands, `mf` the pre-scaled dropout mask of the
+    dropout-fused form or None, `static` the kernels' keywords as a sorted
+    tuple of pairs, `scope` the `jax.named_scope` the backward opens (a
+    custom_vjp's backward is traced outside the forward's)."""
+    return _flash_attn_fwd(qf, kf, vf, mf, static, scope)[0]
+
+
+def _flash_attn_fwd(qf, kf, vf, mf, static, scope):
+    from jax.ad_checkpoint import checkpoint_name
+
+    from veles_tpu.ops.attention import FLASH_SAVED
+    out, lse = flash_forward_pallas(qf, kf, vf, mf, **dict(static))
+    out = checkpoint_name(out, FLASH_SAVED[0])
+    lse = checkpoint_name(lse[:, 0, :], FLASH_SAVED[1])
+    return out, (qf, kf, vf, mf, out, lse)
+
+
+def _flash_attn_bwd(static, scope, res, g):
+    qf, kf, vf, mf, out, lse = res
+    kw = {k: v for k, v in static if k != "kv_order"}
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        # grad wrt the UNMASKED attention output is dO = g ⊙ mask (dropout
+        # backward); the softmax-jacobian diagonal D = rowsum(dO ⊙ O)
+        # equals rowsum(g ⊙ O·mask), so the MASKED output the forward
+        # saved feeds it directly — no unmasked residual needed. A tiny
+        # elementwise reduce, XLA fuses it, no kernel needed
+        do = g if mf is None else (g * mf).astype(g.dtype)
+        di = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                     axis=-1, keepdims=True)              # (bh, s, 1)
+        args = (qf, kf, vf, do, lse[..., None], di)
+        dq = flash_dq_pallas(*args, **kw)
+        dk, dv = flash_dkv_pallas(*args, **kw)
+    # the mask is RNG output, nothing upstream consumes its gradient
+    return dq, dk, dv, None if mf is None else jnp.zeros_like(mf)
 
 
 _flash_attn.defvjp(_flash_attn_fwd, _flash_attn_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_attn_drop(qf, kf, vf, mf, scale, causal, blk_q, blk_k,
-                     kv_order):
-    """Dropout-fused flash attention: the pre-scaled mask multiplies the
-    output block inside the forward kernel's final write."""
-    return _flash_fwd_core(qf, kf, vf, scale, causal, blk_q, blk_k,
-                           kv_order, mask=mf)[0]
-
-
-def _flash_attn_drop_fwd(qf, kf, vf, mf, scale, causal, blk_q, blk_k,
-                         kv_order):
-    out, lse = _flash_fwd_core(qf, kf, vf, scale, causal, blk_q, blk_k,
-                               kv_order, mask=mf)
-    return out, (qf, kf, vf, mf, out, lse)
-
-
-def _flash_attn_drop_bwd(scale, causal, blk_q, blk_k, kv_order, res, g):
-    qf, kf, vf, mf, out_m, lse = res
-    g = g.astype(jnp.float32)
-    # grad wrt the UNMASKED attention output is dO = g ⊙ mask (dropout
-    # backward); the softmax-jacobian diagonal D = rowsum(dO ⊙ O) equals
-    # rowsum(g ⊙ O·mask), so the MASKED output the forward saved feeds
-    # it directly — no unmasked residual needed
-    do = g * mf
-    di = jnp.sum(g * out_m, axis=-1, keepdims=True)
-    dq, dk, dv = _flash_bwd_pallas(qf, kf, vf, do, lse, di, scale,
-                                   causal, blk_q, blk_k)
-    # the mask is RNG output, nothing upstream consumes its gradient
-    return dq, dk, dv, jnp.zeros_like(mf)
-
-
-_flash_attn_drop.defvjp(_flash_attn_drop_fwd, _flash_attn_drop_bwd)
-
-
 def flash_attention_pallas(q, k, v, scale: Optional[float] = None,
                            causal: bool = False, blk_q: int = _FLASH_BLK_Q,
                            blk_k: int = _FLASH_BLK_K,
-                           kv_order: str = "fwd", drop_mask=None):
+                           kv_order: str = "fwd", drop_mask=None,
+                           scope: Optional[str] = None):
     """Intra-chip blocked attention, DIFFERENTIABLE (custom-VJP pair of
-    Pallas kernels). q/k/v: (B, S, H, D) -> (B, S, H, D). Requires
-    S % 128 == 0 (pad upstream). Grid (B·H, S/blk_q, S/blk_k), KV
+    Pallas kernels). q/k: (B, S, H, D), v: (B, S, H, Dv) -> (B, S, H, Dv),
+    in the operands' dtype (a caller that wants float32 products casts).
+    Requires S % 128 == 0 (pad upstream). Grid (B·H, S/blk_q, S/blk_k), KV
     innermost, so the (S, S) score matrix never materializes — O(S·D)
     memory instead of O(S²). The backward is recompute-based: the forward
-    saves only the per-row logsumexp; dQ streams KV tiles (same grid as
+    saves only the output and the per-row logsumexp (named `FLASH_SAVED`
+    for a surrounding `jax.checkpoint`); dQ streams KV tiles (same grid as
     forward), dK/dV streams Q tiles on the transposed grid. Forward block
     defaults tuned on v5e (2026-07-29: 22 ms vs 51 ms for the XLA einsum
     path at B1·S16384·H8·D64 causal — 2.3× — while small-S workloads
     should just use ops.attention). `blk_q`/`blk_k`/`kv_order` are the
     searched tuning axes (ops/templates.py); kv_order applies to the
     forward's KV streaming (the backward keeps its own fixed orders).
-    `drop_mask` ((B, S, H, D), pre-scaled 0-or-1/keep — the dropout
+    `drop_mask` ((B, S, H, Dv), pre-scaled 0-or-1/keep — the dropout
     registry op's output) fuses the dropout over the attention output
     into the kernel's final write (the searched `drop` axis; gated by
-    the composed `ops.reference.attn_dropout_forward` golden)."""
+    the composed `ops.reference.attn_dropout_forward` golden). `scope`
+    names the `jax.named_scope` the backward's operations stand under
+    (the caller's own, which a custom-VJP backward does not inherit)."""
     b, s, h, d = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(d)
@@ -1062,21 +1121,16 @@ def flash_attention_pallas(q, k, v, scale: Optional[float] = None,
         f"seq len {s} must be divisible by 128 (got blocks {blk_q},{blk_k})"
 
     def heads_first(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
-    if drop_mask is None:
-        out = _flash_attn(heads_first(q).astype(jnp.float32),
-                          heads_first(k).astype(jnp.float32),
-                          heads_first(v).astype(jnp.float32),
-                          float(scale), causal, blk_q, blk_k, kv_order)
-    else:
-        out = _flash_attn_drop(
-            heads_first(q).astype(jnp.float32),
-            heads_first(k).astype(jnp.float32),
-            heads_first(v).astype(jnp.float32),
-            heads_first(jnp.asarray(drop_mask)).astype(jnp.float32),
-            float(scale), causal, blk_q, blk_k, kv_order)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3).astype(q.dtype)
+    static = dict(scale=float(scale), causal=bool(causal), blk_q=blk_q,
+                  blk_k=blk_k, kv_order=kv_order, interpret=_interpret())
+    out = _flash_attn(
+        heads_first(q), heads_first(k), heads_first(v),
+        None if drop_mask is None
+        else heads_first(jnp.asarray(drop_mask)).astype(q.dtype),
+        tuple(sorted(static.items())), scope)
+    return out.reshape(b, h, s, -1).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -1407,15 +1461,7 @@ def _hc_geometry(x, n: int) -> Tuple[int, int]:
     return tile, _largest_divisor(c, _LANE, _HC_LANE_SLAB)
 
 
-def _hc_jit(fn):
-    """One trace and one lowering for every site of a step: the arrays
-    are the arguments, every keyword is static."""
-    return jax.jit(fn, static_argnames=tuple(
-        name for name, p in inspect.signature(fn).parameters.items()
-        if p.kind is p.KEYWORD_ONLY))
-
-
-@_hc_jit
+@_kernel_jit
 def hc_pre_forward_pallas(x, pt, aff, *, n: int, iters: int, eps: float,
                           clamp: Tuple[float, float], norm_eps: float,
                           interpret: bool = False):
@@ -1434,7 +1480,7 @@ def hc_pre_forward_pallas(x, pt, aff, *, n: int, iters: int, eps: float,
         slab=slab, iters=iters, eps=eps, clamp=clamp, norm_eps=norm_eps)
 
 
-@_hc_jit
+@_kernel_jit
 def hc_pre_backward_pallas(x, gx, dh, raw, dm, pt, aff, *, n: int,
                            iters: int, eps: float,
                            clamp: Tuple[float, float],
@@ -1452,7 +1498,7 @@ def hc_pre_backward_pallas(x, gx, dh, raw, dm, pt, aff, *, n: int,
         slab=slab, iters=iters, eps=eps, clamp=clamp)
 
 
-@_hc_jit
+@_kernel_jit
 def hc_post_forward_pallas(x, y, m, *, n: int, interpret: bool = False):
     """x (T, n*C), y (T, C), the maps m (T, kp) float32 -> the streams."""
     tile, slab = _hc_geometry(x, n)
@@ -1461,7 +1507,7 @@ def hc_post_forward_pallas(x, y, m, *, n: int, interpret: bool = False):
         [(x.shape, x.dtype)], (True,), tile, interpret, n=n, slab=slab)[0]
 
 
-@_hc_jit
+@_kernel_jit
 def hc_post_backward_pallas(g, x, y, m, *, n: int, interpret: bool = False):
     """-> (dx (T, n*C), dy (T, C), dm (T, kp) float32)."""
     tile, slab = _hc_geometry(x, n)
@@ -1484,9 +1530,6 @@ def hc_post_backward_pallas(g, x, y, m, *, n: int, interpret: bool = False):
 # probabilities the index loss reads (`veles_dsa_pmean`, one head's worth).
 # Each entry point is ONE module-level `jax.jit` (PR 33's lesson).
 # ---------------------------------------------------------------------------
-
-_NEG = -1e30
-
 
 def dsa_view(seq: int, head_dim: int) -> bool:
     """Whether the kernels take a sequence of `seq` tokens and heads of
@@ -1633,11 +1676,7 @@ def _dsa_params(semantics):
                                 vmem_limit_bytes=_DSA_VMEM_LIMIT)
 
 
-def _vmem(block, index_map):
-    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
-
-
-@_hc_jit
+@_kernel_jit
 def dsa_attend_forward_pallas(q, k, v, mask, *, scale: float,
                               interpret: bool = False):
     """q (H, T, D), k and v (Hkv, T, D), mask (T, T) int8 -> (the heads'
@@ -1670,7 +1709,7 @@ def dsa_attend_forward_pallas(q, k, v, mask, *, scale: float,
     )(q, k, v, mask)
 
 
-@_hc_jit
+@_kernel_jit
 def dsa_pmean_pallas(q, k, lse, mask, *, scale: float, q0: int = 0,
                      interpret: bool = False):
     """The queries q (H, Tq, D), the sequence's from its `q0`-th on, with
@@ -1696,7 +1735,7 @@ def dsa_pmean_pallas(q, k, lse, mask, *, scale: float, q0: int = 0,
     )(q, k, lse, mask)
 
 
-@_hc_jit
+@_kernel_jit
 def dsa_attend_backward_pallas(q, k, v, do, lse, di, mask, *, scale: float,
                                interpret: bool = False):
     """`do` (H, T, D) the outputs' cotangent, `di` (H, T, 1) float32 its
@@ -1883,7 +1922,7 @@ def _dsa_index_call(kernel, q0, qi, w, ki, more, out_shape, out_blocks,
     )(jnp.asarray(q0, jnp.int32).reshape(1), qi, w, ki, *more)
 
 
-@_hc_jit
+@_kernel_jit
 def dsa_index_forward_pallas(qi, w, ki, q0, *, interpret: bool = False):
     """qi (Hi, Tq, Di) heads first, w (Tq, Hi) float32, ki (K, Di), q0 an
     int32 scalar -> the index scores (Tq, K) float32 of the queries [q0,
@@ -1894,7 +1933,7 @@ def dsa_index_forward_pallas(qi, w, ki, q0, *, interpret: bool = False):
         ("pairs",), [], interpret)[0]
 
 
-@_hc_jit
+@_kernel_jit
 def dsa_index_backward_pallas(qi, w, ki, q0, d_index, *,
                               interpret: bool = False):
     """The same operands and the scores' cotangent (Tq, K) float32 -> (dqi
@@ -2083,7 +2122,7 @@ def _gmm_tile(rows: int, a: int, b: int, dtype) -> int:
     return tile
 
 
-@_hc_jit
+@_kernel_jit
 def gmm_pallas(x, w, group, tile_of, lo, hi, n, *, transposed: bool = False,
                interpret: bool = False):
     """x (R, A) and w (G, A, B) -> (R, B) in x's dtype; `transposed`: x
@@ -2102,7 +2141,7 @@ def gmm_pallas(x, w, group, tile_of, lo, hi, n, *, transposed: bool = False,
         ((tile, out), lambda g, t: (t, 0)), [], interpret)
 
 
-@_hc_jit
+@_kernel_jit
 def tgmm_pallas(x, dy, group, tile_of, lo, hi, n, *, groups: int,
                 interpret: bool = False):
     """x (R, A) and dy (R, B) -> (groups, A, B) in x's dtype: every

@@ -399,12 +399,13 @@ def _dtype_width(dtype) -> int:
 # -- flash_attn: block shapes + KV streaming order --------------------------
 
 def _flash_build(cfg):
-    def apply(q, k, v, scale=None, causal=False, drop_mask=None):
+    def apply(q, k, v, scale=None, causal=False, drop_mask=None,
+              scope=None):
         from veles_tpu.ops import pallas_kernels as pk
         return pk.flash_attention_pallas(
             q, k, v, scale=scale, causal=causal, blk_q=cfg["blk_q"],
             blk_k=cfg["blk_k"], kv_order=cfg["kv_order"],
-            drop_mask=drop_mask if cfg["drop"] else None)
+            drop_mask=drop_mask if cfg["drop"] else None, scope=scope)
     #: the contract/bench read the fuse axis off the closure so a fused
     #: point is exercised (and timed) WITH its mask leg
     apply.fusion_drop = cfg["drop"]

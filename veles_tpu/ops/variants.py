@@ -757,20 +757,26 @@ register(Variant("grad_reduce", "hier2",
 
 
 # -- blocked flash attention (intra-chip tile loop) -------------------------
-#    apply(q, k, v, scale=None, causal=False) -> (B, S, H, D);
-#    differentiable (the pallas variants are custom-VJP kernel pairs).
+#    apply(q, k, v, scale=None, causal=False) -> (B, S, H, Dv);
+#    differentiable (the pallas variants are custom-VJP kernel pairs, in
+#    the operands' dtype, values of a width of their own; `scope` names
+#    the scope their backward stands under).
 #    MultiHeadAttention consults resolve("flash_attn") on its local path
 #    when the flash gate says long-S beats the einsum; generated
 #    candidates over blk_q x blk_k x kv_order come from ops.templates.
+#    Latent attention (`znicz/lm.py::BlockSpec.mla_lowering`) runs its
+#    core through the resolved kernels where `pallas_kernels.flash_view`
+#    admits the shape, and its own blocked XLA form otherwise.
 
 def _flash_xla_mha(q, k, v, scale=None, causal=False):
     from veles_tpu.ops import attention as oa
     return oa.mha_forward(q, k, v, scale=scale, causal=causal)
 
 
-def _flash_pallas(q, k, v, scale=None, causal=False):
+def _flash_pallas(q, k, v, scale=None, causal=False, scope=None):
     from veles_tpu.ops import pallas_kernels as pk
-    return pk.flash_attention_pallas(q, k, v, scale=scale, causal=causal)
+    return pk.flash_attention_pallas(q, k, v, scale=scale, causal=causal,
+                                     scope=scope)
 
 
 register_op(
@@ -783,7 +789,8 @@ register(Variant("flash_attn", "xla_mha", _flash_xla_mha,
                      "); right for short S — O(S^2) score matrix"))
 register(Variant("flash_attn", "pallas", _flash_pallas, pallas=True,
                  doc="hand-written incumbent: blk 512/1024, forward KV "
-                     "order (= templates seed)"))
+                     "order (= templates seed); each of its three "
+                     "kernels jitted once for all sites"))
 
 
 # -- fused SGD weight update (the step's optimizer leg) ---------------------

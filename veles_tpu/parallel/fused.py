@@ -1749,6 +1749,11 @@ class FusedTrainStep:
                 else variants.resolve(op, unit=u).name
             if name is not None:
                 table[op] = name
+            # a unit that resolves further ops at trace time (a block's
+            # latent attention beside its hyper-connections) names them
+            more = getattr(u, "variant_more", None)
+            if more is not None:
+                table.update(more())
         for i, j, v in pairs:
             a, b = self.forwards[i], self.forwards[j]
             if getattr(a, "variant_op", None) == "lrn":

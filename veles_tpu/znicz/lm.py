@@ -29,6 +29,9 @@ the streams), `mlp`, or `moe/router`, `moe/dispatch`, `moe/experts`,
 `hc_*` scopes are opened by `ops.lm.hyper_connection`, whose lowering
 (`xla` or the kernels of `pallas_one_pass`) the registry resolves at trace
 time from the platform and `BlockSpec.hc_lowering` reports by the shape.
+Latent attention's core (scores, softmax, values) likewise: the registry
+op `flash_attn`'s kernels where `BlockSpec.mla_lowering` admits the shape,
+the blocked XLA form of `ops.attention.latent_attention` otherwise.
 """
 
 from __future__ import annotations
@@ -140,6 +143,22 @@ class BlockSpec:
             if not pk.hc_view(tokens, self.c, self.n):
                 return "xla"
         return v.name
+
+    def mla_lowering(self, seq: int) -> Optional[str]:
+        """What the core of latent attention traces over sequences of
+        `seq` tokens: the registry's `flash_attn` lowering where it is a
+        kernel the platform runs and `pallas_kernels.flash_view` admits
+        the shape, else `xla_blocked`, the blocked XLA form (this
+        attention's fallback in the place of the op's `xla_mha`); None
+        for another kind of attention."""
+        if self.attention != "latent":
+            return None
+        v = variants.resolve("flash_attn", unit=self)
+        if v.pallas:
+            from veles_tpu.ops import pallas_kernels as pk
+            if pk.flash_view(seq, self.nope + self.rope, self.v_dim):
+                return v.name
+        return "xla_blocked"
 
     def dsa_lowering(self, seq: int) -> Optional[str]:
         """The `dsa` lowering a trace of sequences of `seq` tokens takes;
@@ -271,13 +290,16 @@ class BlockSpec:
                 seq, inv_freq,
                 ol.yarn_mscale(factor, rs.get("mscale", 1)) / all_dim)
             hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
+            lowering = self.mla_lowering(seq)
             y = oa.latent_attention(
                 {k[len("attn_"):]: v for k, v in p.items()
                  if k.startswith("attn_")},
                 hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
                 nope=self.nope, rope=self.rope, v_dim=self.v_dim, cos=cos,
                 sin=sin, scale=(self.nope + self.rope) ** -0.5
-                * all_dim * all_dim, norm_eps=self.norm_eps)
+                * all_dim * all_dim, norm_eps=self.norm_eps,
+                flash=None if lowering == "xla_blocked"
+                else variants.get("flash_attn", lowering).apply)
             return y.reshape(h.shape), None
 
     def _indexed_attention(self, p: Dict[str, Any], h, batch: int):
@@ -417,12 +439,16 @@ def _wide(acc) -> int:
     return (int(acc[0]) << WIDE) + int(acc[1])
 
 
-#: ONE object for every block: `jax.checkpoint` splits a jitted kernel's
+#: what a block's `jax.checkpoint` saves, so that the backward pass does
+#: not attend a second time: the names of either kind of attention (a
+#: lowering that names nothing saves nothing). ONE object for every block
+#: and for the head's MTP block: `jax.checkpoint` splits a jitted kernel's
 #: jaxpr by its policy and caches the split by the policy's identity, so a
 #: policy made anew a block leaves the step with a copy of every kernel's
 #: body a block (28 of `veles_dsa_pmean` in the six-block step, lowered
 #: here for a described v5e)
-_DSA_POLICY = jax.checkpoint_policies.save_only_these_names(*oa.DSA_SAVED)
+_SAVED_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *oa.DSA_SAVED, *oa.FLASH_SAVED)
 
 
 def _gaussian(unit):
@@ -444,6 +470,17 @@ def _hc_effective(unit) -> Optional[str]:
     if unit.variant_op == "dsa":
         return unit.spec.dsa_lowering(s)
     return unit.spec.hc_lowering(n * s)
+
+
+def _more_effective(unit) -> Dict[str, str]:
+    """The registry ops the unit resolves at trace time beside its
+    `variant_op`, for `variant_table()`: the core of its latent
+    attention (`flash_attn`)."""
+    if unit.spec is None or not unit.input:
+        return {}
+    unit.spec.allow_pallas = getattr(unit, "allow_pallas", True)
+    name = unit.spec.mla_lowering(unit.input.shape[1])
+    return {"flash_attn": name} if name else {}
 
 
 class _LMUnit(Forward):
@@ -518,6 +555,7 @@ class HCBlock(_LMUnit):
     #: nothing here, the platform and the shape decide
     variant_op = "hc"
     variant_effective = _hc_effective
+    variant_more = _more_effective
 
     def __init__(self, workflow=None, streams: int = 1, **kwargs: Any
                  ) -> None:
@@ -552,14 +590,11 @@ class HCBlock(_LMUnit):
             self.output.reset(np.zeros((n, s, width), np.float32))
         return super().initialize(device=device, **kwargs)
 
-    @property
-    def fused_remat_policy(self):
-        """What the step's `jax.checkpoint` around this unit saves:
-        indexed attention's thresholds and outputs, so that the backward
-        pass neither selects nor attends a second time."""
-        if self.spec is None or self.spec.attention != "indexed":
-            return None
-        return _DSA_POLICY
+    #: what the step's `jax.checkpoint` around this unit saves: indexed
+    #: attention's thresholds and outputs, the flash kernels' outputs and
+    #: logsumexps, so that the backward pass neither selects nor attends a
+    #: second time
+    fused_remat_policy = staticmethod(_SAVED_POLICY)
 
     def fused_apply(self, params, x, *, key=None, train=True, aux=None):
         self.spec.allow_pallas = getattr(self, "allow_pallas", True)
@@ -614,6 +649,7 @@ class LMHead(_LMUnit):
     fused_remat = False
     variant_op = "hc"               # the MTP block's two (`HCBlock`)
     variant_effective = _hc_effective
+    variant_more = _more_effective
 
     def __init__(self, workflow=None, vocab: int = 256, streams: int = 1,
                  loss_chunk: int = 1024, mtp: Optional[Dict[str, Any]] = None,
@@ -715,7 +751,7 @@ class LMHead(_LMUnit):
                 h = ol.mm(joined, p["mtp_w_proj"])
                 x2 = jnp.tile(h, (1, self.streams)).reshape(n, s, -1)
             self.spec.allow_pallas = getattr(self, "allow_pallas", True)
-            block = jax.checkpoint(self.spec.apply)
+            block = jax.checkpoint(self.spec.apply, policy=_SAVED_POLICY)
             x2, counted = block(
                 {k[len("mtp_"):]: v for k, v in p.items()
                  if k.startswith("mtp_")}, x2,
