@@ -19,9 +19,24 @@ every test file; everything built from it (shardings, meshes, shapes)
 is built in fixtures or tests; compiles happen in the test's own
 process; the persistent compilation cache is off around them; and all of
 it lives in this ONE file so one worker owns the library.
+
+What that one worker pays for on every run (ISSUE 39): tier-1 holds the
+kernels compiled at published widths (seconds each), AlexNet's whole
+steps, and a language-model cell's step traced and lowered; the
+whole-step compile at a cell's size is `slow`, run by name
+(`pytest -m slow tests/test_chip_compile.py -k <config>`) before a
+change's first chip call, and guarded on every PR by the cell itself,
+which the driver compiles and runs on the chip (`hbm_peak_gb`, the
+kernels' rooflines; a step that does not fit fails the cell). A
+configuration costs this file its trace, 15-25 s, not the 200 of its
+compile: its tier-1 test asks of `tools/trace_cost.py`'s `measure` what
+is known before `.compile()`, and a twin marked `slow` beside it, on the
+same lowered step, asks the compiled program the rest.
 """
 
 import os
+import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -539,7 +554,11 @@ def test_dp_default_alexnet_train_step_compiles_for_2x2(topo, alexnet,
         < 8 * n_params + (256 << 20)
 
 
-# -- the sparse-expert language model's step at its published widths (ISSUE 32) --
+# -- the language-model cells' steps at their published widths (ISSUE 32, 35) --
+#
+# Each cell twice (ISSUE 39): what `measure` gives before `.compile()`, in
+# tier-1 under the test's old name; and the compiled program's memory and
+# text, in a twin marked `slow` on the same lowered step.
 
 def _trace_cost():
     """`tools/trace_cost.py` as a module (tools/ is no package)."""
@@ -552,39 +571,114 @@ def _trace_cost():
     return mod
 
 
-@pytest.fixture(scope="module")
-def xing4_step(one_chip):
-    """`xing4_ep8`'s step traced and lowered under the XLA forms and under
-    the kernels, the latter compiled: ONE of each for the tests below
-    (`compiled_pallas`'s answer, held here for the module)."""
-    tc = _trace_cost()
+def _lowered(*args, **kw):
+    """`measure`'s row of one trace and one lowering for the described
+    chip (`compiled_pallas`'s answer, for a fixture that lives a module),
+    its text kept as the module's locations name it (`debug_text`: the
+    scope paths of every operation and call site)."""
     prev, pk.available = pk.available, lambda: True
     try:
-        xla = tc.measure("xla", one_chip, flash="xla_mha")
-        one = tc.measure("pallas_one_pass", one_chip)
-        compiled = one["lowered"].compile()
+        row = _trace_cost().measure(*args, **kw)
     finally:
         pk.available = prev
-    return {"xla": xla, "one": one, "compiled": compiled,
-            "text": compiled.as_text()}
+    del row["text"]             # (the counts are taken; 150 MB for keye2_ep8)
+    row["debug_text"] = row["lowered"].as_text(debug_info=True)
+    return row
 
 
-def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_step):
+def _compiled(row):
+    """The lowered step of `row` compiled for the described chip: the one
+    XLA compile of a whole step at a cell's size, minutes here."""
+    t0 = time.perf_counter()
+    compiled = row["lowered"].compile()
+    return {"compiled": compiled, "text": compiled.as_text(),
+            "compile_s": time.perf_counter() - t0}
+
+
+def _n_leaves(row):
+    return sum(int(np.prod(a.shape)) for layer in row["args"][0]["params"]
+               for a in layer.values())
+
+
+def _units_calling(txt, scope, fn):
+    """The units (`L03.hc_block` ...) with a call site of the jitted kernel
+    wrapper `fn` under `scope`, read from the lowered module's locations."""
+    paths = set(re.findall(r'loc\("([^"]*/%s/jit\(%s\))"' % (scope, fn), txt))
+    return {m for p_ in paths for m in re.findall(r"L\d\d\.\w+", p_)}, paths
+
+
+@pytest.fixture(scope="module")
+def xing4_lowered(one_chip):
+    """`xing4_ep8`'s step traced and lowered under the kernels: ONE trace
+    for the tier-1 tests below and for the slow twins."""
+    return _lowered("pallas_one_pass", one_chip)
+
+
+@pytest.fixture(scope="module")
+def xing4_step(one_chip, xing4_lowered):
+    """That step compiled, and the step traced and lowered under the XLA
+    forms to compare with: ONE of each for the slow twins below."""
+    xla = _lowered("xla", one_chip, flash="xla_mha")
+    return {"xla": xla, "one": xing4_lowered, **_compiled(xing4_lowered)}
+
+
+def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_lowered):
     """`benchmark/configs/xing4_ep8.json` through the sample's layer table,
     `StandardWorkflow` and `FusedTrainStep`: 8,192 tokens, bfloat16, one
-    `jax.checkpoint` a block. The grouped products of the held experts
-    lower to the TPU's grouped-matmul kernel (`lax.ragged_dot`); the twelve
-    hyper-connections run the four `veles_hc_*` kernels (ISSUE 34), the
+    `jax.checkpoint` a block, traced and lowered for a described v5e (the
+    compile of what is lowered here, and whether it fits, is
+    `test_xing4_ep8_compiled_step_fits_one_chip`'s, `slow`). The grouped
+    products of the held experts lower to `lax.ragged_dot`; the twelve
+    hyper-connections call the four `veles_hc_*` kernels (ISSUE 34), the
     six latent-attention sites the three `veles_flash_*` (ISSUE 38). A
-    shape or memory fault shows here before a chip is asked. The units
-    hold zeros (`init_std` 0: no draw), nothing is put on a device.
+    shape fault shows here before a chip is asked. The units hold zeros
+    (`init_std` 0: no draw), nothing is put on a device.
 
     What Python pays before XLA sees the step is held to COUNTS, which do
     not wobble under xdist as seconds do (PR 33 inlined a `pallas_call` a
     site, 72 bodies, and was refused for 15 s of `setup_s` that no compile
-    clock held): each kernel is jitted once and called a site, and the
-    traced step is no larger than under the XLA lowerings, traced here
-    too. The seconds of both are printed; PERF.md quotes them."""
+    clock held): each kernel is jitted once and called a site. The seconds
+    are printed; PERF.md quotes them."""
+    one = xing4_lowered
+    assert (one["hc"], one["flash_attn"]) == ("pallas_one_pass", "pallas")
+    print("trace_cost", {k: one[k] for k in (
+        "hc", "flash_attn", "trace_s", "lower_s", "equations",
+        "stablehlo_bytes", "kernels")})
+    # a backward kernel's body once; a forward kernel's at most twice: the
+    # plain one of the first forward and the one `jax.checkpoint`'s partial
+    # evaluation stages for the recomputed forward (derived once, cached)
+    hc = {k: v for k, v in one["kernels"].items() if k.startswith("veles_hc")}
+    assert {k: v["bodies"] for k, v in hc.items()} == {
+        "veles_hc_pre_fwd": 2, "veles_hc_post_fwd": 2,
+        "veles_hc_post_bwd": 1, "veles_hc_pre_bwd": 1}, one["kernels"]
+    assert all(v["sites"] >= 12 for v in hc.values()), one["kernels"]
+    cfg, step = one["config"], one["step"]
+    assert step.has_aux and step.unit_loss
+    assert _n_leaves(one) == cfg["n_params"]
+    txt = one["debug_text"]
+    assert "ragged_dot" in txt and "tpu_custom_call" in txt
+    for scope in ("/mla/", "/moe/experts/", "/hc_pre/", "/hc_post/",
+                  "update/balance", "rematted_computation"):
+        assert scope in txt, scope
+    # every site's call of a kernel stands under the scope `step_hc_ms`
+    # reads (XLA inlines the calls: the compiled text's paths are the twin's)
+    for fn, side in (("hc_pre_forward_pallas", "hc_pre"),
+                     ("hc_pre_backward_pallas", "hc_pre"),
+                     ("hc_post_forward_pallas", "hc_post"),
+                     ("hc_post_backward_pallas", "hc_post")):
+        units, paths = _units_calling(txt, side, fn)
+        assert len(units) >= 5, (fn, sorted(paths)[:3])
+
+
+@pytest.mark.slow
+def test_xing4_ep8_compiled_step_fits_one_chip(xing4_step):
+    """The step `test_xing4_ep8_train_step_compiles_and_fits_one_chip`
+    lowers, compiled for the described v5e: the grouped products are the
+    TPU's grouped-matmul kernel, every site's kernel carries its unit's
+    path, and the arguments, results and temporaries fit one chip. A
+    memory fault shows here before a chip is asked; on every PR the cell
+    `xing4_ep8.step` holds it on the chip (`hbm_peak_gb`). The traced
+    step is no larger than under the XLA lowerings, traced here too."""
     xla, one = xing4_step["xla"], xing4_step["one"]
     assert (xla["hc"], one["hc"]) == ("xla", "pallas_one_pass")
     assert (xla["flash_attn"], one["flash_attn"]) == ("xla_blocked",
@@ -596,16 +690,7 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_step):
     assert not xla["kernels"]
     assert one["equations"] <= xla["equations"]
     assert one["stablehlo_bytes"] <= xla["stablehlo_bytes"]
-    # a backward kernel's body once; a forward kernel's at most twice: the
-    # plain one of the first forward and the one `jax.checkpoint`'s partial
-    # evaluation stages for the recomputed forward (derived once, cached)
-    hc = {k: v for k, v in one["kernels"].items() if k.startswith("veles_hc")}
-    assert {k: v["bodies"] for k, v in hc.items()} == {
-        "veles_hc_pre_fwd": 2, "veles_hc_post_fwd": 2,
-        "veles_hc_post_bwd": 1, "veles_hc_pre_bwd": 1}, one["kernels"]
-    assert all(v["sites"] >= 12 for v in hc.values()), one["kernels"]
-    cfg, step = one["config"], one["step"]
-    assert step.has_aux and step.unit_loss
+    cfg = one["config"]
     compiled, txt = xing4_step["compiled"], xing4_step["text"]
     assert "ragged-dot" in txt and "tpu_custom_call" in txt
     for scope in ("/mla/", "/moe/experts/", "/hc_pre/", "/hc_post/",
@@ -613,7 +698,6 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_step):
         assert scope in txt, scope
     # after XLA inlines the calls every site's kernel carries its own path
     # under the scope `step_hc_ms` reads
-    import re
     for kernel, side in (("veles_hc_pre_fwd", "hc_pre"),
                          ("veles_hc_pre_bwd", "hc_pre"),
                          ("veles_hc_post_fwd", "hc_post"),
@@ -625,11 +709,9 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_step):
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print("xing4 step: generated code",
-          mem.generated_code_size_in_bytes, "B, temporaries",
-          mem.temp_size_in_bytes, "B")
-    assert sum(int(np.prod(a.shape)) for layer in one["args"][0]["params"]
-               for a in layer.values()) == cfg["n_params"]
+    print("xing4 step: compiled in", round(xing4_step["compile_s"], 1),
+          "s; generated code", mem.generated_code_size_in_bytes,
+          "B, temporaries", mem.temp_size_in_bytes, "B, in all", total, "B")
     # parameters and velocity, float32: 8 B a parameter of arguments
     assert mem.argument_size_in_bytes > 8 * cfg["n_params"]
     # what a v5e's allocator offers: `bytes_limit` of its memory
@@ -637,22 +719,36 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_step):
     assert total < 16909336064, total
 
 
-def test_xing4_ep8_attention_core_is_three_kernels_traced_once(xing4_step):
+def test_xing4_ep8_attention_core_is_three_kernels_traced_once(xing4_lowered):
     """The six latent-attention sites (five blocks and the MTP block) call
     ONE body of each `veles_flash_*` kernel: each is a module-level jit,
     and the blocks and the head share ONE `jax.checkpoint` policy object.
     The policy saves the heads' outputs and the logsumexps, so no forward
-    kernel is left in the recomputed forward; the backward's kernels stand
-    under `mla`, which `step_attn_ms` reads; no (heads, queries, keys)
-    score block is left in the step; and the step's temporaries, 7.13 GB
-    with the blocked XLA form (compiled here for a v5e, PR 38), are 6.11."""
-    import re
-    one, txt = xing4_step["one"], xing4_step["text"]
+    kernel is called in the recomputed forward; every call stands under
+    `mla`, which `step_attn_ms` reads. (That no score block is left in the
+    compiled step, and its temporaries, is
+    `test_xing4_ep8_compiled_attention_core_leaves_no_score_block`'s,
+    `slow`.)"""
+    one = xing4_lowered
     flash = {k: v for k, v in one["kernels"].items()
              if k.startswith("veles_flash")}
     assert flash == {k: {"bodies": 1, "sites": 6} for k in (
         "veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv")}, flash
-    for kernel in flash:
+    for fn in ("flash_forward_pallas", "flash_dq_pallas", "flash_dkv_pallas"):
+        units, paths = _units_calling(one["debug_text"], "mla", fn)
+        assert len(paths) == len(units) == 6, sorted(paths)[:3]
+        assert not any("rematted_computation" in p_ for p_ in paths), fn
+
+
+@pytest.mark.slow
+def test_xing4_ep8_compiled_attention_core_leaves_no_score_block(xing4_step):
+    """In the compiled step the three `veles_flash_*` kernels stand at six
+    paths each, under `mla`, none in the recomputed forward; no (heads,
+    queries, keys) score block is left in the step; and the step's
+    temporaries, 7.13 GB with the blocked XLA form (compiled here for a
+    v5e, PR 38), are 6.11."""
+    one, txt = xing4_step["one"], xing4_step["text"]
+    for kernel in ("veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv"):
         paths = set(re.findall(r'op_name="([^"]*%s[^"]*)"' % kernel, txt))
         assert len(paths) == 6 and all("mla" in re.split(r"[/()]", p_)
                                        for p_ in paths), sorted(paths)[:3]
@@ -664,55 +760,86 @@ def test_xing4_ep8_attention_core_is_three_kernels_traced_once(xing4_step):
     assert mem.temp_size_in_bytes < 6_600_000_000, mem.temp_size_in_bytes
 
 
-def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
-                                                         compiled_pallas):
+#: `keye2_ep8`'s kernels and the bodies of each in the lowered module:
+#: every kernel's body once, called a site (one jit each, ONE checkpoint
+#: policy object for the six blocks); the mean-head probabilities by band
+#: of queries, four shapes, forward and backward; the index scores likewise
+#: (the selection's and the loss's calls of a band share one body), their
+#: gradient in the backward alone; the grouped products either width
+#: first, on the fast rows and on the whole buffer: forward, again where
+#: the backward recomputes, and the other way round
+KEYE2_BODIES = (("veles_dsa_attend_fwd", 1), ("veles_dsa_pmean", 8),
+                ("veles_dsa_index_fwd", 8), ("veles_dsa_index_bwd", 4),
+                ("veles_dsa_attend_dq", 1), ("veles_dsa_attend_dkv", 1),
+                ("veles_gmm", 12), ("veles_tgmm", 4))
+# (a block of queries is the body of a `lax.map`: `dsa/while/body/
+# closed_call/select/...`; the readers match whole components)
+KEYE2_SCOPES = ("/dsa/qkv/", "/dsa/indexer/", "/select/", "/attend/",
+                "/index_loss/", "/moe/router/", "/moe/experts/",
+                "/moe/balance_loss/", "rematted_computation",
+                "veles_dsa_attend_fwd", "veles_dsa_pmean",
+                "veles_dsa_attend_dq", "veles_dsa_attend_dkv",
+                "veles_dsa_index_fwd", "veles_dsa_index_bwd",
+                "veles_gmm", "veles_tgmm")
+
+
+@pytest.fixture(scope="module")
+def keye2_lowered(one_chip):
+    """`keye2_ep8`'s step traced and lowered under what the described chip
+    resolves: ONE trace for the tier-1 test below and for its slow twin."""
+    return _lowered(None, one_chip, "keye2_ep8")
+
+
+@pytest.fixture(scope="module")
+def keye2_step(keye2_lowered):
+    return {"row": keye2_lowered, **_compiled(keye2_lowered)}
+
+
+def test_keye2_ep8_train_step_compiles_and_fits_one_chip(keye2_lowered):
     """`benchmark/configs/keye2_ep8.json` through the sample's layer table,
     `StandardWorkflow` and `FusedTrainStep`: ONE sequence of 16,384
     tokens, bfloat16, one `jax.checkpoint` a block that saves the indexed
     attention's thresholds, logsumexps and outputs; the main attention as
     the four `veles_dsa_*` kernels (`dsa: pallas_flash`), the indexer's
-    scores and their gradient as two more (ISSUE 36: nothing of (16 heads,
-    queries, keys) is left in the step), the held experts' products as
-    `veles_gmm` / `veles_tgmm` (`grouped: pallas`). ONE compile:
-    memory is known before the first chip call (ISSUE 35). The units hold
-    zeros (`init_std` 0: no draw), nothing is put on a device."""
-    import time
-    row = _trace_cost().measure(None, one_chip, "keye2_ep8")
-    cfg, step, lowered = row["config"], row["step"], row["lowered"]
+    scores and their gradient as two more (ISSUE 36), the held experts'
+    products as `veles_gmm` / `veles_tgmm` (`grouped: pallas`). Traced and
+    lowered for a described v5e, ONE trace (the compile of what is lowered
+    here, and its memory, is `test_keye2_ep8_compiled_step_fits_one_chip`'s,
+    `slow`). The units hold zeros (`init_std` 0: no draw), nothing is put
+    on a device."""
+    row = keye2_lowered
+    cfg, step = row["config"], row["step"]
     assert step.has_aux and step.unit_loss
     assert row["dsa"] == "pallas_flash"
-    assert sum(int(np.prod(a.shape)) for layer in row["args"][0]["params"]
-               for a in layer.values()) == cfg["n_params"] == 659190016
-    t1 = time.perf_counter()
-    compiled = lowered.compile()
-    txt = compiled.as_text()
-    import re
-    assert "ragged-dot" not in txt and "tpu_custom_call" in txt
-    # (a block of queries is the body of a `lax.map`: `dsa/while/body/
-    # closed_call/select/...`; the readers match whole components)
-    for scope in ("/dsa/qkv/", "/dsa/indexer/", "/select/", "/attend/",
-                  "/index_loss/", "/moe/router/", "/moe/experts/",
-                  "/moe/balance_loss/", "rematted_computation",
-                  "veles_dsa_attend_fwd", "veles_dsa_pmean",
-                  "veles_dsa_attend_dq", "veles_dsa_attend_dkv",
-                  "veles_dsa_index_fwd", "veles_dsa_index_bwd",
-                  "veles_gmm", "veles_tgmm"):
+    assert _n_leaves(row) == cfg["n_params"] == 659190016
+    print("keye2 step: traced and lowered in",
+          round(row["trace_s"] + row["lower_s"], 1), "s")
+    txt = row["debug_text"]
+    assert "ragged_dot" not in txt and "tpu_custom_call" in txt
+    for scope in KEYE2_SCOPES:
         assert scope in txt, scope
-    # every kernel's body once in the module, called a site (one jit each,
-    # ONE checkpoint policy object for the six blocks); the mean-head
-    # probabilities by band of queries, four shapes, forward and backward;
-    # the index scores likewise (the selection's and the loss's calls of a
-    # band share one body), their gradient in the backward alone
-    for kernel, bodies in (("veles_dsa_attend_fwd", 1),
-                           ("veles_dsa_pmean", 8),
-                           ("veles_dsa_index_fwd", 8),
-                           ("veles_dsa_index_bwd", 4),
-                           ("veles_dsa_attend_dq", 1),
-                           ("veles_dsa_attend_dkv", 1),
-                           # either width first, on the fast rows and on
-                           # the whole buffer: forward, again where the
-                           # backward recomputes, and the other way round
-                           ("veles_gmm", 12), ("veles_tgmm", 4)):
+    for kernel, bodies in KEYE2_BODIES:
+        assert row["kernels"][kernel]["bodies"] == bodies, row["kernels"]
+    # the recomputed forward neither selects nor attends again: the
+    # thresholds and outputs are saved (`ops.attention.DSA_SAVED`)
+    assert not re.search(r'rematted_computation[^"]*/dsa/while', txt)
+    assert cfg["query_block"] == 256
+    assert cfg["sa_config"]["indexer_num_heads"] == 16
+
+
+@pytest.mark.slow
+def test_keye2_ep8_compiled_step_fits_one_chip(keye2_step):
+    """The step `test_keye2_ep8_train_step_compiles_and_fits_one_chip`
+    lowers, compiled for the described v5e. ONE compile: memory is known
+    before the first chip call (ISSUE 35); nothing of (16 heads, queries,
+    keys) is left in the step (ISSUE 36). On every PR the cell
+    `keye2_ep8.long16k` holds the memory on the chip (`hbm_peak_gb`)."""
+    row, compiled, txt = (keye2_step[k] for k in ("row", "compiled", "text"))
+    cfg = row["config"]
+    assert "ragged-dot" not in txt and "tpu_custom_call" in txt
+    for scope in KEYE2_SCOPES:
+        assert scope in txt, scope
+    for kernel, bodies in KEYE2_BODIES:
         assert row["kernels"][kernel]["bodies"] == bodies, row["kernels"]
         paths = set(re.findall(r'op_name="([^"]*%s[^"]*)"' % kernel, txt))
         assert len({m for p_ in paths for m in re.findall(
@@ -736,7 +863,7 @@ def test_keye2_ep8_train_step_compiles_and_fits_one_chip(one_chip,
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     print("keye2 step: traced and lowered in",
           round(row["trace_s"] + row["lower_s"], 1), "s, compiled in",
-          round(time.perf_counter() - t1, 1), "s; generated code",
+          round(keye2_step["compile_s"], 1), "s; generated code",
           mem.generated_code_size_in_bytes, "B, arguments",
           mem.argument_size_in_bytes, "B, temporaries",
           mem.temp_size_in_bytes, "B, in all", total, "B")

@@ -13,6 +13,15 @@ The contracts, all CPU-runnable (Pallas via interpret mode):
    second run is a PURE cache hit (any timing is an assertion failure).
 4. CONSUMERS — a searched winner changes what the fused step / the
    attention unit actually trace, trajectory-equivalent to the default.
+
+Three searches that run 30-40 generated candidates end to end, each an
+interpret-mode kernel to compile (90-110 s a test under tier-1's six
+workers), are marked `slow` and run by name (`pytest -m slow
+tests/test_kernel_search.py`, ISSUE 39): the acceptance run, the
+fusion families' sweep and the in-graph fusion search. Each names its
+own ops, budget and cache file and asserts on the process-global ledger
+and selection, so no two of them read one search; the smaller searches
+beside them hold the same contracts on every run.
 """
 
 import json
@@ -231,6 +240,7 @@ def test_every_timed_trial_was_gated_first(tmp_path):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.slow
 def test_search_end_to_end_cpu(tmp_path, monkeypatch):
     """The acceptance run: >=3 ops searched on CPU (interpret mode),
     >=8 generated candidates timed per op, trials <= budget, winners
@@ -653,6 +663,7 @@ def test_fusion_ledger_bypass_raises_ungated_error(tmp_path,
                      cache=at.AutotuneCache(str(tmp_path / "c.json")))
 
 
+@pytest.mark.slow
 def test_search_times_fused_candidate_per_family(tmp_path):
     """The acceptance sweep: one budgeted search over the three fusion
     families times >=1 FUSED candidate (fuse axis on) per family, every
@@ -823,6 +834,7 @@ def test_layer_profile_splits_fused_share_back_to_members():
     assert rec["ops_raw"]["lrn_maxpool"] == pytest.approx(0.4)
 
 
+@pytest.mark.slow
 def test_autotune_workflow_searches_fusion_in_graph(tmp_path):
     """--autotune --autotune-budget: the workflow's adjacent (lrn,
     maxpool) pair makes lrn_maxpool searchable IN-GRAPH, and

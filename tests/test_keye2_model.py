@@ -51,8 +51,64 @@ def session_of(cfg, seed=11, sabotage=None):
                                  lambda _line: None, sabotage)
 
 
-@pytest.mark.parametrize("held,query_block,bands", [
-    ((0, 8), 8, 4), ((2, 2), 8, 1), ((4, 4), 16, 2), ((0, 8), 32, 4)])
+#: the sessions this module has built, by their configuration's JSON, each
+#: with the step and workflow that `free_program` takes from it
+_SESSIONS = {}
+
+
+def shared_session(cfg, seed=11):
+    """The ONE session this module builds of `cfg`, put back at `seed`'s
+    first step (`TrainSession.start_from`): a program the file already has
+    is not compiled a second time for a test that reads nothing else of
+    it. (A step that was released, as `free_program` does, compiles again
+    at its next call: the test that leaves its step loaded stands
+    first.)"""
+    key = json.dumps(cfg, sort_keys=True)
+    if key not in _SESSIONS:
+        mod, ses = session_of(cfg, seed)
+        _SESSIONS[key] = (mod, ses, ses.step, ses.wf)
+    else:
+        mod, ses, step, wf = _SESSIONS[key]
+        ses.step, ses.wf = step, wf
+        ses.start_from(seed)
+    return mod, ses
+
+
+#: the cases of the three steps against the reference: (first held expert,
+#: held experts), queries a block, bands of keys. The first is the preset
+#: as it stands, and the counters' test shares its session
+CASES = [((0, 8), 8, 4), ((2, 2), 8, 1), ((4, 4), 16, 2), ((0, 8), 32, 4)]
+
+
+def case(held, query_block, bands):
+    return tiny(held_experts_first=held[0], num_experts=held[1],
+                query_block=query_block, key_bands=bands)
+
+
+def test_the_blocks_count_their_pairs_and_slots():
+    from veles_tpu.znicz import lm
+    cfg = case(*CASES[0])
+    _mod, ses = shared_session(cfg)
+    for _ in range(3):
+        ses.dispatch()
+    aux = jax.device_get(ses.state["aux"])
+    s, k = cfg["seq_len"], cfg["sa_config"]["topk"]
+    got = lm.dsa_counts(ses.step, aux)
+    assert set(got) == {"L01", "L02"}
+    for c in got.values():
+        assert c["steps"] == 3
+        assert c["causal"] == c["scored"] == 3 * 2 * s * (s + 1) // 2
+        assert c["selected"] == 3 * 2 * keye2_ops_count.pairs_selected(s, k)
+    moe = lm.moe_counts(ses.step, aux)
+    assert all(c["slots"] == 3 * 2 * s * 2 and c["held"] == c["slots"]
+               and c["dropped"] == 0 for c in moe.values())
+    bits = np.unpackbits(aux[1]["selected"], axis=1)
+    assert bits.shape == (2 * s, s)
+    assert bits.sum() == 2 * keye2_ops_count.pairs_selected(s, k)
+    assert not np.triu(bits[:s], 1).any()
+
+
+@pytest.mark.parametrize("held,query_block,bands", CASES)
 def test_three_steps_of_the_program_follow_the_reference(held, query_block,
                                                          bands):
     """The three terms of the loss, every leaf's first gradient and the
@@ -60,9 +116,8 @@ def test_three_steps_of_the_program_follow_the_reference(held, query_block,
     keys: float32 against float32 at `highest` reads 1e-6; the limits
     leave two orders. Weights at 0.2, so that the indexer, the router and
     the softmaxes are far from uniform. Whatever the tiling."""
-    cfg = tiny(held_experts_first=held[0], num_experts=held[1],
-               query_block=query_block, key_bands=bands)
-    mod, ses = session_of(cfg)
+    cfg = case(held, query_block, bands)
+    mod, ses = shared_session(cfg)
     prog = ses.first_steps()
     ses.free_program()
     prog, ref, _ = ses.readings(prog)
@@ -637,29 +692,6 @@ def test_the_pair_counters_hold_more_than_int32():
             total += n
     assert lm._wide(np.asarray(acc)) == total > 2 ** 36
     assert int(acc[1]) < 2 ** lm.WIDE
-
-
-def test_the_blocks_count_their_pairs_and_slots():
-    from veles_tpu.znicz import lm
-    cfg = tiny()
-    _mod, ses = session_of(cfg)
-    for _ in range(3):
-        ses.dispatch()
-    aux = jax.device_get(ses.state["aux"])
-    s, k = cfg["seq_len"], cfg["sa_config"]["topk"]
-    got = lm.dsa_counts(ses.step, aux)
-    assert set(got) == {"L01", "L02"}
-    for c in got.values():
-        assert c["steps"] == 3
-        assert c["causal"] == c["scored"] == 3 * 2 * s * (s + 1) // 2
-        assert c["selected"] == 3 * 2 * keye2_ops_count.pairs_selected(s, k)
-    moe = lm.moe_counts(ses.step, aux)
-    assert all(c["slots"] == 3 * 2 * s * 2 and c["held"] == c["slots"]
-               and c["dropped"] == 0 for c in moe.values())
-    bits = np.unpackbits(aux[1]["selected"], axis=1)
-    assert bits.shape == (2 * s, s)
-    assert bits.sum() == 2 * keye2_ops_count.pairs_selected(s, k)
-    assert not np.triu(bits[:s], 1).any()
 
 
 def test_a_second_kind_of_block_is_one_spec_not_two():

@@ -469,7 +469,12 @@ def test_dropping_the_lookahead_drops_the_next_epochs_too(tmp_path, how):
         else:
             loader.fill_minibatch(np.arange(16, dtype=np.int64)[::-1])
         assert loader._pending == {}
-        assert not wait(futures, timeout=10).not_done   # cancelled, or made
+        # cancelled, or made (`wait` never reports a future that `stop`'s
+        # shutdown cancelled while it was still queued: no worker is left
+        # to notify it, and the test failed whenever a loaded machine had
+        # not started the third produce yet)
+        assert not wait([f for f in futures if not f.cancelled()],
+                        timeout=10).not_done
         for _ in range(3):          # over the boundary, from nothing
             loader.run()
             ref.run()

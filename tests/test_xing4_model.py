@@ -4,6 +4,7 @@ program) at a size the CPU holds: the whole step, the share test that
 ties a chip's share to the uncut model, the dropless expert path, the
 Sinkhorn projection and the yarn frequencies."""
 
+import json
 import os
 import subprocess
 import sys
@@ -42,6 +43,18 @@ def tiny(**over):
     return cfg
 
 
+#: the cases of the three steps against the reference: residual streams,
+#: (first held expert, held experts). The tests that read no more of a
+#: configuration than its step share the first one's session
+#: (`shared_session`)
+CASES = [(2, (2, 2)), (4, (2, 2)), (4, (0, 8))]
+
+
+def case(streams, held):
+    return tiny(hc_mult=streams, held_experts_first=held[0],
+                n_routed_experts=held[1], num_attention_heads=2)
+
+
 def session_of(cfg, seed=11, sabotage=None):
     from benchmark.manifest import Manifest
     cell = {"name": "t.step", "chips": 1, "config_data": cfg,
@@ -52,15 +65,72 @@ def session_of(cfg, seed=11, sabotage=None):
                                  lambda _line: None, sabotage)
 
 
-@pytest.mark.parametrize("streams,held", [(2, (2, 2)), (4, (2, 2)),
-                                          (4, (0, 8))])
+#: the sessions this module has built, by their configuration's JSON, each
+#: with the step and workflow that `free_program` takes from it
+_SESSIONS = {}
+
+
+def shared_session(cfg, seed=11, fresh=False):
+    """The ONE session this module builds of `cfg`, put back at `seed`'s
+    first step (`TrainSession.start_from`): a program the file already has
+    is not compiled a second time for a test that reads nothing else of
+    it. `fresh` builds it anew, for a test that reads its TRACE, and keeps
+    it for the tests after. (A step that was released, as `free_program`
+    does, compiles again at its next call: the tests that leave theirs
+    loaded stand first.)"""
+    key = json.dumps(cfg, sort_keys=True)
+    if fresh or key not in _SESSIONS:
+        mod, ses = session_of(cfg, seed)
+        _SESSIONS[key] = (mod, ses, ses.step, ses.wf)
+    else:
+        mod, ses, step, wf = _SESSIONS[key]
+        ses.step, ses.wf = step, wf
+        ses.start_from(seed)
+    return mod, ses
+
+
+def test_only_a_unit_that_says_so_gets_its_input_as_it_came():
+    """Token ids reach the embedding as int32; any other first unit's
+    integer input (a uint8 wire with no normaliser) is cast to the
+    compute dtype as before (float32 here, as bfloat16: a cast of the ids
+    to either would show)."""
+    from veles_tpu.znicz import lm
+    from veles_tpu.znicz.nn_units import Forward
+    assert lm.TokenEmbedding.fused_integer_input is True
+    assert not hasattr(Forward, "fused_integer_input")
+    _mod, ses = shared_session(case(*CASES[0]), fresh=True)
+    seen = []
+    emb = ses.step.forwards[0]
+    inner = emb.fused_apply
+    emb.fused_apply = lambda p, x, **kw: (seen.append(x.dtype),
+                                          inner(p, x, **kw))[1]
+    try:
+        ses.dispatch()
+    finally:
+        del emb.fused_apply
+    assert seen and all(d == jnp.int32 for d in seen)
+
+
+def test_a_released_step_compiles_again_and_gives_the_same_step():
+    """`FusedTrainStep.release` unloads the compiled programs (the
+    benchmark's reference needs the device after the window); the step
+    object stays usable."""
+    _mod, ses = shared_session(case(*CASES[0]))
+    first = float(ses.dispatch()[0])
+    assert ses.step._train_fn is not None
+    ses.step.release()
+    assert ses.step._train_fn is None and ses.step._eval_fn is None
+    ses.start_from(ses.seed)
+    assert float(ses.dispatch()[0]) == first
+
+
+@pytest.mark.parametrize("streams,held", CASES)
 def test_three_steps_of_the_program_follow_the_reference(streams, held):
     """Loss, every leaf's first gradient, the parameters and the selection
     bias after three steps: float32 against float32 at `highest` reads
     1e-6; the limits leave two orders."""
-    cfg = tiny(hc_mult=streams, held_experts_first=held[0],
-               n_routed_experts=held[1], num_attention_heads=2)
-    mod, ses = session_of(cfg)
+    cfg = case(streams, held)
+    mod, ses = shared_session(cfg)
     prog = ses.first_steps()
     ses.free_program()
     prog, ref, _ = ses.readings(prog)
@@ -77,38 +147,6 @@ def test_three_steps_of_the_program_follow_the_reference(streams, held):
         assert np.abs(steps).max() == 3 or np.abs(steps).max() >= 1
         assert np.allclose(steps * cfg["bias_update_speed"], b, atol=1e-7)
     assert len(prog["bias"]) == len(xing4_ops_count.expert_layers(cfg)) == 3
-
-
-def test_a_released_step_compiles_again_and_gives_the_same_step():
-    """`FusedTrainStep.release` unloads the compiled programs (the
-    benchmark's reference needs the device after the window); the step
-    object stays usable."""
-    _mod, ses = session_of(tiny(num_attention_heads=2))
-    first = float(ses.dispatch()[0])
-    assert ses.step._train_fn is not None
-    ses.step.release()
-    assert ses.step._train_fn is None and ses.step._eval_fn is None
-    ses.start_from(ses.seed)
-    assert float(ses.dispatch()[0]) == first
-
-
-def test_only_a_unit_that_says_so_gets_its_input_as_it_came():
-    """Token ids reach the embedding as int32; any other first unit's
-    integer input (a uint8 wire with no normaliser) is cast to the
-    compute dtype as before."""
-    from veles_tpu.znicz import lm
-    from veles_tpu.znicz.nn_units import Forward
-    assert lm.TokenEmbedding.fused_integer_input is True
-    assert not hasattr(Forward, "fused_integer_input")
-    _mod, ses = session_of(tiny(num_attention_heads=2,
-                                compute_dtype="bfloat16"))
-    seen = []
-    emb = ses.step.forwards[0]
-    inner = emb.fused_apply
-    emb.fused_apply = lambda p, x, **kw: (seen.append(x.dtype),
-                                          inner(p, x, **kw))[1]
-    ses.dispatch()
-    assert seen and all(d == jnp.int32 for d in seen)
 
 
 @pytest.mark.parametrize("vanishing", ["2.hca_a_post", "3.hcm_b_res"])
