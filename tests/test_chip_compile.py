@@ -628,11 +628,16 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_lowered):
     `jax.checkpoint` a block, traced and lowered for a described v5e (the
     compile of what is lowered here, and whether it fits, is
     `test_xing4_ep8_compiled_step_fits_one_chip`'s, `slow`). The grouped
-    products of the held experts lower to `lax.ragged_dot`; the twelve
-    hyper-connections call the four `veles_hc_*` kernels (ISSUE 34), the
-    six latent-attention sites the three `veles_flash_*` (ISSUE 38). A
-    shape fault shows here before a chip is asked. The units hold zeros
-    (`init_std` 0: no draw), nothing is put on a device.
+    products of the held experts lower to `lax.ragged_dot`, under TEN
+    `lax.cond`s: the five expert layers' first forwards and their
+    backwards, and no third run for the hyper-connection's backward, whose
+    operand the checkpoint saves by name (`ops.moe.MOE_SAVED`, ISSUE 40:
+    jax's own dead-code pass drops the rest before the text is lowered;
+    fifteen without the name); the twelve hyper-connections call the four
+    `veles_hc_*` kernels (ISSUE 34), the six latent-attention sites the
+    three `veles_flash_*` (ISSUE 38). A shape fault shows here before a
+    chip is asked. The units hold zeros (`init_std` 0: no draw), nothing is
+    put on a device.
 
     What Python pays before XLA sees the step is held to COUNTS, which do
     not wobble under xdist as seconds do (PR 33 inlined a `pallas_call` a
@@ -643,7 +648,8 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_lowered):
     assert (one["hc"], one["flash_attn"]) == ("pallas_one_pass", "pallas")
     print("trace_cost", {k: one[k] for k in (
         "hc", "flash_attn", "trace_s", "lower_s", "equations",
-        "stablehlo_bytes", "kernels")})
+        "stablehlo_bytes", "kernels", "conds")})
+    assert one["conds"] == 10, one["conds"]
     # a backward kernel's body once; a forward kernel's at most twice: the
     # plain one of the first forward and the one `jax.checkpoint`'s partial
     # evaluation stages for the recomputed forward (derived once, cached)
@@ -696,6 +702,11 @@ def test_xing4_ep8_compiled_step_fits_one_chip(xing4_step):
     for scope in ("/mla/", "/moe/experts/", "/hc_pre/", "/hc_post/",
                   "update/balance", "rematted_computation"):
         assert scope in txt, scope
+    # the held experts' ten `cond`s of the lowered step are the compiled
+    # one's: XLA merges none and none stands in the recomputed forward
+    conds = re.findall(r'[^\n]* conditional\([^\n]*', txt)
+    assert len(conds) == one["conds"] == 10, len(conds)
+    assert not any("rematted_computation" in c for c in conds)
     # after XLA inlines the calls every site's kernel carries its own path
     # under the scope `step_hc_ms` reads
     for kernel, side in (("veles_hc_pre_fwd", "hc_pre"),

@@ -6,6 +6,7 @@ Sinkhorn projection and the yarn frequencies."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -327,6 +328,51 @@ def test_what_a_grouped_product_leaves_past_its_groups_reaches_nothing(
     for a, b in zip(jax.tree.leaves(clean), jax.tree.leaves(soiled)):
         assert np.isfinite(np.asarray(b)).all()
         np.testing.assert_allclose(a, b, atol=1e-6)
+
+
+@pytest.mark.parametrize("residual", ["hc", "plain"])
+def test_a_checkpointed_block_runs_its_held_experts_twice(residual):
+    """Under the unit's own `fused_remat_policy` the compiled gradient of a
+    checkpointed expert block holds TWO `conditional`s of the held experts
+    (`_held_swiglu`: the first forward, the backward with its own
+    recomputation), whatever the residual path. A hyper-connection's
+    backward asks for its sub-layer's output: under a policy that does not
+    save `ops.moe.MOE_SAVED` the checkpoint runs the held experts a third
+    time for it; `x + y` asks for nothing, and XLA drops that copy by
+    itself. The saved value is the value computed again: the gradients are
+    those of the block without `jax.checkpoint`, bit for bit."""
+    from veles_tpu.znicz import lm
+    cfg = tiny(held_experts_first=2, n_routed_experts=2)
+    spec = lm.BlockSpec(**{
+        **{k: v for k, v in layer_table(cfg)[2].items() if k != "type"},
+        "features": cfg["hidden_size"], "residual": residual,
+        **({"streams": 1} if residual == "plain" else {})})
+    batch, seq = 2, cfg["seq_len"]
+    assert spec.fast_rows(batch * seq) < batch * seq * 2    # a `cond` each
+    seeded = _layer_inputs(cfg)[0][2]       # an expert block's leaves
+    p = {k: seeded[k] for k in spec.shapes()}
+    key = jax.random.key(3)
+    x = jax.random.normal(key, (batch, seq, spec.n * spec.c), jnp.float32)
+    dy = jax.random.normal(jax.random.fold_in(key, 1), x.shape)
+
+    def grad_of(block):     # with the value: a step reads its loss too
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: (block(p, x, None)[0] * dy).sum(), argnums=(0, 1)))
+
+    def conds(policy):
+        f = grad_of(jax.checkpoint(spec.apply, policy=policy))
+        text = f.lower(p, x).compile().as_text()
+        return f, len(re.findall(r" conditional\(", text))
+
+    unnamed = jax.checkpoint_policies.save_only_these_names(
+        *oa.DSA_SAVED, *oa.FLASH_SAVED)
+    assert conds(unnamed)[1] == (3 if residual == "hc" else 2)
+    saved, n = conds(lm.HCBlock.fused_remat_policy)
+    assert n == 2
+    for a, b in zip(jax.tree.leaves(saved(p, x)),
+                    jax.tree.leaves(grad_of(spec.apply)(p, x))):
+        assert np.abs(np.asarray(a)).max() > 0
+        np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("n", [2, 4])

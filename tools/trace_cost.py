@@ -2,8 +2,8 @@
 """What Python pays before XLA sees a language-model cell's train step
 (`xing4_ep8.step`; `--config keye2_ep8`: `keye2_ep8.long16k`): the
 seconds of `.trace()` and `.lower()`, the traced step's top-level
-equations, the bytes of StableHLO, and how many bodies and call sites the
-`veles_*` kernels leave in the lowered module.
+equations, the bytes of StableHLO, how many bodies and call sites the
+`veles_*` kernels leave in the lowered module, and its `lax.cond`s.
 
 No chip and no device buffer: the step is built from
 `benchmark/configs/<config>.json` with zero weights and traced at
@@ -156,6 +156,10 @@ def measure(hc: Optional[str] = None, sharding=None,
             "trace_s": t1 - t0, "lower_s": t2 - t1,
             "equations": len(traced.jaxpr.eqns),
             "stablehlo_bytes": len(text), "kernels": kernel_counts(text),
+            # the held experts' (`ops/moe.py::_held_swiglu`), each inlined
+            # where it runs: first forwards, backwards, and whatever a
+            # block's `jax.checkpoint` computes again
+            "conds": text.count("stablehlo.case"),
             "text": text, "lowered": lowered, "config": cfg, "step": step,
             "args": args}
 

@@ -22,8 +22,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from jax.lax import axis_size as _axis_size
+
+#: `jax.ad_checkpoint.checkpoint_name` of what `held_experts_swiglu`
+#: returns, which a surrounding `jax.checkpoint` should save, not
+#: recompute, where the backward of what wraps the expert layer asks for
+#: the layer's output: a hyper-connection's does, for the gradient of its
+#: own mixing weights (`x + y` does not, and nothing is kept for it).
+#: T x C of the compute dtype a layer (58.7 MB in xing4_ep8.step) against a
+#: third run of the three grouped products: the layer's own backward
+#: (`_held_swiglu_bwd`) recomputes them once more whatever is saved
+MOE_SAVED = ("moe_held_out",)
 
 
 def router_probs(x, wr):
@@ -334,4 +345,5 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
     else:
         y = _held_swiglu((int(fast_rows), all_rows), (kernels, interpret),
                          *args)
-    return y, total - jnp.minimum(total, all_rows)
+    return (checkpoint_name(y, MOE_SAVED[0]),
+            total - jnp.minimum(total, all_rows))

@@ -439,16 +439,19 @@ def _wide(acc) -> int:
     return (int(acc[0]) << WIDE) + int(acc[1])
 
 
-#: what a block's `jax.checkpoint` saves, so that the backward pass does
-#: not attend a second time: the names of either kind of attention (a
-#: lowering that names nothing saves nothing). ONE object for every block
-#: and for the head's MTP block: `jax.checkpoint` splits a jitted kernel's
-#: jaxpr by its policy and caches the split by the policy's identity, so a
-#: policy made anew a block leaves the step with a copy of every kernel's
-#: body a block (28 of `veles_dsa_pmean` in the six-block step, lowered
-#: here for a described v5e)
+#: what a block's `jax.checkpoint` saves: the names of either kind of
+#: attention, so that the backward pass does not attend a second time (a
+#: lowering that names nothing saves nothing), and the held experts'
+#: output, so that a hyper-connection's backward, which asks for its
+#: sub-layer's output, does not run their products a third time (a plain
+#: residual path asks for nothing, and nothing is kept for it). ONE object
+#: for every block and for the head's MTP block: `jax.checkpoint` splits a
+#: jitted kernel's jaxpr by its policy and caches the split by the policy's
+#: identity, so a policy made anew a block leaves the step with a copy of
+#: every kernel's body a block (28 of `veles_dsa_pmean` in the six-block
+#: step, lowered here for a described v5e)
 _SAVED_POLICY = jax.checkpoint_policies.save_only_these_names(
-    *oa.DSA_SAVED, *oa.FLASH_SAVED)
+    *oa.DSA_SAVED, *oa.FLASH_SAVED, *om.MOE_SAVED)
 
 
 def _gaussian(unit):
@@ -593,7 +596,8 @@ class HCBlock(_LMUnit):
     #: what the step's `jax.checkpoint` around this unit saves: indexed
     #: attention's thresholds and outputs, the flash kernels' outputs and
     #: logsumexps, so that the backward pass neither selects nor attends a
-    #: second time
+    #: second time, and the held experts' output where the residual path's
+    #: backward reads it
     fused_remat_policy = staticmethod(_SAVED_POLICY)
 
     def fused_apply(self, params, x, *, key=None, train=True, aux=None):
