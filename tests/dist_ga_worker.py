@@ -4,7 +4,10 @@ process leasing GA individuals from the test's coordinator.
 Modes:
 - `work`:  evaluate the analytic fitness, record each evaluated payload
            into `record_path` (proof the individual ran IN THIS PROCESS),
-           post results until the server says done.
+           post results until the server says done. A fourth argument
+           is the seconds one evaluation takes (0 without it): a worker
+           that answers at once can take a whole round before a slower
+           one has polled twice.
 - `die`:   lease ONE task and exit(1) WITHOUT posting a result — the
            lost-slave case; the coordinator must re-issue the lease.
 - `member`: ensemble-member mode — train a tiny real workflow with the
@@ -79,8 +82,11 @@ def main() -> None:
         return
 
     assert mode == "work"
+    eval_s = float(sys.argv[4]) if len(sys.argv) > 4 else 0.0
 
     def fitness(payload):
+        import time
+        time.sleep(eval_s)
         with open(record_path, "a") as f:
             f.write(json.dumps({"payload": payload,
                                 "pid": os.getpid()}) + "\n")

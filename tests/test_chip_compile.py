@@ -888,3 +888,99 @@ def test_keye2_ep8_compiled_step_fits_one_chip(keye2_step):
     # 16.1 GB of peak on the chip where 12.6 were (PR 36): XLA kept four
     # bands' scores alive across the attention
     assert mem.temp_size_in_bytes < 7 << 30, mem.temp_size_in_bytes
+
+
+#: `qwen3next_ep16`'s kernels and the bodies of each in the lowered module:
+#: the full layer's three flash kernels once each (one site: one period
+#: has one full layer); the grouped products either width first on the
+#: fast rows, forward, again where the backward recomputes, and the other
+#: way round, the whole-buffer branch being the same rows a window at a
+#: time (`ops.moe._WHOLE_BUFFER_MAX`)
+QWEN3NEXT_BODIES = (("veles_flash_fwd", 1), ("veles_flash_dq", 1),
+                    ("veles_flash_dkv", 1), ("veles_gmm", 10),
+                    ("veles_tgmm", 4))
+# (a linear layer walks its sequences in `scan_groups` groups, the body of
+# a `lax.map`: `gdn/while/body/.../proj/...`; the chain along the sequence
+# is the body of a `lax.scan` inside it: `.../scan/while/body/...`; its
+# backward opens `gdn/scan` again)
+QWEN3NEXT_SCOPES = ("/gdn/while/", "/proj/", "/conv/", "/scan/out/",
+                    "/scan/while/", "/gdn/scan/", "/out/", "/attn/",
+                    "/moe/router/", "/moe/experts/", "/moe/shared/",
+                    "/moe/balance_loss/", "rematted_computation",
+                    "veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv",
+                    "veles_gmm", "veles_tgmm")
+
+
+@pytest.fixture(scope="module")
+def qwen3next_lowered(one_chip):
+    """`qwen3next_ep16`'s step traced and lowered under what the described
+    chip resolves: ONE trace for the tier-1 test below and its slow twin."""
+    return _lowered(None, one_chip, "qwen3next_ep16")
+
+
+@pytest.fixture(scope="module")
+def qwen3next_step(qwen3next_lowered):
+    return {"row": qwen3next_lowered, **_compiled(qwen3next_lowered)}
+
+
+def test_qwen3next_ep16_train_step_compiles_and_fits_one_chip(
+        qwen3next_lowered):
+    """`benchmark/configs/qwen3next_ep16.json` through the sample's layer
+    table, `StandardWorkflow` and `FusedTrainStep`: 4 sequences of 8,192
+    tokens, bfloat16, one `jax.checkpoint` a block; three Gated DeltaNet
+    blocks whose chunked scan is plain XLA around one `lax.scan` and its
+    hand-written backward, one gated full-attention block whose core is
+    the three `veles_flash_*` kernels (ISSUE 41: a key-value head repeated
+    to its 8 query heads), the held experts' products as `veles_gmm` /
+    `veles_tgmm`. Traced and lowered for a described v5e, ONE trace (the
+    compile of what is lowered here, and its memory, is
+    `test_qwen3next_ep16_compiled_step_fits_one_chip`'s, `slow`). The units
+    hold zeros (`init_std` 0: no draw), nothing is put on a device."""
+    row = qwen3next_lowered
+    cfg, step = row["config"], row["step"]
+    assert step.has_aux and step.unit_loss
+    assert row["flash_attn"] == "pallas" and row["dsa"] is None
+    assert _n_leaves(row) == cfg["n_params"] == 625667136
+    print("qwen3next step: traced and lowered in",
+          round(row["trace_s"] + row["lower_s"], 1), "s")
+    txt = row["debug_text"]
+    assert "ragged_dot" not in txt and "tpu_custom_call" in txt
+    for scope in QWEN3NEXT_SCOPES:
+        assert scope in txt, scope
+    for kernel, bodies in QWEN3NEXT_BODIES:
+        assert row["kernels"][kernel]["bodies"] == bodies, row["kernels"]
+    assert set(row["kernels"]) == {k for k, _ in QWEN3NEXT_BODIES}
+    # the fast rows and the walk in windows, forward and backward, of four
+    # expert layers
+    assert row["conds"] == 8, row["conds"]
+    assert (cfg["chunk"], cfg["scan_groups"]) == (64, 2)
+
+
+@pytest.mark.slow
+def test_qwen3next_ep16_compiled_step_fits_one_chip(qwen3next_step):
+    """The step `test_qwen3next_ep16_train_step_compiles_and_fits_one_chip`
+    lowers, compiled for the described v5e. ONE compile: memory is known
+    before the first chip call. On every PR the cell
+    `qwen3next_ep16.seq8k` holds the memory on the chip (`hbm_peak_gb`)."""
+    row, compiled, txt = (qwen3next_step[k]
+                          for k in ("row", "compiled", "text"))
+    cfg = row["config"]
+    assert "ragged-dot" not in txt and "tpu_custom_call" in txt
+    for kernel, _bodies in QWEN3NEXT_BODIES:
+        assert kernel in txt, kernel
+    for scope in ("/gdn/", "/scan/", "/conv/", "/attn/", "/moe/experts/"):
+        assert scope in txt, scope
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print("qwen3next step: traced and lowered in",
+          round(row["trace_s"] + row["lower_s"], 1), "s, compiled in",
+          round(qwen3next_step["compile_s"], 1), "s; generated code",
+          mem.generated_code_size_in_bytes, "B, arguments",
+          mem.argument_size_in_bytes, "B, temporaries",
+          mem.temp_size_in_bytes, "B, in all", total, "B")
+    # parameters and velocity, float32: 8 B a parameter of arguments
+    assert mem.argument_size_in_bytes > 8 * cfg["n_params"]
+    # what a v5e's allocator offers: `bytes_limit` of its memory
+    # statistics (chip runs of PR 32)
+    assert total < 16909336064, total

@@ -32,13 +32,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "dist_ga_worker.py")
 
 
-def _spawn(mode: str, port: int, record: str) -> subprocess.Popen:
+def _spawn(mode: str, port: int, record: str,
+           *more: str) -> subprocess.Popen:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(HERE)
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.Popen([sys.executable, WORKER, mode, str(port),
-                             record], env=env,
+                             record, *more], env=env,
                             stdout=subprocess.PIPE,
                             stderr=subprocess.PIPE, text=True)
 
@@ -53,7 +54,11 @@ def test_individuals_run_on_both_processes(tmp_path):
         time.sleep(0.3)         # let the subprocess win some leases too
         return (payload["x"] - 3.0) ** 2
 
-    proc = _spawn("work", srv.port, sub_record)
+    # both sides take 0.3 s an individual and poll every 0.05 s, so
+    # neither can take all twelve before the other has asked: with the
+    # subprocess answering at once and the local worker asleep for its
+    # default 0.5 s, the subprocess took the whole round under load
+    proc = _spawn("work", srv.port, sub_record, "0.3")
     # wait until the subprocess is past its imports and polling, so both
     # processes genuinely compete for the leases below
     deadline = time.time() + 60
@@ -61,8 +66,8 @@ def test_individuals_run_on_both_processes(tmp_path):
         assert time.time() < deadline, "worker subprocess never ready"
         assert proc.poll() is None, proc.communicate()
         time.sleep(0.1)
-    FitnessQueueWorker("127.0.0.1", srv.port,
-                       local_fitness).start_thread()
+    FitnessQueueWorker("127.0.0.1", srv.port, local_fitness,
+                       poll_s=0.05).start_thread()
     try:
         payloads = [{"x": float(i)} for i in range(12)]
         fits = srv.submit(payloads, timeout_s=60)

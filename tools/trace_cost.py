@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """What Python pays before XLA sees a language-model cell's train step
-(`xing4_ep8.step`; `--config keye2_ep8`: `keye2_ep8.long16k`): the
+(`xing4_ep8.step`; `--config keye2_ep8`: `keye2_ep8.long16k`; `--config
+qwen3next_ep16`: `qwen3next_ep16.seq8k`): the
 seconds of `.trace()` and `.lower()`, the traced step's top-level
 equations, the bytes of StableHLO, how many bodies and call sites the
 `veles_*` kernels leave in the lowered module, and its `lax.cond`s.
@@ -18,15 +19,22 @@ asserts on them through `measure`.
     python tools/trace_cost.py --described --hc xla --hc pallas_one_pass
     python tools/trace_cost.py --described --flash xla_mha
     python tools/trace_cost.py --described --config keye2_ep8
+    python tools/trace_cost.py --described --bare-locations --config keye2_ep8
 
 `--described` places the arguments on a described v5e (no chip needed) and
 answers the kernels' `available()` as that chip would, so that the Pallas
-lowering is what traces and lowers here.
+lowering is what traces and lowers here. `--bare-locations` keeps Python's
+call stacks out of the module's locations, so that `stablehlo_sha256` says
+whether two trees lower the SAME step: with them a kernel's payload carries
+the line numbers of every file on its stack, and an edit anywhere above a
+kernel moves the hash (PR 41: `keye2_ep8`, `xing4_ep8` and AlexNet lower to
+the parent's module to the byte this way).
 """
 
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import os
 import re
@@ -40,7 +48,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: the configurations this tool builds: the sample whose `layer_table`
 #: makes the program, and a sequence's targets beside its (batch, seq)
 #: ids (xing4's head also reads the next-next token)
-CONFIGS = {"xing4_ep8": ("xing4", (2,)), "keye2_ep8": ("keye2", ())}
+CONFIGS = {"xing4_ep8": ("xing4", (2,)), "keye2_ep8": ("keye2", ()),
+           "qwen3next_ep16": ("qwen3next", ())}
 
 
 def cell_step(sharding=None, config: str = "xing4_ep8"
@@ -155,7 +164,9 @@ def measure(hc: Optional[str] = None, sharding=None,
             "flash_attn": table.get("flash_attn"),
             "trace_s": t1 - t0, "lower_s": t2 - t1,
             "equations": len(traced.jaxpr.eqns),
-            "stablehlo_bytes": len(text), "kernels": kernel_counts(text),
+            "stablehlo_bytes": len(text),
+            "stablehlo_sha256": hashlib.sha256(text.encode()).hexdigest(),
+            "kernels": kernel_counts(text),
             # the held experts' (`ops/moe.py::_held_swiglu`), each inlined
             # where it runs: first forwards, backwards, and whatever a
             # block's `jax.checkpoint` computes again
@@ -177,8 +188,14 @@ def main(argv=None) -> int:
     ap.add_argument("--described", action="store_true",
                     help="lower for a described v5e instead of the "
                          "platform's own device")
+    ap.add_argument("--bare-locations", action="store_true",
+                    help="no call stacks in the module's locations: the "
+                         "hash then compares two trees' steps")
     ns = ap.parse_args(argv)
     sys.path.insert(0, REPO)
+    if ns.bare_locations:
+        import jax
+        jax.config.update("jax_traceback_in_locations_limit", 0)
     sharding = None
     if ns.described:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")

@@ -750,3 +750,67 @@ def indexed_attention(p, h, *, n_heads: int, kv_heads: int, head_dim: int,
     return y, {"index_loss": kl.sum() / (n * s),
                "pairs_selected": n_sel.sum(),
                "selected": bits.reshape(n * s, s // 8)}
+
+
+# -- grouped-query attention behind an output gate ----------------------------------
+
+def _grouped_core_xla(q, k, v, scale: float):
+    """Causal softmax attention, plain XLA: q (N, S, H, D), k and v (N, S,
+    Hkv, D), query head j reading key-value head j // (H / Hkv) -> (N, S,
+    H, D). Blocked as `_latent_core_xla`: a block of queries meets the
+    keys up to its own end, and its float32 scores pass through HBM."""
+    n, s, h, d = q.shape
+    kv = k.shape[2]
+    q = q.reshape(n, s, kv, h // kv, d)
+    block = LATENT_QUERY_BLOCK if s % LATENT_QUERY_BLOCK == 0 else s
+    outs = []
+    for lo in range(0, s, block):
+        hi = lo + block
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", q[:, lo:hi], k[:, :hi],
+                            preferred_element_type=jnp.float32) * scale
+        causal = jnp.arange(hi)[None, :] <= jnp.arange(lo, hi)[:, None]
+        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+        outs.append(jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v.dtype),
+                               v[:, :hi],
+                               preferred_element_type=jnp.float32))
+    return jnp.concatenate(outs, axis=1).astype(v.dtype).reshape(n, s, h, d)
+
+
+def gated_attention(p, h, *, n_heads: int, kv_heads: int, head_dim: int,
+                    rotary_dim: int, rope_theta: float,
+                    norm_eps: float = 1e-6, norm_offset: float = 0.0,
+                    flash=None):
+    """Grouped-query attention whose query projection carries an output
+    gate (Qwen3-Next's full-attention layer): h (N, S, C) -> (N, S, C),
+    causal. `w_q` (C, H 2D) gives a head its query and its gate side by
+    side, `w_k` and `w_v` (C, Hkv D); q and k pass through an RMSNorm over
+    the head with a learned scale (`norm_offset` 1: zero-centred); the
+    rotary embedding turns the first `rotary_dim` of a head, two-halves
+    layout, positions from 0; softmax at D^-1/2, H / Hkv queries a
+    key-value head; out = (attn * sigmoid(gate)) `w_o`. The core is the
+    blocked XLA form, or `flash`, the registry's `flash_attn` lowering,
+    which is handed every query head's own copy of its key-value head."""
+    from veles_tpu.ops.lm import (apply_rope, mm, rms_norm, rope_inv_freq,
+                                  rope_tables)
+    n, s, _ = h.shape
+    qg = mm(h, p["w_q"]).reshape(n, s, n_heads, 2 * head_dim)
+    q, gate = qg[..., :head_dim], qg[..., head_dim:]
+    k = mm(h, p["w_k"]).reshape(n, s, kv_heads, head_dim)
+    v = mm(h, p["w_v"]).reshape(n, s, kv_heads, head_dim)
+    cos, sin = rope_tables(s, rope_inv_freq(rotary_dim, rope_theta))
+
+    def turned(x, scale):
+        x = rms_norm(x, scale, norm_eps, offset=norm_offset)
+        return jnp.concatenate([apply_rope(x[..., :rotary_dim], cos, sin),
+                                x[..., rotary_dim:]], axis=-1)
+
+    q, k = turned(q, p["q_norm"]), turned(k, p["k_norm"])
+    scale = head_dim ** -0.5
+    if flash is None:
+        out = _grouped_core_xla(q, k, v, scale)
+    else:
+        rep = n_heads // kv_heads
+        out = flash(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+                    scale=scale, causal=True, scope="attn")
+    out = out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+    return mm(out.astype(h.dtype).reshape(n, s, n_heads * head_dim), p["w_o"])

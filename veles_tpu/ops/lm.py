@@ -35,13 +35,15 @@ import numpy as np
 from jax import lax
 
 
-def rms_norm(x, scale=None, eps: float = 1e-6):
+def rms_norm(x, scale=None, eps: float = 1e-6, offset: float = 0.0):
     """x / sqrt(mean(x^2) + eps) over the last axis, in float32, times
-    `scale` (none: the plain normalisation); back in x's dtype."""
+    `offset + scale` (no scale: the plain normalisation; `offset` 1 is the
+    zero-centred norm, whose scale starts from 0); back in x's dtype."""
     xf = x.astype(jnp.float32)
     y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     if scale is not None:
-        y = y * scale.astype(jnp.float32)
+        scale = scale.astype(jnp.float32)
+        y = y * (offset + scale if offset else scale)
     return y.astype(x.dtype)
 
 

@@ -239,13 +239,36 @@ def _grouped_product(sizes, rows: int, like, w, kernels: bool,
 
 
 def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
-                      sizes, kernels: bool = False, interpret: bool = False):
+                      sizes, kernels: bool = False, interpret: bool = False,
+                      window=None):
     """The grouped SwiGLU over the first `rows` rows of the sorted
-    buffer: (T, C). Differentiable in h, gates and the weights."""
+    buffer: (T, C). Differentiable in h, gates and the weights. With
+    `window` = (first row, every pair's sorted row (T, k)) over the `rows`
+    rows from `first` on instead (`_in_windows`): what the
+    pairs sorted there add to their tokens."""
     t, k = gates.shape
-    n_live = jnp.minimum(sizes.sum(), rows)
-    token_of = (order[:rows] // k).astype(jnp.int32)
-    slot_of = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+
+    def rows_of():
+        """The pairs sorted into the rows at hand. (Sliced where it is
+        read, twice and in this order, as before there were windows: a
+        step that walks none lowers to the module it lowered to.)"""
+        return order[:rows] if window is None \
+            else lax.dynamic_slice(order, (window[0],), (rows,))
+
+    if window is None:
+        n_live = jnp.minimum(sizes.sum(), rows)
+        token_of = (rows_of() // k).astype(jnp.int32)
+        slot_of = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+    else:
+        first, slot_of = window
+        n_live = jnp.clip(sizes.sum() - first, 0, rows)
+        token_of = (rows_of() // k).astype(jnp.int32)
+        # the groups' rows inside the window
+        ends = jnp.cumsum(sizes)
+        sizes = jnp.clip(jnp.minimum(ends, first + rows)
+                         - jnp.maximum(ends - sizes, first), 0)
+        # a pair sorted before the window is as dead as one sorted after
+        slot_of = jnp.where(slot_of < first, rows, slot_of - first)
     live = (jnp.arange(rows) < n_live)[:, None]
     xs = _take_rows(h, token_of, slot_of, n_live)
     dot = _grouped_product(sizes, rows, h, w_gate, kernels, interpret)
@@ -256,9 +279,46 @@ def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
     y = jnp.where(live, dot((jax.nn.silu(a) * b).astype(h.dtype), w_down), 0)
     # (masked BEFORE the gate multiplies it: the gate's gradient is a sum
     # over y, and 0 x whatever-lies-there is not 0 if it is not finite)
-    gate_of = jnp.where(live, jnp.take(gates.reshape(-1), order[:rows]
+    gate_of = jnp.where(live, jnp.take(gates.reshape(-1), rows_of()
                                        )[:, None], 0)
     return _sum_rows(y * gate_of.astype(y.dtype), token_of, slot_of, n_live)
+
+
+#: the most bytes of sorted rows (`all_rows` x C) the whole-buffer branch
+#: of `_held_swiglu` computes at once: keye2_ep8's 131,072 rows of 2,048
+#: bfloat16, the largest a chip has run whole (PR 35). A larger buffer is
+#: walked a window of `fast_rows` rows at a time: at 32,768 tokens under
+#: top-10 the 327,680 rows whole were 6.7 GB of the backward pass's
+#: temporaries (five arrays of 1.25 GB and the combine's gather of 2 GB,
+#: compiled for a described v5e, PR 41), for a branch that balanced
+#: routing never takes
+_WHOLE_BUFFER_MAX = 512 << 20
+
+
+def _windows(sizes_of: Tuple[int, int], h) -> int:
+    """Windows of `fast_rows` rows the whole-buffer branch is walked in:
+    0 where the buffer is computed whole."""
+    fast_rows, all_rows = sizes_of
+    if all_rows * h.shape[1] * h.dtype.itemsize <= _WHOLE_BUFFER_MAX:
+        return 0
+    return -(-all_rows // fast_rows)
+
+
+def _in_windows(sizes_of, lowering, order, sizes, n_windows: int, k: int):
+    """(part(first row, h, gates, the three weights) -> what the window of
+    `fast_rows` rows from `first` on adds (T, C), the windows that hold a
+    live row). Every pair's sorted row is found once, outside the walk."""
+    fast_rows = sizes_of[0]
+    slot_of = jnp.argsort(order).astype(jnp.int32).reshape(-1, k)
+    padded = jnp.pad(order, (0, max(
+        n_windows * fast_rows - order.shape[0], 0)))
+
+    def part(first, *diff):
+        return _held_rows_swiglu(
+            fast_rows, *diff, order=padded, sizes=sizes, kernels=lowering[0],
+            interpret=lowering[1], window=(first, slot_of))
+
+    return part, (sizes.sum() + fast_rows - 1) // fast_rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -270,14 +330,27 @@ def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool], h,
     (`lowering`: the grouped products' (kernels, interpret)). The
     backward recomputes its branch from the inputs, so that no branch's
     intermediates cross the `cond` (autodiff through it would write the
-    untaken branch's residuals as zeros, at the large size)."""
+    untaken branch's residuals as zeros, at the large size). A whole
+    buffer past `_WHOLE_BUFFER_MAX` is walked in windows of `fast_rows`
+    rows, as many as hold a live row, summed in float32."""
     fast_rows, all_rows = sizes_of
     run = functools.partial(_held_rows_swiglu, h=h, gates=gates,
                             w_gate=w_gate, w_up=w_up, w_down=w_down,
                             order=order, sizes=sizes, kernels=lowering[0],
                             interpret=lowering[1])
+    n_windows = _windows(sizes_of, h)
+
+    def walk():
+        part, n = _in_windows(sizes_of, lowering, order, sizes, n_windows,
+                              gates.shape[1])
+        diff = (h, gates, w_gate, w_up, w_down)
+        return lax.fori_loop(
+            0, n, lambda i, acc: acc + part(
+                i * fast_rows, *diff).astype(jnp.float32),
+            jnp.zeros(h.shape, jnp.float32)).astype(h.dtype)
+
     return lax.cond(sizes.sum() <= fast_rows, lambda: run(fast_rows),
-                    lambda: run(all_rows))
+                    walk if n_windows else lambda: run(all_rows))
 
 
 def _held_swiglu_fwd(sizes_of, lowering, h, gates, w_gate, w_up, w_down,
@@ -297,8 +370,22 @@ def _held_swiglu_bwd(sizes_of, lowering, args, dy):
             return vjp(dy)
         return branch
 
+    def walk():
+        part, n = _in_windows(sizes_of, lowering, order, sizes, n_windows,
+                              diff[1].shape[1])
+
+        def more(i, acc):
+            _, vjp = jax.vjp(lambda *a: part(i * sizes_of[0], *a), *diff)
+            return tuple(a + g.astype(jnp.float32)
+                         for a, g in zip(acc, vjp(dy)))
+
+        acc = lax.fori_loop(0, n, more, tuple(
+            jnp.zeros(a.shape, jnp.float32) for a in diff))
+        return tuple(a.astype(d.dtype) for a, d in zip(acc, diff))
+
+    n_windows = _windows(sizes_of, diff[0])
     grads = lax.cond(sizes.sum() <= sizes_of[0], grads_at(sizes_of[0]),
-                     grads_at(sizes_of[1]))
+                     walk if n_windows else grads_at(sizes_of[1]))
     return (*grads, None, None)
 
 

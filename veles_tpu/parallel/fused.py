@@ -853,7 +853,14 @@ class FusedTrainStep:
                     x = x.astype(self.compute_dtype)
         if self.compute_dtype is not None:
             with jax.named_scope("cast_params"):
-                params = _tree_cast(params, self.compute_dtype)
+                # (a unit may name leaves that keep their master dtype:
+                # `fused_float32_params`, a linear layer's decay)
+                kept = [{k: p[k] for k in getattr(
+                    u, "fused_float32_params", ()) if k in p}
+                    for u, p in zip(self.forwards, params)]
+                params = tuple(
+                    {**c, **k} if k else c for c, k in zip(
+                        _tree_cast(tuple(params), self.compute_dtype), kept))
         # local_trace: trace the DENSE single-program form (no bound
         # collective axis names) for use under plain jit — GSPMD handles
         # any param sharding, gathering EP experts where needed (the

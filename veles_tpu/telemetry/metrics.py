@@ -615,6 +615,34 @@ def dsa_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
             "causal pairs of the tiles it visited", by_layer))
 
 
+def gdn_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
+    """The `veles_gdn_*` families of a Gated DeltaNet layer
+    (`ops/linear_attention.py`): tokens and chunks its chain along the
+    sequence walked, counted inside the step into int32 state
+    (`znicz/lm.py`), and of the last step the final state's root mean
+    square and the lowest cumulative log-decay a chunk reached (what a
+    chunked form that divides by decays would overflow on first);
+    published by whoever reads that state (`znicz.lm.publish_gdn_counters`),
+    never per step. Registered on first use."""
+    reg = reg or default_registry()
+    by_layer = ("layer",)
+    return SimpleNamespace(
+        steps=reg.counter("veles_gdn_steps_total",
+                          "train steps the counters cover", by_layer),
+        tokens=reg.counter("veles_gdn_tokens_total",
+                           "tokens the layer's chain walked", by_layer),
+        chunks=reg.counter("veles_gdn_chunks_total",
+                           "chunks the layer's chain walked", by_layer),
+        state_rms=reg.gauge(
+            "veles_gdn_state_rms",
+            "root mean square of the state after the last step's "
+            "sequences", by_layer),
+        decay_min=reg.gauge(
+            "veles_gdn_decay_min",
+            "lowest cumulative log-decay of a chunk in the last step",
+            by_layer))
+
+
 def setup_handles(reg: Optional[MetricsRegistry] = None) -> SimpleNamespace:
     """The `veles_setup_*` families `telemetry.tracer.phase` writes at a
     phase's close, and the gauge `veles_tpu/__init__.py` sets once."""

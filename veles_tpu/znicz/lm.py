@@ -22,6 +22,16 @@ holds (`ops/moe.py`); its selection bias and its load counters are step
 state that no gradient touches (`aux_arrays`, `fused_aux_update`), moved
 after every step under the scope `update/balance`.
 
+A block's token mixer is one of four (`BlockSpec(attention=...)`): latent
+attention; grouped-query attention over the keys an indexer selects
+(`indexed`); grouped-query attention behind an output gate (`gated`); a
+Gated DeltaNet, linear attention whose state is a matrix a head
+(`gated_delta`, `ops/linear_attention.py`). The blocks of one layer table
+need not be alike (`samples/qwen3next.py`: three `gated_delta` to one
+`gated`); one `jax.checkpoint` policy serves them all
+(`HCBlock.fused_remat_policy`). Their scopes: `dsa`, `attn`, `gdn` (with
+`proj`, `conv`, `scan`, `out` beneath it).
+
 Scopes inside a block, for a profile: `hc_pre` (the three maps, Sinkhorn,
 reading the sub-layer's input from the streams), `mla`, `hc_post` (writing
 the streams), `mlp`, or `moe/router`, `moe/dispatch`, `moe/experts`,
@@ -41,9 +51,11 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from veles_tpu.memory import Array
 from veles_tpu.ops import attention as oa
+from veles_tpu.ops import linear_attention as la
 from veles_tpu.ops import lm as ol
 from veles_tpu.ops import moe as om
 from veles_tpu.ops import variants
@@ -80,9 +92,14 @@ class BlockSpec:
                  v_dim: int = 0, kv_heads: int = 0, head_dim: int = 0,
                  index_heads: int = 0, index_dim: int = 0,
                  index_topk: int = 0, query_block: int = 256,
-                 key_bands: int = 4, n_experts: int = 0,
+                 key_bands: int = 4, rotary_dim: int = 0,
+                 key_heads: int = 0, value_heads: int = 0, key_dim: int = 0,
+                 value_dim: int = 0, conv_kernel: int = 4, chunk: int = 64,
+                 scan_groups: int = 1,
+                 norm: str = "plain", n_experts: int = 0,
                  held: Sequence[int] = (0, 0), top_k: int = 0,
                  scoring: str = "sigmoid_bias", shared: bool = True,
+                 shared_gate: bool = False,
                  grouped: str = "ragged_dot", routed_scaling: float = 1.0,
                  bias_update_speed: float = 0.001,
                  rope_theta: float = 10000.0,
@@ -93,7 +110,9 @@ class BlockSpec:
         for what, value, known in (
                 ("ffn", ffn, ("dense", "experts")),
                 ("residual", residual, ("hc", "plain")),
-                ("attention", attention, ("latent", "indexed")),
+                ("attention", attention, ("latent", "indexed", "gated",
+                                          "gated_delta")),
+                ("norm", norm, ("plain", "zero_centred")),
                 ("scoring", scoring, ("sigmoid_bias", "softmax")),
                 ("grouped", grouped, ("ragged_dot", "pallas"))):
             if value not in known:
@@ -104,6 +123,19 @@ class BlockSpec:
         self.c, self.n = features, streams
         self.residual, self.attention = residual, attention
         self.scoring, self.shared = scoring, bool(shared)
+        if shared_gate and not shared:
+            raise ValueError("a gate with no shared expert behind it")
+        if norm != "plain" and attention in ("latent", "indexed"):
+            raise ValueError(f"{attention} attention's inner norms are "
+                             f"plain ones: norm {norm!r} is not implemented")
+        self.shared_gate = bool(shared_gate)
+        #: 1 where a norm's scale is stored less 1 (`ops.lm.rms_norm`)
+        self.norm, self.norm_offset = norm, float(norm == "zero_centred")
+        self.rotary_dim = rotary_dim or head_dim
+        self.key_heads, self.value_heads = key_heads, value_heads
+        self.key_dim, self.value_dim = key_dim, value_dim
+        self.conv_kernel, self.chunk = conv_kernel, chunk
+        self.scan_groups = int(scan_groups)
         self.grouped = grouped
         self.n_heads, self.q_rank, self.kv_rank = n_heads, q_rank, kv_rank
         self.nope, self.rope, self.v_dim = nope, rope, v_dim
@@ -145,18 +177,22 @@ class BlockSpec:
         return v.name
 
     def mla_lowering(self, seq: int) -> Optional[str]:
-        """What the core of latent attention traces over sequences of
-        `seq` tokens: the registry's `flash_attn` lowering where it is a
-        kernel the platform runs and `pallas_kernels.flash_view` admits
-        the shape, else `xla_blocked`, the blocked XLA form (this
-        attention's fallback in the place of the op's `xla_mha`); None
-        for another kind of attention."""
-        if self.attention != "latent":
+        """What the core of latent or gated attention traces over
+        sequences of `seq` tokens: the registry's `flash_attn` lowering
+        where it is a kernel the platform runs and
+        `pallas_kernels.flash_view` admits the shape, else `xla_blocked`,
+        the blocked XLA form (these attentions' fallback in the place of
+        the op's `xla_mha`); None for another kind of attention."""
+        if self.attention == "latent":
+            key, value = self.nope + self.rope, self.v_dim
+        elif self.attention == "gated":
+            key = value = self.head_dim
+        else:
             return None
         v = variants.resolve("flash_attn", unit=self)
         if v.pallas:
             from veles_tpu.ops import pallas_kernels as pk
-            if pk.flash_view(seq, self.nope + self.rope, self.v_dim):
+            if pk.flash_view(seq, key, value):
                 return v.name
         return "xla_blocked"
 
@@ -192,6 +228,23 @@ class BlockSpec:
                 "attn_kv_norm": (self.kv_rank,),
                 "attn_w_ukv": (self.kv_rank, h * (self.nope + self.v_dim)),
                 "attn_w_o": (h * self.v_dim, c)})
+        elif self.attention == "gated":
+            d, kv = self.head_dim, self.kv_heads
+            out.update({
+                "attn_norm": (c,), "attn_w_q": (c, h * 2 * d),
+                "attn_w_k": (c, kv * d), "attn_w_v": (c, kv * d),
+                "attn_q_norm": (d,), "attn_k_norm": (d,),
+                "attn_w_o": (h * d, c)})
+        elif self.attention == "gated_delta":
+            kw = self.key_heads * self.key_dim
+            vw = self.value_heads * self.value_dim
+            out.update({
+                "attn_norm": (c,), "attn_w_qkvz": (c, 2 * kw + 2 * vw),
+                "attn_w_ba": (c, 2 * self.value_heads),
+                "attn_conv": (self.conv_kernel, 2 * kw + vw),
+                "attn_a_log": (self.value_heads,),
+                "attn_dt_bias": (self.value_heads,),
+                "attn_o_norm": (self.value_dim,), "attn_w_o": (vw, c)})
         else:
             d, kv = self.head_dim, self.kv_heads
             hi, di = self.index_heads, self.index_dim
@@ -216,13 +269,27 @@ class BlockSpec:
                 out.update({"moe_shared_gate": (c, w),
                             "moe_shared_up": (c, w),
                             "moe_shared_down": (w, c)})
+            if self.shared_gate:
+                # (a matrix of one column: a leaf of one dimension is
+                # a bias to the optimizer)
+                out["moe_shared_mix"] = (c, 1)
         return out
 
     def initial(self, name: str, shape: Tuple[int, ...], fill) -> np.ndarray:
         """A leaf's initial value; `fill(shape, std)` draws the normal
         ones from the unit's generator."""
-        if name.endswith("norm"):
+        if name == "attn_a_log":
+            # Qwen3-Next's: the decay rate uniform on (0, 16)
+            if not self.init_std:       # (a caller that brings its own)
+                return np.zeros(shape, np.float32)
+            rate = 8.0 + fill(shape, 8.0 / np.sqrt(3.0), "uniform")
+            return np.log(np.maximum(rate, 1e-3)).astype(np.float32)
+        if name == "attn_dt_bias":
             return np.ones(shape, np.float32)
+        if name.endswith("norm"):
+            # (the gated norm of a linear layer's output is a plain one)
+            zero = self.norm_offset and name != "attn_o_norm"
+            return np.full(shape, 0.0 if zero else 1.0, np.float32)
         if name.endswith("_bias"):
             return np.zeros(shape, np.float32)
         if name[-5:] in ("a_pre", "a_res") or name.endswith("a_post"):
@@ -247,6 +314,21 @@ class BlockSpec:
             out.update({"pairs_" + k: ((2,), np.int32)
                         for k in ("causal", "selected", "scored")})
             out["selected"] = ((batch * seq, seq // 8), np.uint8)
+            if self.ffn != "experts":
+                out["steps"] = ((1,), np.int32)
+        if self.attention == "gated_delta":
+            # tokens and chunks walked so far; of the last step the final
+            # state's root mean square and the lowest cumulative
+            # log-decay a chunk reached
+            out.update({"gdn_tokens": ((1,), np.int32),
+                        "gdn_chunks": ((1,), np.int32),
+                        "gdn_state_rms": ((1,), np.float32),
+                        "gdn_decay_min": ((1,), np.float32),
+                        # the last step's final state itself, which a
+                        # reference's recurrence can be held against
+                        "gdn_state": ((batch, self.value_heads,
+                                       self.key_dim, self.value_dim),
+                                      np.float32)})
             if self.ffn != "experts":
                 out["steps"] = ((1,), np.int32)
         if self.ffn == "experts":
@@ -277,6 +359,10 @@ class BlockSpec:
     def _attention(self, p: Dict[str, Any], h, batch: int):
         if self.attention == "indexed":
             return self._indexed_attention(p, h, batch)
+        if self.attention == "gated":
+            return self._gated_attention(p, h, batch)
+        if self.attention == "gated_delta":
+            return self._gated_delta(p, h, batch)
         with jax.named_scope("mla"):
             seq = h.shape[0] // batch
             rs = self.rope_scaling
@@ -292,8 +378,7 @@ class BlockSpec:
             hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
             lowering = self.mla_lowering(seq)
             y = oa.latent_attention(
-                {k[len("attn_"):]: v for k, v in p.items()
-                 if k.startswith("attn_")},
+                _under(p, "attn_"),
                 hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
                 nope=self.nope, rope=self.rope, v_dim=self.v_dim, cos=cos,
                 sin=sin, scale=(self.nope + self.rope) ** -0.5
@@ -302,13 +387,65 @@ class BlockSpec:
                 else variants.get("flash_attn", lowering).apply)
             return y.reshape(h.shape), None
 
+    def _norm(self, x, scale):
+        return ol.rms_norm(x, scale, self.norm_eps, offset=self.norm_offset)
+
+    def _gated_attention(self, p: Dict[str, Any], h, batch: int):
+        with jax.named_scope("attn"):
+            seq = h.shape[0] // batch
+            lowering = self.mla_lowering(seq)
+            y = oa.gated_attention(
+                _under(p, "attn_"),
+                self._norm(h, p["attn_norm"]).reshape(batch, seq, self.c),
+                n_heads=self.n_heads, kv_heads=self.kv_heads,
+                head_dim=self.head_dim, rotary_dim=self.rotary_dim,
+                rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                norm_offset=self.norm_offset,
+                flash=None if lowering == "xla_blocked"
+                else variants.get("flash_attn", lowering).apply)
+            return y.reshape(h.shape), None
+
+    def _gated_delta(self, p: Dict[str, Any], h, batch: int):
+        """The Gated DeltaNet on `scan_groups` groups of the sequences, one
+        after another (a `lax.map`; 1: all at once), each under a
+        `jax.checkpoint` of its own: the layer's insides exist for one
+        group at a time, forward and backward, its chain along the sequence
+        is that many times as long, and the block's checkpoint keeps the
+        layer's output by name (`ops.linear_attention.GDN_OUT`), so that
+        the layer is formed again once, by its own checkpoint, not twice."""
+        with jax.named_scope("gdn"):
+            seq = h.shape[0] // batch
+            own = _under(p, "attn_")
+            hn = self._norm(h, p["attn_norm"]).reshape(batch, seq, self.c)
+
+            groups = self.scan_groups
+            if batch % groups:
+                raise ValueError(f"{batch} sequences do not divide into "
+                                 f"{groups} groups")
+            y, seen = jax.lax.map(
+                jax.checkpoint(lambda x: la.gated_delta_net(
+                    own, x, key_heads=self.key_heads,
+                    value_heads=self.value_heads, key_dim=self.key_dim,
+                    value_dim=self.value_dim, chunk=self.chunk,
+                    norm_eps=self.norm_eps)),
+                hn.reshape(groups, batch // groups, seq, self.c))
+            y = checkpoint_name(y.reshape(batch, seq, self.c), la.GDN_OUT)
+            return y.reshape(h.shape), {
+                "gdn_state_rms": jnp.sqrt(jnp.mean(jnp.square(
+                    seen["gdn_state_rms"]))),
+                "gdn_decay_min": seen["gdn_decay_min"].min(),
+                "gdn_state": seen["gdn_state"].reshape(
+                    (batch,) + seen["gdn_state"].shape[2:]),
+                "gdn_tokens": jnp.asarray(batch * seq, jnp.int32),
+                "gdn_chunks": jnp.asarray(
+                    batch * la.chunks_of(seq, self.chunk)[1], jnp.int32)}
+
     def _indexed_attention(self, p: Dict[str, Any], h, batch: int):
         with jax.named_scope("dsa"):
             seq = h.shape[0] // batch
             hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
             y, extra = variants.resolve("dsa", unit=self).apply(
-                {k[len("attn_"):]: v for k, v in p.items()
-                 if k.startswith("attn_")},
+                _under(p, "attn_"),
                 hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
                 kv_heads=self.kv_heads, head_dim=self.head_dim,
                 index_heads=self.index_heads, index_dim=self.index_dim,
@@ -332,7 +469,7 @@ class BlockSpec:
 
     def _experts(self, p: Dict[str, Any], h, bias):
         with jax.named_scope("moe"):
-            hn = ol.rms_norm(h, p["moe_norm"], self.norm_eps)
+            hn = self._norm(h, p["moe_norm"])
             out = {}
             with jax.named_scope("router"):
                 logits = jnp.matmul(hn, p["moe_w_router"],
@@ -358,15 +495,21 @@ class BlockSpec:
                     variants.pallas_interpret_active())
             if self.shared:
                 with jax.named_scope("shared"):
-                    y = y + ol.swiglu(hn, p["moe_shared_gate"],
-                                      p["moe_shared_up"],
-                                      p["moe_shared_down"])
+                    ys = ol.swiglu(hn, p["moe_shared_gate"],
+                                   p["moe_shared_up"], p["moe_shared_down"])
+                    if self.shared_gate:
+                        # a sigmoid gate of its own a token (Qwen2-MoE's)
+                        mix = jax.nn.sigmoid(jnp.matmul(
+                            hn, p["moe_shared_mix"],
+                            preferred_element_type=jnp.float32))
+                        ys = (ys.astype(jnp.float32) * mix).astype(ys.dtype)
+                    y = y + ys
         return y, {**out, "load": load, "dropped": dropped,
                    "picked": idx.astype(jnp.int32)}
 
     def _mlp(self, p: Dict[str, Any], h):
         with jax.named_scope("mlp"):
-            hn = ol.rms_norm(h, p["mlp_norm"], self.norm_eps)
+            hn = self._norm(h, p["mlp_norm"])
             return ol.swiglu(hn, p["mlp_w_gate"], p["mlp_w_up"],
                              p["mlp_w_down"]), None
 
@@ -408,6 +551,13 @@ class BlockSpec:
                 "pairs_selected": _add_wide(aux["pairs_selected"],
                                             out["pairs_selected"]),
                 "selected": out["selected"]})
+        if "gdn_tokens" in aux:
+            new.update({
+                "gdn_tokens": aux["gdn_tokens"] + out["gdn_tokens"],
+                "gdn_chunks": aux["gdn_chunks"] + out["gdn_chunks"],
+                "gdn_state": out["gdn_state"],
+                **{k: out[k].reshape(1).astype(jnp.float32)
+                   for k in ("gdn_state_rms", "gdn_decay_min")}})
         if "load" in aux:
             load = out["load"]
             lf = load.astype(jnp.float32)
@@ -422,6 +572,11 @@ class BlockSpec:
                 + out["dropped"].astype(jnp.int32),
                 "picked": out["picked"]})
         return new
+
+
+def _under(p: Dict[str, Any], prefix: str) -> Dict[str, Any]:
+    """The leaves of `p` whose names start with `prefix`, without it."""
+    return {k[len(prefix):]: v for k, v in p.items() if k.startswith(prefix)}
 
 
 WIDE = 20
@@ -444,21 +599,24 @@ def _wide(acc) -> int:
 #: lowering that names nothing saves nothing), and the held experts'
 #: output, so that a hyper-connection's backward, which asks for its
 #: sub-layer's output, does not run their products a third time (a plain
-#: residual path asks for nothing, and nothing is kept for it). ONE object
+#: residual path asks for nothing, and nothing is kept for it), and a
+#: Gated DeltaNet layer's output, which the layer's own checkpoints (a
+#: group of sequences each) would otherwise form a second time. ONE object
 #: for every block and for the head's MTP block: `jax.checkpoint` splits a
 #: jitted kernel's jaxpr by its policy and caches the split by the policy's
 #: identity, so a policy made anew a block leaves the step with a copy of
 #: every kernel's body a block (28 of `veles_dsa_pmean` in the six-block
 #: step, lowered here for a described v5e)
 _SAVED_POLICY = jax.checkpoint_policies.save_only_these_names(
-    *oa.DSA_SAVED, *oa.FLASH_SAVED, *om.MOE_SAVED)
+    *oa.DSA_SAVED, *oa.FLASH_SAVED, *om.MOE_SAVED, la.GDN_OUT)
 
 
 def _gaussian(unit):
     """Normal at `std` from the unit's generator; zeros at `std` 0 (a
     caller that brings its own weights pays for no draw)."""
-    return lambda shape, std: (unit._fill(shape, "gaussian", std) if std
-                               else np.zeros(shape, np.float32))
+    return lambda shape, std, filling="gaussian": (
+        unit._fill(shape, filling, std) if std
+        else np.zeros(shape, np.float32))
 
 
 def _hc_effective(unit) -> Optional[str]:
@@ -569,7 +727,10 @@ class HCBlock(_LMUnit):
                      "norm_eps", "init_std", "residual", "attention",
                      "scoring", "shared", "kv_heads", "head_dim",
                      "index_heads", "index_dim", "index_topk",
-                     "query_block", "key_bands", "grouped")
+                     "query_block", "key_bands", "grouped", "rotary_dim",
+                     "key_heads", "value_heads", "key_dim", "value_dim",
+                     "conv_kernel", "chunk", "scan_groups",
+                     "norm", "shared_gate")
         self._spec_kw = {k: kwargs.pop(k) for k in spec_keys if k in kwargs}
         super().__init__(workflow, **kwargs)
         self.streams = streams
@@ -596,9 +757,13 @@ class HCBlock(_LMUnit):
     #: what the step's `jax.checkpoint` around this unit saves: indexed
     #: attention's thresholds and outputs, the flash kernels' outputs and
     #: logsumexps, so that the backward pass neither selects nor attends a
-    #: second time, and the held experts' output where the residual path's
-    #: backward reads it
+    #: second time, the held experts' output where the residual path's
+    #: backward reads it, and a Gated DeltaNet layer's output
     fused_remat_policy = staticmethod(_SAVED_POLICY)
+
+    #: leaves the fused step hands on in their master dtype whatever the
+    #: compute dtype: a linear layer's decay is float32
+    fused_float32_params = ("attn_a_log", "attn_dt_bias")
 
     def fused_apply(self, params, x, *, key=None, train=True, aux=None):
         self.spec.allow_pallas = getattr(self, "allow_pallas", True)
@@ -660,8 +825,13 @@ class LMHead(_LMUnit):
                  mtp_weight: float = 0.3,
                  term_weights: Optional[Dict[str, float]] = None,
                  norm_eps: float = 1e-6, init_std: float = 0.02,
-                 **kwargs: Any) -> None:
+                 norm: str = "plain", **kwargs: Any) -> None:
         super().__init__(workflow, **kwargs)
+        if norm not in ("plain", "zero_centred"):
+            raise ValueError(f"norm must be plain or zero_centred, "
+                             f"not {norm!r}")
+        #: 1 where the final norm's scale is stored less 1
+        self.norm_offset = float(norm == "zero_centred")
         self.vocab, self.streams = vocab, streams
         self.loss_chunk, self.mtp_weight = loss_chunk, mtp_weight
         self.norm_eps, self.init_std = norm_eps, init_std
@@ -700,7 +870,9 @@ class LMHead(_LMUnit):
         for name, shape in own.items():
             arr = getattr(self, name)
             if not arr:
-                arr.reset(np.ones(shape, np.float32) if len(shape) == 1
+                arr.reset(np.full(shape, 1.0 - self.norm_offset
+                                  if name == "final_norm" else 1.0,
+                                  np.float32) if len(shape) == 1
                           else fill(shape, self.init_std))
         for name in self._anames:
             if not name.startswith("mtp_") and not getattr(self,
@@ -729,7 +901,8 @@ class LMHead(_LMUnit):
         chunk = min(self.loss_chunk, n * s)
         with jax.named_scope("head"):
             ce, n_err = ol.chunked_ce(
-                ol.rms_norm(trunk, p["final_norm"], self.norm_eps),
+                ol.rms_norm(trunk, p["final_norm"], self.norm_eps,
+                            offset=self.norm_offset),
                 p["weights"], targets[..., 0].reshape(-1), wt, chunk)
             ce = ce / denom
         out = {"ce_main": ce, "ce_mtp": jnp.zeros_like(ce)}
@@ -821,6 +994,42 @@ def publish_moe_counters(now: Dict[str, Dict[str, int]],
     h.dropped.set_total(dropped)
     if balance_reached is not None:
         h.reached.set(1.0 if balance_reached else 0.0)
+
+
+def gdn_counts(step, aux) -> Dict[str, Dict[str, float]]:
+    """{layer: {steps, tokens, chunks, state_rms, decay_min}} from the
+    host copy `aux` of a fused step's `state["aux"]`: one entry per Gated
+    DeltaNet block, named by its unit's scope (`L01`). Tokens and chunks
+    are counted so far; the final state's root mean square and the lowest
+    cumulative log-decay of a chunk are the last step's."""
+    out = {}
+    for scope, u, a in zip(step.scopes, step.forwards, aux):
+        spec = getattr(u, "spec", None)
+        if spec is None or spec.attention != "gated_delta" \
+                or isinstance(u, LMHead):
+            continue
+        out[scope.split(".")[0]] = {
+            "steps": int(a["steps"][0]), "tokens": int(a["gdn_tokens"][0]),
+            "chunks": int(a["gdn_chunks"][0]),
+            "state_rms": float(a["gdn_state_rms"][0]),
+            "decay_min": float(a["gdn_decay_min"][0])}
+    return out
+
+
+def publish_gdn_counters(now: Dict[str, Dict[str, float]],
+                         base: Optional[Dict[str, Dict[str, float]]] = None
+                         ) -> None:
+    """Set the `veles_gdn_*` families (`telemetry/metrics.py`) to what
+    `gdn_counts` read `now`, the counters less what it read at `base`."""
+    from veles_tpu.telemetry import metrics
+    h = metrics.gdn_handles()
+    for layer, c in now.items():
+        b = (base or {}).get(layer, dict.fromkeys(c, 0))
+        for key, fam in (("steps", h.steps), ("tokens", h.tokens),
+                         ("chunks", h.chunks)):
+            fam.labels(layer=layer).set_total(c[key] - b[key])
+        h.state_rms.labels(layer=layer).set(c["state_rms"])
+        h.decay_min.labels(layer=layer).set(c["decay_min"])
 
 
 def dsa_counts(step, aux) -> Dict[str, Dict[str, int]]:
