@@ -220,6 +220,46 @@ def test_grouped_product_kernels_compile_at_published_widths(
         assert txt.count("veles_gmm") >= 2 and "veles_tgmm" in txt
 
 
+def test_gdn_chunk_kernels_compile_at_the_cells_shapes(one_chip,
+                                                       compiled_pallas):
+    """One call of `qwen3next_ep16.seq8k` (ISSUE 42): 8,192 chunk-heads
+    (2 sequences x 128 chunks x 32 value heads) of 64 tokens, keys and
+    values of 128, bfloat16 under float32 decays. The stage forward, and
+    forward + backward through its `custom_vjp`, hold the two kernels
+    under their fixed names, the backward under `gdn/scan` again, inside
+    the scoped VMEM they ask for (32 chunk-heads a grid step: the
+    backward's eleven blocks a chunk-head, double-buffered)."""
+    from veles_tpu.ops import linear_attention as la
+    n, b, c, d = 128, 64, 64, 128
+    g = pk.gdn_view(n * b, c, d, d, jnp.float32, jnp.bfloat16)
+    assert g == 32
+    assert 2 * g * 2 * c * 11 * d <= pk._GDN_BLOCK_BUDGET < pk._GDN_VMEM_LIMIT
+    assert la._kernels_take(n * b, c, d, d, jnp.float32, jnp.bfloat16)
+    mat = _sds(one_chip, (n, b, c, d), jnp.bfloat16)
+    row = _sds(one_chip, (n, b, c), jnp.float32)
+
+    def fwd(q, k, v, gam, beta):
+        return la._operands_kernels(q, k, v, gam, beta)[:6]
+
+    def fwd_bwd(q, k, v, gam, beta):
+        out, vjp = jax.vjp(fwd, q, k, v, gam, beta)
+        return vjp(out)                     # the cotangent: the results
+
+    txt = _compile(fwd, mat, mat, mat, row, row)
+    assert txt.count("tpu_custom_call") == 1 and "veles_gdn_chunk_fwd" in txt
+    txt = _compile(fwd_bwd, mat, mat, mat, row, row)
+    for name in ("veles_gdn_chunk_fwd", "veles_gdn_chunk_bwd"):
+        assert name in txt, name
+    assert re.search(
+        r'op_name="[^"]*gdn\)*/scan/jit\(gdn_chunk_backward_pallas\)', txt)
+    # a shape the view refuses is the caller's fault
+    with pytest.raises(ValueError, match="gdn_view"):
+        pk.gdn_chunk_forward_pallas(
+            *(jnp.zeros((4, 32, d), jnp.bfloat16),) * 3,
+            *(jnp.zeros((4, 32), jnp.float32),) * 2,
+            inverse_block=la.INVERSE_BLOCK)
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_hyper_connection_kernels_compile_at_published_widths(
         one_chip, dtype, compiled_pallas):
@@ -895,10 +935,13 @@ def test_keye2_ep8_compiled_step_fits_one_chip(keye2_step):
 #: has one full layer); the grouped products either width first on the
 #: fast rows, forward, again where the backward recomputes, and the other
 #: way round, the whole-buffer branch being the same rows a window at a
-#: time (`ops.moe._WHOLE_BUFFER_MAX`)
+#: time (`ops.moe._WHOLE_BUFFER_MAX`); the operand stage of the linear
+#: layers' scan forward as the forward pass traces it and as a group's
+#: `jax.checkpoint` traces it again (6 sites, 2 bodies), backward once
 QWEN3NEXT_BODIES = (("veles_flash_fwd", 1), ("veles_flash_dq", 1),
                     ("veles_flash_dkv", 1), ("veles_gmm", 10),
-                    ("veles_tgmm", 4))
+                    ("veles_tgmm", 4), ("veles_gdn_chunk_fwd", 2),
+                    ("veles_gdn_chunk_bwd", 1))
 # (a linear layer walks its sequences in `scan_groups` groups, the body of
 # a `lax.map`: `gdn/while/body/.../proj/...`; the chain along the sequence
 # is the body of a `lax.scan` inside it: `.../scan/while/body/...`; its
@@ -908,7 +951,8 @@ QWEN3NEXT_SCOPES = ("/gdn/while/", "/proj/", "/conv/", "/scan/out/",
                     "/moe/router/", "/moe/experts/", "/moe/shared/",
                     "/moe/balance_loss/", "rematted_computation",
                     "veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv",
-                    "veles_gmm", "veles_tgmm")
+                    "veles_gmm", "veles_tgmm", "veles_gdn_chunk_fwd",
+                    "veles_gdn_chunk_bwd")
 
 
 @pytest.fixture(scope="module")
@@ -928,13 +972,16 @@ def test_qwen3next_ep16_train_step_compiles_and_fits_one_chip(
     """`benchmark/configs/qwen3next_ep16.json` through the sample's layer
     table, `StandardWorkflow` and `FusedTrainStep`: 4 sequences of 8,192
     tokens, bfloat16, one `jax.checkpoint` a block; three Gated DeltaNet
-    blocks whose chunked scan is plain XLA around one `lax.scan` and its
-    hand-written backward, one gated full-attention block whose core is
+    blocks whose chunked scan is two kernels and plain XLA around one
+    `lax.scan` and its hand-written backward, one gated full-attention
+    block whose core is
     the three `veles_flash_*` kernels (ISSUE 41: a key-value head repeated
     to its 8 query heads), the held experts' products as `veles_gmm` /
     `veles_tgmm`. Traced and lowered for a described v5e, ONE trace (the
     compile of what is lowered here, and its memory, is
-    `test_qwen3next_ep16_compiled_step_fits_one_chip`'s, `slow`). The units
+    `test_qwen3next_ep16_compiled_step_fits_one_chip`'s, `slow`). Since
+    ISSUE 42 the chunks' operand stage is `veles_gdn_chunk_fwd` / `_bwd`,
+    each traced once and called under `gdn/.../scan`. The units
     hold zeros (`init_std` 0: no draw), nothing is put on a device."""
     row = qwen3next_lowered
     cfg, step = row["config"], row["step"]
@@ -950,6 +997,17 @@ def test_qwen3next_ep16_train_step_compiles_and_fits_one_chip(
     for kernel, bodies in QWEN3NEXT_BODIES:
         assert row["kernels"][kernel]["bodies"] == bodies, row["kernels"]
     assert set(row["kernels"]) == {k for k, _ in QWEN3NEXT_BODIES}
+    # the chunks' operand stage is the two kernels (ISSUE 42): a site a
+    # linear layer in the forward pass, one where its group's checkpoint
+    # forms the layer again, one backward under the scope the backward
+    # opens again (`gdn_scan_ms` reads all three); a site is the body of
+    # the groups' loop: two calls a step
+    assert row["kernels"]["veles_gdn_chunk_fwd"]["sites"] == 6
+    assert row["kernels"]["veles_gdn_chunk_bwd"]["sites"] == 3
+    for path in ("checkpoint/scan/jit(gdn_chunk_forward_pallas)",
+                 "rematted_computation/scan/jit(gdn_chunk_forward_pallas)",
+                 "gdn/scan/jit(gdn_chunk_backward_pallas)"):
+        assert path in txt, path
     # the fast rows and the walk in windows, forward and backward, of four
     # expert layers
     assert row["conds"] == 8, row["conds"]

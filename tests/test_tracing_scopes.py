@@ -209,7 +209,22 @@ PALLAS_SITES = {
         np.ones((128, 2, 8), np.float32), w, np.ones((128, 8), np.float32),
         0).sum()), np.ones((128, 2), np.float32),
         ["veles_dsa_index_fwd", "veles_dsa_index_bwd"]),
+    # the chunked Gated DeltaNet over two chunks of 64 and two heads of
+    # 128 (ISSUE 42): the operand stage forward and, by the values'
+    # gradient, backward
+    "gdn_chunk": (jax.grad(lambda v: _delta_chunks(v).sum()),
+                  np.ones((1, 128, 2, 128), np.float32),
+                  ["veles_gdn_chunk_fwd", "veles_gdn_chunk_bwd"]),
 }
+
+
+def _delta_chunks(v):
+    import jax.numpy as jnp
+
+    from veles_tpu.ops import linear_attention as la
+    v = v.astype(jnp.bfloat16)
+    g = -jnp.ones(v.shape[:3], jnp.float32)
+    return la.gated_delta_chunked(0.1 * v, 0.1 * v, v, g, -0.5 * g)[0]
 
 
 def _grouped(x):
@@ -243,9 +258,9 @@ def test_every_pallas_call_has_its_fixed_name(site):
     assert set(want) <= set(pk.KERNEL_NAMES.values())
 
 
-def test_all_twenty_kernels_are_named_and_no_name_twice():
+def test_all_twenty_two_kernels_are_named_and_no_name_twice():
     names = list(pk.KERNEL_NAMES.values())
-    assert len(names) == 20 == len(set(names))
+    assert len(names) == 22 == len(set(names))
     with open(pk.__file__) as f:
         src = f.read()
     assert src.count("pl.pallas_call(") == src.count("name=KERNEL_NAMES[")

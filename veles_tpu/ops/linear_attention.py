@@ -32,6 +32,21 @@ Precision: gates, decays, the inverse and the state are float32 (`scan_dtype`,
 an argument of the functions here and no key of a layer table, says
 otherwise only where a test breaks it on purpose); the matrix products read
 operands of the activations' dtype and accumulate in float32.
+
+One algorithm, two implementations of its first stage (ISSUE 42). What is
+formed "for all chunks at once" before the chain (`A`, `T`, `W`, `U0`, the
+decayed keys and queries, the decay-weighted `Q K^T`) is a dozen (C, C)
+float32 matrices a chunk and head; as XLA traces the equations
+(`_operands_xla`) each passes through HBM with its 64 lanes padded to 128.
+On a TPU, for chunks of 64, heads of whole lanes, float32 decays and
+bfloat16 operands (`pallas_kernels.gdn_view` says the rule, nothing else
+chooses: no argument, key or variable), the stage is `_chunk_operands`: the
+kernels `veles_gdn_chunk_fwd` and `veles_gdn_chunk_bwd` under a
+`jax.custom_vjp` that keeps its INPUTS alone, every (C, C) matrix living
+and dying in VMEM, the backward forming `T` and the decay matrix again
+(which is the recomputation the XLA form's own `jax.checkpoint` asks for,
+inside VMEM). The cumulative sums along a chunk, the chain and the
+outputs are XLA's in both.
 """
 
 from __future__ import annotations
@@ -210,6 +225,99 @@ def _delta_scan_bwd(static, res, cts):
 _delta_scan.defvjp(_delta_scan_fwd, _delta_scan_bwd)
 
 
+# -- the chunks' operands as two kernels -----------------------------------------
+
+def _kernels_take(chunk_heads: int, chunk: int, dk: int, dv: int,
+                  scan_dtype, op) -> bool:
+    """Whether the operand stage runs as `veles_gdn_chunk_fwd` / `_bwd`:
+    on a TPU, or where a test asked for interpret mode, for the shapes
+    `pallas_kernels.gdn_view` takes."""
+    from veles_tpu.ops import pallas_kernels as pk
+    return bool((pk._interpret() or pk.available())
+                and pk.gdn_view(chunk_heads, chunk, dk, dv, scan_dtype, op))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunk_operands(interpret, q, k, v, gamma, beta):
+    """`gated_delta_chunked`'s `operands` from the cumulative log-decay on,
+    as ONE kernel a direction in which every (C, C) matrix of a chunk
+    stays in VMEM: q and k (n, B, C, dk), v (n, B, C, dv) in the operands'
+    dtype, gamma and beta (n, B, C) float32 -> (w, u0, kd, last, attn,
+    qg). It keeps its inputs alone: the backward kernel forms the inverse
+    and the decay matrix again."""
+    return _chunk_operands_fwd(interpret, q, k, v, gamma, beta)[0]
+
+
+def _over_chunk_heads(kernel, interpret, *arrays):
+    """`kernel` over (n, B, ...) arrays as the (n B, ...) chunk-heads it
+    takes, its results laid out (n, B, ...) again."""
+    lead = arrays[0].shape[:2]
+    return tuple(
+        a.reshape(lead + a.shape[1:]) for a in kernel(
+            *(a.reshape((-1,) + a.shape[2:]) for a in arrays),
+            inverse_block=INVERSE_BLOCK, interpret=interpret))
+
+
+def _chunk_operands_fwd(interpret, q, k, v, gamma, beta):
+    from veles_tpu.ops import pallas_kernels as pk
+    w, u0, kd, attn, qg = _over_chunk_heads(
+        pk.gdn_chunk_forward_pallas, interpret, q, k, v, gamma, beta)
+    return ((w, u0, kd, jnp.exp(gamma[..., -1]), attn, qg),
+            (q, k, v, gamma, beta))
+
+
+def _chunk_operands_bwd(interpret, res, cts):
+    from veles_tpu.ops import pallas_kernels as pk
+    gamma = res[3]
+    d_w, d_u0, d_kd, d_last, d_attn, d_qg = cts
+    with jax.named_scope("gdn"), jax.named_scope("scan"):
+        d_q, d_k, d_v, d_gamma, d_beta = _over_chunk_heads(
+            pk.gdn_chunk_backward_pallas, interpret, *res, d_w, d_u0, d_kd,
+            d_attn, d_qg)
+        d_gamma = d_gamma.at[..., -1].add(d_last * jnp.exp(gamma[..., -1]))
+    return d_q, d_k, d_v, d_gamma, d_beta
+
+
+_chunk_operands.defvjp(_chunk_operands_fwd, _chunk_operands_bwd)
+
+
+def _operands_xla(op, q, k, v, g, beta):
+    """The chunks' operands in plain XLA: q and k (n, B, C, dk), v (n, B,
+    C, dv) in the products' dtype `op`, g and beta (n, B, C) -> (w, u0, kd,
+    last, attn, qg, the lowest cumulative log-decay a chunk reaches)."""
+    chunk = q.shape[-2]
+    gamma = jnp.cumsum(g, axis=-1).astype(jnp.float32)      # (nc, B, C)
+    beta = beta.astype(jnp.float32)[..., None]
+    lower = np.tril(np.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(
+        lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+    a = jnp.where(np.tril(lower, -1),
+                  beta * decay * _dot("bnik,bnjk->bnij", k, k), 0.0)
+    t = unit_lower_inverse(a).astype(op)
+    grow = jnp.exp(gamma)[..., None]                        # (nc, B, C, 1)
+    kf = k.astype(jnp.float32)
+    w = _dot("bnij,bnjk->bnik", t, (beta * grow * kf).astype(op))
+    u0 = _dot("bnij,bnjv->bniv", t,
+              (beta * v.astype(jnp.float32)).astype(op))
+    kd = (jnp.exp(gamma[..., -1:] - gamma)[..., None] * kf).astype(op)
+    attn = (decay * _dot("bnik,bnjk->bnij", q, k)).astype(op)
+    qg = (grow * q.astype(jnp.float32)).astype(op)
+    return (w.astype(op), u0.astype(op), kd, jnp.exp(gamma[..., -1]),
+            attn, qg, lax.stop_gradient(gamma[..., -1].min()))
+
+
+def _operands_kernels(q, k, v, g, beta):
+    """`_operands_xla`'s results through the two kernels. The sums along a
+    chunk stay XLA's (exact in float32; as a triangular product at one
+    bfloat16 pass they would round g), and so does what is a function of
+    a chunk's last gamma alone."""
+    from veles_tpu.ops import pallas_kernels as pk
+    gamma = jnp.cumsum(g, axis=-1).astype(jnp.float32)
+    return _chunk_operands(pk._interpret(), q, k, v, gamma,
+                           beta.astype(jnp.float32)) \
+        + (lax.stop_gradient(gamma[..., -1].min()),)
+
+
 def chunks_of(seq: int, chunk: int) -> Tuple[int, int]:
     """(tokens a chunk, chunks a sequence): `chunk`, or the least power of
     two that holds a shorter sequence whole; the last chunk is filled up."""
@@ -236,7 +344,9 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
     products inside a chunk and the inverse) and the outputs with
     `finish`: their float32 insides, most of them (C, C) a chunk and head,
     are formed again in the backward pass, a stage at a time, not kept
-    side by side."""
+    side by side. Where the operands are the two kernels (module
+    docstring) they need no checkpoint: the stage keeps its inputs and
+    its backward kernel forms the insides again."""
     n, s, h, dk = q.shape
     dv = v.shape[-1]
     op = v.dtype
@@ -251,26 +361,6 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
         a = jnp.moveaxis(a, 2, 0)                       # (nc, N, H, C, ...)
         return a.reshape((nc, n * h, chunk) + a.shape[4:])
 
-    def operands(q, k, v, g, beta):
-        gamma = jnp.cumsum(g, axis=-1).astype(jnp.float32)      # (nc, B, C)
-        beta = beta.astype(jnp.float32)[..., None]
-        lower = np.tril(np.ones((chunk, chunk), bool))
-        decay = jnp.exp(jnp.where(
-            lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
-        a = jnp.where(np.tril(lower, -1),
-                      beta * decay * _dot("bnik,bnjk->bnij", k, k), 0.0)
-        t = unit_lower_inverse(a).astype(op)
-        grow = jnp.exp(gamma)[..., None]                        # (nc, B, C, 1)
-        kf = k.astype(jnp.float32)
-        w = _dot("bnij,bnjk->bnik", t, (beta * grow * kf).astype(op))
-        u0 = _dot("bnij,bnjv->bniv", t,
-                  (beta * v.astype(jnp.float32)).astype(op))
-        kd = (jnp.exp(gamma[..., -1:] - gamma)[..., None] * kf).astype(op)
-        attn = (decay * _dot("bnik,bnjk->bnij", q, k)).astype(op)
-        qg = (grow * q.astype(jnp.float32)).astype(op)
-        return (w.astype(op), u0.astype(op), kd, jnp.exp(gamma[..., -1]),
-                attn, qg, lax.stop_gradient(gamma[..., -1].min()))
-
     def read(qg, attn, states, updates, gate, *args):
         o = _dot("bnik,bnkv->bniv", qg, states) \
             + _dot("bnij,bnjv->bniv", attn, updates)
@@ -279,8 +369,15 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
         o = o.reshape(nc, n, h, chunk, dv).transpose(1, 0, 3, 2, 4)
         return o.reshape(n, nc * chunk, h, dv)[:, :s]
 
+    if _kernels_take(nc * n * h, chunk, dk, dv, scan_dtype, op):
+        # (their backward IS the stage's recomputation, inside VMEM)
+        operands = _operands_kernels
+    else:
+        operands = functools.partial(_operands_xla, op)
+        if finish is not None:
+            operands = jax.checkpoint(operands)
     if finish is not None:
-        operands, read = jax.checkpoint(operands), jax.checkpoint(read)
+        read = jax.checkpoint(read)
     w, u0, kd, last, attn, qg, lowest = operands(
         chunks(q.astype(op)), chunks(k.astype(op)), chunks(v),
         chunks(g.astype(scan_dtype)), chunks(beta.astype(scan_dtype)))

@@ -12,9 +12,14 @@ traced and lowered once), and since ISSUE 35 attention over selected keys
 (`veles_dsa_*`) and the held experts' grouped products (`veles_gmm`,
 `veles_tgmm`), what `keye2_ep8.long16k` runs; since ISSUE 38 the flash
 kernels take keys and values of different widths in the dtype they are
-given, and are the core of `xing4_ep8.step`'s latent attention.
+given, and are the core of `xing4_ep8.step`'s latent attention; since
+ISSUE 42 the operand stage of the chunked Gated DeltaNet
+(`veles_gdn_chunk_fwd`, `veles_gdn_chunk_bwd`: a chunk's (64, 64) float32
+algebra in VMEM, what `qwen3next_ep16.seq8k`'s three linear layers run
+twelve and six times a step).
 
-Every kernel has a lax twin in ops.xla / ops.attention — these are
+Every kernel has a lax twin in ops.xla / ops.attention /
+ops.linear_attention — these are
 drop-in replacements gated by `available()`. Interpret mode is something
 a test ASKS for (`_FORCE_INTERPRET`, `variants.pallas_interpret()`),
 never something the program falls into: off a TPU an unasked kernel
@@ -117,6 +122,20 @@ _DSA_INDEX_VMEM_LIMIT = 64 << 20
 _GMM_ROW_TILE = 512
 _GMM_BLOCK_BUDGET = 48 << 20
 _GMM_VMEM_LIMIT = 64 << 20
+#: the chunked Gated DeltaNet's operand stage (ISSUE 42): the bytes of
+#: blocks, double-buffered, a grid step of `veles_gdn_chunk_fwd` / `_bwd`
+#: may hold (`gdn_view` turns them into chunk-heads a step), and the scoped
+#: VMEM the kernels ask for: the blocks and the float32 (128, 128)
+#: matrices of the pair of chunk-heads in flight, 64 KB each
+#: (32 chunk-heads a step at heads of 128; 16 and 64 ran no differently).
+#: Pairs of chunk-heads one iteration of a step's loop walks: they are
+#: independent, and their chains of small products interleave. On a v5e
+#: a call over 8,192 chunk-heads took 6.16 ms forward and 8.20 backward
+#: at 1 pair an iteration, 5.95 / 7.60 at 2, 5.68 / 7.17 at 4 (my chip
+#: run, PR 42); more is more code for the compiler and little more gain
+_GDN_BLOCK_BUDGET = 12 << 20
+_GDN_VMEM_LIMIT = 32 << 20
+_GDN_PAIRS_IN_FLIGHT = 4
 #: fused-SGD row blocking seed (the pre-search hand-written value)
 _SGD_ROW_TILE = 8
 #: fused LRN+maxpool sample tile seed: SAMPLES per VMEM block (each
@@ -180,6 +199,8 @@ KERNEL_NAMES = {
     "_dsa_index_bwd_kernel": "veles_dsa_index_bwd",
     "_gmm_kernel": "veles_gmm",
     "_tgmm_kernel": "veles_tgmm",
+    "_gdn_chunk_fwd_kernel": "veles_gdn_chunk_fwd",
+    "_gdn_chunk_bwd_kernel": "veles_gdn_chunk_bwd",
 }
 
 
@@ -2180,3 +2201,293 @@ def _grouped_matmul_bwd(interpret, res, dy):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the operand stage of the chunked Gated DeltaNet (ISSUE 42): what
+# `ops.linear_attention.gated_delta_chunked` forms for every chunk before
+# the chain along the sequence walks them. A chunk and head's (C, C)
+# float32 matrices (the decay matrix, K K^T, the ten products of the
+# inverse, Q K^T, and in the backward their cotangents) passed through HBM
+# one by one as XLA traced the same equations, 0.5 GB a pass at 16,384
+# chunk-heads a layer with their 64 lanes padded to 128; here they live and
+# die in VMEM, and what crosses HBM is the stage's interface: q, k, v, the
+# cumulative log-decay and beta in, w, u0, kd, attn and qg out
+# (`veles_gdn_chunk_fwd`), and the inputs with five cotangents in, five
+# gradients out (`veles_gdn_chunk_bwd`, which forms the inverse again: the
+# stage keeps no residual but its inputs).
+#
+# TWO chunk-heads stand side by side as one block-diagonal matrix of 128
+# rows (C = 64): their tokens' rows follow one another in the sublanes
+# (`(B, C, d)` read as `(B / 2, 2 C, d)`: a bitcast), so every (2C, 2C)
+# product is one whole tile of the 128-deep array and every float32 matrix
+# fills its registers' lanes; what the two chunks have no business with,
+# the off-diagonal blocks, is masked out where it arises (K K^T, Q K^T) and
+# stays zero through the inverse. The inverse is `linear_attention.
+# _inverse_of`'s, step for step: the 16-row diagonal blocks by a product of
+# powers, larger blocks by substitution; float32 matrices whose products
+# read them in ONE bfloat16 pass and accumulate in float32, which is what
+# a TPU's default precision gives the XLA form's float32 products. A
+# per-token scalar (gamma, beta) arrives as a ROW, the tokens in the lanes
+# (a column would pad to 128 lanes in HBM), and is turned to a column
+# under the identity's mask.
+# ---------------------------------------------------------------------------
+
+
+def gdn_view(chunk_heads: int, chunk: int, key_dim: int, value_dim: int,
+             scan_dtype, dtype) -> Optional[int]:
+    """The chunk-heads a grid step of the two `veles_gdn_chunk_*` kernels
+    holds, or None where they take none (the caller then traces the XLA
+    form): two chunks fill the 128 lanes side by side (a chunk of 64, an
+    even number of chunk-heads), keys and values are whole lanes, gates
+    and decays are float32 and the products' operands bfloat16 (the
+    precision the kernels' products are written for). The step's blocks
+    are counted for the wider kernel, the backward (seven operands of a
+    head's width in, three out, the chunk's square cotangent padded to
+    the lanes), double-buffered, within _GDN_BLOCK_BUDGET; whole tiles of
+    8 pairs' rows of the per-token scalars, or the whole array."""
+    if (chunk * 2 != _LANE or chunk_heads % 2 or key_dim % _LANE
+            or value_dim % _LANE or jnp.dtype(scan_dtype) != jnp.float32
+            or jnp.dtype(dtype) != jnp.bfloat16):
+        return None
+    a_head = 2 * 2 * chunk * (7 * key_dim + 3 * value_dim + _LANE)
+    cap = _GDN_BLOCK_BUDGET // a_head
+    if chunk_heads <= cap:
+        return chunk_heads
+    return _largest_divisor(chunk_heads, 2 * _MIN_ROW_TILE, cap)
+
+
+def _gdn_dot(a, b, ca: int = 1, cb: int = 0):
+    """a . b over axis `ca` of a and `cb` of b: the operands read in one
+    bfloat16 pass, the sum float32."""
+    return lax.dot_general(
+        a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+        (((ca,), (cb,)), ((), ())), precision=lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+
+
+def _gdn_inverse(a, r, c, chunk: int, block: int):
+    """(I + a)^-1 of a block-diagonal, strictly lower-triangular float32
+    `a` whose blocks hold `chunk` rows (`r`, `c`: its row and column
+    numbers): `linear_attention._inverse_of`, step for step."""
+    eye = (r == c).astype(jnp.float32)
+    d = jnp.where((r // block) == (c // block), a, 0.0)
+    inv, power, reach = eye - d, d, 2
+    while reach < block:
+        power = _gdn_dot(power, power)
+        inv = _gdn_dot(inv, eye + power)
+        reach *= 2
+    size = block
+    while size < chunk:
+        low = ((r // (2 * size)) == (c // (2 * size))) \
+            & ((r // size) % 2 == 1) & ((c // size) % 2 == 0)
+        inv = inv - _gdn_dot(_gdn_dot(inv, jnp.where(low, a, 0.0)), inv)
+        size *= 2
+    return inv
+
+
+def _gdn_chunk_algebra(k, g_row, b_row, *, chunk: int, block: int):
+    """What forward and backward both form of one pair of chunk-heads:
+    k (2C, dk), the cumulative log-decay and beta as rows (1, 2C) ->
+    ((row numbers, column numbers, which entries lie inside one chunk,
+    which of them strictly below the diagonal), (gamma, beta and the
+    chunk's last gamma as columns (2C, 1)), the decay matrix, K K^T,
+    T = (I + A)^-1), the matrices float32."""
+    rows = k.shape[0]
+    r = lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+    c = lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+    same = (r // chunk) == (c // chunk)
+    lower, strict = same & (c <= r), same & (c < r)
+    eye = r == c
+
+    def column(row):
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    g_col, b_col = column(g_row), column(b_row)
+    g_last = jnp.sum(jnp.where(c == r // chunk * chunk + chunk - 1, g_row,
+                               0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(lower, g_col - g_row, -jnp.inf))
+    kk = _gdn_dot(k, k, 1, 1)
+    a = jnp.where(strict, b_col * decay * kk, 0.0)
+    t = _gdn_inverse(a, r, c, chunk, block)
+    return (r, c, same, strict), (g_col, b_col, g_last), decay, kk, t
+
+
+def _gdn_walk(pairs: int, pair):
+    """`pair(p)` for every pair of a grid step's chunk-heads, as many an
+    iteration as _GDN_PAIRS_IN_FLIGHT allows: pairs are independent, and
+    one pair's chain of small products leaves the matrix unit waiting."""
+    fly = _largest_divisor(pairs, 1, _GDN_PAIRS_IN_FLIGHT)
+
+    def step(i, carry):
+        for j in range(fly):
+            pair(i * fly + j)
+        return carry
+
+    lax.fori_loop(0, pairs // fly, step, 0)
+
+
+def _gdn_chunk_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, w_ref, u0_ref,
+                          kd_ref, attn_ref, qg_ref, *, chunk: int,
+                          block: int):
+    """Grid (blocks of chunk-heads,); a step walks its pairs of
+    chunk-heads one after the other."""
+    op = w_ref.dtype
+
+    def pair(p):
+        q, k, v = q_ref[p], k_ref[p], v_ref[p]
+        at = pl.ds(p, 1)
+        _, (g_col, b_col, g_last), decay, _, t = _gdn_chunk_algebra(
+            k, g_ref[at, :], b_ref[at, :], chunk=chunk, block=block)
+        t = t.astype(op)
+        grow = jnp.exp(g_col)
+        kf = k.astype(jnp.float32)
+        w_ref[p] = _gdn_dot(t, (b_col * grow * kf).astype(op)).astype(op)
+        u0_ref[p] = _gdn_dot(
+            t, (b_col * v.astype(jnp.float32)).astype(op)).astype(op)
+        kd_ref[p] = (jnp.exp(g_last - g_col) * kf).astype(op)
+        attn = decay * _gdn_dot(q, k, 1, 1)
+        # the two chunks' squares leave one under the other, as they are
+        # laid out: off the diagonal the blocks are zero
+        attn_ref[p] = sum(attn[:, i * chunk:(i + 1) * chunk]
+                          for i in range(attn.shape[1] // chunk)).astype(op)
+        qg_ref[p] = (grow * q.astype(jnp.float32)).astype(op)
+
+    _gdn_walk(q_ref.shape[0], pair)
+
+
+def _gdn_chunk_bwd_kernel(q_ref, k_ref, v_ref, dw_ref, du0_ref, dkd_ref,
+                          dattn_ref, dqg_ref, g_ref, b_ref, dq_ref, dk_ref,
+                          dv_ref, dg_ref, db_ref, *, chunk: int, block: int):
+    """The stage's transpose, a pair of chunk-heads at a time: T and the
+    decay matrix formed again, d A = -T^T (d T) T^T inside."""
+    op = dq_ref.dtype
+    f32 = jnp.float32
+
+    def pair(p):
+        q, k, v = q_ref[p], k_ref[p], v_ref[p]
+        at = pl.ds(p, 1)
+        (r, c, same, strict), (g_col, b_col, g_last), decay, kk, t = \
+            _gdn_chunk_algebra(k, g_ref[at, :], b_ref[at, :], chunk=chunk,
+                               block=block)
+        qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+        grow, tail = jnp.exp(g_col), jnp.exp(g_last - g_col)
+        dw, du0 = dw_ref[p], du0_ref[p]
+        d_kd, d_qg = dkd_ref[p].astype(f32), dqg_ref[p].astype(f32)
+        # w = T kb, u0 = T vb
+        t_op = t.astype(op)
+        d_t = _gdn_dot(dw, (b_col * grow * kf).astype(op), 1, 1) \
+            + _gdn_dot(du0, (b_col * vf).astype(op), 1, 1)
+        d_kb, d_vb = _gdn_dot(t_op, dw, 0, 0), _gdn_dot(t_op, du0, 0, 0)
+        # T = (I + A)^-1, A = beta decay K K^T strictly below the diagonal
+        m = jnp.where(strict, -_gdn_dot(_gdn_dot(t, d_t, 0, 0), t, 1, 1),
+                      0.0)
+        x = m * decay * kk
+        d_kk = (m * (b_col * decay)).astype(op)
+        # attn = decay Q K^T: its cotangent arrives one chunk under the
+        # other and is laid side by side; the decay's zeros mask the rest
+        d_attn = jnp.concatenate(
+            [dattn_ref[p].astype(f32)] * (decay.shape[1] // chunk), axis=1)
+        qk = _gdn_dot(q, k, 1, 1)
+        d_qk = (d_attn * decay).astype(op)
+        d_decay = b_col * x + d_attn * (decay * qk)     # times the decay
+        s_kb = jnp.sum(d_kb * kf, axis=1, keepdims=True)
+        s_kd = tail * jnp.sum(d_kd * kf, axis=1, keepdims=True)
+        s_qg = jnp.sum(d_qg * qf, axis=1, keepdims=True)
+        dq_ref[p] = (grow * d_qg + _gdn_dot(d_qk, k)).astype(op)
+        dk_ref[p] = (b_col * grow * d_kb + tail * d_kd
+                     + _gdn_dot(d_kk, k) + _gdn_dot(d_kk, k, 0, 0)
+                     + _gdn_dot(d_qk, q, 0, 0)).astype(op)
+        dv_ref[p] = (b_col * d_vb).astype(op)
+        d_beta = jnp.sum(x, axis=1, keepdims=True) + grow * s_kb \
+            + jnp.sum(d_vb * vf, axis=1, keepdims=True)
+        d_gamma = jnp.sum(d_decay, axis=1, keepdims=True) \
+            + grow * (b_col * s_kb + s_qg) - s_kd
+        eye = r == c
+        # columns back to rows; a chunk's last token also takes the sum
+        # of what its tail decays carried
+        db_ref[at, :] = jnp.sum(jnp.where(eye, d_beta, 0.0), axis=0,
+                                keepdims=True)
+        dg_ref[at, :] = jnp.sum(
+            jnp.where(eye, d_gamma, 0.0)
+            + jnp.where(same & (c % chunk == chunk - 1), s_kd, 0.0)
+            - d_decay, axis=0, keepdims=True)
+
+    _gdn_walk(q_ref.shape[0], pair)
+
+
+def _gdn_call(kernel, mats, vecs, outs, *, chunk: int, inverse_block: int,
+              interpret: bool):
+    """One of the two kernels over (B, C, d) arrays `mats` and (B, C)
+    float32 rows `vecs`; `outs`: a (trailing shape, dtype) a result, (C, d)
+    for an array a chunk-head and (C,) for a row."""
+    b = mats[0].shape[0]
+    pack = _LANE // chunk
+    k, v = mats[1:3]
+    g = gdn_view(b, chunk, k.shape[-1], v.shape[-1], vecs[0].dtype, v.dtype)
+    if not g:
+        raise ValueError(
+            f"the Gated DeltaNet kernels take no {b} chunk-heads of {chunk} "
+            f"tokens, keys of {k.shape[-1]}, values of {v.shape[-1]} in "
+            f"{v.dtype} under {vecs[0].dtype} decays (pallas_kernels."
+            "gdn_view): ops.linear_attention traces the XLA form for such "
+            "a shape")
+
+    def paired(shape):
+        """(B, C, ...) -> (B / 2, 2 C, ...): two chunk-heads' rows one
+        under the other, a bitcast; a row of scalars likewise."""
+        return (b // pack, pack * shape[1]) + tuple(shape[2:])
+
+    def spec(shape):
+        shape = paired(shape)
+        return _vmem((g // pack,) + shape[1:],
+                     lambda i: (i,) + (0,) * (len(shape) - 1))
+
+    args = [a.reshape(paired(a.shape)) for a in (*mats, *vecs)]
+    shapes = [(b,) + tuple(s) for s, _ in outs]
+    res = pl.pallas_call(
+        functools.partial(kernel, chunk=chunk, block=inverse_block),
+        out_shape=tuple(jax.ShapeDtypeStruct(paired(s), dt)
+                        for s, (_, dt) in zip(shapes, outs)),
+        grid=(b // g,),
+        in_specs=[spec(a.shape) for a in (*mats, *vecs)],
+        out_specs=tuple(spec(s) for s in shapes),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_GDN_VMEM_LIMIT),
+        interpret=interpret, name=KERNEL_NAMES[kernel.__name__],
+    )(*args)
+    return tuple(a.reshape(s) for a, s in zip(res, shapes))
+
+
+@_kernel_jit
+def gdn_chunk_forward_pallas(q, k, v, gamma, beta, *, inverse_block: int,
+                             interpret: bool = False):
+    """q and k (B, C, dk), v (B, C, dv) of B chunk-heads in the products'
+    dtype, gamma (the cumulative log-decay inside a chunk) and beta (B, C)
+    float32 -> (w (B, C, dk), u0 (B, C, dv), kd (B, C, dk), attn (B, C, C),
+    qg (B, C, dk)) in v's dtype: `linear_attention`'s `operands` but for
+    what is a function of gamma's last column alone."""
+    _, c, dk = q.shape
+    dv, op = v.shape[-1], v.dtype
+    return _gdn_call(
+        _gdn_chunk_fwd_kernel, (q, k, v), (gamma, beta),
+        [((c, dk), op), ((c, dv), op), ((c, dk), op), ((c, c), op),
+         ((c, dk), op)],
+        chunk=c, inverse_block=inverse_block, interpret=interpret)
+
+
+@_kernel_jit
+def gdn_chunk_backward_pallas(q, k, v, gamma, beta, dw, du0, dkd, dattn, dqg,
+                              *, inverse_block: int, interpret: bool = False):
+    """The inputs of `gdn_chunk_forward_pallas` and its five results'
+    cotangents -> the cotangents of (q, k, v) in their dtype and of
+    (gamma, beta) float32."""
+    _, c, dk = q.shape
+    dv = v.shape[-1]
+    return _gdn_call(
+        _gdn_chunk_bwd_kernel, (q, k, v, dw, du0, dkd, dattn, dqg),
+        (gamma, beta),
+        [((c, dk), q.dtype), ((c, dk), k.dtype), ((c, dv), v.dtype),
+         ((c,), jnp.float32), ((c,), jnp.float32)],
+        chunk=c, inverse_block=inverse_block, interpret=interpret)
