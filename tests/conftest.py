@@ -28,6 +28,29 @@ jax.config.update("jax_default_matmul_precision", "highest")
 
 import pytest  # noqa: E402
 
+# Assertions of accepted benchmark files that a later, allowed change
+# made stale. A PR that changes the program may not edit a file under
+# BENCHMARK.json's `paths`, so the test is marked here, its lasting part
+# is asserted in the named new test, and PERF.md section 7 asks a
+# `benchmark` PR to repair the assertion and drop the entry.
+_STALE_BENCHMARK_TESTS = {
+    # asserts that PR 42's two rooflines are the LAST two entries of
+    # `per_layer`; PR 43 appended `veles_seg_sum_roofline` after them.
+    # tests/benchmark/test_benchmark_seg_sum.py::
+    # test_the_entry_stands_after_what_the_parent_had holds the order.
+    "tests/benchmark/test_benchmark_gdn.py::"
+    "test_the_manifest_names_the_two_rooflines_in_the_cell":
+        "per_layer[-2:] is no longer PR 42's pair: PR 43 appended an "
+        "entry; needs a benchmark PR",
+}
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        why = _STALE_BENCHMARK_TESTS.get(item.nodeid)
+        if why:
+            item.add_marker(pytest.mark.xfail(reason=why, strict=True))
+
 
 @pytest.fixture(scope="session")
 def eight_devices():

@@ -220,6 +220,40 @@ def test_grouped_product_kernels_compile_at_published_widths(
         assert txt.count("veles_gmm") >= 2 and "veles_tgmm" in txt
 
 
+def test_seg_sum_kernel_compiles_at_the_cells_shapes(one_chip,
+                                                     compiled_pallas):
+    """The held experts' combine (ISSUE 43) at the three language-model
+    cells' buffers, fast rows and whole ones, bfloat16 (and float32 once:
+    its one-hot product runs at `HIGHEST`): the rows' permutation and
+    `veles_seg_sum` under its fixed name, forward and as the transpose of
+    the rows' gather, with no gather of a row a (token, slot) pair left."""
+    from veles_tpu.ops import moe as om
+    for rows, tokens, k, c, dtype in (
+            (61440, 32768, 10, 2048, jnp.bfloat16),
+            (49152, 16384, 8, 2048, jnp.bfloat16),
+            (131072, 16384, 8, 2048, jnp.bfloat16),
+            (6144, 8192, 4, 3584, jnp.bfloat16),
+            (32768, 8192, 4, 3584, jnp.bfloat16),
+            (6144, 8192, 4, 3584, jnp.float32)):
+        tile = pk.seg_sum_view(rows, tokens, c, jnp.dtype(dtype).itemsize)
+        assert tile == 256
+        plan = jax.tree.map(
+            lambda s: _sds(one_chip, s.shape, s.dtype), jax.eval_shape(
+                lambda p: pk.seg_sum_plan(p, 7, k, tokens, tile),
+                jax.ShapeDtypeStruct((rows,), jnp.int32)))
+
+        def both(y, h, *plan):
+            token_of = plan[0] // k
+            return (om._sum_rows(y, token_of, plan, 7, (tile, False)),
+                    jax.grad(lambda h: om._take_rows(
+                        h, token_of, plan, 7, (tile, False)).astype(
+                            jnp.float32).sum())(h))
+        txt = _compile(both, _sds(one_chip, (rows, c), dtype),
+                       _sds(one_chip, (tokens, c), dtype), *plan)
+        assert txt.count("veles_seg_sum") >= 2 and "tpu_custom_call" in txt
+        assert f"[{tokens},{k}," not in txt, (rows, tokens)
+
+
 def test_gdn_chunk_kernels_compile_at_the_cells_shapes(one_chip,
                                                        compiled_pallas):
     """One call of `qwen3next_ep16.seq8k` (ISSUE 42): 8,192 chunk-heads
@@ -698,6 +732,17 @@ def test_xing4_ep8_train_step_compiles_and_fits_one_chip(xing4_lowered):
         "veles_hc_pre_fwd": 2, "veles_hc_post_fwd": 2,
         "veles_hc_post_bwd": 1, "veles_hc_pre_bwd": 1}, one["kernels"]
     assert all(v["sites"] >= 12 for v in hc.values()), one["kernels"]
+    # the held experts' combine (ISSUE 43), whatever forms the products:
+    # a body a buffer (the fast rows, the whole one) and direction, a site
+    # a branch of the five layers' forward and backward `cond`s
+    assert one["kernels"]["veles_seg_sum"] == {"bodies": 4, "sites": 20}
+    paths = set(re.findall(
+        r'loc\("([^"]*/experts/cond/[^"]*jit\(seg_sum_pallas\))"',
+        one["debug_text"]))
+    units = {m for p_ in paths for m in re.findall(r"L\d\d\.\w+", p_)}
+    # (four expert blocks and the MTP module's expert layer)
+    assert len(units) == 5 and all("moe" in re.split(r"[/()]", p_)
+                                   for p_ in paths), sorted(paths)[:3]
     cfg, step = one["config"], one["step"]
     assert step.has_aux and step.unit_loss
     assert _n_leaves(one) == cfg["n_params"]
@@ -733,7 +778,9 @@ def test_xing4_ep8_compiled_step_fits_one_chip(xing4_step):
         print("trace_cost", {k: row[k] for k in (
             "hc", "flash_attn", "trace_s", "lower_s", "equations",
             "stablehlo_bytes", "kernels")})
-    assert not xla["kernels"]
+    # (the held experts' combine is no registry op: it stays a kernel
+    # under the XLA lowerings of `hc` and `flash_attn`, ISSUE 43)
+    assert set(xla["kernels"]) == {"veles_seg_sum"}, xla["kernels"]
     assert one["equations"] <= xla["equations"]
     assert one["stablehlo_bytes"] <= xla["stablehlo_bytes"]
     cfg = one["config"]
@@ -818,11 +865,12 @@ def test_xing4_ep8_compiled_attention_core_leaves_no_score_block(xing4_step):
 #: (the selection's and the loss's calls of a band share one body), their
 #: gradient in the backward alone; the grouped products either width
 #: first, on the fast rows and on the whole buffer: forward, again where
-#: the backward recomputes, and the other way round
+#: the backward recomputes, and the other way round; the combine
+#: (ISSUE 43) on either buffer, forward and as the rows' gather's transpose
 KEYE2_BODIES = (("veles_dsa_attend_fwd", 1), ("veles_dsa_pmean", 8),
                 ("veles_dsa_index_fwd", 8), ("veles_dsa_index_bwd", 4),
                 ("veles_dsa_attend_dq", 1), ("veles_dsa_attend_dkv", 1),
-                ("veles_gmm", 12), ("veles_tgmm", 4))
+                ("veles_gmm", 12), ("veles_tgmm", 4), ("veles_seg_sum", 4))
 # (a block of queries is the body of a `lax.map`: `dsa/while/body/
 # closed_call/select/...`; the readers match whole components)
 KEYE2_SCOPES = ("/dsa/qkv/", "/dsa/indexer/", "/select/", "/attend/",
@@ -831,7 +879,7 @@ KEYE2_SCOPES = ("/dsa/qkv/", "/dsa/indexer/", "/select/", "/attend/",
                 "veles_dsa_attend_fwd", "veles_dsa_pmean",
                 "veles_dsa_attend_dq", "veles_dsa_attend_dkv",
                 "veles_dsa_index_fwd", "veles_dsa_index_bwd",
-                "veles_gmm", "veles_tgmm")
+                "veles_gmm", "veles_tgmm", "veles_seg_sum")
 
 
 @pytest.fixture(scope="module")
@@ -937,11 +985,12 @@ def test_keye2_ep8_compiled_step_fits_one_chip(keye2_step):
 #: way round, the whole-buffer branch being the same rows a window at a
 #: time (`ops.moe._WHOLE_BUFFER_MAX`); the operand stage of the linear
 #: layers' scan forward as the forward pass traces it and as a group's
-#: `jax.checkpoint` traces it again (6 sites, 2 bodies), backward once
+#: `jax.checkpoint` traces it again (6 sites, 2 bodies), backward once;
+#: the combine (ISSUE 43) on the fast rows, which are a window's rows too
 QWEN3NEXT_BODIES = (("veles_flash_fwd", 1), ("veles_flash_dq", 1),
                     ("veles_flash_dkv", 1), ("veles_gmm", 10),
                     ("veles_tgmm", 4), ("veles_gdn_chunk_fwd", 2),
-                    ("veles_gdn_chunk_bwd", 1))
+                    ("veles_gdn_chunk_bwd", 1), ("veles_seg_sum", 3))
 # (a linear layer walks its sequences in `scan_groups` groups, the body of
 # a `lax.map`: `gdn/while/body/.../proj/...`; the chain along the sequence
 # is the body of a `lax.scan` inside it: `.../scan/while/body/...`; its
@@ -952,7 +1001,7 @@ QWEN3NEXT_SCOPES = ("/gdn/while/", "/proj/", "/conv/", "/scan/out/",
                     "/moe/balance_loss/", "rematted_computation",
                     "veles_flash_fwd", "veles_flash_dq", "veles_flash_dkv",
                     "veles_gmm", "veles_tgmm", "veles_gdn_chunk_fwd",
-                    "veles_gdn_chunk_bwd")
+                    "veles_gdn_chunk_bwd", "veles_seg_sum")
 
 
 @pytest.fixture(scope="module")

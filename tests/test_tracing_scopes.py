@@ -215,7 +215,21 @@ PALLAS_SITES = {
     "gdn_chunk": (jax.grad(lambda v: _delta_chunks(v).sum()),
                   np.ones((1, 128, 2, 128), np.float32),
                   ["veles_gdn_chunk_fwd", "veles_gdn_chunk_bwd"]),
+    # the held experts' combine over a buffer of 128 rows for 64 tokens
+    # (ISSUE 43): the sum, and the transpose of the rows' gather
+    "seg_sum": (jax.grad(lambda h: _combined(h).sum()),
+                np.ones((64, 128), np.float32),
+                ["veles_seg_sum", "veles_seg_sum"]),
 }
+
+
+def _combined(h):
+    from veles_tpu.ops import moe as om
+    pairs = np.arange(128, dtype=np.int32)      # two slots a token, all held
+    seg = (pk.seg_sum_view(128, 64, 128, 4), True)
+    plan = pk.seg_sum_plan(pairs, 100, 2, 64, seg[0])
+    rows = om._take_rows(h, pairs // 2, plan, 100, seg)
+    return om._sum_rows(rows, pairs // 2, plan, 100, seg)
 
 
 def _delta_chunks(v):
@@ -258,12 +272,45 @@ def test_every_pallas_call_has_its_fixed_name(site):
     assert set(want) <= set(pk.KERNEL_NAMES.values())
 
 
-def test_all_twenty_two_kernels_are_named_and_no_name_twice():
+def test_all_twenty_three_kernels_are_named_and_no_name_twice():
     names = list(pk.KERNEL_NAMES.values())
-    assert len(names) == 22 == len(set(names))
+    assert len(names) == 23 == len(set(names))
     with open(pk.__file__) as f:
         src = f.read()
     assert src.count("pl.pallas_call(") == src.count("name=KERNEL_NAMES[")
+
+
+def test_the_combines_kernel_stands_under_the_expert_layers_scope():
+    """`veles_seg_sum` (ISSUE 43) is called under `moe/experts`, forward
+    and backward, in a block whose step allows Pallas kernels whatever
+    forms its grouped products: `step_moe_ms`, `moe_experts_mxu_share` and
+    `step_unscoped_share` keep reading it. With `allow_pallas = False` the
+    block traces no kernel."""
+    import jax.numpy as jnp
+
+    from veles_tpu.ops import variants
+    from veles_tpu.znicz.lm import BlockSpec
+    assert "veles_seg_sum" in pk.KERNEL_NAMES.values()
+    spec = BlockSpec(features=128, n_heads=2, ffn="experts", width=128,
+                     n_experts=8, held=(0, 4), top_k=2, residual="plain",
+                     scoring="softmax", shared=False, attention="gated",
+                     kv_heads=1, head_dim=64, rotary_dim=16)
+    assert spec.grouped == "ragged_dot"
+    p = {k: jnp.full(v, 0.01, jnp.float32) for k, v in spec.shapes().items()}
+    x = jnp.ones((1, 128, 128), jnp.float32)
+
+    def sites():
+        txt = jax.jit(jax.value_and_grad(
+            lambda p: spec.apply(p, x)[0].sum())).lower(p).as_text(
+                debug_info=True)
+        return set(re.findall(r'loc\("([^"]*jit\(seg_sum_pallas\))"', txt))
+    with variants.pallas_interpret():
+        found = sites()
+        spec.allow_pallas = False
+        assert not sites()
+    # the forward's `jvp(moe)/experts`, the backward's `transpose(jvp(moe))`
+    assert len(found) == 2 and all("/moe/experts/" in re.sub(
+        r"transpose\(|jvp\(|\)", "", s) for s in found), found
 
 
 # -- spans on the profiler's clock ------------------------------------------------

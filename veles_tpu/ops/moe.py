@@ -165,19 +165,27 @@ def expert_loads(idx, n_experts: int):
             ).sum(axis=(0, 1), dtype=jnp.int32)
 
 
-@jax.custom_vjp
-def _take_rows(h, token_of, slot_of, n_live):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_rows(h, token_of, pairs, n_live, seg=None):
     """Row r of the sorted buffer: its token's row of h (T, C), zeros past
     the `n_live` live rows. `token_of` (R,) names each sorted row's token,
-    `slot_of` (T, k) each (token, slot) pair's sorted row: the two say the
-    same thing, and each direction of the pair is a GATHER (`_sum_rows` is
-    the transpose; a scatter-add of 6,144 rows of 3,584 measured 2.4 ms on
-    a v5e where the gather takes 0.27, chip run of PR 32)."""
+    `pairs` says the same the other way round, for `_sum_rows`, the
+    transpose: each direction of the pair is a GATHER or a product, never
+    a scatter (a scatter-add of 6,144 rows of 3,584 measured 2.4 ms on a
+    v5e where the gather takes 0.27, chip run of PR 32)."""
     live = (jnp.arange(token_of.shape[0]) < n_live)[:, None]
     return jnp.where(live, jnp.take(h, token_of, axis=0), 0)
 
 
-#: the most bytes of a buffer one gather of `_sum_rows` reads from: a v5e
+#: `_sum_rows` has two forms. Where the step may trace Pallas kernels and
+#: `pallas_kernels.seg_sum_view` takes (rows, tokens, width) (whole lane
+#: tiles of the width, rows in whole lane tiles, tokens in whole sublane
+#: tiles: the three language-model cells' buffers, both branches), the
+#: rows are put in token order and summed by `veles_seg_sum`, which reads
+#: the buffer's rows and never a slot. Everywhere else (off a TPU, under
+#: `allow_pallas = False`, a width or a row count with no view) it gathers
+#: one row a (token, slot) pair, held or not, and these bound that gather.
+#: The most bytes of a buffer one gather of `_sum_rows` reads from: a v5e
 #: gathered the 131,072 (token, slot) rows of 2,048 bfloat16 out of a
 #: 28,672-row buffer (112 MiB) in 1.8 ms and out of a 32,704-row one in 5.9;
 #: out of two halves of the columns of a 49,152-row one in 2.6, out of
@@ -199,11 +207,18 @@ def _gather_width(rows: int, width: int, itemsize: int) -> int:
     return width
 
 
-@jax.custom_vjp
-def _sum_rows(y, token_of, slot_of, n_live):
-    """Token t's sum of its live sorted rows of y (R, C): (T, C)."""
-    at = jnp.minimum(slot_of, y.shape[0] - 1)
-    live = (slot_of < n_live)[..., None]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _sum_rows(y, token_of, pairs, n_live, seg=None):
+    """Token t's sum of its live sorted rows of y (R, C): (T, C), summed
+    in float32 and rounded once. `seg` None: `pairs` (T, k) is every
+    (token, slot) pair's sorted row, gathered whether held or not. `seg`
+    = (token tile, interpret): `pairs` is `pallas_kernels.seg_sum_plan`'s,
+    and the live rows alone are summed, as one-hot products."""
+    if seg is not None:
+        from veles_tpu.ops import pallas_kernels as pk
+        return pk.seg_sum(y, pairs, n_live, *seg)
+    at = jnp.minimum(pairs, y.shape[0] - 1)
+    live = (pairs < n_live)[..., None]
     step = _gather_width(*y.shape, y.dtype.itemsize)
     parts = [jnp.where(live, jnp.take(part, at, axis=0), 0
                        ).astype(jnp.float32).sum(axis=1).astype(y.dtype)
@@ -213,11 +228,11 @@ def _sum_rows(y, token_of, slot_of, n_live):
 
 
 _take_rows.defvjp(
-    lambda h, t, s, n: (_take_rows(h, t, s, n), (t, s, n)),
-    lambda res, g: (_sum_rows(g, *res), None, None, None))
+    lambda h, t, p, n, seg: (_take_rows(h, t, p, n, seg), (t, p, n)),
+    lambda seg, res, g: (_sum_rows(g, *res, seg), None, None, None))
 _sum_rows.defvjp(
-    lambda y, t, s, n: (_sum_rows(y, t, s, n), (t, s, n)),
-    lambda res, g: (_take_rows(g, *res), None, None, None))
+    lambda y, t, p, n, seg: (_sum_rows(y, t, p, n, seg), (t, p, n)),
+    lambda seg, res, g: (_take_rows(g, *res, seg), None, None, None))
 
 
 def _grouped_product(sizes, rows: int, like, w, kernels: bool,
@@ -238,15 +253,28 @@ def _grouped_product(sizes, rows: int, like, w, kernels: bool,
     return lambda x, w: lax.ragged_dot(x, w, sizes)
 
 
+def _seg_tile(lowering, rows: int, h):
+    """The token tile `_sum_rows` sums `rows` sorted rows into h's tokens
+    with as `veles_seg_sum`, or None where it gathers the slots:
+    `lowering` = (the grouped products' kernels, interpret, whether the
+    step may trace Pallas kernels at all)."""
+    if not lowering[2]:
+        return None
+    from veles_tpu.ops import pallas_kernels as pk
+    return pk.seg_sum_view(rows, *h.shape, h.dtype.itemsize)
+
+
 def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
-                      sizes, kernels: bool = False, interpret: bool = False,
-                      window=None):
+                      sizes, lowering=(False, False, False), window=None):
     """The grouped SwiGLU over the first `rows` rows of the sorted
     buffer: (T, C). Differentiable in h, gates and the weights. With
-    `window` = (first row, every pair's sorted row (T, k)) over the `rows`
-    rows from `first` on instead (`_in_windows`): what the
-    pairs sorted there add to their tokens."""
+    `window` = (first row, every pair's sorted row (T, k) or None where
+    the combine asks for none) over the `rows` rows from `first` on
+    instead (`_in_windows`): what the pairs sorted there add to their
+    tokens."""
     t, k = gates.shape
+    tile = _seg_tile(lowering, rows, h)
+    seg = (tile, lowering[1]) if tile else None
 
     def rows_of():
         """The pairs sorted into the rows at hand. (Sliced where it is
@@ -258,20 +286,25 @@ def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
     if window is None:
         n_live = jnp.minimum(sizes.sum(), rows)
         token_of = (rows_of() // k).astype(jnp.int32)
-        slot_of = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+        if not seg:
+            pairs = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
     else:
-        first, slot_of = window
+        first, pairs = window
         n_live = jnp.clip(sizes.sum() - first, 0, rows)
         token_of = (rows_of() // k).astype(jnp.int32)
         # the groups' rows inside the window
         ends = jnp.cumsum(sizes)
         sizes = jnp.clip(jnp.minimum(ends, first + rows)
                          - jnp.maximum(ends - sizes, first), 0)
-        # a pair sorted before the window is as dead as one sorted after
-        slot_of = jnp.where(slot_of < first, rows, slot_of - first)
+        if not seg:
+            # a pair sorted before the window is as dead as one sorted after
+            pairs = jnp.where(pairs < first, rows, pairs - first)
+    if seg:
+        from veles_tpu.ops import pallas_kernels as pk
+        pairs = pk.seg_sum_plan(rows_of(), n_live, k, t, tile)
     live = (jnp.arange(rows) < n_live)[:, None]
-    xs = _take_rows(h, token_of, slot_of, n_live)
-    dot = _grouped_product(sizes, rows, h, w_gate, kernels, interpret)
+    xs = _take_rows(h, token_of, pairs, n_live, seg)
+    dot = _grouped_product(sizes, rows, h, w_gate, *lowering[:2])
     # rows past the last group are no expert's: whatever a grouped product
     # leaves there must not reach the sum or, through it, a gradient
     a = jnp.where(live, dot(xs, w_gate), 0)
@@ -281,7 +314,8 @@ def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
     # over y, and 0 x whatever-lies-there is not 0 if it is not finite)
     gate_of = jnp.where(live, jnp.take(gates.reshape(-1), rows_of()
                                        )[:, None], 0)
-    return _sum_rows(y * gate_of.astype(y.dtype), token_of, slot_of, n_live)
+    return _sum_rows(y * gate_of.astype(y.dtype), token_of, pairs, n_live,
+                     seg)
 
 
 #: the most bytes of sorted rows (`all_rows` x C) the whole-buffer branch
@@ -304,30 +338,33 @@ def _windows(sizes_of: Tuple[int, int], h) -> int:
     return -(-all_rows // fast_rows)
 
 
-def _in_windows(sizes_of, lowering, order, sizes, n_windows: int, k: int):
+def _in_windows(sizes_of, lowering, h, order, sizes, n_windows: int, k: int):
     """(part(first row, h, gates, the three weights) -> what the window of
     `fast_rows` rows from `first` on adds (T, C), the windows that hold a
-    live row). Every pair's sorted row is found once, outside the walk."""
+    live row). Every pair's sorted row is found once, outside the walk,
+    where the combine gathers by it."""
     fast_rows = sizes_of[0]
-    slot_of = jnp.argsort(order).astype(jnp.int32).reshape(-1, k)
+    slot_of = None if _seg_tile(lowering, fast_rows, h) else \
+        jnp.argsort(order).astype(jnp.int32).reshape(-1, k)
     padded = jnp.pad(order, (0, max(
         n_windows * fast_rows - order.shape[0], 0)))
 
     def part(first, *diff):
         return _held_rows_swiglu(
-            fast_rows, *diff, order=padded, sizes=sizes, kernels=lowering[0],
-            interpret=lowering[1], window=(first, slot_of))
+            fast_rows, *diff, order=padded, sizes=sizes, lowering=lowering,
+            window=(first, slot_of))
 
     return part, (sizes.sum() + fast_rows - 1) // fast_rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool], h,
-                 gates, w_gate, w_up, w_down, order, sizes):
+def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool, bool],
+                 h, gates, w_gate, w_up, w_down, order, sizes):
     """`_held_rows_swiglu` on `sizes_of[0]` rows where the held pairs fit
     them, on `sizes_of[1]` where they do not: one `lax.cond` forward and
     one backward, each branch the same code at another static size
-    (`lowering`: the grouped products' (kernels, interpret)). The
+    (`lowering`: the grouped products' (kernels, interpret) and whether
+    the combine may be a kernel, `_seg_tile`). The
     backward recomputes its branch from the inputs, so that no branch's
     intermediates cross the `cond` (autodiff through it would write the
     untaken branch's residuals as zeros, at the large size). A whole
@@ -336,12 +373,11 @@ def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool], h,
     fast_rows, all_rows = sizes_of
     run = functools.partial(_held_rows_swiglu, h=h, gates=gates,
                             w_gate=w_gate, w_up=w_up, w_down=w_down,
-                            order=order, sizes=sizes, kernels=lowering[0],
-                            interpret=lowering[1])
+                            order=order, sizes=sizes, lowering=lowering)
     n_windows = _windows(sizes_of, h)
 
     def walk():
-        part, n = _in_windows(sizes_of, lowering, order, sizes, n_windows,
+        part, n = _in_windows(sizes_of, lowering, h, order, sizes, n_windows,
                               gates.shape[1])
         diff = (h, gates, w_gate, w_up, w_down)
         return lax.fori_loop(
@@ -365,14 +401,14 @@ def _held_swiglu_bwd(sizes_of, lowering, args, dy):
     def grads_at(rows: int):
         def branch():
             _, vjp = jax.vjp(lambda *a: _held_rows_swiglu(
-                rows, *a, order=order, sizes=sizes, kernels=lowering[0],
-                interpret=lowering[1]), *diff)
+                rows, *a, order=order, sizes=sizes, lowering=lowering),
+                *diff)
             return vjp(dy)
         return branch
 
     def walk():
-        part, n = _in_windows(sizes_of, lowering, order, sizes, n_windows,
-                              diff[1].shape[1])
+        part, n = _in_windows(sizes_of, lowering, diff[0], order, sizes,
+                              n_windows, diff[1].shape[1])
 
         def more(i, acc):
             _, vjp = jax.vjp(lambda *a: part(i * sizes_of[0], *a), *diff)
@@ -395,7 +431,8 @@ _held_swiglu.defvjp(_held_swiglu_fwd, _held_swiglu_bwd)
 def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
                         held: Tuple[int, int],
                         fast_rows: Optional[int] = None,
-                        kernels: bool = False, interpret: bool = False):
+                        kernels: bool = False, interpret: bool = False,
+                        seg_sum: bool = False):
     """The held experts' part of a top-k expert layer, nothing dropped:
     sum over the (token, slot) pairs whose expert is one of
     `held = (first, count)` of gate x SwiGLU_expert(token). h (T, C), idx
@@ -414,6 +451,11 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
     against 0.5 ms a product, chip run of PR 32) and T x min(k, count)
     rows, every pair there can be, where they do not: a router that sends
     every token to held experts is computed like any other, more slowly.
+    With `seg_sum` the combine (every token's sum of its held rows) and
+    the transpose of the rows' gather in the backward run as
+    `veles_seg_sum` over the buffer's rows where
+    `pallas_kernels.seg_sum_view` takes the shape, and cost by the rows
+    too; else they gather a row a (token, slot) pair (`_sum_rows`).
     Returns (y (T, C), pairs not computed: 0 by this construction,
     counted from the buffer's bound all the same)."""
     t, k = idx.shape
@@ -427,10 +469,10 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
              ).sum(axis=0, dtype=jnp.int32)
     total = sizes.sum()
     args = (h, gates, w_gate, w_up, w_down, order, sizes)
+    lowering = (kernels, interpret, seg_sum)
     if fast_rows is None or fast_rows >= all_rows:
-        y = _held_rows_swiglu(all_rows, *args, kernels, interpret)
+        y = _held_rows_swiglu(all_rows, *args, lowering)
     else:
-        y = _held_swiglu((int(fast_rows), all_rows), (kernels, interpret),
-                         *args)
+        y = _held_swiglu((int(fast_rows), all_rows), lowering, *args)
     return (checkpoint_name(y, MOE_SAVED[0]),
             total - jnp.minimum(total, all_rows))

@@ -16,7 +16,9 @@ given, and are the core of `xing4_ep8.step`'s latent attention; since
 ISSUE 42 the operand stage of the chunked Gated DeltaNet
 (`veles_gdn_chunk_fwd`, `veles_gdn_chunk_bwd`: a chunk's (64, 64) float32
 algebra in VMEM, what `qwen3next_ep16.seq8k`'s three linear layers run
-twelve and six times a step).
+twelve and six times a step); since ISSUE 43 the held experts' combine
+(`veles_seg_sum`: every token's sum of its held rows as one-hot products
+by token tile, what all three language-model cells run twice a layer).
 
 Every kernel has a lax twin in ops.xla / ops.attention /
 ops.linear_attention — these are
@@ -122,6 +124,27 @@ _DSA_INDEX_VMEM_LIMIT = 64 << 20
 _GMM_ROW_TILE = 512
 _GMM_BLOCK_BUDGET = 48 << 20
 _GMM_VMEM_LIMIT = 64 << 20
+#: the held experts' combine (ISSUE 43): tokens a group of `veles_seg_sum`
+#: owns and rows of the buffer a grid step holds at most. The one-hot
+#: product of an item is 2 x tokens x rows x width operations whatever the
+#: rows' owners, so the work grows with BOTH tiles (live rows x token tile
+#: + tokens x row tile) while a grid step costs a third of a microsecond:
+#: on a v5e a call over 61,440 rows of 2,048 bfloat16 (20,516 live) for
+#: 32,768 tokens took 0.575 ms at 256 tokens x 256 rows, 0.561 at 128 x
+#: 128, 0.541 at 128 x 256, 0.610 at 512 x 256, 0.715 at 512 x 512; over
+#: 6,144 rows of 3,584 for 8,192 tokens 0.239 / 0.241 / 0.233 / 0.255 /
+#: 0.303 (my chip runs, PR 43): the tile hardly matters under 512
+_SEG_SUM_TOKEN_TILE = 256
+_SEG_SUM_ROW_TILE = 256
+#: rows one step of the walk gathers that puts the buffer's LIVE rows in
+#: token order before the kernel (`_rows_in_token_order`): XLA's gather of
+#: rows takes 33-46 ns a row on a v5e whatever their width and whether
+#: anybody reads them, so one `jnp.take` of all 61,440 rows of 2,048
+#: bfloat16 took 2.84 ms where the 20,516 live ones, 2,048 a step, took
+#: 1.20 (4,096 a step 1.26, 12,288 1.26; of 49,152 rows 2.27 against 0.98,
+#: of 131,072 rows 5.99 against 1.55, of 6,144 rows 0.24 against 0.23; my
+#: chip runs, PR 43), 0.37 ms of that the zeros the walk starts from
+_SEG_SUM_TAKE_ROWS = 2048
 #: the chunked Gated DeltaNet's operand stage (ISSUE 42): the bytes of
 #: blocks, double-buffered, a grid step of `veles_gdn_chunk_fwd` / `_bwd`
 #: may hold (`gdn_view` turns them into chunk-heads a step), and the scoped
@@ -199,6 +222,7 @@ KERNEL_NAMES = {
     "_dsa_index_bwd_kernel": "veles_dsa_index_bwd",
     "_gmm_kernel": "veles_gmm",
     "_tgmm_kernel": "veles_tgmm",
+    "_seg_sum_kernel": "veles_seg_sum",
     "_gdn_chunk_fwd_kernel": "veles_gdn_chunk_fwd",
     "_gdn_chunk_bwd_kernel": "veles_gdn_chunk_bwd",
 }
@@ -2127,7 +2151,8 @@ def _gmm_call(kernel, items, arrays, in_blocks, out_shape, out_block,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_GMM_VMEM_LIMIT),
-        interpret=interpret, name=KERNEL_NAMES[kernel.func.__name__],
+        interpret=interpret,
+        name=KERNEL_NAMES[getattr(kernel, "func", kernel).__name__],
     )(*items, *arrays)
 
 
@@ -2201,6 +2226,140 @@ def _grouped_matmul_bwd(interpret, res, dy):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the held experts' combine (ISSUE 43): every token's sum of its live rows of
+# a sorted buffer, what `ops.moe._sum_rows` gathered a row a (token, slot)
+# pair for, held or not. With the rows in TOKEN order (`seg_sum_plan`: one
+# sort of the buffer's rows by the pair sorted there, the dead rows last;
+# `_rows_in_token_order`: the live rows gathered, a chunk at a time) a
+# tile of tokens owns a run of rows, and its sums are one-hot products:
+# out[tile] = onehot(row's token - tile's first)^T @ y[rows], 0 and 1 exact
+# in any dtype, accumulated in float32 and rounded once. That is the grouped
+# transposed product's shape of work with the token tiles as groups, so the
+# work list is `gmm_items`' and the call `_gmm_call`'s; the one-hot is built
+# in VMEM from the rows' token ids. Rows that are no tile's (past `n_live`)
+# are SELECTED away before the product: they hold whatever a grouped
+# product left there, and 0 x NaN is NaN.
+# ---------------------------------------------------------------------------
+
+def _seg_sum_row_tile(rows: int) -> Optional[int]:
+    return _largest_divisor(rows, _LANE, _SEG_SUM_ROW_TILE)
+
+
+def seg_sum_view(rows: int, tokens: int, width: int,
+                 itemsize: int) -> Optional[int]:
+    """The token tile `veles_seg_sum` sums a buffer of `rows` rows of
+    `width` into `tokens` tokens with, or None where it has no view of
+    the shape: whole 128-lane tiles of the width, a row tile of whole lane
+    tiles (the rows are the one-hot's lanes) that divides the rows, a
+    token tile of whole sublane tiles that divides the tokens, and the
+    blocks (double-buffered rows and sums, the float32 accumulator and
+    the product's float32 temporaries) within _GMM_BLOCK_BUDGET."""
+    if width % _LANE:
+        return None
+    row_tile = _seg_sum_row_tile(rows)
+    tile = _largest_divisor(tokens, _sublanes(itemsize), _SEG_SUM_TOKEN_TILE)
+    if not row_tile or not tile:
+        return None
+    blocks = width * ((2 * itemsize + 4) * row_tile + (2 * itemsize + 8) * tile)
+    return tile if blocks <= _GMM_BLOCK_BUDGET else None
+
+
+def seg_sum_plan(pairs, n_live, k: int, tokens: int, tile: int):
+    """What `seg_sum` needs of a sorted buffer whose row r holds the
+    (token, slot) pair `pairs[r]` (token-major ids, (R,) int32), the first
+    `n_live` rows live: (the buffer's rows in token order, the dead ones
+    last (R,); their tokens as (R // row tile, 1, row tile), `tokens` for
+    a dead row; `gmm_items`' work list with the tiles of `tile` tokens as
+    groups)."""
+    rows = pairs.shape[0]
+    row_tile = _seg_sum_row_tile(rows)
+    at = jnp.arange(rows, dtype=jnp.int32)
+    key, perm = lax.sort_key_val(
+        jnp.where(at < n_live, pairs.astype(jnp.int32), tokens * k), at)
+    tok = key // k
+    sizes = (tok[:, None] // tile == jnp.arange(
+        tokens // tile, dtype=jnp.int32)).sum(axis=0, dtype=jnp.int32)
+    return (perm, tok.reshape(-1, 1, row_tile),
+            *gmm_items(sizes, rows, row_tile))
+
+
+def _seg_sum_kernel(group_ref, tile_ref, lo_ref, hi_ref, n_ref, tok_ref,
+                    y_ref, out_ref, acc):
+    """Grid (items,): a token tile's items follow one another, their
+    one-hot products added up in `acc` and written with the tile's last."""
+    i, n = pl.program_id(0), n_ref[0]
+
+    @pl.when(i < n)
+    def _():
+        g = group_ref[i]
+
+        @pl.when((i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != g))
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        y = y_ref[...]
+        keep = _gmm_rows_kept(tile_ref, lo_ref, hi_ref, i, g,
+                              (y.shape[0], 1))
+        y = jnp.where(keep, y.astype(jnp.float32), 0.0).astype(y.dtype)
+        tokens = acc.shape[0]
+        hot = tok_ref[0] - g * tokens == lax.broadcasted_iota(
+            jnp.int32, (tokens, y.shape[0]), 0)
+        # (a float32 buffer's rows go through the product whole)
+        acc[...] += lax.dot_general(
+            hot.astype(y.dtype), y, (((1,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST if y.dtype == jnp.float32
+            else lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+
+        @pl.when((i == n - 1) | (group_ref[jnp.minimum(
+            i + 1, group_ref.shape[0] - 1)] != g))
+        def _():
+            out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+def _rows_in_token_order(y, perm, n_live):
+    """y's rows `perm[j]` for every j below `n_live`, a chunk of
+    _SEG_SUM_TAKE_ROWS rows a step of a walk as long as the live rows
+    are; zeros past the chunk that holds the last of them, which the
+    kernel reads no more than it reads the dead rows of that chunk."""
+    rows = perm.shape[0]
+    chunk = _largest_divisor(rows, _LANE, _SEG_SUM_TAKE_ROWS)
+
+    def more(i, out):
+        at = lax.dynamic_slice(perm, (i * chunk,), (chunk,))
+        return lax.dynamic_update_slice(out, jnp.take(y, at, axis=0),
+                                        (i * chunk, 0))
+
+    return lax.fori_loop(0, (n_live + chunk - 1) // chunk, more,
+                         jnp.zeros_like(y))
+
+
+@_kernel_jit
+def seg_sum_pallas(y, tok, group, tile_of, lo, hi, n, *, tile: int,
+                   interpret: bool = False):
+    """y (R, C), its rows in token order, and `seg_sum_plan`'s tokens and
+    items over tiles of `tile` tokens -> (tokens, C) in y's dtype: every
+    token's sum of its rows inside its tile's [lo, hi), zeros for a token
+    without one."""
+    c, row_tile, tokens = y.shape[1], tok.shape[-1], tile * lo.shape[0]
+    return _gmm_call(
+        _seg_sum_kernel, (group, tile_of, lo, hi, n), (tok, y),
+        [((None, 1, row_tile), lambda g, t: (t, 0, 0)),
+         ((row_tile, c), lambda g, t: (t, 0))],
+        jax.ShapeDtypeStruct((tokens, c), y.dtype),
+        ((tile, c), lambda g, t: (g, 0)),
+        [pltpu.VMEM((tile, c), jnp.float32)], interpret)
+
+
+def seg_sum(y, plan, n_live, tile: int, interpret: bool = False):
+    """Every token's sum of its live rows of the sorted buffer y (R, C):
+    (tokens, C) in y's dtype, summed in float32 and rounded once. `plan`
+    is `seg_sum_plan`'s for the buffer at `tile` tokens a group."""
+    perm, *items = plan
+    return seg_sum_pallas(_rows_in_token_order(y, perm, n_live), *items,
+                          tile=tile, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -492,7 +492,9 @@ class BlockSpec:
                     hn, idx, gates, p["moe_experts_gate"],
                     p["moe_experts_up"], p["moe_experts_down"], self.held,
                     self.fast_rows(h.shape[0]), self.grouped_kernels(),
-                    variants.pallas_interpret_active())
+                    variants.pallas_interpret_active(),
+                    # the combine is the same whatever forms the products
+                    seg_sum=self.allow_pallas and variants.pallas_ok())
             if self.shared:
                 with jax.named_scope("shared"):
                     ys = ol.swiglu(hn, p["moe_shared_gate"],
