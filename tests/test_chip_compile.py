@@ -209,7 +209,7 @@ def test_grouped_product_kernels_compile_at_published_widths(
         assert tile == 512
 
         def fwd_bwd(x, w, sizes, dy):
-            dot = om._grouped_product(sizes, rows, x, w, True, False)
+            dot = om._grouped_product(sizes, rows, x, w, "pallas", True)
             y, vjp = jax.vjp(dot, x, w)
             return y, vjp(dy)
         txt = _compile(fwd_bwd, _sds(one_chip, (rows, a), jnp.bfloat16),
@@ -244,9 +244,9 @@ def test_seg_sum_kernel_compiles_at_the_cells_shapes(one_chip,
 
         def both(y, h, *plan):
             token_of = plan[0] // k
-            return (om._sum_rows(y, token_of, plan, 7, (tile, False)),
+            return (om._sum_rows(y, token_of, plan, 7, tile),
                     jax.grad(lambda h: om._take_rows(
-                        h, token_of, plan, 7, (tile, False)).astype(
+                        h, token_of, plan, 7, tile).astype(
                             jnp.float32).sum())(h))
         txt = _compile(both, _sds(one_chip, (rows, c), dtype),
                        _sds(one_chip, (tokens, c), dtype), *plan)
@@ -268,7 +268,9 @@ def test_gdn_chunk_kernels_compile_at_the_cells_shapes(one_chip,
     g = pk.gdn_view(n * b, c, d, d, jnp.float32, jnp.bfloat16)
     assert g == 32
     assert 2 * g * 2 * c * 11 * d <= pk._GDN_BLOCK_BUDGET < pk._GDN_VMEM_LIMIT
-    assert la._kernels_take(n * b, c, d, d, jnp.float32, jnp.bfloat16)
+    assert la._kernels_take(True, n * b, c, d, d, jnp.float32, jnp.bfloat16)
+    assert not la._kernels_take(False, n * b, c, d, d, jnp.float32,
+                                jnp.bfloat16)
     mat = _sds(one_chip, (n, b, c, d), jnp.bfloat16)
     row = _sds(one_chip, (n, b, c), jnp.float32)
 
@@ -302,7 +304,6 @@ def test_hyper_connection_kernels_compile_at_published_widths(
     `custom_vjp`s, hold the four kernels under their scopes; the float32
     streams of a float32 run fit the same 64 MB of VMEM (`hc_view` is a
     rule of the shape alone)."""
-    from veles_tpu.ops import lm as ol
     n, c, tokens = 4, 3584, 8192
     assert pk.hc_view(tokens, c, n) * 2 * (3 * n + 2) * c * 4 \
         <= pk._HC_BLOCK_BUDGET < pk._HC_VMEM_LIMIT
@@ -321,7 +322,6 @@ def test_hyper_connection_kernels_compile_at_published_widths(
     def fwd_bwd(pp, xx):
         return jax.vjp(fwd, pp, xx)[1](xx)     # the cotangent: x itself
 
-    assert ol.hc_pallas_takes(x, n)
     txt = _compile(fwd, p, x)
     assert txt.count("tpu_custom_call") >= 2
     txt += _compile(fwd_bwd, p, x)

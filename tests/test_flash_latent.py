@@ -159,7 +159,7 @@ def test_the_lowering_follows_the_platform_and_the_shape(case, caplog):
     with caplog.at_level(logging.WARNING, logger="veles.variants"), \
             (variants.pallas_interpret() if interpret
              else contextlib.nullcontext()):
-        assert spec.mla_lowering(seq) == want
+        assert spec.lowerings(2, seq) == {"flash_attn": want}
         text = str(jax.make_jaxpr(jax.grad(
             lambda pp: spec.apply(pp, x)[0].sum()))(p))
     assert not caplog.records
@@ -173,4 +173,4 @@ def test_another_attention_resolves_nothing():
                      residual="plain", kv_heads=1, head_dim=128,
                      index_heads=2, index_dim=8, index_topk=4, ffn="dense",
                      width=32)
-    assert spec.mla_lowering(128) is None
+    assert "flash_attn" not in spec.lowerings(1, 128)

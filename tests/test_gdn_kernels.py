@@ -25,6 +25,7 @@ if ROOT not in sys.path:
 from benchmark import qwen3next_reference  # noqa: E402
 from veles_tpu.ops import linear_attention as la  # noqa: E402
 from veles_tpu.ops import pallas_kernels as pk  # noqa: E402
+from veles_tpu.ops import variants  # noqa: E402
 
 OUTPUTS = ("w", "u0", "kd", "last", "attn", "qg")
 #: log-decays a token: weak ones barely decay inside a chunk, strong ones
@@ -36,6 +37,12 @@ DECAYS = {"weak": (-7.0, -3.0), "strong": (0.5, 2.5), "mixed": (-5.0, 2.5)}
 @pytest.fixture
 def interpreted(monkeypatch):
     monkeypatch.setattr(pk, "_FORCE_INTERPRET", True)
+
+
+def _scan(*a, **kw):
+    """`gated_delta_chunked` as a block calls it: with the package's word
+    on whether kernels may be traced (here: where interpret mode is on)."""
+    return la.gated_delta_chunked(*a, kernels=variants.kernels_ok(), **kw)
 
 
 def _inputs(seq, heads, decay="mixed", n=1, dk=128, dv=128, seed=0,
@@ -114,7 +121,7 @@ def test_the_forward_kernels_outputs_are_the_twins(stage_outputs, decay,
 
 def _value_and_grads(args, ct, chunk=64):
     def loss(*a):
-        o, state, _ = la.gated_delta_chunked(*a, chunk=chunk)
+        o, state, _ = _scan(*a, chunk=chunk)
         return jnp.sum(o * ct), (o, state)
     return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
                               has_aux=True)(*args)
@@ -132,8 +139,10 @@ def scan_gradients():
             args, ct = _inputs(seq, heads)
             prev, pk._FORCE_INTERPRET = pk._FORCE_INTERPRET, True
             try:
+                # (a lambda a trace: jax caches a function's jaxpr by
+                # its arguments' shapes, whatever mode it was traced in)
                 assert "pallas_call" in str(jax.make_jaxpr(
-                    lambda *a: la.gated_delta_chunked(*a))(*args))
+                    lambda *a: _scan(*a))(*args))
                 kernels = _value_and_grads(args, ct)
             finally:
                 pk._FORCE_INTERPRET = prev
@@ -209,7 +218,7 @@ def test_what_the_view_refuses_still_runs_the_twin(interpreted, refused):
                       dtype=f32 if refused == "f32_operands" else bf16)
 
     def run(*a):
-        return la.gated_delta_chunked(*a, **kw)
+        return _scan(*a, **kw)
     assert "pallas_call" not in str(jax.make_jaxpr(run)(*args))
     o, state, _ = run(*args)
     assert bool(jnp.all(jnp.isfinite(o))) and o.shape == args[2].shape
@@ -228,7 +237,7 @@ def test_the_kernels_are_asked_for_never_fallen_into():
     assert not pk._interpret()
     args, _ = _inputs(128, 2)
     assert "pallas_call" not in str(jax.make_jaxpr(
-        lambda *a: la.gated_delta_chunked(*a))(*args))
+        lambda *a: _scan(*a))(*args))
     assert {pk.KERNEL_NAMES[k] for k in ("_gdn_chunk_fwd_kernel",
                                          "_gdn_chunk_bwd_kernel")} \
         == {"veles_gdn_chunk_fwd", "veles_gdn_chunk_bwd"}

@@ -106,7 +106,6 @@ def test_one_pass_a_side_gives_what_the_xla_form_and_the_reference_give(
     tile = pk.hc_view(tokens, c, n)
     assert tokens % tile == 0 and tile % 128 == 0
     with variants.pallas_interpret():
-        assert ol.hc_pallas_takes(args[1], n)
         out_p, g_p = value_and_grads(
             variants.get("hc", "pallas_one_pass").apply, n,
             proj, args)
@@ -223,13 +222,13 @@ def test_the_lowering_follows_the_platform_and_the_shape(case, caplog):
     x = jnp.ones((tokens, n * c), jnp.float32)
 
     def loss(xx):
-        return variants.resolve("hc", unit=spec).apply(
+        return variants.get("hc", spec.lowerings(1, tokens)["hc"]).apply(
             p, xx, lambda h: (h, None), n, **KW)[0].sum()
 
     with caplog.at_level(logging.WARNING, logger="veles.variants"), \
             (variants.pallas_interpret() if interpret
              else contextlib.nullcontext()):
-        assert spec.hc_lowering(tokens) == want
+        assert spec.lowerings(1, tokens)["hc"] == want
         text = str(jax.make_jaxpr(jax.grad(loss))(x))
     assert not caplog.records
     assert text.count("veles_hc_") == (4 if want == "pallas_one_pass" else 0)

@@ -43,8 +43,8 @@ SEARCH_OPS = ["lrn_maxpool", "flash_attn", "sgd_update"]
 @pytest.fixture(autouse=True)
 def _interpret_mode():
     """No TPU here: this file ASKS for interpret-mode kernels (they
-    never fall into it by themselves). Kernel-level only — resolve()'s
-    gating stays as it is off a TPU."""
+    never fall into it by themselves), through the one switch there is:
+    `variants.resolve` and `variants.pallas_ok` read it too."""
     import veles_tpu.ops.pallas_kernels as pk
     prev, pk._FORCE_INTERPRET = pk._FORCE_INTERPRET, True
     yield
@@ -768,9 +768,13 @@ def test_fusion_precedence_conv_epilogue_wins_the_shared_lrn():
     assert pairs == [(0, 1)]
 
 
-def test_fusion_gates_block_claim():
+def test_fusion_gates_block_claim(monkeypatch):
     """No claim under GSPMD (a pallas_call cannot be auto-partitioned),
     under a member override, or for the maxabs flavor."""
+    # (this file's fixture asks for interpret mode; there is one switch,
+    # so the gate off a TPU is read with it off)
+    monkeypatch.setattr("veles_tpu.ops.pallas_kernels._FORCE_INTERPRET",
+                        False)
     variants.select("lrn_maxpool", "fused[rt=2,io=native,fuse=1]")
     wf = _tiny_workflow("FuseGateT")
     wf.initialize(device=None)

@@ -24,6 +24,7 @@ from benchmark import keye2_ops_count, keye2_reference, keye2_seeded  # noqa: E4
 from veles_tpu.ops import attention as oa  # noqa: E402
 from veles_tpu.ops import lm as ol  # noqa: E402
 from veles_tpu.ops import moe as om  # noqa: E402
+from veles_tpu.ops import variants  # noqa: E402
 from veles_tpu.samples.keye2 import TINY, layer_table  # noqa: E402
 
 OPT = {"learning_rate": 0.01, "gradient_moment": 0.9,
@@ -510,23 +511,13 @@ def test_the_kernels_have_no_view_of_a_width_off_the_lanes():
     assert pk.gmm_view(24576, 8192, 8192, 2) is None    # no room for a matrix
 
 
-def test_the_combine_gathers_from_no_more_than_a_gather_reads_fast(
-        monkeypatch):
-    """`_sum_rows` reads a buffer past 112 MiB by halves or quarters of its
-    columns (what a v5e gathers from at full speed), never in more than
-    four parts, and the parts are the whole."""
-    assert om._gather_width(24576, 2048, 2) == 2048
-    assert om._gather_width(49152, 2048, 2) == 1024
-    assert om._gather_width(131072, 2048, 2) == 2048    # eight parts: whole
-    assert om._gather_width(32768, 3584, 2) == 1792
-    assert om._gather_width(49152, 2048 + 64, 2) == 2048 + 64
+def test_the_combines_gather_is_the_hand_sum_of_the_live_rows():
+    """`_sum_rows` without a kernel: every token's slots gathered from the
+    whole width of the buffer, the live ones summed."""
     rng = np.random.default_rng(0)
     y = jnp.asarray(rng.normal(size=(64, 256)), jnp.float32)
     slot = jnp.asarray(rng.permutation(128).reshape(32, 4), jnp.int32)
     whole = om._sum_rows(y, None, slot, 40)
-    monkeypatch.setattr(om, "_GATHER_OPERAND_MAX", 64 * 128 * 4)
-    assert om._gather_width(64, 256, 4) == 128
-    assert np.array_equal(om._sum_rows(y, None, slot, 40), whole)
     want = np.zeros((32, 256), np.float32)
     for t, row in enumerate(np.asarray(slot)):
         want[t] = sum(np.asarray(y)[r] for r in row if r < 40)
@@ -551,10 +542,11 @@ def test_the_held_experts_through_the_kernels_are_the_held_experts(
     def run(kernels):
         def loss(x, gates, *ws):
             y, dropped = om.held_experts_swiglu(
-                x, idx, gates, *ws, held, fast_rows, kernels, True)
+                x, idx, gates, *ws, held, fast_rows, "pallas", kernels)
             return (y * jnp.cos(jnp.arange(c))).sum(), dropped
-        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
-                                  has_aux=True)(x, gates, *ws)
+        with variants.pallas_interpret():
+            return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                      has_aux=True)(x, gates, *ws)
     (la, da), ga = run(False)
     (lb, db), gb = run(True)
     assert int(da) == int(db) == 0
@@ -581,11 +573,12 @@ def test_three_steps_through_the_grouped_kernels_follow_the_reference(
         monkeypatch.setattr(pk, name, (lambda f, name: lambda *a, **kw: (
             calls.append(name), f(*a, **kw))[1])(getattr(pk, name), name))
     mod, ses = session_of(cfg)
-    assert not ses.step.forwards[1].spec.grouped_kernels()
+    spec = ses.step.forwards[1].spec
+    assert spec.grouped == "pallas" and not variants.kernels_ok(spec)
     ses.free_program()
     with variants.pallas_interpret():
         mod, ses = session_of(cfg)
-        assert ses.step.forwards[1].spec.grouped_kernels()
+        assert variants.kernels_ok(ses.step.forwards[1].spec)
         prog = ses.first_steps()
         ses.free_program()
     # forward, the forward `jax.checkpoint` traces again, and the
@@ -712,7 +705,8 @@ def test_a_second_kind_of_block_is_one_spec_not_two():
     assert "moe_shared_up" in a.shapes() and "moe_shared_up" not in b.shapes()
     assert "bias" in a.aux_shapes() and "bias" not in b.aux_shapes()
     assert {"attn_idx_w_q", "hcm_p_res", "moe_shared_up"} <= set(c.shapes())
-    assert b.hc_lowering(64) is None and a.hc_lowering(64) == "xla"
+    assert "hc" not in b.lowerings(1, 64)
+    assert a.lowerings(1, 64)["hc"] == "xla"
     with pytest.raises(ValueError):
         BlockSpec(residual="plain", streams=2, **base, **indexed)
     with pytest.raises(ValueError):

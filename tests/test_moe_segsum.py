@@ -13,6 +13,15 @@ import pytest
 
 from veles_tpu.ops import moe as om
 from veles_tpu.ops import pallas_kernels as pk
+from veles_tpu.ops import variants
+
+
+@pytest.fixture(autouse=True)
+def _interpreted():
+    """Every kernel of this file runs in interpret mode, read where
+    `pallas_kernels` is called."""
+    with variants.pallas_interpret():
+        yield
 
 
 def _routing(t: int, k: int, rows: int, share: float, seed: int,
@@ -36,7 +45,7 @@ def _both(y, order, n_live: int, t: int, k: int):
     assert tile
     plan = pk.seg_sum_plan(order[:rows], n_live, k, t, tile)
     return (om._sum_rows(y, None, slot, n_live),
-            om._sum_rows(y, None, plan, n_live, (tile, True)))
+            om._sum_rows(y, None, plan, n_live, tile))
 
 
 def _ulps(got, want) -> float:
@@ -89,7 +98,7 @@ def test_the_segment_sum_is_the_transpose_of_the_rows_gather(dtype):
     for fn, x, ct in ((om._take_rows, h, g), (om._sum_rows, g, h)):
         (y0, vjp0), (y1, vjp1) = (
             jax.vjp(lambda x: fn(x, token_of, pairs, n_live, seg), x)
-            for pairs, seg in ((slot, None), (plan, (tile, True))))
+            for pairs, seg in ((slot, None), (plan, tile)))
         np.testing.assert_allclose(np.asarray(y1, np.float32),
                                    np.asarray(y0, np.float32),
                                    rtol=1e-6, atol=1e-2 * (
@@ -198,7 +207,7 @@ def _layer(dtype, fast_rows, seg_sum: bool, skew: float = 0.0):
     def loss(h, gates, *ws):
         y, dropped = om.held_experts_swiglu(
             h, idx, gates.astype(dtype), *ws, (0, count), fast_rows,
-            interpret=True, seg_sum=seg_sum)
+            kernels=seg_sum)
         return jnp.sum(jnp.sin(y.astype(jnp.float32))), (y, dropped)
     return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
         h, gates, *weights), (idx < count).sum()
@@ -275,6 +284,6 @@ def test_no_sort_of_every_pair_is_left_on_the_engaged_path():
         return sorted(e.invars[0].aval.shape[0] for e in _traced(
             lambda: om.held_experts_swiglu(
                 h, idx, gates, w, w, w.swapaxes(1, 2), (0, 2),
-                interpret=True, seg_sum=seg_sum)[0], "sort"))
+                kernels=seg_sum)[0], "sort"))
     assert sorted_lengths(False) == [384, 384]
     assert sorted_lengths(True) == [256, 384]

@@ -291,12 +291,12 @@ def test_the_spec_takes_the_flash_lowering_where_the_kernels_take_the_head():
                      rotary_dim=64, **base)
     lin = BlockSpec(attention="gated_delta", n_heads=4, key_heads=2,
                     value_heads=4, key_dim=16, value_dim=16, **base)
-    assert full.mla_lowering(8192) == "xla_blocked"      # off a TPU
-    with variants.pallas_interpret():
-        assert full.mla_lowering(8192) == "pallas"
-        assert full.mla_lowering(100) == "xla_blocked"
-    assert lin.mla_lowering(8192) is None
-    assert full.hc_lowering(64) is None and lin.dsa_lowering(64) is None
+    assert full.lowerings(1, 8192) == {"flash_attn": "xla_blocked"}
+    with variants.pallas_interpret():               # (above: off a TPU)
+        assert full.lowerings(1, 8192) == {"flash_attn": "pallas"}
+        assert full.lowerings(1, 100) == {"flash_attn": "xla_blocked"}
+        # a Gated DeltaNet on a plain residual path resolves no registry op
+        assert lin.lowerings(1, 8192) == {}
 
 
 # -- the share of a deployment ------------------------------------------------------

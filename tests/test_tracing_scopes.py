@@ -226,8 +226,8 @@ PALLAS_SITES = {
 def _combined(h):
     from veles_tpu.ops import moe as om
     pairs = np.arange(128, dtype=np.int32)      # two slots a token, all held
-    seg = (pk.seg_sum_view(128, 64, 128, 4), True)
-    plan = pk.seg_sum_plan(pairs, 100, 2, 64, seg[0])
+    seg = pk.seg_sum_view(128, 64, 128, 4)
+    plan = pk.seg_sum_plan(pairs, 100, 2, 64, seg)
     rows = om._take_rows(h, pairs // 2, plan, 100, seg)
     return om._sum_rows(rows, pairs // 2, plan, 100, seg)
 
@@ -238,7 +238,8 @@ def _delta_chunks(v):
     from veles_tpu.ops import linear_attention as la
     v = v.astype(jnp.bfloat16)
     g = -jnp.ones(v.shape[:3], jnp.float32)
-    return la.gated_delta_chunked(0.1 * v, 0.1 * v, v, g, -0.5 * g)[0]
+    return la.gated_delta_chunked(0.1 * v, 0.1 * v, v, g, -0.5 * g,
+                                  kernels=True)[0]
 
 
 def _grouped(x):

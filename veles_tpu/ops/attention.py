@@ -258,7 +258,7 @@ def latent_attention(p, h, *, n_heads: int, nope: int, rope: int,
     compute dtype before they meet the values, in both forms of the core:
     the blocked XLA one, or the flash kernels where the caller hands on
     `flash`, the registry's `flash_attn` lowering (its rule:
-    `znicz/lm.py::BlockSpec.mla_lowering`)."""
+    `znicz/lm.py::BlockSpec.lowerings`)."""
     from veles_tpu.ops.lm import apply_rope, mm, rms_norm
     n, s, _ = h.shape
     c_q = rms_norm(mm(h, p["w_dq"]), p["q_norm"], norm_eps)

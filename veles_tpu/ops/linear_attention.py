@@ -38,12 +38,13 @@ formed "for all chunks at once" before the chain (`A`, `T`, `W`, `U0`, the
 decayed keys and queries, the decay-weighted `Q K^T`) is a dozen (C, C)
 float32 matrices a chunk and head; as XLA traces the equations
 (`_operands_xla`) each passes through HBM with its 64 lanes padded to 128.
-On a TPU, for chunks of 64, heads of whole lanes, float32 decays and
-bfloat16 operands (`pallas_kernels.gdn_view` says the rule, nothing else
-chooses: no argument, key or variable), the stage is `_chunk_operands`: the
-kernels `veles_gdn_chunk_fwd` and `veles_gdn_chunk_bwd` under a
-`jax.custom_vjp` that keeps its INPUTS alone, every (C, C) matrix living
-and dying in VMEM, the backward forming `T` and the decay matrix again
+Where the caller says kernels may be traced (`kernels`: the step allows
+them and the platform runs them, `variants.kernels_ok`), for chunks of 64,
+heads of whole lanes, float32 decays and bfloat16 operands
+(`pallas_kernels.gdn_view` says the shapes), the stage is
+`_chunk_operands`: the kernels `veles_gdn_chunk_fwd` and
+`veles_gdn_chunk_bwd` under a `jax.custom_vjp` that keeps its INPUTS
+alone, every (C, C) matrix living and dying in VMEM, the backward forming `T` and the decay matrix again
 (which is the recomputation the XLA form's own `jax.checkpoint` asks for,
 inside VMEM). The cumulative sums along a chunk, the chain and the
 outputs are XLA's in both.
@@ -227,14 +228,14 @@ _delta_scan.defvjp(_delta_scan_fwd, _delta_scan_bwd)
 
 # -- the chunks' operands as two kernels -----------------------------------------
 
-def _kernels_take(chunk_heads: int, chunk: int, dk: int, dv: int,
-                  scan_dtype, op) -> bool:
+def _kernels_take(kernels: bool, chunk_heads: int, chunk: int, dk: int,
+                  dv: int, scan_dtype, op) -> bool:
     """Whether the operand stage runs as `veles_gdn_chunk_fwd` / `_bwd`:
-    on a TPU, or where a test asked for interpret mode, for the shapes
-    `pallas_kernels.gdn_view` takes."""
+    where `kernels` may be traced (`gated_delta_chunked`'s), for the
+    shapes `pallas_kernels.gdn_view` takes."""
     from veles_tpu.ops import pallas_kernels as pk
-    return bool((pk._interpret() or pk.available())
-                and pk.gdn_view(chunk_heads, chunk, dk, dv, scan_dtype, op))
+    return bool(kernels and pk.gdn_view(chunk_heads, chunk, dk, dv,
+                                        scan_dtype, op))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -327,7 +328,8 @@ def chunks_of(seq: int, chunk: int) -> Tuple[int, int]:
 
 def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
                         scan_dtype=jnp.float32, finish=None, gate=None,
-                        finish_args=()) -> Tuple[Any, Any, Any]:
+                        finish_args=(), kernels: bool = False
+                        ) -> Tuple[Any, Any, Any]:
     """The gated delta rule over whole sequences, a chunk at a time (module
     docstring). q and k (N, S, H, dk), already normalised and scaled, v
     (N, S, H, dv), g (the log-decay, <= 0) and beta (N, S, H) float32 ->
@@ -335,7 +337,9 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
     lowest cumulative log-decay a chunk reaches). The products read
     operands of v's dtype. A sequence that is no multiple of `chunk` is
     filled up with tokens that neither write (beta 0, k 0) nor decay
-    (g 0).
+    (g 0). `kernels`: whether Pallas kernels may be traced
+    (`variants.kernels_ok`, the caller's); the operand stage is then the
+    two kernels where `pallas_kernels.gdn_view` takes the shape.
 
     With `finish` the first result is `finish(o, gate, *finish_args)` in
     o's place, `gate` (N, S, H, dv) met chunk by chunk as o is laid out
@@ -369,7 +373,7 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
         o = o.reshape(nc, n, h, chunk, dv).transpose(1, 0, 3, 2, 4)
         return o.reshape(n, nc * chunk, h, dv)[:, :s]
 
-    if _kernels_take(nc * n * h, chunk, dk, dv, scan_dtype, op):
+    if _kernels_take(kernels, nc * n * h, chunk, dk, dv, scan_dtype, op):
         # (their backward IS the stage's recomputation, inside VMEM)
         operands = _operands_kernels
     else:
@@ -391,7 +395,7 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
 def gated_delta_net(p: Dict[str, Any], h, *, key_heads: int,
                     value_heads: int, key_dim: int, value_dim: int,
                     chunk: int = 64, norm_eps: float = 1e-6,
-                    scan_dtype=jnp.float32):
+                    scan_dtype=jnp.float32, kernels: bool = False):
     """One Gated DeltaNet layer on normed input h (N, S, C) -> ((N, S, C),
     what the layer counted: the final state (N, Hv, dk, dv) float32 and
     its root mean square, the lowest cumulative log-decay of a chunk).
@@ -406,7 +410,7 @@ def gated_delta_net(p: Dict[str, Any], h, *, key_heads: int,
     elementwise on either side of the scan (the convolution with the
     normalisations and the gates; the outputs' norm and gate) stands under
     a `jax.checkpoint` of its own: its float32 insides are formed again in
-    the backward pass, not kept."""
+    the backward pass, not kept. `kernels` is `gated_delta_chunked`'s."""
     from veles_tpu.ops.lm import mm, rms_norm
     n, s, _ = h.shape
     kw, vw = key_heads * key_dim, value_heads * value_dim
@@ -440,7 +444,7 @@ def gated_delta_net(p: Dict[str, Any], h, *, key_heads: int,
     with jax.named_scope("scan"):
         y, final, lowest = gated_delta_chunked(
             q, k, v, g, beta, chunk=chunk, scan_dtype=scan_dtype,
-            finish=gated, finish_args=(p["o_norm"],),
+            finish=gated, finish_args=(p["o_norm"],), kernels=kernels,
             gate=qkvz[..., 2 * kw + vw:].reshape(n, s, value_heads,
                                                  value_dim))
     with jax.named_scope("out"):

@@ -273,14 +273,6 @@ def _hc_post_core_bwd(n, interpret, res, g):
 _hc_post_core.defvjp(_hc_post_core_fwd, _hc_post_core_bwd)
 
 
-def hc_pallas_takes(x, n: int) -> bool:
-    """Whether the kernels take streams x (T, n*C): the shape half of the
-    rule (`pallas_kernels.hc_view`)."""
-    from veles_tpu.ops import pallas_kernels as pk
-    return x.ndim == 2 and x.shape[1] % n == 0 and bool(pk.hc_view(
-        x.shape[0], x.shape[1] // n, n))
-
-
 def hc_pre_xla(p: Dict[str, Any], x, n: int, **kw):
     """(h, what the post side takes: (Hpost, Hres), x) of one connection,
     the `xla` lowering."""
@@ -293,10 +285,9 @@ def hc_post_xla(x, y, maps, n: int):
 
 
 def hc_pre_pallas(p: Dict[str, Any], x, n: int, **kw):
-    """The same in one pass over x (`pallas_one_pass`); the XLA form for
-    streams the kernels do not take."""
-    if not hc_pallas_takes(x, n):
-        return hc_pre_xla(p, x, n, **kw)
+    """The same in one pass over x (`pallas_one_pass`), for streams the
+    kernels take (`pallas_kernels.hc_view`: the caller's question,
+    `znicz/lm.py::BlockSpec.lowerings`)."""
     from veles_tpu.ops import pallas_kernels as pk
     static = dict(kw, n=n, interpret=pk._interpret(),
                   clamp=tuple(float(v) for v in kw["clamp"]))
@@ -307,8 +298,6 @@ def hc_pre_pallas(p: Dict[str, Any], x, n: int, **kw):
 
 def hc_post_pallas(x, y, maps, n: int):
     """`hc_write` as one pass (`pallas_one_pass`)."""
-    if not hc_pallas_takes(x, n):
-        return hc_post_xla(x, y, maps, n)
     from veles_tpu.ops import pallas_kernels as pk
     return _hc_post_core(x, y, maps, n, pk._interpret())
 

@@ -1,16 +1,30 @@
-"""Mixture-of-experts ops: dense golden routing + expert-parallel form.
+"""Mixture-of-experts ops.
 
-Absent in the reference (2015-era framework); added because the TPU
-build's distributed layer treats expert parallelism as a first-class mesh
-axis alongside data/model/sequence. Design follows the standard TPU
-recipe: top-1 (switch) routing, capacity-bounded dispatch expressed as
-dense einsums with a one-hot dispatch mask (MXU-friendly, no gather
-loops), and `lax.all_to_all` to exchange tokens when experts are sharded
-over a mesh axis.
+Two things live here. First, the top-1 golden model and its
+expert-parallel form, what the `znicz/moe.py` unit runs (no benchmark
+cell): switch routing with a capacity, dispatch and combine as dense
+einsums over a one-hot mask, and `lax.all_to_all` where the experts are
+sharded over a mesh axis. `moe_forward` (all experts local) is the golden
+model; `moe_forward_ep` (inside shard_map) must match it, tested on the
+virtual 8-device mesh.
 
-`moe_forward` (all experts local) is the golden model; `moe_forward_ep`
-(inside shard_map, experts sharded over `axis_name`) must match it —
-tested on the virtual 8-device mesh.
+Second, and two thirds of the file, the held experts' dropless top-k
+path, what the three language-model cells run (`znicz/lm.py::BlockSpec`):
+a chip routes over ALL the experts and computes the `held` ones
+(`held_experts_swiglu`). The (token, slot) pairs are sorted by expert
+into ONE buffer of static size, the held ones first; `_take_rows` fills
+its rows, three grouped products (`_grouped_product`: `lax.ragged_dot`
+or, where the layer table says `grouped="pallas"`, `veles_gmm` /
+`veles_tgmm`) run the SwiGLU over it, and `_sum_rows`, the combine, adds
+every token's rows up (`veles_seg_sum` over the held rows, or a gather of
+a row a pair). The buffer has two sizes under one `lax.cond`
+(`_held_swiglu`): `fast_rows` where the held pairs fit them, every pair
+there can be where they do not, that one walked in windows of `fast_rows`
+past `_WHOLE_BUFFER_MAX`. Which form a product or the combine traces is
+the package's one rule: the caller's `kernels` (`variants.kernels_ok`:
+the step allows kernels and the platform runs them), then the kernel's
+own view of the shape, asked here; `interpret` is read where
+`pallas_kernels` is called.
 """
 
 from __future__ import annotations
@@ -177,54 +191,22 @@ def _take_rows(h, token_of, pairs, n_live, seg=None):
     return jnp.where(live, jnp.take(h, token_of, axis=0), 0)
 
 
-#: `_sum_rows` has two forms. Where the step may trace Pallas kernels and
-#: `pallas_kernels.seg_sum_view` takes (rows, tokens, width) (whole lane
-#: tiles of the width, rows in whole lane tiles, tokens in whole sublane
-#: tiles: the three language-model cells' buffers, both branches), the
-#: rows are put in token order and summed by `veles_seg_sum`, which reads
-#: the buffer's rows and never a slot. Everywhere else (off a TPU, under
-#: `allow_pallas = False`, a width or a row count with no view) it gathers
-#: one row a (token, slot) pair, held or not, and these bound that gather.
-#: The most bytes of a buffer one gather of `_sum_rows` reads from: a v5e
-#: gathered the 131,072 (token, slot) rows of 2,048 bfloat16 out of a
-#: 28,672-row buffer (112 MiB) in 1.8 ms and out of a 32,704-row one in 5.9;
-#: out of two halves of the columns of a 49,152-row one in 2.6, out of
-#: four quarters in 3.4, out of eight eighths of a 131,072-row one in 10.5
-#: against 5.5 whole (my chip runs, PR 35): up to _GATHER_PARTS_MAX parts
-_GATHER_OPERAND_MAX = 112 << 20
-_GATHER_PARTS_MAX = 4
-
-
-def _gather_width(rows: int, width: int, itemsize: int) -> int:
-    """Columns of a (rows, width) buffer one gather of `_sum_rows` reads:
-    the widest whole number of 128-lane tiles that divides the width, in
-    at most _GATHER_PARTS_MAX parts, whose part of the buffer is within
-    _GATHER_OPERAND_MAX; the whole width where there is none."""
-    for parts in range(1, _GATHER_PARTS_MAX + 1):
-        if width % (parts * 128) == 0 and \
-                rows * (width // parts) * itemsize <= _GATHER_OPERAND_MAX:
-            return width // parts
-    return width
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _sum_rows(y, token_of, pairs, n_live, seg=None):
     """Token t's sum of its live sorted rows of y (R, C): (T, C), summed
     in float32 and rounded once. `seg` None: `pairs` (T, k) is every
-    (token, slot) pair's sorted row, gathered whether held or not. `seg`
-    = (token tile, interpret): `pairs` is `pallas_kernels.seg_sum_plan`'s,
-    and the live rows alone are summed, as one-hot products."""
+    (token, slot) pair's sorted row, gathered whether held or not (the
+    plain form: off a TPU, under GSPMD, a shape with no view). `seg` a
+    token tile (`_seg_tile`): `pairs` is `pallas_kernels.seg_sum_plan`'s,
+    the rows are in token order and the live ones alone are summed, as
+    one-hot products by `veles_seg_sum`, which never reads a slot."""
     if seg is not None:
         from veles_tpu.ops import pallas_kernels as pk
-        return pk.seg_sum(y, pairs, n_live, *seg)
+        return pk.seg_sum(y, pairs, n_live, seg, pk._interpret())
     at = jnp.minimum(pairs, y.shape[0] - 1)
     live = (pairs < n_live)[..., None]
-    step = _gather_width(*y.shape, y.dtype.itemsize)
-    parts = [jnp.where(live, jnp.take(part, at, axis=0), 0
-                       ).astype(jnp.float32).sum(axis=1).astype(y.dtype)
-             for part in ([y] if step == y.shape[1] else [
-                 y[:, c:c + step] for c in range(0, y.shape[1], step)])]
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    return jnp.where(live, jnp.take(y, at, axis=0), 0).astype(
+        jnp.float32).sum(axis=1).astype(y.dtype)
 
 
 _take_rows.defvjp(
@@ -235,46 +217,48 @@ _sum_rows.defvjp(
     lambda seg, res, g: (_take_rows(g, *res, seg), None, None, None))
 
 
-def _grouped_product(sizes, rows: int, like, w, kernels: bool,
-                     interpret: bool):
+def _grouped_product(sizes, rows: int, like, w, grouped: str,
+                     kernels: bool):
     """f(x, w) -> x's rows of group g times w[g] over a sorted buffer of
     `rows` rows whose groups hold `sizes`: `lax.ragged_dot` (a grouped
-    product of XLA's own on a TPU, (512, 512, 256) tiles), or with
-    `kernels` the `veles_gmm` / `veles_tgmm` pair where it has a view of
-    the shape (`pallas_kernels.gmm_view`): one work list for the three
-    products and their backward. Neither writes the rows past the last
-    group."""
-    if kernels:
+    product of XLA's own on a TPU, (512, 512, 256) tiles), or, where the
+    layer table asks for them (`grouped="pallas"`), kernels may be traced
+    and `pallas_kernels.gmm_view` has a view of the shape, the
+    `veles_gmm` / `veles_tgmm` pair: one work list for the three products
+    and their backward. Neither writes the rows past the last group."""
+    if kernels and grouped == "pallas":
         from veles_tpu.ops import pallas_kernels as pk
         tile = pk.gmm_view(rows, w.shape[1], w.shape[2], like.dtype.itemsize)
         if tile:
             items = pk.gmm_items(sizes, rows, tile)
-            return lambda x, w: pk.grouped_matmul(x, w, *items, interpret)
+            return lambda x, w: pk.grouped_matmul(x, w, *items,
+                                                  pk._interpret())
     return lambda x, w: lax.ragged_dot(x, w, sizes)
 
 
-def _seg_tile(lowering, rows: int, h):
+def _seg_tile(kernels: bool, rows: int, h):
     """The token tile `_sum_rows` sums `rows` sorted rows into h's tokens
-    with as `veles_seg_sum`, or None where it gathers the slots:
-    `lowering` = (the grouped products' kernels, interpret, whether the
-    step may trace Pallas kernels at all)."""
-    if not lowering[2]:
+    with as `veles_seg_sum`, or None where it gathers the slots: kernels
+    may not be traced, or `pallas_kernels.seg_sum_view` has no view of
+    (rows, tokens, width)."""
+    if not kernels:
         return None
     from veles_tpu.ops import pallas_kernels as pk
     return pk.seg_sum_view(rows, *h.shape, h.dtype.itemsize)
 
 
 def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
-                      sizes, lowering=(False, False, False), window=None):
+                      sizes, grouped: str = "ragged_dot",
+                      kernels: bool = False, window=None):
     """The grouped SwiGLU over the first `rows` rows of the sorted
-    buffer: (T, C). Differentiable in h, gates and the weights. With
+    buffer: (T, C). Differentiable in h, gates and the weights. `grouped`
+    and `kernels` are `held_experts_swiglu`'s. With
     `window` = (first row, every pair's sorted row (T, k) or None where
     the combine asks for none) over the `rows` rows from `first` on
     instead (`_in_windows`): what the pairs sorted there add to their
     tokens."""
     t, k = gates.shape
-    tile = _seg_tile(lowering, rows, h)
-    seg = (tile, lowering[1]) if tile else None
+    seg = _seg_tile(kernels, rows, h)
 
     def rows_of():
         """The pairs sorted into the rows at hand. (Sliced where it is
@@ -301,10 +285,10 @@ def _held_rows_swiglu(rows: int, h, gates, w_gate, w_up, w_down, order,
             pairs = jnp.where(pairs < first, rows, pairs - first)
     if seg:
         from veles_tpu.ops import pallas_kernels as pk
-        pairs = pk.seg_sum_plan(rows_of(), n_live, k, t, tile)
+        pairs = pk.seg_sum_plan(rows_of(), n_live, k, t, seg)
     live = (jnp.arange(rows) < n_live)[:, None]
     xs = _take_rows(h, token_of, pairs, n_live, seg)
-    dot = _grouped_product(sizes, rows, h, w_gate, *lowering[:2])
+    dot = _grouped_product(sizes, rows, h, w_gate, grouped, kernels)
     # rows past the last group are no expert's: whatever a grouped product
     # leaves there must not reach the sum or, through it, a gradient
     a = jnp.where(live, dot(xs, w_gate), 0)
@@ -338,33 +322,32 @@ def _windows(sizes_of: Tuple[int, int], h) -> int:
     return -(-all_rows // fast_rows)
 
 
-def _in_windows(sizes_of, lowering, h, order, sizes, n_windows: int, k: int):
+def _in_windows(sizes_of, grouped, kernels, h, order, sizes, n_windows: int,
+                k: int):
     """(part(first row, h, gates, the three weights) -> what the window of
     `fast_rows` rows from `first` on adds (T, C), the windows that hold a
     live row). Every pair's sorted row is found once, outside the walk,
     where the combine gathers by it."""
     fast_rows = sizes_of[0]
-    slot_of = None if _seg_tile(lowering, fast_rows, h) else \
+    slot_of = None if _seg_tile(kernels, fast_rows, h) else \
         jnp.argsort(order).astype(jnp.int32).reshape(-1, k)
     padded = jnp.pad(order, (0, max(
         n_windows * fast_rows - order.shape[0], 0)))
 
     def part(first, *diff):
         return _held_rows_swiglu(
-            fast_rows, *diff, order=padded, sizes=sizes, lowering=lowering,
-            window=(first, slot_of))
+            fast_rows, *diff, order=padded, sizes=sizes, grouped=grouped,
+            kernels=kernels, window=(first, slot_of))
 
     return part, (sizes.sum() + fast_rows - 1) // fast_rows
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool, bool],
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_swiglu(sizes_of: Tuple[int, int], grouped: str, kernels: bool,
                  h, gates, w_gate, w_up, w_down, order, sizes):
     """`_held_rows_swiglu` on `sizes_of[0]` rows where the held pairs fit
     them, on `sizes_of[1]` where they do not: one `lax.cond` forward and
-    one backward, each branch the same code at another static size
-    (`lowering`: the grouped products' (kernels, interpret) and whether
-    the combine may be a kernel, `_seg_tile`). The
+    one backward, each branch the same code at another static size. The
     backward recomputes its branch from the inputs, so that no branch's
     intermediates cross the `cond` (autodiff through it would write the
     untaken branch's residuals as zeros, at the large size). A whole
@@ -373,12 +356,13 @@ def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool, bool],
     fast_rows, all_rows = sizes_of
     run = functools.partial(_held_rows_swiglu, h=h, gates=gates,
                             w_gate=w_gate, w_up=w_up, w_down=w_down,
-                            order=order, sizes=sizes, lowering=lowering)
+                            order=order, sizes=sizes, grouped=grouped,
+                            kernels=kernels)
     n_windows = _windows(sizes_of, h)
 
     def walk():
-        part, n = _in_windows(sizes_of, lowering, h, order, sizes, n_windows,
-                              gates.shape[1])
+        part, n = _in_windows(sizes_of, grouped, kernels, h, order, sizes,
+                              n_windows, gates.shape[1])
         diff = (h, gates, w_gate, w_up, w_down)
         return lax.fori_loop(
             0, n, lambda i, acc: acc + part(
@@ -389,26 +373,26 @@ def _held_swiglu(sizes_of: Tuple[int, int], lowering: Tuple[bool, bool, bool],
                     walk if n_windows else lambda: run(all_rows))
 
 
-def _held_swiglu_fwd(sizes_of, lowering, h, gates, w_gate, w_up, w_down,
-                     order, sizes):
+def _held_swiglu_fwd(sizes_of, grouped, kernels, h, gates, w_gate, w_up,
+                     w_down, order, sizes):
     args = (h, gates, w_gate, w_up, w_down, order, sizes)
-    return _held_swiglu(sizes_of, lowering, *args), args
+    return _held_swiglu(sizes_of, grouped, kernels, *args), args
 
 
-def _held_swiglu_bwd(sizes_of, lowering, args, dy):
+def _held_swiglu_bwd(sizes_of, grouped, kernels, args, dy):
     *diff, order, sizes = args
 
     def grads_at(rows: int):
         def branch():
             _, vjp = jax.vjp(lambda *a: _held_rows_swiglu(
-                rows, *a, order=order, sizes=sizes, lowering=lowering),
-                *diff)
+                rows, *a, order=order, sizes=sizes, grouped=grouped,
+                kernels=kernels), *diff)
             return vjp(dy)
         return branch
 
     def walk():
-        part, n = _in_windows(sizes_of, lowering, diff[0], order, sizes,
-                              n_windows, diff[1].shape[1])
+        part, n = _in_windows(sizes_of, grouped, kernels, diff[0], order,
+                              sizes, n_windows, diff[1].shape[1])
 
         def more(i, acc):
             _, vjp = jax.vjp(lambda *a: part(i * sizes_of[0], *a), *diff)
@@ -431,8 +415,7 @@ _held_swiglu.defvjp(_held_swiglu_fwd, _held_swiglu_bwd)
 def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
                         held: Tuple[int, int],
                         fast_rows: Optional[int] = None,
-                        kernels: bool = False, interpret: bool = False,
-                        seg_sum: bool = False):
+                        grouped: str = "ragged_dot", kernels: bool = False):
     """The held experts' part of a top-k expert layer, nothing dropped:
     sum over the (token, slot) pairs whose expert is one of
     `held = (first, count)` of gate x SwiGLU_expert(token). h (T, C), idx
@@ -440,10 +423,13 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
 
     The pairs are sorted by expert, the held ones first, and the three
     products run as grouped products over the `count` groups
-    (`lax.ragged_dot`: a grouped-matmul kernel on a TPU, which passes over
-    the row tiles past the last group; with `kernels` the `veles_gmm` /
-    `veles_tgmm` pair of `pallas_kernels`, a group's whole matrix a grid
-    step, `interpret`ed where a test asks). Shapes are static, so the sorted
+    (`grouped`, the layer table's word: `ragged_dot`, a grouped-matmul
+    kernel of XLA's on a TPU, which passes over the row tiles past the last
+    group; `pallas`, the `veles_gmm` / `veles_tgmm` pair of
+    `pallas_kernels`, a group's whole matrix a grid step). `kernels` says
+    whether Pallas kernels may be traced at all (`variants.kernels_ok`:
+    the step allows them and the platform runs them); each kernel then
+    asks its own view of the shape. Shapes are static, so the sorted
     buffer has `fast_rows` rows where the held pairs fit them (what a
     balanced router gives, sized by the caller: everything beside the
     products, the gathers, masks and activations, costs by the buffer's
@@ -451,9 +437,9 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
     against 0.5 ms a product, chip run of PR 32) and T x min(k, count)
     rows, every pair there can be, where they do not: a router that sends
     every token to held experts is computed like any other, more slowly.
-    With `seg_sum` the combine (every token's sum of its held rows) and
-    the transpose of the rows' gather in the backward run as
-    `veles_seg_sum` over the buffer's rows where
+    With `kernels`, whatever forms the products, the combine (every
+    token's sum of its held rows) and the transpose of the rows' gather in
+    the backward run as `veles_seg_sum` over the buffer's rows where
     `pallas_kernels.seg_sum_view` takes the shape, and cost by the rows
     too; else they gather a row a (token, slot) pair (`_sum_rows`).
     Returns (y (T, C), pairs not computed: 0 by this construction,
@@ -469,10 +455,9 @@ def held_experts_swiglu(h, idx, gates, w_gate, w_up, w_down,
              ).sum(axis=0, dtype=jnp.int32)
     total = sizes.sum()
     args = (h, gates, w_gate, w_up, w_down, order, sizes)
-    lowering = (kernels, interpret, seg_sum)
     if fast_rows is None or fast_rows >= all_rows:
-        y = _held_rows_swiglu(all_rows, *args, lowering)
+        y = _held_rows_swiglu(all_rows, *args, grouped, kernels)
     else:
-        y = _held_swiglu((int(fast_rows), all_rows), lowering, *args)
+        y = _held_swiglu((int(fast_rows), all_rows), grouped, kernels, *args)
     return (checkpoint_name(y, MOE_SAVED[0]),
             total - jnp.minimum(total, all_rows))

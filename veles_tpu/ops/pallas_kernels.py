@@ -23,8 +23,8 @@ by token tile, what all three language-model cells run twice a layer).
 Every kernel has a lax twin in ops.xla / ops.attention /
 ops.linear_attention — these are
 drop-in replacements gated by `available()`. Interpret mode is something
-a test ASKS for (`_FORCE_INTERPRET`, `variants.pallas_interpret()`),
-never something the program falls into: off a TPU an unasked kernel
+a test ASKS for (`_FORCE_INTERPRET`, which `variants.pallas_interpret()`
+sets), never something the program falls into: off a TPU an unasked kernel
 call fails in the compiler, and a backend that cannot initialise raises.
 tests/test_chip_compile.py compiles every kernel for a described v5e.
 """
@@ -43,7 +43,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_FORCE_INTERPRET = False  # tests set this on CPU
+#: THE interpret switch: tests set it on the CPU, here or through
+#: `variants.pallas_interpret()`; `_interpret()` reads it where a kernel is
+#: called, `variants.pallas_ok()` where one is chosen
+_FORCE_INTERPRET = False
 
 # ---------------------------------------------------------------------------
 # Hardware bounds and block seeds. The SGD, LRN+maxpool and flash blockings
@@ -229,8 +232,7 @@ KERNEL_NAMES = {
 
 
 def _interpret() -> bool:
-    from veles_tpu.ops import variants
-    return _FORCE_INTERPRET or variants.pallas_interpret_active()
+    return _FORCE_INTERPRET
 
 
 def _kernel_jit(fn):
@@ -1500,9 +1502,9 @@ def _hc_geometry(x, n: int) -> Tuple[int, int]:
     if not tile:
         raise ValueError(
             f"the hyper-connection kernels take no {x.dtype} streams "
-            f"{tuple(x.shape)} of {n} (pallas_kernels.hc_view): call "
-            "ops.lm.hc_pre_pallas / hc_post_pallas, which trace the XLA "
-            "form for such a shape")
+            f"{tuple(x.shape)} of {n} (pallas_kernels.hc_view): "
+            "znicz.lm.BlockSpec.lowerings names the XLA form for such a "
+            "shape")
     return tile, _largest_divisor(c, _LANE, _HC_LANE_SLAB)
 
 

@@ -40,7 +40,8 @@ from typing import Any, Callable, Dict, List, Optional
 __all__ = [
     "Variant", "register_op", "register", "ops", "variants_for", "get",
     "has", "select", "selected", "effective", "clear_selection",
-    "selection_table", "resolve", "pallas_ok", "pallas_interpret",
+    "selection_table", "resolve", "pallas_ok", "kernels_ok",
+    "pallas_interpret",
     "grad_reduce_apply", "grad_reduce_config",
     "grad_reduce_geometry", "grad_reduce_local_request",
     "grad_reduce_resid_len", "grad_reduce_bytes", "q8_encode",
@@ -88,9 +89,6 @@ _OPS: Dict[str, _OpSpec] = {}
 #: global op -> variant-name selection (autotuner / tools write it)
 _selection: Dict[str, str] = {}
 _lock = threading.Lock()
-#: tests and the CPU autotune path set this so pallas variants resolve in
-#: interpret mode where no TPU is attached (tier-1 testability)
-_PALLAS_INTERPRET = False
 
 
 def register_op(op: str, default: str, fallback: Optional[str] = None,
@@ -183,32 +181,36 @@ def selection_table(include_defaults: bool = False) -> Dict[str, str]:
 
 
 def pallas_ok() -> bool:
-    """Can a pallas variant actually run here? True on a TPU backend, or
-    anywhere while `pallas_interpret()` is active. A backend that fails
-    to initialise raises (pallas_kernels.available) — never "no"."""
-    if _PALLAS_INTERPRET:
-        return True
+    """Can a Pallas kernel run here? True on a TPU backend
+    (`pallas_kernels.available`, THE platform question: a backend that
+    fails to initialise raises there, never "no"), or anywhere while
+    interpret mode is asked for (`pallas_interpret()`)."""
     from veles_tpu.ops import pallas_kernels as pk
-    return pk.available()
+    return pk._interpret() or pk.available()
 
 
-def pallas_interpret_active() -> bool:
-    return _PALLAS_INTERPRET
+def kernels_ok(unit: Any = None) -> bool:
+    """Whether what `unit` traces NOW may be a Pallas kernel: the unit
+    allows it (`allow_pallas`, the fused step's word: cleared under GSPMD
+    auto-partitioning, which cannot partition a pallas_call) and the
+    platform runs it (`pallas_ok`). Every kernel of the package asks
+    this, through `resolve` or by itself, and then its own view of the
+    shape (`pallas_kernels.*_view`) where it is called: the XLA form
+    otherwise."""
+    return bool(getattr(unit, "allow_pallas", True)) and pallas_ok()
 
 
 @contextlib.contextmanager
 def pallas_interpret():
-    """Resolve AND run pallas variants in interpret mode — the CPU
-    autotune/tier-1-test path, and the only way besides
-    pallas_kernels._FORCE_INTERPRET that a kernel is ever interpreted
-    (pallas_kernels._interpret reads this flag)."""
-    global _PALLAS_INTERPRET
-    prev = _PALLAS_INTERPRET
-    _PALLAS_INTERPRET = True
+    """Resolve AND run Pallas kernels in interpret mode: the CPU
+    autotune / tier-1-test path. It sets the one switch there is,
+    `pallas_kernels._FORCE_INTERPRET`."""
+    from veles_tpu.ops import pallas_kernels as pk
+    prev, pk._FORCE_INTERPRET = pk._FORCE_INTERPRET, True
     try:
         yield
     finally:
-        _PALLAS_INTERPRET = prev
+        pk._FORCE_INTERPRET = prev
 
 
 def resolve(op: str, unit: Any = None) -> Variant:
@@ -217,13 +219,12 @@ def resolve(op: str, unit: Any = None) -> Variant:
        knobs like MaxPooling(lowering=...));
     2. the global selection (autotuner cache / tools);
     3. the op's registered default.
-    A Pallas variant is swapped for the op's non-pallas fallback in
-    exactly two documented cases, each logged once at WARNING when the
-    variant was explicitly selected: the unit's `allow_pallas` is
-    cleared (FusedTrainStep under GSPMD auto-partitioning — a
-    pallas_call cannot be auto-partitioned), or the initialised backend
-    is not a TPU and interpret mode was not asked for. On a TPU the
-    selected variant is what traces: a kernel the compiler refuses is
+    A Pallas variant is swapped for the op's non-pallas fallback where
+    `kernels_ok(unit)` says no, logged once at WARNING when the variant
+    was explicitly selected: the unit's `allow_pallas` is cleared
+    (FusedTrainStep under GSPMD auto-partitioning), or the initialised
+    backend is not a TPU and interpret mode was not asked for. On a TPU
+    the selected variant is what traces: a kernel the compiler refuses is
     the compiler's error, never a quiet fallback.
     """
     spec = _spec(op)
@@ -232,15 +233,12 @@ def resolve(op: str, unit: Any = None) -> Variant:
     if name is None:
         name = _selection.get(op, spec.default)
     v = get(op, name)
-    if not v.pallas:
+    if not v.pallas or kernels_ok(unit):
         return v
-    if not getattr(unit, "allow_pallas", True):
-        reason = "the unit cleared allow_pallas (GSPMD auto-partitioning)"
-    elif not pallas_ok():
-        reason = ("the backend is not a TPU and interpret mode was not "
-                  "requested")
-    else:
-        return v
+    reason = ("the unit cleared allow_pallas (GSPMD auto-partitioning)"
+              if not getattr(unit, "allow_pallas", True) else
+              "the backend is not a TPU and interpret mode was not "
+              "requested")
     if name != spec.default and (op, name, reason) not in _FALLBACK_WARNED:
         _FALLBACK_WARNED.add((op, name, reason))
         import logging
@@ -289,7 +287,8 @@ register(Variant("lrn", "pallas_one_pass", _lrn_pallas, pallas=True,
 #    apply(p, x, f, n, *, iters, eps, clamp, norm_eps) -> (streams, f's
 #    extra); differentiable. x is (T, n*C), `f` maps the read (T, C) to
 #    ((T, C), anything). No autotuner times this op: the platform
-#    (`resolve`) and the shape (`pallas_kernels.hc_view`) decide.
+#    (`resolve`) and the shape (`pallas_kernels.hc_view`) decide, in
+#    `znicz/lm.py::BlockSpec.lowerings`.
 
 def _hc_xla(p, x, f, n, **kw):
     from veles_tpu.ops import lm
@@ -316,13 +315,15 @@ register(Variant("hc", "pallas_one_pass", _hc_pallas, pallas=True,
                  doc="two custom_vjp functions over four kernels tiled "
                      "over tokens, x read once a side and direction, each "
                      "kernel jitted once for all sites (128 | C and a "
-                     "token tile | T; any other shape traces xla)"))
+                     "token tile | T; a block traces xla for any other "
+                     "shape)"))
 
 
 # -- attention over the keys an indexer selects (ISSUE 35) -------------------
 #    apply(p, h, **kw) -> (y, {index_loss, pairs_selected, selected});
 #    differentiable. h is (N, S, C). No autotuner times this op: the
-#    platform (`resolve`) and the shape (`pallas_kernels.dsa_view`) decide.
+#    platform (`resolve`) and the shape (`pallas_kernels.dsa_view`) decide,
+#    in `znicz/lm.py::BlockSpec.lowerings`.
 
 def _dsa_xla(p, h, **kw):
     from veles_tpu.ops import attention as oa
@@ -332,8 +333,6 @@ def _dsa_xla(p, h, **kw):
 def _dsa_pallas(p, h, **kw):
     from veles_tpu.ops import attention as oa
     from veles_tpu.ops import pallas_kernels as pk
-    if not pk.dsa_view(h.shape[1], kw["head_dim"]):
-        return _dsa_xla(p, h, **kw)
     return oa.indexed_attention(p, h, lowering="pallas_flash",
                                 interpret=pk._interpret(), **kw)
 
@@ -352,7 +351,8 @@ register(Variant("dsa", "pallas_flash", _dsa_pallas, pallas=True,
                  doc="the main attention as four flash kernels over the "
                      "int8 selection, each jitted once for all sites; "
                      "indexer, search and index loss stay XLA (128 | S and "
-                     "128 | head size; any other shape traces xla)"))
+                     "128 | head size; a block traces xla for any other "
+                     "shape)"))
 
 
 # -- max pooling (fused-step lowering; the knob is the BACKWARD shape) ------
@@ -764,9 +764,10 @@ register(Variant("grad_reduce", "hier2",
 #    MultiHeadAttention consults resolve("flash_attn") on its local path
 #    when the flash gate says long-S beats the einsum; generated
 #    candidates over blk_q x blk_k x kv_order come from ops.templates.
-#    Latent attention (`znicz/lm.py::BlockSpec.mla_lowering`) runs its
-#    core through the resolved kernels where `pallas_kernels.flash_view`
-#    admits the shape, and its own blocked XLA form otherwise.
+#    Latent and gated attention (`znicz/lm.py::BlockSpec.lowerings`) run
+#    their core through the resolved kernels where
+#    `pallas_kernels.flash_view` admits the shape, and their own blocked
+#    XLA form otherwise.
 
 def _flash_xla_mha(q, k, v, scale=None, causal=False):
     from veles_tpu.ops import attention as oa

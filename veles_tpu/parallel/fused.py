@@ -319,10 +319,6 @@ class FusedTrainStep:
                         "silently stay shard-local (causality restarts "
                         "at every shard). Set parallel_mode='ring' or "
                         "'ulysses'.")
-        # GSPMD auto-partitioning cannot shard a pallas_call: _forward
-        # clears each unit's `allow_pallas` at trace time, and
-        # variants.resolve() then substitutes the op's non-pallas
-        # fallback
         self.mode = mode
         #: cached identity-jit that gathers cross-process shards to a
         #: replicated array (write_back's host() path); built lazily
@@ -761,14 +757,23 @@ class FusedTrainStep:
 
     # -- forward chain -------------------------------------------------------
 
+    @property
+    def allow_pallas(self) -> bool:
+        """May this step trace Pallas kernels: everywhere except under
+        GSPMD auto-partitioning, which cannot partition a pallas_call.
+        Said once, here: `_chain` and `variant_table` hand it to every
+        registry-consulting unit before they trace or report (several
+        step objects over one workflow each trace the right lowering),
+        and the step's own resolutions (`_pair_fusion`, `_sgd_variant`)
+        pass the step as the unit, so `variants.resolve` reads it."""
+        return self.mode != "gspmd"
+
     def _pair_fusion(self, u, nxt):
         """The FUSED registry variant claiming the adjacent (u, nxt)
         pair at trace time, or None (composed winner / pallas gated /
         per-layer overrides / incompatible flavors). One rule shared by
         _forward, variant_table and the jaxpr auditor's fused-pair pass
         — traced == reported == audited."""
-        import types
-
         from veles_tpu.ops import templates
         if nxt is None:
             return None
@@ -779,14 +784,11 @@ class FusedTrainStep:
         if getattr(u, "variant_override", None) is not None \
                 or getattr(nxt, "variant_override", None) is not None:
             return None
-        # the pallas gate rides a shim unit (the _sgd_variant precedent):
-        # the members' variant_override must not leak into the FUSION
-        # op's resolution
-        shim = types.SimpleNamespace(
-            allow_pallas=self.mode != "gspmd")
+        # the FUSION op resolves against the step, not a member: the
+        # step carries the pallas gate and no variant_override to leak
         if op_a == "lrn" and op_b == "maxpool" \
                 and not getattr(nxt, "use_abs", False):
-            return templates.fusion_point("lrn_maxpool", unit=shim)
+            return templates.fusion_point("lrn_maxpool", unit=self)
         if op_a == "conv_stem" and op_b == "lrn":
             # only auto-mode applicable stems consult the registry end
             # to end (the unit's own fused_apply gate)
@@ -794,7 +796,7 @@ class FusedTrainStep:
                     or not getattr(u, "input", None) \
                     or not u._s2d_applicable(u.input.shape[-1]):
                 return None
-            return templates.fusion_point("conv_stem", unit=shim)
+            return templates.fusion_point("conv_stem", unit=self)
         return None
 
     def fusion_pairs(self):
@@ -891,12 +893,7 @@ class FusedTrainStep:
             if hasattr(u, "ep_axis_name"):
                 u.ep_axis_name = ep_axis
             if getattr(u, "variant_op", None) is not None:
-                # registry-consulting units: pallas lowerings are legal
-                # everywhere except under GSPMD auto-partitioning (a
-                # pallas_call cannot be partitioned); set at trace time
-                # so several step objects over one workflow each trace
-                # the right lowering (same pattern as seq_axis_name)
-                u.allow_pallas = self.mode != "gspmd"
+                u.allow_pallas = self.allow_pallas
         # searched cross-op fusion (ISSUE 13): a fused winner lets the
         # leading unit claim its successor's work — the successor
         # becomes a pass-through for this trace. Key folds keep the
@@ -963,7 +960,7 @@ class FusedTrainStep:
         collectives to get there), so tensor parallelism provably
         partitions the activation flops instead of silently replicating
         them (the failure mode the round-2 verdict flagged)."""
-        if self.mode != "gspmd" or self.mesh is None:
+        if not (self.mode == "gspmd" and self.mesh is not None):
             return x
         if getattr(self, "_tp_out_sharded", None) is None:
             self._param_shardings()
@@ -1159,15 +1156,10 @@ class FusedTrainStep:
         """The sgd_update registry variant this step traces — ONE
         resolution rule for the update itself (_apply_update) and the
         reported table (variant_table), so a record can never name a
-        variant the step didn't trace. GSPMD falls back: a pallas_call
-        cannot be auto-partitioned (same gate as the unit path)."""
-        import types
-
+        variant the step didn't trace. The step is the unit: GSPMD falls
+        back (`allow_pallas`, same gate as the unit path)."""
         from veles_tpu.ops import variants
-        return variants.resolve(
-            "sgd_update",
-            unit=types.SimpleNamespace(
-                allow_pallas=self.mode != "gspmd"))
+        return variants.resolve("sgd_update", unit=self)
 
     def _apply_update(self, state, grads, gathered: frozenset,
                       counted=()):
@@ -1746,7 +1738,7 @@ class FusedTrainStep:
                 # a claimed unit traces the fused kernel, not its own
                 # registry resolution — reported below, qualified
                 continue
-            u.allow_pallas = self.mode != "gspmd"   # mirror _forward
+            u.allow_pallas = self.allow_pallas      # mirror _chain
             # units whose traced lowering can diverge from the raw
             # registry resolution (conv per-layer s2d override /
             # inapplicable auto stems) report through variant_effective;

@@ -36,12 +36,17 @@ Scopes inside a block, for a profile: `hc_pre` (the three maps, Sinkhorn,
 reading the sub-layer's input from the streams), `mla`, `hc_post` (writing
 the streams), `mlp`, or `moe/router`, `moe/dispatch`, `moe/experts`,
 `moe/combine`, `moe/shared`; in the head `head` and `mtp/...`. The two
-`hc_*` scopes are opened by `ops.lm.hyper_connection`, whose lowering
-(`xla` or the kernels of `pallas_one_pass`) the registry resolves at trace
-time from the platform and `BlockSpec.hc_lowering` reports by the shape.
-Latent attention's core (scores, softmax, values) likewise: the registry
-op `flash_attn`'s kernels where `BlockSpec.mla_lowering` admits the shape,
-the blocked XLA form of `ops.attention.latent_attention` otherwise.
+`hc_*` scopes are opened by `ops.lm.hyper_connection`.
+
+Which kernel a block traces is ONE rule (`variants.kernels_ok`, then the
+kernel's view of the shape): a Pallas kernel where the step allows it
+(not under GSPMD), the platform runs it (a TPU, or interpret mode asked
+for) and the kernel's `*_view` takes the shape; the XLA form otherwise.
+`BlockSpec.lowerings` says it for the registry ops (`hc`, `dsa`,
+`flash_attn`: what `apply` traces and `variant_table()` reports), the
+held experts' products and combine (`ops/moe.py`) and the Gated DeltaNet's
+operand stage (`ops/linear_attention.py`) get the first two thirds as one
+boolean and ask their own view.
 """
 
 from __future__ import annotations
@@ -158,55 +163,44 @@ class BlockSpec:
     #: its `apply` counts it under: differentiable terms of the loss
     LOSS_TERMS = {"balance_loss": "balance", "index_loss": "index"}
 
-    #: whether a Pallas lowering may be traced (`variants.resolve` reads
-    #: it): the unit that owns the spec hands on the fused step's word
-    #: before every trace
+    #: the step's word on Pallas kernels (`variants.kernels_ok` reads it):
+    #: the unit that owns the spec hands it on before every trace
     allow_pallas = True
 
-    def hc_lowering(self, tokens: int) -> Optional[str]:
-        """The `hc` lowering a trace of `tokens` tokens takes: the
-        registry's by platform, `xla` where the kernels have no view of
-        the shape; None on a plain residual path."""
-        if self.residual != "hc":
-            return None
-        v = variants.resolve("hc", unit=self)
-        if v.pallas:
-            from veles_tpu.ops import pallas_kernels as pk
-            if not pk.hc_view(tokens, self.c, self.n):
-                return "xla"
-        return v.name
+    #: the registry ops a block resolves at trace time: (the view of
+    #: `pallas_kernels` that says whether the op's kernels take a shape,
+    #: the op's XLA form; `xla_blocked`: latent and gated attention's
+    #: blocked form in the place of the op's `xla_mha`). A kernel family
+    #: that is a registry op is one row here and one shape below
+    KERNEL_OPS = {"hc": ("hc_view", "xla"), "dsa": ("dsa_view", "xla"),
+                  "flash_attn": ("flash_view", "xla_blocked")}
 
-    def mla_lowering(self, seq: int) -> Optional[str]:
-        """What the core of latent or gated attention traces over
-        sequences of `seq` tokens: the registry's `flash_attn` lowering
-        where it is a kernel the platform runs and
-        `pallas_kernels.flash_view` admits the shape, else `xla_blocked`,
-        the blocked XLA form (these attentions' fallback in the place of
-        the op's `xla_mha`); None for another kind of attention."""
-        if self.attention == "latent":
-            key, value = self.nope + self.rope, self.v_dim
+    def lowerings(self, batch: int, seq: int) -> Dict[str, str]:
+        """{registry op: the lowering it traces} for the ops this block
+        resolves over `batch` sequences of `seq` tokens: `hc` on
+        hyper-connected streams, `dsa` for indexed, `flash_attn` for
+        latent and gated attention. The rule, for each: the registry's
+        kernel where `variants.resolve` hands one out (the step allows
+        kernels and the platform runs them) and the kernels' view takes
+        the shape, else the op's XLA form. What `apply` traces and what
+        `variant_table()` reports."""
+        shapes: Dict[str, Tuple[int, ...]] = {}
+        if self.residual == "hc":
+            shapes["hc"] = (batch * seq, self.c, self.n)
+        if self.attention == "indexed":
+            shapes["dsa"] = (seq, self.head_dim)
+        elif self.attention == "latent":
+            shapes["flash_attn"] = (seq, self.nope + self.rope, self.v_dim)
         elif self.attention == "gated":
-            key = value = self.head_dim
-        else:
-            return None
-        v = variants.resolve("flash_attn", unit=self)
-        if v.pallas:
-            from veles_tpu.ops import pallas_kernels as pk
-            if pk.flash_view(seq, key, value):
-                return v.name
-        return "xla_blocked"
-
-    def dsa_lowering(self, seq: int) -> Optional[str]:
-        """The `dsa` lowering a trace of sequences of `seq` tokens takes;
-        None for another kind of attention."""
-        if self.attention != "indexed":
-            return None
-        v = variants.resolve("dsa", unit=self)
-        if v.pallas:
-            from veles_tpu.ops import pallas_kernels as pk
-            if not pk.dsa_view(seq, self.head_dim):
-                return "xla"
-        return v.name
+            shapes["flash_attn"] = (seq, self.head_dim, self.head_dim)
+        from veles_tpu.ops import pallas_kernels as pk
+        out = {}
+        for op, shape in shapes.items():
+            view, xla = self.KERNEL_OPS[op]
+            v = variants.resolve(op, unit=self)
+            out[op] = v.name if v.pallas and getattr(pk, view)(*shape) \
+                else xla
+        return out
 
     # -- parameters ----------------------------------------------------------
 
@@ -344,25 +338,30 @@ class BlockSpec:
 
     # -- forward -----------------------------------------------------------------
 
-    def _hc(self, p: Dict[str, Any], prefix: str, x, f):
+    def _hc(self, p: Dict[str, Any], prefix: str, x, f, lowering):
         """The residual path around the sub-layer `f`: x (T, n*C) ->
-        (x, f's extra). One hyper-connection, or x + f(x)."""
+        (x, f's extra). One hyper-connection under `lowering`
+        (`lowerings`' `hc`), or x + f(x)."""
         if self.residual == "plain":
             y, extra = f(x)
             return x + y, extra
-        return variants.resolve("hc", unit=self).apply(
+        return variants.get("hc", lowering).apply(
             {k[len(prefix):]: v for k, v in p.items()
              if k.startswith(prefix)}, x, f, self.n,
             iters=self.sinkhorn_iters, eps=self.hc_eps,
             clamp=self.hc_clamp, norm_eps=self.norm_eps)
 
-    def _attention(self, p: Dict[str, Any], h, batch: int):
+    def _attention(self, p: Dict[str, Any], h, batch: int,
+                   low: Dict[str, str]):
+        """The token mixer, its registry op under `low`'s lowering
+        (`lowerings`; a Gated DeltaNet resolves none)."""
         if self.attention == "indexed":
-            return self._indexed_attention(p, h, batch)
+            return self._indexed_attention(p, h, batch, low["dsa"])
         if self.attention == "gated":
-            return self._gated_attention(p, h, batch)
+            return self._gated_attention(p, h, batch, low["flash_attn"])
         if self.attention == "gated_delta":
             return self._gated_delta(p, h, batch)
+        lowering = low["flash_attn"]
         with jax.named_scope("mla"):
             seq = h.shape[0] // batch
             rs = self.rope_scaling
@@ -376,7 +375,6 @@ class BlockSpec:
                 seq, inv_freq,
                 ol.yarn_mscale(factor, rs.get("mscale", 1)) / all_dim)
             hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
-            lowering = self.mla_lowering(seq)
             y = oa.latent_attention(
                 _under(p, "attn_"),
                 hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
@@ -390,10 +388,9 @@ class BlockSpec:
     def _norm(self, x, scale):
         return ol.rms_norm(x, scale, self.norm_eps, offset=self.norm_offset)
 
-    def _gated_attention(self, p: Dict[str, Any], h, batch: int):
+    def _gated_attention(self, p: Dict[str, Any], h, batch: int, lowering):
         with jax.named_scope("attn"):
             seq = h.shape[0] // batch
-            lowering = self.mla_lowering(seq)
             y = oa.gated_attention(
                 _under(p, "attn_"),
                 self._norm(h, p["attn_norm"]).reshape(batch, seq, self.c),
@@ -427,7 +424,8 @@ class BlockSpec:
                     own, x, key_heads=self.key_heads,
                     value_heads=self.value_heads, key_dim=self.key_dim,
                     value_dim=self.value_dim, chunk=self.chunk,
-                    norm_eps=self.norm_eps)),
+                    norm_eps=self.norm_eps,
+                    kernels=variants.kernels_ok(self))),
                 hn.reshape(groups, batch // groups, seq, self.c))
             y = checkpoint_name(y.reshape(batch, seq, self.c), la.GDN_OUT)
             return y.reshape(h.shape), {
@@ -440,11 +438,12 @@ class BlockSpec:
                 "gdn_chunks": jnp.asarray(
                     batch * la.chunks_of(seq, self.chunk)[1], jnp.int32)}
 
-    def _indexed_attention(self, p: Dict[str, Any], h, batch: int):
+    def _indexed_attention(self, p: Dict[str, Any], h, batch: int,
+                           lowering):
         with jax.named_scope("dsa"):
             seq = h.shape[0] // batch
             hn = ol.rms_norm(h, p["attn_norm"], self.norm_eps)
-            y, extra = variants.resolve("dsa", unit=self).apply(
+            y, extra = variants.get("dsa", lowering).apply(
                 _under(p, "attn_"),
                 hn.reshape(batch, seq, self.c), n_heads=self.n_heads,
                 kv_heads=self.kv_heads, head_dim=self.head_dim,
@@ -457,15 +456,6 @@ class BlockSpec:
     def fast_rows(self, tokens: int) -> int:
         even = tokens * self.top_k * self.held[1] / max(self.n_experts, 1)
         return int(np.ceil(FAST_ROWS_HEADROOM[self.scoring] * even))
-
-    def grouped_kernels(self) -> bool:
-        """Whether the held experts' products trace the `veles_gmm` /
-        `veles_tgmm` kernels: where the layer table asks for them
-        (`grouped="pallas"`) and, as for every Pallas lowering
-        (`variants.resolve`), the step allows them and the platform runs
-        them (a TPU, or interpret mode asked for); else `lax.ragged_dot`."""
-        return (self.grouped == "pallas" and self.allow_pallas
-                and variants.pallas_ok())
 
     def _experts(self, p: Dict[str, Any], h, bias):
         with jax.named_scope("moe"):
@@ -491,10 +481,8 @@ class BlockSpec:
                 y, dropped = om.held_experts_swiglu(
                     hn, idx, gates, p["moe_experts_gate"],
                     p["moe_experts_up"], p["moe_experts_down"], self.held,
-                    self.fast_rows(h.shape[0]), self.grouped_kernels(),
-                    variants.pallas_interpret_active(),
-                    # the combine is the same whatever forms the products
-                    seg_sum=self.allow_pallas and variants.pallas_ok())
+                    self.fast_rows(h.shape[0]), self.grouped,
+                    variants.kernels_ok(self))
             if self.shared:
                 with jax.named_scope("shared"):
                     ys = ol.swiglu(hn, p["moe_shared_gate"],
@@ -520,14 +508,17 @@ class BlockSpec:
         layer's loads and selected experts, indexed attention's selection;
         with them the block's terms of the loss, `LOSS_TERMS`)."""
         batch = x.shape[0]
+        low = self.lowerings(*x.shape[:2])
+        hc = low.get("hc")
         flat = x.reshape(-1, x.shape[-1])
-        flat, seen = self._hc(p, "hca_", flat,
-                              lambda h: self._attention(p, h, batch))
+        flat, seen = self._hc(
+            p, "hca_", flat, lambda h: self._attention(p, h, batch, low), hc)
         if self.ffn == "dense":
-            flat, out = self._hc(p, "hcm_", flat, lambda h: self._mlp(p, h))
+            flat, out = self._hc(p, "hcm_", flat, lambda h: self._mlp(p, h),
+                                 hc)
         else:
             flat, out = self._hc(p, "hcm_", flat,
-                                 lambda h: self._experts(p, h, bias))
+                                 lambda h: self._experts(p, h, bias), hc)
         if out and "router_probs" in out:
             # of each sequence, which the expert layer does not know
             with jax.named_scope("moe"), jax.named_scope("balance_loss"):
@@ -621,33 +612,30 @@ def _gaussian(unit):
         else np.zeros(shape, np.float32))
 
 
-def _hc_effective(unit) -> Optional[str]:
-    """What the unit's registry op traces, for `variant_table()`: its
-    hyper-connections' lowering, or its indexed attention's (a unit's
-    `variant_op` names which); None for a unit that holds none (a head
-    without its MTP block)."""
-    if unit.spec is None or not unit.input:
-        return None
-    unit.spec.allow_pallas = getattr(unit, "allow_pallas", True)
-    n, s = unit.input.shape[:2]
-    if unit.variant_op == "dsa":
-        return unit.spec.dsa_lowering(s)
-    return unit.spec.hc_lowering(n * s)
-
-
-def _more_effective(unit) -> Dict[str, str]:
-    """The registry ops the unit resolves at trace time beside its
-    `variant_op`, for `variant_table()`: the core of its latent
-    attention (`flash_attn`)."""
-    if unit.spec is None or not unit.input:
-        return {}
-    unit.spec.allow_pallas = getattr(unit, "allow_pallas", True)
-    name = unit.spec.mla_lowering(unit.input.shape[1])
-    return {"flash_attn": name} if name else {}
-
-
 class _LMUnit(Forward):
     """Parameters by name from a shape table; the fused step only."""
+
+    def _block(self) -> Optional[BlockSpec]:
+        """The unit's block with the fused step's word on Pallas kernels
+        handed on to it (the step sets the unit's `allow_pallas` before
+        every trace and report); None for a head without its MTP
+        block."""
+        if self.spec is not None:
+            self.spec.allow_pallas = getattr(self, "allow_pallas", True)
+        return self.spec
+
+    def variant_more(self) -> Dict[str, str]:
+        """What the registry ops the unit's block resolves trace, for
+        `variant_table()` (`BlockSpec.lowerings`)."""
+        spec = self._block()
+        if spec is None or not self.input:
+            return {}
+        return spec.lowerings(*self.input.shape[:2])
+
+    def variant_effective(self) -> Optional[str]:
+        """The same for the unit's `variant_op` alone; None where the
+        block does not resolve it."""
+        return self.variant_more().get(self.variant_op)
 
     def _make_arrays(self, names: Sequence[str], aux: Sequence[str] = ()
                      ) -> None:
@@ -717,8 +705,6 @@ class HCBlock(_LMUnit):
     #: (xla | pallas_one_pass); no `variant_signature`: the autotuner times
     #: nothing here, the platform and the shape decide
     variant_op = "hc"
-    variant_effective = _hc_effective
-    variant_more = _more_effective
 
     def __init__(self, workflow=None, streams: int = 1, **kwargs: Any
                  ) -> None:
@@ -768,9 +754,8 @@ class HCBlock(_LMUnit):
     fused_float32_params = ("attn_a_log", "attn_dt_bias")
 
     def fused_apply(self, params, x, *, key=None, train=True, aux=None):
-        self.spec.allow_pallas = getattr(self, "allow_pallas", True)
-        y, out = self.spec.apply(params, x["x"],
-                                 None if aux is None else aux.get("bias"))
+        y, out = self._block().apply(
+            params, x["x"], None if aux is None else aux.get("bias"))
         y = {**x, "x": y}
         terms = {name: out.pop(key) for key, name in
                  BlockSpec.LOSS_TERMS.items() if out and key in out}
@@ -819,8 +804,6 @@ class LMHead(_LMUnit):
     fused_emits_logits = True       # n_classes is the vocabulary
     fused_remat = False
     variant_op = "hc"               # the MTP block's two (`HCBlock`)
-    variant_effective = _hc_effective
-    variant_more = _more_effective
 
     def __init__(self, workflow=None, vocab: int = 256, streams: int = 1,
                  loss_chunk: int = 1024, mtp: Optional[Dict[str, Any]] = None,
@@ -929,8 +912,8 @@ class LMHead(_LMUnit):
                     axis=-1)
                 h = ol.mm(joined, p["mtp_w_proj"])
                 x2 = jnp.tile(h, (1, self.streams)).reshape(n, s, -1)
-            self.spec.allow_pallas = getattr(self, "allow_pallas", True)
-            block = jax.checkpoint(self.spec.apply, policy=_SAVED_POLICY)
+            block = jax.checkpoint(self._block().apply,
+                                   policy=_SAVED_POLICY)
             x2, counted = block(
                 {k[len("mtp_"):]: v for k, v in p.items()
                  if k.startswith("mtp_")}, x2,
